@@ -4,7 +4,7 @@ A jit-compiled body executes at TRACE time on abstract values and is
 then replayed from the compiled executable forever after — a
 ``time.time()``, ``random.random()``, ``np.random...`` draw, or
 ``os.environ`` read inside one is evaluated ONCE at compile and baked
-into the program as a constant.  With the persistent AOT cache the
+into the program as a constant.  With the persistent compile cache the
 constant then survives across processes and machines, which turns
 "nondeterminism" into the worse failure: *stale* determinism that
 changes whenever the cache misses.  (Host-side numpy RNG inside a jit
@@ -13,7 +13,7 @@ bit-for-bit assumes the program text is the only input.)
 
 The rule finds functions that are jit targets — decorated ``@jax.jit``
 / ``@partial(jax.jit, ...)``, or referenced by name in ``jax.jit(f)``
-/ ``cached_compile("...", f, ...)`` / ``is_persisted("...", f, ...)``
+/ ``cached_compile("...", f, ...)``
 calls (optionally wrapped in ``x64_scoped``) — and flags calls/reads
 of: ``time.*``, ``random.*``, ``np.random.*``/``numpy.random.*``,
 ``os.environ``/``os.getenv``, ``datetime.now``/``utcnow``,
@@ -36,8 +36,7 @@ from dsi_tpu.analysis.core import (
 )
 
 _JIT_CALLS = ("jax.jit", "jit")
-_COMPILE_CALLS = ("cached_compile", "aotcache.cached_compile",
-                  "is_persisted", "aotcache.is_persisted")
+_COMPILE_CALLS = ("cached_compile", "aotcache.cached_compile")
 _WRAPPERS = ("x64_scoped", "jaxcompat.x64_scoped")
 
 _BANNED_PREFIXES = (

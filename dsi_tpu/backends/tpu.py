@@ -16,12 +16,17 @@ for portable apps):
 * ``tpu_reduce(key, values) -> str`` — optional; defaults to the app's
   ``Reduce``.  For combiner-style apps the reduce phase is tiny (one record
   per unique key per split), so it stays on the host.
+
+Every map task is counted as a device map or a host map (``device_maps`` /
+``host_maps`` here, ``tpu_map_device`` / ``tpu_map_host`` in the tracer's
+counters), so a job whose inputs took the host fallback says so.
 """
 
 from __future__ import annotations
 
 from dsi_tpu.mr import worker as w
 from dsi_tpu.mr.plugin import load_plugin_module
+from dsi_tpu.obs import count as _count
 
 
 class TpuTaskRunner:
@@ -31,6 +36,9 @@ class TpuTaskRunner:
         self.app = app_module
         self.tpu_map = getattr(app_module, "tpu_map", None)
         self.tpu_reduce = getattr(app_module, "tpu_reduce", None)
+        self.device_maps = 0
+        self.host_maps = 0
+        self.platform = ""
         if self.tpu_map is None and self.tpu_reduce is None:
             import sys
 
@@ -42,10 +50,12 @@ class TpuTaskRunner:
 
     @classmethod
     def for_app(cls, name_or_path: str) -> "TpuTaskRunner":
-        from dsi_tpu.utils.platformpin import pin_platform_from_env
+        from dsi_tpu.utils.platformpin import require_device
 
-        pin_platform_from_env()  # e.g. cpu for harness runs
-        return cls(load_plugin_module(name_or_path))
+        devices = require_device("mrworker --backend tpu")
+        runner = cls(load_plugin_module(name_or_path))
+        runner.platform = devices[0].platform
+        return runner
 
     def run_map(self, mapf, filename: str, map_task: int, n_reduce: int,
                 workdir: str = ".") -> None:
@@ -53,10 +63,21 @@ class TpuTaskRunner:
             raw = f.read()
         kva = self.tpu_map(filename, raw) if self.tpu_map else None
         if kva is None:  # host fallback (worker.go:55-92 semantics)
+            self.host_maps += 1
+            _count("tpu_map_host")
             kva = mapf(filename, raw.decode("utf-8", errors="replace"))
+        else:
+            self.device_maps += 1
+            _count("tpu_map_device")
         w.write_intermediates(kva, map_task, n_reduce, workdir)
 
     def run_reduce(self, reducef, reduce_task: int, n_map: int,
                    workdir: str = ".") -> None:
         w.run_reduce_task(self.tpu_reduce or reducef, reduce_task, n_map,
                           workdir)
+
+    def report(self) -> str:
+        """The line the worker prints at exit."""
+        return (f"backend=tpu platform={self.platform} "
+                f"device_maps={self.device_maps} "
+                f"host_maps={self.host_maps}")

@@ -17,7 +17,7 @@ full replay.  This package closes that gap:
   fsync, newest-valid-wins loading and last-two retention;
 * :mod:`~dsi_tpu.ckpt.fault` — :func:`fault_point`, the named
   kill-points (``DSI_FAULT_POINT``/``DSI_FAULT_STEP``) that let tests
-  and ``onchip_evidence.sh`` prove resume against REAL crashes;
+  prove resume against REAL crashes;
 * :mod:`~dsi_tpu.ckpt.writer` — :class:`CheckpointWriter`, the
   capture/commit split (``--ckpt-async``: snapshot pulls overlap the
   next pipeline window, a background writer runs the durable path);

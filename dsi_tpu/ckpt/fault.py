@@ -3,9 +3,8 @@
 The crash-resume guarantee is only evidence if the crashes are real —
 a mocked "restore from dict" test cannot catch a snapshot that forgot
 to fsync, a manifest torn mid-rename, or device state that was captured
-while a fold was still in flight.  This module lets the test grid and
-``scripts/onchip_evidence.sh`` kill a live engine at the points where
-those bugs would hide:
+while a fold was still in flight.  This module lets the test grid
+kill a live engine at the points where those bugs would hide:
 
 * ``post-dispatch`` — right after a step/wave kernel is dispatched (the
   in-flight window holds unconfirmed work that a checkpoint must NOT

@@ -15,6 +15,12 @@ Usage:
 ``--check`` additionally runs the sequential oracle and byte-compares the
 merged output (sort mr-out-* | grep ., test-mr.sh:52-53), exiting non-zero
 on a parity failure.
+
+``--backend tpu`` starts one device worker per chip (``cli/chips.py``) and
+runs the remaining ``--workers`` as reduce-only host helpers, so every map
+task runs on a device; respawned workers keep their slot's role.  This
+process never imports JAX: a parent that holds the chip starves its
+children.
 """
 
 from __future__ import annotations
@@ -24,6 +30,32 @@ import os
 import subprocess
 import sys
 import time
+
+
+def _worker_fleet(args, app: str, env: dict):
+    """Per-slot ``(cmd, env)`` for the job's workers.  Host and native
+    fleets are homogeneous.  A ``tpu`` fleet gets one device worker per
+    chip, each pinned to its chip; the other slots are reduce-only host
+    helpers (``DSI_MR_REDUCE_ONLY``), the shape scripts/test_mr.sh used
+    to build by hand.  With the CPU asked for by name every slot is a
+    device-backend worker (no chip to share)."""
+    base = [sys.executable, "-m", "dsi_tpu.cli.mrworker", "--backend"]
+    if args.backend != "tpu":
+        return [(base + [args.backend, app], env)] * args.workers
+    from dsi_tpu.cli.chips import chip_env, plan_device_workers
+
+    slots, n_chips = plan_device_workers(args.workers, env,
+                                         "mrrun --backend tpu")
+    fleet = []
+    for chip in slots:
+        if chip is None:
+            helper = dict(env)
+            helper["DSI_MR_REDUCE_ONLY"] = "1"
+            fleet.append((base + ["host", app], helper))
+        else:
+            fleet.append((base + ["tpu", app],
+                          chip_env(env, chip, n_chips)))
+    return fleet
 
 
 def main(argv=None) -> int:
@@ -132,12 +164,16 @@ def main(argv=None) -> int:
             except OSError:
                 pass
 
+    # Decided before anything is spawned: the chip-count probe child must
+    # exit before the first worker starts.
+    fleet = _worker_fleet(args, app, env)
+
     if args.replicas:
         if args.net:
             p.error("--net does not support --replicas yet")
         if args.replicas < 2:
             p.error("--replicas wants >= 2 (3 tolerates one kill)")
-        rc = _replica_job(args, workdir, files, app, env)
+        rc = _replica_job(args, workdir, files, fleet, env)
         if args.trace_dir:
             from dsi_tpu.obs import flush_tracing, trace_event
 
@@ -150,7 +186,7 @@ def main(argv=None) -> int:
         p.error("--kill-leader-after needs --replicas")
 
     if args.net:
-        rc = _net_job(args, workdir, files, app, env, journal)
+        rc = _net_job(args, workdir, files, fleet, env, journal)
         if args.trace_dir:
             from dsi_tpu.obs import flush_tracing, trace_event
 
@@ -172,11 +208,9 @@ def main(argv=None) -> int:
     deadline = time.monotonic() + args.timeout
     time.sleep(1.0)  # socket-creation grace (test-mr.sh:39-40)
 
-    worker_cmd = [sys.executable, "-m", "dsi_tpu.cli.mrworker",
-                  "--backend", args.backend, app]
     spawn = time.monotonic()
-    workers = [subprocess.Popen(worker_cmd, env=env, cwd=workdir)
-               for _ in range(args.workers)]
+    workers = [subprocess.Popen(cmd, env=wenv, cwd=workdir)
+               for cmd, wenv in fleet]
     spawned_at = [spawn] * len(workers)
     # A worker that dies crashed (non-zero) is respawned, but an app that
     # can never start (typo'd name, broken plugin) must not burn the whole
@@ -187,7 +221,7 @@ def main(argv=None) -> int:
     #   mr-* data-plane file exists): after a streak covering the whole
     #   fleet twice over, the app provably cannot start, and waiting out
     #   the old ~26-respawn budget (~26 x a 1-3 s interpreter startup)
-    #   just burned the wall clock (VERDICT r5 weak #5).  Seconds, not
+    #   just burned the wall clock.  Seconds, not
     #   minutes.  Any slow death, differing exit code, or completed task
     #   resets the streak — a legitimate crash-app run (which dies
     #   mid-task AFTER committing output) never trips it.
@@ -245,7 +279,8 @@ def main(argv=None) -> int:
                         break
                     respawn_budget -= 1
                     spawned_at[i] = time.monotonic()
-                    workers[i] = subprocess.Popen(worker_cmd, env=env,
+                    workers[i] = subprocess.Popen(fleet[i][0],
+                                                  env=fleet[i][1],
                                                   cwd=workdir)
             if rc:
                 break
@@ -308,7 +343,7 @@ def _parity_check(args, workdir: str, files: list) -> int:
     return 0
 
 
-def _replica_job(args, workdir: str, files: list, app: str,
+def _replica_job(args, workdir: str, files: list, fleet: list,
                  env: dict) -> int:
     """Classic map/reduce under the replicated control plane: the
     coordinator is an N-member ``replicad`` group, workers dial the
@@ -342,13 +377,12 @@ def _replica_job(args, workdir: str, files: list, app: str,
         config={"n_reduce": args.nreduce,
                 "task_timeout_s": args.task_timeout},
         env=env)
-    env["DSI_MR_SOCKET"] = group.spec
-    worker_cmd = [sys.executable, "-m", "dsi_tpu.cli.mrworker",
-                  "--backend", args.backend, app]
+    extra = {"DSI_MR_SOCKET": group.spec, "PYTHONPATH": env["PYTHONPATH"]}
+    fleet = [(cmd, {**wenv, **extra}) for cmd, wenv in fleet]
     t0 = time.monotonic()
     deadline = t0 + args.timeout
-    workers = [subprocess.Popen(worker_cmd, env=env, cwd=workdir)
-               for _ in range(args.workers)]
+    workers = [subprocess.Popen(cmd, env=wenv, cwd=workdir)
+               for cmd, wenv in fleet]
     respawn_budget = max(16, 2 * (len(files) + args.nreduce))
     failover = None
     rc = 0
@@ -383,7 +417,8 @@ def _replica_job(args, workdir: str, files: list, app: str,
                         rc = 1
                         break
                     respawn_budget -= 1
-                    workers[i] = subprocess.Popen(worker_cmd, env=env,
+                    workers[i] = subprocess.Popen(fleet[i][0],
+                                                  env=fleet[i][1],
                                                   cwd=workdir)
             if rc:
                 break
@@ -421,7 +456,7 @@ def _replica_job(args, workdir: str, files: list, app: str,
     return rc
 
 
-def _net_job(args, workdir: str, files: list, app: str,
+def _net_job(args, workdir: str, files: list, fleet: list,
              env: dict, journal: str = "") -> int:
     """The share-nothing job (``--net``): coordinator in-process on
     localhost TCP, each worker in its own PRIVATE workdir serving its
@@ -461,13 +496,22 @@ def _net_job(args, workdir: str, files: list, app: str,
     pkg_root = os.path.dirname(os.path.dirname(
         os.path.abspath(_pkg.__file__)))
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
-    worker_cmd = [sys.executable, "-m", "dsi_tpu.cli.mrworker",
-                  "--backend", args.backend, app]
 
     def spawn(i: int, clean: bool = False):
+        """Worker ``i``.  A replacement past the original fleet reuses
+        the last slot's command; where that is a reduce-only helper it
+        runs as a plain host worker instead — the workers that finished
+        linger outside the task loop, so the replacement must be able to
+        re-execute a lost producer's map as well as the reduce."""
         wdir = os.path.join(workdir, f"worker-{i}")
         os.makedirs(wdir, exist_ok=True)
-        we = dict(env)
+        worker_cmd, wenv = fleet[min(i, len(fleet) - 1)]
+        we = {**wenv, "DSI_MR_SOCKET": env["DSI_MR_SOCKET"],
+              "PYTHONPATH": env["PYTHONPATH"]}
+        if i >= len(fleet):
+            we.pop("DSI_MR_REDUCE_ONLY", None)
+        if "DSI_NET_FETCH_WINDOW" in env:
+            we["DSI_NET_FETCH_WINDOW"] = env["DSI_NET_FETCH_WINDOW"]
         we["DSI_NET_SPOOL"] = wdir
         we["DSI_CHAOS_WORKER_INDEX"] = str(i)
         if clean:

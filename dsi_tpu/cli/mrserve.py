@@ -109,9 +109,9 @@ def main(argv=None) -> int:
 
         start_from_args(args.statusz_port, live_dir=args.trace_dir)
 
-    from dsi_tpu.utils.platformpin import pin_platform_from_env
+    from dsi_tpu.utils.platformpin import require_device
 
-    pin_platform_from_env()
+    require_device("mrserve")
 
     from dsi_tpu.serve.daemon import ServeDaemon
 

@@ -9,13 +9,21 @@ private spool directory, runs the loop in net mode, and LINGERS after
 the job completes so consumers can still fetch its spooled bytes — the
 driver terminates it once every output is safely fetched.
 
-Usage: python -m dsi_tpu.cli.mrworker [--backend host|tpu] <app-name-or-path.py>
+``--backend tpu`` means the device: the worker fails at start unless JAX
+gives it a TPU or the CPU was asked for by name (``JAX_PLATFORMS=cpu``),
+and at exit it prints how many map tasks ran on the device and how many
+fell back to the host.  ``DSI_MR_REDUCE_ONLY=1`` (set by ``mrrun`` for the
+host helpers of a ``--backend tpu`` fleet) makes the worker decline map
+tasks, so every map of such a job runs on the device worker.
+
+Usage: python -m dsi_tpu.cli.mrworker [--backend host|tpu|native] <app-name-or-path.py>
 """
 
 from __future__ import annotations
 
 import argparse
 import os
+import sys
 import time
 
 from dsi_tpu.config import JobConfig
@@ -38,7 +46,8 @@ def main(argv=None) -> int:
     from dsi_tpu import native
 
     native.available()
-    cfg = JobConfig(backend=args.backend)
+    reduce_only = os.environ.get("DSI_MR_REDUCE_ONLY") == "1"
+    cfg = JobConfig(backend=args.backend, take_maps=not reduce_only)
     runner = None
     if args.backend == "tpu":
         from dsi_tpu.backends.tpu import TpuTaskRunner
@@ -53,7 +62,8 @@ def main(argv=None) -> int:
     if spool:
         from dsi_tpu.net import PartitionServer, fetch_window_from_env
 
-        cfg = JobConfig(backend=args.backend, net_shuffle=True,
+        cfg = JobConfig(backend=args.backend, take_maps=not reduce_only,
+                        net_shuffle=True,
                         net_fetch_window=fetch_window_from_env())
         partsrv = PartitionServer(
             spool, bind=os.environ.get("DSI_NET_BIND", ""),
@@ -63,6 +73,9 @@ def main(argv=None) -> int:
     try:
         worker_loop(mapf, reducef, cfg, task_runner=runner,
                     partsrv=partsrv)
+        if args.backend == "tpu":
+            print(f"mrworker: pid={os.getpid()} {runner.report()}",
+                  file=sys.stderr, flush=True)
         if partsrv is not None:
             # Linger: the job is done but the driver may not have
             # fetched this spool's outputs yet — serve until killed.

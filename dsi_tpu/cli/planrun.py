@@ -289,9 +289,9 @@ def main(argv=None) -> int:
 
         configure_tracing(trace_dir=args.trace_dir)
 
-    from dsi_tpu.utils.platformpin import pin_platform_from_env
+    from dsi_tpu.utils.platformpin import require_device
 
-    pin_platform_from_env()
+    require_device("planrun")
 
     from dsi_tpu.ckpt import CheckpointMismatch
     from dsi_tpu.parallel.shuffle import default_mesh

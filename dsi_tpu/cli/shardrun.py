@@ -165,6 +165,20 @@ def main(argv=None) -> int:
                     workers=args.workers, shards=n_shards,
                     files=len(files))
 
+    # Every shard worker runs a device engine, so each needs a chip of
+    # its own (cli/chips.py; this process stays off JAX, and the probe
+    # child has exited before the first worker starts).  With the CPU
+    # asked for by name there is no chip to share and no limit.
+    from dsi_tpu.cli.chips import chip_env, plan_device_workers
+
+    slots, n_chips = plan_device_workers(args.workers, env, "shardrun")
+    if None in slots:
+        print(f"shardrun: --workers {args.workers} device workers but "
+              f"only {n_chips} chip(s): one process per chip. Lower "
+              "--workers, or set JAX_PLATFORMS=cpu to run the engines on "
+              "the CPU on purpose.", file=sys.stderr)
+        return 1
+
     plan = sh.plan_shards(files, n_shards)
     if not plan:
         print("shardrun: empty input", file=sys.stderr)
@@ -233,6 +247,18 @@ def main(argv=None) -> int:
     fault = _parse_worker_knob(args.fault_worker, "--fault-worker") \
         if args.fault_worker else None
 
+    def chip_of(i: int) -> int:
+        """Worker ``i``'s chip.  A replacement past the original fleet
+        takes the chip of a worker that has exited (the one whose death
+        made the replacement necessary), never one still held."""
+        while i >= len(slots):
+            dead = [j for j, w in enumerate(workers)
+                    if w.poll() is not None and j not in retired]
+            j = dead[0] if dead else i % args.workers
+            retired.add(j)
+            slots.append(slots[j])
+        return slots[i]
+
     def worker_dir(i: int) -> str:
         """--hosts: each worker's PRIVATE workdir (cwd + spool); the
         shared-dir plane runs every worker in the job workdir."""
@@ -243,7 +269,7 @@ def main(argv=None) -> int:
         return wdir
 
     def worker_env(i: int) -> dict:
-        we = dict(env)
+        we = chip_env(env, chip_of(i), n_chips)
         we["DSI_CHAOS_WORKER_INDEX"] = str(i)
         if args.hosts:
             we["DSI_NET_SPOOL"] = worker_dir(i)
@@ -260,10 +286,12 @@ def main(argv=None) -> int:
                   "--progress-s", str(args.progress_s)]
     t0 = time.monotonic()
     deadline = t0 + args.timeout
-    workers = [subprocess.Popen(worker_cmd, env=worker_env(i),
-                                cwd=worker_dir(i))
-               for i in range(args.workers)]
+    workers: list = []
+    retired: set = set()  # dead workers whose chip a replacement took
     envs = [worker_env(i) for i in range(args.workers)]
+    workers.extend(subprocess.Popen(worker_cmd, env=envs[i],
+                                    cwd=worker_dir(i))
+                   for i in range(args.workers))
     dirs = [worker_dir(i) for i in range(args.workers)]
     next_idx = args.workers
     # A worker that died crashed (chaos/fault kill) is respawned WITHOUT

@@ -25,6 +25,12 @@ def main(argv=None) -> int:
 
     from dsi_tpu.config import JobConfig
     from dsi_tpu.mr.shardworker import shard_worker_loop
+    from dsi_tpu.utils.platformpin import require_device
+
+    # Every shard runs a device engine: no chip (and no CPU asked for by
+    # name) is an error here, not a silent CPU run (shardrun gives each
+    # worker its chip, cli/chips.py).
+    require_device("shardworker")
 
     kw = {"workdir": args.workdir}
     if args.progress_s is not None:

@@ -61,6 +61,11 @@ class JobConfig:
     # pure Python) or "tpu" (JAX kernels for TPU-aware apps).
     backend: str = "host"
 
+    # Whether this worker takes map tasks.  False for the host helpers of
+    # a ``mrrun --backend tpu`` fleet: they ask for reduce work only, so
+    # every map of a device job runs on the one worker that owns the chip.
+    take_maps: bool = True
+
     # Coordinator socket path ("" -> default_socket_path(workdir)).
     socket_path: str = ""
 

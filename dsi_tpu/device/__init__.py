@@ -25,7 +25,6 @@ from dsi_tpu.device.policy import (SyncPolicy, mesh_shards_default,
                                    sync_every_default)
 from dsi_tpu.device.table import (
     DeviceTable,
-    device_fold_persisted,
     warm_device_fold,
 )
 from dsi_tpu.device.postings import DevicePostings
@@ -34,8 +33,6 @@ from dsi_tpu.device.topk import (
     DeviceHistogram,
     DeviceTopK,
     KeyCounts,
-    histogram_persisted,
-    topk_service_persisted,
     warm_histogram,
     warm_topk_service,
 )
@@ -49,11 +46,8 @@ __all__ = [
     "HostRelay",
     "KeyCounts",
     "SyncPolicy",
-    "device_fold_persisted",
-    "histogram_persisted",
     "mesh_shards_default",
     "sync_every_default",
-    "topk_service_persisted",
     "warm_device_fold",
     "warm_histogram",
     "warm_topk_service",

@@ -289,7 +289,10 @@ class Coordinator:
                 if addr:
                     self._net_addrs[wid] = addr
             if self.c_map < self.n_map:
-                tba = self._pop_untouched(self._map_ready, self.map_log)
+                # A reduce-only worker (host helper of a device fleet)
+                # waits out the map phase.
+                tba = None if args.get("NoMap") else \
+                    self._pop_untouched(self._map_ready, self.map_log)
                 if tba is None:
                     reply["TaskStatus"] = int(TaskStatus.WAITING)  # :58-60
                 else:
@@ -1649,8 +1652,7 @@ class Coordinator:
             self._deadline_cv.notify()
         # Join the watchdog (bounded: it wakes on the notify above) so
         # close() returns with no thread still touching coordinator state
-        # — daemon-abandonment left a shutdown race window (VERDICT r3
-        # nit).  join() on a finished thread returns immediately, so
+        # — daemon-abandonment left a shutdown race window.  join() on a finished thread returns immediately, so
         # repeated close() calls are safe.
         self._monitor.join(timeout=5.0)
         if self._server is not None:

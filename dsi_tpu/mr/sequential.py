@@ -7,7 +7,7 @@ to a single ``mr-out-0`` in ``"%v %v\n"`` format (mrsequential.go:61-86).
 
 The distributed system's merged, sorted output must byte-compare equal to this
 (test-mr.sh:30-31,52-53) — that differential check is this repo's primary
-correctness test and the parity metric in BASELINE.md.
+correctness test.
 """
 
 from __future__ import annotations

@@ -279,6 +279,8 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
     while True:
         chaos_kill_point("task")
         req = {"TaskNumber": 0, "WorkerId": worker_id}
+        if not cfg.take_maps:
+            req["NoMap"] = True  # coordinator answers WAITING in the map phase
         if addr:
             req["Addr"] = addr
         try:

@@ -9,11 +9,19 @@ and loaded lazily.  Every entry point degrades to the pure-Python
 implementation when the library is missing (``DSI_NO_NATIVE=1`` forces
 that), and the C parser defers to Python on any input it cannot prove it
 parsed completely, so native and pure runs can never diverge.
+
+The library is built from the committed sources only: the build writes a
+SHA-256 of ``kvcodec.cpp`` + ``wcjob.cpp`` beside the ``.so``, and a
+library whose recorded hash does not match the sources on disk is never
+loaded — it is rebuilt, or the process says on stderr that it runs the
+pure-Python data plane.  (File times say nothing: a copied tree resets
+them.)
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import struct
 import subprocess
@@ -27,6 +35,31 @@ _lib: "ctypes.CDLL | None | bool" = None  # None = not tried, False = absent
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SO_PATH = os.path.join(_REPO, "build", "libkvcodec.so")
+_HASH_PATH = _SO_PATH + ".sha256"
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_SOURCES = (os.path.join(_HERE, "kvcodec.cpp"),
+            os.path.join(_HERE, "wcjob.cpp"))
+
+
+def _source_hash() -> str:
+    """SHA-256 over the sources in build order — the same bytes
+    ``scripts/build_native.sh`` hashes with ``cat ... | sha256sum``."""
+    h = hashlib.sha256()
+    for path in _SOURCES:
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _built_from_sources() -> bool:
+    """True when the ``.so`` exists and its recorded hash is the hash of
+    the sources on disk."""
+    try:
+        with open(_HASH_PATH, "r", encoding="ascii") as f:
+            recorded = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(_SO_PATH) and recorded == _source_hash()
 
 
 def _load():
@@ -38,26 +71,22 @@ def _load():
         if os.environ.get("DSI_NO_NATIVE") == "1":
             _lib = False
             return None
-        here = os.path.dirname(os.path.abspath(__file__))
-        srcs = [os.path.join(here, "kvcodec.cpp"),
-                os.path.join(here, "wcjob.cpp")]
-        stale = (not os.path.exists(_SO_PATH)
-                 or any(os.path.exists(s)
-                        and os.path.getmtime(s) > os.path.getmtime(_SO_PATH)
-                        for s in srcs))
-        if stale:
+        if not _built_from_sources():
             script = os.path.join(_REPO, "scripts", "build_native.sh")
             try:
                 subprocess.run(["bash", script], check=True,
                                capture_output=True, timeout=120)
-            except Exception as e:  # no compiler / build failure: fall back
-                if os.path.exists(_SO_PATH):
-                    pass  # stale-but-working library beats no library
-                else:
-                    print(f"dsi_tpu.native: build unavailable ({e}); "
-                          "using pure-Python data plane", file=sys.stderr)
-                    _lib = False
-                    return None
+            except (OSError, subprocess.SubprocessError) as e:
+                print(f"dsi_tpu.native: build unavailable ({e}); "
+                      "using pure-Python data plane", file=sys.stderr)
+                _lib = False
+                return None
+            if not _built_from_sources():
+                print("dsi_tpu.native: built library does not match its "
+                      "sources; using pure-Python data plane",
+                      file=sys.stderr)
+                _lib = False
+                return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
             lib.kv_decode_file.restype = ctypes.POINTER(ctypes.c_uint8)

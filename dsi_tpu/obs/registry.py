@@ -2,8 +2,8 @@
 
 Before this module each engine grew its own stats dict with its own
 spellings — ``pipeline_stats``/``stream_phases`` (word count),
-``wave_phases`` (TF-IDF), the grep variants — and bench.py, the CLIs,
-and ``scripts/summarize_onchip.py`` each re-learned every shape.  Now an
+``wave_phases`` (TF-IDF), the grep variants — and bench.py and the CLIs
+each re-learned every shape.  Now an
 engine's stats dict IS a :class:`MetricsScope` registered here under the
 engine's name, and every consumer reads one documented schema.
 
@@ -75,6 +75,11 @@ durability cost, deliberately NOT handoff bytes),
 ``plan_resumed_stages`` (stages skipped by a resume from stage
 manifests), ``plan_stage_walls`` (per-stage wall seconds, keyed by
 stage name), plus the ``plan_s`` / ``stage_commit_s`` phases.
+
+``device_rows`` (the "stream" scope) is a length-``n_dev`` list: the
+reduce-output rows each device of the mesh produced, summed over
+confirmed steps — every entry is non-zero when every device held a shard
+of the all-to-all shuffle.
 
 Mesh-sharded service keys (``mesh_shards`` > 0, the shuffle-fold path
 — ``device/table.py``): ``mesh_shards`` (the sharding degree),
@@ -172,7 +177,7 @@ COUNTER_KEYS = (
     "postings_widens", "topk_snapshots", "hist_folds", "hist_pulls",
     "table_cap", "l_cap", "sync_every", "max_inflight",
     "buffer_allocs", "device_accumulate", "donate_chunks", "stalls",
-    "upload_mode",
+    "device_rows",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

@@ -15,47 +15,13 @@ depends on the kernel (``backends/tpu.py`` contract).
 from __future__ import annotations
 
 import functools
-import os
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-import dsi_tpu.ops.wordcount as _wordcount_mod
 from dsi_tpu.ops.wordcount import _pad_pow2, _shift_left
-
-
-def cold_ok() -> bool:
-    """THE cold-compile bypass knob: ``DSI_COLD_OK=1`` disables every
-    device-readiness gate (this module's, the NFA tier's, and anything
-    the streaming grep/indexer/top-k programs grow) for processes whose
-    JOB the compiles are — scripts/warm_kernels.py sets it around its
-    warm blocks.  The historical per-tier names ``DSI_GREP_COLD_OK`` /
-    ``DSI_NFA_COLD_OK`` remain as aliases so existing scripts and soak
-    recipes keep working, but new gates must consult this one function
-    rather than growing a third env var."""
-    return any(os.environ.get(v) == "1"
-               for v in ("DSI_COLD_OK", "DSI_GREP_COLD_OK",
-                         "DSI_NFA_COLD_OK"))
-
-
-def device_ready(name: str, fn, example, static) -> bool:
-    """Whether dispatching this compiled shape NOW is a millisecond load
-    or a multi-minute remote compile — the bench's
-    ``corpus_executable_persisted`` discipline, shared by every grep
-    tier's rung gate (ADVICE r4: the l_cap escalation rung is a
-    separately compiled shape, and an ungated escalation cold-compiles
-    inside a worker task).  CPU backends are always ready (compiles are
-    seconds); ``DSI_COLD_OK=1`` (see :func:`cold_ok`) bypasses the gate
-    for scripts/warm_kernels.py, whose job the compiles are."""
-    if cold_ok():
-        return True
-    if jax.devices()[0].platform == "cpu":
-        return True
-    from dsi_tpu.backends.aotcache import is_persisted
-
-    return is_persisted(name, fn, example, static=static)
 
 
 def line_flags_from_match(chunk: jax.Array, match: jax.Array, l_cap: int):
@@ -79,23 +45,17 @@ def line_flags_from_match(chunk: jax.Array, match: jax.Array, l_cap: int):
 def line_cap_rungs(n: int):
     """The shared l_cap rung schedule: average line >= 8 bytes first,
     then the n+1 hard bound (every byte a '\\n').  One definition so
-    readiness probes (``ops/nfak._device_ready``) and the retry loop can
-    never drift onto different compiled shapes."""
+    the warm ladders and the retry loop can never drift onto different
+    compiled shapes."""
     return (max(n // 8, 1), n + 1)
 
 
-def retry_line_caps(n: int, run, ready=None):
-    """Shared l_cap rung schedule (exactness_retry discipline): average
-    line >= 8 bytes first, then the n+1 hard bound (every byte a '\\n').
-    ``run(l_cap)`` -> (line_match, n_lines, overflow).
-
-    ``ready(l_cap)``, when given, gates EVERY rung (including the
-    overflow escalation, a separately compiled shape): a not-ready rung
-    returns ``(None, -1)`` and the caller serves the job on the host
-    path instead of cold-compiling inside a worker task."""
+def retry_line_caps(n: int, run):
+    """Walk :func:`line_cap_rungs` (exactness_retry discipline) until a
+    rung's line buffer holds every line.  ``run(l_cap)`` ->
+    (line_match, n_lines, overflow).  A rung not compiled yet compiles
+    here, logged and counted like any other program."""
     for l_cap in line_cap_rungs(n):
-        if ready is not None and not ready(l_cap):
-            return None, -1
         line_match, n_lines, overflow = run(l_cap)
         if not bool(overflow):
             break
@@ -128,11 +88,6 @@ def grep_kernel(chunk: jax.Array, pattern: jax.Array, *, l_cap: int):
     return line_flags_from_match(chunk, match, l_cap)
 
 
-# The AOT cache fingerprints these sources: grep_kernel uses wordcount
-# helpers (_shift_left), so editing them must invalidate stale executables.
-grep_kernel._aot_code_deps = (_wordcount_mod,)
-
-
 def _grep_example(n: int, m: int):
     return (jax.ShapeDtypeStruct((n,), np.uint8),
             jax.ShapeDtypeStruct((m,), np.uint8))
@@ -146,17 +101,9 @@ def _grep_compiled(n: int, m: int, l_cap: int):
                           static={"l_cap": l_cap})
 
 
-def grep_rung_ready(n: int, m: int, l_cap: int) -> bool:
-    """Readiness probe for exactly the shape ``_grep_compiled`` builds —
-    shared with the alternation tier (``ops/altk.py``)."""
-    return device_ready("grep_kernel", grep_kernel, _grep_example(n, m),
-                        {"l_cap": l_cap})
-
-
 def _grep_jit(chunk, pattern, *, l_cap: int):
-    """The grep kernel through the persistent AOT executable cache
-    (backends/aotcache.py) — fresh worker processes load the serialized
-    executable instead of re-paying the XLA compile."""
+    """The grep kernel as an explicitly compiled, memoized program
+    (backends/aotcache.py)."""
     fn = _grep_compiled(int(chunk.shape[0]), int(pattern.shape[0]), l_cap)
     return fn(chunk, pattern)
 
@@ -189,10 +136,6 @@ def grep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     chunk = jnp.asarray(_pad_pow2(data))
     pat = jnp.asarray(np.frombuffer(pattern.encode("ascii"), dtype=np.uint8))
     n = int(chunk.shape[0])
-    m = len(pattern)
     line_match, nl = retry_line_caps(
-        n, lambda l_cap: _grep_jit(chunk, pat, l_cap=l_cap),
-        ready=lambda l_cap: grep_rung_ready(n, m, l_cap))
-    if line_match is None:
-        return None  # cold remote compile in-task: host serves this job
+        n, lambda l_cap: _grep_jit(chunk, pat, l_cap=l_cap))
     return lines_from_flags(text, line_match, nl)

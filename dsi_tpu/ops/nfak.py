@@ -23,9 +23,7 @@ TPU-first shape — no data-dependent control flow, log-depth, MXU-heavy:
    ``segment_max`` machinery as every other grep tier.
 3. The table and start vector are program ARGUMENTS, not constants: one
    compiled executable (per chunk-size/state-bucket/l_cap) serves EVERY
-   pattern — warm it once on the chip and all variable-length patterns
-   accelerate, which matters on a platform where each remote compile
-   costs minutes (BASELINE.md).
+   pattern — compile it once and all variable-length patterns share it.
 
 Line discipline: content classes exclude ``\\n``/``\\0``, so no match
 window spans lines or padding; the line-end bytes reset all NFA states
@@ -45,10 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import dsi_tpu.ops.grepk as _grepk_mod
 from dsi_tpu.ops.altk import split_top_level
 from dsi_tpu.ops.grepk import (
-    device_ready,
     line_cap_rungs,
     line_flags_from_match,
     lines_from_flags,
@@ -346,14 +342,6 @@ def nfa_kernel(chunk: jax.Array, table: jax.Array, v0: jax.Array, *,
     return line_flags_from_match(chunk, mask, l_cap)
 
 
-# The traced program uses only grepk's line machinery; regexk/altk/
-# wordcount contribute HOST-side parsing and padding whose effects reach
-# the program through its runtime arguments and shape key, so hashing
-# them would only cause spurious multi-minute recompiles of the shared
-# pattern-independent executable.
-nfa_kernel._aot_code_deps = (_grepk_mod,)
-
-
 def _nfa_example_static(n: int, s_bucket: int, block: int, l_cap: int):
     sds = jax.ShapeDtypeStruct
     example = (sds((n,), jnp.uint8),
@@ -371,24 +359,18 @@ def _nfa_compiled(n: int, s_bucket: int, block: int, l_cap: int):
                           static=static)
 
 
-def _device_ready(n: int, s_bucket: int, block: int, l_cap: int) -> bool:
-    """Readiness probe for exactly the shape ``_nfa_compiled`` builds
-    (shared rung-gate discipline: ``grepk.device_ready``)."""
-    example, static = _nfa_example_static(n, s_bucket, block, l_cap)
-    return device_ready(f"nfagrep_s{s_bucket}", nfa_kernel, example,
-                        static)
-
-
 #: In-process view of the persisted calibration table (loaded once; a
 #: calibration updates both).
 _cost_cache: dict = {}
 _cost_loaded = False
 
 
-def _cost_path() -> str:
-    from dsi_tpu.backends.aotcache import cache_dir
+def _cost_path() -> Optional[str]:
+    """The calibration table's file, beside the one compile cache; None
+    where the cache is switched off (the table is then per process)."""
+    from dsi_tpu.utils.compilecache import cache_dir, enabled
 
-    return os.path.join(cache_dir(), "nfa_cost.json")
+    return os.path.join(cache_dir(), "nfa_cost.json") if enabled() else None
 
 
 def _load_costs() -> dict:
@@ -396,11 +378,13 @@ def _load_costs() -> dict:
     if not _cost_loaded:
         import json
 
-        try:
-            with open(_cost_path()) as f:
-                _cost_cache.update(json.load(f))
-        except (OSError, ValueError):
-            pass
+        path = _cost_path()
+        if path is not None:
+            try:
+                with open(path) as f:
+                    _cost_cache.update(json.load(f))
+            except (OSError, ValueError):
+                pass
         _cost_loaded = True
     return _cost_cache
 
@@ -410,26 +394,26 @@ def _save_cost(key: str, entry: dict) -> None:
 
     costs = _load_costs()
     costs[key] = entry
-    tmp = _cost_path() + f".tmp{os.getpid()}"
+    path = _cost_path()
+    if path is None:
+        return
+    tmp = path + f".tmp{os.getpid()}"
     try:
+        os.makedirs(os.path.dirname(tmp), exist_ok=True)
         # dsicheck: allow[raw-write] calibration cost cache:
         # temp+rename for atomicity, no fsync — a lost entry just
         # re-measures, and _save_cost already swallows OSError because
         # persistence here is an optimization, never a failure
         with open(tmp, "w") as f:
             json.dump(costs, f, indent=1)
-        os.replace(tmp, _cost_path())
+        os.replace(tmp, path)
     except OSError:
         pass  # cost persistence is an optimization, never a failure
 
 
 def _cost_key(s_bucket: int) -> str:
-    import hashlib
-
-    from dsi_tpu.backends.aotcache import _platform_fingerprint
-
-    fp = hashlib.sha256(_platform_fingerprint().encode()).hexdigest()[:8]
-    return f"{jax.devices()[0].platform}-{fp}|s{s_bucket}"
+    d = jax.devices()[0]
+    return f"{d.platform}-{d.device_kind}-jax{jax.__version__}|s{s_bucket}"
 
 
 #: Representative calibration pattern per state bucket (must parse into
@@ -447,18 +431,15 @@ def _cal_text(n_lines: int = 4000) -> bytes:
 
 
 def calibrate_tier4(s_bucket: int, quick: bool = False) -> dict:
-    """Measure host ``re`` vs the NFA kernel once for this (platform,
-    state bucket) and persist the result beside the AOT cache.  On an
-    accelerator this COMPILES the kernel if it is not warm — call it
-    only where that is acceptable (warm_kernels does, under
-    DSI_NFA_COLD_OK; the CPU backend compiles in seconds).
+    """Measure host ``re`` vs the NFA kernel once for this (device,
+    state bucket) and persist the result beside the compile cache.  The
+    kernel compiles here if this process has not compiled it yet.
 
     ``quick=True`` is the inline-dispatch variant (see
     :func:`tier4_preferred`): an ~8x smaller corpus and a single timing
-    rep, bounding the cold-task cost on a contended box well under the
-    coordinator's 10 s presumed-dead requeue threshold (ADVICE r5
-    item 2).  The persisted entry is marked ``{"quick": true}``; a later
-    warm-time full calibration simply overwrites it."""
+    rep, bounding what a cold worker task pays before its first answer.
+    The persisted entry is marked ``{"quick": true}``; a later full
+    calibration simply overwrites it."""
     import re as _re
     import time
 
@@ -507,23 +488,17 @@ def tier4_preferred(s_bucket: int) -> Optional[bool]:
     """Should an eligible variable-length pattern run on the kernel?
 
     ``DSI_NFA_DISPATCH=device|host`` pins the answer.  Otherwise the
-    persisted calibration for this (platform, bucket) decides; with no
-    measurement, the CPU backend calibrates on the spot with the BOUNDED
-    quick variant (small corpus, one rep — a cold worker task must stay
-    far inside the coordinator's 10 s presumed-dead requeue window even
-    on a contended box; ADVICE r5 item 2) and an accelerator answers
-    False — device dispatch stays opt-in until warm_kernels proves it on
-    the chip (VERDICT r4 weakness #3: the S^3-work kernel measured ~10x
-    slower than host ``re`` on CPU, and nothing gated dispatch on that
-    fact).  warm_kernels' later full calibration replaces the quick
-    entry."""
+    persisted calibration for this (device, bucket) decides; with no
+    measurement, the process calibrates on the spot with the BOUNDED
+    quick variant (small corpus, one rep) — on every platform alike, so
+    the answer is always a measurement of the device in use (the
+    S^3-work kernel measured ~10x slower than host ``re`` on XLA:CPU;
+    not measured on the chip)."""
     pin = os.environ.get("DSI_NFA_DISPATCH")
     if pin in ("device", "host"):
         return pin == "device"
     entry = _load_costs().get(_cost_key(s_bucket))
     if entry is None:
-        if jax.devices()[0].platform != "cpu":
-            return False
         entry = calibrate_tier4(s_bucket, quick=True)
     return entry["kernel_mbps"] > entry["host_mbps"]
 
@@ -551,7 +526,7 @@ def nfagrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     chunk_np = _pad_pow2(data)
     n = len(chunk_np)
     block = min(256, n)
-    # Per-RUNG readiness (ADVICE r4) via the shared gated retry
+    # Per-RUNG readiness via the shared gated retry
     # (grepk.retry_line_caps): the escalation rung is a separately
     # compiled shape, and an ungated escalation would cold-compile
     # inside a worker task.  Device uploads happen lazily on the first
@@ -566,8 +541,5 @@ def nfagrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
         return _nfa_compiled(n, s_bucket, block, l_cap)(
             dev["chunk"], dev["table"], dev["v0"])
 
-    line_match, nl = retry_line_caps(
-        n, run, ready=lambda l_cap: _device_ready(n, s_bucket, block, l_cap))
-    if line_match is None:
-        return None  # cold remote compile in-task: host serves this job
+    line_match, nl = retry_line_caps(n, run)
     return lines_from_flags(text, line_match, nl)

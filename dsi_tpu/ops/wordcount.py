@@ -158,6 +158,32 @@ def unpack_key_rows(rows64: jax.Array, k: int) -> jax.Array:
     return jnp.stack(cols, axis=1)
 
 
+def lex_sort(keys: tuple, payloads: tuple = ()) -> tuple:
+    """Stable lexicographic sort of rows by ``keys`` (uint32 columns, most
+    significant first), carrying ``payloads`` (columns of any dtype).
+    Returns ``(*sorted_keys, *sorted_payloads)``.
+
+    Implemented as an LSD radix over the key columns: one stable
+    SINGLE-key ``lax.sort`` per column, least significant first, every
+    pass with the same operand signature (the pass's key first, the other
+    key columns next, the payloads last).  The reason is the TPU
+    compiler, not the run time: XLA:TPU's compile time for a sort grows
+    with the comparator, not the data — measured on a v5e at 262,145
+    rows, a 2xu64-key (or 4xu32-key) sort with one payload compiles in
+    ~100 s, a single-u32-key sort in ~24 s, and four identical
+    single-key passes in ~20 s together (the identical passes compile
+    once), while every variant runs in 2-3 ms.  The same rows in the
+    same order come out either way."""
+    cols = list(keys)
+    pays = tuple(payloads)
+    for j in reversed(range(len(cols))):
+        out = lax.sort((cols[j], *cols[:j], *cols[j + 1:], *pays),
+                       num_keys=1, is_stable=True)
+        cols = [*out[1:j + 1], out[0], *out[j + 1:len(cols)]]
+        pays = tuple(out[len(cols):])
+    return (*cols, *pays)
+
+
 def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     """Group adjacent equal rows of lexicographically sorted key columns.
 
@@ -210,12 +236,12 @@ def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
     input) does ``group_overflow`` make the caller re-run the whole
     chunk through the sort grouper.
 
-    Motivation (measured, BASELINE.md round 5): at 1 MiB/4 tokens the
-    2xu64-key ``lax.sort`` costs ~99 ms on this CPU while the segment-op
-    group + t_cap/8 repair sort costs ~50 ms — the big sort is the
-    kernel's dominant cost and this halves it.  The sort grouper remains
-    the default for accelerator platforms (TPU scatter characteristics
-    differ; switch there only with on-chip evidence).
+    Motivation (measured on XLA:CPU): at 1 MiB/4 tokens the big
+    lexicographic sort costs ~99 ms while the segment-op group + t_cap/8
+    repair sort costs ~50 ms — the big sort is the kernel's dominant
+    cost there and this halves it.  The sort grouper remains the default
+    for accelerator platforms (TPU scatter characteristics differ; the
+    two groupers' run times are not measured on the chip yet).
 
     ``extra``, when given, is a per-token uint32 payload reduced by MIN
     within each group (the corpus kernel's first-occurrence position
@@ -271,22 +297,24 @@ def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
     (dpos,) = jnp.nonzero(in_dirty, size=d_cap, fill_value=0)
     dvalid = jnp.arange(d_cap, dtype=jnp.int32) < n_dirty_tokens
     dlen = jnp.where(dvalid, lengths[dpos], 0)
-    with enable_x64(True):
-        dkeys = tuple(jnp.where(dvalid, kcol[dpos], jnp.uint64(_PAD_KEY64))
-                      for kcol in keys64)
-        if extra is None:
-            sorted_ops = lax.sort(dkeys + (dlen,), num_keys=k64)
-            dsex = None
-        else:
-            dex = jnp.where(dvalid, extra[dpos], jnp.uint32(0xFFFFFFFF))
-            # extra rides as an additional SORT KEY (not a group key):
-            # within a word's run rows order ascending by it, so the
-            # run's first row carries the group minimum.
-            sorted_ops = lax.sort(dkeys + (dex, dlen), num_keys=k64 + 1)
-            dsex = sorted_ops[k64]
-        dgk, dtot, dupos, dovalid, n_du = group_sorted(
-            sorted_ops[:k64], jnp.ones(d_cap, jnp.int32), u_cap)
-        dslens = sorted_ops[-1]
+    dlanes = tuple(jnp.where(dvalid, col[dpos], jnp.uint32(_PAD_KEY))
+                   for col in packed_cols)
+    k = len(dlanes)
+    if extra is None:
+        sorted_ops = lex_sort(dlanes, (dlen,))
+        dsex = None
+    else:
+        dex = jnp.where(dvalid, extra[dpos], jnp.uint32(0xFFFFFFFF))
+        # extra rides as an additional SORT KEY (not a group key):
+        # within a word's run rows order ascending by it, so the
+        # run's first row carries the group minimum.
+        sorted_ops = lex_sort(dlanes + (dex,), (dlen,))
+        dsex = sorted_ops[k]
+    dgk, dtot, dupos, dovalid, n_du = group_sorted(
+        sorted_ops[:k], jnp.ones(d_cap, jnp.int32), u_cap)
+    dslens = sorted_ops[-1]
+    # The repaired uniques' lanes, packed like the clean buckets' keys.
+    dkeys64 = pack_key_lanes(tuple(dgk[dupos, j] for j in range(k)))
 
     # Assemble: clean level-1 buckets first, dirty-repair uniques after.
     clean1 = occ1 & ~dirty
@@ -303,7 +331,7 @@ def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
             # A clean bucket's segment-max IS its one word's lane value.
             col = jnp.where(v1, keys1[j][cpos1], jnp.uint64(0))
             col = col.at[dst2].set(
-                jnp.where(dovalid, dgk[dupos, j], jnp.uint64(0)),
+                jnp.where(dovalid, dkeys64[j], jnp.uint64(0)),
                 mode="drop")
             out_keys.append(col)
     len_u = jnp.where(v1, len1[cpos1], 0)
@@ -330,10 +358,10 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
     token_overflow bool).
 
     ``grouper`` selects how identical tokens are grouped: ``"sort"`` (the
-    default — lexicographic multi-key ``lax.sort``, right for the TPU) or
+    default — lexicographic :func:`lex_sort`) or
     ``"hash"`` (scatter/segment-op bucketing with exact collision
     verification and sort fallback, ~2x faster on the CPU backend where
-    XLA's sort is the measured kernel floor — BASELINE.md round 5).  A
+    XLA's sort is the measured kernel floor).  A
     hash-grouper attempt that cannot prove exactness reports
     ``token_overflow`` so the shared retry ladder re-runs it; the wrapper
     then routes the chunk to the sort grouper.
@@ -391,21 +419,12 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
         return (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
                 token_overflow | group_of)
 
-    # Group identical words: lexicographic sort over the key lanes packed
-    # pairwise into uint64s (pack_key_lanes: same order, half the
-    # comparator keys — the sort is ~3/4 of this kernel's wall on CPU),
-    # then run boundaries; lanes unpack only after compaction to u_cap.
-    with enable_x64(True):  # every op touching u64 operands needs it
-        keys64 = pack_key_lanes(packed_cols)
-        k64 = len(keys64)
-        sorted_ops = lax.sort(keys64 + (lengths,), num_keys=k64)
-        skeys64, totals, upos, ovalid, n_unique = group_sorted(
-            sorted_ops[:k64], jnp.ones(t_cap, jnp.int32), u_cap)
-        slens = sorted_ops[k64]
-
-        packed_u64 = jnp.where(ovalid[:, None], skeys64[upos],
-                               jnp.uint64(0))
-        packed_u = unpack_key_rows(packed_u64, k)
+    # Group identical words: lexicographic sort over the key lanes
+    # (lex_sort: one single-key pass per lane), then run boundaries.
+    *scols, slens = lex_sort(packed_cols, (lengths,))
+    skeys, totals, upos, ovalid, n_unique = group_sorted(
+        tuple(scols), jnp.ones(t_cap, jnp.int32), u_cap)
+    packed_u = jnp.where(ovalid[:, None], skeys[upos], jnp.uint32(0))
     len_u = jnp.where(ovalid, slens[upos], 0)
     fnv_u = fnv1a32_packed(packed_u, len_u, max_word_len)
     has_high = jnp.any(chunk >= 128)
@@ -420,12 +439,10 @@ count_words_kernel = x64_scoped(jax.jit(
 
 def default_grouper() -> str:
     """Platform-adaptive grouping strategy: ``hash`` on the CPU backend
-    (where the multi-key sort is the measured kernel floor — BASELINE.md
-    round 5), ``sort`` on accelerators until on-chip evidence says
-    otherwise.  ``DSI_WC_GROUPER`` pins the choice — and because the warm
-    ladder persists BOTH variants (``warm_groupers`` below, the ``*_hg``
-    AOT entries), pinning ``hash`` on an accelerator is a warm load, not
-    a cold remote compile."""
+    (where the lexicographic sort is the measured kernel floor), ``sort``
+    on accelerators until on-chip evidence says otherwise.
+    ``DSI_WC_GROUPER`` pins the choice; the warm ladder compiles BOTH
+    variants (``warm_groupers`` below, the ``*_hg`` programs)."""
     env = os.environ.get("DSI_WC_GROUPER")
     if env in ("sort", "hash"):
         return env
@@ -433,25 +450,22 @@ def default_grouper() -> str:
 
 
 def grouper_suffix(grouper: str) -> str:
-    """AOT program-name suffix for a grouper variant: the sort grouper
-    keeps its historical bare names (pre-existing cache entries stay
-    valid), the hash grouper gets ``_hg``.  One definition shared by
-    every program namer (``wc_kernel`` here, ``stream_step_*`` in
-    parallel/streaming.py, ``tfidf_wave_*`` in parallel/tfidf.py) so the
-    warm ladder, the persisted probes, and the runs agree on the key by
-    construction."""
+    """Program-name suffix for a grouper variant: the sort grouper
+    keeps the bare names, the hash grouper gets ``_hg``.  One definition
+    shared by every program namer (``wc_kernel`` here, ``stream_step_*``
+    in parallel/streaming.py, ``tfidf_wave_*`` in parallel/tfidf.py) so
+    the warm ladder and the runs agree on the key by construction."""
     if grouper == "sort":
         return ""
     return "_hg" if grouper == "hash" else f"_g{grouper}"
 
 
 def warm_groupers() -> tuple:
-    """The grouper variants the warm AOT ladder compiles+persists for
-    every program family: both rungs, on every platform.  Distinct from
+    """The grouper variants the warm ladder compiles for every program
+    family: both rungs, on every platform.  Distinct from
     :func:`grouper_ladder` (the rungs ONE run walks, platform/env
     dependent): warming only the ladder would leave an env-selected
-    ``DSI_WC_GROUPER=hash`` accelerator run cold exactly where a remote
-    compile costs minutes (VERDICT r5 weak #3)."""
+    ``DSI_WC_GROUPER=hash`` accelerator run cold."""
     return ("hash", "sort")
 
 
@@ -533,9 +547,7 @@ def rung0_cap(shard_len: int, u_cap: int) -> int:
     """exactness_retry's starting capacity: ``u_cap`` bounded by the
     token-count hard cap for this shard length (n//2+1, pow2-rounded to
     keep the jit shape-cache small), floored at 1 (a zero/negative start
-    could never widen: 0 * 4 == 0).  Shared with cache-existence probes
-    (corpus_wc.corpus_executable_persisted) so the key they compute is,
-    by construction, the key a real run compiles first."""
+    could never widen: 0 * 4 == 0)."""
     hard_cap = 1 << (shard_len // 2).bit_length()
     return max(1, min(u_cap, hard_cap))
 
@@ -545,12 +557,15 @@ def exactness_retry(run, shard_len: int, max_word_len: int, u_cap: int):
 
     ``run(mwl, cap)`` executes a kernel attempt and returns
     ``(has_high, n_unique_max, max_len, payload)`` where the first three are
-    host scalars summarising every shard of the attempt.  Retries with
-    ``cap*4`` while uniques overflow (bounded by the token-count hard cap
-    n//2+1, pow2-rounded to keep the jit shape-cache small), then with a
-    64-byte word window if a word overflowed the packed window.  Returns the
-    successful payload, or None when the input needs the host path
-    (non-ASCII bytes, or words longer than 64)."""
+    host scalars summarising every shard of the attempt.  While uniques
+    overflow, retries at the first ``cap*4^k`` rung that holds the count
+    the overflowing attempt reported — the rungs stay the ``x4`` ladder
+    (bounded by the token-count hard cap n//2+1, pow2-rounded to keep the
+    jit shape-cache small), but a rung that is known not to fit is never
+    compiled: on the chip every rung costs a minute or two of XLA:TPU
+    compile.  Then retries with a 64-byte word window if a word overflowed
+    the packed window.  Returns the successful payload, or None when the
+    input needs the host path (non-ASCII bytes, or words longer than 64)."""
     ladder = (max_word_len, 64) if max_word_len < 64 else (max_word_len,)
     for mwl in ladder:
         cap = rung0_cap(shard_len, u_cap)
@@ -559,7 +574,8 @@ def exactness_retry(run, shard_len: int, max_word_len: int, u_cap: int):
             if has_high:
                 return None
             if n_unique_max > cap:
-                cap *= 4
+                while cap < n_unique_max:
+                    cap *= 4
                 continue
             break
         if max_len > mwl:

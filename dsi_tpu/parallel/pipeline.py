@@ -413,7 +413,7 @@ class StepPipeline:
         except BaseException as e:  # surfaced to the consumer thread
             # Stop-aware retry, like the item put above: a fixed timeout
             # could drop the error while the consumer sits in a long
-            # replay (minutes on a tunneled compile), leaving it blocked
+            # replay (a cold compile can take minutes), leaving it blocked
             # forever on a queue that will never produce the sentinel.
             while not stop.is_set():
                 try:

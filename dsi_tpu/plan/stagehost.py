@@ -130,9 +130,9 @@ def main(argv=None) -> int:
     with open(args.spec, "r", encoding="utf-8") as f:
         spec = json.load(f)
 
-    from dsi_tpu.utils.platformpin import pin_platform_from_env
+    from dsi_tpu.utils.platformpin import require_device
 
-    pin_platform_from_env()
+    require_device("stagehost")
 
     from dsi_tpu.net.fetch import (FetchPipeline, fetch_window_from_env)
     from dsi_tpu.net.partsrv import PartitionServer

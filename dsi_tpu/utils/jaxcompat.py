@@ -1,10 +1,8 @@
-"""Version-tolerant aliases for jax APIs that moved between releases.
+"""The scoped-x64 call wrapper, and the two jax names the kernels share.
 
-The kernels target the modern spellings (``jax.shard_map``,
-``jax.enable_x64``); on installs that predate their graduation from
-``jax.experimental`` the experimental originals are re-exported instead.
-One module so every kernel resolves the same implementation — a per-file
-try/except drift here would let two modules disagree mid-upgrade.
+``enable_x64`` and ``shard_map`` are ``jax.enable_x64`` and
+``jax.shard_map`` (jax 0.9); they are re-exported here so every kernel
+module imports them from one place next to :func:`x64_scoped`.
 """
 
 from __future__ import annotations
@@ -13,15 +11,8 @@ import functools
 
 import jax
 
-try:
-    enable_x64 = jax.enable_x64
-except AttributeError:  # pre-graduation jax (e.g. 0.4.x)
-    from jax.experimental import enable_x64  # noqa: F401
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pre-graduation jax (e.g. 0.4.x)
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+enable_x64 = jax.enable_x64
+shard_map = jax.shard_map
 
 
 def x64_scoped(fn):

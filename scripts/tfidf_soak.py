@@ -3,9 +3,8 @@
 
 BASELINE.json's last config is TF-IDF over a 10 GB shard on a v5e-64; this
 host has one core and a virtual mesh, so the honest reachable evidence is a
-measured ~1 GB single-slice run (VERDICT r3 task 4): wall, throughput,
-postings volume, and peak RSS, from which the 10 GB config's cost model is
-extrapolated in BASELINE.md (device work repeats per slice; host memory
+measured ~1 GB single-slice run: wall, throughput,
+postings volume, and peak RSS (device work repeats per slice; host memory
 divides by the slice count — parallel/tfidf.py module docs).
 
 Verification at this scale: full oracle parity would cost more than the

@@ -5,8 +5,7 @@ One host pass over the corpus counts ASCII-letter tokens and compares
 against the sum of counts in the run's ``mr-out-*`` files — a cheap gross
 miscount detector at sizes where full per-word parity is impractical
 (per-word parity is covered at test scale by ``wcstream --check`` and the
-differential suite).  Shared by scripts/warm_loop.sh step C4 and
-scripts/onchip_evidence.sh so both collectors compute the SAME invariant.
+differential suite).
 
 Usage: python scripts/token_invariant.py <corpus_dir> <workdir>
 Prints ``token-count invariant: corpus=N mr-out=M match=True|False``;

@@ -27,7 +27,7 @@ def test_wc_empty_and_punct_only():
 def test_wc_tokenizer_go_isletter_unicode_parity():
     # Go's unicode.IsLetter is category L ONLY: Ⅳ (Nl, Roman numeral) and
     # ² (No) are separators, while ª (Lo) and µ (Ll) are letters.  A \w-based
-    # regex gets these wrong (VERDICT r1 weakness #3: 'bⅣcªd' must be two
+    # regex gets these wrong ('bⅣcªd' must be two
     # words, not one).
     assert [kv.key for kv in wc.Map("f", "bⅣcªd")] == ["b", "cªd"]
     assert [kv.key for kv in wc.Map("f", "x²y µz 漢字")] == \

@@ -125,49 +125,32 @@ def test_grouper_suffix_convention():
     assert set(warm_groupers()) == {"sort", "hash"}
 
 
-def test_hash_grouper_warm_ladder_persists_hg_entries(tmp_path):
-    """The warm ladder must persist BOTH grouper variants (`*_hg`
-    alongside the bare sort names) and the persisted probes must see
-    them under an env-pinned hash run — the promotion VERDICT r5 weak #3
-    asks for.  Single-device subprocess: persistence is disabled on the
-    8-device test mesh by design."""
-    import os
-    import subprocess
-    import sys
+def test_hash_grouper_warm_ladder_compiles_hg_programs(monkeypatch):
+    """The warm ladder must compile BOTH grouper variants (`*_hg`
+    alongside the bare sort names), so an env-pinned hash run after it
+    compiles nothing."""
+    from dsi_tpu.backends import aotcache
+    from dsi_tpu.parallel.shuffle import default_mesh
+    from dsi_tpu.parallel.streaming import (warm_stream_aot,
+                                            wordcount_streaming)
 
-    child = (
-        "import os\n"
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "from dsi_tpu.parallel.streaming import (\n"
-        "    kernel_row_persisted, stream_programs_persisted,\n"
-        "    warm_kernel_row, warm_stream_aot)\n"
-        "from dsi_tpu.backends.aotcache import cache_dir\n"
-        "kw = dict(chunk_bytes=1 << 14, u_cap=1 << 10)\n"
-        "warm_stream_aot(chunk_bytes=1 << 14, caps=(1 << 10,))\n"
-        "warm_kernel_row(**kw)\n"
-        "names = os.listdir(cache_dir())\n"
-        "assert any('_hg' in n and n.startswith('stream_step_') "
-        "for n in names), names\n"
-        "assert kernel_row_persisted(**kw)\n"
-        "# An env-pinned hash run walks the ('hash','sort') ladder — the\n"
-        "# stricter probe must pass from the same warm pass.\n"
-        "os.environ['DSI_WC_GROUPER'] = 'hash'\n"
-        "assert stream_programs_persisted(**kw)\n"
-        "print('hg-ok')\n"
-    )
-    env = dict(os.environ)
-    env["DSI_AOT_CACHE_DIR"] = str(tmp_path / "aot")
-    env["DSI_AOT_QUIET"] = "1"
-    env.pop("XLA_FLAGS", None)  # single-device process, like the chip
-    env["JAX_PLATFORMS"] = "cpu"
-    p = subprocess.run([sys.executable, "-c", child], env=env,
-                       capture_output=True, text=True, timeout=300)
-    assert p.returncode == 0, p.stderr[-2000:]
-    assert p.stdout.strip().splitlines()[-1] == "hg-ok"
+    mesh = default_mesh(1)
+    warm_stream_aot(mesh=mesh, chunk_bytes=1 << 14, caps=(1 << 10,))
+    names = {key[0] for key in aotcache._memo}
+    steps = {n for n in names if n.startswith("stream_step_d1_")
+             and "_u1024_" in n}
+    assert any(n.endswith("_hg") for n in steps), steps
+    assert any(not n.endswith("_hg") for n in steps), steps
+    monkeypatch.setenv("DSI_WC_GROUPER", "hash")
+    before = aotcache.stats["compiles"]
+    text = ("warm ladder hash grouper " * 500).encode()
+    assert wordcount_streaming([text], mesh=mesh, n_reduce=10,
+                               chunk_bytes=1 << 14, u_cap=1 << 10,
+                               aot=True) is not None
+    assert aotcache.stats["compiles"] == before
 
 
-# ── block-level Unicode fallback (round 5, VERDICT r4 weakness #5) ─────
+# ── block-level Unicode fallback ──────────────────────────────────────
 
 
 def _host_counts(raw: bytes):
@@ -217,14 +200,12 @@ def test_unicode_mostly_nonascii_routes_whole_split_to_host():
 
 
 def test_unicode_single_byte_costs_under_ten_percent():
-    """The VERDICT r4 target: a split with ONE non-ASCII byte loses
+    """The target: a split with ONE non-ASCII byte loses
     < 10% of device throughput.  The functional half (both splits
     produce device results) always asserts; the WALL-CLOCK half is
     opt-in via ``DSI_TIMING_ASSERTS=1`` — timing contention on a busy
-    1-core tier-1 box flaked the default gate (ADVICE r5 item 3), and a
-    load-dependent ratio must not fail a correctness suite.  The typical
-    measured ratio (warm kernels, quiet box) is recorded in
-    BASELINE.md."""
+    1-core tier-1 box flaked the default gate, and a
+    load-dependent ratio must not fail a correctness suite."""
     import os
     import time
 

@@ -818,7 +818,6 @@ def _cli_env(tmp_path):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    env.setdefault("DSI_AOT_CACHE_DIR", str(tmp_path / "aot"))
     env["PYTHONPATH"] = REPO + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     return env

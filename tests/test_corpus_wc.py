@@ -22,10 +22,8 @@ PIECE = 1 << 12  # small static shapes for CPU test speed
 
 
 @pytest.fixture(autouse=True)
-def _aot_tmp(tmp_path, monkeypatch):
-    # Exercise the AOT cache machinery without littering the repo cache.
-    monkeypatch.setenv("DSI_AOT_CACHE_DIR", str(tmp_path / "aot"))
-    monkeypatch.setenv("DSI_AOT_QUIET", "1")
+def _quiet_compiles(monkeypatch):
+    monkeypatch.setenv("DSI_COMPILE_QUIET", "1")
 
 
 def counts_of(res: CorpusResult) -> dict:
@@ -211,98 +209,23 @@ def test_aot_cache_roundtrip_same_result():
     r1 = corpus_wordcount([text], piece_size=PIECE)
     before = dict(aotcache.stats)
     # Force the next call past BOTH in-process layers (the dispatch
-    # lru_cache and the aotcache memo) so it exercises disk-or-compile.
+    # lru_cache and the aotcache memo) so it compiles again.
     corpus_mod._get_compiled.cache_clear()
     aotcache._memo.clear()
     r2 = corpus_wordcount([text], piece_size=PIECE)
     assert counts_of(r1) == counts_of(r2)
-    if aotcache.stats["loads"] == before["loads"]:
-        # Multi-device process (this suite's virtual mesh) or a backend
-        # without serialization: the compile path must have served it.
-        assert aotcache.stats["compiles"] > before["compiles"]
+    # Nothing in process held the program any more: it was compiled
+    # again (tier-1 runs without a persistent cache) and counted.
+    assert aotcache.stats["compiles"] > before["compiles"]
 
 
-def test_aot_cache_hits_across_processes(tmp_path):
-    """The chip configuration (ONE device per process): a second process
-    must load the serialized executable instead of recompiling — VERDICT r2
-    task 1a's cross-process criterion, exercised on CPU."""
-    import subprocess
-    import sys
-
-    child = (
-        "import os, jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "from dsi_tpu.ops.corpus_wc import corpus_wordcount\n"
-        "from dsi_tpu.backends import aotcache\n"
-        "res = corpus_wordcount([b'tiny corpus of words tiny'],"
-        " piece_size=4096)\n"
-        "assert {w: c for w, (c, _) in res.to_dict().items()} =="
-        " {'tiny': 2, 'corpus': 1, 'of': 1, 'words': 1}\n"
-        "print('loads=%d compiles=%d' % (aotcache.stats['loads'],"
-        " aotcache.stats['compiles']))\n"
-    )
-    env = dict(os.environ)
-    env["DSI_AOT_CACHE_DIR"] = str(tmp_path / "aot")
-    env["DSI_AOT_QUIET"] = "1"
-    env.pop("XLA_FLAGS", None)  # single-device process, like the chip
-    env["JAX_PLATFORMS"] = "cpu"
-    outs = []
-    for _ in range(2):
-        p = subprocess.run([sys.executable, "-c", child], env=env,
-                           capture_output=True, text=True, timeout=120)
-        assert p.returncode == 0, p.stderr[-2000:]
-        outs.append(p.stdout.strip().splitlines()[-1])
-    assert outs[0] == "loads=0 compiles=1"
-    assert outs[1] == "loads=1 compiles=0"
-
-
-def test_executable_persisted_probe_mirrors_run_shapes(tmp_path):
-    """corpus_executable_persisted must hit the exact key a real run
-    persists — including exactness_retry's rung-0 capacity, which caps
-    u_cap by the buffer-length hard bound (a drifted mirror silently
-    reports False forever and the bench would skip a warmed pack6
-    transport / never trust its own cache).  Single-device subprocess:
-    persistence is disabled on the 8-device test mesh by design."""
-    import subprocess
-    import sys
-
-    child = (
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "from dsi_tpu.ops.corpus_wc import (corpus_executable_persisted,\n"
-        "                                   corpus_wordcount)\n"
-        "raws = [b'the quick brown fox ' * 500,\n"
-        "        b'jumps over the lazy dog ' * 400]\n"
-        "assert not corpus_executable_persisted(raws)\n"
-        "assert not corpus_executable_persisted(raws, pack6=True)\n"
-        "corpus_wordcount(raws)\n"
-        "corpus_wordcount(raws, pack6=True)\n"
-        "assert corpus_executable_persisted(raws)\n"
-        "assert corpus_executable_persisted(raws, pack6=True)\n"
-        "assert not corpus_executable_persisted([b'word ' * 99999])\n"
-        "print('probe-ok')\n"
-    )
-    env = dict(os.environ)
-    env["DSI_AOT_CACHE_DIR"] = str(tmp_path / "aot")
-    env["DSI_AOT_QUIET"] = "1"
-    env.pop("XLA_FLAGS", None)  # single-device process, like the chip
-    env["JAX_PLATFORMS"] = "cpu"
-    p = subprocess.run([sys.executable, "-c", child], env=env,
-                       capture_output=True, text=True, timeout=300)
-    assert p.returncode == 0, p.stderr[-2000:]
-    assert p.stdout.strip().splitlines()[-1] == "probe-ok"
-
-
-@pytest.mark.parametrize("mode", ["async", "sync"])
-def test_upload_modes_identical(mode, monkeypatch):
-    """DSI_UPLOAD_MODE selects transfer geometry only — results must be
-    byte-identical either way, and xfer telemetry must record the run."""
+def test_upload_wall_is_accounted():
+    """The piece upload goes through ops/xfer.put_views, whose wall time
+    is the bench's upload_s phase."""
     from dsi_tpu.ops import xfer
 
-    monkeypatch.setenv("DSI_UPLOAD_MODE", mode)
     xfer.stats["upload_s"] = 0.0
-    texts = ["upload mode parity check one two two three three three"]
+    texts = ["upload accounting check one two two three three three"]
     res = corpus_wordcount([t.encode() for t in texts], piece_size=PIECE)
     assert counts_of(res) == oracle(texts)
-    assert xfer.stats["upload_mode"] == mode
     assert xfer.stats["upload_s"] > 0.0

@@ -274,7 +274,6 @@ def test_stream_device_accumulate_aot_warm_covers_everything(tmp_path,
     from dsi_tpu.backends import aotcache
     from dsi_tpu.parallel.streaming import warm_stream_aot
 
-    monkeypatch.setenv("DSI_AOT_CACHE_DIR", str(tmp_path / "aot"))
     mesh = default_mesh(1)
     warm_stream_aot(mesh=mesh, chunk_bytes=1 << 14, caps=(1 << 10,),
                     device_accumulate=True)

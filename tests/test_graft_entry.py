@@ -3,7 +3,7 @@
 The driver runs ``entry()`` (single-device compile check) and
 ``dryrun_multichip(n)`` (virtual 8-device mesh) and records stdout as the
 round's MULTICHIP evidence artifact — rc=0 with an empty tail proved
-nothing (ADVICE r2), so the dryrun must print self-evidencing parity lines.
+nothing, so the dryrun must print self-evidencing parity lines.
 """
 
 import sys

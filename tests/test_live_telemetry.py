@@ -491,8 +491,16 @@ def test_bench_diff_gates_serve_latency_row(tmp_path):
     assert re.search(r"serve_lat_parity.*ok", p.stdout)
 
 
-def test_bench_diff_passes_on_real_r04_r05_pair():
-    p = run_diff(os.path.join(REPO, "BENCH_r04.json"),
-                 os.path.join(REPO, "BENCH_r05.json"))
+def test_bench_diff_passes_on_a_steady_pair(tmp_path):
+    """Two explicit record paths in the driver's wrapper format, every
+    metric inside its band: PASS."""
+    old = {"metric": "wc_tpu_throughput", "value": 12.0,
+           "median_mbps": 11.5, "stream_mbps": 8.0, "stream_parity": True,
+           "ckpt_overhead_pct": 12.0}
+    new = dict(old, value=12.3, stream_mbps=7.8, ckpt_overhead_pct=13.0)
+    a, b = tmp_path / "old.json", tmp_path / "new.json"
+    a.write_text(json.dumps({"n": 1, "rc": 0, "parsed": old}))
+    b.write_text(json.dumps({"n": 2, "rc": 0, "parsed": new}))
+    p = run_diff(str(a), str(b))
     assert p.returncode == 0, p.stdout + p.stderr
     assert "PASS" in p.stdout

@@ -233,7 +233,6 @@ def test_trace_survives_real_process_death(tmp_path):
     env.update({"JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
                 "DSI_FAULT_POINT": "mid-fold", "DSI_FAULT_STEP": "3"})
-    env.setdefault("DSI_AOT_CACHE_DIR", str(tmp_path / "aot"))
     env["PYTHONPATH"] = REPO + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     trace_dir = tmp_path / "trace"

@@ -95,7 +95,7 @@ def test_tpu_map_emits_per_line_records():
 
 def test_line_count_mismatch_falls_back(monkeypatch):
     # A host/device line-count disagreement must return None (host regex
-    # path), not crash the worker task mid-job (VERDICT r2 weakness #5).
+    # path), not crash the worker task mid-job.
     import dsi_tpu.ops.grepk as grepk
 
     import dsi_tpu.ops.regexk as regexk
@@ -159,24 +159,20 @@ def test_control_byte_pattern_rejected():
     assert grep_host_result(b"abc\x00x\ndef", "\x00") is None
 
 
-def test_rung_gate_covers_all_tiers(monkeypatch):
-    """Round-5 review: every grep tier must refuse a rung whose compiled
-    shape is not persisted on an accelerator (host fallback), including
-    the n+1 overflow escalation."""
+def test_cold_shape_compiles_and_runs_on_every_tier():
+    """No tier refuses a shape because its program has not been compiled
+    yet: a cold program compiles (counted in aotcache.stats) and the
+    device path answers."""
     import dsi_tpu.ops.altk as altk
     import dsi_tpu.ops.grepk as grepk
     import dsi_tpu.ops.regexk as regexk
+    from dsi_tpu.backends import aotcache
 
-    class _FakeDev:
-        platform = "tpu"
-
-    monkeypatch.setattr(grepk.jax, "devices", lambda: [_FakeDev()])
-    monkeypatch.setattr("dsi_tpu.backends.aotcache.is_persisted",
-                        lambda *a, **k: False)
-    data = b"the quick fox\nplain line\n" * 8
-    assert grepk.grep_host_result(data, "fox") is None
-    assert regexk.classgrep_host_result(data, "[Tt]he") is None
-    assert altk.altgrep_host_result(data, "fox|[Tt]he") is None
-    # Warm-script bypass keeps compiles possible where they are the job.
-    monkeypatch.setenv("DSI_GREP_COLD_OK", "1")
+    # A size no other test uses, so every tier's shape is new here.
+    data = b"the quick fox\nplain line\n" * 8 + b"x" * 3000
+    before = aotcache.stats["compiles"]
     assert grepk.grep_host_result(data, "fox") is not None
+    assert regexk.classgrep_host_result(data, "[Tt]he") is not None
+    assert altk.altgrep_host_result(data, "fox|[Tt]he") is not None
+    assert aotcache.stats["compiles"] >= before + 2
+

@@ -13,6 +13,7 @@ import json
 import os
 import re
 
+import numpy as np
 import pytest
 
 hypothesis = pytest.importorskip("hypothesis")
@@ -543,8 +544,6 @@ def test_fuzz_mesh_routing_partitions_exactly(words, n_shards):
     union is the input — and matches the host ihash oracle from
     mr/worker.py byte-for-byte."""
     import functools
-
-    import numpy as np
 
     from dsi_tpu.ops.meshroute import pack_host_rows, route_dest
 

@@ -6,7 +6,7 @@ full job repeatedly to amplify flakes (``main/test-mr.sh:10,19-22``,
 high-contention soak: many workers x tiny tasks x a task timeout on the
 order of task duration, repeated, with output parity asserted every trial —
 the duplicate-execution, requeue-vs-complete, and dial-under-load races all
-fire here if they exist (VERDICT r1 items 2 and 9).
+fire here if they exist.
 """
 
 from __future__ import annotations

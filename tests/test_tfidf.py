@@ -94,7 +94,7 @@ def test_wave_planning_tracks_per_wave_longest():
 
 
 def test_outlier_document_compiles_few_shapes(tmp_path, monkeypatch):
-    """VERDICT r2 task 5: one 10x outlier doc must not inflate every wave's
+    """One 10x outlier doc must not inflate every wave's
     buffers — <= 3 compiled shapes, and parity with the oracle holds."""
     import dsi_tpu.parallel.tfidf as m
     from dsi_tpu.parallel.shuffle import default_mesh
@@ -174,7 +174,7 @@ def test_spmd_falls_back_on_non_ascii(tmp_path):
 
 def test_packed_and_lazy_docs_match_dict(tmp_path):
     """FileDocs + packed=True must agree with resident docs + dict result
-    (the GB-soak memory path, VERDICT r4 weakness #4)."""
+    (the GB-soak memory path)."""
     import numpy as np
 
     from dsi_tpu.parallel.shuffle import default_mesh

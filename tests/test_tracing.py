@@ -1,6 +1,6 @@
 """The DSI_TRACE structured-event layer (utils/tracing.py).
 
-VERDICT r2 weakness #2 / task 6: the worker's task bodies must emit a
+The worker's task bodies must emit a
 per-task timeline under DSI_TRACE=1, and the tracing module must carry no
 dead code.  The reference has no tracing at all (SURVEY.md §5) — this layer
 is additive observability; these tests pin its contract.
@@ -76,7 +76,7 @@ def test_worker_tasks_emit_timeline(monkeypatch, capsys, tmp_path):
 
 
 def test_no_dead_tracing_api():
-    # PhaseTimer / maybe_jax_profile were dead code (VERDICT r2): they must
+    # PhaseTimer / maybe_jax_profile were dead code: they must
     # stay deleted rather than unreferenced.
     import dsi_tpu.utils.tracing as t
 
