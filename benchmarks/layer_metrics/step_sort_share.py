@@ -1,0 +1,7 @@
+"""Percent of the stream's device busy time in sort ops."""
+
+from layer_metrics._common import category_share
+
+
+def read(obs):
+    return category_share(obs, "sort")
