@@ -162,9 +162,9 @@ def run_oracle(files) -> tuple[float, float]:
     """Sequential oracle (mrsequential.go:38-86 semantics); pure host CPU."""
     from dsi_tpu.apps import wc
     from dsi_tpu.mr.sequential import run_sequential
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
-    with Span("bench.oracle") as pt:
+    with span("task", stats={}, phase="bench.oracle") as pt:
         run_sequential(wc.Map, wc.Reduce, files, ORACLE_OUT)
     dt = pt.elapsed_s
     total_mb = sum(os.path.getsize(p) for p in files) / 1e6
@@ -181,7 +181,7 @@ def tpu_child(result_path: str) -> int:
     from dsi_tpu.backends import aotcache
     from dsi_tpu.ops.corpus_wc import corpus_wordcount, write_corpus_output
     from dsi_tpu.utils.corpus import ensure_corpus
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     def emit(obj: dict) -> None:
         # Per-thread temp name: the init-watchdog thread and the main
@@ -305,7 +305,7 @@ def tpu_child(result_path: str) -> int:
     # "auto" compiles and probes both.
     transport = os.environ.get("DSI_BENCH_TRANSPORT", "auto")
     pack6_eligible = transport != "raw"
-    with Span("bench.warmup") as pt:
+    with span("task", stats={}, phase="bench.warmup") as pt:
         for pack6 in ((False, True) if pack6_eligible else (False,)):
             wres, _ = run_once(pack6)
             if wres is None:
@@ -510,7 +510,7 @@ def run_stream_row(files, stream_mb: float) -> dict:
     # accumulator (device/table.py): folds on device, host pulls every
     # DSI_STREAM_SYNC_EVERY steps.
     device_acc = os.environ.get("DSI_BENCH_STREAM_DEVICE_ACC") == "1"
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     corpus_bytes = sum(os.path.getsize(p) for p in files)
     cycles = max(1, round(stream_mb * 1e6 / corpus_bytes))
@@ -525,7 +525,7 @@ def run_stream_row(files, stream_mb: float) -> dict:
     pstats: dict = {}
     tracer = bench_tracer()
     mark = tracer.mark()
-    with Span("bench.stream") as pt:
+    with span("task", stats={}, phase="bench.stream") as pt:
         acc = wordcount_streaming(blocks(), mesh=mesh, n_reduce=N_REDUCE,
                                   chunk_bytes=STREAM_CHUNK_BYTES,
                                   u_cap=STREAM_U_CAP, aot=True,
@@ -627,7 +627,7 @@ def run_stream_ckpt_row(files, mesh, device_acc, oracle,
 
     from dsi_tpu.parallel.streaming import (stream_files,
                                             wordcount_streaming)
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     ckpt_dir = os.path.join(WORKDIR, "ckpt-row")
     async_dir = os.path.join(WORKDIR, "ckpt-row-async")
@@ -643,7 +643,7 @@ def run_stream_ckpt_row(files, mesh, device_acc, oracle,
 
     def run(**kw):
         pstats: dict = {}
-        with Span("bench.stream_ckpt") as pt:
+        with span("task", stats={}, phase="bench.stream_ckpt") as pt:
             acc = wordcount_streaming(
                 blocks(), mesh=mesh, n_reduce=N_REDUCE,
                 chunk_bytes=STREAM_CHUNK_BYTES, u_cap=STREAM_U_CAP,
@@ -794,7 +794,7 @@ def run_tfidf_row(files) -> dict:
                                  "(set DSI_BENCH_TFIDF_MB)"}
     from dsi_tpu.parallel.shuffle import default_mesh
     from dsi_tpu.parallel.tfidf import FileDocs, tfidf_sharded
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     corpus_bytes = sum(os.path.getsize(p) for p in files)
     cycles = max(1, round(mb * 1e6 / corpus_bytes))
@@ -806,7 +806,7 @@ def run_tfidf_row(files) -> dict:
     phases: dict = {}
     tracer = bench_tracer()
     mark = tracer.mark()
-    with Span("bench.tfidf") as pt:
+    with span("task", stats={}, phase="bench.tfidf") as pt:
         res = tfidf_sharded(docs, mesh=default_mesh(), n_reduce=N_REDUCE,
                             u_cap=STREAM_U_CAP, packed=True,
                             wave_stats=phases)
@@ -873,7 +873,7 @@ def run_grep_row(files) -> dict:
                                              grep_streaming)
     from dsi_tpu.parallel.shuffle import default_mesh
     from dsi_tpu.parallel.streaming import stream_files
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     device_acc = os.environ.get("DSI_BENCH_GREP_DEVICE_ACC") == "1"
     single = len(jax.devices()) == 1
@@ -888,7 +888,7 @@ def run_grep_row(files) -> dict:
             yield from stream_files(files)
 
     # The oracle first: parity ground truth AND the host baseline rate.
-    with Span("bench.grep_oracle") as pt:
+    with span("task", stats={}, phase="bench.grep_oracle") as pt:
         want = grep_host_oracle(blocks(), pattern)
     oracle_s = pt.elapsed_s
     total_mb = corpus_bytes * cycles / 1e6
@@ -897,7 +897,7 @@ def run_grep_row(files) -> dict:
     pstats: dict = {}
     tracer = bench_tracer()
     mark = tracer.mark()
-    with Span("bench.grep") as pt:
+    with span("task", stats={}, phase="bench.grep") as pt:
         res = grep_streaming(blocks(), pattern, mesh=mesh,
                              chunk_bytes=GREP_CHUNK_BYTES, aot=aot,
                              device_accumulate=device_acc,
@@ -977,7 +977,7 @@ def run_wire_ingest_row(files) -> dict:
     from dsi_tpu.parallel.streaming import (batch_stream, stream_files,
                                             wordcount_streaming)
     from dsi_tpu.utils.ioread import ParallelBlocks
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     mesh = default_mesh()
     n_dev = mesh.devices.size
@@ -994,7 +994,7 @@ def run_wire_ingest_row(files) -> dict:
     nus = scal_np[:, 0].astype(np.int64)
     mp = occupied_prefix(int(nus.max()), keys.shape[1])
     packed = np.asarray(_slice_pack(keys, lens, cnts, parts, mp=mp))
-    with Span("bench.wire_pack") as pt:
+    with span("task", stats={}, phase="bench.wire_pack") as pt:
         blob = wirecodec.pack_rows(packed, nus)
     rows2, nus2 = wirecodec.unpack_rows(blob)
     wire_parity = (np.array_equal(nus2, nus)
@@ -1023,7 +1023,7 @@ def run_wire_ingest_row(files) -> dict:
 
     def run(source, **kw):
         pstats: dict = {}
-        with Span("bench.wire_ab") as pt:
+        with span("task", stats={}, phase="bench.wire_ab") as pt:
             acc = wordcount_streaming(
                 source, mesh=mesh, n_reduce=N_REDUCE,
                 chunk_bytes=STREAM_CHUNK_BYTES, u_cap=STREAM_U_CAP,
@@ -1116,7 +1116,7 @@ def run_framework_row(bench_oracle_mbps: float) -> dict:
     from dsi_tpu.apps import wc
     from dsi_tpu.mr.sequential import run_sequential
     from dsi_tpu.utils.corpus import ensure_corpus
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     budget = env_float("DSI_BENCH_FRAMEWORK_TIMEOUT", 300.0)
     # Never trade the verdict for the row: the row runs BEFORE the one
@@ -1149,7 +1149,7 @@ def run_framework_row(bench_oracle_mbps: float) -> dict:
     # Oracle at THIS scale: the parity ground truth and the same-corpus
     # baseline the speedup is computed against.
     oracle_out = os.path.join(fw_dir, "mr-correct.txt")
-    with Span("bench.fw_oracle") as pt:
+    with span("task", stats={}, phase="bench.fw_oracle") as pt:
         run_sequential(wc.Map, wc.Reduce, files, oracle_out)
     fw_oracle_mbps = total_mb / pt.elapsed_s
 
@@ -2263,13 +2263,13 @@ def run_native_oracle_row(files, oracle_out, total_mb, native_ok,
     import shutil
 
     from dsi_tpu import native
-    from dsi_tpu.utils.tracing import Span
+    from dsi_tpu.obs import span
 
     ndir = os.path.join(os.path.dirname(oracle_out), "native-seq")
     shutil.rmtree(ndir, ignore_errors=True)
     os.makedirs(ndir)
     out_blobs = []
-    with Span("bench.native_oracle") as pt:
+    with span("task", stats={}, phase="bench.native_oracle") as pt:
         for m, p in enumerate(files):
             blobs = native.wc_map_file(p, N_REDUCE)
             if blobs is None:

@@ -12,12 +12,10 @@ nothing enforced:
   (``obs.trace.SPAN_NAMES`` / ``obs.trace.LANES``) — an off-schema
   name falls out of every rollup, tracecat table, and histogram.
 
-Checked: calls to ``span``/``_span`` (the engines' import alias),
-``<x>.span(...)`` on a tracer, and ``record_span`` name/lane literals.
-Non-literal names are skipped (the ``utils/tracing`` mirror path
-forwards variables by design).  ``obs/trace.py`` and
-``obs/__init__.py`` — the definition sites whose helpers *return*
-spans — are exempt.
+Checked: calls to ``span``/``_span`` (the engines' import alias) and
+``<x>.span(...)`` on a tracer; non-literal names are skipped.
+``obs/trace.py`` and ``obs/__init__.py`` — the definition sites whose
+helpers *return* spans — are exempt.
 """
 
 from __future__ import annotations
@@ -59,13 +57,9 @@ class SpanDisciplineRule(Rule):
         for node in ast.walk(module.tree):
             if not isinstance(node, ast.Call):
                 continue
-            name = dotted(node.func)
-            is_span = _is_span_call(node)
-            is_record = (name == "record_span"
-                         or name.endswith(".record_span"))
-            if not is_span and not is_record:
+            if not _is_span_call(node):
                 continue
-            if is_span and id(node) not in with_exprs:
+            if id(node) not in with_exprs:
                 yield Finding(
                     module.rel, node.lineno, node.col_offset,
                     self.rule_id,

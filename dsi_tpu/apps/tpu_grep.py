@@ -21,6 +21,7 @@ from typing import List, Optional
 
 from dsi_tpu.apps.grep import Map, Reduce  # noqa: F401  (host fallback)
 from dsi_tpu.mr.types import KeyValue
+from dsi_tpu.obs import span as _span
 
 #: C++ task bodies (native/wcjob.cpp via backends/native.py, literal
 #: patterns only — regex declines to the host re path).
@@ -43,4 +44,5 @@ def tpu_map(filename: str, raw: bytes) -> Optional[List[KeyValue]]:
         lines = nfagrep_host_result(raw, pattern)
     if lines is None:
         return None
-    return [KeyValue(line, "") for line in lines]
+    with _span("decode", lane="host", records=len(lines)):
+        return [KeyValue(line, "") for line in lines]

@@ -22,6 +22,7 @@ from typing import List, Optional
 
 from dsi_tpu.apps.wc import tokenize
 from dsi_tpu.mr.types import KeyValue
+from dsi_tpu.obs import span as _span
 
 #: The C++ job kernels (native/wcjob.cpp via backends/native.py) implement
 #: exactly this app's combiner semantics — Map emits per-unique counts,
@@ -92,17 +93,19 @@ def tpu_map(filename: str, raw: bytes) -> Optional[List[KeyValue]]:
     smart-quote costs the affected runs, not the split."""
     from dsi_tpu.ops.wordcount import count_words_host_result
 
-    parts = split_unicode_runs(raw)
+    with _span("decode", lane="host", bytes=len(raw)):  # the non-ASCII scan
+        parts = split_unicode_runs(raw)
     if parts is None:
         return None
     clean, dirty_pieces = parts
     res = count_words_host_result(clean)
     if res is None:
         return None
-    counts = Counter()
-    for w, (c, _) in res.items():
-        counts[w] = c
-    if dirty_pieces:
-        counts.update(tokenize(
-            b" ".join(dirty_pieces).decode("utf-8", errors="replace")))
-    return [KeyValue(w, str(c)) for w, c in sorted(counts.items())]
+    with _span("decode", lane="host", records=len(res)):
+        counts = Counter()
+        for w, (c, _) in res.items():
+            counts[w] = c
+        if dirty_pieces:
+            counts.update(tokenize(
+                b" ".join(dirty_pieces).decode("utf-8", errors="replace")))
+        return [KeyValue(w, str(c)) for w, c in sorted(counts.items())]

@@ -38,7 +38,9 @@ class TpuTaskRunner:
         self.tpu_reduce = getattr(app_module, "tpu_reduce", None)
         self.device_maps = 0
         self.host_maps = 0
-        self.platform = ""
+        #: What JAX reported when the backend came up (``for_app``):
+        #: platform, kind, count.
+        self.device: dict = {}
         if self.tpu_map is None and self.tpu_reduce is None:
             import sys
 
@@ -54,13 +56,14 @@ class TpuTaskRunner:
 
         devices = require_device("mrworker --backend tpu")
         runner = cls(load_plugin_module(name_or_path))
-        runner.platform = devices[0].platform
+        runner.device = {"platform": devices[0].platform,
+                         "kind": devices[0].device_kind,
+                         "count": len(devices)}
         return runner
 
     def run_map(self, mapf, filename: str, map_task: int, n_reduce: int,
                 workdir: str = ".") -> None:
-        with open(filename, "rb") as f:
-            raw = f.read()
+        raw = w.read_split(filename)
         kva = self.tpu_map(filename, raw) if self.tpu_map else None
         if kva is None:  # host fallback (worker.go:55-92 semantics)
             self.host_maps += 1
@@ -78,6 +81,6 @@ class TpuTaskRunner:
 
     def report(self) -> str:
         """The line the worker prints at exit."""
-        return (f"backend=tpu platform={self.platform} "
+        return (f"backend=tpu platform={self.device.get('platform', '')} "
                 f"device_maps={self.device_maps} "
                 f"host_maps={self.host_maps}")

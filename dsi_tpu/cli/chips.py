@@ -21,6 +21,7 @@ import subprocess
 import sys
 from typing import Dict, Optional
 
+from dsi_tpu.obs import span as _span
 from dsi_tpu.utils.platformpin import cpu_requested
 
 _PROBE = ("import json, jax; d = jax.devices(); "
@@ -83,9 +84,12 @@ def plan_device_workers(n_workers: int, env: Dict[str, str], who: str,
     the chips are counted (``chips`` overrides the probe — the tests'
     hook) and a count of zero raises ``SystemExit`` naming the missing
     chip."""
-    if cpu_requested(env):
-        return [0] * n_workers, 0
-    n_chips = probe_chip_count(env) if chips is None else chips
+    with _span("probe", lane="launch") as sp:
+        if cpu_requested(env):
+            sp.set(chips=0)
+            return [0] * n_workers, 0
+        n_chips = probe_chip_count(env) if chips is None else chips
+        sp.set(chips=n_chips)
     if n_chips <= 0:
         raise SystemExit(
             f"{who}: no TPU: a probe process found no chip (missing, or "

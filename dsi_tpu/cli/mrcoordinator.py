@@ -15,9 +15,11 @@ import time
 
 from dsi_tpu.config import JobConfig
 from dsi_tpu.mr.coordinator import make_coordinator
+from dsi_tpu.obs import get_tracer
 
 
 def main(argv=None) -> int:
+    get_tracer()  # its epoch precedes the first assign
     p = argparse.ArgumentParser()
     p.add_argument("--nreduce", type=int, default=10)  # mrcoordinator.go:23
     p.add_argument("--task-timeout", type=float, default=10.0)

@@ -31,6 +31,8 @@ import subprocess
 import sys
 import time
 
+from dsi_tpu.obs import configure_tracing, flush_tracing, trace_event
+
 
 def _worker_fleet(args, app: str, env: dict):
     """Per-slot ``(cmd, env)`` for the job's workers.  Host and native
@@ -56,6 +58,14 @@ def _worker_fleet(args, app: str, env: dict):
             fleet.append((base + ["tpu", app],
                           chip_env(env, chip, n_chips)))
     return fleet
+
+
+def _spawn_worker(cmd: list, env: dict, workdir: str):
+    """Start one worker of the fleet; its role is its ``--backend``."""
+    proc = subprocess.Popen(cmd, env=env, cwd=workdir)
+    trace_event("spawn", lane="launch", pid=proc.pid,
+                role="worker:" + cmd[cmd.index("--backend") + 1])
+    return proc
 
 
 def main(argv=None) -> int:
@@ -144,13 +154,12 @@ def main(argv=None) -> int:
     if args.trace_dir:
         trace_dir = os.path.abspath(args.trace_dir)
         env["DSI_TRACE_DIR"] = trace_dir
-        from dsi_tpu.obs import configure_tracing, trace_event
-
         # mrrun's own lane records the job lifecycle; children commit
         # their trace-<pid>.* files at exit via the env inheritance.
         configure_tracing(trace_dir=trace_dir, basename="trace-mrrun")
-        trace_event("mrrun.start", app=args.app, workers=args.workers,
-                    nreduce=args.nreduce, files=len(files))
+        trace_event("mrrun.start", lane="launch", app=args.app,
+                    workers=args.workers, nreduce=args.nreduce,
+                    files=len(files))
 
     # Clear stale oracle files so a failed job can't pass --check against
     # a previous run's ground truth (the reference harness's rm,
@@ -175,8 +184,6 @@ def main(argv=None) -> int:
             p.error("--replicas wants >= 2 (3 tolerates one kill)")
         rc = _replica_job(args, workdir, files, fleet, env)
         if args.trace_dir:
-            from dsi_tpu.obs import flush_tracing, trace_event
-
             trace_event("mrrun.exit", rc=rc, replicas=args.replicas)
             flush_tracing()
         if rc != 0:
@@ -188,8 +195,6 @@ def main(argv=None) -> int:
     if args.net:
         rc = _net_job(args, workdir, files, fleet, env, journal)
         if args.trace_dir:
-            from dsi_tpu.obs import flush_tracing, trace_event
-
             trace_event("mrrun.exit", rc=rc, net=1)
             flush_tracing()
         if rc != 0:
@@ -205,12 +210,13 @@ def main(argv=None) -> int:
     if journal:
         coord_cmd += ["--journal", journal]
     coord = subprocess.Popen(coord_cmd + files, env=env, cwd=workdir)
+    trace_event("spawn", lane="launch", role="coordinator", pid=coord.pid)
     deadline = time.monotonic() + args.timeout
     time.sleep(1.0)  # socket-creation grace (test-mr.sh:39-40)
+    trace_event("coordinator_up", lane="launch")
 
     spawn = time.monotonic()
-    workers = [subprocess.Popen(cmd, env=wenv, cwd=workdir)
-               for cmd, wenv in fleet]
+    workers = [_spawn_worker(cmd, wenv, workdir) for cmd, wenv in fleet]
     spawned_at = [spawn] * len(workers)
     # A worker that dies crashed (non-zero) is respawned, but an app that
     # can never start (typo'd name, broken plugin) must not burn the whole
@@ -279,9 +285,7 @@ def main(argv=None) -> int:
                         break
                     respawn_budget -= 1
                     spawned_at[i] = time.monotonic()
-                    workers[i] = subprocess.Popen(fleet[i][0],
-                                                  env=fleet[i][1],
-                                                  cwd=workdir)
+                    workers[i] = _spawn_worker(*fleet[i], workdir)
             if rc:
                 break
             time.sleep(0.3)
@@ -300,8 +304,6 @@ def main(argv=None) -> int:
               file=sys.stderr)
         rc = 1
     if args.trace_dir:
-        from dsi_tpu.obs import flush_tracing, trace_event
-
         trace_event("mrrun.exit", rc=rc)
         flush_tracing()
         print(f"mrrun: traces in {args.trace_dir} (render: python "

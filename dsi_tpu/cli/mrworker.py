@@ -29,9 +29,13 @@ import time
 from dsi_tpu.config import JobConfig
 from dsi_tpu.mr.plugin import load_plugin
 from dsi_tpu.mr.worker import worker_loop
+from dsi_tpu.obs import event as _event, span as _span
 
 
 def main(argv=None) -> int:
+    # First thing: a worker enabled by DSI_TRACE_DIR builds its tracer
+    # here, so that the tracer's epoch precedes the first task.
+    _event("worker.start", lane="launch")
     p = argparse.ArgumentParser()
     p.add_argument("--backend", choices=("host", "tpu", "native"),
                    default="host")
@@ -52,7 +56,9 @@ def main(argv=None) -> int:
     if args.backend == "tpu":
         from dsi_tpu.backends.tpu import TpuTaskRunner
 
-        runner = TpuTaskRunner.for_app(args.app)
+        with _span("backend_init", lane="launch"):
+            runner = TpuTaskRunner.for_app(args.app)
+        _event("backend_up", lane="launch", **runner.device)
     elif args.backend == "native":
         from dsi_tpu.backends.native import NativeTaskRunner
 

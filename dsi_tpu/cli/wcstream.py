@@ -199,6 +199,24 @@ def main(argv=None) -> int:
         print("wcstream: --resume found no usable checkpoint in "
               f"{args.checkpoint_dir}; started from scratch",
               file=sys.stderr)
+    if acc is None:
+        # Host fallback: the sequential oracle semantics, partitioned
+        # output — the ONE shared implementation (serve/pack.py), so the
+        # CLI and the serving daemon cannot drift.
+        print("wcstream: stream needs the host path; running host word count",
+              file=sys.stderr)
+        from dsi_tpu.serve.pack import host_wordcount
+
+        acc = host_wordcount(args.files, args.nreduce)
+    os.makedirs(args.workdir, exist_ok=True)
+    from dsi_tpu.obs import span
+
+    with span("write", lane="host", stats=pstats, keys=len(acc)) as sp:
+        paths = write_partitioned_output(acc, args.nreduce, args.workdir)
+        sp.set(bytes=sum(os.path.getsize(path) for path in paths))
+    pstats["write_s"] = round(pstats["write_s"], 4)
+    # After the write, so that the line holds the job's serial tail too
+    # (finalize_s, write_s) and the trace its last span.
     if args.stats:
         from dsi_tpu.utils import compilecache
 
@@ -212,17 +230,6 @@ def main(argv=None) -> int:
         from dsi_tpu.obs import flush_tracing_report
 
         flush_tracing_report(args.trace_dir, "wcstream")
-    if acc is None:
-        # Host fallback: the sequential oracle semantics, partitioned
-        # output — the ONE shared implementation (serve/pack.py), so the
-        # CLI and the serving daemon cannot drift.
-        print("wcstream: stream needs the host path; running host word count",
-              file=sys.stderr)
-        from dsi_tpu.serve.pack import host_wordcount
-
-        acc = host_wordcount(args.files, args.nreduce)
-    os.makedirs(args.workdir, exist_ok=True)
-    write_partitioned_output(acc, args.nreduce, args.workdir)
 
     if args.check:
         from dsi_tpu.apps import wc

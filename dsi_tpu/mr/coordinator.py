@@ -33,14 +33,13 @@ import time
 from typing import Dict, List, Optional
 
 from dsi_tpu.config import JobConfig
-from dsi_tpu.obs import LatencyHistogram, get_registry
+from dsi_tpu.obs import LatencyHistogram, event as _event, get_registry
 from dsi_tpu.mr import rpc
 from dsi_tpu.mr.journal import Journal
 from dsi_tpu.mr.shards import ShardSpec
 from dsi_tpu.mr.types import (LOG_COMPLETED, LOG_IN_PROGRESS, LOG_UNTOUCHED,
                               TaskStatus)
 from dsi_tpu.utils.atomicio import fsync_dir
-from dsi_tpu.utils.tracing import log_event
 
 
 class Coordinator:
@@ -303,8 +302,8 @@ class Coordinator:
                     self._arm_timeout(tba, "map")  # :70-77
                     if wid:
                         self._task_worker[("map", tba)] = wid
-                    log_event("assign", kind="map", task=tba,
-                              file=self.files[tba], worker=wid or None)
+                    _event("assign", kind="map", task=tba,
+                           file=self.files[tba], worker=wid or None)
             elif self.c_reduce < self.n_reduce:  # map barrier passed (:79)
                 tba = self._pick_reduce_locked(addr) if self.net \
                     else self._pop_untouched(self._reduce_ready,
@@ -324,8 +323,8 @@ class Coordinator:
                     self._arm_timeout(tba, "reduce")  # :99-106
                     if wid:
                         self._task_worker[("reduce", tba)] = wid
-                    log_event("assign", kind="reduce", task=tba,
-                              worker=wid or None)
+                    _event("assign", kind="reduce", task=tba,
+                           worker=wid or None)
             else:
                 reply["TaskStatus"] = int(TaskStatus.DONE)  # :109-112
         return reply
@@ -358,10 +357,10 @@ class Coordinator:
                         if t in self._map_sizes:
                             extra["sizes"] = list(self._map_sizes[t])
                     self._journal.record("map", t, extra)
-                log_event("complete", kind="map", task=t, c_map=self.c_map,
-                          worker=wid or None)
+                _event("complete", kind="map", task=t, c_map=self.c_map,
+                       worker=wid or None)
             else:
-                log_event("duplicate_completion", kind="map", task=t)
+                _event("duplicate_completion", kind="map", task=t)
         return {}
 
     def reduce_complete(self, args: dict) -> dict:
@@ -390,10 +389,10 @@ class Coordinator:
                                  "name": str(args.get("Name") or ""),
                                  "crc": int(args.get("Crc", 0) or 0)}
                     self._journal.record("reduce", t, extra)
-                log_event("complete", kind="reduce", task=t,
-                          c_reduce=self.c_reduce, worker=wid or None)
+                _event("complete", kind="reduce", task=t,
+                       c_reduce=self.c_reduce, worker=wid or None)
             else:
-                log_event("duplicate_completion", kind="reduce", task=t)
+                _event("duplicate_completion", kind="reduce", task=t)
         return {}
 
     def fetch_failed(self, args: dict) -> dict:
@@ -427,10 +426,10 @@ class Coordinator:
                 self.reduce_log[r] = LOG_UNTOUCHED
                 heapq.heappush(self._reduce_ready, r)
                 self._task_worker.pop(("reduce", r), None)
-            log_event("fetch_failed", kind="net", task=r, map_task=m,
-                      worker=wid or None,
-                      addr=str(args.get("Addr") or "") or None,
-                      requeued_map=requeued_map)
+            _event("fetch_failed", kind="net", task=r, map_task=m,
+                   worker=wid or None,
+                   addr=str(args.get("Addr") or "") or None,
+                   requeued_map=requeued_map)
             if requeued_map:
                 print(f"coordinator: fetch of mr-{m}-{r} failed "
                       f"(producer server gone); re-executing map {m}",
@@ -535,9 +534,9 @@ class Coordinator:
                 reply["Net"] = True
                 reply["OutPart"] = os.path.basename(reply["OutPart"])
                 reply["CkptRoot"] = ".shards"
-            log_event("assign", kind="shard", task=sid, attempt=aid,
-                      attempt_kind=att["kind"], worker=wid or None,
-                      resume_from=att["resume_from"])
+            _event("assign", kind="shard", task=sid, attempt=aid,
+                   attempt_kind=att["kind"], worker=wid or None,
+                   resume_from=att["resume_from"])
         return reply
 
     def shard_progress(self, args: dict) -> dict:
@@ -620,9 +619,9 @@ class Coordinator:
                 # The subs got there first: the full-range straggler
                 # lost to the split as a whole.
                 self._spec["commit_losses"] += 1
-                log_event("shard_commit_lose", kind="shard", task=sid,
-                          attempt=aid, winner="split",
-                          worker=wid or None)
+                _event("shard_commit_lose", kind="shard", task=sid,
+                       attempt=aid, winner="split",
+                       worker=wid or None)
                 return {"Win": False}
             if shard["committed"] is not None:
                 self._spec["commit_losses"] += 1
@@ -630,9 +629,9 @@ class Coordinator:
                     # The winner re-reporting would double-journal:
                     # MUST stay 0 (the harness gates on it).
                     self._spec["duplicate_commits"] += 1
-                log_event("shard_commit_lose", kind="shard", task=sid,
-                          attempt=aid, winner=shard["committed"][0],
-                          worker=wid or None)
+                _event("shard_commit_lose", kind="shard", task=sid,
+                       attempt=aid, winner=shard["committed"][0],
+                       worker=wid or None)
                 return {"Win": False}
             if self.net:
                 # Net mode: the winner's bytes stay in ITS private
@@ -655,8 +654,8 @@ class Coordinator:
                     os.replace(part, final)
                     fsync_dir(os.path.dirname(final) or ".")
                 except OSError as e:
-                    log_event("shard_commit_missing", kind="shard",
-                              task=sid, attempt=aid, error=str(e))
+                    _event("shard_commit_missing", kind="shard",
+                           task=sid, attempt=aid, error=str(e))
                     return {"Win": False,
                             "Error": f"partial missing: {e}"}
             if self._journal is not None:
@@ -696,9 +695,9 @@ class Coordinator:
                             os.remove(p)
                         except OSError:
                             pass
-                log_event("resplit_overrun", kind="shard", task=sid,
-                          attempt=aid,
-                          subs=sorted(shard["subs"]))
+                _event("resplit_overrun", kind="shard", task=sid,
+                       attempt=aid,
+                       subs=sorted(shard["subs"]))
             att = shard["attempts"].get(aid)
             if att is not None:
                 now = time.monotonic()
@@ -706,9 +705,9 @@ class Coordinator:
                 # The slow-progress backup trigger's reference: how
                 # long a NORMAL shard takes, assignment to commit.
                 self._commit_walls.append(now - att["assigned"])
-            log_event("shard_commit", kind="shard", task=sid, attempt=aid,
-                      crc=crc, worker=wid or None,
-                      resume_cursor=att["resume_cursor"] if att else 0)
+            _event("shard_commit", kind="shard", task=sid, attempt=aid,
+                   crc=crc, worker=wid or None,
+                   resume_cursor=att["resume_cursor"] if att else 0)
             get_registry().set_gauge("dsi_shard_commits",
                                      self._spec["commits"])
             return {"Win": True}
@@ -732,10 +731,10 @@ class Coordinator:
             if att is not None and not att["dead"] and not att["cancelled"]:
                 att["dead"] = True
                 self._spec["failed_attempts"] += 1
-                log_event("shard_failed", kind="shard", task=sid,
-                          attempt=aid, worker=wid or None,
-                          sub=(sub if sub >= 0 else None),
-                          reason=str(args.get("Reason", "") or ""))
+                _event("shard_failed", kind="shard", task=sid,
+                       attempt=aid, worker=wid or None,
+                       sub=(sub if sub >= 0 else None),
+                       reason=str(args.get("Reason", "") or ""))
                 if sub >= 0:
                     self._requeue_sub_locked(sid, sub)
                 else:
@@ -826,7 +825,7 @@ class Coordinator:
             heapq.heappush(self._reduce_ready, r)
             self._out_locs.pop(r, None)
             self._net_counters["net_refetches"] += 1
-            log_event("refetch", kind="reduce", task=r)
+            _event("refetch", kind="reduce", task=r)
             print(f"coordinator: output mr-out-{r} unreachable; "
                   f"re-executing reduce {r}", file=sys.stderr)
         return True
@@ -850,8 +849,8 @@ class Coordinator:
             heapq.heappush(self._shard_ready, sid)
             self._net_counters["net_refetches"] += 1
             self._spec["requeues"] += 1
-            log_event("refetch", kind="shard", task=sid,
-                      lost_attempt=aid)
+            _event("refetch", kind="shard", task=sid,
+                   lost_attempt=aid)
             print(f"coordinator: shard {sid} output (attempt a{aid}) "
                   f"unreachable; re-executing", file=sys.stderr)
         return True
@@ -926,8 +925,8 @@ class Coordinator:
                 if best is not None \
                         and shard["attempts"][best]["worker"] == wid:
                     self._net_counters["locality_hits"] += 1
-                    log_event("locality_hit", kind="shard", task=sid,
-                              worker=wid)
+                    _event("locality_hit", kind="shard", task=sid,
+                           worker=wid)
                     return sid
         while self._shard_ready:
             sid = heapq.heappop(self._shard_ready)
@@ -972,8 +971,8 @@ class Coordinator:
                     continue
                 if self._preferred_host(r) == addr:
                     self._net_counters["locality_hits"] += 1
-                    log_event("locality_hit", kind="reduce", task=r,
-                              addr=addr)
+                    _event("locality_hit", kind="reduce", task=r,
+                           addr=addr)
                     return r
         return self._pop_untouched(self._reduce_ready, self.reduce_log)
 
@@ -1092,14 +1091,14 @@ class Coordinator:
         hb_age, hb_p99, presumed = self._classify(freshest["worker"], now)
         get_registry().set_gauge("dsi_shard_backup_dispatches",
                                  self._spec["backup_dispatches"])
-        log_event("backup_dispatch", kind="shard", task=sid,
-                  attempt=assignment[1], straggler_attempt=aid_f,
-                  straggler_worker=freshest["worker"] or None,
-                  backup_worker=wid or None, reason=best_reason,
-                  attempt_age_s=round(best_age, 3),
-                  heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
-                  presumed=presumed,
-                  resume_from=assignment[3]["resume_from"])
+        _event("backup_dispatch", kind="shard", task=sid,
+               attempt=assignment[1], straggler_attempt=aid_f,
+               straggler_worker=freshest["worker"] or None,
+               backup_worker=wid or None, reason=best_reason,
+               attempt_age_s=round(best_age, 3),
+               heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
+               presumed=presumed,
+               resume_from=assignment[3]["resume_from"])
         print(f"coordinator: backup dispatch shard {sid}: attempt "
               f"a{aid_f} (worker={freshest['worker'] or '?'}) "
               f"{best_reason} for {best_age:.3f}s presumed={presumed}; "
@@ -1124,8 +1123,8 @@ class Coordinator:
             return
         if shard["next_aid"] >= self.config.shard_max_attempts:
             self.job_failed = True
-            log_event("shard_exhausted", kind="shard", task=sid,
-                      attempts=shard["next_aid"])
+            _event("shard_exhausted", kind="shard", task=sid,
+                   attempts=shard["next_aid"])
             print(f"coordinator: shard {sid} failed "
                   f"{shard['next_aid']} attempts; job failed",
                   file=sys.stderr)
@@ -1234,10 +1233,10 @@ class Coordinator:
             reply["Net"] = True
             reply["OutPart"] = os.path.basename(reply["OutPart"])
             reply["CkptRoot"] = ".shards"
-        log_event("assign", kind="subshard", task=sid, sub=k,
-                  attempt=aid, worker=wid or None, start=s, end=e,
-                  resume_from=att["resume_from"],
-                  parent_chain=sub["parent_chain"])
+        _event("assign", kind="subshard", task=sid, sub=k,
+               attempt=aid, worker=wid or None, start=s, end=e,
+               resume_from=att["resume_from"],
+               parent_chain=sub["parent_chain"])
         return reply
 
     def _arm_sub_timeout(self, sid: int, k: int, aid: int) -> None:
@@ -1316,15 +1315,15 @@ class Coordinator:
         get_registry().set_gauge("dsi_shard_resplits",
                                  self._spec["resplits"])
         with span("resplit", lane="control", task=sid):
-            log_event("resplit_dispatch", kind="shard", task=sid,
-                      straggler_attempt=aid_f,
-                      straggler_worker=freshest["worker"] or None,
-                      reason=best_reason, cursor=freshest["cursor"],
-                      ranges=[[int(s), int(e)] for s, e in ranges],
-                      parent_chain=parent,
-                      attempt_age_s=round(best_age, 3),
-                      heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
-                      presumed=presumed)
+            _event("resplit_dispatch", kind="shard", task=sid,
+                   straggler_attempt=aid_f,
+                   straggler_worker=freshest["worker"] or None,
+                   reason=best_reason, cursor=freshest["cursor"],
+                   ranges=[[int(s), int(e)] for s, e in ranges],
+                   parent_chain=parent,
+                   attempt_age_s=round(best_age, 3),
+                   heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
+                   presumed=presumed)
         print(f"coordinator: re-split shard {sid}: attempt a{aid_f} "
               f"(worker={freshest['worker'] or '?'}) {best_reason} for "
               f"{best_age:.3f}s presumed={presumed}; cursor="
@@ -1347,8 +1346,8 @@ class Coordinator:
             if sub["committed"] is not None \
                     and sub["committed"][0] == aid:
                 self._spec["duplicate_commits"] += 1
-            log_event("subshard_commit_lose", kind="shard", task=sid,
-                      sub=k, attempt=aid, worker=wid or None)
+            _event("subshard_commit_lose", kind="shard", task=sid,
+                   sub=k, attempt=aid, worker=wid or None)
             return {"Win": False}
         part = self._sub_part_path(sid, k, aid)
         final = self._sub_out_path(sid, k)
@@ -1356,8 +1355,8 @@ class Coordinator:
             os.replace(part, final)
             fsync_dir(os.path.dirname(final) or ".")
         except OSError as e:
-            log_event("shard_commit_missing", kind="shard", task=sid,
-                      sub=k, attempt=aid, error=str(e))
+            _event("shard_commit_missing", kind="shard", task=sid,
+                   sub=k, attempt=aid, error=str(e))
             return {"Win": False, "Error": f"partial missing: {e}"}
         if self._journal is not None:
             self._journal.record_subshard(sid, k, aid, crc)
@@ -1383,9 +1382,9 @@ class Coordinator:
             shard["status"] = LOG_COMPLETED
             for fatt in shard["attempts"].values():
                 fatt["cancelled"] = True
-        log_event("subshard_commit", kind="shard", task=sid, sub=k,
-                  attempt=aid, crc=crc, worker=wid or None,
-                  resolved=bool(resolved))
+        _event("subshard_commit", kind="shard", task=sid, sub=k,
+               attempt=aid, crc=crc, worker=wid or None,
+               resolved=bool(resolved))
         get_registry().set_gauge("dsi_subshard_commits",
                                  self._spec["subshard_commits"])
         return {"Win": True}
@@ -1401,8 +1400,8 @@ class Coordinator:
             return
         if sub["next_aid"] >= self.config.shard_max_attempts:
             self.job_failed = True
-            log_event("shard_exhausted", kind="shard", task=sid, sub=k,
-                      attempts=sub["next_aid"])
+            _event("shard_exhausted", kind="shard", task=sid, sub=k,
+                   attempts=sub["next_aid"])
             print(f"coordinator: shard {sid} sub {k} failed "
                   f"{sub['next_aid']} attempts; job failed",
                   file=sys.stderr)
@@ -1435,12 +1434,12 @@ class Coordinator:
             return
         att["dead"] = True
         hb_age, hb_p99, presumed = self._classify(att["worker"], now)
-        log_event("requeue", kind="subshard", task=sid, sub=k,
-                  attempt=aid, timeout_s=self.config.shard_timeout_s,
-                  worker=att["worker"] or None, idle_s=round(idle, 3),
-                  heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
-                  presumed=presumed,
-                  reason="no progress past shard_timeout_s")
+        _event("requeue", kind="subshard", task=sid, sub=k,
+               attempt=aid, timeout_s=self.config.shard_timeout_s,
+               worker=att["worker"] or None, idle_s=round(idle, 3),
+               heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
+               presumed=presumed,
+               reason="no progress past shard_timeout_s")
         print(f"coordinator: requeue shard {sid} sub {k} attempt "
               f"a{aid}: no progress for {idle:.3f}s (worker="
               f"{att['worker'] or '?'} presumed={presumed})",
@@ -1520,11 +1519,11 @@ class Coordinator:
                         "mr_worker_heartbeat_hist",
                         {w: hh.snapshot()
                          for w, hh in self._hb_hist.items()})
-                    log_event("requeue", kind=kind, task=task_id,
-                              timeout_s=self.config.task_timeout_s,
-                              worker=wid or None, heartbeat_age_s=hb_age,
-                              heartbeat_p99_s=hb_p99, presumed=presumed,
-                              reason="in-progress past task_timeout_s")
+                    _event("requeue", kind=kind, task=task_id,
+                           timeout_s=self.config.task_timeout_s,
+                           worker=wid or None, heartbeat_age_s=hb_age,
+                           heartbeat_p99_s=hb_p99, presumed=presumed,
+                           reason="in-progress past task_timeout_s")
                     print(f"coordinator: requeue {kind} task {task_id}: "
                           f"in-progress past "
                           f"{self.config.task_timeout_s}s (worker="
@@ -1561,12 +1560,12 @@ class Coordinator:
             return
         att["dead"] = True
         hb_age, hb_p99, presumed = self._classify(att["worker"], now)
-        log_event("requeue", kind="shard", task=sid, attempt=aid,
-                  timeout_s=self.config.shard_timeout_s,
-                  worker=att["worker"] or None, idle_s=round(idle, 3),
-                  heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
-                  presumed=presumed,
-                  reason="no progress past shard_timeout_s")
+        _event("requeue", kind="shard", task=sid, attempt=aid,
+               timeout_s=self.config.shard_timeout_s,
+               worker=att["worker"] or None, idle_s=round(idle, 3),
+               heartbeat_age_s=hb_age, heartbeat_p99_s=hb_p99,
+               presumed=presumed,
+               reason="no progress past shard_timeout_s")
         print(f"coordinator: requeue shard {sid} attempt a{aid}: no "
               f"progress for {idle:.3f}s (worker="
               f"{att['worker'] or '?'} presumed={presumed})",

@@ -39,8 +39,8 @@ from dsi_tpu.mr.types import KeyValue, TaskStatus
 # redirects and rides out elections.  A single address passes straight
 # through to rpc.call, so the classic plane is unchanged.
 from dsi_tpu.replica.client import group_call
+from dsi_tpu.obs import span as _span
 from dsi_tpu.utils.atomicio import atomic_write
-from dsi_tpu.utils.tracing import Span
 
 MapFn = Callable[[str, str], List[KeyValue]]
 ReduceFn = Callable[[str, List[str]], str]
@@ -79,21 +79,29 @@ def write_intermediates(kva: Sequence[KeyValue], map_task: int, n_reduce: int,
     fallback, and both produce records every decoder accepts."""
     from dsi_tpu import native
 
-    blobs = native.encode_partitions(kva, n_reduce)
-    if blobs is not None:
-        for r, blob in enumerate(blobs):
-            with atomic_write(intermediate_name(map_task, r, workdir),
-                              mode="wb") as f:
-                f.write(blob)
-        return
-    buckets: list[list[KeyValue]] = [[] for _ in range(n_reduce)]
-    for kv in kva:
-        buckets[ihash(kv.key) % n_reduce].append(kv)
-    for r, bucket in enumerate(buckets):
-        with atomic_write(intermediate_name(map_task, r, workdir)) as f:
-            for kv in bucket:
-                f.write(json.dumps({"Key": kv.key, "Value": kv.value}))
-                f.write("\n")
+    with _span("write", lane="host", records=len(kva),
+               files=n_reduce) as sp:
+        blobs = native.encode_partitions(kva, n_reduce)
+        if blobs is not None:
+            for r, blob in enumerate(blobs):
+                with atomic_write(intermediate_name(map_task, r, workdir),
+                                  mode="wb") as f:
+                    f.write(blob)
+            sp.set(bytes=sum(len(b) for b in blobs))
+            return
+        buckets: list[list[KeyValue]] = [[] for _ in range(n_reduce)]
+        for kv in kva:
+            buckets[ihash(kv.key) % n_reduce].append(kv)
+        n_bytes = 0
+        for r, bucket in enumerate(buckets):
+            with atomic_write(intermediate_name(map_task, r, workdir)) as f:
+                for kv in bucket:
+                    # json.dumps escapes to ASCII: characters are bytes
+                    line = json.dumps({"Key": kv.key, "Value": kv.value})
+                    f.write(line)
+                    f.write("\n")
+                    n_bytes += len(line) + 1
+        sp.set(bytes=n_bytes)
 
 
 def read_intermediates(reduce_task: int, n_map: int,
@@ -148,12 +156,20 @@ def group_and_reduce(intermediate: list[KeyValue], reducef: ReduceFn, out) -> No
         i = j
 
 
+def read_split(filename: str) -> bytes:
+    """A map task's input, whole (worker.go:58-67)."""
+    with _span("read", lane="host") as sp:
+        with open(filename, "rb") as f:
+            raw = f.read()
+        sp.set(bytes=len(raw))
+    return raw
+
+
 def run_map_task(mapf: MapFn, filename: str, map_task: int, n_reduce: int,
                  workdir: str = ".") -> None:
     """One map task: read the split, run the app map, partition + commit
     (worker.go:55-92)."""
-    with open(filename, "rb") as f:
-        contents = f.read().decode("utf-8", errors="replace")
+    contents = read_split(filename).decode("utf-8", errors="replace")
     kva = mapf(filename, contents)
     write_intermediates(kva, map_task, n_reduce, workdir)
 
@@ -218,6 +234,9 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
     from dsi_tpu.obs import LatencyHistogram, get_registry
 
     task_hist = LatencyHistogram()
+    # The task spans' stats sink: a span with a sink times its region
+    # whether or not tracing is on, which is what note_task reads.
+    task_s: dict = {}
 
     def note_task(seconds: float) -> None:
         task_hist.record(seconds)
@@ -238,7 +257,8 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
         if extra:
             args.update(extra)
         try:
-            group_call(sock, method, args)
+            with _span("rpc", lane="control", method=method):
+                group_call(sock, method, args)
             return True
         except rpc.AuthError as e:
             print(f"mrworker: {e}", file=sys.stderr)
@@ -284,7 +304,10 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
         if addr:
             req["Addr"] = addr
         try:
-            ok, reply = group_call(sock, "Coordinator.RequestTask", req)
+            with _span("rpc", lane="control",
+                       method="Coordinator.RequestTask"):
+                ok, reply = group_call(sock, "Coordinator.RequestTask",
+                                       req)
         except rpc.CoordinatorGone as e:
             # Coordinator exited; the reference worker dies here
             # (worker.go:176-178).  Normal at end-of-job; noteworthy if this
@@ -297,10 +320,12 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
             break  # worker.go:51-53
         status = reply["TaskStatus"]
         if status == int(TaskStatus.MAP):
-            # Span → DSI_TRACE=1 yields a per-task timeline (the tracing
-            # layer the reference lacks entirely, SURVEY.md §5).
-            with Span("worker.map", task=reply["CMap"],
-                      file=reply["Filename"]) as sp:
+            # One span per task body: a --trace-dir run yields a
+            # per-task timeline, and everything opened inside inherits
+            # the task's kind and number.
+            with _span("worker.map", lane="control", stats=task_s,
+                       kind="map", task=reply["CMap"],
+                       file=reply["Filename"]) as sp:
                 if task_runner is not None:
                     task_runner.run_map(mapf, reply["Filename"], reply["CMap"],
                                         reply["NReduce"], cfg.workdir)
@@ -334,8 +359,9 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
 
                 before = net_snapshot()
                 try:
-                    with Span("worker.reduce", task=reply["CReduce"],
-                              net=1) as sp:
+                    with _span("worker.reduce", lane="control",
+                               stats=task_s, kind="reduce",
+                               task=reply["CReduce"], net=1) as sp:
                         out_name = run_reduce_task_net(
                             reducef, reply["CReduce"],
                             reply.get("MapLocs") or {},
@@ -349,11 +375,13 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
                     # and go back to the well — this reduce re-runs
                     # after the map barrier reopens.
                     try:
-                        group_call(sock, "Coordinator.FetchFailed",
-                                   {"Map": e.task,
-                                    "Reduce": reply["CReduce"],
-                                    "WorkerId": worker_id,
-                                    "Addr": e.addr})
+                        with _span("rpc", lane="control",
+                                   method="Coordinator.FetchFailed"):
+                            group_call(sock, "Coordinator.FetchFailed",
+                                       {"Map": e.task,
+                                        "Reduce": reply["CReduce"],
+                                        "WorkerId": worker_id,
+                                        "Addr": e.addr})
                     except rpc.CoordinatorGone:
                         break
                     print(f"mrworker: fetch failed ({e}); reported, "
@@ -374,7 +402,8 @@ def worker_loop(mapf: MapFn, reducef: ReduceFn,
                                        reply["CReduce"], extra):
                     break
                 continue
-            with Span("worker.reduce", task=reply["CReduce"]) as sp:
+            with _span("worker.reduce", lane="control", stats=task_s,
+                       kind="reduce", task=reply["CReduce"]) as sp:
                 if task_runner is not None:
                     task_runner.run_reduce(reducef, reply["CReduce"],
                                            reply["NMap"], cfg.workdir)

@@ -2,12 +2,15 @@
 
 Four parts, one subsystem:
 
-* :mod:`~dsi_tpu.obs.trace` — the :class:`Tracer`: nested spans,
-  instant events, counters, buffered in memory and flushed durably as a
-  JSONL event log plus a Chrome/Perfetto ``trace.json`` (one lane per
-  pipeline stage, plus device-service and control-plane lanes).
-  Enabled by ``DSI_TRACE_DIR`` or the CLIs' ``--trace-dir``; ~free
-  when disabled (``DSI_TRACE=1`` stays the stderr event stream's knob).
+* :mod:`~dsi_tpu.obs.trace` — the :class:`Tracer`, the repo's one
+  tracer: nested spans (each with an id, its parent and its task's
+  identity), instant events, counters, buffered in memory and flushed
+  durably as a JSONL event log plus a Chrome/Perfetto ``trace.json``
+  (one lane per pipeline stage, plus device-service, host data-plane,
+  launch and control-plane lanes).  Enabled by ``DSI_TRACE_DIR`` or the
+  CLIs' ``--trace-dir``; ~free when disabled.  In a process that has
+  imported JAX its spans are also ``jax.profiler.TraceAnnotation``s, so
+  a profiler trace holds them beside the device's ops.
 * :mod:`~dsi_tpu.obs.registry` — the :class:`MetricsRegistry` every
   engine's phase dict registers into, with the single documented key
   schema that subsumes ``pipeline_stats``/``stream_phases``/

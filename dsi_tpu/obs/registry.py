@@ -16,10 +16,20 @@ that phase):
   wave chunk assembly); in the producer thread at depth > 1
 * ``materialize_wait_s`` — consumer starvation on the producer queue
 * ``upload_s``           — H2D puts of step inputs
-* ``kernel_s``           — time blocked on a step's deferred scalar/flag
-  check (the device-compute wall the window failed to hide)
-* ``pull_s``             — D2H result pulls
+* ``kernel_s``           — seconds blocked on a step's deferred
+  scalar/flag check.  NOT device time: the flags of a step land long
+  before the step's result tables are asked for, and most of the
+  device-compute wall the window failed to hide shows up in
+  ``device_wait_s`` instead
+* ``pull_s``             — result pulls, host-blocked; the sum of
+  ``device_wait_s`` (blocked in ``jax.block_until_ready`` until the
+  device has produced what is pulled: device time, not transfer) and
+  ``d2h_s`` (the device-to-host copy itself)
 * ``merge_s``            — host-side accumulation of pulled results
+* ``finalize_s``         — the final merge of the accumulator into the
+  result (compaction, decode of every distinct word)
+* ``write_s``            — writing the partitioned ``mr-out-*`` (the
+  CLI's phase, not the engine's)
 * ``replay_s``           — exactness-ladder replays of overflowed steps
 * ``fold_s`` / ``append_s`` / ``hist_s`` — device-service folds
 * ``sync_s`` / ``drain_s``               — device-service pulls/drains
@@ -151,7 +161,8 @@ LEGACY_ALIASES = {
 #: contract test pins.
 PHASE_KEYS = (
     "materialize_s", "materialize_wait_s", "upload_s", "kernel_s",
-    "pull_s", "merge_s", "replay_s", "fold_s", "append_s", "hist_s",
+    "pull_s", "device_wait_s", "d2h_s", "merge_s", "replay_s",
+    "finalize_s", "write_s", "fold_s", "append_s", "hist_s",
     "sync_s", "drain_s", "widen_s", "ckpt_s", "ckpt_capture_s",
     "ckpt_commit_s", "ckpt_barrier_s",
     # compressed wire + ingest (ISSUE 13)

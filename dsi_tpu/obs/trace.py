@@ -7,14 +7,28 @@ control-plane visibility.  :class:`Tracer` is the one timeline every
 layer writes into:
 
 * **spans** — ``with tracer.span("upload", step=n): ...`` times a named
-  region.  Spans nest (a per-thread depth counter rides each event), are
-  thread-safe (the buffer append is the only shared write, under one
-  lock), and are ~free when tracing is disabled: a pure span returns a
-  shared no-op singleton (zero allocation), and a span carrying a
-  ``stats``/``key`` sink degenerates to exactly the two
-  ``perf_counter`` calls the engines' hand-rolled phase timing already
-  paid — the sink write IS the phase accounting, so the span totals and
-  the ``stream_phases``-style registry values cannot disagree.
+  region.  Spans nest: every record carries an ``id``, the ``parent``
+  that enclosed it on its thread (an explicit ``parent=`` where work is
+  handed to another thread) and its ``depth``; a span that names a
+  ``kind`` and a ``task`` (``worker.map``, ``worker.reduce``) hands both
+  to everything opened under it, so the records of one task share an
+  identifier.  They are thread-safe (the buffer append is the only
+  shared write, under one lock), and are ~free when tracing is
+  disabled: a pure span returns a shared no-op singleton (zero
+  allocation), and a span carrying a ``stats``/``key`` sink degenerates
+  to exactly the two ``perf_counter`` calls the engines' hand-rolled
+  phase timing already paid — the sink write IS the phase accounting,
+  so the span totals and the ``stream_phases``-style registry values
+  cannot disagree.
+* **one clock with the device trace** — while tracing is enabled and
+  ``jax`` is already imported (this module never imports it: the
+  launchers must stay off JAX), a span also enters
+  ``jax.profiler.TraceAnnotation("dsi:<name>")``, so a profiler trace
+  taken of the process holds the program's spans beside the device's
+  ops; a ``dsi.clock`` annotation carrying ``time.time_ns()`` is emitted
+  when tracing is enabled, at most once a second under a root span, and
+  at flush, and the JSONL head carries ``wall0_ns``: the offset between
+  this file's clock and the profiler's is recoverable from either.
 * **events** — ``tracer.event("requeue", ...)`` instant records (the
   control-plane lane).
 * **counters** — ``tracer.count("steps")`` monotonic counters, emitted
@@ -36,19 +50,19 @@ survives the same crashes the checkpoints do):
 
 The process-global tracer (:func:`get_tracer`) is enabled by
 ``DSI_TRACE_DIR=<dir>`` (buffer + durable flush at exit — how
-``mrrun --trace-dir`` reaches its child coordinator/workers) or by
+``mrrun --trace-dir`` reaches its child coordinator/workers; their
+``main`` builds it at entry, so its epoch precedes the first task) or by
 :func:`configure` (the CLIs' ``--trace-dir``; ``enabled=True`` alone is
-the bench's in-memory rollup mode).  Buffering without a consumer is a
-pure memory cost, so ``DSI_TRACE=1`` keeps its historical stderr-only
-meaning (``utils/tracing.log_event``) and does NOT enable the buffer.
-``ckpt/fault.py`` flushes it right before ``os._exit``, so traces
-survive injected crashes.
+the bench's in-memory rollup mode).  ``ckpt/fault.py`` flushes it right
+before ``os._exit``, so traces survive injected crashes.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -65,7 +79,7 @@ _HOT_STAGES = frozenset(_hist.HIST_STAGES)
 LANES = (
     "materialize", "upload", "dispatch", "kernel", "pull", "merge",
     "replay", "shuffle", "fold", "sync", "widen", "ckpt", "plan",
-    "net", "replica", "control", "counters",
+    "net", "replica", "host", "launch", "control", "counters",
 )
 
 #: The pinned span-name schema: every span opened anywhere in the repo
@@ -79,6 +93,9 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     "wait", "finish", "drain", "append", "hist_fold", "hist_pull",
     "ckpt_capture", "ckpt_commit", "ckpt_save", "ckpt_restore", "task",
     "decode", "stage_commit", "resplit", "stage_overlap",
+    # the batch plane's tasks and what a task and a launch consist of
+    "worker.map", "worker.reduce", "read", "write", "rpc", "d2h",
+    "finalize", "probe", "backend_init",
 ))
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"
@@ -97,6 +114,9 @@ class _NoopSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **fields) -> None:
+        """Fields known only once the work is done: nowhere to go."""
+
 
 _NOOP_SPAN = _NoopSpan()
 
@@ -106,11 +126,12 @@ class _Span:
     (tracing disabled but the engine still needs its phase seconds)."""
 
     __slots__ = ("_tr", "name", "lane", "_stats", "_key", "_fields",
-                 "_t0", "_depth", "elapsed_s")
+                 "_t0", "_depth", "elapsed_s", "id", "_up", "_prev",
+                 "_task", "_ann")
 
     def __init__(self, tr: Optional["Tracer"], name: str, lane: str,
                  stats: Optional[dict], key: Optional[str],
-                 fields: Optional[dict]):
+                 fields: Optional[dict], parent=None):
         self._tr = tr
         self.name = name
         self.lane = lane
@@ -118,13 +139,43 @@ class _Span:
         self._key = key
         self._fields = fields
         self.elapsed_s = 0.0
+        self.id = None
+        # An explicit parent counts only if it is itself being recorded.
+        self._up = parent if getattr(parent, "id", None) is not None \
+            else None
+
+    def set(self, **fields) -> None:
+        """Add fields known only once the work is done (bytes read,
+        records decoded); recorded with the span at its close."""
+        if self._tr is not None:
+            if self._fields is None:
+                self._fields = fields
+            else:
+                self._fields.update(fields)
 
     def __enter__(self) -> "_Span":
         tr = self._tr
         if tr is not None:
             tls = tr._tls
-            self._depth = getattr(tls, "depth", 0)
-            tls.depth = self._depth + 1
+            prev = self._prev = getattr(tls, "cur", None)
+            up = self._up = self._up or prev
+            self.id = next(tr._ids)
+            self._depth = up._depth + 1 if up is not None else 0
+            f = self._fields
+            if f is not None and "kind" in f and "task" in f:
+                self._task = (f["kind"], f["task"])
+            else:
+                self._task = up._task if up is not None else None
+            tls.cur = self
+            ann = tr._annotation()
+            if ann is None:
+                self._ann = None
+            else:
+                if prev is None and \
+                        time.perf_counter() - tr._clock_at > 1.0:
+                    tr._clock_mark()
+                self._ann = ann("dsi:" + self.name)
+                self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -141,9 +192,13 @@ class _Span:
             hs.record(self.name, dur)
         tr = self._tr
         if tr is not None:
-            tr._tls.depth = self._depth
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            tr._tls.cur = self._prev
+            up = self._up
             tr._record("X", self.name, self.lane, self._t0, dur,
-                       self._depth, self._fields)
+                       self._depth, self._fields, self.id,
+                       up.id if up is not None else None, self._task)
         return False
 
 
@@ -156,12 +211,19 @@ class Tracer:
                  buffer_cap: Optional[int] = None):
         self._lock = threading.Lock()
         self._tls = threading.local()
-        #: (ph, name, lane, t_perf, dur_s, depth, fields) tuples.
+        #: (ph, name, lane, t_perf, dur_s, depth, fields, id, parent,
+        #: task) tuples; ``task`` is the enclosing task's (kind, task).
         self._events: List[Tuple] = []
+        self._ids = itertools.count(1)
         self.dropped = 0
         self.counters: Dict[str, float] = {}
         self._t0 = time.perf_counter()
-        self._wall0 = time.time()
+        self._wall0_ns = time.time_ns()
+        self._wall0 = self._wall0_ns / 1e9
+        #: ``jax.profiler.TraceAnnotation`` once JAX is imported, and
+        #: when the last ``dsi.clock`` mark went out (perf_counter).
+        self._ann_cls = None
+        self._clock_at = float("-inf")
         # Construction never DEactivates the histogram plane (another
         # tracer may be feeding it); only an explicit ``enabled=False``
         # assignment does — see the property setter.
@@ -195,8 +257,36 @@ class Tracer:
         self._enabled = bool(v)
         if self._enabled:
             _hist.activate()
+            self._clock_mark()
         else:
             _hist.deactivate()
+
+    # ── the device trace's clock ──
+
+    def _annotation(self):
+        """``jax.profiler.TraceAnnotation``, or None while the process
+        has not imported JAX (it is looked up, never imported: a
+        launcher that imported it would take the chip from its
+        children)."""
+        ann = self._ann_cls
+        if ann is None:
+            ann = getattr(sys.modules.get("jax.profiler"),
+                          "TraceAnnotation", None)
+            self._ann_cls = ann
+        return ann
+
+    def _clock_mark(self) -> None:
+        """One ``dsi.clock`` annotation: the epoch clock (``wall_ns``)
+        and this tracer's own (``ts_ns`` since its epoch) at one instant
+        of the profiler's."""
+        ann = self._annotation()
+        if ann is None:
+            return
+        now = time.perf_counter()
+        self._clock_at = now
+        with ann("dsi.clock", wall_ns=time.time_ns(),
+                 ts_ns=int((now - self._t0) * 1e9)):
+            pass
 
     def set_trace_dir(self, trace_dir: str,
                       basename: Optional[str] = None) -> None:
@@ -218,10 +308,13 @@ class Tracer:
 
     def span(self, name: str, /, *, lane: Optional[str] = None,
              stats: Optional[dict] = None, key: Optional[str] = None,
-             **fields):
+             parent=None, **fields):
         """A context manager timing one region.  With ``stats``/``key``
         the elapsed seconds are ALSO added to ``stats[key]`` (the
         engines' phase dicts — one measurement, two consumers).
+        ``parent`` is the span (as ``with ... as sp`` gave it) this one
+        belongs under when it runs on another thread than its parent;
+        on one thread the enclosing span is found without it.
         Disabled and sink-less returns the shared no-op singleton —
         unless the live histogram plane is active and the span is a hot
         stage, which still needs its close latency recorded (statusz-
@@ -235,32 +328,19 @@ class Tracer:
             return _NOOP_SPAN
         return _Span(self, name, lane or name, stats,
                      (key or (name + "_s")) if stats is not None else None,
-                     fields or None)
+                     fields or None, parent)
 
     def event(self, name: str, /, *, lane: str = "control",
               **fields) -> None:
-        """Record one instant event (control-plane lane by default)."""
+        """Record one instant event (control-plane lane by default),
+        under the span open on this thread, if any."""
         if not self.enabled:
             return
-        self._record("I", name, lane, time.perf_counter(), 0.0,
-                     getattr(self._tls, "depth", 0), fields or None)
-
-    def record_span(self, name: str, dur_s: float, /, *,
-                    lane: str = "control", **fields) -> None:
-        """Record an already-timed region ending now — for measurements
-        taken elsewhere (the worker's task ``Span``s mirror through
-        here), so they land as real spans, not instants.  The start is
-        clamped to the tracer's epoch: the global tracer is built
-        lazily, so the first mirrored span may have BEGUN before ``_t0``
-        and would otherwise export a negative timestamp."""
-        if not self.enabled:
-            return
-        hs = _hist._active
-        if hs is not None:
-            hs.record(name, dur_s)
-        self._record("X", name, lane,
-                     max(self._t0, time.perf_counter() - dur_s),
-                     dur_s, 0, fields or None)
+        cur = getattr(self._tls, "cur", None)
+        depth, parent, task = (0, None, None) if cur is None else \
+            (cur._depth + 1, cur.id, cur._task)
+        self._record("I", name, lane, time.perf_counter(), 0.0, depth,
+                     fields or None, None, parent, task)
 
     def count(self, name: str, /, n: float = 1, *,
               lane: str = "counters") -> None:
@@ -274,13 +354,15 @@ class Tracer:
                      {"value": v})
 
     def _record(self, ph: str, name: str, lane: str, t_perf: float,
-                dur_s: float, depth: int, fields: Optional[dict]) -> None:
+                dur_s: float, depth: int, fields: Optional[dict],
+                id_: Optional[int] = None, parent: Optional[int] = None,
+                task: Optional[tuple] = None) -> None:
         with self._lock:
             if len(self._events) >= self.buffer_cap:
                 self.dropped += 1
                 return
             self._events.append((ph, name, lane, t_perf - self._t0,
-                                 dur_s, depth, fields))
+                                 dur_s, depth, fields, id_, parent, task))
 
     # ── reading back ──
 
@@ -309,7 +391,7 @@ class Tracer:
             evs = self._events[since:]
         out: Dict[str, dict] = {}
         durs: Dict[str, list] = {}
-        for ph, name, lane, ts, dur, depth, fields in evs:
+        for ph, name, lane, ts, dur, depth, *_ in evs:
             if ph != "X":
                 continue
             r = out.setdefault(name, {"total_s": 0.0, "count": 0,
@@ -336,7 +418,8 @@ class Tracer:
 
     def _meta(self, counters: Dict, dropped: int) -> dict:
         meta = {"pid": os.getpid(), "wall0": round(self._wall0, 3),
-                "basename": self.basename, "dropped_events": dropped,
+                "wall0_ns": self._wall0_ns, "basename": self.basename,
+                "dropped_events": dropped,
                 "counters": counters}
         try:
             from dsi_tpu.obs.registry import get_registry
@@ -356,6 +439,7 @@ class Tracer:
             return None
         from dsi_tpu.utils.atomicio import write_bytes_durable
 
+        self._clock_mark()
         with self._lock:
             evs = list(self._events)
             counters = dict(self.counters)
@@ -363,12 +447,20 @@ class Tracer:
         meta = self._meta(counters, dropped)
 
         lines = [json.dumps({"type": "meta", **meta}, sort_keys=True)]
-        for ph, name, lane, ts, dur, depth, fields in evs:
+        for ph, name, lane, ts, dur, depth, fields, id_, parent, task \
+                in evs:
             rec = {"ph": ph, "name": name, "lane": lane,
                    "ts": round(ts, 6), "dur": round(dur, 6),
                    "depth": depth}
+            if ph != "C":
+                rec["parent"] = parent
+                if ph == "X":
+                    rec["id"] = id_
             if fields:
                 rec.update(fields)
+            if task is not None:
+                rec.setdefault("kind", task[0])
+                rec.setdefault("task", task[1])
             lines.append(json.dumps(rec, sort_keys=True, default=str))
         jsonl_path = os.path.join(self.trace_dir, self.basename + ".jsonl")
         write_bytes_durable(jsonl_path,
@@ -386,17 +478,20 @@ class Tracer:
                         "tid": tid, "args": {"name": lane}})
             tev.append({"name": "thread_sort_index", "ph": "M", "pid": pid,
                         "tid": tid, "args": {"sort_index": tid}})
-        for ph, name, lane, ts, dur, depth, fields in evs:
+        for ph, name, lane, ts, dur, depth, fields, id_, parent, task \
+                in evs:
             ev = {"name": name, "cat": lane, "pid": pid,
                   "tid": tid_of[lane], "ts": round(ts * 1e6, 3)}
+            args = dict(fields) if fields else {}
             if ph == "X":
                 ev.update(ph="X", dur=round(dur * 1e6, 3))
+                args.update(id=id_, parent=parent)
             elif ph == "C":
                 ev.update(ph="C")
             else:
                 ev.update(ph="i", s="t")
-            if fields:
-                ev["args"] = fields
+            if args:
+                ev["args"] = args
             tev.append(ev)
         doc = {"traceEvents": tev, "displayTimeUnit": "ms",
                "otherData": meta}
@@ -436,9 +531,9 @@ def _register_atexit() -> None:
 def get_tracer() -> Tracer:
     """The process-global tracer, lazily built from the env:
     ``DSI_TRACE_DIR`` enables buffering with a per-process durable
-    flush target (``trace-<pid>.*``).  ``DSI_TRACE=1`` alone does NOT
-    enable it — buffered events with no flush target are dead weight on
-    long runs, and that knob's stderr stream is ``utils/tracing``'s."""
+    flush target (``trace-<pid>.*``).  A process that may run tasks
+    calls this at the top of its ``main``, so that the tracer's epoch
+    precedes the first of them."""
     global _global
     if _global is None:
         with _global_lock:

@@ -26,12 +26,14 @@ import numpy as np
 
 from dsi_tpu.ops.grepk import (
     _grep_jit,
+    ascii_text,
     is_literal_pattern,
     lines_from_flags,
+    pad_chunk,
     retry_line_caps,
+    upload_chunk,
 )
 from dsi_tpu.ops.regexk import _classgrep_compiled, parse_class_pattern
-from dsi_tpu.ops.wordcount import _pad_pow2
 
 
 def split_top_level(pat: str) -> Optional[List[str]]:
@@ -115,14 +117,11 @@ def altgrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
         if parse_class_pattern(b) is None:
             return None  # branch outside both device tiers
         any_class = True
-    if any_class and b"\x00" in data:
-        return None  # NUL inside a line would disagree with host re
-    try:
-        text = data.decode("ascii")
-    except UnicodeDecodeError:
+    text = ascii_text(data, nul_ok=not any_class)
+    if text is None:
         return None
     n_host_lines = data.count(b"\n") + 1
-    chunk = jnp.asarray(_pad_pow2(data))
+    chunk = upload_chunk(pad_chunk(data))
     n = int(chunk.shape[0])
 
     def run(l_cap: int):
@@ -134,5 +133,5 @@ def altgrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
             n_lines, overflow = nl, of  # chunk-derived: same every branch
         return total, n_lines, overflow
 
-    line_match, nl = retry_line_caps(n, run)
+    line_match, nl = retry_line_caps(n, run, "altgrep")
     return lines_from_flags(text, line_match, nl)

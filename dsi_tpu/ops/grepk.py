@@ -21,9 +21,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dsi_tpu.obs import span as _span
 from dsi_tpu.ops.wordcount import _pad_pow2, _shift_left
 
 
+@jax.named_scope("line_flags")
 def line_flags_from_match(chunk: jax.Array, match: jax.Array, l_cap: int):
     """Per-position match mask -> per-line flags, shared by the literal
     kernel here and the class-pattern kernel (``ops/regexk.py``): line
@@ -50,14 +52,42 @@ def line_cap_rungs(n: int):
     return (max(n // 8, 1), n + 1)
 
 
-def retry_line_caps(n: int, run):
+def ascii_text(data: bytes, nul_ok: bool = True) -> Optional[str]:
+    """``data`` as text, or None when the host path has to decide: a
+    byte is not ASCII, or (``nul_ok`` false: the class and NFA tiers) a
+    NUL inside a line would disagree with host ``re``."""
+    with _span("decode", lane="host", bytes=len(data)):
+        if not nul_ok and b"\x00" in data:
+            return None
+        try:
+            return data.decode("ascii")
+        except UnicodeDecodeError:
+            return None
+
+
+def pad_chunk(data: bytes) -> np.ndarray:
+    """The split as the kernels take it: zero-padded to a power of two."""
+    with _span("materialize", lane="host", bytes=len(data)):
+        return _pad_pow2(data)
+
+
+def upload_chunk(chunk_np: np.ndarray) -> jax.Array:
+    with _span("upload", bytes=chunk_np.nbytes):
+        return jnp.asarray(chunk_np)
+
+
+def retry_line_caps(n: int, run, program: str):
     """Walk :func:`line_cap_rungs` (exactness_retry discipline) until a
     rung's line buffer holds every line.  ``run(l_cap)`` ->
     (line_match, n_lines, overflow).  A rung not compiled yet compiles
-    here, logged and counted like any other program."""
-    for l_cap in line_cap_rungs(n):
-        line_match, n_lines, overflow = run(l_cap)
-        if not bool(overflow):
+    here, logged and counted like any other program.  Each attempt is
+    one ``kernel`` span of ``program``: dispatch to the first blocking
+    scalar read."""
+    for attempt, l_cap in enumerate(line_cap_rungs(n)):
+        with _span("kernel", program=program, attempt=attempt, cap=l_cap):
+            line_match, n_lines, overflow = run(l_cap)
+            overflow = bool(overflow)
+        if not overflow:
             break
     return line_match, int(n_lines)
 
@@ -66,11 +96,16 @@ def lines_from_flags(text: str, line_match, nl: int) -> Optional[List[str]]:
     """Map device line flags back to text lines; None on a host/device
     line-count disagreement (the host path decides — correctness never
     depends on a kernel, ``backends/tpu.py`` contract)."""
-    flags = np.asarray(line_match[:nl])
-    lines = text.split("\n")
-    if len(lines) != nl:
-        return None
-    return [lines[i] for i in range(nl) if flags[i]]
+    with _span("pull") as sp:
+        flags = np.asarray(line_match[:nl])
+        sp.set(bytes=flags.nbytes)
+    with _span("decode", lane="host") as sp:
+        lines = text.split("\n")
+        if len(lines) != nl:
+            return None
+        out = [lines[i] for i in range(nl) if flags[i]]
+        sp.set(records=len(out))
+        return out
 
 
 def grep_kernel(chunk: jax.Array, pattern: jax.Array, *, l_cap: int):
@@ -83,8 +118,9 @@ def grep_kernel(chunk: jax.Array, pattern: jax.Array, *, l_cap: int):
     """
     m = pattern.shape[0]
     match = jnp.ones(chunk.shape[0], jnp.bool_)
-    for j in range(m):  # static unroll over the (short) pattern
-        match &= _shift_left(chunk, j) == pattern[j]
+    with jax.named_scope("match"):
+        for j in range(m):  # static unroll over the (short) pattern
+            match &= _shift_left(chunk, j) == pattern[j]
     return line_flags_from_match(chunk, match, l_cap)
 
 
@@ -127,15 +163,14 @@ def grep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     on overflow (exactness_retry discipline, avg line >= 8 bytes first)."""
     if not is_literal_pattern(pattern):
         return None
-    try:
-        text = data.decode("ascii")
-    except UnicodeDecodeError:
+    text = ascii_text(data)
+    if text is None:
         return None
     if len(pattern) > len(data):
         return []  # a literal longer than the data cannot match any line
-    chunk = jnp.asarray(_pad_pow2(data))
+    chunk = upload_chunk(pad_chunk(data))
     pat = jnp.asarray(np.frombuffer(pattern.encode("ascii"), dtype=np.uint8))
     n = int(chunk.shape[0])
     line_match, nl = retry_line_caps(
-        n, lambda l_cap: _grep_jit(chunk, pat, l_cap=l_cap))
+        n, lambda l_cap: _grep_jit(chunk, pat, l_cap=l_cap), "grep_kernel")
     return lines_from_flags(text, line_match, nl)

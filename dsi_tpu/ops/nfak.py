@@ -45,10 +45,13 @@ import numpy as np
 
 from dsi_tpu.ops.altk import split_top_level
 from dsi_tpu.ops.grepk import (
+    ascii_text,
     line_cap_rungs,
     line_flags_from_match,
     lines_from_flags,
+    pad_chunk,
     retry_line_caps,
+    upload_chunk,
 )
 from dsi_tpu.ops.regexk import ATOM_REJECT, atom_members
 from dsi_tpu.ops.wordcount import _pad_pow2
@@ -510,11 +513,8 @@ def nfagrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     parsed = parse_nfa_pattern(pattern)
     if parsed is None:
         return None
-    if b"\x00" in data:
-        return None  # NUL inside a line would disagree with host re
-    try:
-        text = data.decode("ascii")
-    except UnicodeDecodeError:
+    text = ascii_text(data, nul_ok=False)
+    if text is None:
         return None
     branches, n_atoms = parsed
     if not tier4_preferred(_bucket(n_atoms)):
@@ -523,7 +523,7 @@ def nfagrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     s_bucket = table_np.shape[1]
     # _pad_pow2 guarantees >= 1 trailing zero — the line-end byte the
     # $ latch and final-line handling depend on.
-    chunk_np = _pad_pow2(data)
+    chunk_np = pad_chunk(data)
     n = len(chunk_np)
     block = min(256, n)
     # Per-RUNG readiness via the shared gated retry
@@ -535,11 +535,11 @@ def nfagrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
 
     def run(l_cap: int):
         if not dev:
-            dev["chunk"] = jnp.asarray(chunk_np)
+            dev["chunk"] = upload_chunk(chunk_np)
             dev["table"] = jnp.asarray(table_np)
             dev["v0"] = jnp.asarray(v0_np)
         return _nfa_compiled(n, s_bucket, block, l_cap)(
             dev["chunk"], dev["table"], dev["v0"])
 
-    line_match, nl = retry_line_caps(n, run)
+    line_match, nl = retry_line_caps(n, run, "nfa_kernel")
     return lines_from_flags(text, line_match, nl)

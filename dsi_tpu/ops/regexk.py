@@ -34,11 +34,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from dsi_tpu.ops.grepk import (
+    ascii_text,
     line_flags_from_match,
     lines_from_flags,
+    pad_chunk,
     retry_line_caps,
+    upload_chunk,
 )
-from dsi_tpu.ops.wordcount import _pad_pow2, _shift_left
+from dsi_tpu.ops.wordcount import _shift_left
 
 # Ranges per pattern position beyond which the unrolled compare chain
 # stops being a win (a pathological negated class alternates up to ~128
@@ -193,13 +196,10 @@ def parse_class_pattern(pat: str):
     return tuple(positions), anchor_start, anchor_end
 
 
-def classgrep_kernel(chunk: jax.Array, *, ranges, anchor_start: bool,
-                     anchor_end: bool, l_cap: int):
-    """Match lines of ``chunk`` containing the class pattern.
-
-    Same contract as ``grepk.grep_kernel``: returns (line_match [l_cap]
-    i32 flags in line order, n_lines i32, overflow bool).
-    """
+@jax.named_scope("match")
+def _class_match(chunk: jax.Array, ranges, anchor_start: bool,
+                 anchor_end: bool) -> jax.Array:
+    """Per-position mask: the class pattern matches starting here."""
     m = len(ranges)
     match = jnp.ones(chunk.shape[0], jnp.bool_)
     for j, rs in enumerate(ranges):
@@ -218,6 +218,17 @@ def classgrep_kernel(chunk: jax.Array, *, ranges, anchor_start: bool,
     if anchor_end:
         nxt = _shift_left(chunk, m)  # byte just past the window
         match &= (nxt == jnp.uint8(10)) | (nxt == jnp.uint8(0))
+    return match
+
+
+def classgrep_kernel(chunk: jax.Array, *, ranges, anchor_start: bool,
+                     anchor_end: bool, l_cap: int):
+    """Match lines of ``chunk`` containing the class pattern.
+
+    Same contract as ``grepk.grep_kernel``: returns (line_match [l_cap]
+    i32 flags in line order, n_lines i32, overflow bool).
+    """
+    match = _class_match(chunk, ranges, anchor_start, anchor_end)
     return line_flags_from_match(chunk, match, l_cap)
 
 
@@ -247,15 +258,13 @@ def classgrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     if parsed is None:
         return None
     ranges, anchor_start, anchor_end = parsed
-    if b"\x00" in data:
-        return None  # NUL inside a line would disagree with host re
-    try:
-        text = data.decode("ascii")
-    except UnicodeDecodeError:
+    text = ascii_text(data, nul_ok=False)
+    if text is None:
         return None
-    chunk = jnp.asarray(_pad_pow2(data))
+    chunk = upload_chunk(pad_chunk(data))
     n = int(chunk.shape[0])
     line_match, nl = retry_line_caps(
         n, lambda l_cap: _classgrep_compiled(
-            n, ranges, anchor_start, anchor_end, l_cap)(chunk))
+            n, ranges, anchor_start, anchor_end, l_cap)(chunk),
+        "classgrep_kernel")
     return lines_from_flags(text, line_match, nl)

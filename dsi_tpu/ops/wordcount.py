@@ -45,6 +45,7 @@ with a bigger kernel or falls back to the host implementation
 from __future__ import annotations
 
 import functools
+import itertools
 import os
 from typing import Dict, Optional
 
@@ -53,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from dsi_tpu.obs import span as _span
 from dsi_tpu.utils.jaxcompat import enable_x64, x64_scoped
 
 _FNV_OFFSET = 0x811C9DC5
@@ -101,6 +103,7 @@ def build_lanes(chunk: jax.Array, length_all: jax.Array, max_word_len: int):
     return lanes
 
 
+@jax.named_scope("hash")
 def fnv1a32_packed(packed: jax.Array, lengths: jax.Array,
                    max_word_len: int) -> jax.Array:
     """FNV-1a 32-bit over the packed word bytes — bit-exact Go hash/fnv.New32a
@@ -158,6 +161,7 @@ def unpack_key_rows(rows64: jax.Array, k: int) -> jax.Array:
     return jnp.stack(cols, axis=1)
 
 
+@jax.named_scope("sort")
 def lex_sort(keys: tuple, payloads: tuple = ()) -> tuple:
     """Stable lexicographic sort of rows by ``keys`` (uint32 columns, most
     significant first), carrying ``payloads`` (columns of any dtype).
@@ -184,6 +188,7 @@ def lex_sort(keys: tuple, payloads: tuple = ()) -> tuple:
     return (*cols, *pays)
 
 
+@jax.named_scope("group")
 def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     """Group adjacent equal rows of lexicographically sorted key columns.
 
@@ -375,13 +380,16 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
     k = max_word_len // 4
     t_cap = n // t_cap_frac + 1
 
-    letter = is_ascii_letter(chunk)
-    prev_letter = jnp.concatenate([jnp.zeros((1,), jnp.bool_), letter[:-1]])
-    starts = letter & ~prev_letter
-    next_letter = jnp.concatenate([letter[1:], jnp.zeros((1,), jnp.bool_)])
-    ends = letter & ~next_letter
-    n_tokens = jnp.sum(starts, dtype=jnp.int32)
-    token_overflow = n_tokens > t_cap
+    with jax.named_scope("tokenize"):
+        letter = is_ascii_letter(chunk)
+        prev_letter = jnp.concatenate(
+            [jnp.zeros((1,), jnp.bool_), letter[:-1]])
+        starts = letter & ~prev_letter
+        next_letter = jnp.concatenate(
+            [letter[1:], jnp.zeros((1,), jnp.bool_)])
+        ends = letter & ~next_letter
+        n_tokens = jnp.sum(starts, dtype=jnp.int32)
+        token_overflow = n_tokens > t_cap
 
     # Compact to the token buffer.  Token lengths come from the paired
     # start/end compactions (runs cannot nest, so the i-th start matches
@@ -391,20 +399,23 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
     # array at ``start + 4j`` and are masked AFTER compaction: the same
     # k token-level gathers as before, but the byte-masking runs over
     # t_cap rows instead of building k masked full-chunk lane arrays.
-    (start_pos,) = jnp.nonzero(starts, size=t_cap, fill_value=n - 1)
-    (end_pos,) = jnp.nonzero(ends, size=t_cap, fill_value=n - 1)
-    valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
-    lengths = jnp.where(valid, end_pos - start_pos + 1, 0).astype(jnp.int32)
-    max_len = jnp.max(lengths, initial=0)
-    c = chunk.astype(jnp.uint32)
-    b32 = ((c << 24) | (_shift_left(c, 1) << 16)
-           | (_shift_left(c, 2) << 8) | _shift_left(c, 3))
-    packed_cols = tuple(
-        jnp.where(valid,
-                  b32[start_pos + 4 * j]
-                  & _byte_mask(jnp.clip(lengths - 4 * j, 0, 4)),
-                  jnp.uint32(_PAD_KEY))
-        for j in range(k))
+    with jax.named_scope("compact"):
+        (start_pos,) = jnp.nonzero(starts, size=t_cap, fill_value=n - 1)
+        (end_pos,) = jnp.nonzero(ends, size=t_cap, fill_value=n - 1)
+        valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
+        lengths = jnp.where(valid, end_pos - start_pos + 1,
+                            0).astype(jnp.int32)
+        max_len = jnp.max(lengths, initial=0)
+    with jax.named_scope("pack"):
+        c = chunk.astype(jnp.uint32)
+        b32 = ((c << 24) | (_shift_left(c, 1) << 16)
+               | (_shift_left(c, 2) << 8) | _shift_left(c, 3))
+        packed_cols = tuple(
+            jnp.where(valid,
+                      b32[start_pos + 4 * j]
+                      & _byte_mask(jnp.clip(lengths - 4 * j, 0, 4)),
+                      jnp.uint32(_PAD_KEY))
+            for j in range(k))
 
     if grouper == "hash":
         fnv_t = fnv1a32_packed(jnp.stack(packed_cols, axis=1), lengths,
@@ -424,10 +435,12 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
     *scols, slens = lex_sort(packed_cols, (lengths,))
     skeys, totals, upos, ovalid, n_unique = group_sorted(
         tuple(scols), jnp.ones(t_cap, jnp.int32), u_cap)
-    packed_u = jnp.where(ovalid[:, None], skeys[upos], jnp.uint32(0))
-    len_u = jnp.where(ovalid, slens[upos], 0)
+    with jax.named_scope("group"):
+        packed_u = jnp.where(ovalid[:, None], skeys[upos], jnp.uint32(0))
+        len_u = jnp.where(ovalid, slens[upos], 0)
     fnv_u = fnv1a32_packed(packed_u, len_u, max_word_len)
-    has_high = jnp.any(chunk >= 128)
+    with jax.named_scope("tokenize"):
+        has_high = jnp.any(chunk >= 128)
     return (packed_u, len_u, totals, fnv_u, n_unique, max_len, has_high,
             token_overflow)
 
@@ -593,29 +606,41 @@ def count_words_host_result(
     Returns None if and only if the text needs the host fallback (non-ASCII
     bytes, or words longer than 64 bytes); callers must test ``is None`` —
     letter-free input legitimately returns an empty dict."""
-    chunk = _pad_pow2(data)
-    dev_chunk = jnp.asarray(chunk)
+    with _span("materialize", lane="host", bytes=len(data)):
+        chunk = _pad_pow2(data)
+    with _span("upload", bytes=chunk.nbytes):
+        dev_chunk = jnp.asarray(chunk)
     groupers = grouper_ladder()
+    attempts = itertools.count()
 
     def run(mwl: int, cap: int):
         for g in groupers:
             for frac in (4, 2):  # exact token bound is n//2+1
-                (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
-                 tok_of) = run_count_kernel(dev_chunk, max_word_len=mwl,
-                                            u_cap=cap, t_cap_frac=frac,
-                                            grouper=g)
-                if not bool(tok_of):
+                # dispatch to the first blocking scalar read
+                with _span("kernel", program="wc_kernel" + grouper_suffix(g),
+                           attempt=next(attempts), cap=cap):
+                    (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len,
+                     has_high, tok_of) = run_count_kernel(
+                        dev_chunk, max_word_len=mwl, u_cap=cap,
+                        t_cap_frac=frac, grouper=g)
+                    overflow = bool(tok_of)
+                if not overflow:
                     break
-            if not bool(tok_of):
+            if not overflow:
                 break
         nu = int(n_unique)
 
         def payload():
-            words = decode_packed(np.asarray(packed_u), np.asarray(len_u), nu)
-            counts = np.asarray(cnt_u[:nu])
-            hashes = np.asarray(fnv_u[:nu]) & 0x7FFFFFFF
-            return {w: (int(counts[i]), int(hashes[i]))
-                    for i, w in enumerate(words)}
+            with _span("pull") as sp:
+                packed, lens = np.asarray(packed_u), np.asarray(len_u)
+                counts = np.asarray(cnt_u[:nu])
+                hashes = np.asarray(fnv_u[:nu]) & 0x7FFFFFFF
+                sp.set(bytes=packed.nbytes + lens.nbytes + counts.nbytes
+                       + hashes.nbytes)
+            with _span("decode", lane="host", records=nu):
+                words = decode_packed(packed, lens, nu)
+                return {w: (int(counts[i]), int(hashes[i]))
+                        for i, w in enumerate(words)}
 
         return bool(has_high), nu, int(max_len), payload
 

@@ -63,6 +63,7 @@ def default_mesh(n_devices: int | None = None) -> Mesh:
     return Mesh(np.array(devs), (AXIS,))
 
 
+@jax.named_scope("shuffle")
 def shuffle_rows(rows: jax.Array, dest: jax.Array, *, n_dev: int,
                  u_cap: int, k: int) -> jax.Array:
     """Route per-word rows to their destination devices over ICI.
@@ -93,6 +94,7 @@ def shuffle_rows(rows: jax.Array, dest: jax.Array, *, n_dev: int,
                           tiled=True)
 
 
+@jax.named_scope("map")
 def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
                  max_word_len: int, u_cap: int, t_cap_frac: int,
                  grouper: str = "sort"):
@@ -133,24 +135,28 @@ def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,
         u_cap=u_cap, t_cap_frac=t_cap_frac, grouper=grouper)
 
     # ── shuffle: the mr-X-Y files become one ICI collective ──
-    rows = jnp.concatenate(
-        [packed_u, len_u[:, None].astype(jnp.uint32),
-         cnt_u[:, None].astype(jnp.uint32), part[:, None]], axis=1)
+    with jax.named_scope("shuffle"):
+        rows = jnp.concatenate(
+            [packed_u, len_u[:, None].astype(jnp.uint32),
+             cnt_u[:, None].astype(jnp.uint32), part[:, None]], axis=1)
     recv = shuffle_rows(rows, dest, n_dev=n_dev, u_cap=u_cap, k=k)
 
     # ── reduce: sort received records by word, sum counts per run
     #    (shared grouping idiom, ops/wordcount.py lex_sort +
     #    group_sorted) ──
     out_cap = n_dev * u_cap
-    *scols, mlen, mcnt, mpart = lex_sort(
-        tuple(recv[:, j] for j in range(k)),
-        (recv[:, k], recv[:, k + 1], recv[:, k + 2]))
-    mkeys, tot, upos, ovalid, m_unique = group_sorted(
-        tuple(scols), mcnt.astype(jnp.int32), out_cap)
-    mlen = mlen.astype(jnp.int32)
-    out_keys = jnp.where(ovalid[:, None], mkeys[upos], jnp.uint32(0))
-    out_len = jnp.where(ovalid, mlen[upos], 0)
-    out_part = jnp.where(ovalid, mpart[upos], 0)
+    with jax.named_scope("reduce"):
+        *scols, mlen, mcnt, mpart = lex_sort(
+            tuple(recv[:, j] for j in range(k)),
+            (recv[:, k], recv[:, k + 1], recv[:, k + 2]))
+        mkeys, tot, upos, ovalid, m_unique = group_sorted(
+            tuple(scols), mcnt.astype(jnp.int32), out_cap)
+        with jax.named_scope("group"):
+            mlen = mlen.astype(jnp.int32)
+            out_keys = jnp.where(ovalid[:, None], mkeys[upos],
+                                 jnp.uint32(0))
+            out_len = jnp.where(ovalid, mlen[upos], 0)
+            out_part = jnp.where(ovalid, mpart[upos], 0)
 
     scalars = jnp.stack([m_unique, n_unique, max_len,
                          has_high.astype(jnp.int32),
@@ -222,11 +228,12 @@ def _slice_pack(keys, lens, cnts, parts, *, mp: int):
     ``mp`` is the pow2-rounded occupied prefix, so the bytes pulled track
     vocabulary, not capacity.  Lens/counts/partitions are uint32
     reinterpretations — all are small non-negative ints."""
-    return jnp.concatenate(
-        [keys[:, :mp],
-         lens[:, :mp, None].astype(jnp.uint32),
-         cnts[:, :mp, None].astype(jnp.uint32),
-         parts[:, :mp, None].astype(jnp.uint32)], axis=2)
+    with jax.named_scope("pack"):
+        return jnp.concatenate(
+            [keys[:, :mp],
+             lens[:, :mp, None].astype(jnp.uint32),
+             cnts[:, :mp, None].astype(jnp.uint32),
+             parts[:, :mp, None].astype(jnp.uint32)], axis=2)
 
 
 def shard_text(data: bytes, n_shards: int) -> Tuple[np.ndarray, int]:

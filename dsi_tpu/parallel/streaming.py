@@ -552,7 +552,10 @@ def wordcount_streaming(
     ``pipeline_stats``, if given, is a dict populated with per-phase wall
     seconds (``batch_s`` build time in the batcher, ``batch_wait_s`` main-
     thread starvation, ``upload_s``, ``kernel_s`` time blocked on step
-    flags, ``pull_s``, ``merge_s``, ``replay_s``) plus ``depth``,
+    flags (not device time), ``pull_s`` and its two parts
+    ``device_wait_s`` (blocked until the device has produced the step's
+    packed result) and ``d2h_s`` (the copy), ``merge_s``, ``replay_s``,
+    ``finalize_s`` the final merge into the result) plus ``depth``,
     ``steps``, ``replays``, ``max_inflight_chunks`` (peak device chunk
     buffers — bounded by ``depth``) and ``batch_allocs`` (host batch
     buffers ever allocated — O(depth), not O(steps), thanks to the pool).
@@ -690,8 +693,9 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                   "step_pulls": 0, "device_accumulate": device_accumulate,
                   "device_rows": [0] * n_dev,
                   "batch_s": 0.0, "batch_wait_s": 0.0, "upload_s": 0.0,
-                  "kernel_s": 0.0, "pull_s": 0.0, "merge_s": 0.0,
-                  "replay_s": 0.0})
+                  "kernel_s": 0.0, "pull_s": 0.0, "device_wait_s": 0.0,
+                  "d2h_s": 0.0, "merge_s": 0.0, "replay_s": 0.0,
+                  "finalize_s": 0.0})
     # Compressed chunk uploads (ops/wirecodec.py): encode host-side,
     # ship the packed tensor, decode on device as a map prologue.  Off
     # by default = bit-identical raw uploads; on, a batch the codec
@@ -938,7 +942,24 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                 return mapreduce_step_donate(chunks_dev, **kw)
             return mapreduce_step(chunks_dev, **kw)
 
-    def pull_packed(keys, lens, cnts, parts, scal_np):
+    def to_host(pack, in_pull: bool):
+        """One packed step result on the host, in two parts that one
+        ``np.asarray`` would lump: ``wait`` until the device has
+        produced it (the step program and its pack: device time the
+        window did not hide), then ``d2h``, the copy itself.  ``pack``
+        gives the device tensor (a pack not dispatched yet is
+        dispatched inside the wait).  On the per-step pull path
+        (``in_pull``) the two are also phase keys, the parts of
+        ``pull_s``; a replay's pull stays inside ``replay_s``."""
+        sink = stats if in_pull else None
+        with _span("wait", lane="pull", stats=sink, key="device_wait_s"):
+            dev = jax.block_until_ready(pack())
+        with _span("d2h", lane="pull", stats=sink, bytes=dev.nbytes):
+            packed = np.asarray(dev)
+        stats["pull_bytes"] = stats.get("pull_bytes", 0) + packed.nbytes
+        return packed
+
+    def pull_packed(keys, lens, cnts, parts, scal_np, in_pull=False):
         """One packed host tensor per step (the single-pull D2H shape,
         shuffle._slice_pack) + per-device occupied counts + key width.
         Under aot the prefix is the full capacity instead of the
@@ -949,11 +970,12 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
             return None, None, 0
         kk = keys.shape[2]
         if aot:
-            packed = np.asarray(_aot_pack(keys, lens, cnts, parts,
-                                          mp=keys.shape[1]))
+            packed = to_host(lambda: _aot_pack(
+                keys, lens, cnts, parts, mp=keys.shape[1]), in_pull)
         else:
             mp = occupied_prefix(m, keys.shape[1])
-            packed = np.asarray(_slice_pack(keys, lens, cnts, parts, mp=mp))
+            packed = to_host(lambda: _slice_pack(
+                keys, lens, cnts, parts, mp=mp), in_pull)
         return packed, scal_np[:, 0], kk
 
     def run_step_sync(chunks_np, device_payload: bool = False):
@@ -1097,9 +1119,11 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                     if int(scal_np[:, 0].max()) == 0:
                         packed, nus = None, None
                     elif packed_dev is not None:  # aot: pack already ran
-                        packed, nus = np.asarray(packed_dev), scal_np[:, 0]
+                        packed = to_host(lambda: packed_dev, True)
+                        nus = scal_np[:, 0]
                     else:
-                        packed, nus, kk = pull_packed(*tables, scal_np)
+                        packed, nus, kk = pull_packed(*tables, scal_np,
+                                                      in_pull=True)
                     if packed is not None:
                         stats["step_pulls"] += 1
                 with _span("merge", stats=stats, key="merge_s"):
@@ -1181,7 +1205,9 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         if ck_writer is not None:
             ck_writer.drain()  # surface async commit errors; counters
             # settle before the caller reads them
-        step.result = acc.finalize()
+        with _span("finalize", lane="host", stats=stats) as sp:
+            step.result = acc.finalize()
+            sp.set(keys=len(step.result))
 
     released = []
 
@@ -1195,7 +1221,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         if pipeline_stats is not None:
             stats["batch_allocs"] = pool.allocs
             for k in ("batch_s", "batch_wait_s", "upload_s", "kernel_s",
-                      "pull_s", "merge_s", "replay_s", "fold_s", "sync_s",
+                      "pull_s", "device_wait_s", "d2h_s", "merge_s",
+                      "replay_s", "finalize_s", "fold_s", "sync_s",
                       "widen_s", "ckpt_s", "ckpt_capture_s",
                       "ckpt_commit_s", "ckpt_barrier_s", "decode_s",
                       "ckpt_compress_s"):

@@ -24,6 +24,14 @@ Sections:
 * control     — requeue/fault/assign/complete/stall/aot_load event
                 digest and the per-worker heartbeat-age gauge, when
                 present;
+* tasks       — the batch plane's tasks (``worker.map``/
+                ``worker.reduce``) with what each spent in its child
+                spans (read, materialize, upload, kernel, pull, decode,
+                write) and
+                what no span covers;
+* launch      — the launch lane across the job's processes on one
+                clock: ``mrrun`` start, the chip probe, each spawn, each
+                worker's start, backend init and backend up;
 * shuffle     — the mesh-sharded fold lane (PR 7): fold-span wall,
                 ``shard_widens``/``shard_imbalance``/``pull_bytes``
                 counters and per-event hot-shard details;
@@ -109,6 +117,8 @@ def load(path: str):
             metas.append(meta)
         for e in evs:
             e["_file"] = os.path.basename(f)
+            # the epoch clock: what puts several processes on one axis
+            e["_wall"] = meta.get("wall0", 0.0) + e.get("ts", 0.0)
         events.extend(evs)
     return metas, events
 
@@ -199,7 +209,7 @@ def control(events, metas, out) -> None:
                                                     "stall"):
             extras = {k: v for k, v in e.items()
                       if k not in ("ph", "name", "lane", "ts", "dur",
-                                   "depth", "_file")}
+                                   "depth", "parent", "_file", "_wall")}
             tag = "STALL" if e["name"] == "stall" else e["name"]
             print(f"  {tag} @ {e.get('ts', 0):.3f}s: {extras}",
                   file=out)
@@ -218,9 +228,64 @@ def control(events, metas, out) -> None:
                       f"max={h.get('max_ms')}ms", file=out)
 
 
-def _span_totals(events, names) -> dict:
+_TASK_PARTS = ("read", "materialize", "upload", "kernel", "pull", "decode",
+               "write")
+
+
+def tasks(events, out) -> bool:
+    """The batch plane's tasks, and what a map consists of: seconds in
+    each kind of child span (direct children, by ``parent`` id within
+    the task's file) and the remainder no span covers."""
+    spans = [e for e in events if e.get("ph") == "X"]
+    rows = sorted((e for e in spans
+                   if e["name"] in ("worker.map", "worker.reduce")),
+                  key=lambda e: e["_wall"])
+    if not rows:
+        return False
+    # seconds per (file, parent span, part name), over direct children
+    part_s: dict = {}
+    for e in spans:
+        if e["name"] in _TASK_PARTS and e.get("parent") is not None:
+            key = (e["_file"], e["parent"], e["name"])
+            part_s[key] = part_s.get(key, 0.0) + e.get("dur", 0.0)
+    print(f"  {'task':<12} {'dur_s':>8} "
+          + " ".join(f"{p[:8]:>8}" for p in _TASK_PARTS)
+          + f" {'other':>8}  file", file=out)
+    for t in rows:
+        part = [part_s.get((t["_file"], t.get("id"), p), 0.0)
+                for p in _TASK_PARTS]
+        label = f"{t['name'].split('.')[-1]} {t.get('task', '?')}"
+        print(f"  {label:<12} {t.get('dur', 0.0):>8.3f} "
+              + " ".join(f"{s:>8.3f}" for s in part)
+              + f" {t.get('dur', 0.0) - sum(part):>8.3f}  {t['_file']}",
+              file=out)
+    return True
+
+
+def launch(events, out) -> bool:
+    """The launch lane of every process on the epoch clock, in seconds
+    since its first event."""
+    lane = sorted((e for e in events if e.get("lane") == "launch"),
+                  key=lambda e: e["_wall"])
+    if not lane:
+        return False
+    t0 = lane[0]["_wall"]
+    skip = ("ph", "name", "lane", "ts", "dur", "depth", "id", "parent",
+            "_file", "_wall")
+    for e in lane:
+        extras = " ".join(f"{k}={v}" for k, v in e.items()
+                          if k not in skip)
+        dur = f" dur={e['dur']:.3f}s" if e.get("ph") == "X" else ""
+        print(f"  +{e['_wall'] - t0:>8.3f}s {e['name']:<15}{dur} "
+              f"{extras}  [{e['_file']}]", file=out)
+    return True
+
+
+def _span_totals(events, names, lane=None) -> dict:
     tot: dict = {}
     for e in events:
+        if lane is not None and e.get("lane") != lane:
+            continue
         if e.get("ph") == "X" and e.get("name") in names:
             r = tot.setdefault(e["name"], [0.0, 0])
             r[0] += e.get("dur", 0.0)
@@ -257,7 +322,7 @@ def shuffle(events, metas, out) -> bool:
     for e in widens:
         extras = {k: v for k, v in e.items()
                   if k not in ("ph", "name", "lane", "ts", "dur",
-                               "depth", "_file")}
+                               "depth", "parent", "_file", "_wall")}
         print(f"  shard_widen @ {e.get('ts', 0):.3f}s: {extras}",
               file=out)
     return True
@@ -295,7 +360,9 @@ def wire(events, metas, out) -> bool:
     """The compressed-wire + parallel-ingest keys (ISSUE 13): decode
     span totals plus the codec/reader-pool counters from the phase
     dicts."""
-    tot = _span_totals(events, ("decode",))
+    # the codec's decode spans ride the upload lane; a map task's
+    # decode (host lane) is the tasks section's
+    tot = _span_totals(events, ("decode",), lane="upload")
     keys = ("wire_steps", "wire_raw_steps", "wire_packed_bytes",
             "wire_ratio", "decode_s", "ingest_readers", "ingest_blocks",
             "readahead_hit_pct", "ingest_wait_s", "ckpt_compress",
@@ -532,7 +599,9 @@ def main(argv=None) -> int:
     stragglers(events, out)
     import io
 
-    for title, fn in (("shuffle lane", lambda o: shuffle(events, metas, o)),
+    for title, fn in (("launch lane", lambda o: launch(events, o)),
+                      ("tasks", lambda o: tasks(events, o)),
+                      ("shuffle lane", lambda o: shuffle(events, metas, o)),
                       ("ckpt capture/commit", lambda o: ckpt(events, metas,
                                                              o)),
                       ("wire codec / ingest pool",
