@@ -18,9 +18,11 @@ on a parity failure.
 
 ``--backend tpu`` starts one device worker per chip (``cli/chips.py``) and
 runs the remaining ``--workers`` as reduce-only host helpers, so every map
-task runs on a device; respawned workers keep their slot's role.  This
-process never imports JAX: a parent that holds the chip starves its
-children.
+task runs on a device; respawned workers keep their slot's role.  A device
+worker that can claim no chip (missing, or held by another process) ends
+the job at once, non-zero, with its message on stderr: it is not
+respawned.  This process never imports JAX: a parent that holds the chip
+starves its children.
 """
 
 from __future__ import annotations
@@ -31,6 +33,7 @@ import subprocess
 import sys
 import time
 
+from dsi_tpu.cli.chips import chip_env, lost_chip, plan_device_workers
 from dsi_tpu.obs import configure_tracing, flush_tracing, trace_event
 
 
@@ -44,8 +47,6 @@ def _worker_fleet(args, app: str, env: dict):
     base = [sys.executable, "-m", "dsi_tpu.cli.mrworker", "--backend"]
     if args.backend != "tpu":
         return [(base + [args.backend, app], env)] * args.workers
-    from dsi_tpu.cli.chips import chip_env, plan_device_workers
-
     slots, n_chips = plan_device_workers(args.workers, env,
                                          "mrrun --backend tpu")
     fleet = []
@@ -173,8 +174,8 @@ def main(argv=None) -> int:
             except OSError:
                 pass
 
-    # Decided before anything is spawned: the chip-count probe child must
-    # exit before the first worker starts.
+    # Decided before anything is spawned: where a probe child counts the
+    # chips it must exit before the first worker starts.
     fleet = _worker_fleet(args, app, env)
 
     if args.replicas:
@@ -263,6 +264,9 @@ def main(argv=None) -> int:
             for i, w in enumerate(workers):
                 if (w.poll() is not None and w.returncode != 0
                         and coord.poll() is None):
+                    if lost_chip(w, "mrrun"):
+                        rc = 1
+                        break
                     lifetime = time.monotonic() - spawned_at[i]
                     if lifetime >= _INSTANT_S:
                         instant_streak, streak_code = 0, None
@@ -413,6 +417,9 @@ def _replica_job(args, workdir: str, files: list, fleet: list,
                 break
             for i, w in enumerate(workers):
                 if w.poll() is not None and w.returncode != 0:
+                    if lost_chip(w, "mrrun"):
+                        rc = 1
+                        break
                     if respawn_budget <= 0:
                         print("mrrun: workers failing repeatedly; "
                               "giving up", file=sys.stderr)
@@ -571,6 +578,9 @@ def _net_job(args, workdir: str, files: list, fleet: list,
             for i, w in list(procs.items()):
                 if w.poll() is not None and w.returncode != 0 \
                         and not coord.done():
+                    if lost_chip(w, "mrrun"):
+                        rc = 1
+                        break
                     if respawn_budget <= 0:
                         print("mrrun: workers failing repeatedly; "
                               "giving up", file=sys.stderr)

@@ -166,10 +166,10 @@ def main(argv=None) -> int:
                     files=len(files))
 
     # Every shard worker runs a device engine, so each needs a chip of
-    # its own (cli/chips.py; this process stays off JAX, and the probe
-    # child has exited before the first worker starts).  With the CPU
-    # asked for by name there is no chip to share and no limit.
-    from dsi_tpu.cli.chips import chip_env, plan_device_workers
+    # its own (cli/chips.py; this process stays off JAX, and counts the
+    # chips before the first worker starts).  With the CPU asked for by
+    # name there is no chip to share and no limit.
+    from dsi_tpu.cli.chips import chip_env, lost_chip, plan_device_workers
 
     slots, n_chips = plan_device_workers(args.workers, env, "shardrun")
     if None in slots:
@@ -419,6 +419,9 @@ def main(argv=None) -> int:
             for i, w in enumerate(workers):
                 if w.poll() is not None and w.returncode != 0 \
                         and not coord.done():
+                    if lost_chip(w, "shardrun"):
+                        rc = 1
+                        break
                     if respawn_budget <= 0:
                         print("shardrun: workers failing repeatedly; "
                               "giving up", file=sys.stderr)

@@ -15,13 +15,28 @@ entry point has one line to get right.
 from __future__ import annotations
 
 import os
+import sys
+
+# sysexits' EX_UNAVAILABLE.  A code of its own, so that a launcher can tell
+# "this worker could claim no chip" (respawning meets the same chip) from a
+# crash (respawn): cli/chips.lost_chip.
+NO_ACCELERATOR_EXIT = 69
 
 
 class NoAcceleratorError(SystemExit):
     """No TPU and the CPU was not asked for by name.  A ``SystemExit``
-    with a message: an entry point that does not catch it exits with
-    code 1 and the message on stderr — never a traceback, never a
-    result."""
+    whose code is ``NO_ACCELERATOR_EXIT``: an entry point that does not
+    catch it exits with that code — never a traceback, never a result.
+    :func:`require_device` writes the message to stderr as it raises."""
+
+    def __init__(self, message: str):
+        super().__init__(message)       # str(e) is the message
+        self.code = NO_ACCELERATOR_EXIT
+
+
+def _refuse(message: str) -> NoAcceleratorError:
+    print(message, file=sys.stderr, flush=True)
+    return NoAcceleratorError(message)
 
 
 def requested_platform(env=None) -> str:
@@ -52,14 +67,14 @@ def require_device(who: str = "dsi_tpu"):
     try:
         devices = jax.devices()
     except RuntimeError as e:
-        raise NoAcceleratorError(
+        raise _refuse(
             f"{who}: no TPU: JAX could not initialise a backend "
             f"({str(e).splitlines()[0][:200]}). One process may hold a "
             "chip at a time; set JAX_PLATFORMS=cpu to run the kernels "
             "on the CPU on purpose.") from None
     got = devices[0].platform
     if got != "tpu" and not (got == "cpu" and plat == "cpu"):
-        raise NoAcceleratorError(
+        raise _refuse(
             f"{who}: no TPU: JAX initialised platform {got!r} "
             f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r}). "
             "The chip is missing or held by another process; set "

@@ -2,7 +2,10 @@
 
 * ``utils/platformpin.require_device`` passes with the CPU named and raises
   when a non-TPU platform was not asked for;
-* ``cli/chips`` assigns at most one device process per chip;
+* ``cli/chips`` assigns at most one device process per chip, counts the
+  chips from the PCI bus where the bus can tell and from a probe child where
+  it cannot, and a launcher ends its job on a device worker that could
+  claim no chip instead of respawning it;
 * ``utils/compilecache`` honours ``JAX_COMPILATION_CACHE_DIR`` and otherwise
   gives the fixed ``<checkout>/.jaxcache``; a second process against the
   same directory compiles nothing;
@@ -15,6 +18,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -120,6 +124,165 @@ def test_chip_env_pins_only_on_a_multichip_host():
     assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
 
 
+# ── counting the chips without starting a runtime ──────────────────────
+
+_V5E = ("0x1ae0", "0x0063")
+_GVNIC = ("0x1ae0", "0x0042")      # Google's, and no TPU
+_OTHER = ("0x8086", "0x1237")
+# the chip tool's machines describe their one host like this
+_ONE_HOST = {"JAX_PLATFORMS": "tpu,cpu", "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+             "TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1", "TPU_HOST_BOUNDS": "1,1,1",
+             "TPU_RUNTIME_METRICS_PORTS": "8431,8432,8433,8434",
+             "TPU_SKIP_MDS_QUERY": "true", "TPU_TOPOLOGY": "2x2",
+             "TPU_TOPOLOGY_ALT": "false",
+             "TPU_TOPOLOGY_WRAP": "false,false,false",
+             "TPU_WORKER_HOSTNAMES": "localhost", "TPU_WORKER_ID": "0"}
+
+
+def _fake_machine(root, functions, given):
+    """A sysfs PCI tree with one IOMMU group per function, and a /dev/vfio
+    that holds the groups of the functions listed in ``given``."""
+    (root / "vfio").mkdir(parents=True)
+    (root / "vfio" / "vfio").write_text("")
+    for i, (vendor, device) in enumerate(functions):
+        d = root / "devices" / f"0000:00:{i:02x}.0"
+        d.mkdir(parents=True)
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "device").write_text(device + "\n")
+        group = root / "iommu_groups" / str(i)
+        group.mkdir(parents=True)
+        os.symlink(os.path.relpath(group, d), d / "iommu_group")
+        if given is None or i in given:
+            (root / "vfio" / str(i)).write_text("")
+    return str(root / "devices"), str(root / "vfio")
+
+
+@pytest.mark.parametrize("functions,given,env,want", [
+    ([_OTHER, _GVNIC], None, {}, None),         # 0 TPU functions: ask a child
+    ([_OTHER, _V5E], None, {}, 1),
+    ([_V5E, _OTHER, _V5E, _V5E, _V5E], None, {}, 4),
+    ([_GVNIC, _V5E, _GVNIC], None, {}, 1),      # a Google device, no TPU
+    ([_V5E] * 4, {2}, _ONE_HOST, 1),    # a shared host: one group is ours
+    ([_V5E] * 4, None, _ONE_HOST, 4),
+    ([_V5E] * 4, set(), {}, None),              # no group: another driver
+    ([_V5E] * 4, None, {"TPU_VISIBLE_CHIPS": "2"}, 1),
+    ([_V5E] * 4, None, dict(_ONE_HOST, TPU_VISIBLE_CHIPS="0"), 1),
+    ([_V5E] * 4, None, {"TPU_VISIBLE_CHIPS": "7"}, None),    # no such chip
+    # a longer list needs process bounds to start at all (measured: "0,1"
+    # alone fails on a four-chip v5e), so the child is asked
+    ([_V5E] * 4, None, {"TPU_VISIBLE_CHIPS": "1,3"}, None),
+    ([_V5E] * 4, None, {"TPU_VISIBLE_CHIPS": "all"}, None),
+    ([_V5E] * 4, None, {"TPU_PROCESS_BOUNDS": "2,2,1"}, None),
+    ([_V5E] * 4, None, {"CLOUD_TPU_TASK_ID": "1"}, None),
+    ([_V5E] * 4, None, dict(_ONE_HOST, TPU_HOST_BOUNDS="2,2,1"), None),
+    ([_V5E] * 4, None, dict(_ONE_HOST, TPU_WORKER_HOSTNAMES="a,b"), None),
+    ([_V5E] * 4, None, {"TPU_TOPOLOGY": "4x4"}, None),  # how many hosts?
+    ([_V5E] * 4, None, {"TPU_LOG_DIR": "disabled"}, 4),
+    ([_V5E] * 4, None, {"JAX_PLATFORMS": "cuda,tpu"}, None),
+    (None, None, {}, None),                                  # no sysfs
+])
+def test_bus_count_over_a_fake_sysfs_tree(tmp_path, functions, given, env,
+                                          want):
+    from dsi_tpu.cli.chips import count_chips_on_bus
+
+    if functions is None:
+        bus, vfio = str(tmp_path / "absent"), str(tmp_path / "absent")
+    else:
+        bus, vfio = _fake_machine(tmp_path, functions, given)
+    assert count_chips_on_bus(env, bus, vfio) == want
+
+
+@pytest.mark.parametrize("how,env,chips,on_bus,want", [
+    ("pci", {}, None, 4, ([0, 1, 2], 4)),
+    ("child", {}, None, None, ([0, 1, None], 2)),   # the bus cannot tell
+    ("given", {}, 1, 4, ([0, None, None], 1)),
+    ("cpu", {"JAX_PLATFORMS": "cpu"}, None, 4, ([0, 0, 0], 0)),
+])
+def test_plan_counts_from_the_bus_else_a_child_and_the_span_says_how(
+        tmp_path, monkeypatch, how, env, chips, on_bus, want):
+    import dsi_tpu.obs.trace as obs_trace
+    from dsi_tpu.cli import chips as chips_mod
+
+    tracer = obs_trace.Tracer(enabled=True, trace_dir=str(tmp_path / "tr"))
+    monkeypatch.setattr(obs_trace, "_global", tracer)
+    children = []
+    monkeypatch.setattr(chips_mod, "count_chips_on_bus", lambda env: on_bus)
+    monkeypatch.setattr(chips_mod, "probe_chip_count",
+                        lambda env: children.append(1) or 2)
+    try:
+        got = chips_mod.plan_device_workers(3, env, "t", chips=chips)
+        with open(tracer.flush()[0], encoding="utf-8") as f:
+            events = [json.loads(line) for line in f][1:]
+    finally:
+        tracer.enabled = False
+    assert got == want
+    assert len(children) == (1 if how == "child" else 0)
+    (probe,) = [e for e in events if e["name"] == "probe"]
+    assert probe["ph"] == "X" and probe["lane"] == "launch"
+    assert (probe["chips"], probe["how"]) == (want[1], how)
+
+
+# ── a device worker that can claim no chip ends the job ────────────────
+
+_HELD_CHIP_WORKER = (
+    "import sys\n"
+    "with open(sys.argv[1], 'a') as f:\n"
+    "    f.write('start\\n')\n"
+    "import jax\n"
+    "def held():\n"
+    "    raise RuntimeError(\"Unable to initialize backend 'tpu': \"\n"
+    "                       'ABORTED: the chip is held')\n"
+    "jax.devices = held\n"
+    "from dsi_tpu.utils.platformpin import require_device\n"
+    "require_device('stand-in device worker')\n"
+)
+
+
+def test_no_accelerator_exit_code_is_its_own(tmp_path):
+    from dsi_tpu.utils.platformpin import NO_ACCELERATOR_EXIT
+
+    p = subprocess.run(
+        [sys.executable, "-c", _HELD_CHIP_WORKER, str(tmp_path / "starts")],
+        env=dict(os.environ, PYTHONPATH=REPO), capture_output=True,
+        text=True, timeout=120)
+    assert p.returncode == NO_ACCELERATOR_EXIT not in (0, 1, 2)
+    assert "stand-in device worker: no TPU" in p.stderr
+    assert "the chip is held" in p.stderr and "Traceback" not in p.stderr
+
+
+@pytest.mark.parametrize("plane", ["plain", "net"])
+def test_mrrun_ends_on_a_worker_that_could_claim_no_chip(
+        tmp_path, monkeypatch, capfd, plane):
+    """The stand-in is the device worker of a one-chip fleet whose chip is
+    held: it fails in ``require_device`` as the real one does.  The job
+    ends non-zero in one worker start, with the worker's message, and the
+    slot is not respawned; the host helper beside it is stopped."""
+    from dsi_tpu.cli import mrrun
+
+    starts = tmp_path / "starts"
+    monkeypatch.setenv("PYTHONPATH", REPO)  # children run in the workdir
+    env = dict(os.environ)
+    fleet = [([sys.executable, "-c", _HELD_CHIP_WORKER, str(starts),
+               "--backend", "tpu"], env),
+             ([sys.executable, "-m", "dsi_tpu.cli.mrworker", "--backend",
+               "host", "wc"], dict(env, DSI_MR_REDUCE_ONLY="1"))]
+    monkeypatch.setattr(mrrun, "_worker_fleet", lambda *a: fleet)
+    monkeypatch.setenv("DSI_MR_SOCKET", str(tmp_path / "mr.sock"))
+    f = tmp_path / "in.txt"
+    f.write_text("a b c\n" * 100)
+    t0 = time.monotonic()
+    rc = mrrun.main(["--workers", "2", "--nreduce", "2", "--timeout", "120",
+                     "--workdir", str(tmp_path / "wd")]
+                    + (["--net"] if plane == "net" else [])
+                    + ["wc", str(f)])
+    assert rc != 0 and time.monotonic() - t0 < 60
+    assert starts.read_text() == "start\n"        # no respawn
+    err = capfd.readouterr().err
+    assert "stand-in device worker: no TPU" in err
+    assert "could claim no chip" in err and "without a respawn" in err
+    assert "failing repeatedly" not in err and "--timeout" not in err
+
+
 def test_mrrun_fleet_one_device_worker_then_reduce_only_helpers(monkeypatch):
     """On one chip: worker 0 is the device worker, the others are host
     helpers that decline map tasks; respawns reuse the slot's entry."""
@@ -127,6 +290,7 @@ def test_mrrun_fleet_one_device_worker_then_reduce_only_helpers(monkeypatch):
 
     from dsi_tpu.cli import chips, mrrun
 
+    monkeypatch.setattr(chips, "count_chips_on_bus", lambda env: None)
     monkeypatch.setattr(chips, "probe_chip_count", lambda env: 1)
     args = argparse.Namespace(backend="tpu", workers=3)
     fleet = mrrun._worker_fleet(args, "tpu_wc", {"X": "1"})
@@ -166,6 +330,7 @@ def test_shardrun_refuses_more_device_workers_than_chips(
 
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     monkeypatch.delenv("DSI_JAX_PLATFORM", raising=False)
+    monkeypatch.setattr(chips, "count_chips_on_bus", lambda env: None)
     monkeypatch.setattr(chips, "probe_chip_count", lambda env: 1)
     f = tmp_path / "in.txt"
     f.write_text("a b c\n" * 100)

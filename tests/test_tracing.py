@@ -168,7 +168,8 @@ def test_ids_parents_and_task_inheritance_across_a_thread(tmp_path):
     with t.span("upload"):
         pass
     head, recs = _jsonl(t.flush()[0])
-    assert head["wall0_ns"] // 10**6 == round(head["wall0"] * 1e3)
+    # wall0 is wall0_ns rounded to the millisecond (not floored)
+    assert abs(head["wall0_ns"] / 1e9 - head["wall0"]) <= 0.00051
     by = {r["name"]: r for r in recs}
     ids = [r["id"] for r in recs if r["ph"] == "X"]
     assert len(set(ids)) == len(ids) == 6
@@ -262,7 +263,8 @@ def test_mrrun_trace_dir_leaves_the_launchs_parts(traced_job):
     names = [e["name"] for e in launch]
     assert names[0] == "mrrun.start" and "coordinator_up" in names
     (probe,) = [e for e in launch if e["name"] == "probe"]
-    assert probe["ph"] == "X" and probe["chips"] == 0   # the CPU, by name
+    assert probe["ph"] == "X"
+    assert (probe["chips"], probe["how"]) == (0, "cpu")  # the CPU, by name
     spawned = {e["pid"]: e["role"] for e in launch if e["name"] == "spawn"}
     assert sorted(spawned.values()) == ["coordinator", "worker:tpu",
                                         "worker:tpu"]
