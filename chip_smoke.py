@@ -15,7 +15,10 @@ data size its users would call real, and checks every answer:
   parity against a per-file ``Counter`` over ``apps/wc.tokenize`` kept
   here, independent of the engine;
 * cache — ``wcstream`` over the first file, twice in two processes: the
-  second finds every program in the compile cache and compiles nothing.
+  second finds every program in the compile cache and compiles nothing;
+* grep stream — ``grepstream --pattern the --workdir --check`` over the
+  first file: the grep engine's step on the device, its result committed
+  as ``mr-out-0``, its own check against the host scan passed.
 
 This process never imports JAX: one process uses a chip at a time, and the
 children need it.  It learns the device from a probe child that exits
@@ -199,10 +202,10 @@ def batch_phase(app: str, files: list, root: str, device: dict,
     return res
 
 
-def _stats_dict(text: str, tag: str) -> dict:
-    m = re.search(rf"^wcstream: {tag}=(\{{.*\}})$", text, re.M)
+def _stats_dict(text: str, tag: str, prog: str = "wcstream") -> dict:
+    m = re.search(rf"^{prog}: {tag}=(\{{.*\}})$", text, re.M)
     if not m:
-        raise SmokeFailure(f"wcstream printed no {tag}\n" + text[-3000:])
+        raise SmokeFailure(f"{prog} printed no {tag}\n" + text[-3000:])
     return ast.literal_eval(m.group(1))
 
 
@@ -262,6 +265,35 @@ def stream_phase(name: str, files: list, root: str, device: dict,
     return res
 
 
+def grepstream_phase(files: list, root: str, timeout: float) -> dict:
+    """One small ``grepstream --workdir --check`` job for the literal
+    ``the``: the program's own check against its host scan decides parity;
+    here the commit and the device path are what is looked at."""
+    name = "grepstream"
+    wd = os.path.join(root, name)
+    cmd = [sys.executable, "-m", "dsi_tpu.cli.grepstream", "--pattern",
+           "the", "--stats", "--check", "--workdir", wd] + files
+    rc, wall, text = run_logged(cmd, child_env(),
+                                os.path.join(root, f"{name}.log"), timeout)
+    if rc != 0:
+        raise SmokeFailure(f"{name}: exit code {rc}\n" + text[-3000:])
+    if "needed the host path" in text:
+        raise SmokeFailure(f"{name}: the stream took the host path")
+    pstats = _stats_dict(text, "pipeline_stats", prog=name)
+    with open(os.path.join(wd, "mr-out-0"), encoding="ascii") as f:
+        records = dict(line.split(" ", 1) for line in f.read().splitlines())
+    res = {"phase": name, "bytes": sum(os.path.getsize(f) for f in files),
+           "wall_s": round(wall, 1), "steps": pstats["steps"],
+           "device_rows": pstats["device_rows"],
+           "lines": int(records["lines"]), "matched": int(records["matched"]),
+           "parity": "grepstream: parity OK" in text}
+    log(json.dumps(res))
+    if not res["parity"] or sum(res["device_rows"]) != res["lines"] \
+            or not res["lines"] or os.listdir(wd) != ["mr-out-0"]:
+        raise SmokeFailure(f"{name}: {res}, committed {os.listdir(wd)}")
+    return res
+
+
 def run_phases(device: dict, root: str, n_files: int = N_FILES,
                file_bytes: int = FILE_BYTES, batch_vocab: int = BATCH_VOCAB,
                vocab: int = VOCAB, repeats: int = REPEATS,
@@ -294,6 +326,7 @@ def run_phases(device: dict, root: str, n_files: int = N_FILES,
     if warm["cache"]["cache_misses"] or not warm["cache"]["cache_hits"]:
         raise SmokeFailure("the second wcstream process compiled: "
                            f"{warm['cache']} {warm['compile_s']}")
+    results.append(grepstream_phase(files[:1], root, timeout))
     results.append(stream_phase("wcstream", files * repeats, root, device,
                                 want, timeout))
     if device["count"] > 1:

@@ -8,6 +8,10 @@ every stream step runs ONE compiled literal-match program (the
 the pipeline's sticky-rung replay, and the result is the whole-stream
 match statistics: total/matched lines, occurrences, the per-line
 match-count histogram, and the exact top-k lines by occurrence count.
+``--workdir DIR`` commits them as ``DIR/mr-out-0`` (temp file + rename),
+one self-describing record per line: ``lines <n>``, ``matched <n>``,
+``occurrences <n>``, ``hist <bucket> <n>`` per histogram bucket,
+``top <rank> <line_no> <occurrences>`` per winner (rank 0 first).
 ``--device-accumulate`` keeps the histogram and the top-k candidate
 table ON DEVICE (``dsi_tpu/device/topk.py``), pulling every
 ``--sync-every`` steps instead of every step.
@@ -21,7 +25,7 @@ Usage:
         [--devices D] [--pipeline-depth D] [--device-accumulate]
         [--sync-every K] [--checkpoint-dir DIR] [--checkpoint-every K]
         [--ckpt-async] [--ckpt-delta]
-        [--resume] [--topk K] [--aot] [--stats] [--check]
+        [--resume] [--topk K] [--workdir DIR] [--aot] [--stats] [--check]
         inputfiles...
 """
 
@@ -37,6 +41,16 @@ def _positive_int(s: str) -> int:
     if v < 1:
         raise argparse.ArgumentTypeError(f"must be >= 1, got {v}")
     return v
+
+
+def result_records(res) -> list:
+    """A job's committed output, one record per line of ``mr-out-0``:
+    every record names what it holds, so sorted lines lose nothing."""
+    return ([f"lines {res.lines}", f"matched {res.matched}",
+             f"occurrences {res.occurrences}"]
+            + [f"hist {b} {n}" for b, n in enumerate(res.hist)]
+            + [f"top {rank} {line_no} {occ}"
+               for rank, (line_no, occ) in enumerate(res.topk)])
 
 
 def main(argv=None) -> int:
@@ -86,6 +100,9 @@ def main(argv=None) -> int:
                         "an uninterrupted run")
     p.add_argument("--topk", type=_positive_int, default=16,
                    help="top-k lines by occurrence count to report")
+    p.add_argument("--workdir", default=None,
+                   help="commit the result as DIR/mr-out-0 (temp file + "
+                        "rename; default: print only)")
     p.add_argument("--aot", action="store_true",
                    help="compile the device services explicitly at "
                         "full-capacity shapes (the step programs always "
@@ -171,12 +188,6 @@ def main(argv=None) -> int:
         print("grepstream: --resume found no usable checkpoint in "
               f"{args.checkpoint_dir}; started from scratch",
               file=sys.stderr)
-    if args.stats:
-        print(f"grepstream: pipeline_stats={pstats}", file=sys.stderr)
-    if args.trace_dir:
-        from dsi_tpu.obs import flush_tracing_report
-
-        flush_tracing_report(args.trace_dir, "grepstream")
     host_path = res is None
     if host_path:
         try:
@@ -188,6 +199,28 @@ def main(argv=None) -> int:
             return 1
         print("grepstream: stream needed the host path; ran the host scan",
               file=sys.stderr)
+
+    if args.workdir:
+        from dsi_tpu.obs import span
+        from dsi_tpu.utils.atomicio import atomic_write
+
+        os.makedirs(args.workdir, exist_ok=True)
+        records = result_records(res)
+        with span("write", lane="host", stats=pstats,
+                  records=len(records)) as sp:
+            path = os.path.join(args.workdir, "mr-out-0")
+            with atomic_write(path) as f:
+                f.write("".join(r + "\n" for r in records))
+            sp.set(bytes=os.path.getsize(path))
+        pstats["write_s"] = round(pstats["write_s"], 4)
+    # After the write, so that the line holds the job's tail too
+    # (finalize_s, write_s) and the trace its last span.
+    if args.stats:
+        print(f"grepstream: pipeline_stats={pstats}", file=sys.stderr)
+    if args.trace_dir:
+        from dsi_tpu.obs import flush_tracing_report
+
+        flush_tracing_report(args.trace_dir, "grepstream")
 
     print(f"lines={res.lines} matched={res.matched} "
           f"occurrences={res.occurrences}")

@@ -254,60 +254,66 @@ def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
     dlen0 = dlen.reshape(())
     base0 = base.reshape(())
 
-    match = jnp.ones(n, jnp.bool_)
-    for j in range(m):  # static unroll over the (short) pattern
-        match &= _shift_left(chunk, j) == pat[j]
+    with jax.named_scope("match"):
+        match = jnp.ones(n, jnp.bool_)
+        for j in range(m):  # static unroll over the (short) pattern
+            match &= _shift_left(chunk, j) == pat[j]
 
-    pos = jnp.arange(n, dtype=jnp.int32)
-    valid = pos < dlen0
-    is_nl = (chunk == 10) & valid
-    nl_i32 = is_nl.astype(jnp.int32)
-    line_id = jnp.cumsum(nl_i32) - nl_i32  # newlines strictly before i
-    nl_total = jnp.sum(nl_i32)
-    last = jnp.where(dlen0 > 0, chunk[jnp.maximum(dlen0 - 1, 0)],
-                     jnp.uint8(10))
-    n_lines = nl_total + jnp.where((dlen0 > 0) & (last != 10), 1, 0)
-    overflow = n_lines > l_cap
+    with jax.named_scope("line_ids"):
+        pos = jnp.arange(n, dtype=jnp.int32)
+        valid = pos < dlen0
+        is_nl = (chunk == 10) & valid
+        nl_i32 = is_nl.astype(jnp.int32)
+        line_id = jnp.cumsum(nl_i32) - nl_i32  # newlines strictly before i
+        nl_total = jnp.sum(nl_i32)
+        last = jnp.where(dlen0 > 0, chunk[jnp.maximum(dlen0 - 1, 0)],
+                         jnp.uint8(10))
+        n_lines = nl_total + jnp.where((dlen0 > 0) & (last != 10), 1, 0)
+        overflow = n_lines > l_cap
 
     # Padding bytes are zeros and the pattern is printable ASCII, so a
     # match can neither start in nor extend into padding; occurrences
     # therefore attribute to real lines only.
-    seg = jnp.minimum(line_id, l_cap)
-    occ = jax.ops.segment_sum(match.astype(jnp.int32), seg,
-                              num_segments=l_cap + 1,
-                              indices_are_sorted=True)[:l_cap]
-    lrange = jnp.arange(l_cap, dtype=jnp.int32)
-    line_valid = lrange < n_lines
-    occv = jnp.where(line_valid, occ, 0)
-    matched = jnp.sum((occv > 0).astype(jnp.int32))
-    occurrences = jnp.sum(occv)
+    with jax.named_scope("line_occ"):
+        seg = jnp.minimum(line_id, l_cap)
+        occ = jax.ops.segment_sum(match.astype(jnp.int32), seg,
+                                  num_segments=l_cap + 1,
+                                  indices_are_sorted=True)[:l_cap]
+        lrange = jnp.arange(l_cap, dtype=jnp.int32)
+        line_valid = lrange < n_lines
+        occv = jnp.where(line_valid, occ, 0)
+        matched = jnp.sum((occv > 0).astype(jnp.int32))
+        occurrences = jnp.sum(occv)
 
-    bucket = jnp.where(line_valid, jnp.minimum(occv, bins - 1), bins)
-    hist = jax.ops.segment_sum(jnp.ones(l_cap, jnp.uint32), bucket,
-                               num_segments=bins + 1)[:bins]
-    hist_ext = jnp.concatenate(
-        [hist, jnp.stack([n_lines, matched, occurrences]).astype(jnp.uint32)])
+    with jax.named_scope("hist"):
+        bucket = jnp.where(line_valid, jnp.minimum(occv, bins - 1), bins)
+        hist = jax.ops.segment_sum(jnp.ones(l_cap, jnp.uint32), bucket,
+                                   num_segments=bins + 1)[:bins]
+        hist_ext = jnp.concatenate(
+            [hist,
+             jnp.stack([n_lines, matched, occurrences]).astype(jnp.uint32)])
 
     # Top-k candidates among matched lines, (count desc, line asc): the
     # per-device pruning that keeps candidate folds k rows per step.
-    is_cand = line_valid & (occ > 0)
-    big = jnp.int32(0x7FFFFFFF)
-    neg = jnp.where(is_cand, big - occv, big)
-    sneg, slid = lax.sort((neg, lrange), num_keys=2)
-    top_occ = jnp.where(sneg[:k] < big, big - sneg[:k], 0)
-    top_lid = slid[:k]
-    n_cand = jnp.minimum(matched, k)
-    cvalid = jnp.arange(k, dtype=jnp.int32) < n_cand
-    with enable_x64(True):
-        gline = base0 + top_lid.astype(jnp.uint64)
-        hi = jnp.where(cvalid, (gline >> 32).astype(jnp.uint32),
-                       jnp.uint32(0))
-        lo = jnp.where(cvalid, gline.astype(jnp.uint32), jnp.uint32(0))
-    cand = jnp.stack(
-        [hi, lo,
-         jnp.where(cvalid, jnp.uint32(8), jnp.uint32(0)),
-         jnp.where(cvalid, top_occ.astype(jnp.uint32), jnp.uint32(0)),
-         jnp.zeros(k, jnp.uint32)], axis=1)
+    with jax.named_scope("topk"):
+        is_cand = line_valid & (occ > 0)
+        big = jnp.int32(0x7FFFFFFF)
+        neg = jnp.where(is_cand, big - occv, big)
+        sneg, slid = lax.sort((neg, lrange), num_keys=2)
+        top_occ = jnp.where(sneg[:k] < big, big - sneg[:k], 0)
+        top_lid = slid[:k]
+        n_cand = jnp.minimum(matched, k)
+        cvalid = jnp.arange(k, dtype=jnp.int32) < n_cand
+        with enable_x64(True):
+            gline = base0 + top_lid.astype(jnp.uint64)
+            hi = jnp.where(cvalid, (gline >> 32).astype(jnp.uint32),
+                           jnp.uint32(0))
+            lo = jnp.where(cvalid, gline.astype(jnp.uint32), jnp.uint32(0))
+        cand = jnp.stack(
+            [hi, lo,
+             jnp.where(cvalid, jnp.uint32(8), jnp.uint32(0)),
+             jnp.where(cvalid, top_occ.astype(jnp.uint32), jnp.uint32(0)),
+             jnp.zeros(k, jnp.uint32)], axis=1)
 
     # Pin to int32: under the x64-scoped compile, literal-int promotion
     # would widen these to int64 and drift off the struct-warmed fold
@@ -358,6 +364,9 @@ def _grep_program(*, n_dev: int, chunk_bytes: int, m: int, l_cap: int,
         return _grep_step_impl(chunks, pats, lens, bases, l_cap=l_cap,
                                bins=bins, k=k, mesh=mesh, emit=emit)
 
+    # The HLO module takes the traced function's name: a device trace
+    # then shows ``jit_grep_stream_step`` and not a ``jit_fn`` among others.
+    fn.__name__ = fn.__qualname__ = "grep_stream_step"
     name = (f"grep_stream_d{n_dev}_c{chunk_bytes}_m{m}_l{l_cap}"
             f"_b{bins}_t{k}" + ("_em" if emit else ""))
     return name, fn
@@ -555,9 +564,11 @@ def grep_streaming(
     Results stay bit-identical.
 
     ``pipeline_stats`` mirrors ``wordcount_streaming``'s dict
-    (``batch_s``/``batch_wait_s``/``upload_s``/``kernel_s``/``pull_s``/
-    ``merge_s``/``replay_s``, ``steps``/``replays``/``step_pulls``/
-    ``sync_pulls``/``l_cap`` plus the service counters).
+    (``batch_s``/``batch_wait_s``/``upload_s``/``kernel_s``/``pull_s``
+    with its parts ``device_wait_s`` + ``d2h_s``/``merge_s``/
+    ``replay_s``/``finalize_s``, ``steps``/``replays``/``step_pulls``/
+    ``sync_pulls``/``l_cap``/``pull_bytes``, ``device_rows`` = confirmed
+    lines per device, plus the service counters).
 
     ``checkpoint_dir``/``checkpoint_every``/``resume`` follow the
     ``wordcount_streaming`` crash-resume contract (``dsi_tpu/ckpt``):
@@ -616,6 +627,7 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
                   "device_accumulate": device_accumulate,
                   "l_cap": rungs[0], "batch_s": 0.0, "batch_wait_s": 0.0,
                   "upload_s": 0.0, "kernel_s": 0.0, "pull_s": 0.0,
+                  "device_wait_s": 0.0, "d2h_s": 0.0, "pull_bytes": 0,
                   "merge_s": 0.0, "replay_s": 0.0})
     sh2 = NamedSharding(mesh, P(AXIS, None))
     sh1 = NamedSharding(mesh, P(AXIS))
@@ -624,6 +636,7 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
     pat_dev = jax.device_put(pat_np, sh2)  # once per stream, never donated
     pool = BufferPool((n_dev, chunk_bytes), retain=2 * depth + 3)
     next_line = [0]
+    dev_lines = np.zeros(n_dev, dtype=np.int64)  # confirmed, per device
 
     # Host-merge accumulators (the depth=1-equivalent path).
     hist_h = np.zeros(bins, dtype=np.int64)
@@ -897,6 +910,7 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             raise RuntimeError(
                 f"host/device line-count disagreement: "
                 f"{row_lines.tolist()} vs {scal_np[:, 1].tolist()}")
+        dev_lines[:] += row_lines
         if device_accumulate:
             hist_svc.fold(hist_d)
             if int(scal_np[:, 0].max()) > 0:
@@ -910,18 +924,27 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
                 policy.reset()
         else:
             with _span("pull", stats=stats, key="pull_s"):
-                hist_np = np.asarray(hist_d)
-                cand_np = np.asarray(cand_d)
+                # The parts ``streaming.to_host`` has: ``wait`` until
+                # the device has produced what the next lines convert,
+                # then ``d2h``, the copies themselves.
+                with _span("wait", lane="pull", stats=stats,
+                           key="device_wait_s"):
+                    jax.block_until_ready((hist_d, cand_d))
+                with _span("d2h", lane="pull", stats=stats,
+                           bytes=hist_d.nbytes + cand_d.nbytes):
+                    hist_np = np.asarray(hist_d)
+                    cand_np = np.asarray(cand_d)
+                stats["pull_bytes"] += hist_np.nbytes + cand_np.nbytes
                 stats["step_pulls"] += 1
             with _span("merge", stats=stats, key="merge_s"):
                 hist_h[:] += hist_np[:, :bins].astype(np.int64).sum(axis=0)
                 totals[:] += hist_np[:, bins:].astype(np.int64).sum(axis=0)
-                for d in range(n_dev):
-                    nc = int(scal_np[d, 0])
-                    for i in range(nc):
-                        line = (int(cand_np[d, i, 0]) << 32) | int(
-                            cand_np[d, i, 1])
-                        cand_h.append((line, int(cand_np[d, i, 3])))
+                # Candidate rows in (device, rank) order, each device's
+                # first ``n_cand``: (hi << 32 | lo, occurrences).
+                live = np.arange(cand_np.shape[1]) < scal_np[:, :1]
+                rows = cand_np[live].astype(np.int64)
+                cand_h.extend(zip(((rows[:, 0] << 32) | rows[:, 1]).tolist(),
+                                  rows[:, 3].tolist()))
         if emit:
             # The stage handoff: this confirmed step's compacted
             # matching-line bytes flow into the relay — device-resident
@@ -973,9 +996,11 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
         if ck_writer is not None:
             ck_writer.drain()  # surface async commit errors; counters
             # settle before the caller reads them
-        top = tuple(sorted(cands, key=lambda r: (-r[1], r[0]))[:topk])
-        step.result = GrepStreamResult(int(t[0]), int(t[1]), int(t[2]),
-                                       tuple(int(x) for x in h), top)
+        with _span("finalize", lane="host", stats=stats,
+                   cands=len(cands)):
+            step.result = GrepStreamResult(int(t[0]), int(t[1]), int(t[2]),
+                                           tuple(int(x) for x in h),
+                                           merge_topk(cands, topk))
 
     released = []
 
@@ -988,8 +1013,10 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
         fold_source_stats(stats, blocks)
         if pipeline_stats is not None:
             stats["batch_allocs"] = pool.allocs
+            stats["device_rows"] = dev_lines.tolist()
             for k in ("batch_s", "batch_wait_s", "upload_s", "kernel_s",
-                      "pull_s", "merge_s", "replay_s", "fold_s", "sync_s",
+                      "pull_s", "device_wait_s", "d2h_s", "merge_s",
+                      "replay_s", "finalize_s", "fold_s", "sync_s",
                       "widen_s", "hist_s", "ckpt_s", "ckpt_capture_s",
                       "ckpt_commit_s", "ckpt_barrier_s",
                       "ckpt_compress_s"):

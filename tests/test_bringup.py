@@ -476,8 +476,8 @@ def test_chip_smoke_phases_dry_run_on_named_cpu(tmp_path, monkeypatch):
                                min_distinct=10_000, timeout=300.0)
     names = [r["phase"] for r in results]
     assert names == ["mrrun tpu_wc", "mrrun tpu_grep", "mrrun tpu_indexer",
-                     "wcstream-cold", "wcstream-warm", "wcstream",
-                     "wcstream-mesh"]
+                     "wcstream-cold", "wcstream-warm", "grepstream",
+                     "wcstream", "wcstream-mesh"]
     assert all(r["parity"] for r in results)
     assert all(r["host_maps"] == 0 and r["device_maps"] >= 2
                for r in results[:3])
