@@ -15,11 +15,14 @@ engines honor bit-identically:
   ``aotcache.cached_compile(donate_argnums)``,
 * per-step scalar checks DEFERRED until a step leaves the window, with
   exactly-once replay at sticky rungs — for grep that rung is the
-  ``l_cap`` line-capacity ladder (``ops/grepk.line_cap_rungs``): the
-  kernel's former host-fallback escalation folded into the pipeline's
-  replay protocol, so a short-line stream replays one step at the wider
-  compiled shape and the shape sticks, instead of abandoning the device
-  path,
+  ``l_cap`` line-capacity ladder (``ops/grepk.line_cap_rungs``): a step
+  whose line count passes the rung raises a flag, replays at the wider
+  compiled shape, and the shape sticks.  Since PR 27 the step program
+  keeps no per-line buffer (below), so the ladder guards nothing: the
+  counts of an overflowing step are already exact.  The flag, the second
+  compiled rung and the replay stay because ``serve/pack.py``, the
+  warmers, the program names and their tests are built on them
+  (ROADMAP, Design),
 * cross-step state on device via ``dsi_tpu/device/``: grep folds
   per-line match-count histograms (:class:`DeviceHistogram`) and top-k
   match candidates (:class:`DeviceTopK`), the indexer appends postings
@@ -39,6 +42,19 @@ the earlier line).  Per-(step, device) top-k candidate pruning on device
 is EXACT: a line in the global top-k is necessarily in the top-k of its
 own step and device under the same (count desc, line asc) order, so the
 pruned candidate multiset always contains the global winners.
+
+How the step counts (``_grep_step_device``): every per-line statistic is
+read at the line-END positions of the dense ``[chunk_bytes]`` arrays and
+never scattered into line slots.  With ``M`` the running sum of the
+match flags, the line that ends at ``p`` holds ``M[p] - M[q]``
+occurrences, ``q`` the newline before it, and because ``M`` is monotone
+``M[q]`` is a running maximum of ``M`` over the newlines: one cumsum and
+one cummax.  Histogram buckets and totals are masked sums over the line
+ends; the top-k orders the line ends by (count desc, position asc),
+which is (count desc, line asc), and reads the winners' line numbers
+from the newline cumsum; the emit variant carries a line's count back
+over its bytes with one reverse scan.  The cost depends on the chunk
+size alone, not on line length, match density or ``l_cap``.
 
 Indexer semantics: documents are processed in waves of ``n_dev`` (one
 per device, ``plan_waves`` sizing), the posting step is the word-count
@@ -233,12 +249,39 @@ def batch_lines(blocks: Iterable[bytes], n_dev: int, chunk_bytes: int,
 # ── the grep step program ──────────────────────────────────────────────
 
 
+#: Row width of the step's two-stage top-k (below).
+_TOPK_ROW = 1024
+
+
+def _top_positions(vals, k: int):
+    """``(values, positions)`` of the ``k`` largest of the non-negative
+    ``vals``, equal values at the lower position first: exact, in two
+    stages.  ``lax.top_k`` over ``[n]`` lowers to one stable sort of all
+    ``n`` rows on the TPU (1.0 ms at 2^20); over a ``[n / 1024, 1024]``
+    view it sorts rows of 1,024 (0.14 ms) and leaves ``k`` candidates a
+    row, whose row-major order is still (value desc, position asc)
+    within a row and position asc across rows, so a second ``top_k``
+    over them keeps the tie rule.  A global winner is among the first
+    ``k`` of its own row, so nothing is lost.  (My chip runs, PR 27.)"""
+    n = vals.shape[0]
+    rows = -(-n // _TOPK_ROW)
+    # vals >= 0: the -1 padding loses to every real position
+    grid = jnp.pad(vals, (0, rows * _TOPK_ROW - n), constant_values=-1) \
+        .reshape(rows, _TOPK_ROW)
+    row_val, row_col = lax.top_k(grid, min(k, _TOPK_ROW))
+    row_pos = row_col + (jnp.arange(rows, dtype=jnp.int32)
+                         * _TOPK_ROW)[:, None]
+    top_val, top_at = lax.top_k(row_val.reshape(-1), k)
+    return top_val, jnp.take(row_pos.reshape(-1), top_at)
+
+
 def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
                       k: int, emit: bool = False):
     """Per-device step body (runs under shard_map): literal match mask
     (``len(pattern)`` shifted compares, the ``ops/grepk.py`` idiom) →
-    per-line occurrence counts (cumsum line ids + segment-sum) →
-    histogram, totals, and the top-k candidate rows in DeviceTable's
+    per-line occurrence counts (two scans differenced at the line ends,
+    the module docs) → histogram, totals, and the top-k candidate rows
+    in DeviceTable's
     packed (key lanes, len, count, part) layout with the GLOBAL line
     number (``base`` + local) as the kk=2 key.
 
@@ -272,36 +315,42 @@ def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
         overflow = n_lines > l_cap
 
     # Padding bytes are zeros and the pattern is printable ASCII, so a
-    # match can neither start in nor extend into padding; occurrences
-    # therefore attribute to real lines only.
+    # match can neither start in nor extend into padding, nor sit on a
+    # newline; occurrences therefore attribute to real lines only.
+    #
+    # Per-line counts live at the line ENDS of the dense [n] arrays: a
+    # line ends at every valid newline and, where the last valid byte is
+    # not one, at dlen - 1.  With M the running match count, the line
+    # that ends at p holds M[p] - M[q] occurrences, q the newline before
+    # it; M is monotone, so M[q] is the running maximum of M over the
+    # newlines strictly before p.  Two scans, no per-line buffer: the
+    # counts are exact whatever ``l_cap`` says.
     with jax.named_scope("line_occ"):
-        seg = jnp.minimum(line_id, l_cap)
-        occ = jax.ops.segment_sum(match.astype(jnp.int32), seg,
-                                  num_segments=l_cap + 1,
-                                  indices_are_sorted=True)[:l_cap]
-        lrange = jnp.arange(l_cap, dtype=jnp.int32)
-        line_valid = lrange < n_lines
-        occv = jnp.where(line_valid, occ, 0)
-        matched = jnp.sum((occv > 0).astype(jnp.int32))
-        occurrences = jnp.sum(occv)
+        is_end = is_nl | (pos == dlen0 - 1)
+        run = jnp.cumsum(match.astype(jnp.int32))
+        at_nl = jnp.where(is_nl, run, 0)
+        prev = lax.cummax(jnp.pad(at_nl[:-1], (1, 0)))  # strictly before
+        occ = jnp.where(is_end, run - prev, 0)
+        matched = jnp.sum((occ > 0).astype(jnp.int32))
+        occurrences = jnp.sum(occ)
 
     with jax.named_scope("hist"):
-        bucket = jnp.where(line_valid, jnp.minimum(occv, bins - 1), bins)
-        hist = jax.ops.segment_sum(jnp.ones(l_cap, jnp.uint32), bucket,
-                                   num_segments=bins + 1)[:bins]
+        bucket = jnp.where(is_end, jnp.minimum(occ, bins - 1), bins)
+        hist = jnp.sum(
+            bucket[None, :] == jnp.arange(bins, dtype=jnp.int32)[:, None],
+            axis=1, dtype=jnp.uint32)
         hist_ext = jnp.concatenate(
             [hist,
              jnp.stack([n_lines, matched, occurrences]).astype(jnp.uint32)])
 
     # Top-k candidates among matched lines, (count desc, line asc): the
     # per-device pruning that keeps candidate folds k rows per step.
+    # Line numbers rise with position, so the order over line ends is
+    # (count desc, position asc), and the winners' line numbers are
+    # read from ``line_id`` at their positions.
     with jax.named_scope("topk"):
-        is_cand = line_valid & (occ > 0)
-        big = jnp.int32(0x7FFFFFFF)
-        neg = jnp.where(is_cand, big - occv, big)
-        sneg, slid = lax.sort((neg, lrange), num_keys=2)
-        top_occ = jnp.where(sneg[:k] < big, big - sneg[:k], 0)
-        top_lid = slid[:k]
+        top_occ, top_pos = _top_positions(occ, k)
+        top_lid = jnp.take(line_id, top_pos)
         n_cand = jnp.minimum(matched, k)
         cvalid = jnp.arange(k, dtype=jnp.int32) < n_cand
         with enable_x64(True):
@@ -323,17 +372,20 @@ def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
     if not emit:
         return hist_ext[None], cand[None], scal[None]
     # Matching-line compaction: keep every byte whose line matched (the
-    # terminating newline included — a newline at position i has
-    # line_id == its own line's id), stable-partition kept bytes to the
-    # front (sort by (dropped, position) — order-preserving), zero the
-    # tail.  Rows past l_cap attribute arbitrarily, but such a step
-    # raises the overflow flag and replays wider before confirmation,
-    # so a confirmed emit is always exact.
-    keep = valid & (jnp.take(occv, jnp.minimum(line_id, l_cap - 1)) > 0)
-    keep_inv = jnp.where(keep, jnp.int32(0), jnp.int32(1))
-    _, _, comp = lax.sort((keep_inv, pos, chunk), num_keys=2)
-    kept_n = jnp.sum(keep.astype(jnp.int32))
-    comp = jnp.where(pos < kept_n, comp, 0)
+    # terminating newline included — it is its own line's end).  A
+    # byte's line ends at the first line end at or after it; M there is
+    # the minimum of M over the line ends from the byte on (M is
+    # monotone), one reverse scan, and ``prev`` is already M at the
+    # newline before the byte.  Stable-partition kept bytes to the front
+    # (sort by (dropped, position) — order-preserving), zero the tail.
+    with jax.named_scope("emit"):
+        big = jnp.int32(0x7FFFFFFF)
+        at_end = lax.cummin(jnp.where(is_end, run, big), reverse=True)
+        keep = valid & (at_end > prev)
+        keep_inv = jnp.where(keep, jnp.int32(0), jnp.int32(1))
+        _, _, comp = lax.sort((keep_inv, pos, chunk), num_keys=2)
+        kept_n = jnp.sum(keep.astype(jnp.int32))
+        comp = jnp.where(pos < kept_n, comp, 0)
     return (hist_ext[None], cand[None], scal[None], comp[None],
             kept_n.reshape(1))
 

@@ -9,6 +9,7 @@ order for the indexer), so any divergence is an engine/service bug,
 never a tolerance.
 """
 
+import functools
 import re
 
 import pytest
@@ -24,9 +25,12 @@ from dsi_tpu.parallel.grepstream import (
     grep_streaming,
     indexer_streaming,
     write_indexer_output,
+    _grep_step_device,
     _LineTooLong,
+    _top_positions,
 )
 from dsi_tpu.parallel.shuffle import default_mesh
+from dsi_tpu.utils.jaxcompat import enable_x64
 
 WORDS = re.compile(r"[A-Za-z]+")
 
@@ -129,6 +133,107 @@ def test_grep_host_path_rejections():
     # empty stream: zeros, not None
     res = grep_streaming([], "the", mesh=mesh, chunk_bytes=1 << 11)
     assert res.lines == 0 and res.matched == 0 and res.topk == ()
+
+
+# ── grep: the step body against a plain per-line count ────────────────
+
+_STEP_N, _STEP_L_CAP, _STEP_BINS, _STEP_K = 256, 32, 8, 4
+_STEP_BASE = (7 << 32) + 5  # global line numbers need both key lanes
+
+
+def _run_step(data: bytes, pat: bytes, emit: bool = False):
+    """The step body on one zero-padded chunk, outside ``shard_map``:
+    ``(fn, args, results)``."""
+    chunk = np.zeros((1, _STEP_N), np.uint8)
+    chunk[0, :len(data)] = np.frombuffer(data, np.uint8)
+    args = (chunk, np.frombuffer(pat, np.uint8)[None],
+            np.array([len(data)], np.int32),
+            np.array([_STEP_BASE], np.uint64))
+    fn = functools.partial(_grep_step_device, l_cap=_STEP_L_CAP,
+                           bins=_STEP_BINS, k=_STEP_K, emit=emit)
+    with enable_x64(True):
+        return fn, args, [np.asarray(x) for x in jax.jit(fn)(*args)]
+
+
+_STEP_CASES = {
+    "dlen_0": (b"", b"the"),
+    "no_trailing_newline": (b"xthe\nnone\nthe the", b"the"),
+    "only_newlines": (b"\n" * 20, b"the"),
+    "match_on_every_line": (b"the\n" * 10, b"the"),
+    "more_than_bins_on_a_line": (b"a\n" + b"a" * 12 + b"\naaa\n", b"aa"),
+    "fewer_matched_than_k": (b"x\nthe\ny\nz the the\nw\n", b"the"),
+    "ties_across_the_topk_edge":
+        (b"ab ab\n" * 3 + b"ab ab ab\nq\n" + b"ab ab\n" * 3
+         + b"ab ab ab\nab\n", b"ab"),
+    "n_lines_above_l_cap": (b"a\n" * 20 + b"aaa\nb\n" * 10 + b"aa", b"a"),
+    "pattern_ends_at_last_valid_byte": (b"xx\nab the\nabthe", b"the"),
+    "full_chunk_one_open_line": (b"the " * (_STEP_N // 4), b"the"),
+    "full_chunk_newline_last":
+        (b"aba" * 5 + b"\n" + b"b" * (_STEP_N - 32) + b"\n"
+         + b"ababa" * 2 + b"abab\n", b"aba"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STEP_CASES))
+def test_grep_step_body_matches_plain_count(case):
+    """The step program's three results against the host oracle (split
+    at newlines, overlapping occurrences per line) over the same bytes:
+    the histogram row, the scalars, and the candidate rows in (count
+    desc, line asc) order with ties to the earlier line.  ``n_lines``
+    above ``l_cap`` raises the flag and nothing else: the counts no
+    longer pass through a line buffer."""
+    data, pat = _STEP_CASES[case]
+    assert len(data) <= _STEP_N
+    _, _, (hist_ext, cand, scal) = _run_step(data, pat)
+    n_lines, matched, occurrences, hist, top = grep_host_oracle(
+        [data], pat.decode(), bins=_STEP_BINS, topk=_STEP_K)
+    assert hist_ext.shape == (1, _STEP_BINS + 3)
+    assert cand.shape == (1, _STEP_K, 5) and scal.shape == (1, 5)
+    assert hist_ext[0].tolist() == [*hist, n_lines, matched, occurrences]
+    assert scal[0].tolist() == [len(top), n_lines,
+                                int(n_lines > _STEP_L_CAP), matched,
+                                occurrences]
+    rows = cand[0].astype(np.int64)
+    got = [(int((r[0] << 32) | r[1]) - _STEP_BASE, int(r[3]))
+           for r in rows[:len(top)]]
+    assert got == list(top)  # (line, occurrences)
+    assert (rows[:len(top), 2] == 8).all() and (rows[:, 4] == 0).all()
+    assert not rows[len(top):].any()  # dead candidate rows are zero
+
+
+@pytest.mark.parametrize("n", [100, 1024, 3000, 4096])
+def test_top_positions_exact_with_ties_to_the_lower_position(n):
+    """The two-stage top-k against a stable numpy sort: few distinct
+    values, so ties cross the rows of the ``[n / 1024, 1024]`` view and
+    the edge of the top k; ``n`` below, at and off a multiple of the
+    row width."""
+    vals = np.random.default_rng(n).integers(0, 4, n).astype(np.int32)
+    vals[n // 2] = vals[n - 1] = 9
+    for k in (1, 16, 40):
+        want = np.argsort(-vals, kind="stable")[:k]
+        got_val, got_pos = jax.jit(_top_positions, static_argnums=1)(vals, k)
+        assert np.asarray(got_pos).tolist() == want.tolist()
+        assert np.asarray(got_val).tolist() == vals[want].tolist()
+
+
+@pytest.mark.parametrize("emit", [False, True])
+def test_grep_step_jaxpr_holds_no_scatter(emit):
+    """The mechanism, pinned where no device trace is at hand: per-line
+    statistics come from scans read at the line ends, so neither step
+    variant may lower a scatter (``segment_sum`` and ``.at[].add`` are
+    both ``scatter-add``)."""
+    fn, args, _ = _run_step(b"the\nx\n", b"the", emit=emit)
+
+    def names(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from names(sub)
+
+    with enable_x64(True):
+        prims = set(names(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert {"cumsum", "cummax"} <= prims
+    assert not [p for p in prims if "scatter" in p], sorted(prims)
 
 
 # ── grep: the parity grid ──────────────────────────────────────────────

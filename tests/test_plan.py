@@ -211,6 +211,43 @@ def test_grep_wc_short_lines_replay_rung():
     assert get_registry().phases("grep").get("replays", 0) >= 1
 
 
+def _kept_on_host(data: bytes, pat: bytes) -> bytes:
+    """The bytes of the lines that hold ``pat``, each with its newline
+    where it has one."""
+    return b"".join(line for line in data.splitlines(keepends=True)
+                    if pat in line)
+
+
+_EMIT_CHUNK = 1 << 9
+_EMIT_CASES = {
+    # rows of exactly one line each: a terminated one, a filler, and an
+    # open final line, all as wide as the chunk
+    "line_spans_the_whole_chunk":
+        b"the" + b"x" * (_EMIT_CHUNK - 4) + b"\n"
+        + b"y" * (_EMIT_CHUNK - 1) + b"\n"
+        + b"z" * (_EMIT_CHUNK - 3) + b"the",
+    "last_line_open_and_matching":
+        b"a the b\nfiller\nthe end has the no newline",
+    "last_line_open_not_matching":
+        b"filler\nthe the\n\nan open tail that does not match",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_EMIT_CASES))
+def test_grep_emit_keeps_what_the_host_keeps(case):
+    # The emit step carries "my line matched" back from a line's end
+    # over its bytes with one reverse scan; its edges are a line that
+    # fills the chunk and a last line without a newline.
+    from dsi_tpu.parallel.grepstream import GrepStep, grep_host_oracle
+
+    data = _EMIT_CASES[case]
+    relay = HostRelay()
+    res = GrepStep([data], "the", mesh=mesh(), chunk_bytes=_EMIT_CHUNK,
+                   line_sink=relay).close()
+    assert res == grep_host_oracle([data], "the")
+    assert b"".join(relay.blocks()) == _kept_on_host(data, b"the")
+
+
 # ── stage-boundary crash/resume state machine ─────────────────────────
 
 
