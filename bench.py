@@ -910,7 +910,7 @@ def run_grep_row(files) -> dict:
     phases = {k: pstats[k] for k in ("batch_s", "batch_wait_s", "upload_s",
                                      "kernel_s", "pull_s", "merge_s",
                                      "replay_s", "depth", "replays",
-                                     "l_cap", "device_accumulate",
+                                     "device_accumulate",
                                      "sync_every", "step_pulls", "folds",
                                      "fold_s", "fold_overflows",
                                      "sync_pulls", "sync_s", "widens",

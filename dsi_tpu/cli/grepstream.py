@@ -4,8 +4,8 @@ core (``parallel/grepstream.py``) as a user-facing command, mirroring
 
 Files become one bounded-memory block stream cut at newline boundaries;
 every stream step runs ONE compiled literal-match program (the
-``ops/grepk.py`` shifted-compare idiom) whose ``l_cap`` escalation is
-the pipeline's sticky-rung replay, and the result is the whole-stream
+``ops/grepk.py`` shifted-compare idiom), the same one whatever the
+lines look like, and the result is the whole-stream
 match statistics: total/matched lines, occurrences, the per-line
 match-count histogram, and the exact top-k lines by occurrence count.
 ``--workdir DIR`` commits them as ``DIR/mr-out-0`` (temp file + rename),

@@ -48,7 +48,7 @@ Counters / gauges: ``steps`` (or ``waves``), ``depth``, ``replays``,
 ``step_pulls``, ``sync_pulls``, ``widens``, ``folds``,
 ``fold_overflows``, ``appends``, ``append_overflows``,
 ``postings_widens``, ``topk_snapshots``, ``hist_folds``, ``hist_pulls``,
-``table_cap``, ``l_cap``, ``sync_every``, ``max_inflight``,
+``table_cap``, ``sync_every``, ``max_inflight``,
 ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
 ``resume_cursor``/``resume_wave``, ``device_accumulate``.
 
@@ -186,7 +186,7 @@ COUNTER_KEYS = (
     "steps", "waves", "depth", "replays", "step_pulls", "sync_pulls",
     "widens", "folds", "fold_overflows", "appends", "append_overflows",
     "postings_widens", "topk_snapshots", "hist_folds", "hist_pulls",
-    "table_cap", "l_cap", "sync_every", "max_inflight",
+    "table_cap", "sync_every", "max_inflight",
     "buffer_allocs", "device_accumulate", "donate_chunks", "stalls",
     "device_rows",
     # checkpoint/restore
@@ -202,11 +202,9 @@ COUNTER_KEYS = (
     "ingest_readers", "ingest_blocks", "readahead_hit_pct",
     "wire_upload", "wire_steps", "wire_raw_steps", "wire_packed_bytes",
     "wire_ratio", "ckpt_delta_raw_bytes", "ckpt_compress",
-    # serving daemon (the "serve"/"serve_grep" scopes, serve/pack.py):
-    # rung_widens counts grep lanes sticky-widened to the hard-bound
-    # l_cap rung (the per-tenant AOT rung-affinity move, ISSUE 19)
+    # serving daemon (the "serve"/"serve_grep" scopes, serve/pack.py)
     "packed_steps", "packed_rows", "max_tenants_per_step",
-    "host_fallbacks", "rung_widens",
+    "host_fallbacks",
     # plan layer (the "plan" scope, dsi_tpu/plan + device/relay.py):
     # multi-stage chain accounting — handoff bytes vs commit bytes is
     # the zero-host-round-trip evidence
@@ -255,7 +253,6 @@ SERVE_SERIES = (
     "dsi_serve_evictions_p99_total", "dsi_serve_evictions_quota_total",
     "dsi_serve_packed_steps", "dsi_serve_packed_rows",
     "dsi_serve_grep_packed_steps", "dsi_serve_grep_packed_rows",
-    "dsi_serve_grep_rung_widens",
     "dsi_serve_tenant_steps", "dsi_serve_tenant_rows",
     "dsi_serve_tenant_evictions", "dsi_serve_tenant_resumes",
     "dsi_serve_tenant_done",

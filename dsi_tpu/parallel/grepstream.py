@@ -13,16 +13,13 @@ engines honor bit-identically:
   ``_wave_chunk`` materialization off the critical path),
 * a ``depth``-deep in-flight window of donated per-step uploads through
   ``aotcache.cached_compile(donate_argnums)``,
-* per-step scalar checks DEFERRED until a step leaves the window, with
-  exactly-once replay at sticky rungs — for grep that rung is the
-  ``l_cap`` line-capacity ladder (``ops/grepk.line_cap_rungs``): a step
-  whose line count passes the rung raises a flag, replays at the wider
-  compiled shape, and the shape sticks.  Since PR 27 the step program
-  keeps no per-line buffer (below), so the ladder guards nothing: the
-  counts of an overflowing step are already exact.  The flag, the second
-  compiled rung and the replay stay because ``serve/pack.py``, the
-  warmers, the program names and their tests are built on them
-  (ROADMAP, Design),
+* per-step scalar checks DEFERRED until a step leaves the window.  The
+  grep step is ONE compiled program per ``(n_dev, chunk_bytes, m, bins,
+  k, emit)``: it keeps no per-line buffer (below), so no capacity can
+  overflow and no step is ever replayed — the deferred check is the
+  host/device line-count comparison that guards the global line
+  numbers.  The indexer keeps the word-count engine's capacity ladders
+  and their exactly-once replay at sticky rungs,
 * cross-step state on device via ``dsi_tpu/device/``: grep folds
   per-line match-count histograms (:class:`DeviceHistogram`) and top-k
   match candidates (:class:`DeviceTopK`), the indexer appends postings
@@ -54,7 +51,7 @@ ends; the top-k orders the line ends by (count desc, position asc),
 which is (count desc, line asc), and reads the winners' line numbers
 from the newline cumsum; the emit variant carries a line's count back
 over its bytes with one reverse scan.  The cost depends on the chunk
-size alone, not on line length, match density or ``l_cap``.
+size alone, not on line length or match density.
 
 Indexer semantics: documents are processed in waves of ``n_dev`` (one
 per device, ``plan_waves`` sizing), the posting step is the word-count
@@ -102,7 +99,7 @@ from dsi_tpu.device.table import (DeviceTable, _pow2,
                                   _quiet_unusable_donation)
 from dsi_tpu.device.topk import DeviceHistogram, DeviceTopK, KeyCounts
 from dsi_tpu.obs import metrics_scope, span as _span
-from dsi_tpu.ops.grepk import is_literal_pattern, line_cap_rungs
+from dsi_tpu.ops.grepk import is_literal_pattern
 from dsi_tpu.ops.wordcount import (
     _PAD_KEY64,
     _shift_left,
@@ -275,8 +272,8 @@ def _top_positions(vals, k: int):
     return top_val, jnp.take(row_pos.reshape(-1), top_at)
 
 
-def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
-                      k: int, emit: bool = False):
+def _grep_step_device(chunk, pat, dlen, base, *, bins: int, k: int,
+                      emit: bool = False):
     """Per-device step body (runs under shard_map): literal match mask
     (``len(pattern)`` shifted compares, the ``ops/grepk.py`` idiom) →
     per-line occurrence counts (two scans differenced at the line ends,
@@ -312,7 +309,6 @@ def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
         last = jnp.where(dlen0 > 0, chunk[jnp.maximum(dlen0 - 1, 0)],
                          jnp.uint8(10))
         n_lines = nl_total + jnp.where((dlen0 > 0) & (last != 10), 1, 0)
-        overflow = n_lines > l_cap
 
     # Padding bytes are zeros and the pattern is printable ASCII, so a
     # match can neither start in nor extend into padding, nor sit on a
@@ -323,8 +319,8 @@ def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
     # not one, at dlen - 1.  With M the running match count, the line
     # that ends at p holds M[p] - M[q] occurrences, q the newline before
     # it; M is monotone, so M[q] is the running maximum of M over the
-    # newlines strictly before p.  Two scans, no per-line buffer: the
-    # counts are exact whatever ``l_cap`` says.
+    # newlines strictly before p.  Two scans, no per-line buffer, so no
+    # line count can overflow anything.
     with jax.named_scope("line_occ"):
         is_end = is_nl | (pos == dlen0 - 1)
         run = jnp.cumsum(match.astype(jnp.int32))
@@ -367,7 +363,9 @@ def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
     # Pin to int32: under the x64-scoped compile, literal-int promotion
     # would widen these to int64 and drift off the struct-warmed fold
     # program's [n_dev, 5] int32 contract (device/table._step_structs).
-    scal = jnp.stack([n_cand, n_lines, overflow.astype(jnp.int32),
+    # Lane 2 is reserved (every engine's row has five lanes; the fold
+    # reads lane 0 only): always 0, read by nobody.
+    scal = jnp.stack([n_cand, n_lines, jnp.int32(0),
                       matched, occurrences]).astype(jnp.int32)
     if not emit:
         return hist_ext[None], cand[None], scal[None]
@@ -390,10 +388,9 @@ def _grep_step_device(chunk, pat, dlen, base, *, l_cap: int, bins: int,
             kept_n.reshape(1))
 
 
-def _grep_step_impl(chunks, pats, lens, bases, *, l_cap: int, bins: int,
-                    k: int, mesh: Mesh, emit: bool = False):
-    body = functools.partial(_grep_step_device, l_cap=l_cap, bins=bins,
-                             k=k, emit=emit)
+def _grep_step_impl(chunks, pats, lens, bases, *, bins: int, k: int,
+                    mesh: Mesh, emit: bool = False):
+    body = functools.partial(_grep_step_device, bins=bins, k=k, emit=emit)
     out_specs = (P(AXIS, None), P(AXIS, None, None), P(AXIS, None))
     if emit:
         out_specs += (P(AXIS, None), P(AXIS))
@@ -404,23 +401,23 @@ def _grep_step_impl(chunks, pats, lens, bases, *, l_cap: int, bins: int,
     )(chunks, pats, lens, bases)
 
 
-def _grep_program(*, n_dev: int, chunk_bytes: int, m: int, l_cap: int,
-                  bins: int, k: int, mesh: Mesh, emit: bool = False):
-    """(name, fn) for one compiled grep step shape — single definition
+def _grep_program(*, n_dev: int, chunk_bytes: int, m: int, bins: int,
+                  k: int, mesh: Mesh, emit: bool = False):
+    """(name, fn) for the one compiled grep step of a shape — single definition
     shared by the run, the warmer, and the cache-existence probe (the
     ``streaming._step_program`` discipline).  The emit variant (the plan
     handoff's extra compaction outputs) is a distinct executable and
     gets a distinct name."""
 
     def fn(chunks, pats, lens, bases):
-        return _grep_step_impl(chunks, pats, lens, bases, l_cap=l_cap,
-                               bins=bins, k=k, mesh=mesh, emit=emit)
+        return _grep_step_impl(chunks, pats, lens, bases, bins=bins, k=k,
+                               mesh=mesh, emit=emit)
 
     # The HLO module takes the traced function's name: a device trace
     # then shows ``jit_grep_stream_step`` and not a ``jit_fn`` among others.
     fn.__name__ = fn.__qualname__ = "grep_stream_step"
-    name = (f"grep_stream_d{n_dev}_c{chunk_bytes}_m{m}_l{l_cap}"
-            f"_b{bins}_t{k}" + ("_em" if emit else ""))
+    name = (f"grep_stream_d{n_dev}_c{chunk_bytes}_m{m}_b{bins}_t{k}"
+            + ("_em" if emit else ""))
     return name, fn
 
 
@@ -515,10 +512,10 @@ def merge_topk(cands: Iterable[Tuple[int, int]],
     return tuple(sorted(cands, key=lambda r: (-r[1], r[0]))[:k])
 
 
-def grep_pack_fn(n_dev: int, chunk_bytes: int, m: int, l_cap: int, *,
+def grep_pack_fn(n_dev: int, chunk_bytes: int, m: int, *,
                  bins: int = GREP_BINS, k: int = DEFAULT_TOPK,
                  mesh: Mesh):
-    """The compiled packed-grep step for one ``(shape, rung)`` — the
+    """The compiled packed-grep step for one shape — the
     serving packer's entry (``serve/pack.py PackedGrepScheduler``) to
     the per-row grep program.  The kernel body runs per device row
     under ``shard_map`` with no collectives, so each row may carry a
@@ -527,8 +524,7 @@ def grep_pack_fn(n_dev: int, chunk_bytes: int, m: int, l_cap: int, *,
     Same persistent-AOT cache entry the streaming engine uses — a
     daemon and a one-shot CLI warm each other."""
     return _grep_fn(_grep_examples(n_dev, chunk_bytes, m), n_dev=n_dev,
-                    chunk_bytes=chunk_bytes, m=m, l_cap=l_cap, bins=bins,
-                    k=k, mesh=mesh)
+                    chunk_bytes=chunk_bytes, m=m, bins=bins, k=k, mesh=mesh)
 
 
 class GrepStep(EngineStep):
@@ -585,15 +581,10 @@ def grep_streaming(
 
     Returns a :class:`GrepStreamResult`, or None when the stream needs
     the host path (non-literal pattern, or a line wider than
-    ``chunk_bytes``).  Every step runs one compiled program per
-    ``l_cap`` rung; a step whose line count overflows the optimistic
-    rung (average line >= 8 bytes) is detected ``depth - 1`` steps late
-    and replays exactly that step at the ``n + 1`` hard-bound rung —
-    which then STICKS for every later step (``ops/grepk.line_cap_rungs``
-    escalation as pipeline replay, not host fallback).  Results are
-    bit-identical to ``depth=1`` because the accumulators only ever
-    ingest confirmed per-step tensors, which the replay reproduces
-    exactly (occurrence counts do not depend on the rung).
+    ``chunk_bytes``).  Every step runs the one compiled program of the
+    stream's shape, whatever its lines look like; nothing is replayed.
+    Results are bit-identical to ``depth=1`` because the accumulators
+    only ever ingest confirmed per-step tensors.
 
     ``device_accumulate=True`` folds each confirmed step's histogram
     vector into a persistent :class:`DeviceHistogram` and its top-k
@@ -619,15 +610,16 @@ def grep_streaming(
     (``batch_s``/``batch_wait_s``/``upload_s``/``kernel_s``/``pull_s``
     with its parts ``device_wait_s`` + ``d2h_s``/``merge_s``/
     ``replay_s``/``finalize_s``, ``steps``/``replays``/``step_pulls``/
-    ``sync_pulls``/``l_cap``/``pull_bytes``, ``device_rows`` = confirmed
-    lines per device, plus the service counters).
+    ``sync_pulls``/``pull_bytes``, ``device_rows`` = confirmed lines per
+    device, plus the service counters; ``replays`` and ``replay_s`` are
+    the shared pipeline's keys and stay 0 here).
 
     ``checkpoint_dir``/``checkpoint_every``/``resume`` follow the
     ``wordcount_streaming`` crash-resume contract (``dsi_tpu/ckpt``):
     snapshots at confirmed-step boundaries carry the host accumulators
-    (or the device histogram/top-k images), the global line counter,
-    the sticky ``l_cap`` rung, and the byte cursor; resumed output is
-    bit-identical to an uninterrupted run.  ``checkpoint_async`` /
+    (or the device histogram/top-k images), the global line counter
+    and the byte cursor; resumed output is bit-identical to an
+    uninterrupted run.  ``checkpoint_async`` /
     ``checkpoint_delta`` (env twins ``DSI_STREAM_CKPT_ASYNC`` /
     ``DSI_STREAM_CKPT_DELTA``, both default off = bit-identical PR-5
     behavior) follow the ``wordcount_streaming`` capture/commit and
@@ -669,15 +661,13 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
     n_dev = mesh.devices.size
     depth = pipeline_depth(depth)
     m = len(pattern)
-    rungs = line_cap_rungs(chunk_bytes)
-    state = {"l_cap": rungs[0]}
     # Registry scope (dsi_tpu/obs): grep_phases is a view over the one
     # schema, not its own dialect.
     stats = metrics_scope("grep")
     stats.update({"depth": depth, "steps": 0, "replays": 0,
                   "step_pulls": 0, "sync_pulls": 0,
                   "device_accumulate": device_accumulate,
-                  "l_cap": rungs[0], "batch_s": 0.0, "batch_wait_s": 0.0,
+                  "batch_s": 0.0, "batch_wait_s": 0.0,
                   "upload_s": 0.0, "kernel_s": 0.0, "pull_s": 0.0,
                   "device_wait_s": 0.0, "d2h_s": 0.0, "pull_bytes": 0,
                   "merge_s": 0.0, "replay_s": 0.0})
@@ -756,16 +746,15 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             loaded = ck_store.load_latest_chain()
             if loaded is not None:
                 meta, arrays, deltas = loaded
-                # Cursor/rung state is newest-wins: the final delta's
-                # meta IS the restore point; the base meta only names
-                # the image shapes.
+                # Cursor state is newest-wins: the final delta's meta
+                # IS the restore point; the base meta only names the
+                # image shapes.  (A chain written before PR 28 also
+                # carries a line-capacity key; it is read past.)
                 eff = deltas[-1][0] if deltas else meta
                 start_offset = int(eff["cursor"])
                 ck_cursor.update(offset=start_offset,
                                  lines=int(eff["lines"]))
                 next_line[0] = int(eff["lines"])
-                state["l_cap"] = int(eff["l_cap"])
-                stats["l_cap"] = state["l_cap"]
                 if device_accumulate:
                     acc.restore({k[3:]: v for k, v in arrays.items()
                                  if k.startswith("kc_")})
@@ -839,7 +828,7 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
         with _span("ckpt", stats=stats, key="ckpt_s",
                    lines=ck_cursor["lines"]):
             meta = {"cursor": ck_cursor["offset"],
-                    "lines": ck_cursor["lines"], "l_cap": state["l_cap"]}
+                    "lines": ck_cursor["lines"]}
             kind = "full"
             parts = None
             with _span("ckpt_capture", lane="ckpt", stats=stats,
@@ -891,7 +880,7 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             fault_point("mid-capture")
             ck_writer.commit(parts, meta, kind=kind)
 
-    def step_call(buf, lens_np, bases_np, l_cap):
+    def step_call(buf, lens_np, bases_np):
         with _span("upload", stats=stats, key="upload_s",
                    step=stats["steps"]):
             chunks = jax.device_put(buf, sh2)
@@ -899,8 +888,8 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             with enable_x64(True):  # keep the u64 bases u64 through it
                 bases = jax.device_put(bases_np.astype(np.uint64), sh1)
         fn = _grep_fn((chunks, pat_dev, lens, bases), n_dev=n_dev,
-                      chunk_bytes=chunk_bytes, m=m, l_cap=l_cap, bins=bins,
-                      k=topk, mesh=mesh, emit=emit)
+                      chunk_bytes=chunk_bytes, m=m, bins=bins, k=topk,
+                      mesh=mesh, emit=emit)
         with _quiet_unusable_donation():
             outs = fn(chunks, pat_dev, lens, bases)
         if emit:
@@ -914,46 +903,22 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
         np.cumsum(row_lines[:-1], out=bases[1:])
         bases[1:] += next_line[0]
         next_line[0] += int(row_lines.sum())
-        hist_d, cand_d, scal, comp_d, kept_d = step_call(
-            buf, lens_np, bases, state["l_cap"])
+        hist_d, cand_d, scal, comp_d, kept_d = step_call(buf, lens_np,
+                                                         bases)
         stats["steps"] += 1
         rec_offset = 0
         if offsets is not None:
             rec_offset = start_offset + offsets[dispatch_idx[0]]
             dispatch_idx[0] += 1
         fault_point("post-dispatch")
-        return (buf, lens_np, row_lines, bases, state["l_cap"],
-                hist_d, cand_d, scal, comp_d, kept_d, rec_offset,
-                next_line[0])
-
-    def replay_step(buf, lens_np, bases_np, used_l_cap):
-        """Late-detected line-capacity overflow: replay just this step
-        at the wider sticky rung.  Exactly-once — the optimistic
-        attempt's tensors are dropped unmerged (the emit outputs
-        included: occurrence counts and kept bytes do not depend on the
-        rung, so the replay reproduces them exactly)."""
-        stats["replays"] += 1
-        with _span("replay", stats=stats, key="replay_s"):
-            for l_cap in rungs:
-                if l_cap <= used_l_cap:
-                    continue
-                hist_d, cand_d, scal, comp_d, kept_d = step_call(
-                    buf, lens_np, bases_np, l_cap)
-                scal_np = np.asarray(scal)
-                if not scal_np[:, 2].any():
-                    state["l_cap"] = max(state["l_cap"], l_cap)
-                    stats["l_cap"] = state["l_cap"]
-                    return hist_d, cand_d, scal, comp_d, kept_d, scal_np
-        raise RuntimeError("grep l_cap ladder exhausted (n+1 must fit)")
+        return (buf, row_lines, hist_d, cand_d, scal, comp_d, kept_d,
+                rec_offset, next_line[0])
 
     def finish_one(record) -> None:
-        buf, lens_np, row_lines, bases_np, l_cap_used, hist_d, cand_d, \
-            scal, comp_d, kept_d, rec_offset, rec_lines = record
+        buf, row_lines, hist_d, cand_d, scal, comp_d, kept_d, \
+            rec_offset, rec_lines = record
         with _span("kernel", stats=stats, key="kernel_s"):
             scal_np = np.asarray(scal)  # blocks until the kernel lands
-        if scal_np[:, 2].any():  # l_cap overflow: replay wider, sticky
-            hist_d, cand_d, scal, comp_d, kept_d, scal_np = replay_step(
-                buf, lens_np, bases_np, l_cap_used)
         if not np.array_equal(scal_np[:, 1].astype(np.int64), row_lines):
             # The global line numbering depends on host/device agreeing
             # on per-row line counts; a disagreement is an engine bug and
@@ -1085,9 +1050,7 @@ def warm_grepstream_aot(mesh: Mesh | None = None,
                         bins: int = GREP_BINS, topk: int = DEFAULT_TOPK,
                         device_accumulate: bool = False,
                         mesh_shards: int = 0, emit: bool = False) -> None:
-    """Compile + persist the grep step programs at BOTH ``l_cap`` rungs
-    (the optimistic and the ``n + 1`` replay shape — an ungated
-    escalation must load, never cold-compile) plus, with
+    """Compile + persist the grep step program of this shape plus, with
     ``device_accumulate``, the top-k fold/snapshot and histogram fold
     shapes (the ``mesh_*`` shuffle-fold variants under ``mesh_shards``).
     ``emit`` additionally warms the plan handoff's ``*_em`` compaction
@@ -1097,13 +1060,9 @@ def warm_grepstream_aot(mesh: Mesh | None = None,
         mesh = default_mesh()
     n_dev = mesh.devices.size
     examples = _grep_examples(n_dev, chunk_bytes, pattern_len)
-    for l_cap in line_cap_rungs(chunk_bytes):
+    for em in (False, True) if emit else (False,):
         _grep_fn(examples, n_dev=n_dev, chunk_bytes=chunk_bytes,
-                 m=pattern_len, l_cap=l_cap, bins=bins, k=topk, mesh=mesh)
-        if emit:
-            _grep_fn(examples, n_dev=n_dev, chunk_bytes=chunk_bytes,
-                     m=pattern_len, l_cap=l_cap, bins=bins, k=topk,
-                     mesh=mesh, emit=True)
+                 m=pattern_len, bins=bins, k=topk, mesh=mesh, emit=em)
     if emit:
         from dsi_tpu.device.relay import _pack_fn
 

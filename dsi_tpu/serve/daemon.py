@@ -18,8 +18,8 @@ idiom the reference's coordinator already speaks) and the daemon:
 * **packs** word-count tenants into shared device steps
   (``serve/pack.py``: K tenants ≈ 1 dispatch) AND grep tenants into
   shared lane-isolated dispatches (``PackedGrepScheduler``: rows
-  grouped by pattern length, per-tenant sticky ``l_cap`` rung so one
-  tenant's widen never cold-compiles the rest) — everything else runs
+  grouped by pattern length, one compiled program per length whatever
+  a tenant's lines look like) — everything else runs
   as resumable step objects (``parallel/stepobj.py``) on one scheduler
   thread; a single thread owns all jax work;
 * **evicts by tail latency**: when the resident set is full and jobs
@@ -459,7 +459,6 @@ class ServeDaemon:
                     f"  grep packed_steps={st['packed_steps']} "
                     f"packed_rows={st['packed_rows']} "
                     f"max_tenants_per_step={st['max_tenants_per_step']} "
-                    f"rung_widens={st['rung_widens']} "
                     f"host_fallbacks={st['host_fallbacks']}")
             for jid, rec in sorted(self._resident.items()):
                 job = self._jobs[jid]
@@ -531,8 +530,6 @@ class ServeDaemon:
                          f"{st['packed_steps']}")
                 L.append(f"dsi_serve_grep_packed_rows "
                          f"{st['packed_rows']}")
-                L.append(f"dsi_serve_grep_rung_widens "
-                         f"{st['rung_widens']}")
             for t in self._emit_tenants():
                 s = self._tenants[t]
                 lab = f'tenant="{_mname(t)}"'
@@ -590,7 +587,7 @@ class ServeDaemon:
                     "resume_cursor": lane.start_offset}
         if self.pack_grep:
             # grep as a packed lane: rows join shared dispatches keyed
-            # by (pattern length, rung) — the ISSUE-19 tentpole.
+            # by pattern length — the ISSUE-19 tentpole.
             from dsi_tpu.serve.pack import GrepLane
 
             lane = GrepLane(job, self.chunk_bytes, ckpt_dir,
@@ -642,7 +639,6 @@ class ServeDaemon:
                 stats = {"steps": lane.steps,
                          "rows": lane.confirmed_rows,
                          "hostpath": lane.hostpath,
-                         "rung": lane.rung,
                          "resume_gap_s": lane.resume_gap_s}
             else:
                 step = rec["step"]
@@ -808,8 +804,8 @@ class ServeDaemon:
                 except Exception as e:  # noqa: BLE001
                     self._fail_lanes(wc_lanes, e, "packed step")
                     worked = True
-            # One packed grep step over ONE (pattern length, rung)
-            # group — groups rotate across scheduler iterations.
+            # One packed grep step over ONE pattern-length group —
+            # groups rotate across scheduler iterations.
             grep_lanes = [(jid, rec["lane"])
                           for jid, rec in resident.items()
                           if rec["kind"] == "grep"

@@ -49,28 +49,21 @@ Grep packing (ISSUE 19) is the EASY demux case: the grep step program
 operand — so K tenants' rows never mix and each output row (histogram
 extension, top-k candidates, scalars) already belongs to exactly one
 lane.  :class:`PackedGrepScheduler` therefore groups runnable
-:class:`GrepLane` s by ``(pattern length, l_cap rung)`` — rows sharing a
-compiled shape — and fills one ``[n_dev, chunk_bytes]`` dispatch
-round-robin across the group's tenants.  The rung is per-TENANT sticky
-AOT affinity: a lane whose row overflows rung 0's line capacity is
-replayed at the hard-bound rung (``ops/grepk.line_cap_rungs``) and
-STAYS there (persisted in its checkpoint meta), migrating between pack
-groups instead of widening everyone — one tenant's pathological input
-never cold-compiles, or re-runs, the rest of the pack.  Exactness is
-per-ROW: a step confirms each lane's clean prefix of rows (cursor order
-is byte-range order) and requeues the overflowed row and everything
-after it for the lane's next (wider) dispatch; per-lane line-number
-bases are assigned host-side at row-take time, so requeued rows keep
-exact global line numbers and per-tenant output stays byte-identical to
-the tenant running alone — the same parity bar the wc lanes carry.
+:class:`GrepLane` s by pattern length — rows sharing the one compiled
+program of that shape — and fills one ``[n_dev, chunk_bytes]`` dispatch
+round-robin across the group's tenants.  The step program keeps no
+per-line buffer, so a tenant's line lengths change nothing: every row of
+a dispatch is confirmed in the step that carried it, in take order
+(which is byte-range order).  Per-lane line-number bases are assigned
+host-side at row-take time, so per-tenant output stays byte-identical
+to the tenant running alone — the same parity bar the wc lanes carry.
 """
 
 from __future__ import annotations
 
 import os
 import time
-from collections import deque
-from typing import Deque, Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -457,9 +450,8 @@ class PackedWcScheduler:
 class _GrepRow(NamedTuple):
     """One taken-but-unconfirmed lane row: the bytes, their valid
     length, the host line count, the stream offset just past the row,
-    and the GLOBAL number of its first line.  Assigned once at take
-    time, carried verbatim through requeues — which is why a replayed
-    row's line numbers (the top-k key) cannot drift."""
+    and the GLOBAL number of its first line, assigned once at take
+    time (the top-k key is built from it on the device)."""
 
     row: np.ndarray
     dlen: int
@@ -471,8 +463,8 @@ class _GrepRow(NamedTuple):
 class GrepLane:
     """One tenant grep job's lane in :class:`PackedGrepScheduler`: a
     newline-aligned row stream cut from its input files, host-side
-    whole-stream accumulators (totals, histogram, exact top-k), a
-    sticky ``l_cap`` rung, and a per-tenant checkpoint chain.
+    whole-stream accumulators (totals, histogram, exact top-k) and a
+    per-tenant checkpoint chain.
 
     The accumulators fold per-ROW kernel outputs, so they are
     snapshot-small (``bins`` ints + ``topk`` pairs): checkpoints are
@@ -486,7 +478,7 @@ class GrepLane:
                  checkpoint_every: Optional[int] = None,
                  resume: bool = True, bins: Optional[int] = None,
                  topk: Optional[int] = None):
-        from dsi_tpu.ops.grepk import is_literal_pattern, line_cap_rungs
+        from dsi_tpu.ops.grepk import is_literal_pattern
         from dsi_tpu.parallel.grepstream import (DEFAULT_TOPK, GREP_BINS,
                                                  batch_lines)
         from dsi_tpu.parallel.streaming import stream_files
@@ -499,8 +491,6 @@ class GrepLane:
         self.chunk_bytes = int(chunk_bytes)
         self.bins = int(bins if bins is not None else GREP_BINS)
         self.topk = int(topk if topk is not None else DEFAULT_TOPK)
-        self.rungs = line_cap_rungs(self.chunk_bytes)
-        self.rung = 0                 # sticky per-tenant AOT affinity
         self.lines = 0
         self.matched = 0
         self.occurrences = 0
@@ -516,7 +506,6 @@ class GrepLane:
         self.input_done = False
         self.resume_gap_s = 0.0
         self.stats: Dict = {}
-        self._held: Deque[_GrepRow] = deque()
         self._next_base = 0
         ident = {"tenant": self.tenant, "pattern": self.pattern,
                  "files": [[os.path.basename(f), os.path.getsize(f)]
@@ -532,12 +521,13 @@ class GrepLane:
             t0 = time.perf_counter()
             loaded = self.store.load_latest_chain()
             if loaded is not None:
-                meta, arrays, _deltas = loaded   # full images: no deltas
+                # Full images, no deltas.  (A chain written before
+                # PR 28 also carries a ``rung``; it is read past.)
+                meta, arrays, _deltas = loaded
                 start = int(meta["cursor"])
                 self.lines = int(meta["lines"])
                 self.matched = int(meta["matched"])
                 self.occurrences = int(meta["occurrences"])
-                self.rung = min(int(meta["rung"]), len(self.rungs) - 1)
                 self.confirmed_rows = int(meta["rows"])
                 self.hist = [int(v) for v in arrays["g_hist"]]
                 self.cands = [(int(r[0]), int(r[1]))
@@ -557,22 +547,14 @@ class GrepLane:
 
     @property
     def runnable(self) -> bool:
-        if self.hostpath:
-            return False
-        return bool(self._held) or not self.input_done
-
-    @property
-    def l_cap(self) -> int:
-        return self.rungs[self.rung]
+        return not (self.hostpath or self.input_done)
 
     def take_row(self) -> Optional[_GrepRow]:
-        """The next unconfirmed row — a requeued one first, else one
-        pulled (and base-numbered) from the stream.  None at end of
-        input or on a host-path flip (a line wider than one row)."""
+        """The next row, pulled (and base-numbered) from the stream.
+        None at end of input or on a host-path flip (a line wider than
+        one row)."""
         from dsi_tpu.parallel.grepstream import _LineTooLong
 
-        if self._held:
-            return self._held.popleft()
         try:
             batch, lens, row_lines = next(self._rows)
         except StopIteration:
@@ -588,28 +570,14 @@ class GrepLane:
         self._next_base += info.n_lines
         return info
 
-    def requeue(self, rows: List[_GrepRow]) -> None:
-        """Give back a step's unconfirmed suffix, order preserved —
-        the rows the lane's next (wider) dispatch serves first."""
-        self._held.extendleft(reversed(rows))
-
     def to_hostpath(self) -> None:
         self.hostpath = True
-        self._held.clear()
-
-    def widen(self) -> bool:
-        """Sticky-escalate to the next ``l_cap`` rung; False at the
-        hard bound (``chunk_bytes + 1`` lines cannot overflow)."""
-        if self.rung + 1 >= len(self.rungs):
-            return False
-        self.rung += 1
-        return True
 
     def confirm_row(self, info: _GrepRow, hist_row: np.ndarray,
                     cand_pairs: List[Tuple[int, int]], matched: int,
                     occurrences: int) -> None:
-        """Fold one clean (non-overflowed) row's kernel outputs and
-        advance the durable cursor to the row's end offset."""
+        """Fold one row's kernel outputs and advance the durable
+        cursor to the row's end offset."""
         from dsi_tpu.parallel.grepstream import merge_topk
 
         self.cursor = info.end_off
@@ -637,15 +605,14 @@ class GrepLane:
         meta = {"cursor": self.cursor, "lines": self.lines,
                 "matched": self.matched,
                 "occurrences": self.occurrences,
-                "rung": self.rung, "rows": self.confirmed_rows}
+                "rows": self.confirmed_rows}
         cand = np.array(self.cands or np.zeros((0, 2)), dtype=np.int64)
         parts = [("g_", {"hist": np.array(self.hist, dtype=np.int64),
                          "cand": cand.reshape(-1, 2)})]
         self.writer.commit(parts, meta, kind="full")
 
     def suspend(self) -> None:
-        """Evict: one forced durable snapshot (held rows are simply
-        re-read from the cursor on resume); dead after."""
+        """Evict: one forced durable snapshot; dead after."""
         if not self.hostpath:
             self.save_ckpt()
         self.writer.drain()
@@ -676,7 +643,7 @@ class GrepLane:
 class PackedGrepScheduler:
     """Shared grep-step packer over :class:`GrepLane` rows (module
     docstring).  One instance per daemon; :meth:`step` is one shared
-    dispatch over ONE ``(pattern length, rung)`` group — groups take
+    dispatch over ONE pattern-length group — groups take
     turns round-robin, so mixed pattern lengths interleave fairly
     instead of the shortest length starving the rest."""
 
@@ -697,7 +664,6 @@ class PackedGrepScheduler:
         self.topk = int(topk if topk is not None else DEFAULT_TOPK)
         self.stats = metrics_scope("serve_grep")
         self.stats.update({"packed_steps": 0, "packed_rows": 0,
-                           "replays": 0, "rung_widens": 0,
                            "host_fallbacks": 0, "upload_s": 0.0,
                            "kernel_s": 0.0, "pull_s": 0.0,
                            "merge_s": 0.0, "max_tenants_per_step": 0})
@@ -706,26 +672,24 @@ class PackedGrepScheduler:
         self._rr = 0
         self._jax = jax
 
-    def warm(self, m: int, rung: int = 0) -> None:
+    def warm(self, m: int) -> None:
         """Compile (or load) one pack shape ahead of need — the boot
-        warm for the common pattern length; every other ``(m, rung)``
-        pays its cold compile once, persisted."""
-        from dsi_tpu.ops.grepk import line_cap_rungs
+        warm for the common pattern length; every other length pays its
+        cold compile once, persisted."""
         from dsi_tpu.parallel.grepstream import grep_pack_fn
 
         grep_pack_fn(self.n_dev, self.chunk_bytes, int(m),
-                     line_cap_rungs(self.chunk_bytes)[rung],
                      bins=self.bins, k=self.topk, mesh=self.mesh)
 
     # ── one packed step ──
 
     def _pick_group(self, lanes: List[GrepLane]) -> List[GrepLane]:
-        """The next ``(m, rung)`` group, round-robin over the sorted
-        group keys — a deterministic turn order under churn."""
-        groups: Dict[tuple, List[GrepLane]] = {}
+        """The next pattern-length group, round-robin over the sorted
+        lengths — a deterministic turn order under churn."""
+        groups: Dict[int, List[GrepLane]] = {}
         for lane in lanes:
             if lane.runnable:
-                groups.setdefault((lane.m, lane.rung), []).append(lane)
+                groups.setdefault(lane.m, []).append(lane)
         if not groups:
             return []
         keys = sorted(groups)
@@ -733,7 +697,7 @@ class PackedGrepScheduler:
         self._rr += 1
         return groups[key]
 
-    def _dispatch(self, chunk_np, pats_np, lens_np, bases_np, m, l_cap):
+    def _dispatch(self, chunk_np, pats_np, lens_np, bases_np, m):
         from dsi_tpu.device.table import _quiet_unusable_donation
         from dsi_tpu.parallel.grepstream import grep_pack_fn
         from dsi_tpu.utils.jaxcompat import enable_x64
@@ -745,7 +709,7 @@ class PackedGrepScheduler:
             with enable_x64(True):   # keep the u64 bases u64 through it
                 bases = self._jax.device_put(
                     bases_np.astype(np.uint64), self._sh_row)
-        fn = grep_pack_fn(self.n_dev, self.chunk_bytes, m, l_cap,
+        fn = grep_pack_fn(self.n_dev, self.chunk_bytes, m,
                           bins=self.bins, k=self.topk, mesh=self.mesh)
         with _span("kernel", stats=self.stats, key="kernel_s"):
             with _quiet_unusable_donation():
@@ -757,14 +721,13 @@ class PackedGrepScheduler:
     def step(self, lanes: List[GrepLane]) -> List[GrepLane]:
         """Pack up to ``n_dev`` pending rows from ONE shape group
         (round-robin across its tenants; a lone tenant may fill every
-        row) into one dispatch; demux per row, confirm each lane's
-        clean prefix, requeue + sticky-widen on overflow.  Returns the
-        lanes that confirmed rows."""
+        row) into one dispatch; demux per row and confirm every row, in
+        take order (a lane's byte-range order).  Returns the lanes that
+        confirmed rows."""
         group = self._pick_group(lanes)
         if not group:
             return []
-        m, rung = group[0].m, group[0].rung
-        l_cap = group[0].l_cap
+        m = group[0].m
         picks: List[Tuple[GrepLane, _GrepRow]] = []
         while len(picks) < self.n_dev:
             progressed = False
@@ -798,42 +761,22 @@ class PackedGrepScheduler:
             lens_np[slot] = info.dlen
             bases_np[slot] = info.base
         hist_np, cand_np, scal_np = self._dispatch(
-            chunk_np, pats_np, lens_np, bases_np, m, l_cap)
+            chunk_np, pats_np, lens_np, bases_np, m)
         fault_point("post-dispatch")
-        # Per-lane demux: slots in take order ARE byte-range order, so
-        # each lane confirms its clean prefix and requeues the rest.
-        by_lane: Dict[int, List[tuple]] = {}
-        order: List[GrepLane] = []
-        for slot, (lane, info) in enumerate(picks):
-            if id(lane) not in by_lane:
-                by_lane[id(lane)] = []
-                order.append(lane)
-            by_lane[id(lane)].append((slot, info))
-        confirmed: List[GrepLane] = []
+        # Per-row demux: slots in take order ARE each lane's byte-range
+        # order, so confirming them in slot order advances every lane's
+        # cursor monotonically.
         with _span("merge", stats=self.stats, key="merge_s"):
-            for lane in order:
-                slots = by_lane[id(lane)]
-                n_ok = 0
-                for slot, _info in slots:
-                    if int(scal_np[slot, 2]):
-                        break
-                    n_ok += 1
-                for slot, info in slots[:n_ok]:
-                    n_cand = int(scal_np[slot, 0])
-                    pairs = [((int(cand_np[slot, i, 0]) << 32)
-                              | int(cand_np[slot, i, 1]),
-                              int(cand_np[slot, i, 3]))
-                             for i in range(n_cand)]
-                    lane.confirm_row(info, hist_np[slot],
-                                     pairs, int(scal_np[slot, 3]),
-                                     int(scal_np[slot, 4]))
-                if n_ok < len(slots):
-                    lane.requeue([info for _s, info in slots[n_ok:]])
-                    self.stats["replays"] += 1
-                    if lane.widen():
-                        self.stats["rung_widens"] += 1
-                if n_ok:
-                    confirmed.append(lane)
+            for slot, (lane, info) in enumerate(picks):
+                n_cand = int(scal_np[slot, 0])
+                pairs = [((int(cand_np[slot, i, 0]) << 32)
+                          | int(cand_np[slot, i, 1]),
+                          int(cand_np[slot, i, 3]))
+                         for i in range(n_cand)]
+                lane.confirm_row(info, hist_np[slot], pairs,
+                                 int(scal_np[slot, 3]),
+                                 int(scal_np[slot, 4]))
+        confirmed = list(dict.fromkeys(lane for lane, _info in picks))
         fault_point("mid-fold")
         for lane in confirmed:
             lane.note_step()

@@ -7,10 +7,12 @@ byte-compare with the oracle output (test-mr.sh:13-53).
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import os
 import threading
 import time
+import types
 from typing import List
 
 from dsi_tpu.config import JobConfig
@@ -63,3 +65,75 @@ def run_distributed_threads(app: str, files, workdir: str, n_workers: int = 3,
             w.join(timeout=10.0)
     finally:
         c.close()
+
+
+@contextlib.contextmanager
+def net_job_split_roles(app: str, files, workdir: str, n_reduce: int,
+                        bind: str = ""):
+    """A net-data-plane job in which every shuffle fetch is REMOTE by
+    construction: one partition server's spool holds every map's output
+    (the map branch of ``worker_loop``, driven here), and one
+    reduce-only ``worker_loop`` (``take_maps=False``) behind a second
+    server runs every reduce, so no fetch can take the local-read short
+    cut.  A CLI fleet cannot promise that: the worker that wins all the
+    maps holds all the data, and locality placement then hands it the
+    reduces too.
+
+    Yields, with both servers still up, a namespace of ``stats`` (the
+    coordinator's ``net_stats()``), ``lines`` (the outputs, fetched as
+    the driver fetches them and merged like :func:`merged_output`),
+    ``producer`` and ``consumer`` (the servers)."""
+    from dsi_tpu.mr.coordinator import Coordinator
+    from dsi_tpu.mr.types import TaskStatus
+    from dsi_tpu.mr.worker import intermediate_name, run_map_task
+    from dsi_tpu.net import PartitionServer
+    from dsi_tpu.net.fetch import fetch_partition
+
+    mapf, reducef = load_plugin(app)
+    coord = Coordinator(list(files), n_reduce,
+                        JobConfig(n_reduce=n_reduce, workdir=workdir,
+                                  socket_path="tcp:127.0.0.1:0",
+                                  net_shuffle=True))
+    coord.serve()
+    spools = [os.path.join(workdir, f"worker-{i}") for i in range(2)]
+    producer = PartitionServer(spools[0], bind=bind)
+    consumer = PartitionServer(spools[1], bind=bind)
+    producer.start()
+    consumer.start()
+    try:
+        for _ in files:  # one map per file; a further request would
+            # be handed a reduce
+            r = coord.request_task({"WorkerId": "producer",
+                                    "Addr": producer.address})
+            assert r["TaskStatus"] == int(TaskStatus.MAP), r
+            run_map_task(mapf, r["Filename"], r["CMap"], n_reduce,
+                         spools[0])
+            coord.map_complete({
+                "TaskNumber": r["CMap"], "Addr": producer.address,
+                "PartSizes": [os.path.getsize(intermediate_name(
+                    r["CMap"], p, spools[0])) for p in range(n_reduce)]})
+        cfg = JobConfig(n_reduce=n_reduce, workdir=spools[1],
+                        socket_path=coord.address(), take_maps=False,
+                        net_shuffle=True, wait_sleep_s=0.05)
+        reducer = threading.Thread(
+            target=worker_loop, args=(mapf, reducef, cfg),
+            kwargs={"partsrv": consumer}, daemon=True)
+        reducer.start()
+        deadline = time.time() + 60.0
+        while not coord.done():
+            if time.time() > deadline:
+                raise TimeoutError("net job did not finish in time")
+            time.sleep(0.05)
+        lines: List[str] = []
+        for _r, (addr, name, _crc) in sorted(
+                coord.output_locations().items()):
+            raw = fetch_partition(addr, name, timeout=10.0)
+            lines.extend(l for l in raw.decode("utf-8").splitlines(True)
+                         if l.strip())
+        yield types.SimpleNamespace(stats=coord.net_stats(),
+                                    lines=sorted(lines),
+                                    producer=producer, consumer=consumer)
+    finally:
+        coord.close()
+        producer.close()
+        consumer.close()

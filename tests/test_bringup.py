@@ -481,8 +481,13 @@ def test_chip_smoke_phases_dry_run_on_named_cpu(tmp_path, monkeypatch):
     assert all(r["parity"] for r in results)
     assert all(r["host_maps"] == 0 and r["device_maps"] >= 2
                for r in results[:3])
+    # What the smoke itself gates the warm process on.  ``compile_s``
+    # is not part of it: ``backend_compile_duration`` on a cache hit is
+    # the load, and a slow load (0.5 s under six xdist workers) is booked
+    # there with no miss behind it.
     warm = results[4]
-    assert warm["cache"]["cache_misses"] == 0 and warm["compile_s"] == {}
+    assert warm["cache"]["cache_misses"] == 0
+    assert warm["cache"]["cache_hits"] > 0
 
 
 def test_chip_smoke_fails_a_phase_that_took_the_host_path(tmp_path,

@@ -466,6 +466,78 @@ def test_grep_resume_across_forced_topk_widen(monkeypatch, tmp_path):
     assert res == _baseline("grep", True)
 
 
+def _retired_meta(monkeypatch, **retired):
+    """Every checkpoint commit writes ``retired`` into its meta too, as
+    the engines did before PR 28 (grep's ``l_cap``, a lane's ``rung``).
+    Returns the list the committed kinds are appended to."""
+    from dsi_tpu.ckpt import CheckpointWriter
+
+    commit = CheckpointWriter.commit
+    kinds = []
+
+    def commit_with_retired(self, parts, meta, kind="full"):
+        kinds.append(kind)
+        return commit(self, parts, {**meta, **retired}, kind=kind)
+
+    monkeypatch.setattr(CheckpointWriter, "commit", commit_with_retired)
+    return kinds
+
+
+@pytest.mark.parametrize("dacc", [False, True])
+def test_grep_resume_reads_past_the_retired_l_cap(monkeypatch, tmp_path,
+                                                  dacc):
+    """A chain (base + deltas) whose metas carry the ``l_cap`` the
+    engine wrote before PR 28, here the hard-bound rung a short-line
+    stream had stuck at: the resume restores it and the output is
+    bit-identical to an uninterrupted run."""
+    kinds = _retired_meta(monkeypatch, l_cap=GREP_CHUNK + 1)
+    run = _RUNNERS["grep"]
+    ck = str(tmp_path / "ck")
+    _fault_env(monkeypatch, "mid-fold", 6)
+    with pytest.raises(FaultInjected):
+        run(ckpt=ck, dacc=dacc, delta=True)
+    assert kinds[:2] == ["full", "delta"]  # a chain, not one image
+    _clear_fault(monkeypatch)
+    stats = {}
+    res = run(ckpt=ck, resume=True, dacc=dacc, delta=True, stats=stats)
+    assert stats["resume_cursor"] > 0 and "l_cap" not in stats
+    assert res == _baseline("grep", dacc)
+
+
+def test_grep_lane_resume_reads_past_the_retired_rung(monkeypatch,
+                                                      tmp_path):
+    """The packed serving lane's twin: a lane evicted with the ``rung``
+    it checkpointed before PR 28 resumes from that chain, and its
+    result equals the lane that ran through (and the oracle)."""
+    from dsi_tpu.serve.pack import GrepLane, PackedGrepScheduler
+
+    path = tmp_path / "in.txt"
+    path.write_bytes(GREP_TEXT)
+    job = {"tenant": "t", "pattern": "ab", "files": [str(path)]}
+    sched = PackedGrepScheduler(mesh=default_mesh(1), chunk_bytes=GREP_CHUNK)
+
+    def lane(name):
+        return GrepLane(job, GREP_CHUNK, str(tmp_path / name),
+                        checkpoint_every=2)
+
+    def run(ln):
+        while ln.runnable:
+            sched.step([ln])
+        return ln.finalize()
+
+    through = run(lane("through"))
+    assert through == grep_host_oracle([GREP_TEXT], "ab")
+    kinds = _retired_meta(monkeypatch, rung=1)
+    first = lane("evicted")
+    for _ in range(5):
+        sched.step([first])
+    first.suspend()
+    assert kinds and first.runnable  # saved mid-stream, input left
+    resumed = lane("evicted")
+    assert 0 < resumed.start_offset == first.cursor < len(GREP_TEXT)
+    assert run(resumed) == through
+
+
 def test_tfidf_crash_resume_parity(monkeypatch, tmp_path):
     """The wave-cursor checkpoint on the TF-IDF walk (the indexer grid
     above exercises the same machinery more heavily)."""

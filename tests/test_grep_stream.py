@@ -2,7 +2,7 @@
 on-device top-k/histogram service (device/topk.py).
 
 Oracle discipline as everywhere else: every engine path — depth x
-device_accumulate x forced l_cap replay x forced top-k widen — must
+device_accumulate x short lines x forced top-k widen — must
 agree BIT-FOR-BIT with the depth=1 host-merge path and with a
 pure-Python oracle over the same bytes (including per-word posting
 order for the indexer), so any divergence is an engine/service bug,
@@ -137,7 +137,7 @@ def test_grep_host_path_rejections():
 
 # ── grep: the step body against a plain per-line count ────────────────
 
-_STEP_N, _STEP_L_CAP, _STEP_BINS, _STEP_K = 256, 32, 8, 4
+_STEP_N, _STEP_BINS, _STEP_K = 256, 8, 4
 _STEP_BASE = (7 << 32) + 5  # global line numbers need both key lanes
 
 
@@ -149,8 +149,8 @@ def _run_step(data: bytes, pat: bytes, emit: bool = False):
     args = (chunk, np.frombuffer(pat, np.uint8)[None],
             np.array([len(data)], np.int32),
             np.array([_STEP_BASE], np.uint64))
-    fn = functools.partial(_grep_step_device, l_cap=_STEP_L_CAP,
-                           bins=_STEP_BINS, k=_STEP_K, emit=emit)
+    fn = functools.partial(_grep_step_device, bins=_STEP_BINS, k=_STEP_K,
+                           emit=emit)
     with enable_x64(True):
         return fn, args, [np.asarray(x) for x in jax.jit(fn)(*args)]
 
@@ -165,6 +165,7 @@ _STEP_CASES = {
     "ties_across_the_topk_edge":
         (b"ab ab\n" * 3 + b"ab ab ab\nq\n" + b"ab ab\n" * 3
          + b"ab ab ab\nab\n", b"ab"),
+    # 41 lines in 256 bytes: more than an eighth of the chunk
     "n_lines_above_l_cap": (b"a\n" * 20 + b"aaa\nb\n" * 10 + b"aa", b"a"),
     "pattern_ends_at_last_valid_byte": (b"xx\nab the\nabthe", b"the"),
     "full_chunk_one_open_line": (b"the " * (_STEP_N // 4), b"the"),
@@ -179,9 +180,9 @@ def test_grep_step_body_matches_plain_count(case):
     """The step program's three results against the host oracle (split
     at newlines, overlapping occurrences per line) over the same bytes:
     the histogram row, the scalars, and the candidate rows in (count
-    desc, line asc) order with ties to the earlier line.  ``n_lines``
-    above ``l_cap`` raises the flag and nothing else: the counts no
-    longer pass through a line buffer."""
+    desc, line asc) order with ties to the earlier line.  Lane 2 of the
+    scalar row is reserved and 0 whatever the line count: the counts
+    pass through no line buffer."""
     data, pat = _STEP_CASES[case]
     assert len(data) <= _STEP_N
     _, _, (hist_ext, cand, scal) = _run_step(data, pat)
@@ -190,8 +191,7 @@ def test_grep_step_body_matches_plain_count(case):
     assert hist_ext.shape == (1, _STEP_BINS + 3)
     assert cand.shape == (1, _STEP_K, 5) and scal.shape == (1, 5)
     assert hist_ext[0].tolist() == [*hist, n_lines, matched, occurrences]
-    assert scal[0].tolist() == [len(top), n_lines,
-                                int(n_lines > _STEP_L_CAP), matched,
+    assert scal[0].tolist() == [len(top), n_lines, 0, matched,
                                 occurrences]
     rows = cand[0].astype(np.int64)
     got = [(int((r[0] << 32) | r[1]) - _STEP_BASE, int(r[3]))
@@ -260,10 +260,20 @@ def test_grep_parity_grid_depth_x_device_accumulate():
                 assert st["step_pulls"] == 0
 
 
-def test_grep_forced_l_cap_replay_sticky():
-    """Short lines overflow the optimistic avg-line>=8B rung: the step
-    replays at the n+1 hard bound through the pipeline (NOT the host
-    fallback), the wider rung sticks, and results stay bit-identical."""
+def _grep_step_programs(n_dev: int, chunk_bytes: int, m: int):
+    """Names of the compiled ``grep_stream_*`` programs this process
+    holds for one shape (``backends/aotcache``'s memo)."""
+    from dsi_tpu.backends import aotcache
+
+    head = f"grep_stream_d{n_dev}_c{chunk_bytes}_m{m}_"
+    return sorted({key[0] for key in aotcache._memo
+                   if key[0].startswith(head)})
+
+
+def test_grep_short_lines_exact_no_replay():
+    """One-byte lines, far more of them than an eighth of the chunk:
+    the oracle's result on the host-merge and the device-service path,
+    nothing replayed."""
     blocks = [b"a\n" * 2000, b"aba\nx\n" * 500, b"a\n" * 2000]
     mesh = _mesh()
     want = grep_host_oracle(list(blocks), "aba")
@@ -271,10 +281,8 @@ def test_grep_forced_l_cap_replay_sticky():
     res = grep_streaming(list(blocks), "aba", mesh=mesh,
                          chunk_bytes=1 << 11, depth=2, pipeline_stats=st)
     assert res == want
-    assert st["replays"] >= 1
-    assert st["l_cap"] == (1 << 11) + 1  # the hard-bound rung stuck
-    # ...and exactly once per overflowing step, not once per later step:
-    assert st["replays"] <= st["steps"]
+    assert st["replays"] == 0 and st["replay_s"] == 0.0
+    assert st["steps"] >= 1 and "l_cap" not in st
     # same stream through the device services, same answer
     st2: dict = {}
     res2 = grep_streaming(list(blocks), "aba", mesh=mesh,
@@ -282,7 +290,25 @@ def test_grep_forced_l_cap_replay_sticky():
                           device_accumulate=True, sync_every=2,
                           pipeline_stats=st2)
     assert res2 == want
-    assert st2["replays"] >= 1 and st2["step_pulls"] == 0
+    assert st2["replays"] == 0 and st2["step_pulls"] == 0
+
+
+def test_grep_short_lines_leave_one_cache_entry():
+    """After a short-line stream the process holds exactly one
+    ``grep_stream_*`` executable for the shape (a chunk size no other
+    test of this file uses, so the entry is this stream's)."""
+    mesh = _mesh()
+    n_dev, chunk_bytes = mesh.devices.size, 3 << 9
+    assert _grep_step_programs(n_dev, chunk_bytes, 1) == []
+    blocks = [b"a\n" * 2000] * 3
+    st: dict = {}
+    res = grep_streaming(list(blocks), "a", mesh=mesh,
+                         chunk_bytes=chunk_bytes, depth=2,
+                         pipeline_stats=st)
+    assert res == grep_host_oracle(list(blocks), "a")
+    assert st["replays"] == 0 and st["steps"] >= 1
+    assert _grep_step_programs(n_dev, chunk_bytes, 1) == [
+        f"grep_stream_d{n_dev}_c{chunk_bytes}_m1_b8_t16"]
 
 
 def test_grep_forced_topk_widen_never_drops(monkeypatch):
@@ -482,8 +508,8 @@ def test_write_indexer_output_matches_host_app_format(tmp_path):
 
 def test_grep_warm_covers_everything(tmp_path, monkeypatch):
     """warm_grepstream_aot(device_accumulate=True) must pre-compile
-    every program a device-accumulated aot run then executes — both
-    l_cap rungs, the top-k fold/pack/snapshot shapes, the histogram
+    every program a device-accumulated aot run then executes — the one
+    step program, the top-k fold/pack/snapshot shapes, the histogram
     fold — so a chip run is loads, never compiles."""
     from dsi_tpu.backends import aotcache
     from dsi_tpu.parallel.grepstream import warm_grepstream_aot
@@ -491,6 +517,8 @@ def test_grep_warm_covers_everything(tmp_path, monkeypatch):
     mesh = default_mesh(1)
     warm_grepstream_aot(mesh=mesh, chunk_bytes=1 << 14,
                         device_accumulate=True)
+    assert _grep_step_programs(1, 1 << 14, 3) == [
+        "grep_stream_d1_c16384_m3_b8_t16"]
     compiles_after_warm = aotcache.stats["compiles"]
     blocks = [b"the quick fox\nthe end\n" * 200] * 3
     want = grep_host_oracle(list(blocks), "the")

@@ -503,11 +503,41 @@ def test_mrrun_net_off_loopback_with_hmac(tmp_path):
     assert "parity OK" in r.stderr
     with open(stats_json, encoding="utf-8") as f:
         s = json.load(f)
-    # off-loopback: the advertised addresses are not the local-read
-    # short-circuit's own_addr for OTHER workers, so fetches crossed
-    # the (authenticated) wire
-    assert s["net_fetches"] > 0
+    # Every mr-out-* the driver collected came over the authenticated
+    # wire (--check passed on them).  Whether the SHUFFLE crossed it is
+    # a race (a worker that won both maps reads its own spool), so that
+    # is asserted where every fetch is remote, below.
+    assert s["net_fetches"] + s["net_local_reads"] > 0
     assert s["net_fetch_failures"] == 0
+
+
+def test_net_remote_shuffle_off_loopback_with_hmac(tmp_path, monkeypatch):
+    # the reducer's worker ran none of the maps, both servers bind off
+    # the loopback allowlist: all 2 x 3 shuffle fetches answer the HMAC
+    # challenge, and the same server turns a wrong secret away
+    from tests.harness import net_job_split_roles, oracle_output
+
+    monkeypatch.setenv("DSI_MR_SECRET", "tier1-ci-secret")
+    corpora = []
+    for i in range(2):
+        path = str(tmp_path / f"corpus-{i}.txt")
+        _write_corpus(path, lines=800, seed=i)
+        corpora.append(path)
+    wd = str(tmp_path / "wd")
+    os.makedirs(wd)
+    with net_job_split_roles("wc", corpora, wd, n_reduce=3,
+                             bind="tcp:127.0.0.2:0") as job:
+        assert job.producer.address.startswith("tcp:127.0.0.2:")
+        assert job.consumer.address.startswith("tcp:127.0.0.2:")
+        assert job.lines == oracle_output("wc", corpora, wd)
+        assert job.stats["net_fetches"] == job.producer.served == 2 * 3
+        assert job.stats["net_local_reads"] == 0
+        assert job.stats["net_fetch_failures"] == 0
+        with pytest.raises(rpc.AuthError):
+            rpc.stream_fetch(job.producer.address, "Fetch",
+                             {"Name": "mr-0-0"}, secret="wrong",
+                             timeout=10.0)
+        assert job.producer.served == 2 * 3  # nothing served unauthenticated
 
 
 def test_planrun_hosts_parity_and_share_nothing_audit(tmp_path):

@@ -201,14 +201,17 @@ def test_grep_wc_forced_widen_inside_stage2(monkeypatch):
     assert get_registry().phases("stream").get("widens", 0) >= 1
 
 
-def test_grep_wc_short_lines_replay_rung():
-    # Dense short lines overflow the optimistic l_cap rung: stage 1
-    # replays at the wider rung and the emitted bytes stay exact.
+def test_grep_wc_short_lines_no_replay():
+    # Dense short lines, more of them than an eighth of a chunk: stage 1
+    # runs its one emit program, replays nothing, and the emitted bytes
+    # stay exact.
     data = corpus(short_lines=True)
     chained = run_plan(gw_plan(data), mesh=mesh())
     staged = run_plan(gw_plan(data), mesh=mesh(), staged=True)
     assert chained.final == staged.final
-    assert get_registry().phases("grep").get("replays", 0) >= 1
+    assert len(chained.final) > 0
+    grep = get_registry().phases("grep")
+    assert grep["steps"] >= 1 and grep["replays"] == 0
 
 
 def _kept_on_host(data: bytes, pat: bytes) -> bytes:

@@ -8,7 +8,7 @@ Two layers, the qos.py discipline:
   wall-clock races;
 * end-to-end integration on the in-process daemon — priority ordering
   observable in ``done_ts``, packed-grep byte parity vs the host
-  oracle (literal, non-literal/hostpath, rung-widen, and evict/resume
+  oracle (literal, non-literal/hostpath, short-line, and evict/resume
   arms), the packing evidence in ``grep_packer.stats``;
 * the ``slow``-marked soak — ``scripts/serve_soak.run_soak(1000)``,
   the acceptance bar's thousands-of-tenants churn.
@@ -351,10 +351,11 @@ def test_packed_grep_parity_and_hostpath(tmp_path):
         d.close()
 
 
-def test_grep_rung_widen_stays_exact(tmp_path):
-    """A tenant whose tiny lines overflow rung 0's line cap forces the
-    clean-prefix requeue + per-tenant widen — and only that tenant's
-    rung moves, with byte parity intact."""
+def test_grep_short_and_wide_lines_share_dispatches(tmp_path):
+    """A tenant of tiny lines (more than an eighth of a row's bytes)
+    and a tenant of wide lines, same pattern length: one pack group,
+    shared dispatches, every dispatched row confirmed in its own step,
+    byte parity for both."""
     spool = str(tmp_path / "spool")
     tiny = str(tmp_path / "tiny.txt")
     with open(tiny, "w") as f:
@@ -379,11 +380,13 @@ def test_grep_rung_widen_stays_exact(tmp_path):
                       "rb") as fh:
                 assert fh.read() == grep_oracle_bytes(f, "ab"), t
         st = d.grep_packer.stats
-        assert st["rung_widens"] >= 1 and st["replays"] >= 1
-        # The widen is visible per job: the tiny tenant retired on a
-        # higher rung.
-        assert final[reps["tiny"]["job_id"]]["stats"]["rung"] >= 1
-        assert final[reps["wide"]["job_id"]]["stats"]["rung"] == 0
+        assert st["max_tenants_per_step"] >= 2
+        # Nothing is replayed: the rows the jobs confirmed are the rows
+        # the packer dispatched, each once.
+        assert st.get("replays", 0) == 0 and "rung_widens" not in st
+        assert st["packed_rows"] == sum(
+            final[r["job_id"]]["stats"]["rows"] for r in reps.values())
+        assert st["host_fallbacks"] == 0
     finally:
         d.close()
 
