@@ -45,7 +45,9 @@ that phase):
   (the NEXT save or the end-of-stream drain found a commit in flight)
 
 Counters / gauges: ``steps`` (or ``waves``), ``depth``, ``replays``,
-``step_pulls``, ``sync_pulls``, ``widens``, ``folds``,
+``results_ready`` (steps whose host reads the device had already
+produced when ``finish`` first asked: the hit count of a copy started
+at dispatch), ``step_pulls``, ``sync_pulls``, ``widens``, ``folds``,
 ``fold_overflows``, ``appends``, ``append_overflows``,
 ``postings_widens``, ``topk_snapshots``, ``hist_folds``, ``hist_pulls``,
 ``table_cap``, ``sync_every``, ``max_inflight``,
@@ -183,8 +185,9 @@ PHASE_KEYS = (
 #: docstring, the static gate, and the test cannot drift apart.
 COUNTER_KEYS = (
     # pipeline / engine counters
-    "steps", "waves", "depth", "replays", "step_pulls", "sync_pulls",
-    "widens", "folds", "fold_overflows", "appends", "append_overflows",
+    "steps", "waves", "depth", "replays", "results_ready", "step_pulls",
+    "sync_pulls", "widens", "folds", "fold_overflows", "appends",
+    "append_overflows",
     "postings_widens", "topk_snapshots", "hist_folds", "hist_pulls",
     "table_cap", "sync_every", "max_inflight",
     "buffer_allocs", "device_accumulate", "donate_chunks", "stalls",

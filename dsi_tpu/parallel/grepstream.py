@@ -95,7 +95,7 @@ from dsi_tpu.ckpt import (
     skip_stream,
 )
 from dsi_tpu.device.policy import SyncPolicy, mesh_shards_default
-from dsi_tpu.device.table import (DeviceTable, _pow2,
+from dsi_tpu.device.table import (DeviceTable, _copy_to_host_async, _pow2,
                                   _quiet_unusable_donation)
 from dsi_tpu.device.topk import DeviceHistogram, DeviceTopK, KeyCounts
 from dsi_tpu.obs import metrics_scope, span as _span
@@ -388,8 +388,15 @@ def _grep_step_device(chunk, pat, dlen, base, *, bins: int, k: int,
             kept_n.reshape(1))
 
 
-def _grep_step_impl(chunks, pats, lens, bases, *, bins: int, k: int,
+def _grep_step_impl(chunks, pats, meta, *, bins: int, k: int,
                     mesh: Mesh, emit: bool = False):
+    """``meta`` is the step's one small input, ``uint64[n_dev, 2]``: per
+    row its valid byte count and its first line's global number
+    (:func:`step_meta`).  One array and not two because a host-to-device
+    put costs the host about a quarter of a millisecond per ARRAY
+    whatever its size (PERF.md §6, PR 29)."""
+    lens = meta[:, 0].astype(jnp.int32)
+    bases = meta[:, 1]
     body = functools.partial(_grep_step_device, bins=bins, k=k, emit=emit)
     out_specs = (P(AXIS, None), P(AXIS, None, None), P(AXIS, None))
     if emit:
@@ -401,6 +408,12 @@ def _grep_step_impl(chunks, pats, lens, bases, *, bins: int, k: int,
     )(chunks, pats, lens, bases)
 
 
+def step_meta(lens_np: np.ndarray, bases_np: np.ndarray) -> np.ndarray:
+    """The grep step's ``meta`` input from per-row byte counts and line
+    bases (host side of :func:`_grep_step_impl`)."""
+    return np.stack([lens_np, bases_np], axis=1).astype(np.uint64)
+
+
 def _grep_program(*, n_dev: int, chunk_bytes: int, m: int, bins: int,
                   k: int, mesh: Mesh, emit: bool = False):
     """(name, fn) for the one compiled grep step of a shape — single definition
@@ -409,8 +422,8 @@ def _grep_program(*, n_dev: int, chunk_bytes: int, m: int, bins: int,
     handoff's extra compaction outputs) is a distinct executable and
     gets a distinct name."""
 
-    def fn(chunks, pats, lens, bases):
-        return _grep_step_impl(chunks, pats, lens, bases, bins=bins, k=k,
+    def fn(chunks, pats, meta):
+        return _grep_step_impl(chunks, pats, meta, bins=bins, k=k,
                                mesh=mesh, emit=emit)
 
     # The HLO module takes the traced function's name: a device trace
@@ -425,8 +438,7 @@ def _grep_examples(n_dev: int, chunk_bytes: int, m: int):
     sds = jax.ShapeDtypeStruct
     return (sds((n_dev, chunk_bytes), jnp.uint8),
             sds((n_dev, m), jnp.uint8),
-            sds((n_dev,), jnp.int32),
-            sds((n_dev,), jnp.uint64))
+            sds((n_dev, 2), jnp.uint64))
 
 
 def _grep_fn(example_args, **kw):
@@ -611,7 +623,9 @@ def grep_streaming(
     with its parts ``device_wait_s`` + ``d2h_s``/``merge_s``/
     ``replay_s``/``finalize_s``, ``steps``/``replays``/``step_pulls``/
     ``sync_pulls``/``pull_bytes``, ``device_rows`` = confirmed lines per
-    device, plus the service counters; ``replays`` and ``replay_s`` are
+    device, ``results_ready`` = steps whose host reads the device had
+    already produced when ``finish_one`` first asked (their copies start
+    at dispatch), plus the service counters; ``replays`` and ``replay_s`` are
     the shared pipeline's keys and stay 0 here).
 
     ``checkpoint_dir``/``checkpoint_every``/``resume`` follow the
@@ -665,14 +679,13 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
     # schema, not its own dialect.
     stats = metrics_scope("grep")
     stats.update({"depth": depth, "steps": 0, "replays": 0,
-                  "step_pulls": 0, "sync_pulls": 0,
+                  "results_ready": 0, "step_pulls": 0, "sync_pulls": 0,
                   "device_accumulate": device_accumulate,
                   "batch_s": 0.0, "batch_wait_s": 0.0,
                   "upload_s": 0.0, "kernel_s": 0.0, "pull_s": 0.0,
                   "device_wait_s": 0.0, "d2h_s": 0.0, "pull_bytes": 0,
                   "merge_s": 0.0, "replay_s": 0.0})
     sh2 = NamedSharding(mesh, P(AXIS, None))
-    sh1 = NamedSharding(mesh, P(AXIS))
     pat_np = np.tile(np.frombuffer(pattern.encode("ascii"), np.uint8),
                      (n_dev, 1))
     pat_dev = jax.device_put(pat_np, sh2)  # once per stream, never donated
@@ -880,21 +893,41 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             fault_point("mid-capture")
             ck_writer.commit(parts, meta, kind=kind)
 
+    def host_reads(hist_d, cand_d, scal, kept_d):
+        """The arrays of one step that ``finish_one`` converts on the
+        host: the scalar row always, the histogram and candidate rows
+        where the host accumulates (with ``device_accumulate`` they stay
+        on the device), the kept counts of the ``emit`` handoff."""
+        reads = [scal]
+        if not device_accumulate:
+            reads += [hist_d, cand_d]
+        if emit:
+            reads.append(kept_d)
+        return reads
+
     def step_call(buf, lens_np, bases_np):
         with _span("upload", stats=stats, key="upload_s",
                    step=stats["steps"]):
-            chunks = jax.device_put(buf, sh2)
-            lens = jax.device_put(lens_np, sh1)
-            with enable_x64(True):  # keep the u64 bases u64 through it
-                bases = jax.device_put(bases_np.astype(np.uint64), sh1)
-        fn = _grep_fn((chunks, pat_dev, lens, bases), n_dev=n_dev,
+            # One put for the step's two inputs.  Under x64 so that the
+            # u64 line bases stay u64 through it.
+            with enable_x64(True):
+                chunks, meta = jax.device_put(
+                    (buf, step_meta(lens_np, bases_np)), (sh2, sh2))
+        fn = _grep_fn((chunks, pat_dev, meta), n_dev=n_dev,
                       chunk_bytes=chunk_bytes, m=m, bins=bins, k=topk,
                       mesh=mesh, emit=emit)
         with _quiet_unusable_donation():
-            outs = fn(chunks, pat_dev, lens, bases)
-        if emit:
-            return outs  # (hist, cand, scal, comp, kept)
-        return outs + (None, None)
+            outs = fn(chunks, pat_dev, meta)
+        if not emit:
+            outs += (None, None)  # (hist, cand, scal, comp, kept)
+        hist_d, cand_d, scal, _, kept_d = outs
+        # The results start for the host now, behind the step on the
+        # device's queue, not when ``finish_one`` asks for them one pump
+        # later: it then reads finished copies instead of paying one
+        # round trip per array.
+        for arr in host_reads(hist_d, cand_d, scal, kept_d):
+            _copy_to_host_async(arr)
+        return outs
 
     def dispatch(item):
         buf, lens_np, row_lines = item
@@ -917,6 +950,11 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
     def finish_one(record) -> None:
         buf, row_lines, hist_d, cand_d, scal, comp_d, kept_d, \
             rec_offset, rec_lines = record
+        # Asked before the first read and without blocking: had the
+        # device produced everything this step's host reads convert?
+        if all(arr.is_ready()
+               for arr in host_reads(hist_d, cand_d, scal, kept_d)):
+            stats["results_ready"] += 1
         with _span("kernel", stats=stats, key="kernel_s"):
             scal_np = np.asarray(scal)  # blocks until the kernel lands
         if not np.array_equal(scal_np[:, 1].astype(np.int64), row_lines):

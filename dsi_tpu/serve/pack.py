@@ -668,7 +668,6 @@ class PackedGrepScheduler:
                            "kernel_s": 0.0, "pull_s": 0.0,
                            "merge_s": 0.0, "max_tenants_per_step": 0})
         self._sh_chunk = NamedSharding(mesh, P(AXIS, None))
-        self._sh_row = NamedSharding(mesh, P(AXIS))
         self._rr = 0
         self._jax = jax
 
@@ -699,21 +698,20 @@ class PackedGrepScheduler:
 
     def _dispatch(self, chunk_np, pats_np, lens_np, bases_np, m):
         from dsi_tpu.device.table import _quiet_unusable_donation
-        from dsi_tpu.parallel.grepstream import grep_pack_fn
+        from dsi_tpu.parallel.grepstream import grep_pack_fn, step_meta
         from dsi_tpu.utils.jaxcompat import enable_x64
 
         with _span("upload", stats=self.stats, key="upload_s"):
             chunk = self._jax.device_put(chunk_np, self._sh_chunk)
             pats = self._jax.device_put(pats_np, self._sh_chunk)
-            lens = self._jax.device_put(lens_np, self._sh_row)
             with enable_x64(True):   # keep the u64 bases u64 through it
-                bases = self._jax.device_put(
-                    bases_np.astype(np.uint64), self._sh_row)
+                meta = self._jax.device_put(step_meta(lens_np, bases_np),
+                                            self._sh_chunk)
         fn = grep_pack_fn(self.n_dev, self.chunk_bytes, m,
                           bins=self.bins, k=self.topk, mesh=self.mesh)
         with _span("kernel", stats=self.stats, key="kernel_s"):
             with _quiet_unusable_donation():
-                hist_ext, cand, scal = fn(chunk, pats, lens, bases)
+                hist_ext, cand, scal = fn(chunk, pats, meta)
         with _span("pull", stats=self.stats, key="pull_s"):
             return (np.asarray(hist_ext), np.asarray(cand),
                     np.asarray(scal))

@@ -353,6 +353,128 @@ def test_grep_sync_accounting_windows_plus_close():
         assert st["topk_snapshots"] == windows
 
 
+# ── one transfer each way per step (PR 29) ─────────────────────────────
+
+
+def _prefetch_blocks():
+    # ~115 KB of short lines: 8 steps of 8 x 2 KiB, matches in every one
+    return _grep_blocks(31, n_blocks=240)
+
+
+def _run_prefetch_path(path, depth, tmp_path, monkeypatch):
+    """One stream through one of the four paths whose ``finish_one``
+    converts different arrays on the host; returns (result, relay bytes
+    or None, stats of the run that finished the stream)."""
+    from dsi_tpu.ckpt import FaultInjected, reset_faults
+    from dsi_tpu.device.relay import HostRelay
+    from dsi_tpu.parallel.grepstream import GrepStep
+
+    blocks, mesh, st = _prefetch_blocks(), _mesh(), {}
+    kw = dict(mesh=mesh, chunk_bytes=1 << 11, depth=depth,
+              pipeline_stats=st)
+    if path == "emit":
+        relay = HostRelay()
+        res = GrepStep(list(blocks), "aba", line_sink=relay, **kw).close()
+        return res, b"".join(relay.blocks()), st
+    if path == "resume":
+        ck = str(tmp_path / "ck")
+        monkeypatch.setenv("DSI_FAULT_MODE", "raise")
+        monkeypatch.setenv("DSI_FAULT_POINT", "mid-fold")
+        monkeypatch.setenv("DSI_FAULT_STEP", "3")
+        reset_faults()
+        with pytest.raises(FaultInjected):
+            grep_streaming(list(blocks), "aba", checkpoint_dir=ck,
+                           checkpoint_every=1, **kw)
+        for k in ("DSI_FAULT_MODE", "DSI_FAULT_POINT", "DSI_FAULT_STEP"):
+            monkeypatch.delenv(k)
+        reset_faults()
+        st.clear()
+        res = grep_streaming(list(blocks), "aba", checkpoint_dir=ck,
+                             checkpoint_every=1, resume=True, **kw)
+        assert 0 < st["resume_cursor"] < sum(map(len, blocks))
+        return res, None, st
+    res = grep_streaming(list(blocks), "aba", sync_every=2,
+                         device_accumulate=(path == "device_accumulate"),
+                         **kw)
+    return res, None, st
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("path", ["host_accumulate", "device_accumulate",
+                                  "emit", "resume"])
+def test_grep_prefetched_results_equal_the_oracle(path, depth, tmp_path,
+                                                  monkeypatch):
+    """The copies that start at dispatch change no answer, whichever
+    arrays the path converts on the host, at depth 1 (read at once) and
+    2 (read one pump later)."""
+    res, kept, st = _run_prefetch_path(path, depth, tmp_path, monkeypatch)
+    assert res == grep_host_oracle(_prefetch_blocks(), "aba")
+    if kept is not None:
+        lines = b"".join(_prefetch_blocks()).split(b"\n")
+        assert kept == b"".join(ln + b"\n" for ln in lines if b"aba" in ln)
+    assert 0 <= st["results_ready"] <= st["steps"] and st["steps"] >= 4
+
+
+@pytest.mark.parametrize("path,per_step", [
+    ("host_accumulate", [(8, 5), (8, 11), (8, 16, 5)]),
+    ("device_accumulate", [(8, 5)]),
+    ("emit", [(8, 5), (8, 11), (8, 16, 5), (8,)]),
+])
+def test_grep_host_copies_start_only_for_what_finish_converts(
+        path, per_step, tmp_path, monkeypatch):
+    """What ``step_call`` hands the helper, by shape: the scalar row
+    always; the histogram and candidate rows only where the host
+    accumulates (with ``device_accumulate`` they stay on the device and
+    no copy of them starts); the kept counts with ``emit``.  The helper
+    here also waits for the array, so every step's reads are ready by
+    construction and ``results_ready`` must count them all."""
+    from dsi_tpu.parallel import grepstream
+
+    started = []
+
+    def start_and_wait(arr):
+        started.append(tuple(arr.shape))
+        arr.block_until_ready()
+
+    monkeypatch.setattr(grepstream, "_copy_to_host_async", start_and_wait)
+    res, _, st = _run_prefetch_path(path, 2, tmp_path, monkeypatch)
+    assert res == grep_host_oracle(_prefetch_blocks(), "aba")
+    assert started == per_step * st["steps"]
+    assert st["results_ready"] == st["steps"]
+
+
+def test_grep_step_puts_its_inputs_in_one_call(monkeypatch):
+    """A step's inputs go up in one ``device_put`` (the pattern goes up
+    once a stream): the chunk and ONE small array, uint64 so that the
+    line bases stay whole, holding each row's byte count and line base."""
+    puts = []
+    real_put = jax.device_put
+
+    def counting_put(x, *a, **kw):
+        out = real_put(x, *a, **kw)
+        puts.append(out)
+        return out
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    st: dict = {}
+    res = grep_streaming(_prefetch_blocks(), "aba", mesh=_mesh(),
+                         chunk_bytes=1 << 11, depth=2, pipeline_stats=st)
+    assert res == grep_host_oracle(_prefetch_blocks(), "aba")
+    pattern, steps = puts[0], puts[1:]
+    assert pattern.shape == (8, 3)
+    assert len(steps) == st["steps"] >= 4
+    all_bases = []
+    for chunks, meta in steps:
+        assert chunks.shape == (8, 1 << 11) and chunks.dtype == np.uint8
+        assert meta.shape == (8, 2) and meta.dtype == np.uint64
+        lens, bases = np.asarray(meta).T
+        assert lens.max() <= 1 << 11
+        all_bases += bases.tolist()
+    # each row's first line number: from 0, never backwards, within the total
+    assert all_bases[0] == 0 and all_bases == sorted(all_bases)
+    assert all_bases[-1] <= res.lines
+
+
 def test_grep_property_random_streams():
     """Property: random streams x random K x both paths, equal to the
     oracle and to each other."""
