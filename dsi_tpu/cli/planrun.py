@@ -342,13 +342,24 @@ def main(argv=None) -> int:
           file=sys.stderr)
 
     os.makedirs(args.workdir, exist_ok=True)
+    # What --stats prints as pipeline_stats: the engines' scopes per
+    # stage, the plan scope, and the commit below.
+    pstats = {"stages": stats.get("stage_stats", {}),
+              "plan": {k: v for k, v in stats.items()
+                       if k != "stage_stats"}}
     if args.chain == "grep-wc":
+        from dsi_tpu.obs import span
         from dsi_tpu.parallel.shuffle import write_partitioned_output
 
         g = res.results["grep"]
         print(f"planrun: grep lines={g.lines} matched={g.matched} "
               f"occurrences={g.occurrences}", file=sys.stderr)
-        write_partitioned_output(res.final, args.nreduce, args.workdir)
+        with span("write", lane="host", stats=pstats,
+                  keys=len(res.final)) as sp:
+            paths = write_partitioned_output(res.final, args.nreduce,
+                                             args.workdir)
+            sp.set(bytes=sum(os.path.getsize(path) for path in paths))
+        pstats["write_s"] = round(pstats["write_s"], 4)
     elif args.chain == "grep-grep":
         stages = {name: {"lines": r.lines, "matched": r.matched,
                          "occurrences": r.occurrences}
@@ -380,8 +391,11 @@ def main(argv=None) -> int:
         print(f"planrun: join of {len(out)} terms -> {path}",
               file=sys.stderr)
 
+    # After the commit, so that the line holds the job's tail too
+    # (write_s) and the trace its last span.
     if args.stats:
-        print(f"planrun: plan_stats={stats}", file=sys.stderr)
+        print(f"planrun: plan_stats={pstats['plan']}", file=sys.stderr)
+        print(f"planrun: pipeline_stats={pstats}", file=sys.stderr)
     if args.stats_json:
         # dsicheck: allow[raw-write] bench parse surface, not durable state
         with open(args.stats_json, "w", encoding="utf-8") as f:

@@ -40,62 +40,75 @@ zero.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dsi_tpu.obs import count as _count, span as _span
 from dsi_tpu.parallel.shuffle import AXIS
+from dsi_tpu.utils.jaxcompat import shard_map
 
-#: jax.jit donate_argnums for the pack program: both the accumulation
-#: buffer (rebound to the program's output) and the appended chunk are
-#: consumed by the concatenation.
-_RELAY_DONATE = (0, 2)
-
-
-def _pack_impl(acc, off, new):
-    """Per-row concatenation at a dynamic offset: ``out[r, i] = acc[r, i]``
-    for ``i < off[r]`` else ``new[r, i - off[r]]``.  Pure elementwise +
-    per-row gather, so a ``[AXIS, None]``-sharded call stays shard-local
-    (no collectives — each device packs its own row)."""
-    n = acc.shape[1]
-    idx = jnp.arange(n, dtype=jnp.int32)[None, :]
-    offc = off[:, None].astype(jnp.int32)
-    shifted = jnp.take_along_axis(new, jnp.clip(idx - offc, 0, n - 1),
-                                  axis=1)
-    return jnp.where(idx < offc, acc, shifted)
+#: jax.jit donate_argnums for the pack program: the accumulation buffer
+#: is rebound to the program's one output, so it is the one buffer that
+#: output can alias.  The appended chunk is consumed too (the relay
+#: drops its reference), but a second donation of the same shape has no
+#: output left to alias and only earns jax's "not usable" warning.
+_RELAY_DONATE = (0,)
 
 
-_pack_jit = jax.jit(_pack_impl, donate_argnums=_RELAY_DONATE)
+def _pack_row(acc, off, new):
+    """One device's row (the body under ``shard_map``: ``[1, n]``,
+    ``[1]``, ``[1, n]``): ``out[i] = acc[i]`` for ``i < off`` else
+    ``new[i - off]``.  The appended row moves right by ``off`` as ONE
+    window of itself behind ``n`` zeros — a dynamic slice, which the
+    TPU runs as a copy; as a ``take_along_axis`` over computed indices
+    it stays a gather of ``n`` single bytes there (PERF.md §6, PR 30)."""
+    with jax.named_scope("relay_pack"):
+        n = acc.shape[1]
+        o = off.reshape(()).astype(jnp.int32)
+        behind = jnp.concatenate([jnp.zeros((n,), new.dtype), new[0]])
+        shifted = lax.dynamic_slice(behind, (n - o,), (n,))
+        idx = jnp.arange(n, dtype=jnp.int32)
+        return jnp.where(idx < o, acc[0], shifted)[None]
 
 
-def _relay_pack_program(*, n_dev: int, cap: int):
-    """(name, fn) for one compiled relay pack shape — the shared
-    definition discipline (``streaming._step_program``)."""
+def _relay_pack(mesh: Mesh):
+    """The pack program over ``mesh``'s rows: under ``shard_map`` each
+    device packs its own row at its own offset, no collectives.  The
+    HLO module takes the traced function's name: a device trace shows
+    ``jit_relay_pack``."""
 
-    def fn(acc, off, new):
-        return _pack_impl(acc, off, new)
+    def relay_pack(acc, off, new):
+        return shard_map(_pack_row, mesh=mesh,
+                         in_specs=(P(AXIS, None), P(AXIS), P(AXIS, None)),
+                         out_specs=P(AXIS, None))(acc, off, new)
 
-    return f"plan_pack_d{n_dev}_c{cap}", fn
-
-
-def _relay_structs(n_dev: int, cap: int):
-    sds = jax.ShapeDtypeStruct
-    return (sds((n_dev, cap), jnp.uint8), sds((n_dev,), jnp.int32),
-            sds((n_dev, cap), jnp.uint8))
+    return relay_pack
 
 
-def _pack_fn(aot: bool, *, n_dev: int, cap: int):
+@functools.lru_cache(maxsize=None)
+def _pack_jit(mesh: Mesh):
+    return jax.jit(_relay_pack(mesh), donate_argnums=_RELAY_DONATE)
+
+
+def _pack_fn(aot: bool, *, mesh: Mesh, cap: int):
     if not aot:
-        return _pack_jit
+        return _pack_jit(mesh)
     from dsi_tpu.backends import aotcache
     from dsi_tpu.device.table import _quiet_unusable_donation
 
-    name, fn = _relay_pack_program(n_dev=n_dev, cap=cap)
+    n_dev = int(mesh.devices.size)
+    sds = jax.ShapeDtypeStruct
+    structs = (sds((n_dev, cap), jnp.uint8), sds((n_dev,), jnp.int32),
+               sds((n_dev, cap), jnp.uint8))
     with _quiet_unusable_donation():
-        return aotcache.cached_compile(name, fn, _relay_structs(n_dev, cap),
+        return aotcache.cached_compile(f"plan_pack_d{n_dev}_c{cap}",
+                                       _relay_pack(mesh), structs,
                                        donate_argnums=_RELAY_DONATE)
 
 
@@ -104,8 +117,11 @@ class DeviceRelay:
 
     ``stats`` is the plan run's metrics scope: ``plan_intermediate_bytes``
     counts bytes that crossed the host on the HANDOFF path (0 here unless
-    spilled), ``plan_relay_buffers`` the sealed-buffer count, and
-    ``plan_spilled_bytes`` the spill volume.  ``spill_bytes`` bounds
+    spilled), ``plan_relay_buffers`` the sealed-buffer count,
+    ``plan_spilled_bytes`` the spill volume, and ``relay_appends`` /
+    ``relay_seals`` / ``relay_append_s`` / ``relay_spill_s`` what the
+    producer's appends cost the host (the ``relay_append`` and
+    ``relay_spill`` spans, lane ``plan``).  ``spill_bytes`` bounds
     device residency: when the relay's buffer bytes exceed it, the oldest
     sealed buffers are pulled to the host (counted) until back under.
     """
@@ -121,6 +137,8 @@ class DeviceRelay:
         self.stats.setdefault("plan_handoff_bytes", 0)
         self.stats.setdefault("plan_relay_buffers", 0)
         self.stats.setdefault("plan_spilled_bytes", 0)
+        self.stats.setdefault("relay_appends", 0)
+        self.stats.setdefault("relay_seals", 0)
         self.spill_bytes = max(0, int(spill_bytes))
         self._sh = NamedSharding(mesh, P(AXIS, None))
         self._sh1 = NamedSharding(mesh, P(AXIS))
@@ -138,32 +156,45 @@ class DeviceRelay:
     def append(self, comp_dev, kept: np.ndarray) -> None:
         """Append one confirmed step's compacted ``[n_dev, cap]`` output
         (fill ``kept[r]`` bytes per row, zero tail).  ``comp_dev`` is
-        consumed (donated to the pack program or adopted as the next
+        consumed (handed to the pack program or adopted as the next
         accumulation buffer) — the producer must not reuse it."""
         kept = np.asarray(kept, dtype=np.int64)
-        if int(kept.sum()) == 0:
-            return
-        self.total_bytes += int(kept.sum())
-        self.stats["plan_handoff_bytes"] += int(kept.sum())
+        content = int(kept.sum())
+        self.stats["relay_appends"] += 1
+        _count("relay_appends")
+        with _span("relay_append", lane="plan", stats=self.stats,
+                   bytes=content) as sp:
+            sp.set(sealed=self._append(comp_dev, kept, content)
+                   if content else 0)
+
+    def _append(self, comp_dev, kept: np.ndarray, content: int) -> int:
+        """The append proper; returns the buffers it sealed (0 or 1)."""
+        self.total_bytes += content
+        self.stats["plan_handoff_bytes"] += content
+        sealed = 0
         if self._acc is None:
             self._acc = comp_dev
             self._lens = kept.copy()
         elif bool(((self._lens + kept) > self.cap).any()):
             self._seal()
+            sealed = 1
             self._acc = comp_dev
             self._lens = kept.copy()
         else:
             off = jax.device_put(self._lens.astype(np.int32), self._sh1)
-            fn = _pack_fn(self.aot, n_dev=self.n_dev, cap=self.cap)
+            fn = _pack_fn(self.aot, mesh=self.mesh, cap=self.cap)
             self._acc = fn(self._acc, off, comp_dev)
             self._lens += kept
         self._maybe_spill()
+        return sealed
 
     def _seal(self) -> None:
         self._sealed.append(self._acc)
         self._sealed_lens.append(self._lens.copy())
         self._acc = None
         self.stats["plan_relay_buffers"] += 1
+        self.stats["relay_seals"] += 1
+        _count("relay_seals")
 
     def _maybe_spill(self) -> None:
         if not self.spill_bytes:
@@ -178,9 +209,10 @@ class DeviceRelay:
         i = 0
         while resident() > self.spill_bytes and i < len(self._sealed):
             if not isinstance(self._sealed[i], np.ndarray):
-                host = np.asarray(self._sealed[i])
                 content = int(self._sealed_lens[i].sum())
-                self._sealed[i] = host
+                with _span("relay_spill", lane="plan", stats=self.stats,
+                           bytes=content):
+                    self._sealed[i] = np.asarray(self._sealed[i])
                 self.stats["plan_spilled_bytes"] += content
                 self.stats["plan_intermediate_bytes"] += content
             i += 1
@@ -285,17 +317,22 @@ class HostRelay:
         self.stats = stats if stats is not None else {}
         self.stats.setdefault("plan_intermediate_bytes", 0)
         self.stats.setdefault("plan_handoff_bytes", 0)
+        self.stats.setdefault("relay_appends", 0)
         self._chunks: List[bytes] = []
         self.total_bytes = 0
 
     def append(self, comp_dev, kept: np.ndarray) -> None:
-        comp_np = np.asarray(comp_dev)
         kept = np.asarray(kept, dtype=np.int64)
-        for r in range(comp_np.shape[0]):
-            k = int(kept[r])
-            if k:
-                self._chunks.append(comp_np[r, :k].tobytes())
         content = int(kept.sum())
+        self.stats["relay_appends"] += 1
+        _count("relay_appends")
+        with _span("relay_append", lane="plan", stats=self.stats,
+                   bytes=content, sealed=0):
+            comp_np = np.asarray(comp_dev)
+            for r in range(comp_np.shape[0]):
+                k = int(kept[r])
+                if k:
+                    self._chunks.append(comp_np[r, :k].tobytes())
         self.total_bytes += content
         self.stats["plan_handoff_bytes"] += content
         self.stats["plan_intermediate_bytes"] += content

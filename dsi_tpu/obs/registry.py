@@ -87,6 +87,14 @@ durability cost, deliberately NOT handoff bytes),
 ``plan_resumed_stages`` (stages skipped by a resume from stage
 manifests), ``plan_stage_walls`` (per-stage wall seconds, keyed by
 stage name), plus the ``plan_s`` / ``stage_commit_s`` phases.
+``stage_stats`` maps each stage's name to the scope of the engine that
+ran it (its ``pipeline_stats`` as ``wcstream`` / ``grepstream`` print
+them, plus ``bytes_in``, the bytes the stage read; a list of them, one
+per shard, where ``stage_shards`` split the stage).  The relay's own
+cost is ``relay_appends`` (calls of ``append``), ``relay_seals``
+(buffers sealed by this run; ``plan_relay_buffers`` also counts
+restored ones) and the ``relay_append_s`` / ``relay_spill_s`` phases
+(the ``relay_append`` and ``relay_spill`` spans).
 
 ``device_rows`` (the "stream" scope) is a length-``n_dev`` list: the
 reduce-output rows each device of the mesh produced, summed over
@@ -174,6 +182,9 @@ PHASE_KEYS = (
     # elastic dataflow (ISSUE 16): wall spent with two adjacent stages
     # advancing concurrently (seal-driven pipelining)
     "plan_overlap_s",
+    # the plan layer's relay (device/relay.py): the host's cost of the
+    # producer's appends, and of the pulls a spill budget forces
+    "relay_append_s", "relay_spill_s",
     # overlapped shuffle (ISSUE 18): consumer time blocked on the
     # prefetch pool vs dialer wire time hidden behind the decode
     "net_fetch_wait_s", "net_overlap_s",
@@ -217,6 +228,8 @@ COUNTER_KEYS = (
     "plan_resumed_stages", "plan_stage_walls",
     # elastic dataflow (ISSUE 16): pipelined pair + stage-shard fan-out
     "plan_pipelined", "plan_stage_shards",
+    # per-stage engine scopes, a stage's input bytes, relay calls
+    "stage_stats", "bytes_in", "relay_appends", "relay_seals",
     # network data plane (ISSUE 17, the "net" scope, dsi_tpu/net):
     # worker-served shuffle attribution — raw vs wire bytes is the
     # codec's evidence, locality_hits the placement policy's, and

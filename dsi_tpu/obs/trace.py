@@ -93,6 +93,8 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     "wait", "finish", "drain", "append", "hist_fold", "hist_pull",
     "ckpt_capture", "ckpt_commit", "ckpt_save", "ckpt_restore", "task",
     "decode", "stage_commit", "resplit", "stage_overlap",
+    # the plan layer's device relay (device/relay.py)
+    "relay_append", "relay_spill",
     # the batch plane's tasks and what a task and a launch consist of
     "worker.map", "worker.reduce", "read", "write", "rpc", "d2h",
     "finalize", "probe", "backend_init",

@@ -1104,7 +1104,7 @@ def warm_grepstream_aot(mesh: Mesh | None = None,
     if emit:
         from dsi_tpu.device.relay import _pack_fn
 
-        _pack_fn(True, n_dev=n_dev, cap=chunk_bytes)
+        _pack_fn(True, mesh=mesh, cap=chunk_bytes)
     if device_accumulate:
         from dsi_tpu.device.topk import warm_histogram, warm_topk_service
 

@@ -708,6 +708,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
     # late-detected overflow, so their step programs must not consume
     # it — donation is a host-upload optimization only.
     donate_steps = device_batches is None
+    stats["donate_chunks"] = donate_steps
     wire_raw_total = [0]  # raw-equivalent bytes of the packed uploads
     if wire:
         stats.update({"wire_upload": True, "wire_steps": 0,

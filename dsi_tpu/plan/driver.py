@@ -78,7 +78,12 @@ class StageOut:
 
 class PlanResult:
     """``results[name]`` per stage, ``final`` = last stage's result,
-    ``stats`` = the run's plan scope (plan_* keys, obs/registry.py)."""
+    ``stats`` = the run's plan scope (plan_* keys, obs/registry.py),
+    with ``stage_stats``: per stage that ran an engine, that engine's
+    own scope (``steps``, ``replays``, ``device_rows``, ``upload_s``,
+    ``kernel_s``, ... as ``wcstream --stats`` / ``grepstream --stats``
+    print them) plus ``bytes_in``; a list of them, in shard order,
+    where ``stage_shards`` split the stage."""
 
     def __init__(self, results: Dict, final, stats: Dict):
         self.results = results
@@ -94,6 +99,37 @@ def _spill_bytes(plan: Plan) -> int:
         except ValueError:
             mb = 0.0
     return int(float(mb) * 1e6)
+
+
+class _Books:
+    """What the driver keeps for one engine of a stage: the dict the
+    engine copies its scope into when it closes, and the bytes it was
+    given — counted off the block stream as the engine reads it, or
+    set by the driver where the input is a relay."""
+
+    def __init__(self, blocks=None):
+        self.stats: dict = {}
+        self.bytes_in = 0
+        self._blocks = blocks
+
+    def __iter__(self):
+        for block in self._blocks:
+            self.bytes_in += len(block)
+            yield block
+
+    def record(self) -> dict:
+        return dict(self.stats, bytes_in=self.bytes_in)
+
+
+def _note_stage(sc: dict, sp, stage: Stage, books: List[_Books],
+                sharded: bool = False) -> None:
+    """File the stage's engine scopes under ``stage_stats`` and put the
+    stage's ``steps`` and ``bytes_in`` on its ``plan`` span, so that a
+    ``--trace-dir`` file reads without the stats line."""
+    recs = [b.record() for b in books]
+    sc["stage_stats"][stage.name] = recs if sharded else recs[0]
+    sp.set(steps=sum(int(r.get("steps", 0)) for r in recs),
+           bytes_in=sum(r["bytes_in"] for r in recs))
 
 
 def _drive(step, i: int):
@@ -283,7 +319,7 @@ def run_plan(plan: Plan, *, mesh=None, staged: bool = False,
                "plan_stage_shards": stage_shards,
                "plan_overlap_s": 0.0,
                "plan_s": 0.0, "stage_commit_s": 0.0,
-               "plan_stage_walls": {}})
+               "plan_stage_walls": {}, "stage_stats": {}})
     order = plan.ordered()
     sig = plan.signature()
     ctx: Dict[str, StageOut] = {}
@@ -350,9 +386,9 @@ def run_plan(plan: Plan, *, mesh=None, staged: bool = False,
             continue
         t0 = time.perf_counter()
         with _span("plan", stats=sc, key="plan_s", stage=stage.name,
-                   kind=stage.kind):
+                   kind=stage.kind) as sp:
             out = _run_stage(plan, i, stage, ctx, mesh, staged, sc,
-                             stage_shards)
+                             stage_shards, sp)
         ctx[stage.name] = out
         sc["plan_stage_walls"][stage.name] = round(
             time.perf_counter() - t0, 4)
@@ -362,6 +398,9 @@ def run_plan(plan: Plan, *, mesh=None, staged: bool = False,
     sc["plan_s"] = round(sc["plan_s"], 4)
     sc["stage_commit_s"] = round(sc["stage_commit_s"], 4)
     sc["plan_overlap_s"] = round(sc["plan_overlap_s"], 4)
+    for k in ("relay_append_s", "relay_spill_s"):
+        if k in sc:
+            sc[k] = round(sc[k], 4)
     if stats is not None:
         stats.update(sc)
     results = {name: out.result for name, out in ctx.items()}
@@ -424,26 +463,27 @@ class _RelayFeed:
 
 def _grep_steps(plan: Plan, stage: Stage, relay, mesh, kw,
                 stage_shards: int, ctx: Optional[Dict] = None):
-    """The stage's grep step(s): K shard steps over newline-aligned
-    byte ranges when sharding applies, else one step over the whole
-    source (or the upstream relay's line stream — the cascade)."""
+    """The stage's grep step(s), each with its books: K shard steps
+    over newline-aligned byte ranges when sharding applies, else one
+    step over the whole source (or the upstream relay's line stream —
+    the cascade).  Returns ``(steps, books, sharded)``."""
     from dsi_tpu.parallel.grepstream import GrepStep
 
     pattern = plan.param(stage, "pattern")
     topk = int(plan.param(stage, "topk", 16))
+    specs = None
     if stage.deps:
         up = ctx[stage.deps[0]]
-        src = (up.relay.blocks() if hasattr(up.relay, "blocks")
-               else up.relay.host_blocks())
-        return [GrepStep(src, pattern, mesh=mesh, topk=topk,
-                         line_sink=relay, **kw)], False
-    specs = _shard_specs(plan, stage, stage_shards)
-    if specs is None:
-        return [GrepStep(_source_blocks(plan, stage), pattern, mesh=mesh,
-                         topk=topk, line_sink=relay, **kw)], False
-    return [GrepStep(_spec_blocks(plan, stage, spec), pattern, mesh=mesh,
-                     topk=topk, line_sink=relay, **kw)
-            for spec in specs], True
+        sources = [up.relay.blocks() if hasattr(up.relay, "blocks")
+                   else up.relay.host_blocks()]
+    else:
+        specs = _shard_specs(plan, stage, stage_shards)
+        sources = ([_source_blocks(plan, stage)] if specs is None
+                   else [_spec_blocks(plan, stage, spec) for spec in specs])
+    books = [_Books(src) for src in sources]
+    steps = [GrepStep(b, pattern, mesh=mesh, topk=topk, line_sink=relay,
+                      pipeline_stats=b.stats, **kw) for b in books]
+    return steps, books, specs is not None
 
 
 def _run_pipelined_pair(plan: Plan, i: int, g_stage: Stage,
@@ -461,19 +501,21 @@ def _run_pipelined_pair(plan: Plan, i: int, g_stage: Stage,
     kw = _engine_kw(plan, g_stage)
     relay = DeviceRelay(mesh, cap=kw["chunk_bytes"], aot=kw["aot"],
                         stats=sc, spill_bytes=_spill_bytes(plan))
-    gsteps, sharded = _grep_steps(plan, g_stage, relay, mesh, kw,
-                                  stage_shards)
+    gsteps, g_books, sharded = _grep_steps(plan, g_stage, relay, mesh, kw,
+                                           stage_shards)
     wkw = _engine_kw(plan, wc_stage)
     feed = _RelayFeed()
+    w_books = _Books()
     wc = WordcountStep([], mesh=mesh,
                        n_reduce=int(plan.param(wc_stage, "n_reduce", 10)),
                        u_cap=int(plan.param(wc_stage, "u_cap", 1 << 12)),
-                       device_batches=feed, **wkw)
+                       device_batches=feed, pipeline_stats=w_books.stats,
+                       **wkw)
     fed = consumed = 0
     wc_live = True
     t0 = time.perf_counter()
     with _span("plan", stats=sc, key="plan_s", stage=g_stage.name,
-               kind="grep"):
+               kind="grep") as sp:
         live = list(gsteps)
         while live:
             nxt = []
@@ -493,6 +535,7 @@ def _run_pipelined_pair(plan: Plan, i: int, g_stage: Stage,
                         wc_live = wc.advance()
                         consumed += 1
         g_results = [st.close() for st in gsteps]
+        _note_stage(sc, sp, g_stage, g_books, sharded)
     g_wall = time.perf_counter() - t0
     if any(r is None for r in g_results):
         feed.close()
@@ -508,11 +551,13 @@ def _run_pipelined_pair(plan: Plan, i: int, g_stage: Stage,
         fed += 1
     feed.close()
     with _span("plan", stats=sc, key="plan_s", stage=wc_stage.name,
-               kind="wordcount"):
+               kind="wordcount") as sp:
         while wc_live:
             fault_point(f"plan-stage{i + 1}-advance")
             wc_live = wc.advance()
         w_res = wc.close()
+        w_books.bytes_in = relay.total_bytes
+        _note_stage(sc, sp, wc_stage, [w_books])
     if w_res is None:
         raise PlanHostPath(f"stage {wc_stage.name!r}: wordcount needs "
                            f"the host path (non-ASCII or >64-byte "
@@ -522,7 +567,9 @@ def _run_pipelined_pair(plan: Plan, i: int, g_stage: Stage,
 
 
 def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
-               staged: bool, sc: dict, stage_shards: int = 0) -> StageOut:
+               staged: bool, sc: dict, stage_shards: int, sp) -> StageOut:
+    """Run one stage to its end.  ``sp`` is the stage's open ``plan``
+    span: a stage that drives an engine notes its books on it."""
     kw = _engine_kw(plan, stage)
     if stage.kind == "grep":
         from dsi_tpu.device.relay import DeviceRelay, HostRelay
@@ -531,10 +578,11 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
                  else DeviceRelay(mesh, cap=kw["chunk_bytes"],
                                   aot=kw["aot"], stats=sc,
                                   spill_bytes=_spill_bytes(plan)))
-        steps, sharded = _grep_steps(plan, stage, relay, mesh, kw,
-                                     stage_shards, ctx)
+        steps, books, sharded = _grep_steps(plan, stage, relay, mesh, kw,
+                                            stage_shards, ctx)
         results = _drive_many(steps, i) if sharded \
             else [_drive(steps[0], i)]
+        _note_stage(sc, sp, stage, books, sharded)
         if any(r is None for r in results):
             raise PlanHostPath(f"stage {stage.name!r}: grep needs the "
                                f"host path (non-literal pattern or "
@@ -560,13 +608,17 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
         if stage.deps:
             up = ctx[stage.deps[0]]
             if hasattr(up.relay, "blocks"):  # staged / restored host
-                step = WordcountStep(up.relay.blocks(), mesh=mesh,
-                                     **wc_kw)
+                books = _Books(up.relay.blocks())
+                step = WordcountStep(books, mesh=mesh,
+                                     pipeline_stats=books.stats, **wc_kw)
             else:
+                books = _Books()
+                books.bytes_in = up.relay.total_bytes
                 step = WordcountStep([], mesh=mesh,
                                      device_batches=up.relay.batches(),
-                                     **wc_kw)
+                                     pipeline_stats=books.stats, **wc_kw)
             res = _drive(step, i)
+            _note_stage(sc, sp, stage, [books])
             if res is None:
                 raise PlanHostPath(f"stage {stage.name!r}: wordcount "
                                    f"needs the host path (non-ASCII or "
@@ -575,15 +627,14 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
         # A source wordcount (no upstream): plain stream, K shard
         # attempts when sharding applies.
         specs = _shard_specs(plan, stage, stage_shards)
-        if specs is None:
-            steps = [WordcountStep(_source_blocks(plan, stage),
-                                   mesh=mesh, **wc_kw)]
-        else:
-            steps = [WordcountStep(_spec_blocks(plan, stage, spec),
-                                   mesh=mesh, **wc_kw)
-                     for spec in specs]
+        books = ([_Books(_source_blocks(plan, stage))] if specs is None
+                 else [_Books(_spec_blocks(plan, stage, spec))
+                       for spec in specs])
+        steps = [WordcountStep(b, mesh=mesh, pipeline_stats=b.stats,
+                               **wc_kw) for b in books]
         results = _drive_many(steps, i) if len(steps) > 1 \
             else [_drive(steps[0], i)]
+        _note_stage(sc, sp, stage, books, specs is not None)
         if any(r is None for r in results):
             raise PlanHostPath(f"stage {stage.name!r}: wordcount needs "
                                f"the host path (non-ASCII or >64-byte "
@@ -602,7 +653,10 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
     if stage.kind == "indexer":
         from dsi_tpu.parallel.grepstream import IndexerStep
 
-        step = IndexerStep(list(plan.param(stage, "docs")), mesh=mesh,
+        docs = list(plan.param(stage, "docs"))
+        books = _Books()
+        books.bytes_in = sum(len(d) for d in docs)
+        step = IndexerStep(docs, mesh=mesh, stats=books.stats,
                            n_reduce=int(plan.param(stage, "n_reduce", 10)),
                            u_cap=int(plan.param(stage, "u_cap", 1 << 15)),
                            topk=int(plan.param(stage, "topk", 16)),
@@ -612,6 +666,7 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
                            sync_every=kw["sync_every"],
                            mesh_shards=kw["mesh_shards"])
         res = _drive(step, i)
+        _note_stage(sc, sp, stage, [books])
         if res is None:
             raise PlanHostPath(f"stage {stage.name!r}: indexer needs "
                                f"the host path (non-ASCII or >64-byte "

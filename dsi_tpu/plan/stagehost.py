@@ -148,6 +148,7 @@ def main(argv=None) -> int:
     stage = order[i]
     mesh = default_mesh(spec["plan"].get("devices"))
     sc = metrics_scope("plan")
+    sc["stage_stats"] = {}
     net_io = metrics_scope("net")
     srv = PartitionServer(spec["spool"],
                           bind=os.environ.get("DSI_NET_BIND", ""))
@@ -191,8 +192,10 @@ def main(argv=None) -> int:
                                           stats=net_io))
 
         t0 = time.perf_counter()
-        out = _run_stage(plan, i, stage, ctx, mesh, True, sc,
-                         int(spec.get("stage_shards", 0)))
+        with span("plan", stats=sc, key="plan_s", stage=stage.name,
+                  kind=stage.kind) as sp:
+            out = _run_stage(plan, i, stage, ctx, mesh, True, sc,
+                             int(spec.get("stage_shards", 0)), sp)
         wall = round(time.perf_counter() - t0, 4)
         arrays, meta = _commit_payload(plan, stage, out, True)
         blob = pack_commit(arrays, meta)
