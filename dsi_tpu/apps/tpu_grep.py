@@ -7,11 +7,14 @@ ASCII literal ``DSI_GREP_PATTERN`` runs as the shifted-compare kernel
 (``ops/grepk.py``); fixed-length class patterns (``[Tt]he``, ``w.rd``,
 ``^\\d\\d`` …) run as the range-compare kernel (``ops/regexk.py``);
 top-level alternations of those (``the|and``, ``[Cc]at|[Dd]og``) run one
-kernel pass per branch with line flags OR-ed (``ops/altk.py``);
+kernel pass per branch with the matched line ends OR-ed (``ops/altk.py``);
 variable-length patterns (``* + ?``, mixed alternation: ``ab*c``,
 ``[0-9]+``, ``colou?r|gr[ae]y$``) run as a log-depth NFA transition-
-matrix scan (``ops/nfak.py``); anything wider (groups, bounded reps,
-nullable patterns) falls back to the host Map.
+matrix scan (``ops/nfak.py``); anything wider (groups, backrefs,
+nullable patterns) falls back to the host Map.  Every tier returns the
+END positions of the matching lines as packed bits
+(``grepk.line_flags_from_match``) and the host takes those lines by
+offset (``grepk.lines_from_hits``).
 """
 
 from __future__ import annotations

@@ -1,16 +1,18 @@
 """TPU grep tier 3: top-level alternation of fixed-length branches.
 
-Widens the device scope one more step past ``ops/regexk.py``: a pattern that is a top-level ``|``-alternation whose every
-branch is itself device-eligible — a plain literal (``ops/grepk.py``) or a
-fixed-length class pattern (``ops/regexk.py``) — runs as one kernel pass
-PER BRANCH with the per-line flags OR-ed on device.  ``the|and``,
-``[Cc]at|[Dd]og``, ``^\\d\\d|total`` all land here; variable-length
-operators, groups, or an ineligible branch still fall back to the host app
-(``backends/tpu.py`` contract: correctness never depends on a kernel).
+Widens the device scope one more step past ``ops/regexk.py``: a pattern
+that is a top-level ``|``-alternation whose every branch is itself
+device-eligible — a plain literal (``ops/grepk.py``) or a fixed-length
+class pattern (``ops/regexk.py``) — runs as one kernel pass PER BRANCH
+with the branches' matched-line-end bit words OR-ed on device.
+``the|and``, ``[Cc]at|[Dd]og``, ``^\\d\\d|total`` all land here;
+variable-length operators, groups, or an ineligible branch still fall
+back to the host app (``backends/tpu.py`` contract: correctness never
+depends on a kernel).
 
 Python ``re`` semantics hold exactly: alternation binds loosest, so
 ``re.search(a|b, line)`` is ``search(a) or search(b)`` per line, i.e. the
-elementwise max of the branches' line-flag vectors; per-branch anchors
+bitwise OR of the branches' hit words; per-branch anchors
 (``^a|b$`` parses as ``(^a)|(b$)``) are handled by each branch's own
 parser.  No new kernels and no new AOT entries beyond the branch programs
 themselves — an alternation of already-warmed branch shapes reuses their
@@ -28,9 +30,9 @@ from dsi_tpu.ops.grepk import (
     _grep_jit,
     ascii_text,
     is_literal_pattern,
-    lines_from_flags,
+    lines_from_hits,
     pad_chunk,
-    retry_line_caps,
+    run_kernel,
     upload_chunk,
 )
 from dsi_tpu.ops.regexk import _classgrep_compiled, parse_class_pattern
@@ -83,30 +85,21 @@ def split_alternation(pat: str) -> Optional[List[str]]:
     return branches
 
 
-def _branch_flags(chunk, n_data: int, n_host_lines: int, branch: str,
-                  l_cap: int):
-    """(line_match, n_lines, overflow) for one branch at one rung —
-    literal branches via the shifted-compare kernel, class branches via
-    the range-compare kernel.  A literal longer than the DATA (not the
-    padded chunk: padding is zeros, unmatchable by printable literals)
-    cannot match; its flags are zero without compiling a dead kernel."""
+def _branch_hits(chunk, branch: str):
+    """(hit_bits, n_lines) for one branch — literal branches via the
+    shifted-compare kernel, class branches via the range-compare kernel."""
     if is_literal_pattern(branch):
-        if len(branch) > n_data:
-            return (jnp.zeros(l_cap, jnp.int32), jnp.int32(n_host_lines),
-                    jnp.bool_(n_host_lines > l_cap))
         pat = jnp.asarray(
             np.frombuffer(branch.encode("ascii"), dtype=np.uint8))
-        return _grep_jit(chunk, pat, l_cap=l_cap)
+        return _grep_jit(chunk, pat)
     ranges, anchor_start, anchor_end = parse_class_pattern(branch)
     return _classgrep_compiled(int(chunk.shape[0]), ranges, anchor_start,
-                               anchor_end, l_cap)(chunk)
+                               anchor_end)(chunk)
 
 
 def altgrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     """Matching lines of ``data`` (split on '\\n', in order), or None when
-    the pattern or data needs the host regex path.  Same retry discipline
-    as the single-branch tiers (``retry_line_caps``), applied to all
-    branches per rung so the flag vectors share one ``l_cap``."""
+    the pattern or data needs the host regex path."""
     branches = split_alternation(pattern)
     if branches is None:
         return None
@@ -120,18 +113,21 @@ def altgrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     text = ascii_text(data, nul_ok=not any_class)
     if text is None:
         return None
-    n_host_lines = data.count(b"\n") + 1
+    # A literal longer than the DATA (not the padded chunk: padding is
+    # zeros, unmatchable by printable literals) cannot match: it adds no
+    # words and compiles no dead kernel.
+    live = [b for b in branches
+            if not (is_literal_pattern(b) and len(b) > len(data))]
+    if not live:
+        return []
     chunk = upload_chunk(pad_chunk(data))
-    n = int(chunk.shape[0])
 
-    def run(l_cap: int):
-        total, n_lines, overflow = None, None, None
-        for b in branches:
-            lm, nl, of = _branch_flags(chunk, len(data), n_host_lines, b,
-                                       l_cap)
-            total = lm if total is None else jnp.maximum(total, lm)
-            n_lines, overflow = nl, of  # chunk-derived: same every branch
-        return total, n_lines, overflow
+    def run():
+        hits = [_branch_hits(chunk, b) for b in live]
+        total = hits[0][0]
+        for words, _ in hits[1:]:
+            total |= words
+        return total, hits[0][1]  # n_lines is chunk-derived: same each
 
-    line_match, nl = retry_line_caps(n, run, "altgrep")
-    return lines_from_flags(text, line_match, nl)
+    hit_bits, nl = run_kernel("altgrep", run)
+    return lines_from_hits(text, hit_bits, nl)

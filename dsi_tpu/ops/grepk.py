@@ -3,9 +3,12 @@
 Device replacement for the grep app's map hot loop (per-line regex scan,
 reference intent at ``mrapps/dgrep.go:27-35``): the pattern-match mask for
 every byte position is computed with ``len(pattern)`` shifted elementwise
-compares (no gathers, no loops over positions), line membership is a cumsum
-over newline bytes, and per-line match flags are a sorted segment-max —
-the same static-shape, vector-only discipline as ``ops/wordcount.py``.
+compares (no gathers, no loops over positions), and which lines hold a
+match is read at the line ENDS from two scans (:func:`line_flags_from_match`,
+shared by all four grep tiers; no scatter, no per-line buffer) — the same
+static-shape, vector-only discipline as ``ops/wordcount.py``.  The host
+half is here too: the matched line ends come back as packed bits and each
+line is taken from the text by its end offset (:func:`lines_from_hits`).
 
 Scope: fixed ASCII literal patterns without newlines; anything else (regex
 metacharacters, non-ASCII) falls back to the host app — correctness never
@@ -20,36 +23,42 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from dsi_tpu.obs import span as _span
 from dsi_tpu.ops.wordcount import _pad_pow2, _shift_left
 
 
 @jax.named_scope("line_flags")
-def line_flags_from_match(chunk: jax.Array, match: jax.Array, l_cap: int):
-    """Per-position match mask -> per-line flags, shared by the literal
-    kernel here and the class-pattern kernel (``ops/regexk.py``): line
-    membership is a cumsum over newline bytes, per-line flags a sorted
-    segment-max.  Returns (line_match [l_cap] i32 in line order,
-    n_lines i32, overflow bool)."""
+def line_flags_from_match(chunk: jax.Array, match: jax.Array):
+    """Per-position match mask -> matched line ENDS as packed bits, shared
+    by all four grep tiers.  Returns (hit_bits uint32 [n / 32], n_lines
+    i32).
+
+    A line ends at every newline and, the last one, at ``n - 1``
+    (``_pad_pow2`` leaves at least one zero byte, and padding cannot
+    match).  With ``run`` the running match count, the line that ends at
+    p holds ``run[p] - run[q]`` flags, q the newline before it; ``run``
+    never falls, so ``run[q]`` is the running maximum of ``run`` over the
+    newlines strictly before p.  A flag on a newline's own position (the
+    NFA tier's end latch) counts for the line that newline ends.  Two
+    scans, no per-line buffer: no line count can overflow anything.
+
+    Bit k of word w is position ``k * (n / 32) + w``: 32 contiguous slabs
+    OR-ed elementwise (:func:`hit_ends` is the host's inverse)."""
+    n = chunk.shape[0]
     is_nl = chunk == 10
-    cum = jnp.cumsum(is_nl.astype(jnp.int32))
-    line_id = cum - is_nl.astype(jnp.int32)  # newlines strictly before i
-    n_lines = cum[-1] + 1
-    overflow = n_lines > l_cap
-    seg = jnp.minimum(line_id, l_cap)
-    line_match = jax.ops.segment_max(
-        match.astype(jnp.int32), seg, num_segments=l_cap + 1,
-        indices_are_sorted=True)[:l_cap]
-    return line_match, n_lines, overflow
-
-
-def line_cap_rungs(n: int):
-    """The shared l_cap rung schedule: average line >= 8 bytes first,
-    then the n+1 hard bound (every byte a '\\n').  One definition so
-    the warm ladders and the retry loop can never drift onto different
-    compiled shapes."""
-    return (max(n // 8, 1), n + 1)
+    is_end = is_nl | (jnp.arange(n, dtype=jnp.int32) == n - 1)
+    run = jnp.cumsum(match.astype(jnp.int32))
+    at_nl = jnp.where(is_nl, run, 0)
+    prev = lax.cummax(jnp.pad(at_nl[:-1], (1, 0)))  # strictly before
+    hit = is_end & (run > prev)
+    n_lines = jnp.sum(is_nl.astype(jnp.int32)) + 1
+    words = n // 32
+    hit_bits = jnp.zeros(words, jnp.uint32)
+    for k in range(32):  # static unroll: slab k is bit k
+        hit_bits |= hit[k * words:(k + 1) * words].astype(jnp.uint32) << k
+    return hit_bits, n_lines
 
 
 def ascii_text(data: bytes, nul_ok: bool = True) -> Optional[str]:
@@ -76,52 +85,59 @@ def upload_chunk(chunk_np: np.ndarray) -> jax.Array:
         return jnp.asarray(chunk_np)
 
 
-def retry_line_caps(n: int, run, program: str):
-    """Walk :func:`line_cap_rungs` (exactness_retry discipline) until a
-    rung's line buffer holds every line.  ``run(l_cap)`` ->
-    (line_match, n_lines, overflow).  A rung not compiled yet compiles
-    here, logged and counted like any other program.  Each attempt is
-    one ``kernel`` span of ``program``: dispatch to the first blocking
-    scalar read."""
-    for attempt, l_cap in enumerate(line_cap_rungs(n)):
-        with _span("kernel", program=program, attempt=attempt, cap=l_cap):
-            line_match, n_lines, overflow = run(l_cap)
-            overflow = bool(overflow)
-        if not overflow:
-            break
-    return line_match, int(n_lines)
+def run_kernel(program: str, run):
+    """One ``kernel`` span of ``program``: dispatch to the blocking read
+    of the line count.  ``run()`` -> (hit_bits, n_lines).  A program not
+    compiled yet compiles here, logged and counted like any other."""
+    with _span("kernel", program=program, attempt=0):
+        hit_bits, n_lines = run()
+        return hit_bits, int(n_lines)
 
 
-def lines_from_flags(text: str, line_match, nl: int) -> Optional[List[str]]:
-    """Map device line flags back to text lines; None on a host/device
-    line-count disagreement (the host path decides — correctness never
-    depends on a kernel, ``backends/tpu.py`` contract)."""
+def hit_ends(words: np.ndarray) -> np.ndarray:
+    """Positions of the set bits of ``line_flags_from_match``'s packed
+    words, ascending.  Only the non-zero words are expanded, so the cost
+    follows the hits, not the 2^24 positions."""
+    nz = np.flatnonzero(words)
+    bits = (words[nz, None] >> np.arange(32, dtype=np.uint32)) & 1
+    row, k = np.nonzero(bits)
+    return np.sort(k * len(words) + nz[row])
+
+
+def lines_from_hits(text: str, hit_bits, nl: int) -> Optional[List[str]]:
+    """Map the device's matched line ends back to text lines, each taken
+    by offset; None on a host/device line-count disagreement (the host
+    path decides: correctness never depends on a kernel,
+    ``backends/tpu.py`` contract)."""
     with _span("pull") as sp:
-        flags = np.asarray(line_match[:nl])
-        sp.set(bytes=flags.nbytes)
+        words = np.asarray(hit_bits)
+        sp.set(bytes=words.nbytes)
     with _span("decode", lane="host") as sp:
-        lines = text.split("\n")
-        if len(lines) != nl:
+        if text.count("\n") + 1 != nl:
             return None
-        out = [lines[i] for i in range(nl) if flags[i]]
+        out = []
+        for e in hit_ends(words).tolist():
+            e = min(e, len(text))  # the last line ends in the padding
+            out.append(text[text.rfind("\n", 0, e) + 1:e])
         sp.set(records=len(out))
         return out
 
 
-def grep_kernel(chunk: jax.Array, pattern: jax.Array, *, l_cap: int):
+def grep_kernel(chunk: jax.Array, pattern: jax.Array):
     """Match lines of ``chunk`` containing the literal ``pattern``.
 
-    Returns (line_match [l_cap] i32 flags in line order, n_lines i32,
-    overflow bool).  Lines are '\\n'-delimited; the host maps flags back to
-    text with ``text.split('\\n')``.  Padding zeros can never match
-    (patterns are printable ASCII).
+    Returns (hit_bits uint32 [n / 32], n_lines i32), the shared tier
+    contract (:func:`line_flags_from_match`).  Lines are '\\n'-delimited;
+    the host takes each matched line by its end offset
+    (:func:`lines_from_hits`).  Padding zeros can never match (patterns
+    are printable ASCII).
     """
     m = pattern.shape[0]
     match = jnp.ones(chunk.shape[0], jnp.bool_)
     with jax.named_scope("match"):
         for j in range(m):  # static unroll over the (short) pattern
             match &= _shift_left(chunk, j) == pattern[j]
-    return line_flags_from_match(chunk, match, l_cap)
+    return line_flags_from_match(chunk, match)
 
 
 def _grep_example(n: int, m: int):
@@ -130,17 +146,16 @@ def _grep_example(n: int, m: int):
 
 
 @functools.lru_cache(maxsize=64)
-def _grep_compiled(n: int, m: int, l_cap: int):
+def _grep_compiled(n: int, m: int):
     from dsi_tpu.backends.aotcache import cached_compile
 
-    return cached_compile("grep_kernel", grep_kernel, _grep_example(n, m),
-                          static={"l_cap": l_cap})
+    return cached_compile("grep_kernel", grep_kernel, _grep_example(n, m))
 
 
-def _grep_jit(chunk, pattern, *, l_cap: int):
+def _grep_jit(chunk, pattern):
     """The grep kernel as an explicitly compiled, memoized program
     (backends/aotcache.py)."""
-    fn = _grep_compiled(int(chunk.shape[0]), int(pattern.shape[0]), l_cap)
+    fn = _grep_compiled(int(chunk.shape[0]), int(pattern.shape[0]))
     return fn(chunk, pattern)
 
 
@@ -159,8 +174,7 @@ def is_literal_pattern(pat: str) -> bool:
 
 def grep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     """Matching lines of ``data`` (split on '\\n', in order), or None when
-    the pattern needs the host regex path.  Retries the static line buffer
-    on overflow (exactness_retry discipline, avg line >= 8 bytes first)."""
+    the pattern needs the host regex path."""
     if not is_literal_pattern(pattern):
         return None
     text = ascii_text(data)
@@ -170,7 +184,5 @@ def grep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
         return []  # a literal longer than the data cannot match any line
     chunk = upload_chunk(pad_chunk(data))
     pat = jnp.asarray(np.frombuffer(pattern.encode("ascii"), dtype=np.uint8))
-    n = int(chunk.shape[0])
-    line_match, nl = retry_line_caps(
-        n, lambda l_cap: _grep_jit(chunk, pat, l_cap=l_cap), "grep_kernel")
-    return lines_from_flags(text, line_match, nl)
+    hit_bits, nl = run_kernel("grep_kernel", lambda: _grep_jit(chunk, pat))
+    return lines_from_hits(text, hit_bits, nl)

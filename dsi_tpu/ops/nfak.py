@@ -19,16 +19,16 @@ TPU-first shape — no data-dependent control flow, log-depth, MXU-heavy:
    matrices with a K-step batched-matmul scan, an exclusive
    ``lax.associative_scan`` product across blocks (log depth), and a
    vmapped K-step vector re-walk that emits a per-position "matched"
-   latch bit — turned into per-line flags by the same newline-cumsum +
-   ``segment_max`` machinery as every other grep tier.
+   latch bit — turned into matched line ends by the same two-scan
+   machinery as every other grep tier (``grepk.line_flags_from_match``).
 3. The table and start vector are program ARGUMENTS, not constants: one
-   compiled executable (per chunk-size/state-bucket/l_cap) serves EVERY
+   compiled executable (per chunk-size/state-bucket) serves EVERY
    pattern — compile it once and all variable-length patterns share it.
 
 Line discipline: content classes exclude ``\\n``/``\\0``, so no match
 window spans lines or padding; the line-end bytes reset all NFA states
 to the line-start states, and the absorbing "matched" latch survives to
-the line's last position where ``segment_max`` picks it up.  Inputs
+the line's last position, where the line's flag count is read.  Inputs
 containing NUL route to the host (NUL acts as a line-end here but not
 in ``re``), same as ``regexk``.
 """
@@ -46,11 +46,10 @@ import numpy as np
 from dsi_tpu.ops.altk import split_top_level
 from dsi_tpu.ops.grepk import (
     ascii_text,
-    line_cap_rungs,
     line_flags_from_match,
-    lines_from_flags,
+    lines_from_hits,
     pad_chunk,
-    retry_line_caps,
+    run_kernel,
     upload_chunk,
 )
 from dsi_tpu.ops.regexk import ATOM_REJECT, atom_members
@@ -233,7 +232,7 @@ def _build_table(branches, n_atoms: int) -> Tuple[np.ndarray, np.ndarray]:
     # Fixed machinery: the sentinel is always alive; the line-start state
     # is entered (from the sentinel) by every line-end byte; the latch
     # survives every byte except newline (padding keeps the final line's
-    # verdict alive for segment_max).
+    # verdict alive to the line's end at n - 1).
     M[:, _S_ANY, _S_ANY] = 1.0
     for b in _LINE_END:
         M[b, _S_ANY, _S_LINE] = 1.0
@@ -300,12 +299,12 @@ def _build_table(branches, n_atoms: int) -> Tuple[np.ndarray, np.ndarray]:
 
 
 def nfa_kernel(chunk: jax.Array, table: jax.Array, v0: jax.Array, *,
-               s_bucket: int, block: int, l_cap: int):
+               s_bucket: int, block: int):
     """Match lines of ``chunk`` against the NFA in ``table``.
 
-    Returns (line_match [l_cap] i32 in line order, n_lines i32,
-    overflow bool) — the shared tier contract.  ``table``/``v0`` are
-    runtime arguments: the compiled program is pattern-independent.
+    Returns (hit_bits uint32 [n / 32], n_lines i32) — the shared tier
+    contract.  ``table``/``v0`` are runtime arguments: the compiled
+    program is pattern-independent.
     """
     n = chunk.shape[0]
     k = min(block, n)
@@ -342,24 +341,19 @@ def nfa_kernel(chunk: jax.Array, table: jax.Array, v0: jax.Array, *,
 
     _, latch = jax.lax.scan(vstep, u, cols)              # [k, nb]
     mask = latch.T.reshape(n) > 0
-    return line_flags_from_match(chunk, mask, l_cap)
+    return line_flags_from_match(chunk, mask)
 
 
-def _nfa_example_static(n: int, s_bucket: int, block: int, l_cap: int):
+@functools.lru_cache(maxsize=64)
+def _nfa_compiled(n: int, s_bucket: int, block: int):
+    from dsi_tpu.backends.aotcache import cached_compile
+
     sds = jax.ShapeDtypeStruct
     example = (sds((n,), jnp.uint8),
                sds((256, s_bucket, s_bucket), jnp.float32),
                sds((s_bucket,), jnp.float32))
-    return example, {"s_bucket": s_bucket, "block": block, "l_cap": l_cap}
-
-
-@functools.lru_cache(maxsize=64)
-def _nfa_compiled(n: int, s_bucket: int, block: int, l_cap: int):
-    from dsi_tpu.backends.aotcache import cached_compile
-
-    example, static = _nfa_example_static(n, s_bucket, block, l_cap)
     return cached_compile(f"nfagrep_s{s_bucket}", nfa_kernel, example,
-                          static=static)
+                          static={"s_bucket": s_bucket, "block": block})
 
 
 #: In-process view of the persisted calibration table (loaded once; a
@@ -467,10 +461,9 @@ def calibrate_tier4(s_bucket: int, quick: bool = False) -> dict:
     chunk = jnp.asarray(_pad_pow2(data))
     n = int(chunk.shape[0])
     block = min(256, n)
-    l_cap = line_cap_rungs(n)[0]
     table = jnp.asarray(table_np)
     v0 = jnp.asarray(v0_np)
-    fn = _nfa_compiled(n, s_bucket, block, l_cap)
+    fn = _nfa_compiled(n, s_bucket, block)
 
     def kernel():
         jax.block_until_ready(fn(chunk, table, v0))
@@ -508,8 +501,7 @@ def tier4_preferred(s_bucket: int) -> Optional[bool]:
 
 def nfagrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     """Matching lines of ``data`` (split on '\\n', in order), or None
-    when the pattern or data needs the host regex path.  Same retry
-    discipline as the other tiers."""
+    when the pattern or data needs the host regex path."""
     parsed = parse_nfa_pattern(pattern)
     if parsed is None:
         return None
@@ -523,23 +515,9 @@ def nfagrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     s_bucket = table_np.shape[1]
     # _pad_pow2 guarantees >= 1 trailing zero — the line-end byte the
     # $ latch and final-line handling depend on.
-    chunk_np = pad_chunk(data)
-    n = len(chunk_np)
-    block = min(256, n)
-    # Per-RUNG readiness via the shared gated retry
-    # (grepk.retry_line_caps): the escalation rung is a separately
-    # compiled shape, and an ungated escalation would cold-compile
-    # inside a worker task.  Device uploads happen lazily on the first
-    # rung that actually runs, so a not-ready refusal stays device-free.
-    dev = {}
-
-    def run(l_cap: int):
-        if not dev:
-            dev["chunk"] = upload_chunk(chunk_np)
-            dev["table"] = jnp.asarray(table_np)
-            dev["v0"] = jnp.asarray(v0_np)
-        return _nfa_compiled(n, s_bucket, block, l_cap)(
-            dev["chunk"], dev["table"], dev["v0"])
-
-    line_match, nl = retry_line_caps(n, run, "nfa_kernel")
-    return lines_from_flags(text, line_match, nl)
+    chunk = upload_chunk(pad_chunk(data))
+    n = int(chunk.shape[0])
+    hit_bits, nl = run_kernel(
+        "nfa_kernel", lambda: _nfa_compiled(n, s_bucket, min(256, n))(
+            chunk, jnp.asarray(table_np), jnp.asarray(v0_np)))
+    return lines_from_hits(text, hit_bits, nl)

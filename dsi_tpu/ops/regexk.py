@@ -12,11 +12,11 @@ still fall back to the host app; correctness never depends on the kernel
 
 TPU-first shape: each pattern position compiles to a handful of
 ``lo <= byte <= hi`` range tests over the shifted chunk — static unroll,
-vector compares only, no gathers, no scans — then the same
-newline-cumsum + sorted ``segment_max`` line machinery as the literal
-kernel.  The pattern is STATIC (baked into the compiled program and its
-cache key): a grep job runs one pattern over many splits, so one
-compile serves the whole job.
+vector compares only, no gathers — then the same two-scan line
+machinery as the literal kernel (``grepk.line_flags_from_match``:
+matched line ends as packed bits).  The pattern is STATIC (baked into
+the compiled program and its cache key): a grep job runs one pattern
+over many splits, so one compile serves the whole job.
 
 Cross-line discipline: every class excludes ``\\n`` (byte 10) and ``\\0``
 (padding), so a match window can never span lines or leak into padding —
@@ -36,9 +36,9 @@ import numpy as np
 from dsi_tpu.ops.grepk import (
     ascii_text,
     line_flags_from_match,
-    lines_from_flags,
+    lines_from_hits,
     pad_chunk,
-    retry_line_caps,
+    run_kernel,
     upload_chunk,
 )
 from dsi_tpu.ops.wordcount import _shift_left
@@ -222,38 +222,31 @@ def _class_match(chunk: jax.Array, ranges, anchor_start: bool,
 
 
 def classgrep_kernel(chunk: jax.Array, *, ranges, anchor_start: bool,
-                     anchor_end: bool, l_cap: int):
+                     anchor_end: bool):
     """Match lines of ``chunk`` containing the class pattern.
 
-    Same contract as ``grepk.grep_kernel``: returns (line_match [l_cap]
-    i32 flags in line order, n_lines i32, overflow bool).
+    Same contract as ``grepk.grep_kernel``: returns (hit_bits uint32
+    [n / 32], n_lines i32).
     """
     match = _class_match(chunk, ranges, anchor_start, anchor_end)
-    return line_flags_from_match(chunk, match, l_cap)
-
-
-def _classgrep_example_static(n: int, ranges, anchor_start: bool,
-                              anchor_end: bool, l_cap: int):
-    example = (jax.ShapeDtypeStruct((n,), np.uint8),)
-    return example, {"ranges": ranges, "anchor_start": anchor_start,
-                     "anchor_end": anchor_end, "l_cap": l_cap}
+    return line_flags_from_match(chunk, match)
 
 
 @functools.lru_cache(maxsize=64)
 def _classgrep_compiled(n: int, ranges, anchor_start: bool,
-                        anchor_end: bool, l_cap: int):
+                        anchor_end: bool):
     from dsi_tpu.backends.aotcache import cached_compile
 
-    example, static = _classgrep_example_static(n, ranges, anchor_start,
-                                                anchor_end, l_cap)
-    return cached_compile("classgrep_kernel", classgrep_kernel, example,
-                          static=static)
+    return cached_compile(
+        "classgrep_kernel", classgrep_kernel,
+        (jax.ShapeDtypeStruct((n,), np.uint8),),
+        static={"ranges": ranges, "anchor_start": anchor_start,
+                "anchor_end": anchor_end})
 
 
 def classgrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     """Matching lines of ``data`` (split on '\\n', in order), or None when
-    the pattern or data needs the host regex path.  Same retry discipline
-    as ``grepk.grep_host_result``."""
+    the pattern or data needs the host regex path."""
     parsed = parse_class_pattern(pattern)
     if parsed is None:
         return None
@@ -262,9 +255,7 @@ def classgrep_host_result(data: bytes, pattern: str) -> Optional[List[str]]:
     if text is None:
         return None
     chunk = upload_chunk(pad_chunk(data))
-    n = int(chunk.shape[0])
-    line_match, nl = retry_line_caps(
-        n, lambda l_cap: _classgrep_compiled(
-            n, ranges, anchor_start, anchor_end, l_cap)(chunk),
-        "classgrep_kernel")
-    return lines_from_flags(text, line_match, nl)
+    hit_bits, nl = run_kernel(
+        "classgrep_kernel", lambda: _classgrep_compiled(
+            int(chunk.shape[0]), ranges, anchor_start, anchor_end)(chunk))
+    return lines_from_hits(text, hit_bits, nl)

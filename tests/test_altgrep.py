@@ -72,6 +72,8 @@ def test_nul_data_with_class_branch_routes_to_host():
 
 def test_branch_longer_than_data():
     assert altgrep_host_result(b"tiny\nthe\n", "the|" + "a" * 300) == ["the"]
+    # ...and where no branch fits the data, no kernel runs at all.
+    assert altgrep_host_result(b"ab", "abc|abd") == []
 
 
 def test_tpu_map_dispatches_alternation():
@@ -84,7 +86,7 @@ def test_tpu_map_dispatches_alternation():
     assert [kv.key for kv in kva] == host_lines(TEXT, "fox|[Dd]og")
 
 
-def test_line_overflow_retry_with_alternation():
+def test_mostly_empty_lines_with_alternation():
     data = b"\n" * 3000 + b"needle\n" + b"\n" * 3000 + b"pin\n"
     assert altgrep_host_result(data, "needle|pin") == ["needle", "pin"]
 

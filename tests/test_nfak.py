@@ -86,31 +86,40 @@ def test_stray_modifier_routes_to_host():
     assert nfagrep_host_result(TEXT, "a|+b") is None
 
 
-def test_overflow_rung_escalates_on_device(monkeypatch):
-    """The l_cap retry schedule escalates to the n+1 rung on line-count
-    overflow, a separately compiled shape: the escalation rung compiles
-    and serves the job on the device (no rung is refused for being
-    cold)."""
-    import numpy as np
+def test_short_lines_one_program_one_attempt(monkeypatch, tmp_path):
+    """64 lines of 3 bytes (the shape the retired line-slot ladder
+    replayed at a second, separately compiled rung): one program, one
+    attempt, the oracle's lines."""
+    import json
 
+    import dsi_tpu.obs.trace as obs_trace
     import dsi_tpu.ops.nfak as nfak
-    from dsi_tpu.ops.grepk import line_cap_rungs
+    from dsi_tpu.obs import Tracer
 
-    monkeypatch.setenv("DSI_NFA_DISPATCH", "device")
-    compiled_caps = []
+    tracer = Tracer(enabled=True, trace_dir=str(tmp_path / "trace"))
+    monkeypatch.setattr(obs_trace, "_global", tracer)
+    compiled = []
     real_compiled = nfak._nfa_compiled
 
-    def spy_compiled(n, s, b, l_cap):
-        compiled_caps.append(l_cap)
-        return real_compiled(n, s, b, l_cap)
+    def spy_compiled(n, s, b):
+        compiled.append((n, s, b))
+        return real_compiled(n, s, b)
 
     monkeypatch.setattr(nfak, "_nfa_compiled", spy_compiled)
-    data = b"ab\n" * 64  # average line 3 B < 8 B: rung 1 overflows
-    got = nfak.nfagrep_host_result(data, "ab+")
+    data = b"ab\n" * 64
+    try:
+        got = nfak.nfagrep_host_result(data, "ab+")
+        with open(tracer.flush()[0], encoding="utf-8") as f:
+            events = [json.loads(line) for line in f][1:]
+    finally:
+        tracer.enabled = False
     assert got == oracle(data, "ab+")
-    n = len(nfak._pad_pow2(data))
-    assert compiled_caps == list(line_cap_rungs(n))
-
+    assert compiled == [(len(nfak._pad_pow2(data)), 16, 256)]
+    (kernel,) = [e for e in events if e["name"] == "kernel"]
+    assert (kernel["program"], kernel["attempt"]) == ("nfa_kernel", 0)
+    assert "cap" not in kernel
+    (pull,) = [e for e in events if e["name"] == "pull"]
+    assert pull["bytes"] == len(nfak._pad_pow2(data)) // 8
 
 
 def test_multi_block_spanning():
@@ -134,7 +143,7 @@ def test_empty_lines_and_no_trailing_newline():
     assert nfagrep_host_result(data2, "ab+$") == oracle(data2, "ab+$")
 
 
-def test_line_overflow_retry():
+def test_mostly_empty_lines():
     data = b"\n" * 3000 + b"needle\n" + b"\n" * 3000 + b"needles\n"
     assert nfagrep_host_result(data, "needles?$") == ["needle", "needles"]
 

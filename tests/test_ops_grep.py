@@ -1,13 +1,23 @@
 """Device grep kernel: differential vs the host regex app."""
 
 import os
+import re
 
+import numpy as np
 import pytest
 
-pytest.importorskip("jax")
+jax = pytest.importorskip("jax")
 
 from dsi_tpu.apps import grep, tpu_grep
-from dsi_tpu.ops.grepk import grep_host_result, is_literal_pattern
+from dsi_tpu.ops.altk import altgrep_host_result
+from dsi_tpu.ops.grepk import (
+    grep_host_result,
+    hit_ends,
+    is_literal_pattern,
+    lines_from_hits,
+)
+from dsi_tpu.ops.nfak import nfagrep_host_result
+from dsi_tpu.ops.regexk import classgrep_host_result
 
 TEXT = (b"the quick brown fox\njumps over the lazy dog\n"
         b"no match here\nfoxes and boxes\n\nfox")
@@ -42,9 +52,128 @@ def test_empty_lines_and_final_line():
     assert out[-1] == "fox"  # final line without trailing newline
 
 
-def test_line_buffer_overflow_retry():
+def test_mostly_empty_lines():
     data = b"\n" * 3000 + b"needle\n" + b"\n" * 3000
     assert grep_host_result(data, "needle") == ["needle"]
+
+
+# ── the tier contract: matched line ends as packed bits ────────────────
+
+#: Every tier on a pattern of its own that finds "fox" (the anchored
+#: ones only at a line's end: the class tier's window test and the NFA
+#: tier's one-position end latch, which sits ON the newline).
+TIERS = {
+    "literal": (grep_host_result, "fox"),
+    "class": (classgrep_host_result, "[Ff]ox"),
+    "class_end": (classgrep_host_result, "fox$"),
+    "alt": (altgrep_host_result, "fox|F[o0]x"),
+    "nfa": (nfagrep_host_result, "fo+x"),
+    "nfa_end": (nfagrep_host_result, "fo*x$"),
+}
+
+#: The shapes a position-space layout can get wrong.
+SHAPES = {
+    "last_line_without_newline": b"a fox\nnothing\nthe fox",
+    "ends_in_newline": b"a fox\nnothing\nthe fox\n",
+    "empty_lines": b"\n\nfox\n\n\nno\n\nfox\n\n",
+    "every_byte_a_newline": b"\n" * 300,
+    "match_at_first_and_last_byte": b"fox is\nno\nis fox",
+    "single_line": b"one fox",
+    "single_line_no_match": b"nothing here",
+    "one_byte_short_of_a_power_of_two": b"x" * 250 + b"\nfox",  # 255 B
+    "hits_in_adjacent_lines": b"no\nfox\nfox\nfox\nno\nfox\nfox",
+    "every_line_a_hit": b"fox\n" * 40 + b"fox",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("tier", sorted(TIERS))
+def test_tier_lines_equal_re_over_split(tier, shape, monkeypatch):
+    monkeypatch.setenv("DSI_NFA_DISPATCH", "device")
+    run, pattern = TIERS[tier]
+    data = SHAPES[shape]
+    want = [ln for ln in data.decode().split("\n")
+            if re.search(pattern, ln)]
+    assert run(data, pattern) == want
+
+
+def _kernel_cases():
+    import jax.numpy as jnp
+
+    from dsi_tpu.ops import grepk, nfak, regexk
+
+    chunk = jnp.asarray(grepk._pad_pow2(b"the\nx\nthe"))
+    ranges, a_start, a_end = regexk.parse_class_pattern("[Tt]he")
+    branches, n_atoms = nfak.parse_nfa_pattern("th+e")
+    table, v0 = nfak._build_table(branches, n_atoms)
+    return {
+        "grep_kernel": (grepk.grep_kernel,
+                        (chunk, jnp.asarray(np.frombuffer(b"the", np.uint8)))),
+        "classgrep_kernel": (
+            lambda c: regexk.classgrep_kernel(
+                c, ranges=ranges, anchor_start=a_start, anchor_end=a_end),
+            (chunk,)),
+        "nfa_kernel": (
+            lambda c, t, v: nfak.nfa_kernel(
+                c, t, v, s_bucket=table.shape[1], block=256),
+            (chunk, jnp.asarray(table), jnp.asarray(v0))),
+    }
+
+
+@pytest.mark.parametrize("kernel",
+                         ["grep_kernel", "classgrep_kernel", "nfa_kernel"])
+def test_kernel_jaxpr_holds_no_scatter_gather_or_sort(kernel):
+    """The mechanism, pinned where no device trace is at hand: matched
+    line ends come from two scans read at the line ends, and leave as
+    bits packed by slices, shifts and ORs."""
+    fn, args = _kernel_cases()[kernel]
+
+    def names(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from names(sub)
+
+    prims = set(names(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert {"cumsum", "cummax"} <= prims
+    # The NFA tier looks its per-byte transition matrices up in its
+    # table (``table[col]``, the match itself); its line flags gather
+    # nothing, like the other two.
+    allowed = {"gather"} if kernel == "nfa_kernel" else set()
+    bad = [p for p in prims - allowed
+           if "scatter" in p or "gather" in p or "sort" in p]
+    assert not bad, sorted(prims)
+
+
+def _words(n_words: int, positions) -> np.ndarray:
+    """Packed words as the kernels lay them out: bit k of word w is
+    position ``k * n_words + w``."""
+    words = np.zeros(n_words, np.uint32)
+    for p in positions:
+        words[p % n_words] |= np.uint32(1) << np.uint32(p // n_words)
+    return words
+
+
+@pytest.mark.parametrize("positions", [
+    [0],                                   # first bit
+    [255],                                 # last bit
+    [3 + 8 * k for k in range(32)],        # every bit of one word
+    [],                                    # none
+    [5, 6, 7, 100, 254, 255],
+], ids=["first", "last", "full_word", "none", "mixed"])
+def test_hit_ends_inverts_the_packing(positions):
+    assert hit_ends(_words(8, positions)).tolist() == positions
+
+
+def test_lines_from_hits_takes_lines_by_offset():
+    text = "ab\n\ncd\nef"  # line ends at 2, 3, 6 and in the padding
+    n_words = 8
+    assert lines_from_hits(text, _words(n_words, [2, 6, 255]), 4) == [
+        "ab", "cd", "ef"]
+    assert lines_from_hits(text, _words(n_words, [3]), 4) == [""]
+    assert lines_from_hits(text, _words(n_words, []), 4) == []
+    # A device line count the host does not share: the host decides.
+    assert lines_from_hits(text, _words(n_words, [2]), 5) is None
 
 
 def test_pattern_longer_than_data():
@@ -102,9 +231,9 @@ def test_line_count_mismatch_falls_back(monkeypatch):
 
     real = grepk._grep_jit
 
-    def skewed(chunk, pat, *, l_cap):
-        line_match, n_lines, overflow = real(chunk, pat, l_cap=l_cap)
-        return line_match, n_lines + 1, overflow
+    def skewed(chunk, pat):
+        hit_bits, n_lines = real(chunk, pat)
+        return hit_bits, n_lines + 1
 
     monkeypatch.setattr(grepk, "_grep_jit", skewed)
     assert grep_host_result(TEXT, "fox") is None
@@ -114,12 +243,12 @@ def test_line_count_mismatch_falls_back(monkeypatch):
     # assert the FULL device->host fallback chain.
     real_c = regexk._classgrep_compiled
 
-    def skewed_c(n, ranges, a_start, a_end, l_cap):
-        fn = real_c(n, ranges, a_start, a_end, l_cap)
+    def skewed_c(n, ranges, a_start, a_end):
+        fn = real_c(n, ranges, a_start, a_end)
 
         def wrap(chunk):
-            line_match, n_lines, overflow = fn(chunk)
-            return line_match, n_lines + 1, overflow
+            hit_bits, n_lines = fn(chunk)
+            return hit_bits, n_lines + 1
 
         return wrap
 
@@ -132,12 +261,12 @@ def test_line_count_mismatch_falls_back(monkeypatch):
 
     real_n = nfak._nfa_compiled
 
-    def skewed_n(n, s_bucket, block, l_cap):
-        fn = real_n(n, s_bucket, block, l_cap)
+    def skewed_n(n, s_bucket, block):
+        fn = real_n(n, s_bucket, block)
 
         def wrap(chunk, table, v0):
-            line_match, n_lines, overflow = fn(chunk, table, v0)
-            return line_match, n_lines + 1, overflow
+            hit_bits, n_lines = fn(chunk, table, v0)
+            return hit_bits, n_lines + 1
 
         return wrap
 

@@ -133,8 +133,8 @@ def test_fuzz_generated_class_patterns_vs_oracle():
         assert got == _oracle(data, pattern), (trial, pattern, lines)
 
 
-def test_line_buffer_overflow_retries_exactly():
-    # every byte a newline: n_lines = n+1 forces the widest l_cap rung
+def test_more_lines_than_an_eighth_of_the_bytes():
+    # 600 empty lines, then 3-byte ones: no line count overflows anything
     data = b"\n" * 600 + b"xa\n" * 40
     got = classgrep_host_result(data, "[xy]a")
     assert got == _oracle(data, "[xy]a")
