@@ -143,6 +143,8 @@ def main(argv=None) -> int:
             daemon.join(timeout=0.5)
     finally:
         daemon.close()
+        print(f"mrserve: pipeline_stats={daemon.stats_section()!r}",
+              file=sys.stderr, flush=True)
         if args.trace_dir:
             from dsi_tpu.obs import flush_tracing_report
 

@@ -96,6 +96,26 @@ cost is ``relay_appends`` (calls of ``append``), ``relay_seals``
 restored ones) and the ``relay_append_s`` / ``relay_spill_s`` phases
 (the ``relay_append`` and ``relay_spill`` spans).
 
+Serving keys (``dsi_tpu/serve``): the packers' scopes ``serve`` (word
+count) and ``serve_grep`` carry ``packed_steps`` / ``packed_rows`` /
+``max_tenants_per_step`` / ``host_fallbacks`` and the phases of one
+packed step, ``take_s`` (the ``take_row`` spans: a lane's next row cut
+from its input on the scheduler thread), ``upload_s``, ``kernel_s``,
+``pull_s``, ``merge_s``.  The daemon's own scope ``serve_daemon``
+carries ``submits`` and ``submit_s`` (the ``Submit`` handler:
+validation, the durable journal write, the reply), ``admit_s`` (runner
+construction and chain load), ``evict_s`` and ``evictions`` (a park: the
+forced snapshot, the journal write), ``resumes``, ``finish_s`` and
+``jobs_done`` (finalize and the durable output), and what the lanes'
+checkpoint writers add: ``ckpt_s`` (a lane's ``save_ckpt``, periodic or
+forced by a park) with the ``ckpt_*`` counters above.  A job record's
+``stats`` holds ``queue_wait_s`` (submission to the first row a packer
+took from the job) and ``service_s`` (from there to its committed
+output).  ``Status`` without a job id returns the three scopes as
+``stats`` (``serve``, ``serve_grep``, ``daemon``: the last with the
+admission counters ``shed``, ``rate_limited``, ``evict_p99``,
+``evict_quota``), counted from the daemon's start.
+
 ``device_rows`` (the "stream" scope) is a length-``n_dev`` list: the
 reduce-output rows each device of the mesh produced, summed over
 confirmed steps — every entry is non-zero when every device held a shard
@@ -188,6 +208,10 @@ PHASE_KEYS = (
     # overlapped shuffle (ISSUE 18): consumer time blocked on the
     # prefetch pool vs dialer wire time hidden behind the decode
     "net_fetch_wait_s", "net_overlap_s",
+    # serving daemon (the "serve_daemon" scope, serve/daemon.py, and the
+    # packers' row cut): the submit, admit, take_row, evict and finish
+    # spans
+    "submit_s", "admit_s", "take_s", "evict_s", "finish_s",
 )
 
 #: The canonical counter/gauge keys (module docstring) — previously
@@ -219,6 +243,11 @@ COUNTER_KEYS = (
     # serving daemon (the "serve"/"serve_grep" scopes, serve/pack.py)
     "packed_steps", "packed_rows", "max_tenants_per_step",
     "host_fallbacks",
+    # the daemon's own counts (the "serve_daemon" scope) and, per job,
+    # the seconds from submission to its first row and from there to
+    # its committed output (a job record's ``stats``)
+    "submits", "evictions", "resumes", "jobs_done", "queue_wait_s",
+    "service_s",
     # plan layer (the "plan" scope, dsi_tpu/plan + device/relay.py):
     # multi-stage chain accounting — handoff bytes vs commit bytes is
     # the zero-host-round-trip evidence

@@ -98,6 +98,10 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # the batch plane's tasks and what a task and a launch consist of
     "worker.map", "worker.reduce", "read", "write", "rpc", "d2h",
     "finalize", "probe", "backend_init",
+    # the serving daemon (serve/daemon.py, serve/pack.py): a submission,
+    # an admission to the resident set, a row cut for a packed step, a
+    # park to the checkpoint chain ("ckpt" and "finish" are above)
+    "submit", "admit", "take_row", "evict",
 ))
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"

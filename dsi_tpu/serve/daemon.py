@@ -61,6 +61,7 @@ import time
 from typing import Dict, List, Optional
 
 from dsi_tpu.mr.rpc import RpcServer
+from dsi_tpu.obs import count as _count, metrics_scope, span as _span
 from dsi_tpu.obs.hist import KeyedHistograms
 from dsi_tpu.serve import qos
 from dsi_tpu.serve.client import default_socket
@@ -161,6 +162,15 @@ class ServeDaemon:
         # dsi_serve_* series (SERVE_SERIES), not step-pipeline stats.
         self._qos = {"shed": 0, "rate_limited": 0, "evict_p99": 0,
                      "evict_quota": 0}
+        # What the daemon's own work costs, beside the packers' scopes:
+        # the seconds of its spans and the counts of what it did.  The
+        # lanes' checkpoint writers add their ckpt_* keys here too.
+        # Every key is set here, so that a reader on an RPC thread
+        # (``Status``) copies a dict whose size does not change.
+        self.stats = metrics_scope("serve_daemon")
+        self.stats.update({"submits": 0, "submit_s": 0.0, "admit_s": 0.0,
+                           "evict_s": 0.0, "finish_s": 0.0, "ckpt_s": 0.0,
+                           "evictions": 0, "resumes": 0, "jobs_done": 0})
         # Per-tenant packed-step wall distributions — the eviction
         # policy's evidence and the bounded /metrics tenant selector.
         self._hist = KeyedHistograms()
@@ -299,6 +309,20 @@ class ServeDaemon:
     # ── RPC handlers (no jax; scheduler owns the device) ──
 
     def _rpc_submit(self, args: dict) -> dict:
+        """The ``submit`` span: validation, the durable journal write,
+        the reply.  RPC threads run this side by side, so each times
+        itself and adds its seconds under the lock."""
+        took: Dict = {}
+        with _span("submit", stats=took, key="submit_s",
+                   tenant=str(args.get("tenant") or "default")) as sp:
+            reply = self._submit(args)
+            sp.set(job=reply.get("job_id"))
+        with self._lock:
+            self.stats["submits"] += 1
+            self.stats["submit_s"] += took["submit_s"]
+        return reply
+
+    def _submit(self, args: dict) -> dict:
         tenant = str(args.get("tenant") or "default")
         # The tenant id is spliced into journal filenames and chain
         # paths: a separator or dot-dot would write outside the spool
@@ -414,7 +438,27 @@ class ServeDaemon:
                     if tenant is None or j["tenant"] == tenant]
             return {"jobs": jobs,
                     "tenants": {t: dict(s)
-                                for t, s in self._tenants.items()}}
+                                for t, s in self._tenants.items()},
+                    "stats": self._stats_section()}
+
+    def stats_section(self) -> Dict:
+        with self._lock:
+            return self._stats_section()
+
+    def _stats_section(self) -> Dict:
+        """The scheduler's statistics as a client reads them (``Status``
+        with no job id; ``mrserve`` prints the same at shutdown): the
+        packers' scopes under ``serve`` and ``serve_grep`` once the
+        scheduler has built them, and under ``daemon`` this process's own
+        spans and counts with the admission counters.  All of it counts
+        up from the daemon's start: a reader takes it before and after
+        what it measures and subtracts.  Caller holds the lock."""
+        out = {"daemon": {**self.stats, **self._qos}}
+        if self.packer is not None:
+            out["serve"] = dict(self.packer.stats)
+        if self.grep_packer is not None:
+            out["serve_grep"] = dict(self.grep_packer.stats)
+        return out
 
     def _rpc_ping(self, args: dict) -> dict:
         with self._lock:
@@ -553,21 +597,27 @@ class ServeDaemon:
                 len(self._resident) < self.max_resident:
             jid = self._queue.pop()
             job = self._jobs[jid]
+            resumed = job["state"] == "parked"
             try:
-                rec = self._make_runner(job)
+                with _span("admit", stats=self.stats, key="admit_s",
+                           job=jid) as sp:
+                    rec = self._make_runner(job)
+                    resumed = resumed or bool(rec.get("resume_cursor", 0))
+                    sp.set(resumed=resumed)
             except Exception as e:  # noqa: BLE001 — job fails, daemon lives
                 job["state"] = "failed"
                 job["error"] = f"{type(e).__name__}: {e}"
                 job["done_ts"] = round(time.time(), 3)
                 self._persist(job)
                 continue
-            was_parked = job["state"] == "parked"
             job["state"] = "running"
             self._persist(job)
             self._resident[jid] = rec
             ts = self._tenant(job["tenant"])
-            if was_parked or rec.get("resume_cursor", 0):
+            if resumed:
                 ts["resumes"] += 1
+                self.stats["resumes"] += 1
+                _count("resumes")
                 ts["resume_gap_s"] = round(
                     ts["resume_gap_s"] + rec.get("resume_gap_s", 0.0), 4)
             admitted = True
@@ -581,7 +631,7 @@ class ServeDaemon:
 
             lane = TenantLane(job, self.chunk_bytes, ckpt_dir,
                               checkpoint_every=self.checkpoint_every,
-                              resume=True)
+                              resume=True, stats=self.stats)
             return {"kind": "wc", "lane": lane,
                     "resume_gap_s": lane.resume_gap_s,
                     "resume_cursor": lane.start_offset}
@@ -592,7 +642,7 @@ class ServeDaemon:
 
             lane = GrepLane(job, self.chunk_bytes, ckpt_dir,
                             checkpoint_every=self.checkpoint_every,
-                            resume=True)
+                            resume=True, stats=self.stats)
             return {"kind": "grep", "lane": lane,
                     "resume_gap_s": lane.resume_gap_s,
                     "resume_cursor": lane.start_offset}
@@ -619,6 +669,10 @@ class ServeDaemon:
         writes) must not freeze the control plane mid-multi-GB job —
         only the final job/tenant bookkeeping takes the lock."""
         job = self._jobs[jid]
+        with _span("finish", stats=self.stats, key="finish_s", job=jid):
+            self._finalize(job, rec)
+
+    def _finalize(self, job: Dict, rec: Dict) -> None:
         hostpath = False
         stats: Dict = {}
         error = None
@@ -657,14 +711,23 @@ class ServeDaemon:
         except Exception as e:  # noqa: BLE001 — job fails, daemon lives
             error = f"{type(e).__name__}: {e}"
         with self._lock:
+            # What the job waited and what it was served for: from its
+            # submission to its first row, and from there to here.
+            wait = (job.get("stats") or {}).get("queue_wait_s")
+            done_ts = time.time()
+            if wait is not None:
+                stats["queue_wait_s"] = wait
+                stats["service_s"] = round(
+                    max(0.0, done_ts - job["submitted_ts"] - wait), 4)
             job["stats"] = stats
             job["state"] = "done" if error is None else "failed"
             job["error"] = error
-            job["done_ts"] = round(time.time(), 3)
+            job["done_ts"] = round(done_ts, 3)
             ts = self._tenant(job["tenant"])
             if hostpath:
                 ts["hostpath"] += 1
             if error is None:
+                self.stats["jobs_done"] += 1
                 ts["done"] += 1
                 ts["steps"] += int(stats.get("steps") or 0)
                 ts["rows"] += int(stats.get("rows") or 0)
@@ -728,6 +791,13 @@ class ServeDaemon:
             reason = "evict_quota"
         if victim is None:
             return
+        with _span("evict", stats=self.stats, key="evict_s", job=victim,
+                   how=reason[len("evict_"):]):
+            self._park(victim, reason)
+
+    def _park(self, victim: str, reason: str) -> None:
+        """Snapshot the victim to its chain, drop its runner, and put the
+        job at the back of its priority lane.  Caller holds the lock."""
         rec = self._resident.pop(victim)
         job = self._jobs[victim]
         try:
@@ -747,6 +817,20 @@ class ServeDaemon:
                                          qos.DEFAULT_PRIORITY))
         self._tenant(job["tenant"])["evictions"] += 1
         self._qos[reason] += 1
+        self.stats["evictions"] += 1
+        _count("evictions")
+
+    def _note_first_rows(self, pairs) -> None:
+        """A job's ``queue_wait_s``: from its submission to the first row
+        a packer took from it.  Kept in the job's ``stats``, which a park
+        persists, so a resumed job keeps the wait of its first turn."""
+        for jid, lane in pairs:
+            job = self._jobs[jid]
+            stats = job.setdefault("stats", {})
+            if lane.first_take_ts is not None and \
+                    "queue_wait_s" not in stats:
+                stats["queue_wait_s"] = round(max(
+                    0.0, lane.first_take_ts - job["submitted_ts"]), 4)
 
     def _fail_lanes(self, pairs, e: Exception, what: str) -> None:
         """Fail the jobs riding a packer that threw — the packer error
@@ -797,6 +881,7 @@ class ServeDaemon:
                     confirmed = self.packer.step(
                         [ln for _, ln in wc_lanes])
                     wall = time.perf_counter() - t0
+                    self._note_first_rows(wc_lanes)
                     for ln in confirmed:
                         self._hist.record(ln.tenant, wall)
                     worked = bool(confirmed) or any(
@@ -816,6 +901,7 @@ class ServeDaemon:
                     confirmed = self.grep_packer.step(
                         [ln for _, ln in grep_lanes])
                     wall = time.perf_counter() - t0
+                    self._note_first_rows(grep_lanes)
                     for ln in confirmed:
                         self._hist.record(ln.tenant, wall)
                     worked = worked or bool(confirmed) or any(

@@ -77,7 +77,7 @@ from dsi_tpu.ckpt import (
     fault_point,
     skip_stream,
 )
-from dsi_tpu.obs import metrics_scope, span as _span
+from dsi_tpu.obs import count as _count, metrics_scope, span as _span
 from dsi_tpu.ops.wordcount import grouper_ladder, rung0_cap
 from dsi_tpu.parallel.merge import PackedCounts
 from dsi_tpu.parallel.shuffle import write_partitioned_output
@@ -111,7 +111,8 @@ class TenantLane:
 
     def __init__(self, job: Dict, chunk_bytes: int, ckpt_dir: str,
                  checkpoint_every: Optional[int] = None,
-                 resume: bool = True, delta: bool = True):
+                 resume: bool = True, delta: bool = True,
+                 stats: Optional[Dict] = None):
         from dsi_tpu.parallel.streaming import batch_stream, stream_files
 
         self.job = job
@@ -127,7 +128,10 @@ class TenantLane:
         self.hostpath = False
         self.input_done = False
         self.resume_gap_s = 0.0
-        self.stats: Dict = {}
+        # Where this lane's checkpoint seconds and counts go: the
+        # daemon's scope, which outlives the lane, or a dict of its own.
+        self.stats: Dict = {} if stats is None else stats
+        self.first_take_ts: Optional[float] = None
         self._pending: List[int] = []  # end offsets of unconfirmed rows
         ident = {"tenant": self.tenant,
                  "files": [[os.path.basename(f), os.path.getsize(f)]
@@ -188,6 +192,8 @@ class TenantLane:
         except _TokenTooLong:
             self.to_hostpath()
             return None
+        if self.first_take_ts is None:
+            self.first_take_ts = time.time()
         off = self.start_offset + self.offsets[self.rows_taken]
         self.rows_taken += 1
         self._pending.append(off)
@@ -231,14 +237,18 @@ class TenantLane:
         want_delta/re-base discipline, one writer)."""
         meta = {"cursor": self.cursor, "rows": self.confirmed_rows}
         kind, parts = "full", None
-        if self.writer.want_delta():
-            entries = self.delta_log.take()
-            if entries is not None:
-                parts, kind = [("", DeltaSteps(entries))], "delta"
-        if parts is None:
-            self.delta_log.reset()
-            parts = [("acc_", self.acc.snapshot())]
-        self.writer.commit(parts, meta, kind=kind)
+        with _span("ckpt", stats=self.stats, key="ckpt_s",
+                   tenant=self.tenant) as sp:
+            if self.writer.want_delta():
+                entries = self.delta_log.take()
+                if entries is not None:
+                    parts, kind = [("", DeltaSteps(entries))], "delta"
+            if parts is None:
+                self.delta_log.reset()
+                parts = [("acc_", self.acc.snapshot())]
+            self.writer.commit(parts, meta, kind=kind)
+            sp.set(bytes=self.store.last_payload_bytes)
+        _count("ckpt_saves")
 
     def suspend(self) -> None:
         """Evict: one forced durable snapshot; the object is dead after
@@ -289,7 +299,7 @@ class PackedWcScheduler:
         self.stats = metrics_scope("serve")
         self.stats.update({"packed_steps": 0, "packed_rows": 0,
                            "replays": 0, "upload_s": 0.0, "kernel_s": 0.0,
-                           "pull_s": 0.0, "merge_s": 0.0,
+                           "pull_s": 0.0, "merge_s": 0.0, "take_s": 0.0,
                            "max_tenants_per_step": 0})
         self._sh_chunk = NamedSharding(mesh, P(AXIS, None))
         self._sh_ids = NamedSharding(mesh, P(AXIS))
@@ -315,11 +325,11 @@ class PackedWcScheduler:
 
     # ── one packed step ──
 
-    def _wave_call(self, chunk_np, ids_np, mwl, cap, frac, g):
+    def _wave_call(self, chunk_np, ids_np, mwl, cap, frac, g, batch):
         from dsi_tpu.device.table import _quiet_unusable_donation
         from dsi_tpu.parallel.tfidf import _wave_fn
 
-        with _span("upload", stats=self.stats, key="upload_s"):
+        with _span("upload", stats=self.stats, key="upload_s", **batch):
             chunk = self._jax.device_put(chunk_np, self._sh_chunk)
             ids = self._jax.device_put(ids_np, self._sh_ids)
         fn = _wave_fn((chunk, ids), n_dev=self.n_dev,
@@ -329,7 +339,7 @@ class PackedWcScheduler:
         with _quiet_unusable_donation():
             return fn(chunk, ids)
 
-    def _dispatch_ladder(self, chunk_np, ids_np, picks):
+    def _dispatch_ladder(self, chunk_np, ids_np, picks, batch):
         """The synchronous exactness ladder for ONE packed batch — the
         wave walk's replay discipline, with per-lane host-path
         attribution instead of rung aborts: a poisoned lane (non-ASCII,
@@ -342,9 +352,9 @@ class PackedWcScheduler:
             for g in self.groupers:
                 for frac in (4, 2):
                     with _span("kernel", stats=self.stats,
-                               key="kernel_s"):
-                        rows, scal = self._wave_call(chunk_np, ids_np,
-                                                     mwl, cap, frac, g)
+                               key="kernel_s", **batch):
+                        rows, scal = self._wave_call(chunk_np, ids_np, mwl,
+                                                     cap, frac, g, batch)
                         scal_np = np.asarray(scal)
                     if not scal_np[:, 4].any():
                         break
@@ -391,7 +401,9 @@ class PackedWcScheduler:
                     break
                 if not lane.runnable:
                     continue
-                row = lane.take_row()
+                with _span("take_row", stats=self.stats, key="take_s",
+                           tenant=lane.tenant, bytes=self.chunk_bytes):
+                    row = lane.take_row()
                 if row is None:
                     continue
                 chunk_np[len(picks), :] = row
@@ -404,14 +416,17 @@ class PackedWcScheduler:
         # Doc id = batch slot: rides every shuffled row, so the pull
         # demuxes exactly.  Idle rows are all-zero chunks (no tokens).
         ids_np = np.arange(self.n_dev, dtype=np.int32)
-        rows, scal_np, kk = self._dispatch_ladder(chunk_np, ids_np, picks)
+        n_tenants = len({ln.tenant for ln in picks})
+        batch = {"rows": len(picks), "tenants": n_tenants}  # span fields
+        rows, scal_np, kk = self._dispatch_ladder(chunk_np, ids_np, picks,
+                                                  batch)
         fault_point("post-dispatch")
         m = int(scal_np[:, 0].max())
         if m:
-            with _span("pull", stats=self.stats, key="pull_s"):
+            with _span("pull", stats=self.stats, key="pull_s", **batch):
                 mp = occupied_prefix(m, rows.shape[1])
                 rows_np = np.asarray(rows[:, :mp])
-            with _span("merge", stats=self.stats, key="merge_s"):
+            with _span("merge", stats=self.stats, key="merge_s", **batch):
                 for d in range(self.n_dev):
                     nr = int(scal_np[d, 0])
                     if not nr:
@@ -438,7 +453,8 @@ class PackedWcScheduler:
             confirmed.append(lane)
         self.stats["packed_steps"] += 1
         self.stats["packed_rows"] += len(picks)
-        n_tenants = len({ln.tenant for ln in picks})
+        _count("packed_steps")
+        _count("packed_rows", len(picks))
         if n_tenants > self.stats["max_tenants_per_step"]:
             self.stats["max_tenants_per_step"] = n_tenants
         return confirmed
@@ -477,7 +493,8 @@ class GrepLane:
     def __init__(self, job: Dict, chunk_bytes: int, ckpt_dir: str,
                  checkpoint_every: Optional[int] = None,
                  resume: bool = True, bins: Optional[int] = None,
-                 topk: Optional[int] = None):
+                 topk: Optional[int] = None,
+                 stats: Optional[Dict] = None):
         from dsi_tpu.ops.grepk import is_literal_pattern
         from dsi_tpu.parallel.grepstream import (DEFAULT_TOPK, GREP_BINS,
                                                  batch_lines)
@@ -505,7 +522,8 @@ class GrepLane:
                              and self.m <= self.chunk_bytes)
         self.input_done = False
         self.resume_gap_s = 0.0
-        self.stats: Dict = {}
+        self.stats: Dict = {} if stats is None else stats  # as TenantLane
+        self.first_take_ts: Optional[float] = None
         self._next_base = 0
         ident = {"tenant": self.tenant, "pattern": self.pattern,
                  "files": [[os.path.basename(f), os.path.getsize(f)]
@@ -563,6 +581,8 @@ class GrepLane:
         except _LineTooLong:
             self.to_hostpath()
             return None
+        if self.first_take_ts is None:
+            self.first_take_ts = time.time()
         end = self.start_offset + self.offsets[self.rows_taken]
         self.rows_taken += 1
         info = _GrepRow(batch[0], int(lens[0]), int(row_lines[0]), end,
@@ -606,10 +626,14 @@ class GrepLane:
                 "matched": self.matched,
                 "occurrences": self.occurrences,
                 "rows": self.confirmed_rows}
-        cand = np.array(self.cands or np.zeros((0, 2)), dtype=np.int64)
-        parts = [("g_", {"hist": np.array(self.hist, dtype=np.int64),
-                         "cand": cand.reshape(-1, 2)})]
-        self.writer.commit(parts, meta, kind="full")
+        with _span("ckpt", stats=self.stats, key="ckpt_s",
+                   tenant=self.tenant) as sp:
+            cand = np.array(self.cands or np.zeros((0, 2)), dtype=np.int64)
+            parts = [("g_", {"hist": np.array(self.hist, dtype=np.int64),
+                             "cand": cand.reshape(-1, 2)})]
+            self.writer.commit(parts, meta, kind="full")
+            sp.set(bytes=self.store.last_payload_bytes)
+        _count("ckpt_saves")
 
     def suspend(self) -> None:
         """Evict: one forced durable snapshot; dead after."""
@@ -666,7 +690,8 @@ class PackedGrepScheduler:
         self.stats.update({"packed_steps": 0, "packed_rows": 0,
                            "host_fallbacks": 0, "upload_s": 0.0,
                            "kernel_s": 0.0, "pull_s": 0.0,
-                           "merge_s": 0.0, "max_tenants_per_step": 0})
+                           "merge_s": 0.0, "take_s": 0.0,
+                           "max_tenants_per_step": 0})
         self._sh_chunk = NamedSharding(mesh, P(AXIS, None))
         self._rr = 0
         self._jax = jax
@@ -696,12 +721,14 @@ class PackedGrepScheduler:
         self._rr += 1
         return groups[key]
 
-    def _dispatch(self, chunk_np, pats_np, lens_np, bases_np, m):
+    def _dispatch(self, chunk_np, pats_np, lens_np, bases_np, m, batch):
+        """Put, run, read: one packed step.  ``batch`` is what its spans
+        say of it (``rows``, ``tenants``)."""
         from dsi_tpu.device.table import _quiet_unusable_donation
         from dsi_tpu.parallel.grepstream import grep_pack_fn, step_meta
         from dsi_tpu.utils.jaxcompat import enable_x64
 
-        with _span("upload", stats=self.stats, key="upload_s"):
+        with _span("upload", stats=self.stats, key="upload_s", **batch):
             chunk = self._jax.device_put(chunk_np, self._sh_chunk)
             pats = self._jax.device_put(pats_np, self._sh_chunk)
             with enable_x64(True):   # keep the u64 bases u64 through it
@@ -709,10 +736,10 @@ class PackedGrepScheduler:
                                             self._sh_chunk)
         fn = grep_pack_fn(self.n_dev, self.chunk_bytes, m,
                           bins=self.bins, k=self.topk, mesh=self.mesh)
-        with _span("kernel", stats=self.stats, key="kernel_s"):
+        with _span("kernel", stats=self.stats, key="kernel_s", **batch):
             with _quiet_unusable_donation():
                 hist_ext, cand, scal = fn(chunk, pats, meta)
-        with _span("pull", stats=self.stats, key="pull_s"):
+        with _span("pull", stats=self.stats, key="pull_s", **batch):
             return (np.asarray(hist_ext), np.asarray(cand),
                     np.asarray(scal))
 
@@ -734,7 +761,10 @@ class PackedGrepScheduler:
                     break
                 if not lane.runnable:
                     continue
-                info = lane.take_row()
+                with _span("take_row", stats=self.stats, key="take_s",
+                           tenant=lane.tenant) as sp:
+                    info = lane.take_row()
+                    sp.set(bytes=info.dlen if info is not None else 0)
                 if info is None:
                     if lane.hostpath:
                         self.stats["host_fallbacks"] += 1
@@ -758,13 +788,15 @@ class PackedGrepScheduler:
             pats_np[slot] = np.frombuffer(lane.pat, dtype=np.uint8)
             lens_np[slot] = info.dlen
             bases_np[slot] = info.base
+        n_tenants = len({ln.tenant for ln, _i in picks})
+        batch = {"rows": len(picks), "tenants": n_tenants}
         hist_np, cand_np, scal_np = self._dispatch(
-            chunk_np, pats_np, lens_np, bases_np, m)
+            chunk_np, pats_np, lens_np, bases_np, m, batch)
         fault_point("post-dispatch")
         # Per-row demux: slots in take order ARE each lane's byte-range
         # order, so confirming them in slot order advances every lane's
         # cursor monotonically.
-        with _span("merge", stats=self.stats, key="merge_s"):
+        with _span("merge", stats=self.stats, key="merge_s", **batch):
             for slot, (lane, info) in enumerate(picks):
                 n_cand = int(scal_np[slot, 0])
                 pairs = [((int(cand_np[slot, i, 0]) << 32)
@@ -780,7 +812,8 @@ class PackedGrepScheduler:
             lane.note_step()
         self.stats["packed_steps"] += 1
         self.stats["packed_rows"] += len(picks)
-        n_tenants = len({ln.tenant for ln, _i in picks})
+        _count("packed_steps")
+        _count("packed_rows", len(picks))
         if n_tenants > self.stats["max_tenants_per_step"]:
             self.stats["max_tenants_per_step"] = n_tenants
         return confirmed
