@@ -10,11 +10,11 @@ worker.  What it knows about the device comes from the worker itself, through
 What throughput counts of a job is the time the deployment is in service:
 from the first device worker's backend being up (the hook's report, a moment
 before it asks for its first task) to the last ``mr-out-*`` committed (its
-modification time).  The two device-process starts before that moment (probe
-child, then the worker) and the TPU runtime's teardown after it take 23-42 s
-and 3-9 s and differ by +-5 s from one job to the next on one machine: no
-window the contract allows averages that out, so they are reported per layer
-(``launch_s``) and, for a run's first job, inside ``setup_s``.
+modification time).  The device worker's start before that moment (12-16 s;
+a probe child came before it until PR 25) and the TPU runtime's teardown
+after it (3-9 s) differ by seconds from one job to the next on one machine:
+no window the contract allows averages that out, so they are reported per
+layer (``launch_s``) and, for a run's first job, inside ``setup_s``.
 
 The configuration's ``mrrun`` block gives the deployment's flags, the
 traffic mix gives ``app`` and ``env``.

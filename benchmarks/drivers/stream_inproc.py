@@ -1,13 +1,16 @@
 """Driver: a stream job is one call of the entry point the configuration
 names, in the harness process.
 
-As ``wcstream_inproc``, for any stream command of the program that commits
-``mr-out-*`` under ``--workdir`` and prints ``<stats_tag>:
-pipeline_stats={...}`` on stderr with ``--stats``.  The configuration gives
-the entry point as data (``"entry": "<module>"``, loaded here by name, so
-this file holds no import statement of the program), the tag, and the flags
-(``"argv"``, in which ``{workdir}`` stands for the job's work directory);
-the traffic mix may add ``extra_args``; the corpus files come last.
+The harness process holds the chip(s) for the whole run, as a user's stream
+process does for its job.  For any stream command of the program that
+commits ``mr-out-*`` under ``--workdir`` and prints ``<stats_tag>:
+pipeline_stats={...}`` on stderr with ``--stats`` (``wcstream``,
+``grepstream``).  The configuration gives the entry point as data
+(``"entry": "<module>"``, loaded here by name, so this file holds no import
+statement of the program), the tag, the flags (``"argv"``, in which
+``{workdir}`` stands for the job's work directory) and the layout the
+checks hold a job to (``devices``, ``chunk_bytes``); the traffic mix may
+add ``extra_args``; the corpus files come last.
 
 Importing this file also registers the plain reference of kind
 ``grepstats`` (``reference_grepstats.py``).  ``run.py`` imports a
@@ -19,7 +22,8 @@ configuration can name its reference (PERF.md, Open questions).
 The trace of a traced run is anchored to the job, not to the wall clock:
 the profiler starts immediately before the traced job's call of ``main``
 and stops after ``trace_seconds`` or when the call returns, whichever is
-first.  So the trace holds the job's first steps however fast they get.
+first.  So the trace holds the job's first steps however fast they get, and
+a job that returns in a tenth of a second is traced whole.
 """
 
 from __future__ import annotations
@@ -38,7 +42,7 @@ import time
 import jaxwatch  # benchmarks/ is on sys.path: run.py put it there
 import reference
 import reference_grepstats
-from drivers.wcstream_inproc import claim_device, finish  # noqa: F401
+from drivers._common import claim_device, finish  # noqa: F401
 
 reference.KINDS.setdefault("grepstats", reference_grepstats.lines)
 
@@ -140,7 +144,7 @@ def run_job(cell, i: int) -> dict:
 def job_problems(cell, job: dict) -> list:
     """Every byte through a device step, on every device of the layout."""
     problems = []
-    if "needed the host path" in job["log_text"]:
+    if re.search(r"need(s|ed) the host path", job["log_text"]):
         problems.append("the stream took the host path")
     ps = job["pipeline_stats"]
     want, chunk = int(cell.config["devices"]), int(cell.config["chunk_bytes"])

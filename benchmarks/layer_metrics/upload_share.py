@@ -1,6 +1,7 @@
 """Percent of the job wall the engine spends putting step inputs on the
-device (``upload_s`` of ``pipeline_stats``: host-blocked in the
-host-to-device puts of a step's chunk, lengths and line bases)."""
+device (``upload_s`` of ``pipeline_stats``: host-blocked in the one
+host-to-device put of a step's inputs, the chunk and one small array of
+its lengths and line bases)."""
 
 from layer_metrics._common import median_of, pipeline_stats
 

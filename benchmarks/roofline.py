@@ -29,7 +29,8 @@ def wordcount_bytes(shapes: dict) -> float:
 
 def lineflag_bytes(shapes: dict) -> float:
     """Least bytes for one run of a line-matching program: read the text,
-    write one flag byte per possible line start."""
+    write ``flag_bytes`` of line flags (the kernel block says how many the
+    program's result holds: one packed bit per byte position today)."""
     return float(shapes["input_bytes"] + shapes["flag_bytes"])
 
 

@@ -6,8 +6,10 @@
 Runs one cell of ``BENCHMARK.json`` on the machine it is started on and
 prints, as the last line of stdout, one JSON object with the keys
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (with
-``--trace 1`` also ``breakdown``).  Everything else a reader needs to
-recompute a metric goes on earlier lines.
+``--trace 1`` also ``breakdown``) and, last, ``compared``: each number the
+comparison with the plain reference held to a limit, beside that limit; the
+same numbers are the last lines of stderr.  Everything else a reader needs
+to recompute a metric goes on earlier lines.
 
 A run is: set-up (corpus and plain reference from ``--seed``, warm-up of the
 cell's own programs), then a closed loop of whole jobs, one submitter: a new
@@ -35,6 +37,7 @@ holds only the benchmark and not the program: non-zero, and no result.
 from __future__ import annotations
 
 import argparse
+import collections
 import importlib
 import json
 import os
@@ -187,43 +190,59 @@ def run_window(cell: Cell, driver) -> list:
     return jobs
 
 
-def verify(cell: Cell, driver, jobs: list) -> bool:
+def verify(cell: Cell, driver, jobs: list) -> dict:
     """After the window: every job's merged, sorted output against the
     plain reference, byte for byte; plus the driver's own conditions (every
-    map or step on the device, the right platform)."""
-    all_equal = True
+    map or step on the device, the right platform).  Returns the numbers
+    compared, each an exact comparison with the limit 0: jobs that exited
+    non-zero, output lines that differ from the reference (missing or
+    surplus, as multisets, over all jobs), conditions of the driver that a
+    job broke."""
+    bad_exits = differing = broken = 0
     for job in jobs:
-        problems = list(job.get("problems", []))
+        problems = []
         if job["rc"] != 0:
             problems.append(f"exit code {job['rc']}")
-            all_equal = False
+            bad_exits += 1
         else:
             got = reference.read_output(job["workdir"])
             if got != cell.reference_lines:
+                want_n = collections.Counter(cell.reference_lines)
+                got_n = collections.Counter(got)
+                off = sum(((want_n - got_n) + (got_n - want_n)).values())
                 problems.append(
                     f"output differs from the plain reference: {len(got)} "
-                    f"lines, want {len(cell.reference_lines)}")
-                all_equal = False
-        problems += driver.job_problems(cell, job)
+                    f"lines, want {len(cell.reference_lines)}; {off} lines "
+                    "missing or surplus")
+                differing += off
+        own = list(job.get("problems", [])) + driver.job_problems(cell, job)
+        broken += len(own)
+        problems += own
         job["problems"] = problems
         if problems:
             log(json.dumps({"job_failed": {"i": job["i"],
                                            "problems": problems}}))
-    return all_equal
+    return {"jobs_exited_nonzero": {"value": bad_exits, "limit": 0},
+            "output_lines_differing": {"value": differing, "limit": 0},
+            "driver_conditions_broken": {"value": broken, "limit": 0}}
 
 
 def read_layer_metrics(cell: Cell) -> dict:
     """Each per-layer metric of the cell through its own reader; a reader
-    that finds nothing returns None and the metric is left out."""
+    that finds nothing returns None and the metric is left out.  A quantity
+    whose cells report different end-to-end metrics is split by a suffix
+    (``map_task_s`` moves one, ``map_task_s.grep`` the other): the reader
+    is the file of the name before the first dot."""
     out = {}
     for m in cell.metric_entries("per_layer"):
         if cell.rehearsal and m["source"] != "program_counter":
             continue  # a CPU run gives no time, rate or share
+        reader = m["name"].split(".")[0]
         try:
-            mod = importlib.import_module(f"layer_metrics.{m['name']}")
+            mod = importlib.import_module(f"layer_metrics.{reader}")
         except ModuleNotFoundError:
             raise BenchError(f"per-layer metric {m['name']!r} has no reader "
-                             f"benchmarks/layer_metrics/{m['name']}.py")
+                             f"benchmarks/layer_metrics/{reader}.py")
         value = mod.read(cell.obs)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
@@ -283,7 +302,7 @@ def _run(args) -> int:
         window_s = jobs[-1]["t_end"] - jobs[0]["t_start"]
         spans = [measured_span(j) for j in jobs]
         measured_s = sum(end - start for start, end in spans)
-        correct = verify(cell, driver, jobs)
+        compared = verify(cell, driver, jobs)
         driver.finish(cell, jobs)   # device report, trace reduction
     finally:
         shutil.rmtree(cell.workroot, ignore_errors=True)
@@ -316,11 +335,15 @@ def _run(args) -> int:
     else:
         values = {"setup_s": setup_s}
         if done_bytes:
-            values[cell.config["throughput_metric"]] = \
+            # the configuration names its throughput metric; a mix whose
+            # cell is held to a bound of its own names another
+            values[cell.traffic.get("throughput_metric",
+                                    cell.config["throughput_metric"])] = \
                 done_bytes / 1e6 / measured_s
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in cell.metric_entries("end_to_end")
                    if m["name"] in values and not cell.rehearsal}
+    correct = all(c["value"] <= c["limit"] for c in compared.values())
     result = {"correct": bool(correct and done), "attempted": len(jobs),
               "failed": len(jobs) - len(done), "metrics": metrics,
               "device": device}
@@ -328,7 +351,11 @@ def _run(args) -> int:
         result["breakdown"] = cell.obs["breakdown"]
     if cell.rehearsal:
         result["rehearsal"] = True
+    result["compared"] = compared   # each number beside its limit, last
     print(json.dumps(result), flush=True)
+    for name, c in compared.items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -139,7 +139,43 @@ def test_batch_readers_on_a_recorded_job():
 def test_stream_readers_on_recorded_jobs():
     rec = _recorded("stream-pipeline-stats.json")
     got = {name: _read(name, rec["obs"])
-           for name in STREAM + ("finalize_write_s", "pull_share")}
+           for name in STREAM + ("pull_share",)}
     assert got == pytest.approx(rec["expected"], rel=1e-9, abs=1e-9)
-    assert abs(got["finalize_s"] + got["write_s"]
-               - got["finalize_write_s"]) < 0.3
+
+
+def test_a_split_quantity_reads_through_the_quantitys_reader():
+    """``batch-grep`` reports its throughput under a metric of its own, so
+    its batch-plane quantities carry the suffix ``.grep``: each is read by
+    the file of the name before the dot and printed under the whole name,
+    and the cell keeps none of the unsplit names that move the other
+    metric."""
+    import argparse
+
+    import run
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    args = argparse.Namespace(workload="batch-grep", seed=1, seconds=1.0,
+                              trace=1, rehearse_cpu=False)
+    cell = run.Cell(bench, args)
+    assert cell.traffic["throughput_metric"] == "batch_plane_grep_MBps"
+    mine = {m["name"]: m for m in cell.metric_entries("per_layer")}
+    split = {n for n in mine if n.endswith(".grep")}
+    assert {"map_task_s.grep", "map_write_s.grep",
+            "worker_device_idle.grep"} <= split
+    for name, m in mine.items():
+        assert m["moves"] in ("setup_s", "batch_plane_grep_MBps"), name
+    cell.obs = _batch_obs()
+    spans_only = ("map_read_s", "map_device_wait_s", "map_decode_s",
+                  "map_write_s", "task_gap_ms")   # what the hand-made obs holds
+    entries = [mine[name + ".grep"] for name in spans_only]
+    cell.metric_entries = lambda group: entries
+    got = run.read_layer_metrics(cell)
+    assert set(got) == {name + ".grep" for name in spans_only}
+    for name in spans_only:
+        assert name not in got
+        assert got[name + ".grep"]["value"] == pytest.approx(
+            _read(name, cell.obs))
+        assert got[name + ".grep"]["unit"] == mine[name + ".grep"]["unit"]
