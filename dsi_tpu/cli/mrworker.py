@@ -80,8 +80,11 @@ def main(argv=None) -> int:
         worker_loop(mapf, reducef, cfg, task_runner=runner,
                     partsrv=partsrv)
         if args.backend == "tpu":
-            print(f"mrworker: pid={os.getpid()} {runner.report()}",
-                  file=sys.stderr, flush=True)
+            # one write: print() sends the newline separately, and the
+            # workers of a job share their launcher's stderr
+            sys.stderr.write(
+                f"mrworker: pid={os.getpid()} {runner.report()}\n")
+            sys.stderr.flush()
         if partsrv is not None:
             # Linger: the job is done but the driver may not have
             # fetched this spool's outputs yet — serve until killed.

@@ -43,6 +43,7 @@ import numpy as np
 from dsi_tpu.ops.wordcount import (
     _PAD_KEY,
     build_lanes,
+    compact_positions,
     exactness_retry,
     group_sorted,
     is_ascii_letter,
@@ -133,7 +134,7 @@ def _corpus_core(chunk, max_word_len: int, u_cap: int, t_cap_frac: int,
 
     lanes = build_lanes(chunk, length_all, max_word_len)
 
-    (start_pos,) = jnp.nonzero(starts, size=t_cap, fill_value=n - 1)
+    start_pos = compact_positions(starts, t_cap, n - 1)
     valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
     lengths = jnp.where(valid, length_all[start_pos], 0)
     max_len = jnp.max(lengths, initial=0)

@@ -188,18 +188,45 @@ def lex_sort(keys: tuple, payloads: tuple = ()) -> tuple:
     return (*cols, *pays)
 
 
+def compact_positions(mask: jax.Array, size: int,
+                      fill_value: int) -> jax.Array:
+    """The first ``size`` set positions of ``mask`` in ascending order,
+    then ``fill_value``: the values of ``jnp.nonzero(mask, size=size,
+    fill_value=fill_value)[0]``, as ``int32`` whatever the x64 scope says.
+
+    One single-key ``lax.sort`` of ``where(mask, position, m)``: the set
+    positions come first, in order, and every other row carries ``m``,
+    which no position equals.  No scatter: ``jnp.nonzero(size=)`` ranks
+    with a ``scatter-add`` of one update per input position, 64-bit
+    under the scoped x64 flag of this package's programs, and that
+    emulated scatter cost 66-89 ns a position on a TPU v5e where a sort
+    pass costs about 1 (PERF.md, PR 35)."""
+    (m,) = mask.shape
+    if m >= 1 << 31:
+        raise ValueError(f"compact_positions: {m} positions overflow int32")
+    key = jnp.where(mask, jnp.arange(m, dtype=jnp.int32), jnp.int32(m))
+    (key,) = lax.sort((key,), num_keys=1)
+    if size > m:
+        key = jnp.concatenate([key, jnp.full((size - m,), m, jnp.int32)])
+    key = key[:size]
+    return jnp.where(key < m, key, jnp.int32(fill_value))
+
+
 @jax.named_scope("group")
 def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     """Group adjacent equal rows of lexicographically sorted key columns.
 
     The shared reduce idiom (run-boundary detect + segment-sum + compact)
-    used by the single-chunk kernel and by the sharded all_to_all merge
-    (parallel/shuffle.py).  ``skeys_cols``: k sorted unsigned key columns
-    (uint32 lanes or uint64 packed lane pairs), PAD rows last — a pad row
-    is all-ones in every lane, i.e. the dtype's max in every column;
-    ``counts``: per-row counts to sum within each group.
+    used by the single-chunk kernel, by the sharded all_to_all merge
+    (parallel/shuffle.py) and by the device table's folds
+    (device/table.py).  The segment-sum runs over sorted 32-bit ids; the
+    compaction of the run starts is :func:`compact_positions` (one
+    single-key int32 sort, no scatter).  ``skeys_cols``: k sorted
+    unsigned key columns (uint32 lanes or uint64 packed lane pairs), PAD
+    rows last — a pad row is all-ones in every lane, i.e. the dtype's max
+    in every column; ``counts``: per-row counts to sum within each group.
 
-    Returns (keys2d [t,k], totals [out_cap], upos [out_cap], ovalid
+    Returns (keys2d [t,k], totals [out_cap], upos [out_cap] int32, ovalid
     [out_cap], n_unique) — callers gather their payloads at ``upos`` and
     mask with ``ovalid``.
     """
@@ -218,11 +245,7 @@ def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     totals = jax.ops.segment_sum(
         jnp.where(valid, counts, 0), jnp.where(valid, uid, out_cap),
         num_segments=out_cap + 1, indices_are_sorted=True)[:out_cap]
-    (upos,) = jnp.nonzero(is_new, size=out_cap, fill_value=t - 1)
-    # Callers run this under the scoped x64 flag (u64 packed keys), where
-    # nonzero yields int64 — pin indices to int32 so they don't drag
-    # 64-bit promotion into the caller's non-x64 ops.
-    upos = upos.astype(jnp.int32)
+    upos = compact_positions(is_new, out_cap, t - 1)
     ovalid = jnp.arange(out_cap, dtype=jnp.int32) < n_unique
     return keys, totals, upos, ovalid, n_unique
 
@@ -239,7 +262,9 @@ def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
     lexicographic sort, so the result is exact regardless of hash
     behavior; only if the dirty set overflows its buffer (pathological
     input) does ``group_overflow`` make the caller re-run the whole
-    chunk through the sort grouper.
+    chunk through the sort grouper.  Both compactions (the dirty tokens,
+    the clean buckets) are :func:`compact_positions`, as everywhere on
+    the device path; the bucketing itself stays segment ops.
 
     Motivation (measured on XLA:CPU): at 1 MiB/4 tokens the big
     lexicographic sort costs ~99 ms while the segment-op group + t_cap/8
@@ -299,7 +324,7 @@ def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
     in_dirty = valid & dirty[jnp.clip(idx1, 0, n_buckets - 1)]
     n_dirty_tokens = jnp.sum(in_dirty, dtype=jnp.int32)
     group_overflow = n_dirty_tokens > d_cap
-    (dpos,) = jnp.nonzero(in_dirty, size=d_cap, fill_value=0)
+    dpos = compact_positions(in_dirty, d_cap, 0)
     dvalid = jnp.arange(d_cap, dtype=jnp.int32) < n_dirty_tokens
     dlen = jnp.where(dvalid, lengths[dpos], 0)
     dlanes = tuple(jnp.where(dvalid, col[dpos], jnp.uint32(_PAD_KEY))
@@ -325,7 +350,7 @@ def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
     clean1 = occ1 & ~dirty
     n_clean1 = jnp.sum(clean1, dtype=jnp.int32)
     n_unique = n_clean1 + n_du
-    (cpos1,) = jnp.nonzero(clean1, size=u_cap, fill_value=n_buckets - 1)
+    cpos1 = compact_positions(clean1, u_cap, n_buckets - 1)
     v1 = jnp.arange(u_cap, dtype=jnp.int32) < n_clean1
     dst2 = jnp.where(dovalid, jnp.arange(u_cap, dtype=jnp.int32) + n_clean1,
                      u_cap)
@@ -393,18 +418,16 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
 
     # Compact to the token buffer.  Token lengths come from the paired
     # start/end compactions (runs cannot nest, so the i-th start matches
-    # the i-th end) — cheaper than the former per-position reverse-min
-    # scan, whose log-depth passes over the whole chunk were ~10% of the
-    # kernel.  Key lanes gather straight from the single packed-bytes
-    # array at ``start + 4j`` and are masked AFTER compaction: the same
-    # k token-level gathers as before, but the byte-masking runs over
-    # t_cap rows instead of building k masked full-chunk lane arrays.
+    # the i-th end), each one int32 sort over the chunk's positions
+    # (compact_positions).  Key lanes gather straight from the single
+    # packed-bytes array at ``start + 4j`` and are masked AFTER
+    # compaction, so the byte-masking runs over t_cap rows and no masked
+    # full-chunk lane arrays are built.
     with jax.named_scope("compact"):
-        (start_pos,) = jnp.nonzero(starts, size=t_cap, fill_value=n - 1)
-        (end_pos,) = jnp.nonzero(ends, size=t_cap, fill_value=n - 1)
+        start_pos = compact_positions(starts, t_cap, n - 1)
+        end_pos = compact_positions(ends, t_cap, n - 1)
         valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
-        lengths = jnp.where(valid, end_pos - start_pos + 1,
-                            0).astype(jnp.int32)
+        lengths = jnp.where(valid, end_pos - start_pos + 1, 0)
         max_len = jnp.max(lengths, initial=0)
     with jax.named_scope("pack"):
         c = chunk.astype(jnp.uint32)

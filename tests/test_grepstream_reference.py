@@ -167,6 +167,9 @@ def test_new_spans_counter_and_keys_are_recorded(tmp_path, capsys):
     trace_dir = tmp_path / "trace"
     tracer = get_tracer()
     was = tracer.enabled
+    # the tracer is the process's: an earlier test of this worker may have
+    # left spans in its buffer, and a flush writes the whole buffer
+    since = tracer.mark()
     try:
         rc = cli.main(["--pattern", "e", "--workdir", str(tmp_path / "wd"),
                        "--devices", "1", "--chunk-bytes", "4096", "--stats",
@@ -183,7 +186,7 @@ def test_new_spans_counter_and_keys_are_recorded(tmp_path, capsys):
     assert ps["pull_bytes"] == ps["steps"] * (44 + 320)
     assert ps["pull_s"] >= ps["device_wait_s"] + ps["d2h_s"] - 1e-3
     with open(trace_dir / "trace.jsonl") as f:
-        events = [json.loads(line) for line in f][1:]
+        events = [json.loads(line) for line in f][1 + since:]
     spans = {e["id"]: e for e in events if e.get("ph") == "X"}
     names = collections.Counter(e["name"] for e in spans.values())
     assert names["pull"] == names["d2h"] == ps["steps"]

@@ -206,3 +206,151 @@ def test_hash_grouper_matches_sort_on_random_text(monkeypatch):
     monkeypatch.setenv("DSI_WC_GROUPER", "sort")
     rs = count_words_host_result(text.encode())
     assert rh == rs and rh is not None
+
+
+# ── compaction: one int32 helper, no scatter ───────────────────────────
+
+
+def _mask(kind: str, m: int):
+    import numpy as np
+
+    if kind == "empty":
+        return np.zeros(m, bool)
+    if kind == "full":
+        return np.ones(m, bool)
+    if kind == "alternating":
+        return np.arange(m) % 2 == 1
+    return np.random.default_rng(m).random(m) < 0.3
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("fill", ["last", "zero"])
+@pytest.mark.parametrize("size_is", ["below", "equal", "above"])
+@pytest.mark.parametrize("kind", ["empty", "full", "alternating", "random"])
+def test_compact_positions_equals_flatnonzero(kind, size_is, fill, x64):
+    """``compact_positions`` against ``np.flatnonzero``: the first ``size``
+    set positions, then the fill; ``m`` no power of two; int32 inside and
+    outside the x64 scope its callers run in."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dsi_tpu.ops.wordcount import compact_positions
+    from dsi_tpu.utils.jaxcompat import enable_x64
+
+    m = 1000
+    mask = _mask(kind, m)
+    hits = np.flatnonzero(mask)
+    size = {"below": max(1, len(hits) - 7), "equal": max(1, len(hits)),
+            "above": len(hits) + 5}[size_is]
+    fill_value = m - 1 if fill == "last" else 0
+    want = np.full(size, fill_value)
+    want[:min(size, len(hits))] = hits[:size]
+    with enable_x64(x64):
+        got = jax.jit(compact_positions, static_argnums=(1, 2))(
+            jnp.asarray(mask), size, fill_value)
+        assert got.dtype == jnp.int32 and got.shape == (size,)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        ref = jnp.nonzero(jnp.asarray(mask), size=size,
+                          fill_value=fill_value)[0]
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def _scatters(fn, *args):
+    """Names of the scatter primitives in ``fn``'s jaxpr, with repeats,
+    traced under the x64 scope the word-count programs run in."""
+    import jax
+
+    from dsi_tpu.utils.jaxcompat import enable_x64
+
+    def names(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from names(sub)
+
+    with enable_x64(True):
+        return sorted(p for p in names(jax.make_jaxpr(fn)(*args).jaxpr)
+                      if "scatter" in p)
+
+
+def test_compact_positions_jaxpr_holds_no_scatter():
+    """The mechanism, pinned where no device trace is at hand (after
+    ``test_grep_step_jaxpr_holds_no_scatter``): the compaction is a sort,
+    where ``jnp.nonzero(size=)`` lowers a 64-bit ``scatter-add``."""
+    import jax.numpy as jnp
+
+    from dsi_tpu.ops.wordcount import compact_positions
+
+    mask = jnp.arange(1000) % 3 == 0
+    assert _scatters(lambda x: compact_positions(x, 300, 999), mask) == []
+    assert _scatters(
+        lambda x: jnp.nonzero(x, size=300, fill_value=999)[0], mask) != []
+
+
+def test_sort_grouper_jaxpr_holds_one_scatter():
+    """The word-count program with the sort grouper scatters once: the
+    ``segment_sum`` of ``group_sorted`` (sorted 32-bit ids)."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from dsi_tpu.ops.wordcount import tokenize_group_core
+
+    fn = functools.partial(tokenize_group_core, u_cap=64, grouper="sort")
+    assert _scatters(fn, jnp.zeros(256, jnp.uint8)) == ["scatter-add"]
+
+
+_N = 256  # one chunk; t_cap = _N // 4 + 1 = 65 tokens
+
+
+def _boundary_chunk(case: str) -> bytes:
+    t_cap = _N // 4 + 1
+    body = {
+        "t_cap_tokens": b"ab " * (t_cap - 1) + b"zz",
+        "t_cap_plus_one": b"ab " * t_cap + b"zz",
+        "no_tokens": b"12 34 !? \n" * 20,
+        # every start is an end
+        "single_letters": b" ".join(bytes([97 + i % 26]) for i in range(60)),
+        # the chunk's last byte is a letter: no byte follows the last end
+        "ends_in_letter": (b"tail words here " * 16)[:_N - 3] + b" xy",
+    }[case]
+    return body.ljust(_N, b"\0")
+
+
+@pytest.mark.parametrize("grouper", ["sort", "hash"])
+@pytest.mark.parametrize("case", ["t_cap_tokens", "t_cap_plus_one",
+                                  "no_tokens", "single_letters",
+                                  "ends_in_letter"])
+def test_program_at_its_token_boundaries(case, grouper):
+    """The program as a whole where its buffers end: exactly ``t_cap``
+    tokens, one more (``token_overflow``, and the first ``t_cap`` tokens
+    counted), none, single letters only, a letter in the last byte; each
+    equal to the host reference."""
+    import re
+
+    import numpy as np
+
+    from dsi_tpu.ops.wordcount import count_words_kernel, decode_packed
+
+    chunk = _boundary_chunk(case)
+    assert len(chunk) == _N
+    t_cap = _N // 4 + 1
+    tokens = re.findall(rb"[A-Za-z]+", chunk)
+    (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
+     token_overflow) = count_words_kernel(
+        np.frombuffer(chunk, np.uint8), max_word_len=16, u_cap=128,
+        t_cap_frac=4, grouper=grouper)
+    assert bool(token_overflow) == (len(tokens) > t_cap) \
+        == (case == "t_cap_plus_one")
+    assert not bool(has_high)
+    want = collections.Counter(t.decode() for t in tokens[:t_cap])
+    nu = int(n_unique)
+    words = decode_packed(np.asarray(packed_u), np.asarray(len_u), nu)
+    counts = np.asarray(cnt_u)[:nu].tolist()
+    assert dict(zip(words, counts)) == dict(want) and len(words) == len(want)
+    assert int(max_len) == max((len(t) for t in tokens[:t_cap]), default=0)
+    hashes = (np.asarray(fnv_u)[:nu] & 0x7FFFFFFF).tolist()
+    assert hashes == [ihash(w) for w in words]
+    if not bool(token_overflow):
+        check(chunk.rstrip(b"\0").decode())
