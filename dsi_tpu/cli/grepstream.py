@@ -53,6 +53,78 @@ def result_records(res) -> list:
                for rank, (line_no, occ) in enumerate(res.topk)])
 
 
+def _job(args, pattern: str, pstats: dict):
+    """The job between its parsed arguments and its ``--stats`` line:
+    ``(exit code, result, whether the host scan produced it)``."""
+    from dsi_tpu.obs import span
+
+    with span("start", lane="host", stats=pstats):
+        from dsi_tpu.utils.platformpin import require_device
+
+        require_device("grepstream")
+
+        from dsi_tpu.ckpt import CheckpointMismatch
+        from dsi_tpu.parallel.grepstream import GrepStep, grep_host_oracle
+        from dsi_tpu.parallel.shuffle import default_mesh
+        from dsi_tpu.parallel.streaming import stream_files
+        from dsi_tpu.utils.ioread import open_blocks
+
+        mesh = default_mesh(args.devices)
+        try:
+            # Construction ends with the pipeline armed; close() below
+            # drives it (grep_streaming is the two in one call).
+            step = GrepStep(
+                open_blocks(args.files, readers=args.ingest_readers),
+                pattern, mesh=mesh,
+                chunk_bytes=args.chunk_bytes, depth=args.pipeline_depth,
+                aot=args.aot, device_accumulate=args.device_accumulate,
+                sync_every=args.sync_every, mesh_shards=args.mesh_shards,
+                topk=args.topk,
+                checkpoint_dir=args.checkpoint_dir,
+                checkpoint_every=args.checkpoint_every,
+                checkpoint_async=args.ckpt_async,
+                checkpoint_delta=args.ckpt_delta, resume=args.resume,
+                pipeline_stats=pstats)
+        except CheckpointMismatch as e:
+            # A valid checkpoint for a DIFFERENT job (other
+            # pattern/shape): refuse loudly rather than corrupt or
+            # overwrite the lineage.
+            print(f"grepstream: {e}", file=sys.stderr)
+            return 1, None, False
+    res = step.close()
+    if args.resume and not pstats.get("resume_cursor"):
+        # Legitimate when the crash predated the first checkpoint, but a
+        # typo'd --checkpoint-dir looks identical — never replay a whole
+        # stream silently.
+        print("grepstream: --resume found no usable checkpoint in "
+              f"{args.checkpoint_dir}; started from scratch",
+              file=sys.stderr)
+    host_path = res is None
+    if host_path:
+        try:
+            res = grep_host_oracle(stream_files(args.files), pattern,
+                                   topk=args.topk)
+        except UnicodeEncodeError:
+            print("grepstream: pattern is not plain ASCII; use the "
+                  "tpu_grep MR app for regex tiers", file=sys.stderr)
+            return 1, None, True
+        print("grepstream: stream needed the host path; ran the host scan",
+              file=sys.stderr)
+
+    if args.workdir:
+        from dsi_tpu.utils.atomicio import atomic_write
+
+        os.makedirs(args.workdir, exist_ok=True)
+        records = result_records(res)
+        with span("write", lane="host", stats=pstats,
+                  records=len(records)) as sp:
+            path = os.path.join(args.workdir, "mr-out-0")
+            with atomic_write(path) as f:
+                f.write("".join(r + "\n" for r in records))
+            sp.set(bytes=os.path.getsize(path))
+    return 0, res, host_path
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("files", nargs="+")
@@ -150,69 +222,20 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
 
-    from dsi_tpu.utils.platformpin import require_device
+    from dsi_tpu.obs import span
+    from dsi_tpu.obs.registry import job_children_s
 
-    require_device("grepstream")
-
-    from dsi_tpu.parallel.grepstream import grep_host_oracle, grep_streaming
-    from dsi_tpu.parallel.shuffle import default_mesh
-    from dsi_tpu.parallel.streaming import stream_files
-    from dsi_tpu.utils.ioread import open_blocks
-
-    from dsi_tpu.ckpt import CheckpointMismatch
-
-    mesh = default_mesh(args.devices)
+    # The root of the main thread's account, as in wcstream: its direct
+    # children (the registry's JOB_CHILDREN) cover it.
     pstats: dict = {}
-    try:
-        res = grep_streaming(
-            open_blocks(args.files, readers=args.ingest_readers),
-            pattern, mesh=mesh,
-            chunk_bytes=args.chunk_bytes, depth=args.pipeline_depth,
-            aot=args.aot, device_accumulate=args.device_accumulate,
-            sync_every=args.sync_every, mesh_shards=args.mesh_shards,
-            topk=args.topk,
-            checkpoint_dir=args.checkpoint_dir,
-            checkpoint_every=args.checkpoint_every,
-            checkpoint_async=args.ckpt_async,
-            checkpoint_delta=args.ckpt_delta, resume=args.resume,
-            pipeline_stats=pstats)
-    except CheckpointMismatch as e:
-        # A valid checkpoint for a DIFFERENT job (other pattern/shape):
-        # refuse loudly rather than corrupt or overwrite the lineage.
-        print(f"grepstream: {e}", file=sys.stderr)
-        return 1
-    if args.resume and not pstats.get("resume_cursor"):
-        # Legitimate when the crash predated the first checkpoint, but a
-        # typo'd --checkpoint-dir looks identical — never replay a whole
-        # stream silently.
-        print("grepstream: --resume found no usable checkpoint in "
-              f"{args.checkpoint_dir}; started from scratch",
-              file=sys.stderr)
-    host_path = res is None
-    if host_path:
-        try:
-            res = grep_host_oracle(stream_files(args.files), pattern,
-                                   topk=args.topk)
-        except UnicodeEncodeError:
-            print("grepstream: pattern is not plain ASCII; use the "
-                  "tpu_grep MR app for regex tiers", file=sys.stderr)
-            return 1
-        print("grepstream: stream needed the host path; ran the host scan",
-              file=sys.stderr)
-
-    if args.workdir:
-        from dsi_tpu.obs import span
-        from dsi_tpu.utils.atomicio import atomic_write
-
-        os.makedirs(args.workdir, exist_ok=True)
-        records = result_records(res)
-        with span("write", lane="host", stats=pstats,
-                  records=len(records)) as sp:
-            path = os.path.join(args.workdir, "mr-out-0")
-            with atomic_write(path) as f:
-                f.write("".join(r + "\n" for r in records))
-            sp.set(bytes=os.path.getsize(path))
-        pstats["write_s"] = round(pstats["write_s"], 4)
+    with span("job", lane="host", stats=pstats):
+        rc, res, host_path = _job(args, pattern, pstats)
+    if rc:
+        return rc
+    for key in ("job_s", "start_s", "write_s"):
+        if key in pstats:  # no write without --workdir
+            pstats[key] = round(pstats[key], 4)
+    pstats["job_children_s"] = round(job_children_s(pstats), 4)
     # After the write, so that the line holds the job's tail too
     # (finalize_s, write_s) and the trace its last span.
     if args.stats:
@@ -229,6 +252,9 @@ def main(argv=None) -> int:
         print(f"top line={line_no} occ={occ}")
 
     if args.check and not host_path:
+        from dsi_tpu.parallel.grepstream import grep_host_oracle
+        from dsi_tpu.parallel.streaming import stream_files
+
         want = grep_host_oracle(stream_files(args.files), pattern,
                                 topk=args.topk)
         if res != want:

@@ -357,9 +357,10 @@ def main(argv=None) -> int:
         with span("write", lane="host", stats=pstats,
                   keys=len(res.final)) as sp:
             paths = write_partitioned_output(res.final, args.nreduce,
-                                             args.workdir)
+                                             args.workdir, stats=pstats)
             sp.set(bytes=sum(os.path.getsize(path) for path in paths))
-        pstats["write_s"] = round(pstats["write_s"], 4)
+        for key in ("write_s", "write_format_s", "write_commit_s"):
+            pstats[key] = round(pstats[key], 4)
     elif args.chain == "grep-grep":
         stages = {name: {"lines": r.lines, "matched": r.matched,
                          "occurrences": r.occurrences}

@@ -39,6 +39,71 @@ def _positive_int(s: str) -> int:
     return v
 
 
+def _job(args, pstats: dict):
+    """The job between its parsed arguments and its ``--stats`` line:
+    ``(exit code, devices)``."""
+    from dsi_tpu.obs import span
+
+    with span("start", lane="host", stats=pstats):
+        from dsi_tpu.utils.platformpin import require_device
+
+        devices = require_device("wcstream")
+
+        from dsi_tpu.ckpt import CheckpointMismatch
+        from dsi_tpu.parallel.shuffle import (default_mesh,
+                                              write_partitioned_output)
+        from dsi_tpu.parallel.streaming import WordcountStep
+        from dsi_tpu.utils.ioread import open_blocks
+
+        mesh = default_mesh(args.devices)
+        try:
+            # Construction ends with the pipeline armed; close() below
+            # drives it (wordcount_streaming is the two in one call).
+            step = WordcountStep(
+                open_blocks(args.files, readers=args.ingest_readers),
+                mesh=mesh, n_reduce=args.nreduce,
+                chunk_bytes=args.chunk_bytes, u_cap=args.u_cap,
+                aot=args.aot, depth=args.pipeline_depth,
+                device_accumulate=args.device_accumulate,
+                sync_every=args.sync_every, mesh_shards=args.mesh_shards,
+                checkpoint_dir=args.checkpoint_dir,
+                checkpoint_every=args.checkpoint_every,
+                checkpoint_async=args.ckpt_async,
+                checkpoint_delta=args.ckpt_delta, resume=args.resume,
+                wire_upload=args.wire_upload,
+                pipeline_stats=pstats)
+        except CheckpointMismatch as e:
+            # A valid checkpoint for a DIFFERENT job (other corpus shape /
+            # mesh / mode): resuming would corrupt it, starting fresh
+            # would overwrite it — the caller must fix the command or the
+            # dir.
+            print(f"wcstream: {e}", file=sys.stderr)
+            return 1, devices
+    acc = step.close()
+    if args.resume and not pstats.get("resume_cursor"):
+        # Legitimate when the crash predated the first checkpoint, but a
+        # typo'd --checkpoint-dir looks identical — say it out loud so a
+        # GB-scale from-scratch replay is never a silent surprise.
+        print("wcstream: --resume found no usable checkpoint in "
+              f"{args.checkpoint_dir}; started from scratch",
+              file=sys.stderr)
+    if acc is None:
+        # Host fallback: the sequential oracle semantics, partitioned
+        # output — the ONE shared implementation (serve/pack.py), so the
+        # CLI and the serving daemon cannot drift.
+        print("wcstream: stream needs the host path; running host word count",
+              file=sys.stderr)
+        from dsi_tpu.serve.pack import host_wordcount
+
+        acc = host_wordcount(args.files, args.nreduce)
+    os.makedirs(args.workdir, exist_ok=True)
+    with span("write", lane="host", stats=pstats, keys=len(acc)) as sp:
+        paths = write_partitioned_output(acc, args.nreduce, args.workdir,
+                                         stats=pstats)
+        sp.set(bytes=sum(os.path.getsize(path) for path in paths))
+    return 0, devices
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("files", nargs="+")
@@ -160,61 +225,21 @@ def main(argv=None) -> int:
 
         start_from_args(args.statusz_port, live_dir=args.trace_dir)
 
-    from dsi_tpu.utils.platformpin import require_device
-
-    devices = require_device("wcstream")
-
-    from dsi_tpu.parallel.shuffle import default_mesh, write_partitioned_output
-    from dsi_tpu.parallel.streaming import wordcount_streaming
-    from dsi_tpu.utils.ioread import open_blocks
-
-    from dsi_tpu.ckpt import CheckpointMismatch
-
-    mesh = default_mesh(args.devices)
-    pstats: dict = {}
-    try:
-        acc = wordcount_streaming(
-            open_blocks(args.files, readers=args.ingest_readers),
-            mesh=mesh, n_reduce=args.nreduce,
-            chunk_bytes=args.chunk_bytes, u_cap=args.u_cap, aot=args.aot,
-            depth=args.pipeline_depth,
-            device_accumulate=args.device_accumulate,
-            sync_every=args.sync_every, mesh_shards=args.mesh_shards,
-            checkpoint_dir=args.checkpoint_dir,
-            checkpoint_every=args.checkpoint_every,
-            checkpoint_async=args.ckpt_async,
-            checkpoint_delta=args.ckpt_delta, resume=args.resume,
-            wire_upload=args.wire_upload,
-            pipeline_stats=pstats)
-    except CheckpointMismatch as e:
-        # A valid checkpoint for a DIFFERENT job (other corpus shape /
-        # mesh / mode): resuming would corrupt it, starting fresh would
-        # overwrite it — the caller must fix the command or the dir.
-        print(f"wcstream: {e}", file=sys.stderr)
-        return 1
-    if args.resume and not pstats.get("resume_cursor"):
-        # Legitimate when the crash predated the first checkpoint, but a
-        # typo'd --checkpoint-dir looks identical — say it out loud so a
-        # GB-scale from-scratch replay is never a silent surprise.
-        print("wcstream: --resume found no usable checkpoint in "
-              f"{args.checkpoint_dir}; started from scratch",
-              file=sys.stderr)
-    if acc is None:
-        # Host fallback: the sequential oracle semantics, partitioned
-        # output — the ONE shared implementation (serve/pack.py), so the
-        # CLI and the serving daemon cannot drift.
-        print("wcstream: stream needs the host path; running host word count",
-              file=sys.stderr)
-        from dsi_tpu.serve.pack import host_wordcount
-
-        acc = host_wordcount(args.files, args.nreduce)
-    os.makedirs(args.workdir, exist_ok=True)
     from dsi_tpu.obs import span
+    from dsi_tpu.obs.registry import job_children_s
 
-    with span("write", lane="host", stats=pstats, keys=len(acc)) as sp:
-        paths = write_partitioned_output(acc, args.nreduce, args.workdir)
-        sp.set(bytes=sum(os.path.getsize(path) for path in paths))
-    pstats["write_s"] = round(pstats["write_s"], 4)
+    # The root of the main thread's account: its direct children (the
+    # registry's JOB_CHILDREN) cover it, so job_s less job_children_s is
+    # what no span holds.
+    pstats: dict = {}
+    with span("job", lane="host", stats=pstats):
+        rc, devices = _job(args, pstats)
+    if rc:
+        return rc
+    for key in ("job_s", "start_s", "write_s", "write_format_s",
+                "write_commit_s"):
+        pstats[key] = round(pstats[key], 4)
+    pstats["job_children_s"] = round(job_children_s(pstats), 4)
     # After the write, so that the line holds the job's serial tail too
     # (finalize_s, write_s) and the trace its last span.
     if args.stats:

@@ -28,11 +28,35 @@ that phase):
 * ``merge_s``            — host-side accumulation of pulled results
 * ``finalize_s``         — the final merge of the accumulator into the
   result (compaction, decode of every distinct word)
+* ``finalize_decode_s``  — inside it, the ``decode`` span: the spellings
+  of the merged table decoded and the result dict built
+* ``compact_s``          — the ``compact`` spans of the host accumulator
+  (``parallel/merge.py``): every buffered row sorted and merged again;
+  inside ``merge_s``, ``finalize_s`` or ``sync_s``, whichever called it
 * ``write_s``            — writing the partitioned ``mr-out-*`` (the
   CLI's phase, not the engine's)
+* ``write_format_s`` / ``write_commit_s`` — inside it
+  (``shuffle.write_partitioned_output``): the ``format`` spans (the
+  bucketing, a partition's sort and line formatting) and the ``commit``
+  spans (its write, flush, fsync and rename)
+* ``job_s``              — a stream command's root ``job`` span, from
+  its parsed arguments to the ``--stats`` line; its direct children on
+  the main thread are :data:`JOB_CHILDREN`, and ``job_children_s`` is
+  the sum of their keys (``job_s`` less it is the root's self time)
+* ``start_s``            — the ``start`` span: the device gate, the
+  mesh, the engine's construction up to the pipeline armed
+* ``dispatch_s`` / ``retire_s`` — the pipeline core's ``dispatch`` and
+  ``finish`` spans (``finish_s`` is the daemon's job-finish key), one
+  each a step; ``upload_s`` and ``enqueue_s`` are inside the first,
+  ``kernel_s``, ``pull_s``, ``merge_s``, ``replay_s``, ``fold_s`` and a
+  step's ``sync_s`` and ``ckpt_s`` inside the second
+* ``enqueue_s``          — the ``enqueue`` span: the call of the
+  compiled step program and the starts of its async copies to the host
 * ``replay_s``           — exactness-ladder replays of overflowed steps
 * ``fold_s`` / ``append_s`` / ``hist_s`` — device-service folds
-* ``sync_s`` / ``drain_s``               — device-service pulls/drains
+* ``sync_s`` / ``drain_s``               — device-service pulls/drains;
+  in the stream engines ``drain_s`` is the end-of-stream drain (the
+  table's close, the checkpoint writer's last commits)
 * ``widen_s``            — drain→realloc→re-fold recoveries
 * ``ckpt_s``             — checkpoint snapshot + durable write (with
   async commits, only the boundary-side work: capture + any barrier)
@@ -51,7 +75,10 @@ at dispatch), ``step_pulls``, ``sync_pulls``, ``widens``, ``folds``,
 ``fold_overflows``, ``appends``, ``append_overflows``,
 ``postings_widens``, ``topk_snapshots``, ``hist_folds``, ``hist_pulls``,
 ``table_cap``, ``sync_every``, ``max_inflight``,
-``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
+``merge_rows_in`` (rows handed to the host accumulator's ``add``),
+``merge_rows_sorted`` (rows through its lexsort, summed over
+compactions) and ``merge_compacts`` (all three repeat exactly for one
+input), ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
 ``resume_cursor``/``resume_wave``, ``device_accumulate``.
 
 Async/incremental checkpoint keys (``dsi_tpu/ckpt`` writer/delta —
@@ -212,7 +239,36 @@ PHASE_KEYS = (
     # packers' row cut): the submit, admit, take_row, evict and finish
     # spans
     "submit_s", "admit_s", "take_s", "evict_s", "finish_s",
+    # a stream job's main thread (ISSUE 36): the root span, the sum of
+    # its direct children's keys, and what had no key: the start, the
+    # pipeline core's dispatch and finish spans, the step program's call
+    "job_s", "job_children_s", "start_s", "dispatch_s", "retire_s",
+    "enqueue_s",
+    # the host merge and the serial tail, split where the work happens
+    "compact_s", "finalize_decode_s", "write_format_s", "write_commit_s",
 )
+
+#: The direct children of a stream command's root ``job`` span on its
+#: main thread, each with the key its seconds land in as
+#: ``pipeline_stats`` spells it: ``job_s`` less the sum of these keys is
+#: what no span covers.  ``tests/test_tracing.py`` holds the tuple to
+#: what a ``--trace-dir`` run records.
+JOB_CHILDREN = (
+    ("start", "start_s"), ("wait", "batch_wait_s"),
+    ("dispatch", "dispatch_s"), ("finish", "retire_s"),
+    ("drain", "drain_s"), ("finalize", "finalize_s"),
+    ("write", "write_s"),
+)
+
+
+def job_children_s(stats: dict) -> float:
+    """Seconds in the direct children of a job's root span."""
+    keys = [key for _, key in JOB_CHILDREN]
+    if stats.get("depth") == 1:
+        # No batcher thread: the ``materialize`` spans run inline on the
+        # main thread and are children of ``job`` as well.
+        keys.append("batch_s")
+    return sum(stats.get(key, 0.0) for key in keys)
 
 #: The canonical counter/gauge keys (module docstring) — previously
 #: prose; now machine-readable because the ``metric-schema`` dsicheck
@@ -227,6 +283,8 @@ COUNTER_KEYS = (
     "table_cap", "sync_every", "max_inflight",
     "buffer_allocs", "device_accumulate", "donate_chunks", "stalls",
     "device_rows",
+    # the host accumulator (parallel/merge.py PackedCounts)
+    "merge_rows_in", "merge_rows_sorted", "merge_compacts",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

@@ -102,6 +102,13 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # an admission to the resident set, a row cut for a packed step, a
     # park to the checkpoint chain ("ckpt" and "finish" are above)
     "submit", "admit", "take_row", "evict",
+    # a stream command's main thread (cli/wcstream.py, cli/grepstream.py):
+    # the root span of a job and its start (everything before the first
+    # step); inside a step's "dispatch", the call of the step program
+    # and its async copy starts; inside "merge", "finalize" or "sync", a
+    # compaction of the host accumulator (parallel/merge.py); inside
+    # "write", a partition's CPU work and its durable commit
+    "job", "start", "enqueue", "compact", "format", "commit",
 ))
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"

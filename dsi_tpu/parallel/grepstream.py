@@ -913,20 +913,25 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             with enable_x64(True):
                 chunks, meta = jax.device_put(
                     (buf, step_meta(lens_np, bases_np)), (sh2, sh2))
-        fn = _grep_fn((chunks, pat_dev, meta), n_dev=n_dev,
-                      chunk_bytes=chunk_bytes, m=m, bins=bins, k=topk,
-                      mesh=mesh, emit=emit)
-        with _quiet_unusable_donation():
-            outs = fn(chunks, pat_dev, meta)
-        if not emit:
-            outs += (None, None)  # (hist, cand, scal, comp, kept)
-        hist_d, cand_d, scal, _, kept_d = outs
-        # The results start for the host now, behind the step on the
-        # device's queue, not when ``finish_one`` asks for them one pump
-        # later: it then reads finished copies instead of paying one
-        # round trip per array.
-        for arr in host_reads(hist_d, cand_d, scal, kept_d):
-            _copy_to_host_async(arr)
+        # What dispatch costs beside its upload: the program's lookup and
+        # call, which returns before the device has run it, and the
+        # starts of the copies below.
+        with _span("enqueue", lane="dispatch", stats=stats,
+                   step=stats["steps"], program="grep_stream_step"):
+            fn = _grep_fn((chunks, pat_dev, meta), n_dev=n_dev,
+                          chunk_bytes=chunk_bytes, m=m, bins=bins, k=topk,
+                          mesh=mesh, emit=emit)
+            with _quiet_unusable_donation():
+                outs = fn(chunks, pat_dev, meta)
+            if not emit:
+                outs += (None, None)  # (hist, cand, scal, comp, kept)
+            hist_d, cand_d, scal, _, kept_d = outs
+            # The results start for the host now, behind the step on the
+            # device's queue, not when ``finish_one`` asks for them one
+            # pump later: it then reads finished copies instead of paying
+            # one round trip per array.
+            for arr in host_reads(hist_d, cand_d, scal, kept_d):
+                _copy_to_host_async(arr)
         return outs
 
     def dispatch(item):
@@ -1041,16 +1046,21 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
 
     def on_complete():
         h, t, cands = hist_h, totals, cand_h
-        if device_accumulate:
-            fault_point("pre-sync")
-            topk_svc.close()  # the exact final drain into the KeyCounts
-            final = hist_svc.close()
-            h = final[:bins]
-            t = final[bins:]
-            cands = [(line, occ) for line, occ in acc.finalize().items()]
-        if ck_writer is not None:
-            ck_writer.drain()  # surface async commit errors; counters
-            # settle before the caller reads them
+        if device_accumulate or ck_writer is not None:
+            with _span("drain", lane="sync", stats=stats, key="drain_s"):
+                if device_accumulate:
+                    fault_point("pre-sync")
+                    # the exact final drain into the KeyCounts
+                    topk_svc.close()
+                    final = hist_svc.close()
+                    h = final[:bins]
+                    t = final[bins:]
+                    cands = [(line, occ)
+                             for line, occ in acc.finalize().items()]
+                if ck_writer is not None:
+                    # surface async commit errors; counters settle
+                    # before the caller reads them
+                    ck_writer.drain()
         with _span("finalize", lane="host", stats=stats,
                    cands=len(cands)):
             step.result = GrepStreamResult(int(t[0]), int(t[1]), int(t[2]),
@@ -1074,7 +1084,8 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
                       "replay_s", "finalize_s", "fold_s", "sync_s",
                       "widen_s", "hist_s", "ckpt_s", "ckpt_capture_s",
                       "ckpt_commit_s", "ckpt_barrier_s",
-                      "ckpt_compress_s"):
+                      "ckpt_compress_s", "dispatch_s", "retire_s",
+                      "enqueue_s", "drain_s"):
                 if k in stats:
                     stats[k] = round(stats[k], 4)
             pipeline_stats.update(stats)

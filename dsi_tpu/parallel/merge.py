@@ -27,10 +27,11 @@ the host side can keep up with the device side at GB scale.
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from dsi_tpu.obs import span as _span
 from dsi_tpu.ops.wordcount import decode_packed
 
 
@@ -72,13 +73,25 @@ class PackedCounts:
     O(corpus).  ``finalize`` decodes spellings once and returns the same
     ``{word: (count, reduce_partition)}`` mapping the dict-based merge
     produced.
+
+    ``stats`` (an engine's scope, else a dict of the accumulator's own)
+    takes what the merge costs: ``merge_rows_in`` (rows handed to
+    ``add``), ``merge_rows_sorted`` (rows through the lexsort, summed
+    over compactions) and ``merge_compacts``, which repeat exactly for
+    one input, and the seconds of the ``compact`` and ``decode`` spans
+    (``compact_s``, ``finalize_decode_s``).
     """
 
-    def __init__(self, compact_rows: int = 1 << 21):
+    def __init__(self, compact_rows: int = 1 << 21,
+                 stats: Optional[dict] = None):
         self._bufs: List[Tuple[np.ndarray, np.ndarray, np.ndarray,
                                np.ndarray]] = []
         self._pending = 0
         self._compact_rows = max(1, compact_rows)
+        self.stats = {} if stats is None else stats
+        for key in ("merge_rows_in", "merge_rows_sorted",
+                    "merge_compacts"):
+            self.stats.setdefault(key, 0)
 
     def add(self, keys: np.ndarray, lens: np.ndarray, cnts: np.ndarray,
             parts: np.ndarray) -> None:
@@ -92,6 +105,7 @@ class PackedCounts:
             np.array(cnts, dtype=np.int64),
             np.array(parts, dtype=np.int32)))
         self._pending += len(keys)
+        self.stats["merge_rows_in"] += len(keys)
         if self._pending >= self._compact_rows:
             self._compact()
 
@@ -111,29 +125,38 @@ class PackedCounts:
     def _compact(self) -> None:
         if len(self._bufs) <= 1:
             return
-        k = max(b[0].shape[1] for b in self._bufs)
-        keys = np.concatenate([_pad_width(b[0], k) for b in self._bufs])
-        lens = np.concatenate([b[1] for b in self._bufs])
-        cnts = np.concatenate([b[2] for b in self._bufs])
-        parts = np.concatenate([b[3] for b in self._bufs])
-        order = _lexsort_rows(keys)
-        skeys = keys[order]
-        starts = _group_starts(skeys)
-        # len and partition are functions of the word, so first-of-run is
-        # exact; only counts need the segmented sum.
-        self._bufs = [(skeys[starts], lens[order][starts],
-                       np.add.reduceat(cnts[order], starts),
-                       parts[order][starts])]
-        self._pending = len(starts)
+        with _span("compact", lane="merge", stats=self.stats,
+                   rows_in=self._pending, bufs=len(self._bufs)) as sp:
+            k = max(b[0].shape[1] for b in self._bufs)
+            keys = np.concatenate([_pad_width(b[0], k)
+                                   for b in self._bufs])
+            lens = np.concatenate([b[1] for b in self._bufs])
+            cnts = np.concatenate([b[2] for b in self._bufs])
+            parts = np.concatenate([b[3] for b in self._bufs])
+            order = _lexsort_rows(keys)
+            skeys = keys[order]
+            starts = _group_starts(skeys)
+            # len and partition are functions of the word, so
+            # first-of-run is exact; only counts need the segmented sum.
+            self._bufs = [(skeys[starts], lens[order][starts],
+                           np.add.reduceat(cnts[order], starts),
+                           parts[order][starts])]
+            self.stats["merge_rows_sorted"] += len(keys)
+            self.stats["merge_compacts"] += 1
+            self._pending = len(starts)
+            sp.set(rows_out=self._pending)
 
     def finalize(self) -> Dict[str, Tuple[int, int]]:
         self._compact()
         if not self._bufs:
             return {}
         keys, lens, cnts, parts = self._bufs[0]
-        words = decode_packed(keys, lens, len(keys))
-        return {w: (int(c), int(p))
-                for w, c, p in zip(words, cnts.tolist(), parts.tolist())}
+        with _span("decode", lane="host", stats=self.stats,
+                   key="finalize_decode_s", keys=len(keys)):
+            words = decode_packed(keys, lens, len(keys))
+            return {w: (int(c), int(p))
+                    for w, c, p in zip(words, cnts.tolist(),
+                                       parts.tolist())}
 
     # ── checkpoint image (dsi_tpu/ckpt) ──
 

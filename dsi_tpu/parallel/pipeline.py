@@ -308,8 +308,10 @@ class StepPipeline:
     retires the oldest record — deferred flag check, replay, merge all
     live in the consumer.  ``stats`` receives ``produce_key`` (seconds
     building items — in the producer thread at depth > 1, inline at
-    depth 1), ``wait_key`` (consumer starvation on the queue) and
-    ``inflight_key`` (peak window occupancy, bounded by ``depth``).
+    depth 1), ``wait_key`` (consumer starvation on the queue),
+    ``inflight_key`` (peak window occupancy, bounded by ``depth``),
+    ``dispatch_s`` and ``retire_s`` (the ``dispatch`` and ``finish``
+    spans below: with the wait, the whole of the thread that pumps).
 
     Tracing (``dsi_tpu/obs``) is instrumented HERE once for all four
     engines: every produced item, dispatch, and finish is a span —
@@ -496,8 +498,8 @@ class StepPipeline:
         # straggler table in scripts/tracecat.py ranks and the
         # ``finish`` histogram the watchdog thresholds on.
         step, _ts = self._inflight[0]
-        with _span("finish", lane="dispatch", step=step,
-                   engine=self._engine) as sp:
+        with _span("finish", lane="dispatch", stats=self._stats,
+                   key="retire_s", step=step, engine=self._engine) as sp:
             self._finish(self._pending.popleft())
         self._inflight.popleft()
         self.finished += 1
@@ -514,7 +516,8 @@ class StepPipeline:
             item = next(self._feed_iter)
         except StopIteration:
             return False
-        with _span("dispatch", step=self._idx, engine=self._engine):
+        with _span("dispatch", stats=self._stats, key="dispatch_s",
+                   step=self._idx, engine=self._engine):
             rec = self._dispatch(item)
         self._idx += 1
         self.dispatched = self._idx

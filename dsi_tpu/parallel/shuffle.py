@@ -328,22 +328,36 @@ def wordcount_sharded(
 
 
 def write_partitioned_output(result: Dict[str, Tuple[int, int]],
-                             n_reduce: int, workdir: str = ".") -> List[str]:
+                             n_reduce: int, workdir: str = ".",
+                             stats: Optional[dict] = None) -> List[str]:
     """Materialise mr-out-<r> files from a sharded result — same file layout,
     line format ("%v %v\\n", mr/worker.go:144) and within-file key order the
-    reference's reduce tasks produce (worker.go:124-146)."""
+    reference's reduce tasks produce (worker.go:124-146).
+
+    The CPU work and the durable commits are timed apart: ``format`` spans
+    (``write_format_s`` of ``stats``: the bucketing, then each partition's
+    sort and line formatting) and ``commit`` spans (``write_commit_s``: a
+    partition's write, flush, fsync and rename)."""
     import os
 
+    from dsi_tpu.obs import span as _span
     from dsi_tpu.utils.atomicio import atomic_write
 
-    by_part: List[List[Tuple[str, int]]] = [[] for _ in range(n_reduce)]
-    for w, (c, r) in result.items():
-        by_part[r].append((w, c))
+    with _span("format", lane="host", stats=stats, key="write_format_s",
+               keys=len(result)):
+        by_part: List[List[Tuple[str, int]]] = [[] for _ in range(n_reduce)]
+        for w, (c, r) in result.items():
+            by_part[r].append((w, c))
     paths = []
     for r in range(n_reduce):
         path = os.path.join(workdir, f"mr-out-{r}")
-        with atomic_write(path) as f:
-            for w, c in sorted(by_part[r]):
-                f.write(f"{w} {c}\n")
+        with _span("format", lane="host", stats=stats,
+                   key="write_format_s", part=r, keys=len(by_part[r])):
+            text = "".join(f"{w} {c}\n" for w, c in sorted(by_part[r]))
+            by_part[r] = None  # the bucket's teardown belongs to it too
+        with _span("commit", lane="host", stats=stats,
+                   key="write_commit_s", part=r, bytes=len(text)):
+            with atomic_write(path) as f:
+                f.write(text)
         paths.append(path)
     return paths

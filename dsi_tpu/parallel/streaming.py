@@ -673,7 +673,6 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         mesh = default_mesh()
     n_dev = mesh.devices.size
     depth = pipeline_depth(depth)
-    acc = PackedCounts()
     groupers = grouper_ladder()
     # Sticky dispatch rung: starts where the sync ladder would, and only
     # ever moves toward more headroom (run_step_sync records the rung
@@ -696,6 +695,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                   "kernel_s": 0.0, "pull_s": 0.0, "device_wait_s": 0.0,
                   "d2h_s": 0.0, "merge_s": 0.0, "replay_s": 0.0,
                   "finalize_s": 0.0})
+    # The accumulator's compactions and counts land in the same scope.
+    acc = PackedCounts(stats=stats)
     # Compressed chunk uploads (ops/wirecodec.py): encode host-side,
     # ship the packed tensor, decode on device as a map prologue.  Off
     # by default = bit-identical raw uploads; on, a batch the codec
@@ -1067,22 +1068,29 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
             with _span("upload", stats=stats, key="upload_s",
                        step=stats["steps"]):
                 chunks = jax.device_put(buf, sharding)
-        keys, lens, cnts, parts, scal = step_call(
-            chunks, mwl, cap, state["frac"], state["grouper"])
-        if aot or device_accumulate:
-            # Only scal + the packed tensor stay referenced: the four
-            # result tables free as soon as the pack consumes them, so an
-            # in-flight step holds one packed copy, not five tables.
-            # Device accumulation packs eagerly even under jit — the fold
-            # consumes the packed layout, and its full-capacity shape is
-            # deterministic (no flags needed at dispatch time).
-            mp = keys.shape[1]
-            packed_dev = (_aot_pack(keys, lens, cnts, parts, mp=mp) if aot
-                          else _slice_pack(keys, lens, cnts, parts, mp=mp))
-            handles = (scal, packed_dev, keys.shape[2], None)
-        else:
-            handles = (scal, None, keys.shape[2],
-                       (keys, lens, cnts, parts))
+        # What dispatch costs beside its upload: the call of the step
+        # program (and of the eager pack), each of which returns before
+        # the device has run it.
+        with _span("enqueue", lane="dispatch", stats=stats,
+                   step=stats["steps"], program="mapreduce_step"):
+            keys, lens, cnts, parts, scal = step_call(
+                chunks, mwl, cap, state["frac"], state["grouper"])
+            if aot or device_accumulate:
+                # Only scal + the packed tensor stay referenced: the four
+                # result tables free as soon as the pack consumes them, so
+                # an in-flight step holds one packed copy, not five
+                # tables.  Device accumulation packs eagerly even under
+                # jit — the fold consumes the packed layout, and its
+                # full-capacity shape is deterministic (no flags needed at
+                # dispatch time).
+                mp = keys.shape[1]
+                packed_dev = (
+                    _aot_pack(keys, lens, cnts, parts, mp=mp) if aot
+                    else _slice_pack(keys, lens, cnts, parts, mp=mp))
+                handles = (scal, packed_dev, keys.shape[2], None)
+            else:
+                handles = (scal, None, keys.shape[2],
+                           (keys, lens, cnts, parts))
         stats["steps"] += 1
         rec_offset = 0
         if offsets is not None:
@@ -1200,12 +1208,15 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         # End-of-stream epilogue, exactly the monolithic function's
         # success path: final device drain, async-commit errors
         # surfaced, then the result.
-        if table_svc is not None:
-            fault_point("pre-sync")
-            table_svc.close()  # the "or at stream end" pull
-        if ck_writer is not None:
-            ck_writer.drain()  # surface async commit errors; counters
-            # settle before the caller reads them
+        if table_svc is not None or ck_writer is not None:
+            with _span("drain", lane="sync", stats=stats, key="drain_s"):
+                if table_svc is not None:
+                    fault_point("pre-sync")
+                    table_svc.close()  # the "or at stream end" pull
+                if ck_writer is not None:
+                    # surface async commit errors; counters settle
+                    # before the caller reads them
+                    ck_writer.drain()
         with _span("finalize", lane="host", stats=stats) as sp:
             step.result = acc.finalize()
             sp.set(keys=len(step.result))
@@ -1226,7 +1237,9 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                       "replay_s", "finalize_s", "fold_s", "sync_s",
                       "widen_s", "ckpt_s", "ckpt_capture_s",
                       "ckpt_commit_s", "ckpt_barrier_s", "decode_s",
-                      "ckpt_compress_s"):
+                      "ckpt_compress_s", "dispatch_s", "retire_s",
+                      "enqueue_s", "drain_s", "compact_s",
+                      "finalize_decode_s"):
                 if k in stats:
                     stats[k] = round(stats[k], 4)
             pipeline_stats.update(stats)

@@ -14,8 +14,10 @@ Sections:
 * header      — event counts, wall span, dropped events, counters, and
                 the metrics-registry snapshot (per-engine unified phase
                 dicts) embedded at flush time;
-* flame       — per span-name totals (total seconds, count, mean, max)
-                with text bars, sorted by total: WHERE the wall went;
+* flame       — per span-name totals (total seconds, self seconds =
+                total less the direct children, count, mean, max) with
+                text bars, sorted by total, and under each name what
+                its direct children sum to: WHERE the wall went;
 * top steps   — the N slowest per-step ``finish`` spans (the pipeline
                 core's per-step retire wall: deferred flag wait + merge
                 or replay), with engine and step ordinal;
@@ -129,27 +131,48 @@ def _bar(frac: float, width: int = 28) -> str:
 
 
 def flame(events, out) -> None:
-    rows = {}
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        r = rows.setdefault((e.get("lane", "?"), e["name"]),
-                            [0.0, 0, 0.0])
-        r[0] += e.get("dur", 0.0)
+    """Per span name: total, self time (the total less the direct
+    children's, so a span with nothing inside it reads its whole total)
+    and, indented under it, what its direct children sum to."""
+    spans = [e for e in events if e.get("ph") == "X"]
+    by_id = {(e.get("_file"), e["id"]): e for e in spans
+             if e.get("id") is not None}
+    rows, kids = {}, {}
+    for e in spans:
+        key = (e.get("lane", "?"), e["name"])
+        dur = e.get("dur", 0.0)
+        r = rows.setdefault(key, [0.0, 0, 0.0])
+        r[0] += dur
         r[1] += 1
-        r[2] = max(r[2], e.get("dur", 0.0))
+        r[2] = max(r[2], dur)
+        up = by_id.get((e.get("_file"), e.get("parent")))
+        if up is not None:
+            k = kids.setdefault((up.get("lane", "?"), up["name"]),
+                                {}).setdefault(key, [0.0, 0])
+            k[0] += dur
+            k[1] += 1
     if not rows:
         print("  (no spans)", file=out)
         return
+
+    def label(key) -> str:
+        lane, name = key
+        return f"{lane}/{name}" if lane != name else name
+
     top = max(r[0] for r in rows.values()) or 1.0
-    print(f"  {'lane/span':<24} {'total_s':>9} {'count':>7} "
+    print(f"  {'lane/span':<24} {'total_s':>9} {'self_s':>9} {'count':>7} "
           f"{'mean_ms':>9} {'max_ms':>9}", file=out)
-    for (lane, name), (tot, cnt, mx) in sorted(
-            rows.items(), key=lambda kv: -kv[1][0]):
-        label = f"{lane}/{name}" if lane != name else name
-        print(f"  {label:<24} {tot:>9.3f} {cnt:>7} "
+    for key, (tot, cnt, mx) in sorted(rows.items(),
+                                      key=lambda kv: -kv[1][0]):
+        inside = kids.get(key, {})
+        self_s = tot - sum(k[0] for k in inside.values())
+        print(f"  {label(key):<24} {tot:>9.3f} {self_s:>9.3f} {cnt:>7} "
               f"{1e3 * tot / cnt:>9.2f} {1e3 * mx:>9.2f}  "
               f"{_bar(tot / top)}", file=out)
+        for kkey, (ktot, kcnt) in sorted(inside.items(),
+                                         key=lambda kv: -kv[1][0]):
+            print(f"    > {label(kkey):<20} {ktot:>9.3f} {'':>9} "
+                  f"{kcnt:>7}", file=out)
 
 
 def _finish_spans(events):

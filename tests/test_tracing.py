@@ -59,6 +59,40 @@ def tracing_off(monkeypatch):
     monkeypatch.setattr(obs_hist, "_active", None)
 
 
+@pytest.fixture
+def fresh_tracer(monkeypatch):
+    """A disabled tracer in the process-global slot, for a command that
+    may turn it on with ``--trace-dir``; off again afterwards."""
+    monkeypatch.delenv("DSI_TRACE_DIR", raising=False)
+    tracer = Tracer(enabled=False)
+    monkeypatch.setattr(obs_trace, "_global", tracer)
+    yield tracer
+    tracer.enabled = False
+
+
+def _stream_main(command, tmp_path, *flags):
+    """One small job of ``wcstream`` or ``grepstream`` in this process:
+    ``(exit code, its pipeline_stats, its work directory)``."""
+    pytest.importorskip("jax")
+    import importlib
+
+    main = importlib.import_module(f"dsi_tpu.cli.{command}").main
+    (src,) = ensure_corpus(str(tmp_path / "inputs"), n_files=1,
+                           file_size=120_000)
+    out = str(tmp_path / f"out-{len(os.listdir(tmp_path))}")
+    argv = ["--devices", "2", "--chunk-bytes", "16384", "--stats",
+            "--workdir", out, *flags, src]
+    argv = (["--pattern", "the"] if command == "grepstream"
+            else ["--nreduce", "3"]) + argv
+    err = io.StringIO()
+    with contextlib.redirect_stderr(err), \
+            contextlib.redirect_stdout(io.StringIO()):
+        rc = main(argv)
+    m = re.search(rf"^{command}: pipeline_stats=(\{{.*\}})$",
+                  err.getvalue(), re.M)
+    return rc, ast.literal_eval(m.group(1)), out
+
+
 # ── the four tests of the former utils/tracing layer, against obs ──────
 
 
@@ -186,15 +220,30 @@ def test_ids_parents_and_task_inheritance_across_a_thread(tmp_path):
         assert by[name]["parent"] is None and "task" not in by[name]
 
 
-def test_spans_ride_the_profilers_clock(tmp_path):
+def _tracer_under_a_task(tmp_path):
     jax = pytest.importorskip("jax")
     t = Tracer(enabled=True, trace_dir=str(tmp_path / "t"))
+    with t.span("worker.map", lane="control", kind="map", task=0):
+        with t.span("kernel"):
+            jax.block_until_ready(jax.numpy.arange(8) + 1)
+    t.flush()
+
+
+def _small_wcstream_job(tmp_path):
+    assert _stream_main("wcstream", tmp_path, "--trace-dir",
+                        str(tmp_path / "t"))[0] == 0
+
+
+@pytest.mark.parametrize("traced, want", [
+    (_tracer_under_a_task, {"dsi:worker.map", "dsi:kernel"}),
+    (_small_wcstream_job, {"dsi:job", "dsi:enqueue", "dsi:compact"}),
+], ids=["a-task", "a-wcstream-job"])
+def test_spans_ride_the_profilers_clock(tmp_path, fresh_tracer, traced,
+                                        want):
+    jax = pytest.importorskip("jax")
     jax.profiler.start_trace(str(tmp_path / "prof"))
     try:
-        with t.span("worker.map", lane="control", kind="map", task=0):
-            with t.span("kernel"):
-                jax.block_until_ready(jax.numpy.arange(8) + 1)
-        t.flush()
+        traced(tmp_path)
     finally:
         jax.profiler.stop_trace()
     (pb,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
@@ -206,7 +255,7 @@ def test_spans_ride_the_profilers_clock(tmp_path):
             for ev in line.events:
                 if ev.name.startswith(("dsi:", "dsi.clock")):
                     seen.setdefault(ev.name.split("#")[0], plane.name)
-    assert {"dsi:worker.map", "dsi:kernel", "dsi.clock"} <= set(seen), seen
+    assert want | {"dsi.clock"} <= set(seen), seen
     assert all(p.startswith("/host:") for p in seen.values()), seen
 
 
@@ -337,3 +386,167 @@ def test_untraced_map_task_builds_no_span_without_a_sink(tracing_off,
     assert sorted(os.listdir(tmp_path))[-3:] == ["mr-0-0", "mr-0-1",
                                                  "mr-0-2"]
     assert [name for name, sink in built if not sink] == []
+
+
+# ── a stream job's main thread, accounted for ──────────────────────────
+
+
+@pytest.mark.parametrize("command, flags", [
+    ("wcstream", ()), ("grepstream", ()),
+    # no batcher thread: the batches are cut on the main thread too
+    ("wcstream", ("--pipeline-depth", "1"))],
+    ids=["wcstream", "grepstream", "wcstream-depth1"])
+def test_stream_stats_account_for_the_main_thread(tracing_off, tmp_path,
+                                                  command, flags):
+    rc, ps, _ = _stream_main(command, tmp_path, *flags)
+    assert rc == 0 and ps["depth"] == (1 if flags else 2)
+    for key in ("job_s", "job_children_s", "start_s", "dispatch_s",
+                "retire_s", "enqueue_s"):
+        assert ps[key] > 0, key
+    assert 0.95 * ps["job_s"] <= ps["job_children_s"] <= ps["job_s"] + 1e-3
+    # the step program's call is inside the dispatch, the upload beside it
+    assert ps["enqueue_s"] + ps["upload_s"] <= ps["dispatch_s"] + 2e-4
+
+
+def _children(events, parent):
+    return [e for e in events if e["ph"] == "X"
+            and e["parent"] == parent["id"]]
+
+
+@pytest.mark.parametrize("command, flags", [
+    ("wcstream", ()), ("wcstream", ("--device-accumulate",)),
+    ("grepstream", ())], ids=["wcstream", "wcstream-accumulate",
+                              "grepstream"])
+def test_job_children_are_the_registrys_tuple(fresh_tracer, tmp_path,
+                                              monkeypatch, command, flags):
+    from dsi_tpu.obs.registry import JOB_CHILDREN
+    from dsi_tpu.parallel.merge import PackedCounts
+
+    # compactions inside the steps' merges too, not only the last one
+    monkeypatch.setattr(PackedCounts.__init__, "__defaults__", (512, None))
+    rc, ps, _ = _stream_main(command, tmp_path, "--trace-dir",
+                             str(tmp_path / "trace"), *flags)
+    assert rc == 0
+    _, events = _jsonl(str(tmp_path / "trace" / "trace.jsonl"))
+    spans = {e["id"]: e for e in events if e["ph"] == "X"}
+    (job,) = [e for e in spans.values() if e["name"] == "job"]
+    assert job["parent"] is None and job["depth"] == 0
+    kids = _children(events, job)
+    key_of = dict(JOB_CHILDREN)
+    want = {"start", "wait", "dispatch", "finish", "finalize", "write"}
+    if flags:
+        want.add("drain")
+    assert {e["name"] for e in kids} == want <= set(key_of)
+    # the keys are the spans: what the stats line subtracts is what the
+    # trace holds under the root
+    for name in want:
+        total = sum(e["dur"] for e in kids if e["name"] == name)
+        assert ps[key_of[name]] == pytest.approx(total, abs=5e-4), name
+    assert ps["job_s"] == pytest.approx(job["dur"], abs=1e-4)
+    assert ps["job_s"] - ps["job_children_s"] == pytest.approx(
+        job["dur"] - sum(e["dur"] for e in kids), abs=2e-3)
+    # the parts of a step, and of the tail, where the work happens
+    parents = {}
+    for e in spans.values():
+        up = spans.get(e["parent"])
+        parents.setdefault(e["name"], set()).add(up and up["name"])
+    assert parents["upload"] == parents["enqueue"] == {"dispatch"}
+    inside_finish = ("kernel",) if flags else ("kernel", "pull", "merge")
+    for name in inside_finish:
+        assert parents[name] == {"finish"}, name
+    assert parents.get("replay", {"finish"}) == {"finish"}
+    if command == "wcstream":
+        # a compaction belongs to whichever span handed the rows over (a
+        # replayed step merges inside its ``replay``), or to the last one
+        where = {"sync"} if flags else {"merge", "replay"}
+        assert parents["compact"] & where
+        assert parents["compact"] <= where | {"finalize"}
+        assert parents["decode"] == {"finalize"}
+        assert parents["format"] == parents["commit"] == {"write"}
+        if flags:
+            assert "drain" in parents["sync"]
+
+
+def test_merge_counters_equal_a_hand_count():
+    np = pytest.importorskip("numpy")
+    from dsi_tpu.parallel.merge import PackedCounts
+
+    def rows(*words):
+        # word i is "w" and three digits, packed big-endian in one lane
+        keys = np.array([[int.from_bytes(b"w%03d" % w, "big")]
+                         for w in words], dtype=np.uint32).reshape(-1, 1)
+        ones = np.ones(len(words), dtype=np.int32)
+        return keys, 4 * ones, ones.astype(np.int64), ones
+
+    stats = {}
+    acc = PackedCounts(compact_rows=16, stats=stats)
+    acc.add(*rows(*range(0, 5)))      # 5 pending
+    acc.add(*rows(*range(3, 8)))      # 10
+    acc.add(*rows(*range(6, 12)))     # 16: sorted, 12 distinct left
+    acc.add(*rows(0, 1, 20))          # 15
+    acc.add(*rows())                  # nothing handed over
+    got = acc.finalize()              # 15 sorted once more
+    assert len(got) == 13 and got["w007"] == (2, 1) and got["w020"] == (1, 1)
+    counts = {k: stats[k] for k in ("merge_rows_in", "merge_rows_sorted",
+                                    "merge_compacts")}
+    assert counts == {"merge_rows_in": 19, "merge_rows_sorted": 31,
+                      "merge_compacts": 2}
+    assert stats["compact_s"] > 0 and stats["finalize_decode_s"] > 0
+    # one table and nothing new: finalize has nothing to sort again
+    assert len(acc.finalize()) == 13 and stats["merge_compacts"] == 2
+    # an accumulator without an engine keeps its own
+    assert PackedCounts().stats["merge_rows_in"] == 0
+
+
+def test_wcstream_merge_counters_repeat_and_the_output_is_the_oracles(
+        tracing_off, tmp_path, monkeypatch):
+    from dsi_tpu.mr.worker import ihash
+    from dsi_tpu.parallel.merge import PackedCounts
+
+    monkeypatch.setattr(PackedCounts.__init__, "__defaults__", (512, None))
+    runs = [_stream_main("wcstream", tmp_path) for _ in range(2)]
+    assert [rc for rc, _, _ in runs] == [0, 0]
+    counters = ("merge_rows_in", "merge_rows_sorted", "merge_compacts")
+    first, second = ({k: ps[k] for k in counters} for _, ps, _ in runs)
+    assert first == second
+    ps = runs[0][1]
+    # every confirmed row of every step is handed to the accumulator
+    assert first["merge_rows_in"] == sum(ps["device_rows"])
+    assert first["merge_compacts"] > 1
+    assert first["merge_rows_sorted"] > first["merge_rows_in"]
+    # what is written has not changed: each partition holds its words in
+    # order, one "word count" line each, as the parent commit wrote them
+    (src,) = glob.glob(str(tmp_path / "inputs" / "*"))
+    with open(src, encoding="ascii") as f:
+        counts = {}
+        for w in re.findall(r"[A-Za-z]+", f.read()):
+            counts[w] = counts.get(w, 0) + 1
+    for _, _, out in runs:
+        assert sorted(os.listdir(out)) == ["mr-out-0", "mr-out-1",
+                                           "mr-out-2"]
+        for r in range(3):
+            want = "".join(f"{w} {c}\n" for w, c in sorted(counts.items())
+                           if ihash(w) % 3 == r)
+            with open(os.path.join(out, f"mr-out-{r}"), "rb") as f:
+                assert f.read() == want.encode(), r
+
+
+@pytest.mark.parametrize("command", ["wcstream", "grepstream"])
+def test_untraced_stream_step_builds_no_span_without_a_sink(
+        tracing_off, tmp_path, monkeypatch, command):
+    built = []
+    init = obs_trace._Span.__init__
+
+    def spy(self, tr, name, lane, stats, *rest):
+        built.append((name, stats is not None))
+        init(self, tr, name, lane, stats, *rest)
+
+    monkeypatch.setattr(obs_trace._Span, "__init__", spy)
+    rc, ps, _ = _stream_main(command, tmp_path)
+    assert rc == 0
+    assert [name for name, sink in built if not sink] == []
+    # pump's spans are the keys' accumulators now, one each a step
+    names = [name for name, _ in built]
+    for name in ("dispatch", "finish", "enqueue"):
+        assert names.count(name) == ps["steps"], name
+    assert names.count("job") == names.count("start") == 1
