@@ -27,18 +27,25 @@ that phase):
   ``d2h_s`` (the device-to-host copy itself)
 * ``merge_s``            — host-side accumulation of pulled results
 * ``finalize_s``         — the final merge of the accumulator into the
-  result (compaction, decode of every distinct word)
-* ``finalize_decode_s``  — inside it, the ``decode`` span: the spellings
-  of the merged table decoded and the result dict built
+  result: the last compaction; the merged table is the result
+  (``merge.PackedWordCounts``)
+* ``finalize_decode_s``  — the ``decode`` span: the spellings of the
+  merged table decoded and the result dict built, on the first keyed
+  access, iteration or comparison of the result (0.0 in a ``wcstream``
+  job, whose writer reads the arrays); ``finalize_decoded_keys`` counts
+  the spellings
 * ``compact_s``          — the ``compact`` spans of the host accumulator
   (``parallel/merge.py``): every buffered row sorted and merged again;
   inside ``merge_s``, ``finalize_s`` or ``sync_s``, whichever called it
 * ``write_s``            — writing the partitioned ``mr-out-*`` (the
   CLI's phase, not the engine's)
 * ``write_format_s`` / ``write_commit_s`` — inside it
-  (``shuffle.write_partitioned_output``): the ``format`` spans (the
-  bucketing, a partition's sort and line formatting) and the ``commit``
-  spans (its write, flush, fsync and rename)
+  (``shuffle.write_partitioned_output``): the ``format`` spans (a
+  partition's bytes rendered from the merged table's arrays; for a dict,
+  the bucketing, a partition's sort and line formatting) and the
+  ``commit`` spans (its write, flush, fsync and rename);
+  ``write_rows_packed`` / ``write_rows_dict`` count the rows written
+  either way
 * ``job_s``              — a stream command's root ``job`` span, from
   its parsed arguments to the ``--stats`` line; its direct children on
   the main thread are :data:`JOB_CHILDREN`, and ``job_children_s`` is
@@ -285,6 +292,10 @@ COUNTER_KEYS = (
     "device_rows",
     # the host accumulator (parallel/merge.py PackedCounts)
     "merge_rows_in", "merge_rows_sorted", "merge_compacts",
+    # its result and the partition writer: spellings turned into ``str``
+    # (0 in a wcstream job), rows rendered from the merged table's
+    # arrays, rows formatted from a dict (the host fallback's)
+    "finalize_decoded_keys", "write_rows_packed", "write_rows_dict",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

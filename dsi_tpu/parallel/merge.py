@@ -12,8 +12,11 @@ This module replaces the per-row loops with numpy table algebra:
   buffer so no device-shaped block stays alive),
 * merging is one ``np.lexsort`` over the key lanes + run-boundary detection
   + ``np.add.reduceat`` per compaction window — O(rows log rows) in C,
-* word spellings are decoded ONCE, from the final merged table
-  (vocabulary-sized), via the same bulk ``decode_packed`` the kernels use.
+* the final merged table IS the result (``PackedWordCounts``): the
+  partition writer renders ``mr-out-*`` from its arrays, and word
+  spellings are decoded (ONCE, vocabulary-sized, via the same bulk
+  ``decode_packed`` the kernels use) only for a caller that asks for a
+  word by key, iterates or compares.
 
 Zero-padded key lanes make width harmonisation trivial: a word packed into
 K lanes and the same word packed into K' > K lanes agree on the first K
@@ -27,12 +30,17 @@ the host side can keep up with the device side at GB scale.
 
 from __future__ import annotations
 
+from collections.abc import Mapping
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from dsi_tpu.obs import span as _span
 from dsi_tpu.ops.wordcount import decode_packed
+
+
+#: 10^1 .. 10^18, every power of ten an int64 count can reach.
+_POW10 = 10 ** np.arange(1, 19, dtype=np.int64)
 
 
 def _pad_width(keys: np.ndarray, k: int) -> np.ndarray:
@@ -63,6 +71,18 @@ def _lexsort_rows(keys: np.ndarray) -> np.ndarray:
                                                       -1, -1)))
 
 
+def _rows_increase(keys: np.ndarray) -> bool:
+    """Whether a [n, k] table's rows strictly increase lexicographically
+    (lane 0 primary): sorted and distinct, decided at each row's first
+    lane that differs from the row before."""
+    if len(keys) < 2:
+        return True
+    prev, nxt = keys[:-1], keys[1:]
+    first = (prev != nxt).argmax(axis=1)
+    rows = np.arange(len(prev))
+    return bool((nxt[rows, first] > prev[rows, first]).all())
+
+
 class PackedCounts:
     """Word-count accumulator over packed-key row batches.
 
@@ -70,16 +90,19 @@ class PackedCounts:
     lengths, counts, reduce partitions); batches are compacted into one
     merged table whenever the buffered row count crosses
     ``compact_rows`` — so host memory is O(vocabulary + window), never
-    O(corpus).  ``finalize`` decodes spellings once and returns the same
-    ``{word: (count, reduce_partition)}`` mapping the dict-based merge
-    produced.
+    O(corpus).  ``finalize`` returns the merged table as a
+    :class:`PackedWordCounts`, which equals the ``{word: (count,
+    reduce_partition)}`` dict the dict-based merge produced and builds
+    it only when a caller needs Python objects.
 
     ``stats`` (an engine's scope, else a dict of the accumulator's own)
     takes what the merge costs: ``merge_rows_in`` (rows handed to
     ``add``), ``merge_rows_sorted`` (rows through the lexsort, summed
     over compactions) and ``merge_compacts``, which repeat exactly for
-    one input, and the seconds of the ``compact`` and ``decode`` spans
-    (``compact_s``, ``finalize_decode_s``).
+    one input, the seconds of the ``compact`` and ``decode`` spans
+    (``compact_s``; ``finalize_decode_s``, 0.0 until the result is
+    decoded) and ``finalize_decoded_keys`` (spellings turned into
+    ``str``).
     """
 
     def __init__(self, compact_rows: int = 1 << 21,
@@ -146,17 +169,21 @@ class PackedCounts:
             self._pending = len(starts)
             sp.set(rows_out=self._pending)
 
-    def finalize(self) -> Dict[str, Tuple[int, int]]:
+    def finalize(self) -> "PackedWordCounts":
+        """The merged table as the job's result: no spelling is decoded
+        and no Python object per word is built here."""
         self._compact()
         if not self._bufs:
-            return {}
+            return PackedWordCounts(stats=self.stats)
         keys, lens, cnts, parts = self._bufs[0]
-        with _span("decode", lane="host", stats=self.stats,
-                   key="finalize_decode_s", keys=len(keys)):
-            words = decode_packed(keys, lens, len(keys))
-            return {w: (int(c), int(p))
-                    for w, c, p in zip(words, cnts.tolist(),
-                                       parts.tolist())}
+        if not _rows_increase(keys):
+            # One buffer that no compaction ever sorted (a one-device,
+            # one-step job; a restored image of one) is in the device's
+            # order.  Its rows are distinct, so ordering them is all.
+            order = _lexsort_rows(keys)
+            keys, lens, cnts, parts = (keys[order], lens[order],
+                                       cnts[order], parts[order])
+        return PackedWordCounts(keys, lens, cnts, parts, stats=self.stats)
 
     # ── checkpoint image (dsi_tpu/ckpt) ──
 
@@ -183,6 +210,122 @@ class PackedCounts:
                        np.array(arrays["cnts"], dtype=np.int64),
                        np.array(arrays["parts"], dtype=np.int32))]
         self._pending = len(self._bufs[0][0])
+
+
+class PackedWordCounts(Mapping):
+    """A word count's result: the merged table itself, as a read-only
+    ``{word: (count, reduce_partition)}`` mapping.
+
+    ``skeys`` ([n, K] uint32, rows strictly increasing: big-endian
+    zero-padded lanes, so lane order is byte order is ``str`` order for
+    the ASCII letters a word holds), ``lens`` (bytes a word), ``cnts``
+    (int64) and ``parts`` are per word.  ``len()`` and
+    :meth:`render_partition` (what ``shuffle.write_partitioned_output``
+    commits) read the arrays alone.  The first keyed access, iteration
+    or comparison decodes every spelling once (``decode_packed``) into
+    the dict the merge used to return, and keeps it: the ``decode`` span
+    (``finalize_decode_s`` of ``stats``, the accumulator's scope) and
+    ``finalize_decoded_keys`` say when that happened and for how many
+    words; a ``wcstream`` job reads 0.0 and 0.
+    """
+
+    def __init__(self, skeys: Optional[np.ndarray] = None,
+                 lens: Optional[np.ndarray] = None,
+                 cnts: Optional[np.ndarray] = None,
+                 parts: Optional[np.ndarray] = None,
+                 stats: Optional[dict] = None):
+        if skeys is None:
+            skeys = np.zeros((0, 1), np.uint32)
+            lens = parts = np.zeros(0, np.int32)
+            cnts = np.zeros(0, np.int64)
+        self.skeys, self.lens, self.cnts, self.parts = (skeys, lens, cnts,
+                                                        parts)
+        self.stats = {} if stats is None else stats
+        self.stats.setdefault("finalize_decode_s", 0.0)
+        self.stats.setdefault("finalize_decoded_keys", 0)
+        self._dict: Optional[Dict[str, Tuple[int, int]]] = None
+        self._bytes: Optional[np.ndarray] = None
+
+    def __len__(self) -> int:
+        return len(self.skeys)
+
+    def __repr__(self) -> str:
+        return (f"PackedWordCounts(words={len(self)}, "
+                f"lanes={self.skeys.shape[1]}, "
+                f"decoded={self._dict is not None})")
+
+    def to_dict(self) -> Dict[str, Tuple[int, int]]:
+        """The whole table as Python objects, built once and kept."""
+        if self._dict is None:
+            n = len(self.skeys)
+            with _span("decode", lane="host", stats=self.stats,
+                       key="finalize_decode_s", keys=n):
+                words = decode_packed(self.skeys, self.lens, n)
+                self._dict = {w: (c, p) for w, c, p
+                              in zip(words, self.cnts.tolist(),
+                                     self.parts.tolist())}
+            self.stats["finalize_decoded_keys"] += n
+        return self._dict
+
+    def __getitem__(self, word: str) -> Tuple[int, int]:
+        return self.to_dict()[word]
+
+    def __iter__(self):
+        return iter(self.to_dict())
+
+    def __contains__(self, word) -> bool:
+        return word in self.to_dict()
+
+    def keys(self):
+        return self.to_dict().keys()
+
+    def items(self):
+        return self.to_dict().items()
+
+    def values(self):
+        return self.to_dict().values()
+
+    def __eq__(self, other) -> bool:
+        if isinstance(other, PackedWordCounts):
+            other = other.to_dict()
+        elif not isinstance(other, Mapping):
+            return NotImplemented
+        return self.to_dict() == other
+
+    def render_partition(self, r: int) -> bytes:
+        """Partition ``r``'s ``mr-out`` bytes, ``"word count\\n"`` a row
+        in table order (the order ``sorted`` gives the spellings), from
+        the arrays: one [rows, longest word + 1 + most digits + 1] byte
+        matrix and a keep-mask, a few MB a partition."""
+        rows = np.flatnonzero(self.parts == r)
+        if len(rows) == 0:
+            return b""
+        if self._bytes is None:
+            # [n, 4K] uint8: big-endian lanes are the spelling's bytes
+            self._bytes = np.ascontiguousarray(
+                self.skeys.astype(">u4")).view(np.uint8)
+        lens, cnts = self.lens[rows], self.cnts[rows]
+        w = int(lens.max())
+        # decimal digits a count by integer comparisons: int64 counts
+        # past 2^53 must print exactly, so no float logarithm
+        digits = np.searchsorted(_POW10, cnts, side="right") + 1
+        d = int(digits.max())
+        width = w + d + 2
+        mat = np.empty((len(rows), width), np.uint8)
+        mat[:, :w] = np.take(self._bytes, rows, axis=0)[:, :w]
+        mat[:, w] = 0x20
+        for col in range(w + d, w, -1):  # right-aligned, least first
+            cnts, digit = np.divmod(cnts, 10)
+            mat[:, col] = digit + 0x30
+        mat[:, w + d + 1] = 0x0A
+        # what a row keeps follows its (length, digits) alone: one mask
+        # a class, gathered a row
+        col = np.arange(width)
+        masks = ((col < np.arange(w + 1)[:, None, None])
+                 | (col >= w + 1 + d - np.arange(d + 1)[None, :, None])
+                 | (col == w)).reshape(-1, width)
+        keep = np.take(masks, lens * (d + 1) + digits, axis=0)
+        return mat[keep].tobytes()
 
 
 class PostingsTable:

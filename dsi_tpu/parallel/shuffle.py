@@ -32,7 +32,7 @@ exact (same discipline as ``ops/wordcount.py``).
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -327,37 +327,69 @@ def wordcount_sharded(
     return None if payload is None else payload()
 
 
-def write_partitioned_output(result: Dict[str, Tuple[int, int]],
+def write_partitioned_output(result: Mapping[str, Tuple[int, int]],
                              n_reduce: int, workdir: str = ".",
                              stats: Optional[dict] = None) -> List[str]:
     """Materialise mr-out-<r> files from a sharded result — same file layout,
     line format ("%v %v\\n", mr/worker.go:144) and within-file key order the
     reference's reduce tasks produce (worker.go:124-146).
 
+    One algorithm over two representations, chosen by what it is handed:
+    a merged table (``merge.PackedWordCounts``, what a stream's
+    accumulator returns) renders each partition's bytes from its arrays
+    (``write_rows_packed`` of ``stats`` counts the rows), with no Python
+    object a word; a dict (the host fallback's) is bucketed, sorted and
+    formatted a line at a time (``write_rows_dict``).  The bytes and the
+    commits are the same.
+
     The CPU work and the durable commits are timed apart: ``format`` spans
-    (``write_format_s`` of ``stats``: the bucketing, then each partition's
-    sort and line formatting) and ``commit`` spans (``write_commit_s``: a
-    partition's write, flush, fsync and rename)."""
+    (``write_format_s`` of ``stats``: a partition's rendering; for a dict
+    first the bucketing, then each partition's sort and line formatting)
+    and ``commit`` spans (``write_commit_s``: a partition's write, flush,
+    fsync and rename)."""
     import os
 
     from dsi_tpu.obs import span as _span
+    from dsi_tpu.parallel.merge import PackedWordCounts
     from dsi_tpu.utils.atomicio import atomic_write
 
-    with _span("format", lane="host", stats=stats, key="write_format_s",
-               keys=len(result)):
-        by_part: List[List[Tuple[str, int]]] = [[] for _ in range(n_reduce)]
-        for w, (c, r) in result.items():
-            by_part[r].append((w, c))
+    packed = isinstance(result, PackedWordCounts)
+    if packed:
+        # the dict's bucketing raises on such a row; a mask would skip it
+        outside = np.count_nonzero((result.parts < 0)
+                                   | (result.parts >= n_reduce))
+        if outside:
+            raise ValueError(f"{outside} of {len(result)} words lie "
+                             f"outside the {n_reduce} partitions")
+    else:
+        with _span("format", lane="host", stats=stats,
+                   key="write_format_s", keys=len(result)):
+            by_part: List[List[Tuple[str, int]]] = [
+                [] for _ in range(n_reduce)]
+            for w, (c, r) in result.items():
+                by_part[r].append((w, c))
     paths = []
     for r in range(n_reduce):
         path = os.path.join(workdir, f"mr-out-{r}")
         with _span("format", lane="host", stats=stats,
-                   key="write_format_s", part=r, keys=len(by_part[r])):
-            text = "".join(f"{w} {c}\n" for w, c in sorted(by_part[r]))
-            by_part[r] = None  # the bucket's teardown belongs to it too
+                   key="write_format_s", part=r) as sp:
+            if packed:
+                data = result.render_partition(r)
+                keys = data.count(b"\n")
+            else:
+                keys = len(by_part[r])
+                data = "".join(f"{w} {c}\n" for w, c
+                               in sorted(by_part[r])).encode("utf-8")
+                by_part[r] = None  # the bucket's teardown belongs to it too
+            sp.set(keys=keys)
         with _span("commit", lane="host", stats=stats,
-                   key="write_commit_s", part=r, bytes=len(text)):
-            with atomic_write(path) as f:
-                f.write(text)
+                   key="write_commit_s", part=r, bytes=len(data)):
+            with atomic_write(path, "wb") as f:
+                f.write(data)
         paths.append(path)
+    if stats is not None:
+        for key in ("write_rows_packed", "write_rows_dict"):
+            stats.setdefault(key, 0)
+        stats["write_rows_packed" if packed
+              else "write_rows_dict"] += len(result)
     return paths

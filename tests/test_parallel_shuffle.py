@@ -120,3 +120,30 @@ def test_write_partitioned_output(tmp_path):
                 w, c = line.split()
                 merged[w] = int(c)
     assert merged == dict(truth(data))
+
+
+def test_sharded_result_is_the_table_and_writes_what_its_dict_writes(
+        tmp_path):
+    """``wordcount_sharded`` hands back the merged table: equal to the
+    oracle's dict, and written from its arrays to the bytes the dict
+    formatting gives."""
+    from dsi_tpu.parallel.merge import PackedWordCounts
+
+    data = make_text(6000, seed=11)
+    res = wordcount_sharded(data, mesh=default_mesh(8), u_cap=256)
+    assert isinstance(res, PackedWordCounts)
+    want = {w: (c, ihash(w) % 10) for w, c in truth(data).items()}
+    assert len(res) == len(want)
+    stats: dict = {}
+    packed = write_partitioned_output(res, 10, str(tmp_path), stats=stats)
+    assert stats == {**stats, "write_rows_packed": len(want),
+                     "write_rows_dict": 0}
+    assert res.stats["finalize_decoded_keys"] == 0
+    plain_dir = tmp_path / "dict"
+    plain_dir.mkdir()
+    plain = write_partitioned_output(want, 10, str(plain_dir), stats=stats)
+    assert stats["write_rows_dict"] == len(want)
+    for a, b in zip(packed, plain):
+        with open(a, "rb") as fa, open(b, "rb") as fb:
+            assert fa.read() == fb.read(), a
+    assert res == want and res.stats["finalize_decoded_keys"] == len(want)

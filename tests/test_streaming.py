@@ -315,3 +315,44 @@ def test_streaming_aot_path_matches_counter(tmp_path, monkeypatch):
     for w, (_, part) in res.items():
         assert part == ihash(w) % 10
     assert aotcache.stats["compiles"] == compiles_after_warm
+
+
+@pytest.mark.parametrize("how", ["stream", "stream-accumulate", "step"])
+def test_stream_result_is_a_mapping_equal_to_the_oracles_dict(how):
+    """The merged table is the result: a read-only mapping that compares
+    equal to the oracle's dict, whose ``len()`` reads the arrays and whose
+    first keyed access decodes every spelling once."""
+    from collections.abc import Mapping
+
+    from dsi_tpu.parallel.merge import PackedWordCounts
+    from dsi_tpu.parallel.streaming import WordcountStep
+
+    text = ("Alpha alpha beta Beta gamma a ab abc the quick brown fox "
+            * 700).encode()
+    kw = dict(mesh=_mesh(), n_reduce=10, chunk_bytes=1 << 12,
+              u_cap=1 << 10)
+    ps: dict = {}
+    if how == "step":
+        step = WordcountStep([text], pipeline_stats=ps, **kw)
+        while step.advance():
+            pass
+        res = step.close()
+        assert step.result is res
+    else:
+        res = wordcount_streaming(
+            [text], pipeline_stats=ps,
+            device_accumulate=how == "stream-accumulate", **kw)
+    counts = collections.Counter(WORDS.findall(text.decode()))
+    want = {w: (c, ihash(w) % 10) for w, c in counts.items()}
+    assert isinstance(res, PackedWordCounts) and isinstance(res, Mapping)
+    assert len(res) == len(want)
+    # len() and the engine's own keys=len(...) decoded nothing
+    assert ps["finalize_decoded_keys"] == 0 == ps["finalize_decode_s"]
+    assert res.stats["finalize_decoded_keys"] == 0
+    assert res["alpha"] == want["alpha"]
+    assert res.stats["finalize_decoded_keys"] == len(want)
+    assert "Alpha" in res and "gamma" in res and "delta" not in res
+    assert dict(res.items()) == want and set(res) == set(want)
+    assert res == want and want == res
+    assert res.stats["finalize_decoded_keys"] == len(want)  # once, kept
+    assert res.stats["finalize_decode_s"] > 0

@@ -70,15 +70,16 @@ def fresh_tracer(monkeypatch):
     tracer.enabled = False
 
 
-def _stream_main(command, tmp_path, *flags):
+def _stream_main(command, tmp_path, *flags, src=None):
     """One small job of ``wcstream`` or ``grepstream`` in this process:
     ``(exit code, its pipeline_stats, its work directory)``."""
     pytest.importorskip("jax")
     import importlib
 
     main = importlib.import_module(f"dsi_tpu.cli.{command}").main
-    (src,) = ensure_corpus(str(tmp_path / "inputs"), n_files=1,
-                           file_size=120_000)
+    if src is None:
+        (src,) = ensure_corpus(str(tmp_path / "inputs"), n_files=1,
+                               file_size=120_000)
     out = str(tmp_path / f"out-{len(os.listdir(tmp_path))}")
     argv = ["--devices", "2", "--chunk-bytes", "16384", "--stats",
             "--workdir", out, *flags, src]
@@ -461,10 +462,61 @@ def test_job_children_are_the_registrys_tuple(fresh_tracer, tmp_path,
         where = {"sync"} if flags else {"merge", "replay"}
         assert parents["compact"] & where
         assert parents["compact"] <= where | {"finalize"}
-        assert parents["decode"] == {"finalize"}
+        # the writer reads the merged table's arrays: nothing is decoded
+        assert "decode" not in parents
         assert parents["format"] == parents["commit"] == {"write"}
         if flags:
             assert "drain" in parents["sync"]
+
+
+@pytest.mark.parametrize("flags", [(), ("--device-accumulate",),
+                                   ("--mesh-shards", "2")],
+                         ids=["host-merge", "device-accumulate",
+                              "mesh-shards"])
+def test_wcstream_tail_builds_no_object_per_word(tracing_off, tmp_path,
+                                                 flags):
+    from dsi_tpu.obs.registry import COUNTER_KEYS, PHASE_KEYS
+
+    rc, ps, out = _stream_main("wcstream", tmp_path, *flags)
+    assert rc == 0
+    (src,) = glob.glob(str(tmp_path / "inputs" / "*"))
+    with open(src, encoding="ascii") as f:
+        keys = len(set(re.findall(r"[A-Za-z]+", f.read())))
+    # every row of mr-out-* was rendered from the arrays, none formatted
+    # from a dict, and no spelling became a str
+    assert ps["write_rows_packed"] == keys and ps["write_rows_dict"] == 0
+    assert ps["finalize_decoded_keys"] == 0
+    lines = 0
+    for r in range(3):
+        with open(os.path.join(out, f"mr-out-{r}"), "rb") as f:
+            lines += f.read().count(b"\n")
+    assert lines == keys
+    # the spans' keys stay, as numbers: the decode's reads 0.0
+    assert ps["finalize_decode_s"] == 0.0
+    assert ps["write_format_s"] > 0 and ps["write_commit_s"] > 0
+    assert ps["write_format_s"] + ps["write_commit_s"] <= ps["write_s"] + 1e-3
+    assert 0.95 * ps["job_s"] <= ps["job_children_s"] <= ps["job_s"] + 1e-3
+    for key in ("write_rows_packed", "write_rows_dict",
+                "finalize_decoded_keys"):
+        assert key in COUNTER_KEYS, key
+    for key in ("finalize_decode_s", "write_format_s", "write_commit_s"):
+        assert key in PHASE_KEYS, key
+
+
+def test_wcstream_host_fallback_formats_from_the_dict(tracing_off,
+                                                      tmp_path):
+    src = tmp_path / "in.txt"
+    src.write_text("caf\u00e9 words caf\u00e9 and more words",
+                   encoding="utf-8")
+    rc, ps, out = _stream_main("wcstream", tmp_path, src=str(src))
+    assert rc == 0
+    assert ps["write_rows_dict"] == 4 and ps["write_rows_packed"] == 0
+    assert "finalize_decoded_keys" not in ps  # no table was finalized
+    assert ps["write_format_s"] >= 0 and ps["write_commit_s"] > 0
+    got = b"".join(open(os.path.join(out, f"mr-out-{r}"), "rb").read()
+                   for r in range(3))
+    assert sorted(got.decode("utf-8").split("\n")) == [
+        "", "and 1", "caf\u00e9 2", "more 1", "words 2"]
 
 
 def test_merge_counters_equal_a_hand_count():
