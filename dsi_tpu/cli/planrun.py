@@ -18,7 +18,15 @@ Chains:
   wc-topk   — word count → top-k highest-count words (host reduction
               over the full table); writes plan-topk.json.
   indexer   — indexer → df-top-k (k-row snapshot off the resident df
-              table) → per-term postings join; writes plan-join.json.
+              table) → per-term postings join; commits the whole
+              inverted index as mr-out-<r> files in --workdir, one line
+              a term, "<word> <n> <doc>,<doc>,..." (a document is an
+              input file, named by its basename; names sorted and
+              unique; the term in partition ihash(word) % nreduce:
+              merged and sorted, what mrsequential with apps/indexer
+              writes over the same names), and writes plan-join.json
+              (the --topk terms of highest document frequency with
+              their postings).
 
 Elastic execution (ISSUE 16): ``--pipeline`` overlaps a grep→wordcount
 pair (the wordcount consumes relay buffers as they SEAL while the grep
@@ -80,7 +88,7 @@ def _run_hosts(args, spec: dict, mesh):
     import subprocess
 
     from dsi_tpu.obs import metrics_scope
-    from dsi_tpu.plan.driver import PlanResult, _load_commit
+    from dsi_tpu.plan.driver import PlanResult, _load_commit, plan_index
     from dsi_tpu.plan.stagehost import build_plan, fetch_stage_payload
     from dsi_tpu.utils.atomicio import atomic_write
 
@@ -184,7 +192,8 @@ def _run_hosts(args, spec: dict, mesh):
                 net_io[k] = round(float(net_io[k]), 6)
         sc.update(net_io)
         results = {name: out.result for name, out in ctx.items()}
-        res = PlanResult(results, ctx[order[-1].name].result, sc)
+        res = PlanResult(results, ctx[order[-1].name].result, sc,
+                         index=plan_index(plan, ctx, sc))
     finally:
         for proc, logf in procs:
             if proc.poll() is None:
@@ -206,7 +215,13 @@ def main(argv=None) -> int:
     p.add_argument("--chain",
                    choices=("grep-wc", "grep-grep", "wc-topk",
                             "indexer"),
-                   default="grep-wc")
+                   default="grep-wc",
+                   help="grep-wc commits the word counts of the matching "
+                        "lines as mr-out-<r>; indexer commits the whole "
+                        "inverted index as mr-out-<r> (a document is an "
+                        "input file) and writes plan-join.json; grep-grep "
+                        "and wc-topk write plan-grep.json / "
+                        "plan-topk.json")
     p.add_argument("--pattern", default=None,
                    help="literal grep pattern (required for grep-wc "
                         "and grep-grep)")
@@ -347,21 +362,29 @@ def main(argv=None) -> int:
     pstats = {"stages": stats.get("stage_stats", {}),
               "plan": {k: v for k, v in stats.items()
                        if k != "stage_stats"}}
+    committed = None  # what goes out as mr-out-<r>: a merged table
     if args.chain == "grep-wc":
-        from dsi_tpu.obs import span
-        from dsi_tpu.parallel.shuffle import write_partitioned_output
-
         g = res.results["grep"]
         print(f"planrun: grep lines={g.lines} matched={g.matched} "
               f"occurrences={g.occurrences}", file=sys.stderr)
+        committed = res.final
+    elif args.chain == "indexer":
+        # The table the join stage grouped, its documents named as the
+        # host app names them (mrsequential in the files' directory).
+        committed = res.index.named(
+            [os.path.basename(path) for path in args.files])
+    if committed is not None:
+        from dsi_tpu.obs import span
+        from dsi_tpu.parallel.shuffle import write_partitioned_output
+
         with span("write", lane="host", stats=pstats,
-                  keys=len(res.final)) as sp:
-            paths = write_partitioned_output(res.final, args.nreduce,
+                  keys=len(committed)) as sp:
+            paths = write_partitioned_output(committed, args.nreduce,
                                              args.workdir, stats=pstats)
             sp.set(bytes=sum(os.path.getsize(path) for path in paths))
         for key in ("write_s", "write_format_s", "write_commit_s"):
             pstats[key] = round(pstats[key], 4)
-    elif args.chain == "grep-grep":
+    if args.chain == "grep-grep":
         stages = {name: {"lines": r.lines, "matched": r.matched,
                          "occurrences": r.occurrences}
                   for name, r in res.results.items()}
@@ -380,7 +403,7 @@ def main(argv=None) -> int:
                       f, sort_keys=True, indent=1)
         print(f"planrun: top-{len(res.final)} words -> {path}",
               file=sys.stderr)
-    else:
+    elif args.chain == "indexer":
         out = {w: {"df": df, "part": part, "docs": list(docs)}
                for w, (df, part, docs) in res.final.items()}
         path = os.path.join(args.workdir, "plan-join.json")
@@ -389,8 +412,9 @@ def main(argv=None) -> int:
             json.dump({"topk": [[c, w] for c, w in
                                 res.results.get("dftopk", ())],
                        "join": out}, f, sort_keys=True, indent=1)
-        print(f"planrun: join of {len(out)} terms -> {path}",
-              file=sys.stderr)
+        print(f"planrun: index of {len(committed)} terms -> "
+              f"{args.workdir}/mr-out-*; join of {len(out)} terms -> "
+              f"{path}", file=sys.stderr)
 
     # After the commit, so that the line holds the job's tail too
     # (write_s) and the trace its last span.

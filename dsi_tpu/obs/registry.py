@@ -37,6 +37,10 @@ that phase):
 * ``compact_s``          — the ``compact`` spans of the host accumulator
   (``parallel/merge.py``): every buffered row sorted and merged again;
   inside ``merge_s``, ``finalize_s`` or ``sync_s``, whichever called it
+* ``group_s``            — the ``group`` span: the indexer's postings
+  table grouped into the index (``merge.PostingsTable.finalize_packed``:
+  one lexsort over the key lanes and the run detection), once a job,
+  when the first stage that needs the whole table asks for it
 * ``write_s``            — writing the partitioned ``mr-out-*`` (the
   CLI's phase, not the engine's)
 * ``write_format_s`` / ``write_commit_s`` — inside it
@@ -86,7 +90,13 @@ at dispatch), ``step_pulls``, ``sync_pulls``, ``widens``, ``folds``,
 ``merge_rows_sorted`` (rows through its lexsort, summed over
 compactions) and ``merge_compacts`` (all three repeat exactly for one
 input), ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
-``resume_cursor``/``resume_wave``, ``device_accumulate``.
+``resume_cursor``/``resume_wave``, ``device_accumulate``.  The indexer's
+wave walk adds ``docs`` (documents handed over), ``waves_by_size``
+(padded chunk bytes → waves dispatched), ``wave_doc_bytes`` and
+``wave_chunk_bytes`` (the documents' bytes and the padded bytes they
+were uploaded as: their ratio is how full the waves were),
+``postings_rows`` and ``index_terms`` (the table the index is grouped
+from and the terms it holds).
 
 Async/incremental checkpoint keys (``dsi_tpu/ckpt`` writer/delta —
 present when checkpointing is on): ``ckpt_async``/``ckpt_delta`` (the
@@ -253,6 +263,10 @@ PHASE_KEYS = (
     "enqueue_s",
     # the host merge and the serial tail, split where the work happens
     "compact_s", "finalize_decode_s", "write_format_s", "write_commit_s",
+    # the postings table grouped into the index (``group`` span of
+    # ``merge.PostingsTable.finalize_packed``: the lexsort and the run
+    # detection), in the indexer's scope
+    "group_s",
 )
 
 #: The direct children of a stream command's root ``job`` span on its
@@ -296,6 +310,12 @@ COUNTER_KEYS = (
     # (0 in a wcstream job), rows rendered from the merged table's
     # arrays, rows formatted from a dict (the host fallback's)
     "finalize_decoded_keys", "write_rows_packed", "write_rows_dict",
+    # the indexer's wave walk (parallel/grepstream.py): documents handed
+    # over, waves dispatched by padded chunk size, the documents' bytes
+    # and the padded bytes they were uploaded as, and the table it ends
+    # with: posting rows grouped, terms of the index
+    "docs", "waves_by_size", "wave_doc_bytes", "wave_chunk_bytes",
+    "postings_rows", "index_terms",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

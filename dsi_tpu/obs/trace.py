@@ -109,6 +109,9 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # compaction of the host accumulator (parallel/merge.py); inside
     # "write", a partition's CPU work and its durable commit
     "job", "start", "enqueue", "compact", "format", "commit",
+    # the indexer's postings table grouped into the index, once a job
+    # (parallel/merge.py PostingsTable.finalize_packed)
+    "group",
 ))
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"

@@ -101,12 +101,11 @@ from dsi_tpu.device.topk import DeviceHistogram, DeviceTopK, KeyCounts
 from dsi_tpu.obs import metrics_scope, span as _span
 from dsi_tpu.ops.grepk import is_literal_pattern
 from dsi_tpu.ops.wordcount import (
-    _PAD_KEY64,
+    _PAD_KEY,
     _shift_left,
+    compact_positions,
     grouper_ladder,
-    pack_key_lanes,
     rung0_cap,
-    unpack_key_lanes,
     warm_groupers,
 )
 from dsi_tpu.parallel.merge import PackedCounts, PostingsTable
@@ -1143,29 +1142,35 @@ def _idx_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
     chunk = chunk.reshape(-1)
     doc = doc_id.reshape(())
 
-    packed_u, len_u, cnt_u, part, dest, (
-        n_unique, max_len, has_high, token_overflow) = map_prologue(
-        chunk, n_dev=n_dev, n_reduce=n_reduce, max_word_len=max_word_len,
-        u_cap=u_cap, t_cap_frac=t_cap_frac, grouper=grouper)
+    with jax.named_scope("map"):
+        packed_u, len_u, cnt_u, part, dest, (
+            n_unique, max_len, has_high, token_overflow) = map_prologue(
+            chunk, n_dev=n_dev, n_reduce=n_reduce,
+            max_word_len=max_word_len, u_cap=u_cap, t_cap_frac=t_cap_frac,
+            grouper=grouper)
 
-    rows = jnp.concatenate(
-        [packed_u, len_u[:, None].astype(jnp.uint32),
-         jnp.ones((u_cap, 1), jnp.uint32),
-         jnp.broadcast_to(doc.astype(jnp.uint32), (u_cap,))[:, None],
-         part[:, None]], axis=1)
-    recv = shuffle_rows(rows, dest, n_dev=n_dev, u_cap=u_cap, k=k)
+    with jax.named_scope("shuffle"):
+        rows = jnp.concatenate(
+            [packed_u, len_u[:, None].astype(jnp.uint32),
+             jnp.ones((u_cap, 1), jnp.uint32),
+             jnp.broadcast_to(doc.astype(jnp.uint32), (u_cap,))[:, None],
+             part[:, None]], axis=1)
+        recv = shuffle_rows(rows, dest, n_dev=n_dev, u_cap=u_cap, k=k)
 
-    with enable_x64(True):  # every op touching u64 operands needs it
-        keys64 = pack_key_lanes(tuple(recv[:, j] for j in range(k)))
-        pay64 = pack_key_lanes(tuple(recv[:, k + j] for j in range(4)))
-        k64 = len(keys64)
-        is_pad = (keys64[0] == jnp.array(_PAD_KEY64, jnp.uint64)) \
-            .astype(jnp.uint8)
-        sorted_cols = lax.sort((is_pad,) + keys64 + pay64, num_keys=1)
-        srecv = jnp.stack(
-            unpack_key_lanes(sorted_cols[1:1 + k64], k)
-            + unpack_key_lanes(sorted_cols[1 + k64:], 4), axis=1)
-    n_rows = jnp.sum(sorted_cols[0] == 0, dtype=jnp.int32)
+    # Valid rows first, in the order they came; every pad row behind
+    # them.  One single-key int32 sort of the valid positions and a row
+    # gather (``compact_positions``), where a stable sort of the rows
+    # themselves carried all their lanes as operands: that sort doubled
+    # the wave program's compile for the v5e (120 s against 61 at 1 MiB
+    # and 32,768 rows; CHANGES.md, PR 38).
+    with jax.named_scope("sort"):
+        m = recv.shape[0]
+        valid = recv[:, 0] != jnp.uint32(_PAD_KEY)
+        n_rows = jnp.sum(valid, dtype=jnp.int32)
+        pad_row = jnp.concatenate([jnp.full((k,), _PAD_KEY, jnp.uint32),
+                                   jnp.zeros((4,), jnp.uint32)])
+        srecv = jnp.concatenate([recv, pad_row[None]])[
+            compact_positions(valid, m, fill_value=m)]
 
     df = jnp.concatenate([srecv[:, :k + 2], srecv[:, k + 3:k + 4]], axis=1)
     scalars = jnp.stack([n_rows, n_unique, max_len,
@@ -1206,6 +1211,8 @@ def _idx_program(*, n_dev: int, n_reduce: int, max_word_len: int,
                                    mesh=mesh, t_cap_frac=t_cap_frac,
                                    grouper=grouper)
 
+    # The HLO module takes the traced function's name (``_grep_program``).
+    fn.__name__ = fn.__qualname__ = "idx_wave_step"
     name = (f"idx_wave_d{n_dev}_r{n_reduce}_w{max_word_len}"
             f"_u{u_cap}_s{size}_f{t_cap_frac}")
     name += grouper_suffix(grouper)
@@ -1379,10 +1386,18 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
     # Internal registry scope (dsi_tpu/obs); copied out to the caller's
     # ``stats`` dict when the walk ends, like pipeline_stats everywhere.
     st = metrics_scope("indexer")
-    st.update({"waves": len(waves), "step_pulls": 0, "depth": depth,
-               "replays": 0, "device_accumulate": device_accumulate,
-               "upload_s": 0.0, "kernel_s": 0.0, "pull_s": 0.0,
-               "merge_s": 0.0, "replay_s": 0.0})
+    st.update({"waves": len(waves), "docs": n_real, "step_pulls": 0,
+               "depth": depth, "replays": 0,
+               "device_accumulate": device_accumulate,
+               # what the walk uploaded, counted a wave as it is
+               # dispatched (a restarted rung's waves again): chunk
+               # bytes -> waves, the documents' bytes and the padded
+               # bytes they went up as (their ratio is how full the
+               # waves were)
+               "waves_by_size": {}, "wave_doc_bytes": 0,
+               "wave_chunk_bytes": 0,
+               "upload_s": 0.0, "enqueue_s": 0.0, "kernel_s": 0.0,
+               "pull_s": 0.0, "merge_s": 0.0, "replay_s": 0.0})
     groupers = grouper_ladder()
     sh_chunk = NamedSharding(mesh, P(AXIS, None))
     sh_ids = NamedSharding(mesh, P(AXIS))
@@ -1602,20 +1617,28 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                 ids_np = np.array(
                     list(idxs) + [n_real] * (n_dev - len(idxs)),
                     dtype=np.int32)
-                yield (size, chunk_np, ids_np)
+                yield (size, chunk_np, ids_np,
+                       sum(doc_lens[i] for i in idxs))
 
         def wave_call(chunk_np, ids_np, size, cap, frac, g):
             with _span("upload", stats=st, key="upload_s"):
                 chunk = jax.device_put(chunk_np, sh_chunk)
                 ids = jax.device_put(ids_np, sh_ids)
-            fn = _idx_fn((chunk, ids), n_dev=n_dev, n_reduce=n_reduce,
-                         max_word_len=mwl, u_cap=cap, size=size, mesh=mesh,
-                         t_cap_frac=frac, grouper=g)
-            with _quiet_unusable_donation():
-                return fn(chunk, ids)
+            # The program's lookup and call, which returns before the
+            # device has run it (``grep``'s ``enqueue``).
+            with _span("enqueue", lane="dispatch", stats=st,
+                       program="idx_wave_step", size=size, cap=cap):
+                fn = _idx_fn((chunk, ids), n_dev=n_dev, n_reduce=n_reduce,
+                             max_word_len=mwl, u_cap=cap, size=size,
+                             mesh=mesh, t_cap_frac=frac, grouper=g)
+                with _quiet_unusable_donation():
+                    return fn(chunk, ids)
 
         def dispatch(item):
-            size, chunk_np, ids_np = item
+            size, chunk_np, ids_np, doc_bytes = item
+            st["waves_by_size"][size] = st["waves_by_size"].get(size, 0) + 1
+            st["wave_doc_bytes"] += doc_bytes
+            st["wave_chunk_bytes"] += n_dev * size
             rows, df, scal = wave_call(chunk_np, ids_np, size,
                                        state["cap"], state["frac"],
                                        state["grouper"])
@@ -1642,8 +1665,14 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                     if int(scal_np[:, 2].max()) > mwl:
                         outcome["widen"] = True
                         raise _AbortRung
-                    if int(scal_np[:, 1].max()) > cap:
-                        cap *= 4  # uniques <= tokens <= size/2: terminates
+                    uniques = int(scal_np[:, 1].max())
+                    if uniques > cap:
+                        # The first x4 rung that holds what the wave
+                        # reported (``exactness_retry``): a rung known
+                        # not to fit is never compiled.  Uniques <=
+                        # tokens <= size/2, so this terminates.
+                        while cap < uniques:
+                            cap *= 4
                         continue
                     break
             state["cap"], state["grouper"], state["frac"] = cap, g, frac
@@ -1771,7 +1800,7 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                     ck_writer.shutdown()
             postings = {
                 w: (part, [d for d, _ in pairs])
-                for w, (part, pairs) in table.finalize().items()}
+                for w, (part, pairs) in table.finalize(stats=st).items()}
             if device_accumulate and topk_svc is not None:
                 df_map = {w: c for w, (c, _) in df_acc.finalize().items()}
             else:

@@ -43,6 +43,41 @@ from dsi_tpu.ops.wordcount import decode_packed
 _POW10 = 10 ** np.arange(1, 19, dtype=np.int64)
 
 
+def _index_dtype(limit: int):
+    """The narrowest integer type that holds indices below ``limit``."""
+    return np.int32 if limit < 1 << 31 else np.int64
+
+
+def _word_number_rows(key_bytes: np.ndarray, rows: np.ndarray,
+                      lens: np.ndarray, numbers: np.ndarray,
+                      last: int) -> np.ndarray:
+    """``"word number"`` and the byte ``last`` for each of ``rows``, flat,
+    from the arrays: the words' bytes out of ``key_bytes`` ([n, 4K]
+    uint8, the big-endian view of the key lanes) cut at the longest
+    word; a space; decimal digits by integer comparisons and ``divmod``
+    (an int64 past 2^53 must print exactly, so no float logarithm);
+    ``last``.  One [rows, longest word + 1 + most digits + 1] byte matrix
+    and a keep-mask: what a row keeps follows its (length, digits)
+    alone, so one mask a class, gathered a row."""
+    lens = lens.astype(np.int64)
+    w = int(lens.max())
+    digits = np.searchsorted(_POW10, numbers, side="right") + 1
+    d = int(digits.max())
+    width = w + d + 2
+    mat = np.empty((len(rows), width), np.uint8)
+    mat[:, :w] = np.take(key_bytes, rows, axis=0)[:, :w]
+    mat[:, w] = 0x20
+    for col in range(w + d, w, -1):  # right-aligned, least first
+        numbers, digit = np.divmod(numbers, 10)
+        mat[:, col] = digit + 0x30
+    mat[:, w + d + 1] = last
+    col = np.arange(width)
+    masks = ((col < np.arange(w + 1)[:, None, None])
+             | (col >= w + 1 + d - np.arange(d + 1)[None, :, None])
+             | (col == w)).reshape(-1, width)
+    return mat[np.take(masks, lens * (d + 1) + digits, axis=0)]
+
+
 def _pad_width(keys: np.ndarray, k: int) -> np.ndarray:
     """Right-pad packed-key lanes with zero columns to width ``k``."""
     if keys.shape[1] == k:
@@ -304,28 +339,8 @@ class PackedWordCounts(Mapping):
             # [n, 4K] uint8: big-endian lanes are the spelling's bytes
             self._bytes = np.ascontiguousarray(
                 self.skeys.astype(">u4")).view(np.uint8)
-        lens, cnts = self.lens[rows], self.cnts[rows]
-        w = int(lens.max())
-        # decimal digits a count by integer comparisons: int64 counts
-        # past 2^53 must print exactly, so no float logarithm
-        digits = np.searchsorted(_POW10, cnts, side="right") + 1
-        d = int(digits.max())
-        width = w + d + 2
-        mat = np.empty((len(rows), width), np.uint8)
-        mat[:, :w] = np.take(self._bytes, rows, axis=0)[:, :w]
-        mat[:, w] = 0x20
-        for col in range(w + d, w, -1):  # right-aligned, least first
-            cnts, digit = np.divmod(cnts, 10)
-            mat[:, col] = digit + 0x30
-        mat[:, w + d + 1] = 0x0A
-        # what a row keeps follows its (length, digits) alone: one mask
-        # a class, gathered a row
-        col = np.arange(width)
-        masks = ((col < np.arange(w + 1)[:, None, None])
-                 | (col >= w + 1 + d - np.arange(d + 1)[None, :, None])
-                 | (col == w)).reshape(-1, width)
-        keep = np.take(masks, lens * (d + 1) + digits, axis=0)
-        return mat[keep].tobytes()
+        return _word_number_rows(self._bytes, rows, self.lens[rows],
+                                 self.cnts[rows], 0x0A).tobytes()
 
 
 class PostingsTable:
@@ -374,16 +389,31 @@ class PostingsTable:
         self._kk = int(arrays["kk"])
         self._bufs = [np.array(arrays["rows"], dtype=np.uint32)]
 
-    def finalize(self) -> Dict[str, Tuple[int, List[Tuple[int, int]]]]:
-        return self.finalize_packed().to_dict()
+    def finalize(self, stats: Optional[dict] = None
+                 ) -> Dict[str, Tuple[int, List[Tuple[int, int]]]]:
+        return self.finalize_packed(stats).to_dict()
 
-    def finalize_packed(self) -> "PackedPostings":
+    def finalize_packed(self, stats: Optional[dict] = None
+                        ) -> "PackedPostings":
         """Group without pythonizing: the full postings stay as numpy
         arrays (~32 B/posting) instead of ~250 B of tuples/lists/ints per
         posting — at GB scale the dict materialization alone was ~2 GB of
         the soak's peak RSS.  Use ``to_dict()``
         (or ``lookup_many`` for a few words) only at scales that afford
-        it."""
+        it.  The lexsort and the run detection are the ``group`` span
+        (``group_s`` of ``stats``, the engine's scope), which also takes
+        the table's ``postings_rows`` and ``index_terms``."""
+        n_rows = sum(len(b) for b in self._bufs)
+        with _span("group", lane="merge", stats=stats, key="group_s",
+                   rows=n_rows) as sp:
+            out = self._group()
+            sp.set(terms=len(out))
+        if stats is not None:
+            stats["postings_rows"] = n_rows
+            stats["index_terms"] = len(out)
+        return out
+
+    def _group(self) -> "PackedPostings":
         if not self._bufs:
             return PackedPostings(0)
         kk = self._kk
@@ -408,14 +438,21 @@ class PackedPostings:
     """Grouped TF-IDF postings as numpy tables (lexicographic word
     order).  ``skeys/lens/parts/starts/ends`` are per-unique-word;
     ``tfs/docs`` are the full postings, ``starts[i]:ends[i]`` slicing
-    word i's."""
+    word i's.
+
+    With the documents' names (:meth:`named`) the table is also an
+    inverted index that ``shuffle.write_partitioned_output`` commits:
+    :meth:`render_partition` gives a partition's ``mr-out`` bytes from
+    the arrays, with no Python object a posting."""
 
     __slots__ = ("kk", "skeys", "lens", "parts", "starts", "ends",
-                 "tfs", "docs", "_be")
+                 "tfs", "docs", "_be", "doc_names", "_by_name")
 
     def __init__(self, kk: int):
         self.kk = kk
-        self._be = None  # lazy big-endian key view (lookup_many)
+        self._be = None  # lazy big-endian key view (_be_keys)
+        self.doc_names: Optional[List[str]] = None
+        self._by_name = None  # lazy (named, render_partition)
         self.skeys = np.zeros((0, max(kk, 1)), np.uint32)
         self.lens = np.zeros(0, np.uint32)
         self.parts = np.zeros(0, np.uint32)
@@ -434,6 +471,14 @@ class PackedPostings:
     def postings_per_word(self) -> np.ndarray:
         return self.ends - self.starts
 
+    def _be_keys(self) -> np.ndarray:
+        """The key lanes big-endian ([n, K] ``>u4``: lane order is byte
+        order), built once: the table is immutable after
+        ``finalize_packed``."""
+        if self._be is None:
+            self._be = np.ascontiguousarray(self.skeys.astype(">u4"))
+        return self._be
+
     def lookup_many(self, words) -> Dict[str, Tuple[int, List[Tuple[int,
                                                                     int]]]]:
         """{word: (part, [(doc, tf), ...])} for just these words (absent
@@ -444,9 +489,7 @@ class PackedPostings:
         n = len(self.skeys)
         if n == 0:
             return {}
-        if self._be is None:  # immutable after finalize_packed: cache it
-            self._be = np.ascontiguousarray(self.skeys.astype(">u4"))
-        be = self._be
+        be = self._be_keys()
         width = 4 * self.kk
         out: Dict[str, Tuple[int, List[Tuple[int, int]]]] = {}
         for w in words:
@@ -473,6 +516,134 @@ class PackedPostings:
                       list(zip(self.docs[s:e].tolist(),
                                self.tfs[s:e].tolist())))
         return out
+
+    @classmethod
+    def from_postings(cls, postings: Dict[str, Tuple[int, List[int]]]
+                      ) -> "PackedPostings":
+        """The table of ``{word: (part, [doc, ...])}``, an indexer's
+        result that is already Python objects (the plan layer's staged
+        baseline and its stage commits): term frequencies read 1."""
+        words = sorted(postings)
+        if not words:
+            return cls(0)
+        raw = [w.encode("ascii") for w in words]
+        kk = (max(map(len, raw)) + 3) // 4
+        out = cls(kk)
+        out.skeys = np.frombuffer(
+            b"".join(b.ljust(4 * kk, b"\x00") for b in raw),
+            dtype=">u4").reshape(len(raw), kk).astype(np.uint32)
+        out.lens = np.array([len(b) for b in raw], np.uint32)
+        out.parts = np.array([postings[w][0] for w in words], np.uint32)
+        df = np.array([len(postings[w][1]) for w in words], np.int64)
+        out.ends = np.cumsum(df)
+        out.starts = out.ends - df
+        out.docs = np.fromiter(
+            (d for w in words for d in postings[w][1]), np.uint32,
+            int(out.ends[-1]))
+        out.tfs = np.ones(len(out.docs), np.uint32)
+        return out
+
+    def named(self, doc_names) -> "PackedPostings":
+        """The same table, its documents named: ``doc_names[d]`` is
+        document ``d``'s name in the committed index."""
+        self.doc_names = list(doc_names)
+        self._by_name = None
+        return self
+
+    def _named_postings(self):
+        """What :meth:`render_partition` reads, built once: every
+        word's documents as ranks among the sorted, unique names,
+        sorted and unique within the word (``ranks``, word ``i``'s at
+        ``offs[i]:offs[i + 1]``), and the names as one byte table, each
+        followed by a comma (``table``, name ``j`` at ``name_offs[j]``,
+        ``name_lens[j]`` bytes with its comma)."""
+        if self._by_name is None:
+            if self.doc_names is None:
+                raise ValueError("PackedPostings.named() first: an index "
+                                 "line names its documents")
+            if len(self.docs) and int(self.docs.max()) >= len(
+                    self.doc_names):
+                raise ValueError(
+                    f"document {int(self.docs.max())} of a posting has no "
+                    f"name among {len(self.doc_names)}")
+            names = sorted(set(self.doc_names))
+            rank = {name: j for j, name in enumerate(names)}
+            rank_of = np.array([rank[name] for name in self.doc_names],
+                               np.int64)
+            n_words = len(self.skeys)
+            # every posting as one number, word * names + rank, in the
+            # narrowest index type (the arrays are postings long, and
+            # what they allocate is most of a commit's time): one sort
+            # orders every word's documents by name, and what two
+            # documents of one name would repeat is dropped
+            idx = _index_dtype(n_words * len(names))
+            pairs = np.repeat(np.arange(n_words, dtype=idx),
+                              self.ends - self.starts)
+            pairs *= idx(len(names))
+            pairs += rank_of.astype(idx)[self.docs]
+            pairs.sort()
+            if len(pairs):
+                fresh = np.empty(len(pairs), bool)
+                fresh[0] = True
+                np.not_equal(pairs[1:], pairs[:-1], out=fresh[1:])
+                if not fresh.all():
+                    pairs = pairs[fresh]
+            word_of, ranks = np.divmod(pairs, idx(len(names)))
+            offs = np.zeros(n_words + 1, np.int64)
+            np.cumsum(np.bincount(word_of, minlength=n_words),
+                      out=offs[1:])
+            raw = [name.encode("utf-8") + b"," for name in names]
+            name_lens = np.array([len(b) for b in raw], np.int64)
+            self._by_name = (
+                ranks, offs, np.frombuffer(b"".join(raw), np.uint8),
+                np.cumsum(name_lens) - name_lens, name_lens,
+                self._be_keys().view(np.uint8))
+        return self._by_name
+
+    def render_partition(self, r: int) -> bytes:
+        """Partition ``r``'s ``mr-out`` bytes of the inverted index,
+        ``"word n doc,doc,...\n"`` a word in table order (the order
+        ``sorted`` gives the spellings), the documents' names sorted and
+        unique and ``n`` their number (``apps/indexer.Reduce``): from
+        the arrays.  The output is one gather out of one source buffer
+        (each word's ``"word n "``, then the name table), a piece a
+        word and a piece a posting."""
+        ranks, offs, table, name_offs, name_lens, key_bytes = \
+            self._named_postings()
+        rows = np.flatnonzero(self.parts == r)
+        if len(rows) == 0:
+            return b""
+        # the heads, "word n ", as PackedWordCounts renders its rows
+        df = offs[rows + 1] - offs[rows]
+        heads = _word_number_rows(key_bytes, rows, self.lens[rows], df,
+                                  0x20)
+        head_lens = (self.lens[rows].astype(np.int64) + 2
+                     + np.searchsorted(_POW10, df, side="right") + 1)
+        # the pieces in output order: word k's head at piece
+        # (postings before it) + k, its postings behind it
+        before = np.cumsum(df) - df
+        picked = np.repeat(offs[rows] - before, df) \
+            + np.arange(int(df.sum()))
+        n_pieces = len(rows) + len(picked)
+        src = np.empty(n_pieces, np.int64)
+        length = np.empty(n_pieces, np.int64)
+        head_at = before + np.arange(len(rows))
+        post_at = np.arange(len(picked)) \
+            + np.repeat(np.arange(1, len(rows) + 1), df)
+        src[head_at] = np.cumsum(head_lens) - head_lens
+        length[head_at] = head_lens
+        src[post_at] = len(heads) + name_offs[ranks[picked]]
+        length[post_at] = name_lens[ranks[picked]]
+        ends = np.cumsum(length)
+        source = np.concatenate([heads, table])
+        idx = _index_dtype(max(len(source), int(ends[-1])))
+        gather = np.arange(int(ends[-1]), dtype=idx)
+        gather += np.repeat((src - (ends - length)).astype(idx), length)
+        out = source[gather]
+        # a word's last comma is its line's end (every word of an
+        # index has a posting, so the piece before a head is a posting)
+        out[ends[np.append(head_at[1:], n_pieces) - 1] - 1] = 0x0A
+        return out.tobytes()
 
     def to_dict(self) -> Dict[str, Tuple[int, List[Tuple[int, int]]]]:
         if len(self.skeys) == 0:

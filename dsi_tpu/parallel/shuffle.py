@@ -336,9 +336,11 @@ def write_partitioned_output(result: Mapping[str, Tuple[int, int]],
 
     One algorithm over two representations, chosen by what it is handed:
     a merged table (``merge.PackedWordCounts``, what a stream's
-    accumulator returns) renders each partition's bytes from its arrays
-    (``write_rows_packed`` of ``stats`` counts the rows), with no Python
-    object a word; a dict (the host fallback's) is bucketed, sorted and
+    accumulator returns; ``merge.PackedPostings`` with its documents
+    named, an inverted index: a row is ``"word n doc,doc,..."``) renders
+    each partition's bytes from its arrays (``write_rows_packed`` of
+    ``stats`` counts the rows), with no Python object a word or a
+    posting; a dict (the host fallback's) is bucketed, sorted and
     formatted a line at a time (``write_rows_dict``).  The bytes and the
     commits are the same.
 
@@ -350,10 +352,10 @@ def write_partitioned_output(result: Mapping[str, Tuple[int, int]],
     import os
 
     from dsi_tpu.obs import span as _span
-    from dsi_tpu.parallel.merge import PackedWordCounts
+    from dsi_tpu.parallel.merge import PackedPostings, PackedWordCounts
     from dsi_tpu.utils.atomicio import atomic_write
 
-    packed = isinstance(result, PackedWordCounts)
+    packed = isinstance(result, (PackedWordCounts, PackedPostings))
     if packed:
         # the dict's bucketing raises on such a row; a mask would skip it
         outside = np.count_nonzero((result.parts < 0)

@@ -63,17 +63,22 @@ class StageOut:
     ``handoff`` (exported live services, indexer).  ``relay_spent``
     marks a relay consumed INSIDE the producing run (the pipelined
     handoff): its stage manifest carries no relay image, so a resume
-    may trust it only while the consumer's manifest verifies too."""
+    may trust it only while the consumer's manifest verifies too.
+    ``index`` is the whole postings table a ``postings_join`` stage
+    grouped to look its terms up (``merge.PackedPostings``)."""
 
-    __slots__ = ("result", "relay", "handoff", "resumed", "relay_spent")
+    __slots__ = ("result", "relay", "handoff", "resumed", "relay_spent",
+                 "index")
 
     def __init__(self, result=None, relay=None, handoff=None,
-                 resumed: bool = False, relay_spent: bool = False):
+                 resumed: bool = False, relay_spent: bool = False,
+                 index=None):
         self.result = result
         self.relay = relay
         self.handoff = handoff
         self.resumed = resumed
         self.relay_spent = relay_spent
+        self.index = index
 
 
 class PlanResult:
@@ -83,12 +88,15 @@ class PlanResult:
     own scope (``steps``, ``replays``, ``device_rows``, ``upload_s``,
     ``kernel_s``, ... as ``wcstream --stats`` / ``grepstream --stats``
     print them) plus ``bytes_in``; a list of them, in shard order,
-    where ``stage_shards`` split the stage."""
+    where ``stage_shards`` split the stage.  ``index`` is the whole
+    postings table of a plan with a ``postings_join`` stage
+    (:func:`plan_index`), None for every other plan."""
 
-    def __init__(self, results: Dict, final, stats: Dict):
+    def __init__(self, results: Dict, final, stats: Dict, index=None):
         self.results = results
         self.final = final
         self.stats = stats
+        self.index = index
 
 
 def _spill_bytes(plan: Plan) -> int:
@@ -404,7 +412,8 @@ def run_plan(plan: Plan, *, mesh=None, staged: bool = False,
     if stats is not None:
         stats.update(sc)
     results = {name: out.result for name, out in ctx.items()}
-    return PlanResult(results, ctx[order[-1].name].result, sc)
+    return PlanResult(results, ctx[order[-1].name].result, sc,
+                      index=plan_index(plan, ctx, sc))
 
 
 def _engine_kw(plan: Plan, stage: Stage) -> Dict:
@@ -682,33 +691,66 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
         if up.handoff is None:  # staged (or restored) indexer result
             _, top = up.result
             return StageOut(result=tuple(top[:k]))
-        return StageOut(result=_df_topk_from_handoff(up.handoff, k))
+        return StageOut(result=_df_topk_from_handoff(
+            up.handoff, k, sc["stage_stats"].get(stage.deps[0])))
 
     if stage.kind == "postings_join":
         fault_point(f"plan-stage{i}-advance")
         up_idx = ctx[stage.deps[0]]
         top = ctx[stage.deps[1]].result
-        words = [w for _, w in top]
-        if up_idx.handoff is None:
-            postings, _ = up_idx.result
-            join = {w: (df, postings[w][0], tuple(postings[w][1]))
-                    for df, w in top if w in postings}
-        else:
-            h = up_idx.handoff
-            if h.get("postings_svc") is not None:
-                h["postings_svc"].close()  # flush the device buffer's
-                h["postings_svc"] = None  # remainder into the table
-            packed = h["table"].finalize_packed()
-            found = packed.lookup_many(words)
-            join = {w: (df, found[w][0],
-                        tuple(d for d, _ in found[w][1]))
-                    for df, w in top if w in found}
-        return StageOut(result=join)
+        packed = _postings_index(
+            up_idx, sc["stage_stats"].get(stage.deps[0]))
+        found = packed.lookup_many([w for _, w in top])
+        join = {w: (df, found[w][0], tuple(d for d, _ in found[w][1]))
+                for df, w in top if w in found}
+        return StageOut(result=join, index=packed)
 
     raise PlanError(f"unrunnable stage kind {stage.kind!r}")
 
 
-def _df_topk_from_handoff(h: Dict, k: int) -> Tuple:
+def _postings_index(up: StageOut, stats: Optional[dict] = None):
+    """The whole postings table behind an indexer stage's output, as
+    ``merge.PackedPostings``, grouped once and kept: out of the handoff's
+    host table (the device buffer's remainder flushed into it first),
+    or out of the result a staged or restored indexer left as Python
+    objects.  ``stats`` (the indexer's record under ``stage_stats``)
+    takes the ``group`` span's seconds and the table's counts."""
+    if up.handoff is None:
+        from dsi_tpu.parallel.merge import PackedPostings
+
+        postings, _ = up.result
+        return PackedPostings.from_postings(postings)
+    return _handoff_index(up.handoff, stats)
+
+
+def _handoff_index(h: Dict, stats: Optional[dict] = None):
+    """The handoff's host table grouped, once (kept under ``packed``)."""
+    if h.get("packed") is None:
+        if h.get("postings_svc") is not None:
+            h["postings_svc"].close()  # flush the device buffer's
+            h["postings_svc"] = None  # remainder into the table
+        h["packed"] = h["table"].finalize_packed(stats=stats)
+    return h["packed"]
+
+
+def plan_index(plan: Plan, ctx: Dict, sc: dict):
+    """The index a plan with a ``postings_join`` stage ends with: the
+    table that stage grouped, or, where the stage was restored from its
+    commit (a resume, a stage host's payload), the one its indexer
+    stage's output gives.  None for a plan without such a stage."""
+    for stage in plan.ordered():
+        if stage.kind == "postings_join":
+            out = ctx[stage.name]
+            if out.index is None:
+                out.index = _postings_index(
+                    ctx[stage.deps[0]],
+                    sc.get("stage_stats", {}).get(stage.deps[0]))
+            return out.index
+    return None
+
+
+def _df_topk_from_handoff(h: Dict, k: int,
+                          stats: Optional[dict] = None) -> Tuple:
     """The chained df-top-k: a k-row snapshot off the RESIDENT df table
     (no drain-to-host) when it holds the complete state; the exact
     drain fallback when a widen already spilled rows into the host
@@ -734,22 +776,17 @@ def _df_topk_from_handoff(h: Dict, k: int) -> Tuple:
     dfm = {w: c for w, (c, _p) in df_acc.finalize().items()}
     if not dfm:
         # Host-merge indexer (no dacc): document frequency is the
-        # postings list length; close any device buffer first.
-        if h.get("postings_svc") is not None:
-            h["postings_svc"].close()
-            h["postings_svc"] = None
-        dfm = {w: int(e - s) for w, s, e in _word_spans(h["table"])}
+        # postings list length.  The table is in word order, so a
+        # stable sort by df alone breaks ties by word, and only the k
+        # leaders' spellings are decoded.
+        packed = _handoff_index(h, stats)
+        df = packed.ends - packed.starts
+        lead = np.argsort(-df, kind="stable")[:k]
+        words = decode_packed(packed.skeys[lead], packed.lens[lead],
+                              len(lead))
+        return tuple((int(df[j]), w) for j, w in zip(lead, words))
     return tuple(sorted(((c, w) for w, c in dfm.items()),
                         key=lambda r: (-r[0], r[1]))[:k])
-
-
-def _word_spans(table):
-    from dsi_tpu.ops.wordcount import decode_packed
-
-    packed = table.finalize_packed()
-    words = decode_packed(packed.skeys, packed.lens, len(packed.skeys))
-    for i, w in enumerate(words):
-        yield w, int(packed.starts[i]), int(packed.ends[i])
 
 
 # ── stage-commit payloads ─────────────────────────────────────────────

@@ -23,7 +23,12 @@ Stage kinds (what the driver knows how to run):
 * ``df_topk``       — k-row document-frequency snapshot off an upstream
   indexer's resident :class:`DeviceTopK` (no drain-to-host).
 * ``postings_join`` — per-term postings lookup for an upstream df_topk's
-  terms (selective decode, not the full materialization).
+  terms (selective decode, not the full materialization).  To look them
+  up it groups the indexer's whole postings table once
+  (``merge.PackedPostings``); a plan that has such a stage ends with that
+  table as ``PlanResult.index``, which ``planrun --chain indexer``
+  commits as the inverted index (``mr-out-<r>``, one line a term,
+  ``<word> <n> <doc>,<doc>,...``) beside ``plan-join.json``.
 * ``top_k``         — k highest-count words of an upstream wordcount's
   result (count desc, word asc) — a host reduction over an
   already-host value, no engine.
@@ -202,7 +207,8 @@ def indexer_join_plan(docs: Sequence[bytes], *, topk: int = 16,
                       **defaults) -> Plan:
     """indexer → df-top-k → per-term postings join: stage 2 takes a
     k-row snapshot of the resident df table (no drain), stage 3 decodes
-    postings for just those k terms."""
+    postings for just those k terms, out of the whole table it groups
+    once: the run's ``PlanResult.index``, the inverted index."""
     p = Plan("indexer-join", **defaults)
     i = p.add(Stage("indexer", "indexer", docs=list(docs), topk=topk))
     t = p.add(Stage("dftopk", "df_topk", deps=[i.name], topk=topk))

@@ -602,3 +602,79 @@ def test_untraced_stream_step_builds_no_span_without_a_sink(
     for name in ("dispatch", "finish", "enqueue"):
         assert names.count(name) == ps["steps"], name
     assert names.count("job") == names.count("start") == 1
+
+
+# ── the indexer chain: the wave walk's spans and counters ──────────────
+
+
+def test_indexer_chain_records_its_new_spans_and_counters(fresh_tracer,
+                                                          tmp_path):
+    """``planrun --chain indexer --trace-dir``: the wave program's call is
+    an ``enqueue`` inside ``dispatch`` (or a ``replay``), the table is
+    grouped once in a ``group`` span inside the stage that first needs it,
+    the index commit is a ``write`` with ``format`` / ``commit`` inside;
+    the seconds are the stats keys, and the counters say what was walked."""
+    pytest.importorskip("jax")
+    from dsi_tpu.cli import planrun
+    from dsi_tpu.obs.registry import COUNTER_KEYS, PHASE_KEYS
+
+    docs = ensure_corpus(str(tmp_path / "inputs"), n_files=3,
+                         file_size=30_000)
+    # a fourth, shorter document: a second chunk size
+    short = tmp_path / "inputs" / "short.txt"
+    with open(docs[0], "rb") as f:
+        short.write_bytes(f.read(9_000))
+    docs.append(str(short))
+    err = io.StringIO()
+    with contextlib.redirect_stderr(err), \
+            contextlib.redirect_stdout(io.StringIO()):
+        rc = planrun.main(["--chain", "indexer", "--devices", "1",
+                           "--nreduce", "3", "--u-cap", "256", "--stats",
+                           "--workdir", str(tmp_path / "wd"),
+                           "--trace-dir", str(tmp_path / "trace"), *docs])
+    assert rc == 0, err.getvalue()[-2000:]
+    m = re.search(r"^planrun: pipeline_stats=(\{.*\})$", err.getvalue(),
+                  re.M)
+    ps = ast.literal_eval(m.group(1))
+    walk = ps["stages"]["indexer"]
+    new_counters = {"docs", "waves_by_size", "wave_doc_bytes",
+                    "wave_chunk_bytes", "postings_rows", "index_terms"}
+    assert new_counters <= set(walk) and new_counters <= set(COUNTER_KEYS)
+    assert {"group_s", "enqueue_s", "dispatch_s", "retire_s"} <= set(walk)
+    assert "group_s" in PHASE_KEYS
+    sizes = [os.path.getsize(d) for d in docs]
+    assert walk["docs"] == walk["waves"] == 4
+    assert walk["wave_doc_bytes"] == sum(sizes) == walk["bytes_in"]
+    assert walk["waves_by_size"] == {32768: 3, 16384: 1}
+    assert walk["wave_chunk_bytes"] == 3 * 32768 + 16384
+    assert walk["replays"] >= 1              # wider than 256 words
+    assert walk["postings_rows"] >= walk["index_terms"] > 256
+    assert ps["write_rows_packed"] == walk["index_terms"]
+    assert ps["write_rows_dict"] == 0
+
+    _, events = _jsonl(str(tmp_path / "trace" / "trace.jsonl"))
+    spans = {e["id"]: e for e in events if e["ph"] == "X"}
+    assert {e["name"] for e in spans.values()} <= obs_trace.SPAN_NAMES
+    parents = {}
+    for e in spans.values():
+        up = spans.get(e["parent"])
+        parents.setdefault(e["name"], set()).add(up and up["name"])
+    assert parents["enqueue"] <= {"dispatch", "replay"}
+    assert "dispatch" in parents["enqueue"]
+    assert parents["upload"] == parents["enqueue"]
+    assert parents["group"] == {"plan"}
+    assert parents["format"] == parents["commit"] == {"write"}
+    (group,) = [e for e in spans.values() if e["name"] == "group"]
+    assert group["rows"] == walk["postings_rows"]
+    assert group["terms"] == walk["index_terms"]
+    assert walk["group_s"] == pytest.approx(group["dur"], abs=5e-4)
+    for name, key in (("enqueue", "enqueue_s"), ("dispatch", "dispatch_s"),
+                      ("finish", "retire_s")):
+        total = sum(e["dur"] for e in spans.values() if e["name"] == name)
+        assert walk[key] == pytest.approx(total, abs=5e-4), name
+    (write,) = [e for e in spans.values() if e["name"] == "write"]
+    assert write["keys"] == walk["index_terms"] and write["bytes"] > 0
+    assert ps["write_s"] == pytest.approx(write["dur"], abs=5e-4)
+    # the wave program has its own name in a device trace
+    enq = [e for e in spans.values() if e["name"] == "enqueue"]
+    assert {e["program"] for e in enq} == {"idx_wave_step"}
