@@ -19,7 +19,7 @@ This module keeps the merged table ON DEVICE instead:
   per-device tables are disjoint and a host drain is a concatenation.
 * ``fold``: ONE compiled program (cached via ``backends/aotcache`` under
   ``aot``) merges a step's packed reduce output into the table in place:
-  concat + lexicographic sort (``lex_sort``) + run detection + segment-sum —
+  concat + lexicographic sort (``lex_sort``) + run detection + per-run sum —
   the same grouping idiom as the kernels' reduce, at table+step size.
   The table arrays are DONATED to the fold, so XLA updates the table in
   place and table residency never doubles; the step tensor is NOT

@@ -14,8 +14,9 @@ the TPU execution model rather than translated:
 * grouping is by **exact word bytes**, not by hash: each token's first
   ``max_word_len`` bytes are packed big-endian into ``max_word_len/4``
   ``uint32`` lanes and grouped with a multi-key lexicographic ``lax.sort`` +
-  segment-sum — no collision risk, and the packed keys double as the exact
-  word bytes for host-side detokenization (SURVEY.md §7 hard part 1),
+  per-run sums (a prefix sum read at the run starts) — no collision risk,
+  and the packed keys double as the exact word bytes for host-side
+  detokenization (SURVEY.md §7 hard part 1),
 * the partition hash is FNV-1a 32-bit, bit-identical to the reference's
   ``ihash`` (``mr/worker.go:33-37``), computed on-device per *unique* word.
 
@@ -200,7 +201,9 @@ def compact_positions(mask: jax.Array, size: int,
     with a ``scatter-add`` of one update per input position, 64-bit
     under the scoped x64 flag of this package's programs, and that
     emulated scatter cost 66-89 ns a position on a TPU v5e where a sort
-    pass costs about 1 (PERF.md, PR 35)."""
+    pass costs about 1 (PERF.md, PR 35).  The sums over sorted ids went
+    the same way in PR 39: :func:`group_sorted`, and the block starts of
+    ``parallel/shuffle.shuffle_rows``."""
     (m,) = mask.shape
     if m >= 1 << 31:
         raise ValueError(f"compact_positions: {m} positions overflow int32")
@@ -212,23 +215,53 @@ def compact_positions(mask: jax.Array, size: int,
     return jnp.where(key < m, key, jnp.int32(fill_value))
 
 
+def running_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a 1-D integer array in its OWN dtype: the
+    adds are modular, as a ``segment_sum``'s are, so differences of it
+    are exact wherever the difference itself fits.
+
+    32-bit and narrower: ``jnp.cumsum``.  64-bit (the device table's
+    counts; call it under the x64 scope that made them): three 32-bit
+    scans, since the chip emulates 64-bit integers.  The low halves are
+    summed modulo 2^32; each step of that sum wraps at most once, and
+    exactly when the sum falls, so a scan of the falls counts the carries
+    into the high halves' sum.  On a TPU v5e at 1,310,720 rows this runs
+    in 0.80 ms and compiles in 8 s, where ``jnp.cumsum`` of ``uint64``
+    runs in 1.44 ms and compiles in 50-96 s and a
+    ``lax.associative_scan`` over (lo, hi) pairs takes 3.3 ms
+    (``scripts/segsum_micro.py``; PERF.md, PR 39)."""
+    if x.dtype.itemsize < 8:
+        return jnp.cumsum(x, dtype=x.dtype)
+    lo = jnp.cumsum(x.astype(jnp.uint32), dtype=jnp.uint32)
+    hi = jnp.cumsum((x >> 32).astype(jnp.uint32), dtype=jnp.uint32)
+    fell = lo < jnp.concatenate([jnp.zeros((1,), jnp.uint32), lo[:-1]])
+    hi = hi + jnp.cumsum(fell, dtype=jnp.uint32)
+    return ((hi.astype(jnp.uint64) << 32) | lo.astype(jnp.uint64)).astype(
+        x.dtype)
+
+
 @jax.named_scope("group")
 def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     """Group adjacent equal rows of lexicographically sorted key columns.
 
-    The shared reduce idiom (run-boundary detect + segment-sum + compact)
+    The shared reduce idiom (run-boundary detect + per-run sum + compact)
     used by the single-chunk kernel, by the sharded all_to_all merge
     (parallel/shuffle.py) and by the device table's folds
-    (device/table.py).  The segment-sum runs over sorted 32-bit ids; the
-    compaction of the run starts is :func:`compact_positions` (one
-    single-key int32 sort, no scatter).  ``skeys_cols``: k sorted
-    unsigned key columns (uint32 lanes or uint64 packed lane pairs), PAD
-    rows last — a pad row is all-ones in every lane, i.e. the dtype's max
-    in every column; ``counts``: per-row counts to sum within each group.
+    (device/table.py).  No scatter: the compaction of the run starts is
+    :func:`compact_positions` (one single-key int32 sort), and a run's
+    total is the prefix sum of the counts (:func:`running_sum`, in the
+    counts' own dtype) read at the run's start and at the next run's and
+    differenced, the last run ending where the valid rows end.  Sums are
+    modular, so a difference is exact wherever the total itself fits.
+    ``skeys_cols``: k sorted unsigned key columns (uint32 lanes or uint64
+    packed lane pairs), PAD rows last — a pad row is all-ones in every
+    lane, i.e. the dtype's max in every column; ``counts``: per-row
+    counts to sum within each group.
 
     Returns (keys2d [t,k], totals [out_cap], upos [out_cap] int32, ovalid
     [out_cap], n_unique) — callers gather their payloads at ``upos`` and
-    mask with ``ovalid``.
+    mask with ``ovalid``.  With ``n_unique > out_cap`` the first
+    ``out_cap`` runs are returned whole.
     """
     t = skeys_cols[0].shape[0]
     k = len(skeys_cols)
@@ -241,12 +274,20 @@ def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
             [jnp.full((1, k), pad, dtype), keys[:-1]], axis=0)
     is_new = jnp.any(keys != prev, axis=1) & valid
     n_unique = jnp.sum(is_new, dtype=jnp.int32)
-    uid = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    totals = jax.ops.segment_sum(
-        jnp.where(valid, counts, 0), jnp.where(valid, uid, out_cap),
-        num_segments=out_cap + 1, indices_are_sorted=True)[:out_cap]
-    upos = compact_positions(is_new, out_cap, t - 1)
-    ovalid = jnp.arange(out_cap, dtype=jnp.int32) < n_unique
+    # One start more than out_cap: where the last returned run ends when
+    # there are more runs than fit.
+    starts = compact_positions(is_new, out_cap + 1, t - 1)
+    upos = starts[:out_cap]
+    live = jnp.arange(out_cap + 1, dtype=jnp.int32) < n_unique
+    ovalid = live[:out_cap]
+    bounds = jnp.where(live, starts, jnp.int32(t))
+    with enable_x64(True):  # the counts may be 64-bit
+        zero = jnp.zeros((), counts.dtype)
+        csum = running_sum(jnp.where(valid, counts, zero))
+        # The sum of every row before a boundary; pad rows add 0, so the
+        # boundary t reads the sum of the valid rows.
+        before = jnp.where(bounds > 0, csum[jnp.maximum(bounds - 1, 0)], zero)
+        totals = jnp.where(ovalid, before[1:] - before[:-1], zero)
     return keys, totals, upos, ovalid, n_unique
 
 

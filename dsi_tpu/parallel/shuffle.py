@@ -7,7 +7,7 @@ This is the multi-device redesign of the reference's whole data path:
 * shuffle    = ``jax.lax.all_to_all`` over the device mesh, replacing the
   NxM ``mr-<m>-<r>`` intermediate files on a shared filesystem
   (``mr/worker.go:81-92, 102-121``) — the exchange rides ICI, not disk,
-* reduce     = per-device sort + segment-sum of the received records,
+* reduce     = per-device sort + per-run sum of the received records,
   replacing the reduce task's decode/sort/group/count
   (``mr/worker.go:110-146``).
 
@@ -75,15 +75,19 @@ def shuffle_rows(rows: jax.Array, dest: jax.Array, *, n_dev: int,
     per-destination block of the same size can never overflow — then one
     ``lax.all_to_all``.  ``dest`` must be ``n_dev`` for invalid rows (they
     are parked on the scatter's overflow row and dropped).  Pad rows carry
-    key ``0xFFFFFFFF``, which sorts after every real ASCII word.
+    key ``0xFFFFFFFF``, which sorts after every real ASCII word.  The
+    placement is the one scatter: where a destination's block starts in
+    the sorted rows is a count of the rows bound for a lower destination
+    (``n_dev + 1`` compare-and-sum reductions in int32), where a
+    ``jnp.bincount`` was a 64-bit ``scatter-add`` of one update a row
+    under the x64 scope (PERF.md, PR 39).
     """
     p = rows.shape[1] - k
     order = jnp.argsort(dest, stable=True)
     sdest = dest[order]
     srows = rows[order]
-    counts = jnp.bincount(sdest, length=n_dev + 1).astype(jnp.int32)
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
+    bins = jnp.arange(n_dev + 1, dtype=dest.dtype)
+    starts = jnp.sum(dest[None, :] < bins[:, None], axis=1, dtype=jnp.int32)
     pos_in = jnp.arange(u_cap, dtype=jnp.int32) - starts[sdest]
     flat = jnp.where(sdest < n_dev, sdest * u_cap + pos_in, n_dev * u_cap)
     pad_row = jnp.concatenate(

@@ -288,9 +288,9 @@ def test_compact_positions_jaxpr_holds_no_scatter():
         lambda x: jnp.nonzero(x, size=300, fill_value=999)[0], mask) != []
 
 
-def test_sort_grouper_jaxpr_holds_one_scatter():
-    """The word-count program with the sort grouper scatters once: the
-    ``segment_sum`` of ``group_sorted`` (sorted 32-bit ids)."""
+def test_sort_grouper_jaxpr_holds_no_scatter():
+    """The word-count program with the sort grouper scatters nowhere:
+    ``group_sorted``'s totals are a prefix sum read at the run starts."""
     import functools
 
     import jax.numpy as jnp
@@ -298,7 +298,209 @@ def test_sort_grouper_jaxpr_holds_one_scatter():
     from dsi_tpu.ops.wordcount import tokenize_group_core
 
     fn = functools.partial(tokenize_group_core, u_cap=64, grouper="sort")
-    assert _scatters(fn, jnp.zeros(256, jnp.uint8)) == ["scatter-add"]
+    assert _scatters(fn, jnp.zeros(256, jnp.uint8)) == []
+
+
+@pytest.mark.parametrize("mesh_fold", [False, True])
+def test_fold_programs_jaxpr_hold_no_scatter_add(mesh_fold):
+    """The device table's fold programs over a one-device mesh: no
+    ``scatter-add`` (``group_sorted``'s totals, the exchange's block
+    starts).  The mesh fold keeps the exchange's one placement
+    ``scatter``; the plain fold has no scatter at all."""
+    import functools
+
+    from dsi_tpu.device import table
+    from dsi_tpu.parallel.shuffle import default_mesh
+
+    mesh = default_mesh(1)
+    cap, kk, rows = 64, 4, 32
+    args = (*table._table_structs(1, cap, kk),
+            *table._step_structs(1, rows, kk))
+    if mesh_fold:
+        fn = functools.partial(table._mesh_fold_impl, mesh=mesh, n_shards=1)
+        args += (table._apply_struct(1),)
+    else:
+        fn = functools.partial(table._fold_impl, mesh=mesh)
+    assert _scatters(fn, *args) == (["scatter"] if mesh_fold else [])
+
+
+def test_shuffle_rows_jaxpr_holds_one_placement_scatter():
+    """``shuffle_rows`` scatters once, the send buffer's placement
+    (``sendbuf.at[flat].set``), and adds nowhere: the block starts are
+    compare-and-sum reductions."""
+    import functools
+
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from dsi_tpu.parallel.shuffle import AXIS, default_mesh, shuffle_rows
+    from dsi_tpu.utils.jaxcompat import shard_map
+
+    fn = shard_map(
+        functools.partial(shuffle_rows, n_dev=1, u_cap=16, k=2),
+        mesh=default_mesh(1), in_specs=(P(AXIS, None), P(AXIS)),
+        out_specs=P(AXIS, None))
+    assert _scatters(fn, jnp.zeros((16, 5), jnp.uint32),
+                     jnp.zeros((16,), jnp.int32)) == ["scatter"]
+
+
+# ── group_sorted's totals: a prefix sum read at the run starts ─────────
+
+
+def _sorted_runs(case: str, t: int, out_cap: int):
+    """Run lengths of the valid rows (they sum to at most ``t``)."""
+    import numpy as np
+
+    rng = np.random.default_rng(len(case))
+    if case == "no_valid":
+        return []
+    if case == "one_run":
+        return [t]
+    if case == "singletons":
+        return [1] * t
+    if case == "pads_after_first":
+        return [1]
+    n = out_cap if case == "fits_exactly" else out_cap + 9
+    return rng.integers(1, 4, n).tolist()
+
+
+_TOTALS_CASES = [
+    (dtype, case, x64)
+    for dtype in ("int32", "uint32", "uint64")
+    for case in ("no_valid", "one_run", "singletons", "fits_exactly",
+                 "overflows", "pads_after_first")
+    for x64 in (False, True)
+    if x64 or dtype != "uint64"]  # 64-bit arrays exist under the scope only
+
+
+@pytest.mark.parametrize("dtype,case,x64", _TOTALS_CASES)
+def test_group_sorted_totals_equal_segment_sum(dtype, case, x64):
+    """``group_sorted``'s totals against the ``jax.ops.segment_sum`` they
+    replace and against numpy.  The counts are large: 32-bit running sums
+    pass 2^31 many times while a short run's total fits, 64-bit ones (near
+    2^63) wrap at every other row, so the differences of the modular
+    prefix sum must still be exact.  With more runs than ``out_cap`` the
+    first ``out_cap`` come back whole."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dsi_tpu.ops.wordcount import group_sorted
+    from dsi_tpu.utils.jaxcompat import enable_x64
+
+    t = 128 if case != "singletons" else 40
+    out_cap = 40
+    runs = _sorted_runs(case, t, out_cap)
+    n_valid = sum(runs)
+    assert n_valid <= t
+    rng = np.random.default_rng(7)
+    rid = np.repeat(np.arange(len(runs)), runs)
+    lanes = np.full((t, 2), 0xFFFFFFFF, np.uint32)
+    lanes[:n_valid, 0] = rid >> 2
+    lanes[:n_valid, 1] = (rid & 3) * 1000
+    if dtype == "uint64":
+        c = (np.uint64(1 << 63) - rng.integers(1, 1 << 40, t).astype(
+            np.uint64))
+    else:
+        c = rng.integers(1 << 24, 1 << 27, t).astype(dtype)
+    c[n_valid:] = 0
+    starts = np.concatenate([[0], np.cumsum(runs)[:-1]]).astype(int)
+    want = np.zeros(out_cap, dtype)
+    k = min(len(runs), out_cap)
+    with np.errstate(over="ignore"):
+        if runs:
+            want[:k] = np.add.reduceat(c[:n_valid], starts)[:k]
+
+    with enable_x64(x64):
+        counts = jnp.asarray(c)
+        cols = (jnp.asarray(lanes[:, 0]), jnp.asarray(lanes[:, 1]))
+        keys, totals, upos, ovalid, n_unique = jax.jit(
+            group_sorted, static_argnums=2)(cols, counts, out_cap)
+        assert totals.dtype == counts.dtype and totals.shape == (out_cap,)
+        assert int(n_unique) == len(runs)
+        np.testing.assert_array_equal(np.asarray(totals), want)
+        np.testing.assert_array_equal(
+            np.asarray(ovalid), np.arange(out_cap) < len(runs))
+        np.testing.assert_array_equal(np.asarray(upos)[:k], starts[:k])
+        assert (np.asarray(upos)[k:] == t - 1).all()
+        uid = np.full(t, out_cap, np.int32)
+        uid[:n_valid] = np.minimum(rid, out_cap)
+        ref = jax.ops.segment_sum(
+            counts, jnp.asarray(uid),
+            num_segments=out_cap + 1, indices_are_sorted=True)[:out_cap]
+        np.testing.assert_array_equal(np.asarray(totals), np.asarray(ref))
+
+
+@pytest.mark.parametrize("dtype", ["int32", "uint32", "int64", "uint64"])
+def test_running_sum_equals_numpy_cumsum(dtype):
+    """``running_sum`` against ``np.cumsum`` in the same dtype (wrapping):
+    the 64-bit form's three 32-bit scans carry from the low halves into
+    the high ones at every wrap, and a zero addend carries nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dsi_tpu.ops.wordcount import running_sum
+    from dsi_tpu.utils.jaxcompat import enable_x64
+
+    rng = np.random.default_rng(3)
+    info = np.iinfo(dtype)
+    x = rng.integers(info.min, info.max, 1000, dtype=dtype, endpoint=True)
+    x[rng.random(1000) < 0.3] = 0
+    x[500:520] = info.max  # low halves all ones: a carry at every step
+    with np.errstate(over="ignore"):
+        want = np.cumsum(x, dtype=dtype)
+    with enable_x64(True):
+        got = jax.jit(running_sum)(jnp.asarray(x))
+        assert got.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("case", ["empty_destination", "one_takes_all",
+                                  "parked_only"])
+def test_shuffle_rows_blocks_equal_numpy_routing(case, n_dev):
+    """``shuffle_rows`` against a numpy routing: receiver ``r`` gets, per
+    source in device order, that source's rows bound for ``r`` in their
+    order, then pad rows to ``u_cap``; parked rows (``dest == n_dev``)
+    leave nowhere."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+
+    from dsi_tpu.parallel.shuffle import AXIS, default_mesh, shuffle_rows
+    from dsi_tpu.utils.jaxcompat import shard_map
+
+    u_cap, k, p = 24, 2, 3
+    rng = np.random.default_rng(n_dev)
+    rows = rng.integers(0, 1 << 30, (n_dev, u_cap, k + p)).astype(np.uint32)
+    if case == "parked_only":
+        dest = np.full((n_dev, u_cap), n_dev)
+    elif case == "one_takes_all":
+        dest = np.zeros((n_dev, u_cap), int)
+    else:  # the last destination gets nothing; a fifth of the rows park
+        dest = rng.integers(0, max(n_dev - 1, 1), (n_dev, u_cap))
+        dest[dest == n_dev - 1] = n_dev  # one device: its only destination
+        dest[rng.random((n_dev, u_cap)) < 0.2] = n_dev
+    pad_row = np.array([0xFFFFFFFF] * k + [0] * p, np.uint32)
+    want = np.broadcast_to(pad_row, (n_dev, n_dev, u_cap, k + p)).copy()
+    for r in range(n_dev):
+        for s in range(n_dev):
+            mine = rows[s][dest[s] == r]
+            want[r, s, :len(mine)] = mine
+
+    def body(rows, dest):
+        return shuffle_rows(rows[0], dest[0], n_dev=n_dev, u_cap=u_cap,
+                            k=k)[None]
+
+    fn = jax.jit(shard_map(
+        body, mesh=default_mesh(n_dev),
+        in_specs=(P(AXIS, None, None), P(AXIS, None)),
+        out_specs=P(AXIS, None, None)))
+    got = fn(jnp.asarray(rows), jnp.asarray(dest.astype(np.int32)))
+    np.testing.assert_array_equal(
+        np.asarray(got), want.reshape(n_dev, n_dev * u_cap, k + p))
 
 
 _N = 256  # one chunk; t_cap = _N // 4 + 1 = 65 tokens
