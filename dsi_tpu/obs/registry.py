@@ -41,6 +41,15 @@ that phase):
   table grouped into the index (``merge.PostingsTable.finalize_packed``:
   one lexsort over the key lanes and the run detection), once a job,
   when the first stage that needs the whole table asks for it
+* ``pack_s``             — the ``pack`` spans of a packed index walk
+  (``planrun --chain indexer --pack-docs``): one wave's chunks joined
+  from whole documents and its vector of ordinals built
+  (``grepstream.pack_chunk``), on the producer thread, inside
+  ``materialize_s``
+* ``read_s``             — ``planrun --chain indexer`` reading its
+  documents whole before the first stage (the ``read`` span around the
+  plan's construction; at the top of its ``pipeline_stats``, beside
+  ``write_s``)
 * ``write_s``            — writing the partitioned ``mr-out-*`` (the
   CLI's phase, not the engine's)
 * ``write_format_s`` / ``write_commit_s`` — inside it
@@ -96,7 +105,11 @@ wave walk adds ``docs`` (documents handed over), ``waves_by_size``
 ``wave_chunk_bytes`` (the documents' bytes and the padded bytes they
 were uploaded as: their ratio is how full the waves were),
 ``postings_rows`` and ``index_terms`` (the table the index is grouped
-from and the terms it holds).
+from and the terms it holds), ``pack_docs`` (whether the walk packs
+whole documents into its waves), ``wave_docs`` (documents dispatched in
+waves: ``docs`` unless a rung restarted the walk) and
+``docs_per_wave_max`` (the most one wave held: the devices' count
+unless the walk packs).
 
 Async/incremental checkpoint keys (``dsi_tpu/ckpt`` writer/delta —
 present when checkpointing is on): ``ckpt_async``/``ckpt_delta`` (the
@@ -267,6 +280,9 @@ PHASE_KEYS = (
     # ``merge.PostingsTable.finalize_packed``: the lexsort and the run
     # detection), in the indexer's scope
     "group_s",
+    # a packed index walk's packer, a wave at a time on the producer
+    # thread, and planrun --chain indexer reading its documents
+    "pack_s", "read_s",
 )
 
 #: The direct children of a stream command's root ``job`` span on its
@@ -316,6 +332,9 @@ COUNTER_KEYS = (
     # with: posting rows grouped, terms of the index
     "docs", "waves_by_size", "wave_doc_bytes", "wave_chunk_bytes",
     "postings_rows", "index_terms",
+    # whether it packs whole documents into its waves, the documents it
+    # dispatched in waves, the most one wave held
+    "pack_docs", "wave_docs", "docs_per_wave_max",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

@@ -112,6 +112,9 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # the indexer's postings table grouped into the index, once a job
     # (parallel/merge.py PostingsTable.finalize_packed)
     "group",
+    # a packed index walk's packer, a wave at a time on the producer
+    # thread, inside "materialize" (parallel/grepstream.py pack_chunk)
+    "pack",
 ))
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"

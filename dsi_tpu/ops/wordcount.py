@@ -61,6 +61,11 @@ from dsi_tpu.utils.jaxcompat import enable_x64, x64_scoped
 _FNV_OFFSET = 0x811C9DC5
 _FNV_PRIME = 0x01000193
 _PAD_KEY = 0xFFFFFFFF  # sorts after every real word (ASCII first byte < 0x80)
+#: The byte between the documents of a packed chunk: ASCII's record
+#: separator, a non-letter under 128, so it ends a word as a space does
+#: and raises no ``has_high``.  The packer rewrites the byte as a space
+#: where a document holds it (``parallel/grepstream.pack_chunk``).
+DOC_SEP = 0x1E
 
 
 def is_ascii_letter(b: jax.Array) -> jax.Array:
@@ -421,12 +426,23 @@ def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
 
 def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
                         u_cap: int = 1 << 17, t_cap_frac: int = 4,
-                        grouper: str = "sort"):
+                        grouper: str = "sort",
+                        doc_sep: Optional[int] = None):
     """Exact unique-word counts over one uint8 chunk (zero-padded tail).
 
     Returns (packed_u [u_cap, K] uint32, len_u [u_cap] i32, cnt_u [u_cap]
     i32, fnv_u [u_cap] u32, n_unique i32, max_len i32, has_high bool,
     token_overflow bool).
+
+    ``doc_sep`` (a non-letter byte under 128, :data:`DOC_SEP`) makes the
+    chunk a pack of whole documents with that byte between them
+    (``parallel/grepstream.pack_chunk``), and the group key (word,
+    document): one row for every distinct word of every document, and a
+    ninth result, ``doc_u`` [u_cap] int32, the row's document as its
+    place in the chunk.  A token's place is the count of separators
+    before its first byte, a prefix sum over the chunk read at
+    ``start_pos``, and it sorts as one more key lane behind the word's.
+    The sort grouper only; with ``None`` the program is what it was.
 
     ``grouper`` selects how identical tokens are grouped: ``"sort"`` (the
     default — lexicographic :func:`lex_sort`) or
@@ -470,6 +486,12 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
         valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
         lengths = jnp.where(valid, end_pos - start_pos + 1, 0)
         max_len = jnp.max(lengths, initial=0)
+        if doc_sep is not None:
+            # No letter is a separator, so the sum at a token's start
+            # counts the separators before it.
+            seps = jnp.cumsum(chunk == jnp.uint8(doc_sep), dtype=jnp.int32)
+            doc_lane = jnp.where(valid, seps[start_pos].astype(jnp.uint32),
+                                 jnp.uint32(_PAD_KEY))
     with jax.named_scope("pack"):
         c = chunk.astype(jnp.uint32)
         b32 = ((c << 24) | (_shift_left(c, 1) << 16)
@@ -480,6 +502,10 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
                       & _byte_mask(jnp.clip(lengths - 4 * j, 0, 4)),
                       jnp.uint32(_PAD_KEY))
             for j in range(k))
+
+    if doc_sep is not None and grouper != "sort":
+        raise ValueError("tokenize_group_core: a packed chunk groups with "
+                         f"the sort grouper, not {grouper!r}")
 
     if grouper == "hash":
         fnv_t = fnv1a32_packed(jnp.stack(packed_cols, axis=1), lengths,
@@ -495,23 +521,29 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
                 token_overflow | group_of)
 
     # Group identical words: lexicographic sort over the key lanes
-    # (lex_sort: one single-key pass per lane), then run boundaries.
-    *scols, slens = lex_sort(packed_cols, (lengths,))
+    # (lex_sort: one single-key pass per lane), then run boundaries.  A
+    # packed chunk's document lane sorts behind the word's.
+    docs = () if doc_sep is None else (doc_lane,)
+    *scols, slens = lex_sort(packed_cols + docs, (lengths,))
     skeys, totals, upos, ovalid, n_unique = group_sorted(
         tuple(scols), jnp.ones(t_cap, jnp.int32), u_cap)
     with jax.named_scope("group"):
         packed_u = jnp.where(ovalid[:, None], skeys[upos], jnp.uint32(0))
         len_u = jnp.where(ovalid, slens[upos], 0)
+        if doc_sep is not None:
+            docs = (packed_u[:, k].astype(jnp.int32),)
+            packed_u = packed_u[:, :k]
     fnv_u = fnv1a32_packed(packed_u, len_u, max_word_len)
     with jax.named_scope("tokenize"):
         has_high = jnp.any(chunk >= 128)
     return (packed_u, len_u, totals, fnv_u, n_unique, max_len, has_high,
-            token_overflow)
+            token_overflow, *docs)
 
 
 count_words_kernel = x64_scoped(jax.jit(
     tokenize_group_core,
-    static_argnames=("max_word_len", "u_cap", "t_cap_frac", "grouper")))
+    static_argnames=("max_word_len", "u_cap", "t_cap_frac", "grouper",
+                     "doc_sep")))
 
 
 def default_grouper() -> str:

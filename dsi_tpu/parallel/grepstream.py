@@ -101,6 +101,7 @@ from dsi_tpu.device.topk import DeviceHistogram, DeviceTopK, KeyCounts
 from dsi_tpu.obs import metrics_scope, span as _span
 from dsi_tpu.ops.grepk import is_literal_pattern
 from dsi_tpu.ops.wordcount import (
+    DOC_SEP,
     _PAD_KEY,
     _shift_left,
     compact_positions,
@@ -1129,7 +1130,8 @@ def warm_grepstream_aot(mesh: Mesh | None = None,
 
 def _idx_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
                      n_reduce: int, max_word_len: int, u_cap: int,
-                     t_cap_frac: int, grouper: str = "sort"):
+                     t_cap_frac: int, grouper: str = "sort",
+                     pack_docs: bool = False):
     """Per-device wave body: the word-count map prologue over its
     document with a (tf ≡ 1, doc, part) payload — one posting row per
     distinct word per document — routed by the shared shuffle primitive
@@ -1137,23 +1139,36 @@ def _idx_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
     minus the term frequency.  A second output carries the received
     rows with the doc lane dropped: DeviceTable's packed (keys, len,
     count, part) layout with count ≡ 1, i.e. the wave's
-    document-frequency increments ready to fold into the top-k table."""
+    document-frequency increments ready to fold into the top-k table.
+
+    ``pack_docs``: the chunk holds whole documents with ``DOC_SEP``
+    between them (:func:`pack_chunk`) and ``doc_id`` is the vector of
+    their ordinals in chunk order.  The prologue then groups by (word,
+    document), a row a pair, and a row's document lane is the ordinal
+    its place in the chunk names: the same rows, in another order, as
+    one wave a document gives."""
     k = max_word_len // 4
     chunk = chunk.reshape(-1)
-    doc = doc_id.reshape(())
+
+    if not pack_docs:
+        doc = doc_id.reshape(())
 
     with jax.named_scope("map"):
         packed_u, len_u, cnt_u, part, dest, (
-            n_unique, max_len, has_high, token_overflow) = map_prologue(
-            chunk, n_dev=n_dev, n_reduce=n_reduce,
-            max_word_len=max_word_len, u_cap=u_cap, t_cap_frac=t_cap_frac,
-            grouper=grouper)
+            n_unique, max_len, has_high, token_overflow), *doc_u = \
+            map_prologue(
+                chunk, n_dev=n_dev, n_reduce=n_reduce,
+                max_word_len=max_word_len, u_cap=u_cap,
+                t_cap_frac=t_cap_frac, grouper=grouper,
+                doc_sep=DOC_SEP if pack_docs else None)
 
     with jax.named_scope("shuffle"):
         rows = jnp.concatenate(
             [packed_u, len_u[:, None].astype(jnp.uint32),
              jnp.ones((u_cap, 1), jnp.uint32),
-             jnp.broadcast_to(doc.astype(jnp.uint32), (u_cap,))[:, None],
+             (doc_id.reshape(-1)[doc_u[0]].astype(jnp.uint32) if pack_docs
+              else jnp.broadcast_to(doc.astype(jnp.uint32),
+                                    (u_cap,)))[:, None],
              part[:, None]], axis=1)
         recv = shuffle_rows(rows, dest, n_dev=n_dev, u_cap=u_cap, k=k)
 
@@ -1182,14 +1197,15 @@ def _idx_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
 
 def _idx_wave_step_impl(chunks, doc_ids, *, n_dev: int, n_reduce: int,
                         max_word_len: int, u_cap: int, mesh: Mesh,
-                        t_cap_frac: int = 4, grouper: str = "sort"):
+                        t_cap_frac: int = 4, grouper: str = "sort",
+                        pack_docs: bool = False):
     body = functools.partial(_idx_device_step, n_dev=n_dev,
                              n_reduce=n_reduce, max_word_len=max_word_len,
                              u_cap=u_cap, t_cap_frac=t_cap_frac,
-                             grouper=grouper)
+                             grouper=grouper, pack_docs=pack_docs)
     return shard_map(
         body, mesh=mesh,
-        in_specs=(P(AXIS, None), P(AXIS)),
+        in_specs=(P(AXIS, None), P(AXIS, None) if pack_docs else P(AXIS)),
         out_specs=(P(AXIS, None, None), P(AXIS, None, None),
                    P(AXIS, None)))(chunks, doc_ids)
 
@@ -1201,7 +1217,7 @@ _IDX_DONATE = (0,)
 
 def _idx_program(*, n_dev: int, n_reduce: int, max_word_len: int,
                  u_cap: int, size: int, mesh: Mesh, t_cap_frac: int,
-                 grouper: str = "sort"):
+                 grouper: str = "sort", pack_docs: bool = False):
     from dsi_tpu.ops.wordcount import grouper_suffix
 
     def fn(chunk, ids):
@@ -1209,13 +1225,16 @@ def _idx_program(*, n_dev: int, n_reduce: int, max_word_len: int,
                                    n_reduce=n_reduce,
                                    max_word_len=max_word_len, u_cap=u_cap,
                                    mesh=mesh, t_cap_frac=t_cap_frac,
-                                   grouper=grouper)
+                                   grouper=grouper, pack_docs=pack_docs)
 
-    # The HLO module takes the traced function's name (``_grep_program``).
+    # The HLO module takes the traced function's name (``_grep_program``),
+    # packed or not: one wave program, one name in a device trace.
     fn.__name__ = fn.__qualname__ = "idx_wave_step"
     name = (f"idx_wave_d{n_dev}_r{n_reduce}_w{max_word_len}"
             f"_u{u_cap}_s{size}_f{t_cap_frac}")
     name += grouper_suffix(grouper)
+    if pack_docs:
+        name += "_pk"
     return name, fn
 
 
@@ -1227,6 +1246,75 @@ def _idx_fn(example_args, **kw):
         return aotcache.cached_compile(name, fn, example_args,
                                        donate_argnums=_IDX_DONATE,
                                        x64=True)
+
+
+def pack_docs_cap(size: int) -> int:
+    """The most documents one packed chunk of ``size`` bytes names: the
+    length of the wave program's vector of ordinals a device."""
+    return max(64, size // 256)
+
+
+def plan_packed_waves(doc_lens: Sequence[int], n_dev: int,
+                      chunk_bytes: int) -> List[Tuple[List[List[int]], int]]:
+    """``[(slots, chunk_size), ...]``: the waves of a packed walk, a
+    function of the documents' lengths alone (so a checkpoint's
+    confirmed-wave cursor means the same waves in every run).  ``slots``
+    holds a device's documents, in chunk order, for at most ``n_dev``
+    devices.
+
+    A document longer than ``chunk_bytes`` goes alone, as
+    ``tfidf.plan_waves`` has it: those first, longest first, ``n_dev`` a
+    wave, the chunk the power of two over the wave's longest.  Every
+    other document goes, in document order, into the open chunk of
+    ``chunk_bytes`` while it fits behind a separator byte and the chunk
+    names fewer than :func:`pack_docs_cap` documents; then the chunk
+    closes.  The chunks go ``n_dev`` a wave in the order they closed.  No
+    document is split."""
+    alone = sorted((i for i, n in enumerate(doc_lens) if n > chunk_bytes),
+                   key=lambda i: doc_lens[i], reverse=True)
+    waves: List[Tuple[List[List[int]], int]] = []
+    for w in range(0, len(alone), n_dev):
+        idxs = alone[w:w + n_dev]
+        longest = max(doc_lens[i] for i in idxs)
+        waves.append(([[i] for i in idxs],
+                      1 << max(8, int(longest).bit_length())))
+    most = pack_docs_cap(chunk_bytes)
+    chunks: List[List[int]] = []
+    used = chunk_bytes  # no chunk is open
+    for i, n in enumerate(doc_lens):
+        if n > chunk_bytes:
+            continue
+        if used + 1 + n > chunk_bytes or len(chunks[-1]) >= most:
+            chunks.append([])
+            used = -1  # the first document stands behind no separator
+        chunks[-1].append(i)
+        used += 1 + n
+    for w in range(0, len(chunks), n_dev):
+        waves.append((chunks[w:w + n_dev], chunk_bytes))
+    return waves
+
+
+_DOC_SEP_BYTE = bytes([DOC_SEP])
+
+
+def pack_chunk(docs: Sequence[bytes], slots: Sequence[Sequence[int]],
+               n_dev: int, size: int,
+               pad_id: int) -> Tuple[np.ndarray, np.ndarray]:
+    """One packed wave: the ``[n_dev, size]`` block, a device's documents
+    joined by ``DOC_SEP`` and zero-padded, and the ``[n_dev,
+    pack_docs_cap(size)]`` vector of their ordinals (``pad_id`` behind
+    them).  A document that holds the separator byte goes up with a
+    space in its place: both are non-letters under 128, so its words are
+    the same, and the count of separators before a byte stays the
+    document's place in the chunk."""
+    out = np.zeros((n_dev, size), dtype=np.uint8)
+    ids = np.full((n_dev, pack_docs_cap(size)), pad_id, dtype=np.int32)
+    for r, idxs in enumerate(slots):
+        joined = _DOC_SEP_BYTE.join(
+            bytes(docs[i]).replace(_DOC_SEP_BYTE, b" ") for i in idxs)
+        out[r, :len(joined)] = np.frombuffer(joined, dtype=np.uint8)
+        ids[r, :len(idxs)] = idxs
+    return out, ids
 
 
 class _AbortRung(Exception):
@@ -1265,13 +1353,14 @@ class IndexerStep(EngineStep):
                  checkpoint_async: Optional[bool] = None,
                  checkpoint_delta: Optional[bool] = None,
                  resume: bool = False, keep_services: bool = False,
-                 input_range: Optional[Tuple[int, int]] = None):
+                 input_range: Optional[Tuple[int, int]] = None,
+                 pack_docs: bool = False, chunk_bytes: int = 1 << 20):
         super().__init__()
         _indexer_setup(self, docs, mesh, n_reduce, max_word_len, u_cap,
                        depth, device_accumulate, sync_every, mesh_shards,
                        topk, stats, checkpoint_dir, checkpoint_every,
                        checkpoint_async, checkpoint_delta, resume,
-                       keep_services, input_range)
+                       keep_services, input_range, pack_docs, chunk_bytes)
 
     def _next_rung(self) -> bool:
         self._pipe.end()
@@ -1299,6 +1388,7 @@ def indexer_streaming(
         checkpoint_every: Optional[int] = None,
         checkpoint_async: Optional[bool] = None,
         checkpoint_delta: Optional[bool] = None, resume: bool = False,
+        pack_docs: bool = False, chunk_bytes: int = 1 << 20,
 ):
     """Whole-corpus inverted index over the mesh, waves of ``n_dev``
     documents, pipelined ``depth`` waves deep.
@@ -1338,6 +1428,18 @@ def indexer_streaming(
     widens after resume simply restarts wider, exactly as the
     uninterrupted walk would.  Resumed postings (incl. per-word order)
     and df top-k are bit-identical to an uninterrupted run.
+
+    ``pack_docs=True`` fills a wave with whole documents, a chunk of
+    ``chunk_bytes`` a device (:func:`plan_packed_waves`,
+    :func:`pack_chunk`), and the wave program groups by (word,
+    document): the collection of many small documents, where a document
+    a wave pays a dispatch and a pull for a few kilobytes.  The posting
+    rows are the same rows, so the postings are the same sets; within a
+    word the documents come in another order than the unpacked walk
+    gives, which no consumer reads (the index names a word's documents
+    sorted).  The capacity rungs count (word, document) pairs, the cursor
+    of a checkpoint counts the packed waves, and the checkpoint's
+    identity holds ``chunk_bytes``.
     """
     return IndexerStep(
         docs, mesh=mesh, n_reduce=n_reduce, max_word_len=max_word_len,
@@ -1346,14 +1448,16 @@ def indexer_streaming(
         stats=stats, checkpoint_dir=checkpoint_dir,
         checkpoint_every=checkpoint_every,
         checkpoint_async=checkpoint_async,
-        checkpoint_delta=checkpoint_delta, resume=resume).close()
+        checkpoint_delta=checkpoint_delta, resume=resume,
+        pack_docs=pack_docs, chunk_bytes=chunk_bytes).close()
 
 
 def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                    depth, device_accumulate, sync_every, mesh_shards,
                    topk, stats, checkpoint_dir, checkpoint_every,
                    checkpoint_async, checkpoint_delta, resume,
-                   keep_services=False, input_range=None):
+                   keep_services=False, input_range=None, pack_docs=False,
+                   chunk_bytes=1 << 20):
     """The engine body behind :class:`IndexerStep`: corpus-wide setup,
     then ``begin_rung`` (the former per-rung ``run``) arms the pipeline
     and attaches the lifecycle hooks to ``step``.
@@ -1379,9 +1483,13 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
     doc_lens = getattr(docs, "lengths", None)
     if doc_lens is None:
         doc_lens = [len(d) for d in docs]
-    waves = plan_waves(doc_lens, n_dev)
-    longest = max(doc_lens, default=1)
-    size_max = 1 << max(8, int(longest).bit_length())
+    if pack_docs:
+        waves = plan_packed_waves(doc_lens, n_dev, int(chunk_bytes))
+        size_max = max((size for _, size in waves), default=256)
+    else:
+        waves = plan_waves(doc_lens, n_dev)
+        longest = max(doc_lens, default=1)
+        size_max = 1 << max(8, int(longest).bit_length())
     n_real = len(docs)
     # Internal registry scope (dsi_tpu/obs); copied out to the caller's
     # ``stats`` dict when the walk ends, like pipeline_stats everywhere.
@@ -1396,11 +1504,19 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                # waves were)
                "waves_by_size": {}, "wave_doc_bytes": 0,
                "wave_chunk_bytes": 0,
+               # and the documents those waves held: all of them once,
+               # the most in one wave (n_dev unless the walk packs)
+               "pack_docs": bool(pack_docs), "wave_docs": 0,
+               "docs_per_wave_max": 0,
                "upload_s": 0.0, "enqueue_s": 0.0, "kernel_s": 0.0,
                "pull_s": 0.0, "merge_s": 0.0, "replay_s": 0.0})
-    groupers = grouper_ladder()
+    # (word, document) pairs fill a hash grouper's buckets several times
+    # as full as a document's words: the packed program has the sort only.
+    groupers = ("sort",) if pack_docs else grouper_ladder()
     sh_chunk = NamedSharding(mesh, P(AXIS, None))
-    sh_ids = NamedSharding(mesh, P(AXIS))
+    sh_ids = NamedSharding(mesh, P(AXIS, None) if pack_docs else P(AXIS))
+    if pack_docs:
+        st["pack_s"] = 0.0
 
     # ── checkpoint/restore (dsi_tpu/ckpt): wave-cursor variant ──
     ck_store: Optional[CheckpointStore] = None
@@ -1424,6 +1540,8 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
         if input_range is not None:
             ident["input_range"] = [int(input_range[0]),
                                     int(input_range[1])]
+        if pack_docs:  # the chunk size decides the packed plan
+            ident["pack_docs"] = int(chunk_bytes)
         ck_store = CheckpointStore(checkpoint_dir, "indexer", ident)
         if resume:
             loaded = ck_store.load_latest_chain()
@@ -1613,12 +1731,20 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
 
         def materialize():
             for idxs, size in waves[start_wave:]:
-                chunk_np = _wave_chunk(docs, idxs, n_dev, size)
-                ids_np = np.array(
-                    list(idxs) + [n_real] * (n_dev - len(idxs)),
-                    dtype=np.int32)
+                if pack_docs:
+                    held = [i for slot in idxs for i in slot]
+                    with _span("pack", lane="materialize", stats=st,
+                               key="pack_s", docs=len(held), size=size):
+                        chunk_np, ids_np = pack_chunk(docs, idxs, n_dev,
+                                                      size, n_real)
+                else:
+                    held = idxs
+                    chunk_np = _wave_chunk(docs, idxs, n_dev, size)
+                    ids_np = np.array(
+                        list(idxs) + [n_real] * (n_dev - len(idxs)),
+                        dtype=np.int32)
                 yield (size, chunk_np, ids_np,
-                       sum(doc_lens[i] for i in idxs))
+                       sum(doc_lens[i] for i in held), len(held))
 
         def wave_call(chunk_np, ids_np, size, cap, frac, g):
             with _span("upload", stats=st, key="upload_s"):
@@ -1630,15 +1756,18 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                        program="idx_wave_step", size=size, cap=cap):
                 fn = _idx_fn((chunk, ids), n_dev=n_dev, n_reduce=n_reduce,
                              max_word_len=mwl, u_cap=cap, size=size,
-                             mesh=mesh, t_cap_frac=frac, grouper=g)
+                             mesh=mesh, t_cap_frac=frac, grouper=g,
+                             pack_docs=pack_docs)
                 with _quiet_unusable_donation():
                     return fn(chunk, ids)
 
         def dispatch(item):
-            size, chunk_np, ids_np, doc_bytes = item
+            size, chunk_np, ids_np, doc_bytes, n_held = item
             st["waves_by_size"][size] = st["waves_by_size"].get(size, 0) + 1
             st["wave_doc_bytes"] += doc_bytes
             st["wave_chunk_bytes"] += n_dev * size
+            st["wave_docs"] += n_held
+            st["docs_per_wave_max"] = max(st["docs_per_wave_max"], n_held)
             rows, df, scal = wave_call(chunk_np, ids_np, size,
                                        state["cap"], state["frac"],
                                        state["grouper"])
@@ -1856,11 +1985,12 @@ def warm_indexer_aot(mesh: Mesh | None = None, sizes: Sequence[int] = (
         1 << 18,), n_reduce: int = 10, word_lens: Sequence[int] = (16,),
         caps: Sequence[int] = (1 << 14,), fracs: Sequence[int] = (4, 2),
         topk: int = DEFAULT_TOPK, device_accumulate: bool = False,
-        mesh_shards: int = 0) -> None:
+        mesh_shards: int = 0, pack_docs: bool = False) -> None:
     """Compile + persist the ``idx_wave_*`` shapes an
     ``indexer_streaming`` run reaches at these wave sizes/capacities
-    (both grouper variants), plus — with ``device_accumulate`` — the
-    df top-k fold shapes.  From shape structs alone."""
+    (both grouper variants; the packed program has the sort grouper
+    only), plus — with ``device_accumulate`` — the df top-k fold
+    shapes.  From shape structs alone."""
     if mesh is None:
         mesh = default_mesh()
     n_dev = mesh.devices.size
@@ -1868,13 +1998,16 @@ def warm_indexer_aot(mesh: Mesh | None = None, sizes: Sequence[int] = (
     for mwl in word_lens:
         for cap in caps:
             for size in sizes:
+                ids = (n_dev, pack_docs_cap(size)) if pack_docs else (n_dev,)
                 examples = (sds((n_dev, size), jnp.uint8),
-                            sds((n_dev,), jnp.int32))
+                            sds(ids, jnp.int32))
                 for frac in fracs:
-                    for g in sorted(warm_groupers()):
+                    for g in ("sort",) if pack_docs else sorted(
+                            warm_groupers()):
                         _idx_fn(examples, n_dev=n_dev, n_reduce=n_reduce,
                                 max_word_len=mwl, u_cap=cap, size=size,
-                                mesh=mesh, t_cap_frac=frac, grouper=g)
+                                mesh=mesh, t_cap_frac=frac, grouper=g,
+                                pack_docs=pack_docs)
             if device_accumulate:
                 from dsi_tpu.device.topk import warm_topk_service
 

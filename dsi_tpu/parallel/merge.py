@@ -550,6 +550,55 @@ class PackedPostings:
         self._by_name = None
         return self
 
+    def _ranks_in_order(self, rank_of: np.ndarray):
+        """``(ranks, offs)`` straight from the table where every word's
+        documents already stand in name order, no name twice: what a walk
+        in document order over names that sort as their ordinals leaves
+        (``planrun --pack-docs`` over ``d00000.txt``, ``d00001.txt``,
+        ...), 10^7 postings checked in one pass and none moved.  ``(None,
+        None)`` where a word's documents do not."""
+        if not (self.ends > self.starts).all():
+            return None, None
+        rank_of = rank_of.astype(_index_dtype(len(rank_of)))
+        # A table in another order (a walk longest document first) shows
+        # it within its first words: the head is looked at alone before
+        # the whole is gathered.
+        for n in (min(len(self.docs), 1 << 14), len(self.docs)):
+            ranks = rank_of[self.docs[:n]]
+            falls = ranks[1:] <= ranks[:-1]
+            # a new word may start lower
+            falls[self.ends[:np.searchsorted(self.ends, n)] - 1] = False
+            if falls.any():
+                return None, None
+        offs = np.empty(len(self.skeys) + 1, np.int64)
+        offs[0] = 0
+        offs[1:] = self.ends
+        return ranks, offs
+
+    def _ranks_sorted(self, rank_of: np.ndarray, n_names: int):
+        """``(ranks, offs)`` by one sort: every posting as one number,
+        word * names + rank, in the narrowest index type (the arrays are
+        postings long, and what they allocate is most of a commit's
+        time): the sort orders every word's documents by name, and what
+        two documents of one name would repeat is dropped."""
+        n_words = len(self.skeys)
+        idx = _index_dtype(n_words * n_names)
+        pairs = np.repeat(np.arange(n_words, dtype=idx),
+                          self.ends - self.starts)
+        pairs *= idx(n_names)
+        pairs += rank_of.astype(idx)[self.docs]
+        pairs.sort()
+        if len(pairs):
+            fresh = np.empty(len(pairs), bool)
+            fresh[0] = True
+            np.not_equal(pairs[1:], pairs[:-1], out=fresh[1:])
+            if not fresh.all():
+                pairs = pairs[fresh]
+        word_of, ranks = np.divmod(pairs, idx(n_names))
+        offs = np.zeros(n_words + 1, np.int64)
+        np.cumsum(np.bincount(word_of, minlength=n_words), out=offs[1:])
+        return ranks, offs
+
     def _named_postings(self):
         """What :meth:`render_partition` reads, built once: every
         word's documents as ranks among the sorted, unique names,
@@ -570,28 +619,9 @@ class PackedPostings:
             rank = {name: j for j, name in enumerate(names)}
             rank_of = np.array([rank[name] for name in self.doc_names],
                                np.int64)
-            n_words = len(self.skeys)
-            # every posting as one number, word * names + rank, in the
-            # narrowest index type (the arrays are postings long, and
-            # what they allocate is most of a commit's time): one sort
-            # orders every word's documents by name, and what two
-            # documents of one name would repeat is dropped
-            idx = _index_dtype(n_words * len(names))
-            pairs = np.repeat(np.arange(n_words, dtype=idx),
-                              self.ends - self.starts)
-            pairs *= idx(len(names))
-            pairs += rank_of.astype(idx)[self.docs]
-            pairs.sort()
-            if len(pairs):
-                fresh = np.empty(len(pairs), bool)
-                fresh[0] = True
-                np.not_equal(pairs[1:], pairs[:-1], out=fresh[1:])
-                if not fresh.all():
-                    pairs = pairs[fresh]
-            word_of, ranks = np.divmod(pairs, idx(len(names)))
-            offs = np.zeros(n_words + 1, np.int64)
-            np.cumsum(np.bincount(word_of, minlength=n_words),
-                      out=offs[1:])
+            ranks, offs = self._ranks_in_order(rank_of)
+            if ranks is None:
+                ranks, offs = self._ranks_sorted(rank_of, len(names))
             raw = [name.encode("utf-8") + b"," for name in names]
             name_lens = np.array([len(b) for b in raw], np.int64)
             self._by_name = (

@@ -101,7 +101,7 @@ def shuffle_rows(rows: jax.Array, dest: jax.Array, *, n_dev: int,
 @jax.named_scope("map")
 def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
                  max_word_len: int, u_cap: int, t_cap_frac: int,
-                 grouper: str = "sort"):
+                 grouper: str = "sort", doc_sep: Optional[int] = None):
     """Shared per-device map phase: tokenize + combine + partition.
 
     The one place the reference-parity partition rule lives on device:
@@ -112,17 +112,20 @@ def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
     drift apart.
 
     Returns (packed_u, len_u, cnt_u, part, dest, scalars) where scalars =
-    (n_unique, max_len, has_high, token_overflow).
+    (n_unique, max_len, has_high, token_overflow); over a packed chunk
+    (``doc_sep``: ``tokenize_group_core``) a row is a (word, document)
+    pair and a seventh result, ``doc_u``, is its document's place in the
+    chunk.
     """
     (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
-     token_overflow) = tokenize_group_core(
+     token_overflow, *docs) = tokenize_group_core(
         chunk, max_word_len=max_word_len, u_cap=u_cap, t_cap_frac=t_cap_frac,
-        grouper=grouper)
+        grouper=grouper, doc_sep=doc_sep)
     uvalid = jnp.arange(u_cap, dtype=jnp.int32) < n_unique
     part = (fnv_u & jnp.uint32(0x7FFFFFFF)) % jnp.uint32(n_reduce)
     dest = jnp.where(uvalid, (part % n_dev).astype(jnp.int32), n_dev)
     return (packed_u, len_u, cnt_u, part, dest,
-            (n_unique, max_len, has_high, token_overflow))
+            (n_unique, max_len, has_high, token_overflow), *docs)
 
 
 def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,

@@ -673,7 +673,10 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
                            depth=kw["depth"],
                            device_accumulate=kw["device_accumulate"],
                            sync_every=kw["sync_every"],
-                           mesh_shards=kw["mesh_shards"])
+                           mesh_shards=kw["mesh_shards"],
+                           pack_docs=bool(plan.param(stage, "pack_docs",
+                                                     False)),
+                           chunk_bytes=kw["chunk_bytes"])
         res = _drive(step, i)
         _note_stage(sc, sp, stage, [books])
         if res is None:

@@ -204,13 +204,19 @@ def wordcount_topk_plan(k: int = 16, *,
 
 
 def indexer_join_plan(docs: Sequence[bytes], *, topk: int = 16,
-                      **defaults) -> Plan:
+                      pack_docs: bool = False, **defaults) -> Plan:
     """indexer → df-top-k → per-term postings join: stage 2 takes a
     k-row snapshot of the resident df table (no drain), stage 3 decodes
     postings for just those k terms, out of the whole table it groups
-    once: the run's ``PlanResult.index``, the inverted index."""
+    once: the run's ``PlanResult.index``, the inverted index.
+    ``pack_docs`` fills the indexer's waves with whole documents, a
+    chunk of the plan's ``chunk_bytes`` a device
+    (``IndexerStep(pack_docs=True)``); off, the stage and the plan's
+    signature are what they were."""
     p = Plan("indexer-join", **defaults)
-    i = p.add(Stage("indexer", "indexer", docs=list(docs), topk=topk))
+    packed = {"pack_docs": True} if pack_docs else {}
+    i = p.add(Stage("indexer", "indexer", docs=list(docs), topk=topk,
+                    **packed))
     t = p.add(Stage("dftopk", "df_topk", deps=[i.name], topk=topk))
     p.add(Stage("join", "postings_join", deps=[i.name, t.name]))
     return p

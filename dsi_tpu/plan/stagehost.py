@@ -104,7 +104,8 @@ def build_plan(spec: Dict):
         for path in files:
             with open(path, "rb") as f:
                 docs.append(f.read())
-        return indexer_join_plan(docs, **defaults)
+        return indexer_join_plan(docs, pack_docs=bool(
+            spec.get("pack_docs", False)), **defaults)
     raise ValueError(f"unknown chain {chain!r}")
 
 
