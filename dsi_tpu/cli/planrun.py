@@ -30,7 +30,16 @@ Chains:
               whole documents, --chunk-bytes a device, for a
               collection of many small documents (a page, a mail);
               a document longer than that still goes alone.  The
-              committed bytes are the same.
+              committed bytes are the same.  The documents are read
+              AHEAD of the walk: only their lengths are taken before
+              the first stage, and a pool of reader threads
+              (utils/ioread.ReadAheadDocs) fetches their bytes in the
+              order the waves will ask, while the device works.  A
+              file that cannot be read, or that grew or was cut after
+              its length was taken, fails the job (exit 1, nothing
+              committed).  --stats: read_s (the lengths), read_wait_s
+              (the walk held by a document not yet read),
+              read_ahead_hits / read_docs, read_threads.
 
 Elastic execution (ISSUE 16): ``--pipeline`` overlaps a grep→wordcount
 pair (the wordcount consumes relay buffers as they SEAL while the grep
@@ -80,7 +89,7 @@ def _plan_spec(args) -> dict:
             "pack_docs": args.pack_docs}
 
 
-def _run_hosts(args, spec: dict, mesh):
+def _run_hosts(args, spec: dict, plan, mesh):
     """``--hosts``: every stage in its OWN process with a PRIVATE
     working directory; inter-stage bytes move ONLY over TCP (net-served
     plan relays, ISSUE 18).  Spawns one ``plan.stagehost`` per stage in
@@ -94,10 +103,9 @@ def _run_hosts(args, spec: dict, mesh):
 
     from dsi_tpu.obs import metrics_scope
     from dsi_tpu.plan.driver import PlanResult, _load_commit, plan_index
-    from dsi_tpu.plan.stagehost import build_plan, fetch_stage_payload
+    from dsi_tpu.plan.stagehost import fetch_stage_payload
     from dsi_tpu.utils.atomicio import atomic_write
 
-    plan = build_plan(spec)
     order = plan.ordered()
     sc = metrics_scope("plan")
     sc.update({"plan_stages": len(order), "plan_intermediate_bytes": 0,
@@ -215,6 +223,18 @@ def _run_hosts(args, spec: dict, mesh):
 
 
 def main(argv=None) -> int:
+    # The indexer chain's documents are read by a pool of threads
+    # (``ioread.ReadAheadDocs``); this process runs job after job, and
+    # no reader thread outlives the call, however it ends.
+    opened: list = []
+    try:
+        return _main(argv, opened)
+    finally:
+        for docs in opened:
+            docs.close()
+
+
+def _main(argv, opened: list) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("files", nargs="+")
     p.add_argument("--chain",
@@ -224,9 +244,10 @@ def main(argv=None) -> int:
                    help="grep-wc commits the word counts of the matching "
                         "lines as mr-out-<r>; indexer commits the whole "
                         "inverted index as mr-out-<r> (a document is an "
-                        "input file) and writes plan-join.json; grep-grep "
-                        "and wc-topk write plan-grep.json / "
-                        "plan-topk.json")
+                        "input file, read ahead of the wave walk by a "
+                        "pool of reader threads) and writes "
+                        "plan-join.json; grep-grep and wc-topk write "
+                        "plan-grep.json / plan-topk.json")
     p.add_argument("--pattern", default=None,
                    help="literal grep pattern (required for grep-wc "
                         "and grep-grep)")
@@ -332,17 +353,22 @@ def main(argv=None) -> int:
     spec = _plan_spec(args)
 
     def build():
-        return build_plan(spec)
+        plan = build_plan(spec)
+        if args.chain == "indexer":
+            opened.append(plan.param(plan["indexer"], "docs"))
+        return plan
 
     stats: dict = {}
     read_stats: dict = {}
     try:
         if args.hosts:
-            res, stats = _run_hosts(args, spec, mesh)
+            res, stats = _run_hosts(args, spec, build(), mesh)
         else:
             if args.chain == "indexer":
-                # The chain's documents are read whole, here, before
-                # its first stage; the other chains' stages read theirs.
+                # What the chain's job pays for its documents before
+                # its first stage: their lengths.  Their bytes are read
+                # ahead of the walk (``read_wait_s`` is what the walk
+                # waits); the other chains' stages read theirs.
                 from dsi_tpu.obs import span
 
                 with span("read", lane="host", stats=read_stats,
@@ -355,6 +381,13 @@ def main(argv=None) -> int:
                            resume=args.resume, pipelined=args.pipeline,
                            stage_shards=args.stage_shards, stats=stats)
     except CheckpointMismatch as e:
+        print(f"planrun: {e}", file=sys.stderr)
+        return 1
+    except OSError as e:
+        if args.chain != "indexer" or args.hosts:
+            raise
+        # A document that cannot be read, or is not the bytes its
+        # length was taken from: nothing is committed.
         print(f"planrun: {e}", file=sys.stderr)
         return 1
     except PlanHostPath as e:
@@ -389,7 +422,9 @@ def main(argv=None) -> int:
               "plan": {k: v for k, v in stats.items()
                        if k != "stage_stats"}}
     if "read_s" in read_stats:
+        ahead = opened[0].stats
         pstats["read_s"] = round(read_stats["read_s"], 4)
+        pstats.update(ahead, read_wait_s=round(ahead["read_wait_s"], 4))
     committed = None  # what goes out as mr-out-<r>: a merged table
     if args.chain == "grep-wc":
         g = res.results["grep"]

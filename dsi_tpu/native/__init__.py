@@ -11,7 +11,8 @@ that), and the C parser defers to Python on any input it cannot prove it
 parsed completely, so native and pure runs can never diverge.
 
 The library is built from the committed sources only: the build writes a
-SHA-256 of ``kvcodec.cpp`` + ``wcjob.cpp`` beside the ``.so``, and a
+SHA-256 of ``kvcodec.cpp`` + ``wcjob.cpp`` + ``docread.cpp`` beside the
+``.so``, and a
 library whose recorded hash does not match the sources on disk is never
 loaded — it is rebuilt, or the process says on stderr that it runs the
 pure-Python data plane.  (File times say nothing: a copied tree resets
@@ -27,7 +28,7 @@ import struct
 import subprocess
 import sys
 import threading
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None | bool" = None  # None = not tried, False = absent
@@ -38,7 +39,8 @@ _SO_PATH = os.path.join(_REPO, "build", "libkvcodec.so")
 _HASH_PATH = _SO_PATH + ".sha256"
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = (os.path.join(_HERE, "kvcodec.cpp"),
-            os.path.join(_HERE, "wcjob.cpp"))
+            os.path.join(_HERE, "wcjob.cpp"),
+            os.path.join(_HERE, "docread.cpp"))
 
 
 def _source_hash() -> str:
@@ -125,6 +127,18 @@ def _load():
             lib.tfidf_map_file.argtypes = [
                 ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint32,
                 ctypes.POINTER(ctypes.c_size_t)]
+            lib.doc_file_lengths.restype = ctypes.c_long
+            lib.doc_file_lengths.argtypes = [
+                ctypes.POINTER(ctypes.c_char_p),
+                ctypes.POINTER(ctypes.c_int), ctypes.c_long,
+                ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_int)]
+            lib.doc_read_files.restype = ctypes.c_long
+            lib.doc_read_files.argtypes = [
+                ctypes.POINTER(ctypes.c_char_p),
+                ctypes.POINTER(ctypes.c_int),
+                ctypes.POINTER(ctypes.c_int64), ctypes.c_long,
+                ctypes.c_char_p, ctypes.POINTER(ctypes.c_int)]
             _lib = lib
         except (OSError, AttributeError) as e:
             # AttributeError: a stale .so predating a symbol and a failed
@@ -321,3 +335,43 @@ def tfidf_map_file(path: str, docname: str,
     except UnicodeEncodeError:
         return None
     return _call_arena("tfidf_map_file", args, n_reduce)
+
+
+def file_lengths(names: Sequence[bytes], dir_fds: Sequence[int]):
+    """The files' lengths in one call that holds no interpreter lock
+    (``docread.cpp``), named as for :func:`read_files`.  Returns
+    ``(lengths, bad, errno)``: -1, or the index of the first file that
+    has none.  None -> the caller stats them itself."""
+    lib = _load()
+    if lib is None:
+        return None
+    n = len(names)
+    out = (ctypes.c_int64 * n)()
+    err = ctypes.c_int()
+    bad = lib.doc_file_lengths((ctypes.c_char_p * n)(*names),
+                               (ctypes.c_int * n)(*dir_fds), n, out,
+                               ctypes.byref(err))
+    return list(out), bad, err.value
+
+
+def read_files(names: Sequence[bytes], dir_fds: Sequence[int],
+               lengths: Sequence[int]):
+    """Whole files in one call that holds no interpreter lock
+    (``docread.cpp``): file ``k`` is ``names[k]`` from the directory
+    open as ``dir_fds[k]`` (-1: the name is a path) and had
+    ``lengths[k]`` bytes when the job began.  Returns ``(data, bad,
+    errno)``: the files' bytes one behind the other, and -1, or the
+    index of the first file that could not be read (``errno``) or was
+    no longer its length (``errno`` 0).  None -> the caller reads them
+    itself (library unavailable)."""
+    lib = _load()
+    if lib is None:
+        return None
+    n = len(names)
+    out = ctypes.create_string_buffer(sum(lengths) + 1)
+    err = ctypes.c_int()
+    bad = lib.doc_read_files((ctypes.c_char_p * n)(*names),
+                             (ctypes.c_int * n)(*dir_fds),
+                             (ctypes.c_int64 * n)(*lengths), n, out,
+                             ctypes.byref(err))
+    return memoryview(out), bad, err.value

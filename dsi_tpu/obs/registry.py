@@ -46,10 +46,19 @@ that phase):
   from whole documents and its vector of ordinals built
   (``grepstream.pack_chunk``), on the producer thread, inside
   ``materialize_s``
-* ``read_s``             — ``planrun --chain indexer`` reading its
-  documents whole before the first stage (the ``read`` span around the
-  plan's construction; at the top of its ``pipeline_stats``, beside
-  ``write_s``)
+* ``read_s``             — what a ``planrun --chain indexer`` job
+  pays for its documents before its first stage (the ``read`` span
+  around the plan's construction; at the top of its ``pipeline_stats``,
+  beside ``write_s``): their lengths.  Their bytes are read AHEAD of the
+  walk, by the reader threads of ``utils/ioread.ReadAheadDocs``, in the
+  order the waves will ask
+* ``read_wait_s``        — the ``read_wait`` spans beside it: seconds a
+  caller of ``docs[i]`` was held by a document not yet read (the
+  walk's producer thread, before a wave's ``pack`` span and outside
+  it; a signature's CRC on the main thread under
+  ``--checkpoint-dir``).  ``read_docs`` counts the documents asked
+  for, each once, ``read_ahead_hits`` those that were there when first
+  asked for (their ratio is the hit share), ``read_threads`` the pool
 * ``write_s``            — writing the partitioned ``mr-out-*`` (the
   CLI's phase, not the engine's)
 * ``write_format_s`` / ``write_commit_s`` — inside it
@@ -281,8 +290,10 @@ PHASE_KEYS = (
     # detection), in the indexer's scope
     "group_s",
     # a packed index walk's packer, a wave at a time on the producer
-    # thread, and planrun --chain indexer reading its documents
-    "pack_s", "read_s",
+    # thread, and planrun --chain indexer reading its documents: what
+    # the job pays before its first stage, and what the walk then waits
+    # for a document its reader threads have not read yet
+    "pack_s", "read_s", "read_wait_s",
 )
 
 #: The direct children of a stream command's root ``job`` span on its
@@ -335,6 +346,10 @@ COUNTER_KEYS = (
     # whether it packs whole documents into its waves, the documents it
     # dispatched in waves, the most one wave held
     "pack_docs", "wave_docs", "docs_per_wave_max",
+    # its documents read ahead of it (utils/ioread.ReadAheadDocs; at the
+    # top of planrun's pipeline_stats): documents asked for, those that
+    # were there when first asked for, the pool's threads
+    "read_docs", "read_ahead_hits", "read_threads",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

@@ -115,6 +115,10 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # a packed index walk's packer, a wave at a time on the producer
     # thread, inside "materialize" (parallel/grepstream.py pack_chunk)
     "pack",
+    # the same thread held by a document of the wave that the reader
+    # threads have not read yet, before "pack" and outside it
+    # (utils/ioread.py ReadAheadDocs)
+    "read_wait",
 ))
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"

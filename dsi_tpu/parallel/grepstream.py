@@ -1491,6 +1491,11 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
         longest = max(doc_lens, default=1)
         size_max = 1 << max(8, int(longest).bit_length())
     n_real = len(docs)
+
+    def wave_ordinals(idxs) -> List[int]:
+        """A wave's documents, in the order its chunk is built."""
+        return [i for slot in idxs for i in slot] if pack_docs else idxs
+
     # Internal registry scope (dsi_tpu/obs); copied out to the caller's
     # ``stats`` dict when the walk ends, like pipeline_stats everywhere.
     st = metrics_scope("indexer")
@@ -1731,15 +1736,17 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
 
         def materialize():
             for idxs, size in waves[start_wave:]:
+                held = wave_ordinals(idxs)
+                # A lazy sequence is asked here, once a document and
+                # outside ``pack``: what it waits for is its own span.
+                wave = {i: docs[i] for i in held}
                 if pack_docs:
-                    held = [i for slot in idxs for i in slot]
                     with _span("pack", lane="materialize", stats=st,
                                key="pack_s", docs=len(held), size=size):
-                        chunk_np, ids_np = pack_chunk(docs, idxs, n_dev,
+                        chunk_np, ids_np = pack_chunk(wave, idxs, n_dev,
                                                       size, n_real)
                 else:
-                    held = idxs
-                    chunk_np = _wave_chunk(docs, idxs, n_dev, size)
+                    chunk_np = _wave_chunk(wave, idxs, n_dev, size)
                     ids_np = np.array(
                         list(idxs) + [n_real] * (n_dev - len(idxs)),
                         dtype=np.int32)
@@ -1891,6 +1898,13 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
         step._outcome = outcome
         step._save = save_ckpt if ck_policy is not None else None
         step._writer = ck_writer
+        # A sequence that reads ahead (``ioread.ReadAheadDocs``) is told
+        # the order this rung's walk will ask in, duck-typed as
+        # ``lengths`` is.
+        read_ahead = getattr(docs, "read_ahead", None)
+        if read_ahead is not None:
+            read_ahead([i for idxs, _ in waves[start_wave:]
+                        for i in wave_ordinals(idxs)])
         pipe.begin(materialize)
 
         def end_ok():

@@ -832,7 +832,10 @@ class FileDocs:
     """Lazy document sequence for :func:`tfidf_sharded`: documents load
     from disk per access (one wave's working set at a time) instead of
     holding the whole corpus resident — at the 1 GB soak that was 1.07 GB
-    of the peak RSS."""
+    of the peak RSS.  Its sibling ``utils/ioread.ReadAheadDocs`` makes
+    the other trade for a job that held its documents anyway
+    (``planrun --chain indexer``): every document read once, ahead of
+    the walk, by a pool of threads, and kept."""
 
     def __init__(self, paths: Sequence[str]):
         import os

@@ -329,7 +329,9 @@ def run_plan(plan: Plan, *, mesh=None, staged: bool = False,
                "plan_s": 0.0, "stage_commit_s": 0.0,
                "plan_stage_walls": {}, "stage_stats": {}})
     order = plan.ordered()
-    sig = plan.signature()
+    # The job identity of the stage manifests.  It CRCs every document of
+    # an indexer stage, so a run that keeps no manifest does not ask.
+    sig = plan.signature() if checkpoint_dir else None
     ctx: Dict[str, StageOut] = {}
     completed = 0
     if checkpoint_dir:
@@ -662,9 +664,10 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
     if stage.kind == "indexer":
         from dsi_tpu.parallel.grepstream import IndexerStep
 
-        docs = list(plan.param(stage, "docs"))
+        docs = plan.param(stage, "docs")
         books = _Books()
-        books.bytes_in = sum(len(d) for d in docs)
+        books.bytes_in = sum(getattr(docs, "lengths", None)
+                             or map(len, docs))
         step = IndexerStep(docs, mesh=mesh, stats=books.stats,
                            n_reduce=int(plan.param(stage, "n_reduce", 10)),
                            u_cap=int(plan.param(stage, "u_cap", 1 << 15)),

@@ -40,7 +40,8 @@ A plan is VALIDATED at build time (unique names, known deps, acyclic)
 and serializes to a :meth:`Plan.signature` — the job identity its stage
 manifests carry, so a resume against a different plan refuses instead of
 misreading stage payloads.  Bulk inputs (corpus bytes, document lists)
-enter the signature as CRCs, not content.
+enter the signature as CRCs, not content; the CRC reads every document,
+so the driver asks for a signature only where a stage store reads it.
 """
 
 from __future__ import annotations
@@ -212,10 +213,14 @@ def indexer_join_plan(docs: Sequence[bytes], *, topk: int = 16,
     ``pack_docs`` fills the indexer's waves with whole documents, a
     chunk of the plan's ``chunk_bytes`` a device
     (``IndexerStep(pack_docs=True)``); off, the stage and the plan's
-    signature are what they were."""
+    signature are what they were.  ``docs`` may be a lazy sequence with
+    ``lengths`` (``ioread.ReadAheadDocs``): the stage keeps it as it is,
+    and no document is asked for until the walk, or a signature, asks."""
     p = Plan("indexer-join", **defaults)
     packed = {"pack_docs": True} if pack_docs else {}
-    i = p.add(Stage("indexer", "indexer", docs=list(docs), topk=topk,
+    if not hasattr(docs, "lengths"):
+        docs = list(docs)
+    i = p.add(Stage("indexer", "indexer", docs=docs, topk=topk,
                     **packed))
     t = p.add(Stage("dftopk", "df_topk", deps=[i.name], topk=topk))
     p.add(Stage("join", "postings_join", deps=[i.name, t.name]))

@@ -100,11 +100,12 @@ def build_plan(spec: Dict):
         return wordcount_topk_plan(defaults["topk"], paths=files,
                                    **defaults)
     if chain == "indexer":
-        docs = []
-        for path in files:
-            with open(path, "rb") as f:
-                docs.append(f.read())
-        return indexer_join_plan(docs, pack_docs=bool(
+        # Lengths now, bytes when the walk (or a signature) asks: the
+        # sequence reads them ahead of the walk, and a stage host that
+        # indexes nothing reads none.  Whoever built the plan closes it.
+        from dsi_tpu.utils.ioread import ReadAheadDocs
+
+        return indexer_join_plan(ReadAheadDocs(files), pack_docs=bool(
             spec.get("pack_docs", False)), **defaults)
     raise ValueError(f"unknown chain {chain!r}")
 
