@@ -11,8 +11,8 @@ that), and the C parser defers to Python on any input it cannot prove it
 parsed completely, so native and pure runs can never diverge.
 
 The library is built from the committed sources only: the build writes a
-SHA-256 of ``kvcodec.cpp`` + ``wcjob.cpp`` + ``docread.cpp`` beside the
-``.so``, and a
+SHA-256 of ``kvcodec.cpp`` + ``wcjob.cpp`` + ``docread.cpp`` +
+``mergeruns.cpp`` beside the ``.so``, and a
 library whose recorded hash does not match the sources on disk is never
 loaded — it is rebuilt, or the process says on stderr that it runs the
 pure-Python data plane.  (File times say nothing: a copied tree resets
@@ -40,7 +40,8 @@ _HASH_PATH = _SO_PATH + ".sha256"
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = (os.path.join(_HERE, "kvcodec.cpp"),
             os.path.join(_HERE, "wcjob.cpp"),
-            os.path.join(_HERE, "docread.cpp"))
+            os.path.join(_HERE, "docread.cpp"),
+            os.path.join(_HERE, "mergeruns.cpp"))
 
 
 def _source_hash() -> str:
@@ -139,6 +140,13 @@ def _load():
                 ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_int64), ctypes.c_long,
                 ctypes.c_char_p, ctypes.POINTER(ctypes.c_int)]
+            lib.pc_rows_increase.restype = ctypes.c_int
+            lib.pc_rows_increase.argtypes = [ctypes.c_void_p, ctypes.c_long,
+                                             ctypes.c_int]
+            lib.pc_merge2.restype = ctypes.c_long
+            lib.pc_merge2.argtypes = (
+                [ctypes.c_void_p] * 4 + [ctypes.c_long]) * 2 + [
+                ctypes.c_int] + [ctypes.c_void_p] * 4
             _lib = lib
         except (OSError, AttributeError) as e:
             # AttributeError: a stale .so predating a symbol and a failed
@@ -375,3 +383,53 @@ def read_files(names: Sequence[bytes], dir_fds: Sequence[int],
                              (ctypes.c_int64 * n)(*lengths), n, out,
                              ctypes.byref(err))
     return memoryview(out), bad, err.value
+
+
+def _table_pointers(table, k: Optional[int] = None) -> list:
+    """The four data pointers of a ``(keys, lens, cnts, parts)`` table
+    for ``mergeruns.cpp``, which reads them as C arrays of uint32 ``[n,
+    k]``, int32, int64 and int32 of ``n`` rows: anything else is refused
+    here, not read there."""
+    keys = table[0]
+    if keys.ndim != 2 or (k is not None and keys.shape[1] != k):
+        raise ValueError(f"key lanes {keys.shape}, wanted [n, {k or 'k'}]")
+    for x, dtype in zip(table, ("uint32", "int32", "int64", "int32")):
+        if x.dtype != dtype or not x.flags.c_contiguous \
+                or len(x) != len(keys):
+            raise ValueError(
+                f"a table's column has to be C-contiguous {dtype} of "
+                f"{len(keys)} rows, not {x.dtype}{x.shape}")
+    return [x.ctypes.data for x in table]
+
+
+def rows_increase(keys) -> Optional[bool]:
+    """Whether the rows of a C-contiguous ``[n, k]`` uint32 table
+    strictly increase, lane 0 primary (``mergeruns.cpp``).  None -> the
+    caller decides it itself."""
+    lib = _load()
+    if lib is None:
+        return None
+    if keys.ndim != 2 or keys.dtype != "uint32" \
+            or not keys.flags.c_contiguous:
+        raise ValueError("key lanes have to be C-contiguous uint32 [n, k], "
+                         f"not {keys.dtype}{keys.shape}")
+    return bool(lib.pc_rows_increase(keys.ctypes.data, keys.shape[0],
+                                     keys.shape[1]))
+
+
+def merge_runs2(a, b, out) -> Optional[int]:
+    """Two sorted runs into ``out`` (``mergeruns.cpp``): ``a``, ``b`` and
+    ``out`` are ``(keys, lens, cnts, parts)``, C-contiguous uint32 ``[n,
+    k]`` of one ``k``, int32, int64, int32, ``out`` with room for the rows
+    of both.  Returns the rows written (the distinct keys, increasing,
+    the counts of a key both hold summed), or None -> the caller merges
+    them itself."""
+    lib = _load()
+    if lib is None:
+        return None
+    k = a[0].shape[1]
+    if len(out[0]) < len(a[0]) + len(b[0]):
+        raise ValueError("the output has no room for the rows of both runs")
+    return lib.pc_merge2(*_table_pointers(a, k), len(a[0]),
+                         *_table_pointers(b, k), len(b[0]), k,
+                         *_table_pointers(out, k))

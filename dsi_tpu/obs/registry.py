@@ -35,8 +35,9 @@ that phase):
   job, whose writer reads the arrays); ``finalize_decoded_keys`` counts
   the spellings
 * ``compact_s``          — the ``compact`` spans of the host accumulator
-  (``parallel/merge.py``): every buffered row sorted and merged again;
-  inside ``merge_s``, ``finalize_s`` or ``sync_s``, whichever called it
+  (``parallel/merge.py``): the window's runs merged into one and that
+  one into the merged table, which is never sorted again; inside
+  ``merge_s``, ``finalize_s`` or ``sync_s``, whichever called it
 * ``group_s``            — the ``group`` span: the indexer's postings
   table grouped into the index (``merge.PostingsTable.finalize_packed``:
   one lexsort over the key lanes and the run detection), once a job,
@@ -105,9 +106,13 @@ at dispatch), ``step_pulls``, ``sync_pulls``, ``widens``, ``folds``,
 ``postings_widens``, ``topk_snapshots``, ``hist_folds``, ``hist_pulls``,
 ``table_cap``, ``sync_every``, ``max_inflight``,
 ``merge_rows_in`` (rows handed to the host accumulator's ``add``),
-``merge_rows_sorted`` (rows through its lexsort, summed over
-compactions) and ``merge_compacts`` (all three repeat exactly for one
-input), ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
+``merge_runs_in`` (batches handed to it), ``merge_runs_unsorted`` (those
+whose rows did not strictly increase and were sorted on entry: 0 where
+every batch is a device's step table), ``merge_rows_sorted`` (rows
+handed to an ordering routine: an unsorted batch's on entry, a
+window's at its compaction, the merged table's never) and
+``merge_compacts`` (all five repeat exactly for one input, with the
+native library or without), ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
 ``resume_cursor``/``resume_wave``, ``device_accumulate``.  The indexer's
 wave walk adds ``docs`` (documents handed over), ``waves_by_size``
 (padded chunk bytes → waves dispatched), ``wave_doc_bytes`` and
@@ -333,6 +338,7 @@ COUNTER_KEYS = (
     "device_rows",
     # the host accumulator (parallel/merge.py PackedCounts)
     "merge_rows_in", "merge_rows_sorted", "merge_compacts",
+    "merge_runs_in", "merge_runs_unsorted",
     # its result and the partition writer: spellings turned into ``str``
     # (0 in a wcstream job), rows rendered from the merged table's
     # arrays, rows formatted from a dict (the host fallback's)

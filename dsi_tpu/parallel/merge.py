@@ -10,8 +10,17 @@ This module replaces the per-row loops with numpy table algebra:
 
 * rows accumulate as raw uint32 arrays (copied out of the step's transfer
   buffer so no device-shaped block stays alive),
-* merging is one ``np.lexsort`` over the key lanes + run-boundary detection
-  + ``np.add.reduceat`` per compaction window — O(rows log rows) in C,
+* a batch whose rows strictly increase is a *run*, and a device's step
+  table is one as it arrives (the step program sorted and grouped it), so
+  the word-count accumulator merges runs and sorts nothing twice: the
+  batches since the last compaction (the window) are merged into one run
+  and that run into the merged table, which stands apart from the window
+  and never goes through a sort again.  Two pointers in
+  ``native/mergeruns.cpp``; without the library one stable sort of the
+  window on a packed ``uint64`` key (a merge of the runs it finds), ties
+  repaired, ``np.add.reduceat``, and ``np.searchsorted`` placement into the
+  table.  Only a batch that does not arrive sorted is sorted, alone, on
+  entry (one ``np.lexsort`` over its key lanes),
 * the final merged table IS the result (``PackedWordCounts``): the
   partition writer renders ``mr-out-*`` from its arrays, and word
   spellings are decoded (ONCE, vocabulary-sized, via the same bulk
@@ -21,7 +30,7 @@ This module replaces the per-row loops with numpy table algebra:
 Zero-padded key lanes make width harmonisation trivial: a word packed into
 K lanes and the same word packed into K' > K lanes agree on the first K
 lanes and are zero beyond, so narrower tables are right-padded with zero
-columns before concatenation.
+columns before they are merged, which keeps a run sorted.
 
 The reference has no analogue (its reduce merge is the in-memory group of
 ``mr/worker.go:110-124``); this is that merge re-done as array algebra so
@@ -35,6 +44,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from dsi_tpu import native as _native
 from dsi_tpu.obs import span as _span
 from dsi_tpu.ops.wordcount import decode_packed
 
@@ -108,33 +118,197 @@ def _lexsort_rows(keys: np.ndarray) -> np.ndarray:
 
 def _rows_increase(keys: np.ndarray) -> bool:
     """Whether a [n, k] table's rows strictly increase lexicographically
-    (lane 0 primary): sorted and distinct, decided at each row's first
-    lane that differs from the row before."""
+    (lane 0 primary): sorted and distinct, which makes the table a run.
+    One pass in ``native/mergeruns.cpp``; else decided at each row's
+    first lane that differs from the row before."""
     if len(keys) < 2:
         return True
+    native = _native.rows_increase(keys)
+    if native is not None:
+        return native
     prev, nxt = keys[:-1], keys[1:]
     first = (prev != nxt).argmax(axis=1)
     rows = np.arange(len(prev))
     return bool((nxt[rows, first] > prev[rows, first]).all())
 
 
+#: One table of the accumulator: key lanes [n, K] uint32, byte lengths
+#: int32, counts int64, reduce partitions int32, all C-contiguous.
+_Table = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
+
+
+def _pad_table(table: _Table, k: int) -> _Table:
+    return (_pad_width(table[0], k),) + table[1:]
+
+
+def _reduce_ordered(table: _Table, order: np.ndarray,
+                    skeys: np.ndarray) -> _Table:
+    """The run of a table whose rows ``order`` sorts (``skeys`` =
+    ``keys[order]``): one row a word.  Length and partition are
+    functions of the word, so first-of-run is exact; only the counts
+    need the segmented sum."""
+    _, lens, cnts, parts = table
+    starts = _group_starts(skeys)
+    first = order[starts]
+    return (skeys[starts], lens[first],
+            np.add.reduceat(cnts[order], starts), parts[first])
+
+
+def _sort_reduce(table: _Table) -> _Table:
+    """Any batch as a run: its rows ordered by one ``np.lexsort`` over
+    the key lanes, the counts of a word it holds twice summed."""
+    order = _lexsort_rows(table[0])
+    return _reduce_ordered(table, order, table[0][order])
+
+
+def _own_run(keys, lens, cnts, parts) -> Tuple[_Table, bool]:
+    """A batch as a run of the accumulator's own arrays, and whether it
+    arrived as one.  Copies detach the rows from the step's
+    full-capacity transfer buffer; counts widen to int64 so multi-step
+    sums can't wrap."""
+    batch = (np.array(keys, dtype=np.uint32, order="C"),
+             np.array(lens, dtype=np.int32),
+             np.array(cnts, dtype=np.int64),
+             np.array(parts, dtype=np.int32))
+    if _rows_increase(batch[0]):
+        return batch, True
+    return _sort_reduce(batch), False
+
+
+def _merge2_native(a: _Table, b: _Table) -> Optional[_Table]:
+    """Two runs of one lane width as one, by ``mergeruns.cpp``'s two
+    pointers; None without the library."""
+    cap = len(a[0]) + len(b[0])
+    out = (np.empty((cap, a[0].shape[1]), np.uint32),
+           np.empty(cap, np.int32), np.empty(cap, np.int64),
+           np.empty(cap, np.int32))
+    n = _native.merge_runs2(a, b, out)
+    return None if n is None else tuple(x[:n] for x in out)
+
+
+def _merge_runs_numpy(runs: List[_Table]) -> _Table:
+    """Runs of one lane width as one, in numpy: the rows one behind the
+    other, ordered by ONE stable sort of their first two lanes packed
+    into a ``uint64`` (numpy's stable sort of 64-bit integers is a merge
+    of the runs it finds, so rows that arrive as runs cost a merge and
+    not a sort), then the counts summed over each word's rows.  Words
+    that share their first eight bytes and differ later tie in that
+    column: the groups that hold such words, a few, are put in order by
+    their other lanes."""
+    table = tuple(np.concatenate([r[i] for r in runs]) for i in range(4))
+    keys = table[0]
+    k = keys.shape[1]
+    primary = keys[:, 0].astype(np.uint64)
+    if k > 1:
+        primary <<= np.uint64(32)
+        primary |= keys[:, 1]
+    order = np.argsort(primary, kind="stable")
+    skeys = keys[order]
+    if k > 2:
+        primary = primary[order]
+        tie = primary[1:] == primary[:-1]
+        mixed = tie & (skeys[1:, 2:] != skeys[:-1, 2:]).any(axis=1)
+        if mixed.any():
+            group = np.zeros(len(keys), np.int64)
+            np.cumsum(~tie, out=group[1:])
+            holds_words = np.zeros(int(group[-1]) + 1, bool)
+            holds_words[group[1:][mixed]] = True
+            pos = np.flatnonzero(holds_words[group])
+            rest = skeys[pos, 2:]
+            inner = np.lexsort(tuple(rest[:, j] for j in
+                                     range(k - 3, -1, -1)) + (group[pos],))
+            order[pos] = order[pos][inner]
+            skeys[pos] = keys[order[pos]]
+    return _reduce_ordered(table, order, skeys)
+
+
+def _merge_runs(runs: List[_Table]) -> _Table:
+    """A window's runs as one run: pairwise by the native two-pointer
+    merge, level by level (a row is copied once a level, and a word that
+    several runs hold collapses on the way), else in numpy."""
+    k = max(r[0].shape[1] for r in runs)
+    runs = [_pad_table(r, k) for r in runs]
+    if len(runs) > 1 and not _native.available():
+        return _merge_runs_numpy(runs)
+    while len(runs) > 1:
+        merged = [_merge2_native(runs[i], runs[i + 1])
+                  for i in range(0, len(runs) - 1, 2)]
+        if len(runs) % 2:
+            merged.append(runs[-1])
+        runs = merged
+    return runs[0]
+
+
+def _bytes_column(keys: np.ndarray) -> np.ndarray:
+    """A [n, K] key table as n byte strings of 4K bytes, big-endian
+    lanes, so that byte order is lane order: what ``np.searchsorted``
+    can search."""
+    return np.ascontiguousarray(keys.astype(">u4")).view(
+        f"S{4 * keys.shape[1]}").ravel()
+
+
+def _merge_into(table: _Table, run: _Table) -> _Table:
+    """The merged table with one run merged in, no row of the table
+    through a sort: the native two pointers, else each of the run's rows
+    placed by binary search (its count added where the table holds the
+    word, the row inserted where it does not)."""
+    k = max(table[0].shape[1], run[0].shape[1])
+    table, run = _pad_table(table, k), _pad_table(run, k)
+    merged = _merge2_native(table, run)
+    if merged is not None:
+        # the output had room for every row of both: let that go
+        return tuple(x.copy() for x in merged) \
+            if len(merged[0]) < len(table[0]) + len(run[0]) else merged
+    tkeys, tlens, tcnts, tparts = table
+    tcol, rcol = _bytes_column(tkeys), _bytes_column(run[0])
+    pos = np.searchsorted(tcol, rcol)
+    held = pos < len(tcol)
+    held[held] = tcol[pos[held]] == rcol[held]
+    tcnts = tcnts.copy()  # a snapshot or a result may hold the old one
+    tcnts[pos[held]] += run[2][held]
+    new = np.flatnonzero(~held)
+    if len(new) == 0:
+        return tkeys, tlens, tcnts, tparts
+    at = pos[new] + np.arange(len(new))
+    old = np.ones(len(tkeys) + len(new), bool)
+    old[at] = False
+    out = []
+    for told, rnew in zip((tkeys, tlens, tcnts, tparts), run):
+        col = np.empty((len(old),) + told.shape[1:], told.dtype)
+        col[old] = told
+        col[at] = rnew[new]
+        out.append(col)
+    return tuple(out)
+
+
 class PackedCounts:
     """Word-count accumulator over packed-key row batches.
 
     ``add`` ingests per-device step outputs (keys [n, K] uint32, byte
-    lengths, counts, reduce partitions); batches are compacted into one
-    merged table whenever the buffered row count crosses
-    ``compact_rows`` — so host memory is O(vocabulary + window), never
-    O(corpus).  ``finalize`` returns the merged table as a
+    lengths, counts, reduce partitions).  The accumulator holds one
+    merged table, sorted and distinct, and beside it the window: the
+    batches added since the last compaction, each a run (rows that
+    strictly increase: a device's step table is one as it arrives; any
+    other batch is sorted and reduced on entry, alone).  When the
+    window's rows reach ``compact_rows`` a compaction merges the
+    window's runs into one and that one into the table, so host memory
+    is O(vocabulary + window), never O(corpus), and the number of
+    compactions follows the rows handed over, not the table's size.
+    Nothing is sorted that arrived sorted, and the table is never sorted
+    again.  ``finalize`` returns the merged table as a
     :class:`PackedWordCounts`, which equals the ``{word: (count,
     reduce_partition)}`` dict the dict-based merge produced and builds
     it only when a caller needs Python objects.
 
     ``stats`` (an engine's scope, else a dict of the accumulator's own)
-    takes what the merge costs: ``merge_rows_in`` (rows handed to
-    ``add``), ``merge_rows_sorted`` (rows through the lexsort, summed
-    over compactions) and ``merge_compacts``, which repeat exactly for
-    one input, the seconds of the ``compact`` and ``decode`` spans
+    takes what the merge costs.  Five counters that repeat exactly for
+    one input, with the native library or without: ``merge_rows_in``
+    (rows handed to ``add``), ``merge_runs_in`` (batches handed to
+    ``add``), ``merge_runs_unsorted`` (those that had to be sorted on
+    entry), ``merge_rows_sorted`` (rows handed to an ordering routine:
+    the rows of each unsorted batch, and a window's rows at its
+    compaction; the merged table's rows never) and ``merge_compacts``.
+    And the seconds of the ``compact`` and ``decode`` spans
     (``compact_s``; ``finalize_decode_s``, 0.0 until the result is
     decoded) and ``finalize_decoded_keys`` (spellings turned into
     ``str``).
@@ -142,28 +316,29 @@ class PackedCounts:
 
     def __init__(self, compact_rows: int = 1 << 21,
                  stats: Optional[dict] = None):
-        self._bufs: List[Tuple[np.ndarray, np.ndarray, np.ndarray,
-                               np.ndarray]] = []
-        self._pending = 0
+        self._table: Optional[_Table] = None
+        self._window: List[_Table] = []
+        self._pending = 0  # rows of the window
+        self._unsorted = 0  # batches of the window sorted on entry
         self._compact_rows = max(1, compact_rows)
         self.stats = {} if stats is None else stats
-        for key in ("merge_rows_in", "merge_rows_sorted",
-                    "merge_compacts"):
+        for key in ("merge_rows_in", "merge_rows_sorted", "merge_compacts",
+                    "merge_runs_in", "merge_runs_unsorted"):
             self.stats.setdefault(key, 0)
 
     def add(self, keys: np.ndarray, lens: np.ndarray, cnts: np.ndarray,
             parts: np.ndarray) -> None:
         if len(keys) == 0:
             return
-        # Copies detach the rows from the step's full-capacity transfer
-        # buffer; counts widen to int64 so multi-step sums can't wrap.
-        self._bufs.append((
-            np.array(keys, dtype=np.uint32),
-            np.array(lens, dtype=np.int32),
-            np.array(cnts, dtype=np.int64),
-            np.array(parts, dtype=np.int32)))
-        self._pending += len(keys)
         self.stats["merge_rows_in"] += len(keys)
+        self.stats["merge_runs_in"] += 1
+        run, arrived_sorted = _own_run(keys, lens, cnts, parts)
+        if not arrived_sorted:
+            self._unsorted += 1
+            self.stats["merge_runs_unsorted"] += 1
+            self.stats["merge_rows_sorted"] += len(keys)
+        self._window.append(run)
+        self._pending += len(run[0])
         if self._pending >= self._compact_rows:
             self._compact()
 
@@ -172,7 +347,8 @@ class PackedCounts:
         """Ingest one pulled step tensor ``[n_dev, mp, kk+3]`` (the
         ``shuffle._slice_pack`` layout: kk key lanes + len/count/partition
         columns), taking the first ``n_uniques[d]`` rows of each device's
-        table.  One call per stream step — the merge phase the pipelined
+        table: a run each, the step program having sorted and grouped
+        them.  One call per stream step — the merge phase the pipelined
         engine (parallel/streaming.py) runs on the host while later
         steps' kernels are still in flight on device."""
         for d in range(packed.shape[0]):
@@ -181,44 +357,30 @@ class PackedCounts:
             self.add(r[:, :kk], r[:, kk], r[:, kk + 1], r[:, kk + 2])
 
     def _compact(self) -> None:
-        if len(self._bufs) <= 1:
+        """The window's runs into one run, that run into the table."""
+        if not self._window:
             return
         with _span("compact", lane="merge", stats=self.stats,
-                   rows_in=self._pending, bufs=len(self._bufs)) as sp:
-            k = max(b[0].shape[1] for b in self._bufs)
-            keys = np.concatenate([_pad_width(b[0], k)
-                                   for b in self._bufs])
-            lens = np.concatenate([b[1] for b in self._bufs])
-            cnts = np.concatenate([b[2] for b in self._bufs])
-            parts = np.concatenate([b[3] for b in self._bufs])
-            order = _lexsort_rows(keys)
-            skeys = keys[order]
-            starts = _group_starts(skeys)
-            # len and partition are functions of the word, so
-            # first-of-run is exact; only counts need the segmented sum.
-            self._bufs = [(skeys[starts], lens[order][starts],
-                           np.add.reduceat(cnts[order], starts),
-                           parts[order][starts])]
-            self.stats["merge_rows_sorted"] += len(keys)
+                   rows_in=self._pending, runs_in=len(self._window),
+                   runs_unsorted=self._unsorted,
+                   table_rows=0 if self._table is None
+                   else len(self._table[0])) as sp:
+            run = _merge_runs(self._window)
+            self._table = run if self._table is None \
+                else _merge_into(self._table, run)
+            self.stats["merge_rows_sorted"] += self._pending
             self.stats["merge_compacts"] += 1
-            self._pending = len(starts)
-            sp.set(rows_out=self._pending)
+            self._window, self._pending, self._unsorted = [], 0, 0
+            sp.set(rows_out=len(self._table[0]))
 
     def finalize(self) -> "PackedWordCounts":
-        """The merged table as the job's result: no spelling is decoded
-        and no Python object per word is built here."""
+        """The merged table as the job's result, after the last
+        compaction: no spelling is decoded and no Python object per word
+        is built here."""
         self._compact()
-        if not self._bufs:
+        if self._table is None:
             return PackedWordCounts(stats=self.stats)
-        keys, lens, cnts, parts = self._bufs[0]
-        if not _rows_increase(keys):
-            # One buffer that no compaction ever sorted (a one-device,
-            # one-step job; a restored image of one) is in the device's
-            # order.  Its rows are distinct, so ordering them is all.
-            order = _lexsort_rows(keys)
-            keys, lens, cnts, parts = (keys[order], lens[order],
-                                       cnts[order], parts[order])
-        return PackedWordCounts(keys, lens, cnts, parts, stats=self.stats)
+        return PackedWordCounts(*self._table, stats=self.stats)
 
     # ── checkpoint image (dsi_tpu/ckpt) ──
 
@@ -227,24 +389,27 @@ class PackedCounts:
         first so the image is bounded by vocabulary, not by the window.
         Empty accumulator -> empty dict (no keys saved)."""
         self._compact()
-        if not self._bufs:
+        if self._table is None:
             return {}
-        keys, lens, cnts, parts = self._bufs[0]
+        keys, lens, cnts, parts = self._table
         return {"keys": keys, "lens": lens, "cnts": cnts, "parts": parts}
 
     def restore(self, arrays: Dict[str, np.ndarray]) -> None:
-        """Load a :meth:`snapshot` image, replacing any current state.
-        Final results are invariant to how the same (word, count)
-        contributions were buffered, so a restored accumulator
-        finalizes bit-identically to the uninterrupted one."""
+        """Load a :meth:`snapshot` image, replacing any current state:
+        the image is the merged table (an image whose rows do not
+        increase, one an earlier layout wrote of a single batch, is
+        sorted once).  Final results are invariant to how the same
+        (word, count) contributions were buffered, so a restored
+        accumulator finalizes bit-identically to the uninterrupted
+        one."""
+        self._table, self._window = None, []
+        self._pending = self._unsorted = 0
         if not arrays or "keys" not in arrays or len(arrays["keys"]) == 0:
-            self._bufs, self._pending = [], 0
             return
-        self._bufs = [(np.array(arrays["keys"], dtype=np.uint32),
-                       np.array(arrays["lens"], dtype=np.int32),
-                       np.array(arrays["cnts"], dtype=np.int64),
-                       np.array(arrays["parts"], dtype=np.int32))]
-        self._pending = len(self._bufs[0][0])
+        self._table, arrived_sorted = _own_run(
+            arrays["keys"], arrays["lens"], arrays["cnts"], arrays["parts"])
+        if not arrived_sorted:
+            self.stats["merge_rows_sorted"] += len(arrays["keys"])
 
 
 class PackedWordCounts(Mapping):

@@ -5,7 +5,8 @@
 set -eu
 REPO=$(cd "$(dirname "$0")/.." && pwd)
 SRCS=("$REPO/dsi_tpu/native/kvcodec.cpp" "$REPO/dsi_tpu/native/wcjob.cpp"
-      "$REPO/dsi_tpu/native/docread.cpp")
+      "$REPO/dsi_tpu/native/docread.cpp"
+      "$REPO/dsi_tpu/native/mergeruns.cpp")
 mkdir -p "$REPO/build"
 # Build to a temp name + atomic rename: concurrent workers may trigger the
 # lazy first-use build simultaneously, and no process may ever dlopen a

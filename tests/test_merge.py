@@ -158,8 +158,7 @@ def _tail_case(name):
     if name == "mixed-widths":
         acc, oracle = _acc_of(_random_batches(7, "abc", 9), k=4)
         wide, more = _acc_of(_random_batches(8, "abc", 40), k=16)
-        for buf in wide._bufs:
-            acc.add(*buf)
+        acc.add(*wide.snapshot().values())
         for w, (c, p) in more.items():
             oracle[w] = (oracle.get(w, (0, 0))[0] + c, p)
         return acc, oracle, 10
@@ -183,12 +182,15 @@ def _tail_case(name):
         return (*_acc_of(_random_batches(13, letters, 12),
                          part=lambda w: 3), 5)
     if name == "single-buffer-device-order":
-        # one never-compacted buffer: the device's order, not the table's
+        # one batch in no order of the table's, never compacted before
+        # ``finalize``: sorted on entry, alone
         words = ["pear", "Apple", "fig", "apple", "figs", "Zebra", "kiwi",
                  "a", "quince", "ap"]
         acc, oracle = _acc_of([[(w, i + 1) for i, w in enumerate(words)]],
                               part=lambda w: len(w) % 4)
-        assert len(acc._bufs) == 1 and acc.stats["merge_compacts"] == 0
+        assert len(acc._window) == 1 and acc._table is None
+        assert acc.stats["merge_compacts"] == 0
+        assert acc.stats["merge_runs_unsorted"] == 1
         return acc, oracle, 4
     if name == "snapshot-restore":
         acc, oracle = _acc_of(_random_batches(17, letters, 16),
@@ -295,3 +297,323 @@ def test_writer_refuses_a_row_outside_the_partitions(tmp_path):
     with pytest.raises(IndexError):
         write_partitioned_output(oracle, 3, str(tmp_path))
     assert os.listdir(tmp_path) == []
+
+
+# ── the accumulator merges sorted runs: against two independent oracles ──
+
+
+def _parent_table(batches):
+    """What the accumulator's parent did, kept as a test helper only:
+    every row of every batch one behind the other, one ``np.lexsort``
+    over the lanes, the counts summed over each run of equal keys."""
+    batches = [b for b in batches if len(b[0])]
+    if not batches:
+        return (np.zeros((0, 1), np.uint32), np.zeros(0, np.int32),
+                np.zeros(0, np.int64), np.zeros(0, np.int32))
+    k = max(b[0].shape[1] for b in batches)
+    keys = np.concatenate([np.pad(np.asarray(b[0], np.uint32),
+                                  ((0, 0), (0, k - b[0].shape[1])))
+                           for b in batches])
+    lens, cnts, parts = (np.concatenate([np.asarray(b[i]) for b in batches])
+                         for i in (1, 2, 3))
+    order = np.lexsort(tuple(keys[:, j] for j in range(k - 1, -1, -1)))
+    skeys = keys[order]
+    starts = np.flatnonzero(np.r_[True, (skeys[1:] != skeys[:-1]).any(1)])
+    return (skeys[starts], lens[order][starts].astype(np.int32),
+            np.add.reduceat(cnts[order].astype(np.int64), starts),
+            parts[order][starts].astype(np.int32))
+
+
+def _dict_oracle(batches):
+    """``{key lanes without their zero padding: [count, len, part]}`` by
+    a Python loop a row."""
+    out: dict = {}
+    for keys, lens, cnts, parts in batches:
+        for row, ln, c, p in zip(np.asarray(keys).tolist(), lens, cnts,
+                                 parts):
+            while row and row[-1] == 0:
+                row.pop()
+            ent = out.setdefault(tuple(row), [0, int(ln), int(p)])
+            ent[0] += int(c)
+    return out
+
+
+def _word_batch(words, counts, k):
+    keys, lens, cnts, _ = _rows(words, counts, k)
+    return keys, lens, cnts, np.array([sum(map(ord, w)) % 7 for w in words],
+                                      dtype=np.int32)
+
+
+def _sorted_batches(seed, n_batches, k=4, longest=12, vocab=400,
+                    alphabet="abcdefghijklmnop", most=60):
+    """Batches as a device hands them over: distinct words, in order."""
+    rng = random.Random(seed)
+    words = sorted({"".join(rng.choices(alphabet, k=rng.randint(1, longest)))
+                    for _ in range(vocab)})
+    out = []
+    for _ in range(n_batches):
+        local = Counter(rng.choices(words, k=rng.randint(1, most)))
+        out.append(_word_batch(sorted(local), [local[w] for w in
+                                               sorted(local)], k))
+    return out
+
+
+def _merge_case(name):
+    """``(batches, compact_rows, batches that arrive unsorted)``."""
+    rng = random.Random(len(name))
+    if name == "sorted-batches":
+        return _sorted_batches(1, 20), 256, 0
+    if name == "unsorted-and-duplicates":
+        out = []
+        for _ in range(12):  # a caller's arbitrary ``add``
+            words = rng.choices(["ab", "abc", "b", "Zed", "zed", "q" * 11,
+                                 "mnop", "a"], k=rng.randint(2, 30))
+            out.append(_word_batch(words, [rng.randint(1, 9) for _ in words],
+                                   4))
+        return out + _sorted_batches(2, 6), 64, 12
+    if name == "mixed-lane-widths":
+        out = []
+        for i, k in enumerate((2, 4, 8, 4, 2, 8, 8, 2, 4)):
+            out += _sorted_batches(10 + i, 3, k=k, longest=4 * k, vocab=80)
+        return out, 128, 0
+    if name == "empty-batches":
+        empty = (np.zeros((0, 4), np.uint32), np.zeros(0, np.int32),
+                 np.zeros(0, np.int64), np.zeros(0, np.int32))
+        real = _sorted_batches(3, 6)
+        return [empty, real[0], empty, empty] + real[1:] + [empty], 64, 0
+    if name == "one-batch-never-compacted":
+        return _sorted_batches(4, 1), 1 << 21, 0
+    if name == "window-never-reaches-compact-rows":
+        return _sorted_batches(5, 25), 1 << 21, 0
+    if name == "dozens-of-compactions":
+        return _sorted_batches(6, 120, vocab=900), 48, 0
+    if name == "shared-first-eight-bytes":
+        # the tie case of a sort on the first two lanes packed: words of
+        # one eight-byte stem that differ in lanes 2 and 3, and the stem
+        stems = ["internat", "abcdefgh", "Abcdefgh"]
+        tails = ["", "a", "b", "ional", "ionally", "ionale", "zzzzzzzz",
+                 "ab", "ba", "ionalism"]
+        words = sorted({s + t for s in stems for t in tails}
+                       | {"intern", "abc", "zebra"})
+        out = []
+        for _ in range(30):
+            local = sorted(rng.sample(words, rng.randint(3, len(words))))
+            out.append(_word_batch(local, [rng.randint(1, 5) for _ in local],
+                                   4))
+        return out, 100, 0
+    if name == "counts-past-2-to-the-32":
+        big = (1 << 31) + 12345
+        return [_word_batch(["alpha", "beta", "x"], [big, 7, big], 4)
+                for _ in range(9)], 8, 0
+    raise AssertionError(name)
+
+
+_MERGE_CASES = ["sorted-batches", "unsorted-and-duplicates",
+                "mixed-lane-widths", "empty-batches",
+                "one-batch-never-compacted",
+                "window-never-reaches-compact-rows", "dozens-of-compactions",
+                "shared-first-eight-bytes", "counts-past-2-to-the-32"]
+
+
+@pytest.fixture(params=["native", "numpy"])
+def merge_route(request, monkeypatch):
+    """Both routes of a compaction: ``native/mergeruns.cpp``, and numpy
+    alone, as ``DSI_NO_NATIVE=1`` leaves it."""
+    from dsi_tpu import native
+
+    if request.param == "numpy":
+        monkeypatch.setattr(native, "_lib", False)
+    elif not native.available():
+        pytest.skip("no native library on this host")
+    return request.param
+
+
+def _run_case(batches, compact_rows):
+    stats: dict = {}
+    acc = PackedCounts(compact_rows=compact_rows, stats=stats)
+    for b in batches:
+        acc.add(*b)
+    return acc, acc.finalize(), stats
+
+
+_COUNTERS = ("merge_rows_in", "merge_rows_sorted", "merge_compacts",
+             "merge_runs_in", "merge_runs_unsorted")
+
+
+@pytest.mark.parametrize("name", _MERGE_CASES)
+def test_accumulator_equals_the_parents_sort_and_a_dict(merge_route, name):
+    batches, compact_rows, unsorted = _merge_case(name)
+    acc, table, stats = _run_case(batches, compact_rows)
+    want = _parent_table(batches)
+    # the parent's table row for row: lanes, lengths, int64 sums, partitions
+    for got, exp in zip((table.skeys, table.lens, table.cnts, table.parts),
+                        want):
+        assert got.dtype == exp.dtype and np.array_equal(got, exp)
+    assert table.cnts.dtype == np.int64
+    oracle = _dict_oracle(batches)
+    assert len(table) == len(oracle)
+    for row, ln, c, p in zip(table.skeys.tolist(), table.lens.tolist(),
+                             table.cnts.tolist(), table.parts.tolist()):
+        while row and row[-1] == 0:
+            row.pop()
+        assert oracle[tuple(row)] == [c, ln, p]
+    given = [b for b in batches if len(b[0])]
+    rows = sum(len(b[0]) for b in given)
+    assert stats["merge_rows_in"] == rows
+    assert stats["merge_runs_in"] == len(given)
+    assert stats["merge_runs_unsorted"] == unsorted
+    # every row is ordered once in its window, an unsorted batch's once
+    # more on entry; the merged table's rows never
+    sorted_on_entry = sum(len(b[0]) for b in batches[:unsorted])
+    after_entry = rows - sorted_on_entry + sum(
+        len(set(map(tuple, b[0].tolist()))) for b in batches[:unsorted])
+    assert stats["merge_rows_sorted"] == sorted_on_entry + after_entry
+    if name == "dozens-of-compactions":
+        assert stats["merge_compacts"] >= 36
+    if "never" in name:
+        assert stats["merge_compacts"] == 1  # the one in ``finalize``
+    if name == "counts-past-2-to-the-32":
+        assert table["alpha"][0] == 9 * ((1 << 31) + 12345) > 1 << 32
+
+
+@pytest.mark.parametrize("name", _MERGE_CASES)
+def test_both_routes_count_alike_and_repeat(monkeypatch, name):
+    from dsi_tpu import native
+
+    if not native.available():
+        pytest.skip("no native library on this host")
+    batches, compact_rows, _ = _merge_case(name)
+    _, first, stats = _run_case(batches, compact_rows)
+    _, again, stats2 = _run_case(batches, compact_rows)
+    monkeypatch.setattr(native, "_lib", False)
+    _, plain, stats3 = _run_case(batches, compact_rows)
+    counts = {k: stats[k] for k in _COUNTERS}
+    assert counts == {k: stats2[k] for k in _COUNTERS} \
+        == {k: stats3[k] for k in _COUNTERS}
+    for a, b, c in zip(*((t.skeys, t.lens, t.cnts, t.parts)
+                         for t in (first, again, plain))):
+        assert a.tobytes() == b.tobytes() == c.tobytes()
+
+
+def test_compactions_follow_the_rows_handed_over_not_the_table(merge_route):
+    """The trigger counts the window alone: a vocabulary of three times
+    ``compact_rows`` words compacts once every ``compact_rows`` rows, not
+    once a batch as soon as the table is that large."""
+    compact_rows, per_batch, n_batches = 256, 32, 96
+    rng = np.random.default_rng(43)
+    words = np.sort(rng.choice(1 << 20, 3 * compact_rows, replace=False))
+    stats: dict = {}
+    acc = PackedCounts(compact_rows=compact_rows, stats=stats)
+    oracle = Counter()
+    for _ in range(n_batches):
+        pick = np.sort(rng.choice(words, per_batch, replace=False))
+        keys = np.stack([pick >> 8, pick & 0xFF], axis=1).astype(np.uint32)
+        acc.add(keys, np.full(per_batch, 5), np.ones(per_batch, np.int64),
+                pick % 3)
+        oracle.update(pick.tolist())
+    table = acc.finalize()
+    assert len(table) == len(oracle) > 2 * compact_rows
+    assert table.cnts.sum() == n_batches * per_batch
+    got = dict(zip(((table.skeys[:, 0].astype(np.int64) << 8)
+                    | table.skeys[:, 1]).tolist(), table.cnts.tolist()))
+    assert got == dict(oracle)
+    # 96 batches of 32 rows: a window of 256 rows fills every 8 batches
+    assert stats["merge_compacts"] == n_batches * per_batch // compact_rows
+    assert stats["merge_rows_sorted"] == stats["merge_rows_in"]
+
+
+def _images_equal(a, b):
+    return sorted(a) == sorted(b) and all(
+        a[k].dtype == b[k].dtype and np.array_equal(a[k], b[k]) for k in a)
+
+
+def test_snapshot_restore_finalize_equals_the_uninterrupted_run(merge_route):
+    batches = _sorted_batches(21, 40, vocab=700)
+    _, whole, _ = _run_case(batches, 128)
+    acc = PackedCounts(compact_rows=128)
+    for b in batches[:17]:
+        acc.add(*b)
+    image = {k: v.copy() for k, v in acc.snapshot().items()}
+    assert sorted(image) == ["cnts", "keys", "lens", "parts"]
+    assert acc._window == []  # compacted first: the image is the table
+    back = PackedCounts(compact_rows=128)
+    back.restore(image)
+    for b in batches[17:]:
+        acc.add(*b)
+        back.add(*b)
+    for table in (acc.finalize(), back.finalize()):
+        assert np.array_equal(table.skeys, whole.skeys)
+        assert np.array_equal(table.cnts, whole.cnts)
+        assert np.array_equal(table.lens, whole.lens)
+        assert np.array_equal(table.parts, whole.parts)
+    # what a snapshot handed out is not written again by a later merge
+    assert _images_equal(image, {k: v for k, v in zip(
+        ("keys", "lens", "cnts", "parts"), _parent_table(batches[:17]))})
+    assert PackedCounts().snapshot() == {}
+    back.restore({})
+    assert len(back.finalize()) == 0
+
+
+def test_an_image_of_the_parents_layout_restores(merge_route):
+    """The parent wrote whatever its one buffer held: a compacted table
+    (sorted, distinct), or a single batch as it was handed over, in any
+    order.  Both install as the merged table."""
+    batches = _sorted_batches(22, 9)
+    compacted = dict(zip(("keys", "lens", "cnts", "parts"),
+                         _parent_table(batches)))
+    words = ["pear", "Apple", "fig", "apple", "figs", "Zebra", "kiwi"]
+    raw = dict(zip(("keys", "lens", "cnts", "parts"),
+                   _word_batch(words, range(1, 8), 4)))
+    more = _sorted_batches(23, 5)
+    for image, before in ((compacted, batches), (raw, [tuple(
+            raw[k] for k in ("keys", "lens", "cnts", "parts"))])):
+        stats: dict = {}
+        acc = PackedCounts(compact_rows=64, stats=stats)
+        acc.restore(image)
+        assert acc._window == [] and stats["merge_runs_in"] == 0
+        assert stats["merge_runs_unsorted"] == 0  # an image is no batch
+        for b in more:
+            acc.add(*b)
+        table = acc.finalize()
+        for got, exp in zip((table.skeys, table.lens, table.cnts,
+                             table.parts), _parent_table(before + more)):
+            assert np.array_equal(got, exp)
+        # and the change's image is the parent's, name for name
+        assert _images_equal(
+            acc.snapshot(), dict(zip(("keys", "lens", "cnts", "parts"),
+                                     _parent_table(before + more))))
+
+
+def test_native_merge_refuses_a_table_it_cannot_read():
+    """``mergeruns.cpp`` reads raw pointers: a column of another dtype,
+    stride or length, lanes of another width, and an output without room
+    are refused before the call."""
+    from dsi_tpu import native
+
+    if not native.available():
+        pytest.skip("no native library on this host")
+    a = _sorted_batches(31, 1)[0]
+    b = _sorted_batches(32, 1)[0]
+    a, b = (tuple(np.ascontiguousarray(x) for x in t) for t in (a, b))
+
+    def room(n, k=4):
+        return (np.empty((n, k), np.uint32), np.empty(n, np.int32),
+                np.empty(n, np.int64), np.empty(n, np.int32))
+
+    n = native.merge_runs2(a, b, room(len(a[0]) + len(b[0])))
+    assert max(len(a[0]), len(b[0])) <= n <= len(a[0]) + len(b[0])
+    with pytest.raises(ValueError, match="no room"):
+        native.merge_runs2(a, b, room(len(a[0])))
+    out = room(len(a[0]) + len(b[0]))
+    for bad in ((a[0], a[1].astype(np.int64), a[2], a[3]),   # dtype
+                (a[0], a[1], a[2][::-1][::-1][:-1], a[3]),   # length
+                (a[0][:, :2], a[1], a[2], a[3]),             # stride, width
+                (np.zeros((len(a[0]), 8), np.uint32),) + a[1:]):  # width
+        with pytest.raises(ValueError):
+            native.merge_runs2(bad, b, out)
+    with pytest.raises(ValueError):
+        native.rows_increase(a[0][:, ::2])
+    with pytest.raises(ValueError):
+        native.rows_increase(a[0].astype(np.int64))
+    assert native.rows_increase(a[0]) is True
+    assert native.rows_increase(np.ascontiguousarray(a[0][::-1])) is False

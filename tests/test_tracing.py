@@ -532,19 +532,25 @@ def test_merge_counters_equal_a_hand_count():
 
     stats = {}
     acc = PackedCounts(compact_rows=16, stats=stats)
-    acc.add(*rows(*range(0, 5)))      # 5 pending
+    acc.add(*rows(*range(0, 5)))      # 5 in the window
     acc.add(*rows(*range(3, 8)))      # 10
-    acc.add(*rows(*range(6, 12)))     # 16: sorted, 12 distinct left
-    acc.add(*rows(0, 1, 20))          # 15
+    acc.add(*rows(*range(6, 12)))     # 16: three runs merged, 12 distinct
+    acc.add(*rows(0, 1, 20))          # 3: the window alone is counted
     acc.add(*rows())                  # nothing handed over
-    got = acc.finalize()              # 15 sorted once more
+    acc.add(*rows(9, 4, 4))           # no run: sorted on entry, 2 distinct
+    got = acc.finalize()              # two runs, 5 rows, into the table
     assert len(got) == 13 and got["w007"] == (2, 1) and got["w020"] == (1, 1)
-    counts = {k: stats[k] for k in ("merge_rows_in", "merge_rows_sorted",
-                                    "merge_compacts")}
-    assert counts == {"merge_rows_in": 19, "merge_rows_sorted": 31,
-                      "merge_compacts": 2}
+    assert got["w004"] == (4, 1)
+    counts = {k: stats[k] for k in (
+        "merge_rows_in", "merge_rows_sorted", "merge_compacts",
+        "merge_runs_in", "merge_runs_unsorted")}
+    # sorted: 16 in the first window, 3 on entry, 5 in the second window;
+    # the merged table's 12 rows never again
+    assert counts == {"merge_rows_in": 22, "merge_rows_sorted": 24,
+                      "merge_compacts": 2, "merge_runs_in": 5,
+                      "merge_runs_unsorted": 1}
     assert stats["compact_s"] > 0 and stats["finalize_decode_s"] > 0
-    # one table and nothing new: finalize has nothing to sort again
+    # one table and nothing new: finalize has nothing to merge
     assert len(acc.finalize()) == 13 and stats["merge_compacts"] == 2
     # an accumulator without an engine keeps its own
     assert PackedCounts().stats["merge_rows_in"] == 0
@@ -558,14 +564,22 @@ def test_wcstream_merge_counters_repeat_and_the_output_is_the_oracles(
     monkeypatch.setattr(PackedCounts.__init__, "__defaults__", (512, None))
     runs = [_stream_main("wcstream", tmp_path) for _ in range(2)]
     assert [rc for rc, _, _ in runs] == [0, 0]
-    counters = ("merge_rows_in", "merge_rows_sorted", "merge_compacts")
+    counters = ("merge_rows_in", "merge_rows_sorted", "merge_compacts",
+                "merge_runs_in", "merge_runs_unsorted")
     first, second = ({k: ps[k] for k in counters} for _, ps, _ in runs)
     assert first == second
     ps = runs[0][1]
     # every confirmed row of every step is handed to the accumulator
     assert first["merge_rows_in"] == sum(ps["device_rows"])
     assert first["merge_compacts"] > 1
-    assert first["merge_rows_sorted"] > first["merge_rows_in"]
+    # every batch is a device's step table, a run as it arrives: nothing
+    # is sorted on entry, every row is ordered once, in its window, and
+    # the merged table never again
+    assert first["merge_runs_in"] >= ps["steps"]
+    assert first["merge_runs_unsorted"] == 0
+    assert first["merge_rows_sorted"] == first["merge_rows_in"]
+    # a window of 512 rows: the compactions follow the rows handed over
+    assert first["merge_compacts"] <= first["merge_rows_in"] // 512 + 1
     # what is written has not changed: each partition holds its words in
     # order, one "word count" line each, as the parent commit wrote them
     (src,) = glob.glob(str(tmp_path / "inputs" / "*"))
