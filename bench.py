@@ -54,8 +54,7 @@ Environment knobs:
                           (default 5; 0 disables): upload one stream
                           chunk once, run the wc step K times on the
                           HBM-resident buffer, report median kernel-only
-                          MB/s per grouper (kernel_sort_mbps /
-                          kernel_hash_mbps).
+                          MB/s (kernel_sort_mbps).
   DSI_BENCH_TFIDF_MB      size of the TF-IDF engine row (default 16;
                           0 disables; accelerators run it only when the
                           knob is set explicitly): the pipelined wave
@@ -732,9 +731,7 @@ def run_kernel_row(files) -> dict:
     """Transfer-independent kernel-only measurement: upload ONE
     stream-shaped chunk, run the wc step DSI_BENCH_KERNEL_REPS times
     (default 5; 0 disables) on the HBM-resident buffer, report the
-    median kernel-only MB/s per grouper variant.  Running BOTH groupers
-    makes the sort-vs-hash kernel gap a measured bench artifact instead
-    of a CPU-only extrapolation.
+    median kernel-only MB/s as ``kernel_sort_mbps``.
     """
     reps = int(env_float("DSI_BENCH_KERNEL_REPS", 5))
     if reps <= 0:
@@ -744,7 +741,6 @@ def run_kernel_row(files) -> dict:
     import jax
     import numpy as np
 
-    from dsi_tpu.ops.wordcount import warm_groupers
     from dsi_tpu.parallel.shuffle import default_mesh
     from dsi_tpu.parallel.streaming import (batch_stream, stream_files,
                                             stream_kernel_reps)
@@ -757,17 +753,16 @@ def run_kernel_row(files) -> dict:
     chunk = np.array(chunk)  # detach from the batch-stream buffer
     mb = float(np.count_nonzero(chunk)) / 1e6  # honest: bytes processed
     out = {"kernel_reps": reps, "kernel_mb": round(mb, 2)}
-    for g in warm_groupers():
-        times, exact = stream_kernel_reps(
-            chunk, mesh=mesh, n_reduce=N_REDUCE, u_cap=STREAM_U_CAP,
-            reps=reps, grouper=g, aot=single)
-        med = statistics.median(times)
-        log(f"kernel row [{g}]: {mb:.2f} MB x {reps} reps, median "
-            f"{med:.3f}s = {mb / med:.2f} MB/s (exact={exact})")
-        if exact:  # a rate for an overflowing kernel never enters a trend
-            out[f"kernel_{g}_mbps"] = round(mb / med, 2)
-        else:
-            out[f"kernel_{g}_skipped"] = "kernel overflowed at this shape"
+    times, exact = stream_kernel_reps(
+        chunk, mesh=mesh, n_reduce=N_REDUCE, u_cap=STREAM_U_CAP,
+        reps=reps, aot=single)
+    med = statistics.median(times)
+    log(f"kernel row: {mb:.2f} MB x {reps} reps, median "
+        f"{med:.3f}s = {mb / med:.2f} MB/s (exact={exact})")
+    if exact:  # a rate for an overflowing kernel never enters a trend
+        out["kernel_sort_mbps"] = round(mb / med, 2)
+    else:
+        out["kernel_sort_skipped"] = "kernel overflowed at this shape"
     return out
 
 

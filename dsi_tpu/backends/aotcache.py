@@ -18,9 +18,7 @@ seconds they took, and each is logged on stderr by program name.
 Program-name families: ``wc_kernel*`` and ``corpus_wc*`` single-chunk
 programs, ``stream_step_*``/``stream_pack_*`` streaming programs,
 ``tfidf_wave_*`` the pipelined TF-IDF wave step, ``dacc_*`` the device
-accumulator's fold/clear/pack.  Grouper variants append
-``ops.wordcount.grouper_suffix``: bare names are the sort grouper,
-``*_hg`` the hash grouper.
+accumulator's fold/clear/pack.
 """
 
 from __future__ import annotations

@@ -16,7 +16,7 @@ Usage:
         [--pipeline-depth D] [--device-accumulate] [--sync-every K]
         [--checkpoint-dir DIR] [--checkpoint-every K] [--resume]
         [--ckpt-async] [--ckpt-delta] [--ingest-readers N]
-        [--wire-upload] [--grouper sort|hash] [--stats] inputfiles...
+        [--wire-upload] [--stats] inputfiles...
 
 This is a device entry point: it fails at start unless JAX gives it a TPU
 or the CPU was asked for by name (``JAX_PLATFORMS=cpu``).
@@ -180,12 +180,6 @@ def main(argv=None) -> int:
                         "0.63-0.88x the bytes, HBM sees identical "
                         "tensors (env DSI_STREAM_WIRE; results are "
                         "bit-identical either way)")
-    p.add_argument("--grouper", choices=("sort", "hash"), default=None,
-                   help="pin the kernel's token-grouping strategy "
-                        "(DSI_WC_GROUPER): 'hash' is the measured ~1.8x "
-                        "kernel win the warm ladder now pre-compiles for "
-                        "accelerators too (*_hg AOT entries); sort stays "
-                        "the always-exact fallback rung either way")
     p.add_argument("--stats", action="store_true",
                    help="print to stderr the device, the pipeline_stats "
                         "dict (phase walls + fold/sync/widen counters) "
@@ -209,9 +203,6 @@ def main(argv=None) -> int:
 
     if args.resume and not args.checkpoint_dir:
         p.error("--resume requires --checkpoint-dir")
-
-    if args.grouper:
-        os.environ["DSI_WC_GROUPER"] = args.grouper
 
     if args.trace_dir:
         from dsi_tpu.obs import configure_tracing

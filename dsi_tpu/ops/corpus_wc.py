@@ -61,25 +61,22 @@ _FNV_PRIME = np.uint32(0x01000193)
 
 
 def corpus_kernel(*pieces, max_word_len: int = 16, u_cap: int = 1 << 18,
-                  t_cap_frac: int = 4, grouper: str = "sort"):
+                  t_cap_frac: int = 4):
     """Count every word of the concatenated pieces; emit position-coded rows.
 
     Returns ONE 1-D uint32 array of length ``2*u_cap + 4``:
-    ``rows[u_cap, 2]`` flattened (``pos << 7 | len``, ``count``; with the
-    sort grouper rows are in lexicographic word order, with the hash
-    grouper in bucket order — the output writer sorts host-side either
-    way; pad rows zero) followed by the scalars ``[n_unique, max_len,
-    has_high, token_overflow]``.
+    ``rows[u_cap, 2]`` flattened (``pos << 7 | len``, ``count``; rows in
+    lexicographic word order, pad rows zero) followed by the scalars
+    ``[n_unique, max_len, has_high, token_overflow]``.
     """
     import jax.numpy as jnp
 
     chunk = jnp.concatenate(pieces) if len(pieces) > 1 else pieces[0]
-    return _corpus_core(chunk, max_word_len, u_cap, t_cap_frac, grouper)
+    return _corpus_core(chunk, max_word_len, u_cap, t_cap_frac)
 
 
 def corpus_kernel_packed(*pieces_and_table, max_word_len: int = 16,
-                         u_cap: int = 1 << 18, t_cap_frac: int = 4,
-                         grouper: str = "sort"):
+                         u_cap: int = 1 << 18, t_cap_frac: int = 4):
     """``corpus_kernel`` over a 6-bit transport encoding of the corpus.
 
     The host packs 4 corpus bytes into 3 wire bytes when the corpus uses
@@ -104,11 +101,10 @@ def corpus_kernel_packed(*pieces_and_table, max_word_len: int = 16,
     chunk = jnp.zeros_like(codes, dtype=jnp.uint8)
     for k in range(64):
         chunk = jnp.where(codes == k, table[k], chunk)
-    return _corpus_core(chunk, max_word_len, u_cap, t_cap_frac, grouper)
+    return _corpus_core(chunk, max_word_len, u_cap, t_cap_frac)
 
 
-def _corpus_core(chunk, max_word_len: int, u_cap: int, t_cap_frac: int,
-                 grouper: str = "sort"):
+def _corpus_core(chunk, max_word_len: int, u_cap: int, t_cap_frac: int):
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -148,32 +144,14 @@ def _corpus_core(chunk, max_word_len: int, u_cap: int, t_cap_frac: int,
         (start_pos.astype(jnp.uint32) << 7)
         | lengths.astype(jnp.uint32), 0)
 
-    if grouper == "hash":
-        # Scatter/segment grouping (ops/wordcount.py _hash_group): exact
-        # via per-bucket lane verification + dirty-repair sort; the
-        # first-occurrence poslen is the per-group MIN of the combined
-        # column (length is group-invariant, so min == min position).
-        from dsi_tpu.ops.wordcount import _hash_group, fnv1a32_packed
-
-        fnv_t = fnv1a32_packed(jnp.stack(packed_cols, axis=1), lengths,
-                               max_word_len)
-        _, _, cnt_u, poslen_u, n_unique, group_of = _hash_group(
-            packed_cols, lengths, valid, fnv_t, u_cap=u_cap,
-            max_word_len=max_word_len, extra=poslen_tok)
-        uvalid = jnp.arange(u_cap, dtype=jnp.int32) < n_unique
-        poslen = jnp.where(uvalid, poslen_u, 0)
-        totals = jnp.where(uvalid, cnt_u, 0)
-        token_overflow = token_overflow | group_of
-    else:
-        # Stable sort over the key lanes (wordcount.py lex_sort): within
-        # a group of equal words the original token order (ascending
-        # position) survives, so each group's FIRST row carries the
-        # word's first occurrence position (its length is
-        # group-invariant).
-        *scols, sposlen = lex_sort(packed_cols, (poslen_tok,))
-        _, totals, upos, ovalid, n_unique = group_sorted(
-            tuple(scols), jnp.ones(t_cap, jnp.int32), u_cap)
-        poslen = jnp.where(ovalid, sposlen[upos], 0)
+    # Stable sort over the key lanes (wordcount.py lex_sort): within a
+    # group of equal words the original token order (ascending position)
+    # survives, so each group's FIRST row carries the word's first
+    # occurrence position (its length is group-invariant).
+    *scols, sposlen = lex_sort(packed_cols, (poslen_tok,))
+    _, totals, upos, ovalid, n_unique = group_sorted(
+        tuple(scols), jnp.ones(t_cap, jnp.int32), u_cap)
+    poslen = jnp.where(ovalid, sposlen[upos], 0)
     rows = jnp.stack([poslen, totals.astype(jnp.uint32)], axis=1)
     has_high = jnp.any(chunk >= 128)
     scalars = jnp.stack([
@@ -280,19 +258,15 @@ class CorpusResult:
 
 def corpus_wordcount(raws: Sequence[bytes], *, piece_size: int | None = None,
                      max_word_len: int = 16, u_cap: int = 1 << 18,
-                     use_aot: bool = True, pack6: bool = False,
-                     grouper: str | None = None) -> Optional[CorpusResult]:
+                     use_aot: bool = True,
+                     pack6: bool = False) -> Optional[CorpusResult]:
     """Exact whole-corpus counts, or None when the host path is needed
     (non-ASCII bytes or a word longer than 64 — same escape contract as
     ``count_words_host_result``).  Retries wider static shapes on overflow.
 
     ``pack6=True`` ships the corpus 6 bits per byte (25% fewer upload
     bytes — the upload is this platform's measured wall) when its alphabet
-    fits in 64 symbols, transparently reverting to raw bytes when not.
-
-    ``grouper`` (default: the platform-adaptive ``default_grouper``)
-    picks the grouping stage; an unresolvable hash-grouper collision
-    retries through the sort grouper, the always-exact last rung."""
+    fits in 64 symbols, transparently reverting to raw bytes when not."""
     import jax
 
     buf, n_pieces, piece_size = _resolve_pieces(raws, piece_size)
@@ -321,28 +295,18 @@ def corpus_wordcount(raws: Sequence[bytes], *, piece_size: int | None = None,
     if table is not None:
         views.append(table)
 
-    from dsi_tpu.ops.wordcount import grouper_ladder
-
-    if grouper is None:
-        groupers = grouper_ladder()
-    else:
-        groupers = (grouper, "sort") if grouper != "sort" else ("sort",)
-
     def run(mwl: int, cap: int):
         # The shared overflow/retry discipline (exactness_retry) drives mwl
-        # and cap; the token-buffer frac and grouper retries are local, as
-        # in the other callers (wordcount, shuffle, tfidf).
-        for g in groupers:
-            for frac in (4, 2):  # exact token bound is n//2+1
-                fn = _get_compiled(n_pieces, piece_size, mwl, cap,
-                                   frac, use_aot, pack6, g)
-                from dsi_tpu.ops import xfer  # host-side; NOT a kernel dep
+        # and cap; the token-buffer frac retry is local, as in the other
+        # callers (wordcount, shuffle, tfidf).
+        for frac in (4, 2):  # exact token bound is n//2+1
+            fn = _get_compiled(n_pieces, piece_size, mwl, cap,
+                               frac, use_aot, pack6)
+            from dsi_tpu.ops import xfer  # host-side; NOT a kernel dep
 
-                dev_args = xfer.put_views(views)
-                out = np.asarray(fn(*dev_args))   # the ONE D2H round trip
-                nu, max_len, has_high, tok_of = (int(x) for x in out[-4:])
-                if not tok_of:
-                    break
+            dev_args = xfer.put_views(views)
+            out = np.asarray(fn(*dev_args))   # the ONE D2H round trip
+            nu, max_len, has_high, tok_of = (int(x) for x in out[-4:])
             if not tok_of:
                 break
 
@@ -385,13 +349,9 @@ def _example_and_fn(n_pieces: int, piece_size: int, pack6: bool):
 
 @functools.lru_cache(maxsize=64)
 def _get_compiled(n_pieces: int, piece_size: int, mwl: int, cap: int,
-                  frac: int, use_aot: bool, pack6: bool = False,
-                  grouper: str = "sort"):
+                  frac: int, use_aot: bool, pack6: bool = False):
     static = {"max_word_len": mwl, "u_cap": cap, "t_cap_frac": frac}
     example, fn, name = _example_and_fn(n_pieces, piece_size, pack6)
-    if grouper != "sort":  # sort keeps its historical, readable name
-        static["grouper"] = grouper
-        name += f"_g{grouper}"
     from dsi_tpu.backends.aotcache import cached_compile
 
     return cached_compile(name, fn, example, static=static, x64=True)
@@ -446,13 +406,13 @@ def write_corpus_output(res: CorpusResult, n_reduce: int,
     """Materialise mr-out-<r> files straight from the position-coded table.
 
     Rows are first put in lexicographic word order host-side (ASCII byte
-    order == Python ``sorted`` order on str; a no-op permutation for the
-    sort grouper's already-ordered rows, required for the hash grouper's
-    bucket-ordered rows), then a stable sort by partition leaves each
-    partition's lines in the reference's within-file order
-    (``mr/worker.go:124-146``).  Everything is vectorized numpy — this
-    sits inside the bench's timed window (~0.3 s of Python loop before,
-    ~30 ms now at 137k unique words).
+    order == Python ``sorted`` order on str; the identity permutation on
+    the kernel's rows, which arrive in that order, and what makes the
+    writer hold for any ``CorpusResult``), then a stable sort by
+    partition leaves each partition's lines in the reference's
+    within-file order (``mr/worker.go:124-146``).  Everything is
+    vectorized numpy — this sits inside the bench's timed window (~0.3 s
+    of Python loop before, ~30 ms now at 137k unique words).
     """
     from dsi_tpu.utils.atomicio import atomic_write
 

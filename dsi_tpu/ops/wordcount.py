@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import functools
 import itertools
-import os
 from typing import Dict, Optional
 
 import jax
@@ -157,14 +156,6 @@ def unpack_key_lanes(cols64, k: int) -> tuple:
             w = cols64[j // 2]
             out.append(((w >> 32) if j % 2 == 0 else w).astype(jnp.uint32))
     return tuple(out)
-
-
-def unpack_key_rows(rows64: jax.Array, k: int) -> jax.Array:
-    """[n, ceil(k/2)] packed uint64 key rows -> [n, k] uint32 lane rows —
-    the shared unpack-and-restack step after a packed sort+group."""
-    cols = unpack_key_lanes(
-        tuple(rows64[:, j] for j in range(rows64.shape[1])), k)
-    return jnp.stack(cols, axis=1)
 
 
 @jax.named_scope("sort")
@@ -296,137 +287,8 @@ def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     return keys, totals, upos, ovalid, n_unique
 
 
-def _hash_group(packed_cols: tuple, lengths: jax.Array, valid: jax.Array,
-                fnv_t: jax.Array, *, u_cap: int, max_word_len: int,
-                extra=None):
-    """Group identical tokens WITHOUT the big sort: scatter tokens into
-    fnv-addressed buckets and verify each bucket holds exactly one
-    distinct word (segment-min == segment-max over every packed key
-    lane).  Tokens from buckets that fail the check (distinct words
-    sharing low hash bits — a few hundred per MiB of real text) are
-    compacted into a small fixed buffer and grouped by the exact
-    lexicographic sort, so the result is exact regardless of hash
-    behavior; only if the dirty set overflows its buffer (pathological
-    input) does ``group_overflow`` make the caller re-run the whole
-    chunk through the sort grouper.  Both compactions (the dirty tokens,
-    the clean buckets) are :func:`compact_positions`, as everywhere on
-    the device path; the bucketing itself stays segment ops.
-
-    Motivation (measured on XLA:CPU): at 1 MiB/4 tokens the big
-    lexicographic sort costs ~99 ms while the segment-op group + t_cap/8
-    repair sort costs ~50 ms — the big sort is the kernel's dominant
-    cost there and this halves it.  The sort grouper remains the default
-    for accelerator platforms (TPU scatter characteristics differ; the
-    two groupers' run times are not measured on the chip yet).
-
-    ``extra``, when given, is a per-token uint32 payload reduced by MIN
-    within each group (the corpus kernel's first-occurrence position
-    coding) and returned as a fifth table.
-
-    Returns (keys64_u tuple [u_cap] per lane, len_u, cnt_u, extra_u or
-    None, n_unique, group_overflow).
-    """
-    t_cap = lengths.shape[0]
-    # ~1x t_cap buckets, power of two (the index is a low-bits mask):
-    # measured on this CPU, the halved segment arrays beat the doubled
-    # (still tiny) dirty fraction.  d_cap absorbs the worst realistic
-    # dirty set — a hot word ("the" ~6% of English tokens, i.e. about
-    # t_cap/4 x 0.24) landing in a dirty bucket — with the
-    # group_overflow escape for pathological inputs.
-    n_buckets = 1 << max(10, int(t_cap).bit_length() - 1)
-    d_cap = max(1 << 8, t_cap // 16)
-    keys64 = pack_key_lanes(packed_cols)
-    k64 = len(keys64)
-
-    # Level 1: bucket by the (reference-exact) fnv1a hash's low bits.
-    idx1 = jnp.where(valid, (fnv_t & jnp.uint32(n_buckets - 1))
-                     .astype(jnp.int32), n_buckets)
-    tot1 = jax.ops.segment_sum(
-        jnp.where(valid, 1, 0), idx1, num_segments=n_buckets + 1)[:n_buckets]
-    len1 = jax.ops.segment_max(
-        jnp.where(valid, lengths, 0), idx1,
-        num_segments=n_buckets + 1)[:n_buckets]
-    ex1 = None
-    if extra is not None:
-        ex1 = jax.ops.segment_min(
-            jnp.where(valid, extra, jnp.uint32(0xFFFFFFFF)), idx1,
-            num_segments=n_buckets + 1)[:n_buckets]
-    keys1 = []
-    with enable_x64(True):
-        dirty = jnp.zeros(n_buckets, jnp.bool_)
-        for kcol in keys64:
-            mn = jax.ops.segment_min(
-                kcol, idx1, num_segments=n_buckets + 1)[:n_buckets]
-            mx = jax.ops.segment_max(
-                kcol, idx1, num_segments=n_buckets + 1)[:n_buckets]
-            dirty |= mn != mx
-            keys1.append(mx)
-    occ1 = tot1 > 0
-    dirty &= occ1
-
-    # Dirty repair: compact the (few) tokens of dirty buckets and group
-    # them with the exact sort — small static buffer, zero collision
-    # risk, no retry unless it overflows.
-    in_dirty = valid & dirty[jnp.clip(idx1, 0, n_buckets - 1)]
-    n_dirty_tokens = jnp.sum(in_dirty, dtype=jnp.int32)
-    group_overflow = n_dirty_tokens > d_cap
-    dpos = compact_positions(in_dirty, d_cap, 0)
-    dvalid = jnp.arange(d_cap, dtype=jnp.int32) < n_dirty_tokens
-    dlen = jnp.where(dvalid, lengths[dpos], 0)
-    dlanes = tuple(jnp.where(dvalid, col[dpos], jnp.uint32(_PAD_KEY))
-                   for col in packed_cols)
-    k = len(dlanes)
-    if extra is None:
-        sorted_ops = lex_sort(dlanes, (dlen,))
-        dsex = None
-    else:
-        dex = jnp.where(dvalid, extra[dpos], jnp.uint32(0xFFFFFFFF))
-        # extra rides as an additional SORT KEY (not a group key):
-        # within a word's run rows order ascending by it, so the
-        # run's first row carries the group minimum.
-        sorted_ops = lex_sort(dlanes + (dex,), (dlen,))
-        dsex = sorted_ops[k]
-    dgk, dtot, dupos, dovalid, n_du = group_sorted(
-        sorted_ops[:k], jnp.ones(d_cap, jnp.int32), u_cap)
-    dslens = sorted_ops[-1]
-    # The repaired uniques' lanes, packed like the clean buckets' keys.
-    dkeys64 = pack_key_lanes(tuple(dgk[dupos, j] for j in range(k)))
-
-    # Assemble: clean level-1 buckets first, dirty-repair uniques after.
-    clean1 = occ1 & ~dirty
-    n_clean1 = jnp.sum(clean1, dtype=jnp.int32)
-    n_unique = n_clean1 + n_du
-    cpos1 = compact_positions(clean1, u_cap, n_buckets - 1)
-    v1 = jnp.arange(u_cap, dtype=jnp.int32) < n_clean1
-    dst2 = jnp.where(dovalid, jnp.arange(u_cap, dtype=jnp.int32) + n_clean1,
-                     u_cap)
-
-    with enable_x64(True):
-        out_keys = []
-        for j in range(k64):
-            # A clean bucket's segment-max IS its one word's lane value.
-            col = jnp.where(v1, keys1[j][cpos1], jnp.uint64(0))
-            col = col.at[dst2].set(
-                jnp.where(dovalid, dkeys64[j], jnp.uint64(0)),
-                mode="drop")
-            out_keys.append(col)
-    len_u = jnp.where(v1, len1[cpos1], 0)
-    len_u = len_u.at[dst2].set(
-        jnp.where(dovalid, dslens[dupos], 0).astype(len_u.dtype),
-        mode="drop")
-    cnt_u = jnp.where(v1, tot1[cpos1], 0)
-    cnt_u = cnt_u.at[dst2].set(jnp.where(dovalid, dtot, 0), mode="drop")
-    ex_u = None
-    if extra is not None:
-        ex_u = jnp.where(v1, ex1[cpos1], jnp.uint32(0))
-        ex_u = ex_u.at[dst2].set(
-            jnp.where(dovalid, dsex[dupos], jnp.uint32(0)), mode="drop")
-    return tuple(out_keys), len_u, cnt_u, ex_u, n_unique, group_overflow
-
-
 def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
                         u_cap: int = 1 << 17, t_cap_frac: int = 4,
-                        grouper: str = "sort",
                         doc_sep: Optional[int] = None):
     """Exact unique-word counts over one uint8 chunk (zero-padded tail).
 
@@ -442,16 +304,13 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
     place in the chunk.  A token's place is the count of separators
     before its first byte, a prefix sum over the chunk read at
     ``start_pos``, and it sorts as one more key lane behind the word's.
-    The sort grouper only; with ``None`` the program is what it was.
+    With ``None`` the program is what it was.
 
-    ``grouper`` selects how identical tokens are grouped: ``"sort"`` (the
-    default — lexicographic :func:`lex_sort`) or
-    ``"hash"`` (scatter/segment-op bucketing with exact collision
-    verification and sort fallback, ~2x faster on the CPU backend where
-    XLA's sort is the measured kernel floor).  A
-    hash-grouper attempt that cannot prove exactness reports
-    ``token_overflow`` so the shared retry ladder re-runs it; the wrapper
-    then routes the chunk to the sort grouper.
+    Identical tokens are grouped by an exact lexicographic sort of the
+    key lanes (:func:`lex_sort`) and a scan of the run boundaries
+    (:func:`group_sorted`), on every platform.  ``token_overflow`` says
+    the chunk holds more tokens than ``t_cap``; the callers' ladders then
+    run it again at ``t_cap_frac=2``, which no chunk overflows.
 
     Not jitted itself so it can be inlined into larger programs (the
     ``shard_map`` SPMD step in ``dsi_tpu/parallel/shuffle.py`` traces it per
@@ -503,23 +362,6 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
                       jnp.uint32(_PAD_KEY))
             for j in range(k))
 
-    if doc_sep is not None and grouper != "sort":
-        raise ValueError("tokenize_group_core: a packed chunk groups with "
-                         f"the sort grouper, not {grouper!r}")
-
-    if grouper == "hash":
-        fnv_t = fnv1a32_packed(jnp.stack(packed_cols, axis=1), lengths,
-                               max_word_len)
-        keys64_u, len_u, cnt_u, _, n_unique, group_of = _hash_group(
-            packed_cols, lengths, valid, fnv_t, u_cap=u_cap,
-            max_word_len=max_word_len)
-        with enable_x64(True):
-            packed_u = unpack_key_rows(jnp.stack(keys64_u, axis=1), k)
-        fnv_u = fnv1a32_packed(packed_u, len_u, max_word_len)
-        has_high = jnp.any(chunk >= 128)
-        return (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
-                token_overflow | group_of)
-
     # Group identical words: lexicographic sort over the key lanes
     # (lex_sort: one single-key pass per lane), then run boundaries.  A
     # packed chunk's document lane sorts behind the word's.
@@ -542,87 +384,30 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
 
 count_words_kernel = x64_scoped(jax.jit(
     tokenize_group_core,
-    static_argnames=("max_word_len", "u_cap", "t_cap_frac", "grouper",
-                     "doc_sep")))
-
-
-def default_grouper() -> str:
-    """Platform-adaptive grouping strategy: ``hash`` on the CPU backend
-    (where the lexicographic sort is the measured kernel floor), ``sort``
-    on accelerators until on-chip evidence says otherwise.
-    ``DSI_WC_GROUPER`` pins the choice; the warm ladder compiles BOTH
-    variants (``warm_groupers`` below, the ``*_hg`` programs)."""
-    env = os.environ.get("DSI_WC_GROUPER")
-    if env in ("sort", "hash"):
-        return env
-    return "hash" if jax.devices()[0].platform == "cpu" else "sort"
-
-
-def grouper_suffix(grouper: str) -> str:
-    """Program-name suffix for a grouper variant: the sort grouper
-    keeps the bare names, the hash grouper gets ``_hg``.  One definition
-    shared by every program namer (``wc_kernel`` here, ``stream_step_*``
-    in parallel/streaming.py, ``tfidf_wave_*`` in parallel/tfidf.py) so
-    the warm ladder and the runs agree on the key by construction."""
-    if grouper == "sort":
-        return ""
-    return "_hg" if grouper == "hash" else f"_g{grouper}"
-
-
-def warm_groupers() -> tuple:
-    """The grouper variants the warm ladder compiles for every program
-    family: both rungs, on every platform.  Distinct from
-    :func:`grouper_ladder` (the rungs ONE run walks, platform/env
-    dependent): warming only the ladder would leave an env-selected
-    ``DSI_WC_GROUPER=hash`` accelerator run cold."""
-    return ("hash", "sort")
-
-
-def grouper_ladder() -> tuple:
-    """The retry rungs every kernel wrapper walks: the platform's
-    preferred grouper first, with the sort grouper as the always-exact
-    last rung (a hash-grouper collision overflow cannot clear at frac=2;
-    the sort can never overflow there).  One definition so the four
-    wrappers (here, parallel/shuffle.py, parallel/streaming.py,
-    parallel/tfidf.py) cannot drift."""
-    g0 = default_grouper()
-    return (g0, "sort") if g0 != "sort" else ("sort",)
+    static_argnames=("max_word_len", "u_cap", "t_cap_frac", "doc_sep")))
 
 
 @functools.lru_cache(maxsize=256)
-def _cached_kernel(n: int, max_word_len: int, u_cap: int, t_cap_frac: int,
-                   grouper: str = "sort"):
+def _cached_kernel(n: int, max_word_len: int, u_cap: int, t_cap_frac: int):
     """The single-chunk kernel via the persistent AOT executable cache
     (backends/aotcache.py): a fresh worker process loads the serialized
     executable in milliseconds instead of re-paying the XLA compile —
     essential on platforms where jit compiles run to minutes and every
     mrworker is its own process (main/test-mr.sh:43-45 spawns three).
-    lru_cached so repeat dispatches skip the cache-key fingerprinting.
-
-    The ``grouper`` static enters the key/name only for the hash variant
-    (``grouper_suffix``: ``wc_kernel_hg``) — purely so sort-grouper
-    cache filenames keep their historical, readable names.  (It is NOT a
-    warm-cache-survival guarantee: the key also fingerprints this
-    module's source, so any kernel edit misses and recompiles
-    regardless.)"""
+    lru_cached so repeat dispatches skip the cache-key fingerprinting."""
     from dsi_tpu.backends.aotcache import cached_compile
 
     example = (jax.ShapeDtypeStruct((n,), np.uint8),)
     static = {"max_word_len": max_word_len, "u_cap": u_cap,
               "t_cap_frac": t_cap_frac}
-    name = "wc_kernel"
-    if grouper != "sort":
-        static["grouper"] = grouper
-        name += grouper_suffix(grouper)
-    return cached_compile(name, tokenize_group_core, example,
+    return cached_compile("wc_kernel", tokenize_group_core, example,
                           static=static, x64=True)
 
 
 def run_count_kernel(chunk: jax.Array, *, max_word_len: int, u_cap: int,
-                     t_cap_frac: int, grouper: str = "sort"):
+                     t_cap_frac: int):
     """Dispatch one chunk through the AOT-cached executable."""
-    fn = _cached_kernel(int(chunk.shape[0]), max_word_len, u_cap, t_cap_frac,
-                        grouper)
+    fn = _cached_kernel(int(chunk.shape[0]), max_word_len, u_cap, t_cap_frac)
     return fn(chunk)
 
 
@@ -706,22 +491,18 @@ def count_words_host_result(
         chunk = _pad_pow2(data)
     with _span("upload", bytes=chunk.nbytes):
         dev_chunk = jnp.asarray(chunk)
-    groupers = grouper_ladder()
     attempts = itertools.count()
 
     def run(mwl: int, cap: int):
-        for g in groupers:
-            for frac in (4, 2):  # exact token bound is n//2+1
-                # dispatch to the first blocking scalar read
-                with _span("kernel", program="wc_kernel" + grouper_suffix(g),
-                           attempt=next(attempts), cap=cap):
-                    (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len,
-                     has_high, tok_of) = run_count_kernel(
-                        dev_chunk, max_word_len=mwl, u_cap=cap,
-                        t_cap_frac=frac, grouper=g)
-                    overflow = bool(tok_of)
-                if not overflow:
-                    break
+        for frac in (4, 2):  # exact token bound is n//2+1
+            # dispatch to the first blocking scalar read
+            with _span("kernel", program="wc_kernel",
+                       attempt=next(attempts), cap=cap):
+                (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len,
+                 has_high, tok_of) = run_count_kernel(
+                    dev_chunk, max_word_len=mwl, u_cap=cap,
+                    t_cap_frac=frac)
+                overflow = bool(tok_of)
             if not overflow:
                 break
         nu = int(n_unique)
@@ -755,15 +536,13 @@ def count_words_many(datas, *, max_word_len: int = 16,
     as ``count_words_host_result``.
     """
     launches = []
-    g0 = default_grouper()
     for data in datas:
         chunk = _pad_pow2(data)
         cap = rung0_cap(len(chunk), u_cap)
         launches.append((data, cap,
                          run_count_kernel(jnp.asarray(chunk),
                                           max_word_len=max_word_len,
-                                          u_cap=cap, t_cap_frac=4,
-                                          grouper=g0)))
+                                          u_cap=cap, t_cap_frac=4)))
     results = []
     for data, cap, out in launches:
         (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,

@@ -105,9 +105,7 @@ from dsi_tpu.ops.wordcount import (
     _PAD_KEY,
     _shift_left,
     compact_positions,
-    grouper_ladder,
     rung0_cap,
-    warm_groupers,
 )
 from dsi_tpu.parallel.merge import PackedCounts, PostingsTable
 from dsi_tpu.parallel.pipeline import (
@@ -1130,8 +1128,7 @@ def warm_grepstream_aot(mesh: Mesh | None = None,
 
 def _idx_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
                      n_reduce: int, max_word_len: int, u_cap: int,
-                     t_cap_frac: int, grouper: str = "sort",
-                     pack_docs: bool = False):
+                     t_cap_frac: int, pack_docs: bool = False):
     """Per-device wave body: the word-count map prologue over its
     document with a (tf ≡ 1, doc, part) payload — one posting row per
     distinct word per document — routed by the shared shuffle primitive
@@ -1159,7 +1156,7 @@ def _idx_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
             map_prologue(
                 chunk, n_dev=n_dev, n_reduce=n_reduce,
                 max_word_len=max_word_len, u_cap=u_cap,
-                t_cap_frac=t_cap_frac, grouper=grouper,
+                t_cap_frac=t_cap_frac,
                 doc_sep=DOC_SEP if pack_docs else None)
 
     with jax.named_scope("shuffle"):
@@ -1197,12 +1194,11 @@ def _idx_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
 
 def _idx_wave_step_impl(chunks, doc_ids, *, n_dev: int, n_reduce: int,
                         max_word_len: int, u_cap: int, mesh: Mesh,
-                        t_cap_frac: int = 4, grouper: str = "sort",
-                        pack_docs: bool = False):
+                        t_cap_frac: int = 4, pack_docs: bool = False):
     body = functools.partial(_idx_device_step, n_dev=n_dev,
                              n_reduce=n_reduce, max_word_len=max_word_len,
                              u_cap=u_cap, t_cap_frac=t_cap_frac,
-                             grouper=grouper, pack_docs=pack_docs)
+                             pack_docs=pack_docs)
     return shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None) if pack_docs else P(AXIS)),
@@ -1217,22 +1213,19 @@ _IDX_DONATE = (0,)
 
 def _idx_program(*, n_dev: int, n_reduce: int, max_word_len: int,
                  u_cap: int, size: int, mesh: Mesh, t_cap_frac: int,
-                 grouper: str = "sort", pack_docs: bool = False):
-    from dsi_tpu.ops.wordcount import grouper_suffix
-
+                 pack_docs: bool = False):
     def fn(chunk, ids):
         return _idx_wave_step_impl(chunk, ids, n_dev=n_dev,
                                    n_reduce=n_reduce,
                                    max_word_len=max_word_len, u_cap=u_cap,
                                    mesh=mesh, t_cap_frac=t_cap_frac,
-                                   grouper=grouper, pack_docs=pack_docs)
+                                   pack_docs=pack_docs)
 
     # The HLO module takes the traced function's name (``_grep_program``),
     # packed or not: one wave program, one name in a device trace.
     fn.__name__ = fn.__qualname__ = "idx_wave_step"
     name = (f"idx_wave_d{n_dev}_r{n_reduce}_w{max_word_len}"
             f"_u{u_cap}_s{size}_f{t_cap_frac}")
-    name += grouper_suffix(grouper)
     if pack_docs:
         name += "_pk"
     return name, fn
@@ -1399,7 +1392,7 @@ def indexer_streaming(
     — or None when any document needs the host path (non-ASCII bytes,
     words longer than 64).  Same exactness discipline as
     ``tfidf_sharded``: waves dispatch optimistically at a sticky
-    (capacity, grouper, frac) rung, scalar checks are deferred until a
+    (capacity, frac) rung, scalar checks are deferred until a
     wave leaves the window, a failed check replays exactly that wave,
     and a word wider than the packed window restarts the walk at the
     64-byte rung.
@@ -1515,9 +1508,6 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                "docs_per_wave_max": 0,
                "upload_s": 0.0, "enqueue_s": 0.0, "kernel_s": 0.0,
                "pull_s": 0.0, "merge_s": 0.0, "replay_s": 0.0})
-    # (word, document) pairs fill a hash grouper's buckets several times
-    # as full as a document's words: the packed program has the sort only.
-    groupers = ("sort",) if pack_docs else grouper_ladder()
     sh_chunk = NamedSharding(mesh, P(AXIS, None))
     sh_ids = NamedSharding(mesh, P(AXIS, None) if pack_docs else P(AXIS))
     if pack_docs:
@@ -1558,8 +1548,7 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
     def begin_rung(mwl: int):
         kk = mwl // 4
         table = PostingsTable()
-        state = {"cap": rung0_cap(size_max, u_cap),
-                 "grouper": groupers[0], "frac": 4}
+        state = {"cap": rung0_cap(size_max, u_cap), "frac": 4}
         outcome = {"high": False, "widen": False}
 
         def buffer_rows(r: np.ndarray) -> None:
@@ -1618,7 +1607,6 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                 start_wave = int(eff["wave"])
                 ck_wave[0] = start_wave
                 state.update({"cap": int(eff["cap"]),
-                              "grouper": eff["grouper"],
                               "frac": int(eff["frac"])})
                 table.restore({k[3:]: v for k, v in resume_arrays.items()
                                if k.startswith("pt_")})
@@ -1686,8 +1674,7 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
             is a full re-base (an invalid delta window forces one)."""
             with _span("ckpt", stats=st, key="ckpt_s", wave=ck_wave[0]):
                 meta = {"mwl": mwl, "wave": ck_wave[0],
-                        "cap": state["cap"], "grouper": state["grouper"],
-                        "frac": state["frac"]}
+                        "cap": state["cap"], "frac": state["frac"]}
                 kind = "full"
                 parts = None
                 with _span("ckpt_capture", lane="ckpt", stats=st,
@@ -1753,7 +1740,7 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                 yield (size, chunk_np, ids_np,
                        sum(doc_lens[i] for i in held), len(held))
 
-        def wave_call(chunk_np, ids_np, size, cap, frac, g):
+        def wave_call(chunk_np, ids_np, size, cap, frac):
             with _span("upload", stats=st, key="upload_s"):
                 chunk = jax.device_put(chunk_np, sh_chunk)
                 ids = jax.device_put(ids_np, sh_ids)
@@ -1763,7 +1750,7 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                        program="idx_wave_step", size=size, cap=cap):
                 fn = _idx_fn((chunk, ids), n_dev=n_dev, n_reduce=n_reduce,
                              max_word_len=mwl, u_cap=cap, size=size,
-                             mesh=mesh, t_cap_frac=frac, grouper=g,
+                             mesh=mesh, t_cap_frac=frac,
                              pack_docs=pack_docs)
                 with _quiet_unusable_donation():
                     return fn(chunk, ids)
@@ -1776,8 +1763,7 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
             st["wave_docs"] += n_held
             st["docs_per_wave_max"] = max(st["docs_per_wave_max"], n_held)
             rows, df, scal = wave_call(chunk_np, ids_np, size,
-                                       state["cap"], state["frac"],
-                                       state["grouper"])
+                                       state["cap"], state["frac"])
             fault_point("post-dispatch")
             return (size, chunk_np, ids_np, rows, df, scal, state["cap"])
 
@@ -1786,13 +1772,10 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
             cap = state["cap"]
             with _span("replay", stats=st, key="replay_s"):
                 while True:
-                    for g in groupers:
-                        for frac in (4, 2):
-                            rows, df, scal = wave_call(chunk_np, ids_np,
-                                                       size, cap, frac, g)
-                            scal_np = np.asarray(scal)
-                            if not scal_np[:, 4].any():
-                                break
+                    for frac in (4, 2):
+                        rows, df, scal = wave_call(chunk_np, ids_np,
+                                                   size, cap, frac)
+                        scal_np = np.asarray(scal)
                         if not scal_np[:, 4].any():
                             break
                     if bool(scal_np[:, 3].any()):
@@ -1811,7 +1794,7 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                             cap *= 4
                         continue
                     break
-            state["cap"], state["grouper"], state["frac"] = cap, g, frac
+            state["cap"], state["frac"] = cap, frac
             return rows, df, scal, scal_np
 
         def commit(rows, df, scal, scal_np):
@@ -2001,10 +1984,9 @@ def warm_indexer_aot(mesh: Mesh | None = None, sizes: Sequence[int] = (
         topk: int = DEFAULT_TOPK, device_accumulate: bool = False,
         mesh_shards: int = 0, pack_docs: bool = False) -> None:
     """Compile + persist the ``idx_wave_*`` shapes an
-    ``indexer_streaming`` run reaches at these wave sizes/capacities
-    (both grouper variants; the packed program has the sort grouper
-    only), plus — with ``device_accumulate`` — the df top-k fold
-    shapes.  From shape structs alone."""
+    ``indexer_streaming`` run reaches at these wave sizes/capacities,
+    plus — with ``device_accumulate`` — the df top-k fold shapes.  From
+    shape structs alone."""
     if mesh is None:
         mesh = default_mesh()
     n_dev = mesh.devices.size
@@ -2016,12 +1998,10 @@ def warm_indexer_aot(mesh: Mesh | None = None, sizes: Sequence[int] = (
                 examples = (sds((n_dev, size), jnp.uint8),
                             sds(ids, jnp.int32))
                 for frac in fracs:
-                    for g in ("sort",) if pack_docs else sorted(
-                            warm_groupers()):
-                        _idx_fn(examples, n_dev=n_dev, n_reduce=n_reduce,
-                                max_word_len=mwl, u_cap=cap, size=size,
-                                mesh=mesh, t_cap_frac=frac, grouper=g,
-                                pack_docs=pack_docs)
+                    _idx_fn(examples, n_dev=n_dev, n_reduce=n_reduce,
+                            max_word_len=mwl, u_cap=cap, size=size,
+                            mesh=mesh, t_cap_frac=frac,
+                            pack_docs=pack_docs)
             if device_accumulate:
                 from dsi_tpu.device.topk import warm_topk_service
 

@@ -101,7 +101,7 @@ def shuffle_rows(rows: jax.Array, dest: jax.Array, *, n_dev: int,
 @jax.named_scope("map")
 def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
                  max_word_len: int, u_cap: int, t_cap_frac: int,
-                 grouper: str = "sort", doc_sep: Optional[int] = None):
+                 doc_sep: Optional[int] = None):
     """Shared per-device map phase: tokenize + combine + partition.
 
     The one place the reference-parity partition rule lives on device:
@@ -120,7 +120,7 @@ def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
     (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
      token_overflow, *docs) = tokenize_group_core(
         chunk, max_word_len=max_word_len, u_cap=u_cap, t_cap_frac=t_cap_frac,
-        grouper=grouper, doc_sep=doc_sep)
+        doc_sep=doc_sep)
     uvalid = jnp.arange(u_cap, dtype=jnp.int32) < n_unique
     part = (fnv_u & jnp.uint32(0x7FFFFFFF)) % jnp.uint32(n_reduce)
     dest = jnp.where(uvalid, (part % n_dev).astype(jnp.int32), n_dev)
@@ -129,8 +129,7 @@ def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
 
 
 def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,
-                 max_word_len: int, u_cap: int, t_cap_frac: int,
-                 grouper: str = "sort"):
+                 max_word_len: int, u_cap: int, t_cap_frac: int):
     """Per-device body (runs under shard_map): map, all_to_all, reduce."""
     k = max_word_len // 4
     chunk = chunk.reshape(-1)  # [1, L] block -> [L]
@@ -139,7 +138,7 @@ def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,
     packed_u, len_u, cnt_u, part, dest, (
         n_unique, max_len, has_high, token_overflow) = map_prologue(
         chunk, n_dev=n_dev, n_reduce=n_reduce, max_word_len=max_word_len,
-        u_cap=u_cap, t_cap_frac=t_cap_frac, grouper=grouper)
+        u_cap=u_cap, t_cap_frac=t_cap_frac)
 
     # ── shuffle: the mr-X-Y files become one ICI collective ──
     with jax.named_scope("shuffle"):
@@ -174,14 +173,14 @@ def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,
 
 def _mapreduce_step_impl(chunks: jax.Array, *, n_dev: int, n_reduce: int,
                          max_word_len: int, u_cap: int, mesh: Mesh,
-                         t_cap_frac: int = 4, grouper: str = "sort"):
+                         t_cap_frac: int = 4):
     """The full SPMD job step body — jitted twice below (with and without
     input-buffer donation) so the streaming engine's per-step uploads can
     be consumed by the kernel while ``wordcount_sharded`` keeps reusing
     one uploaded corpus across its retry attempts."""
     body = functools.partial(_device_step, n_dev=n_dev, n_reduce=n_reduce,
                              max_word_len=max_word_len, u_cap=u_cap,
-                             t_cap_frac=t_cap_frac, grouper=grouper)
+                             t_cap_frac=t_cap_frac)
     return _shard_map(
         body, mesh=mesh,
         in_specs=P(AXIS, None),
@@ -190,7 +189,7 @@ def _mapreduce_step_impl(chunks: jax.Array, *, n_dev: int, n_reduce: int,
 
 
 _STEP_STATICS = ("n_dev", "n_reduce", "max_word_len", "u_cap", "t_cap_frac",
-                 "mesh", "grouper")
+                 "mesh")
 
 #: The full SPMD job step, jitted over the mesh.
 #:
@@ -199,11 +198,6 @@ _STEP_STATICS = ("n_dev", "n_reduce", "max_word_len", "u_cap", "t_cap_frac",
 #: [D, D*u_cap, K], byte lengths, summed counts, reduce-partition ids, and a
 #: [D, 5] scalar block (m_unique, n_unique, max_len, has_high,
 #: token_overflow).
-#:
-#: ``grouper`` (ops/wordcount.py default_grouper): with ``"hash"`` the
-#: per-device map groups by scattered hash buckets instead of the big
-#: sort; an unresolvable collision rides the token_overflow scalar and
-#: the host wrapper re-runs the step with ``"sort"``.
 mapreduce_step = x64_scoped(
     jax.jit(_mapreduce_step_impl, static_argnames=_STEP_STATICS))
 
@@ -291,19 +285,13 @@ def wordcount_sharded(
     n_dev = mesh.devices.size
     chunks_np, shard_len = shard_text(data, n_dev)
     chunks = jnp.asarray(chunks_np)
-    from dsi_tpu.ops.wordcount import grouper_ladder
-
-    groupers = grouper_ladder()
 
     def run(mwl: int, cap: int):
-        for g in groupers:
-            for frac in (4, 2):  # exact token bound is n//2+1
-                keys, lens, cnts, parts, scal = mapreduce_step(
-                    chunks, n_dev=n_dev, n_reduce=n_reduce, max_word_len=mwl,
-                    u_cap=cap, mesh=mesh, t_cap_frac=frac, grouper=g)
-                scal = np.asarray(scal)
-                if not scal[:, 4].any():
-                    break
+        for frac in (4, 2):  # exact token bound is n//2+1
+            keys, lens, cnts, parts, scal = mapreduce_step(
+                chunks, n_dev=n_dev, n_reduce=n_reduce, max_word_len=mwl,
+                u_cap=cap, mesh=mesh, t_cap_frac=frac)
+            scal = np.asarray(scal)
             if not scal[:, 4].any():
                 break
 

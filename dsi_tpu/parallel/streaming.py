@@ -117,12 +117,7 @@ from dsi_tpu.ckpt import (
 from dsi_tpu.device.policy import SyncPolicy, mesh_shards_default
 from dsi_tpu.device.table import DeviceTable, _quiet_unusable_donation
 from dsi_tpu.obs import metrics_scope, span as _span
-from dsi_tpu.ops.wordcount import (
-    exactness_retry,
-    grouper_ladder,
-    rung0_cap,
-    warm_groupers,
-)
+from dsi_tpu.ops.wordcount import exactness_retry, rung0_cap
 from dsi_tpu.ops import wirecodec
 from dsi_tpu.parallel.merge import PackedCounts
 from dsi_tpu.parallel.pipeline import (
@@ -268,24 +263,18 @@ def stream_files(paths: Sequence[str],
 
 
 def _step_program(*, n_dev: int, n_reduce: int, max_word_len: int,
-                  u_cap: int, mesh: Mesh, t_cap_frac: int,
-                  grouper: str = "sort"):
+                  u_cap: int, mesh: Mesh, t_cap_frac: int):
     """The (name, fn) pair for one compiled ``mapreduce_step`` shape —
     single definition shared by the cached-compile path and the warmer,
-    so the warmer's key is by construction the key a run compiles.  The
-    sort grouper keeps the bare name; the hash grouper gets the ``_hg``
-    suffix (``ops.wordcount.grouper_suffix``)."""
-    import dsi_tpu.ops.wordcount as _wc
+    so the warmer's key is by construction the key a run compiles."""
 
     def fn(c):
         return _mapreduce_step_impl(c, n_dev=n_dev, n_reduce=n_reduce,
                                     max_word_len=max_word_len, u_cap=u_cap,
-                                    mesh=mesh, t_cap_frac=t_cap_frac,
-                                    grouper=grouper)
+                                    mesh=mesh, t_cap_frac=t_cap_frac)
 
     name = (f"stream_step_d{n_dev}_r{n_reduce}_w{max_word_len}"
             f"_u{u_cap}_f{t_cap_frac}")
-    name += _wc.grouper_suffix(grouper)
     return name, fn
 
 
@@ -373,21 +362,14 @@ def warm_stream_aot(mesh: Mesh | None = None, chunk_bytes: int = 1 << 20,
     if mesh is None:
         mesh = default_mesh()
     n_dev = mesh.devices.size
-    # Warm BOTH groupers on every platform (ops/wordcount.warm_groupers):
-    # the hash grouper is promoted into the accelerator warm ladder as
-    # ``*_hg`` entries, so a DSI_WC_GROUPER=hash run on the chip loads a
-    # serialized executable instead of paying the remote cold compile —
-    # sort stays the always-exact fallback rung either way.
-    groupers = warm_groupers()
     for mwl in word_lens:
         for cap in caps:
             chunks, rows, pack_args = _stream_examples(n_dev, chunk_bytes,
                                                        cap, mwl)
             for frac in fracs:
-                for g in sorted(groupers):
-                    _aot_step_fn(chunks, n_dev=n_dev, n_reduce=n_reduce,
-                                 max_word_len=mwl, u_cap=cap, mesh=mesh,
-                                 t_cap_frac=frac, grouper=g)
+                _aot_step_fn(chunks, n_dev=n_dev, n_reduce=n_reduce,
+                             max_word_len=mwl, u_cap=cap, mesh=mesh,
+                             t_cap_frac=frac)
             _aot_pack_fn(pack_args, mp=rows)
             if device_accumulate:
                 # Fold/clear/pack shapes for the device accumulator at
@@ -402,25 +384,24 @@ def warm_stream_aot(mesh: Mesh | None = None, chunk_bytes: int = 1 << 20,
 def warm_kernel_row(mesh: Mesh | None = None, chunk_bytes: int = 1 << 21,
                     n_reduce: int = 10, max_word_len: int = 16,
                     u_cap: int = 1 << 15) -> None:
-    """Compile the NON-donated step programs the bench's
-    kernel-only row runs (both grouper variants), from shape structs
-    alone — the rep loop re-executes one program on an HBM-resident
-    buffer, so its input cannot be donated, and a non-donated program is
-    a distinct cache key from the pipeline's donated one."""
+    """Compile the NON-donated step program the bench's kernel-only row
+    runs, from shape structs alone — the rep loop re-executes one program
+    on an HBM-resident buffer, so its input cannot be donated, and a
+    non-donated program is a distinct cache key from the pipeline's
+    donated one."""
     if mesh is None:
         mesh = default_mesh()
     n_dev = mesh.devices.size
     chunks, _, _ = _stream_examples(n_dev, chunk_bytes, u_cap, max_word_len)
-    for g in warm_groupers():
-        _aot_step_fn(chunks, donate=False, n_dev=n_dev, n_reduce=n_reduce,
-                     max_word_len=max_word_len, u_cap=u_cap, mesh=mesh,
-                     t_cap_frac=4, grouper=g)
+    _aot_step_fn(chunks, donate=False, n_dev=n_dev, n_reduce=n_reduce,
+                 max_word_len=max_word_len, u_cap=u_cap, mesh=mesh,
+                 t_cap_frac=4)
 
 
 def stream_kernel_reps(chunk_np: np.ndarray, mesh: Mesh | None = None,
                        n_reduce: int = 10, max_word_len: int = 16,
                        u_cap: int = 1 << 15, reps: int = 5,
-                       grouper: str = "sort", aot: bool = True):
+                       aot: bool = True):
     """Transfer-independent kernel-only measurement: upload ``chunk_np``
     ONCE, run the stream's ``mapreduce_step`` ``reps`` times on the
     HBM-resident buffer (non-donated program, so the buffer survives
@@ -439,7 +420,7 @@ def stream_kernel_reps(chunk_np: np.ndarray, mesh: Mesh | None = None,
     sharding = NamedSharding(mesh, PartitionSpec(AXIS, None))
     chunks = jax.device_put(chunk_np, sharding)
     kw = dict(n_dev=n_dev, n_reduce=n_reduce, max_word_len=max_word_len,
-              u_cap=u_cap, mesh=mesh, t_cap_frac=4, grouper=grouper)
+              u_cap=u_cap, mesh=mesh, t_cap_frac=4)
     if aot:
         fn = _aot_step_fn(chunks, donate=False, **kw)
     else:
@@ -673,15 +654,14 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         mesh = default_mesh()
     n_dev = mesh.devices.size
     depth = pipeline_depth(depth)
-    groupers = grouper_ladder()
     # Sticky dispatch rung: starts where the sync ladder would, and only
     # ever moves toward more headroom (run_step_sync records the rung
-    # that cleared) — cap and word window widen, and grouper/frac follow
-    # the last cleared combination so a stream that consistently
-    # token-overflows the optimistic frac (dense 1-letter words) or needs
-    # the sort fallback doesn't replay every step forever.
+    # that cleared) — cap and word window widen, and frac follows the
+    # last cleared rung so a stream that consistently token-overflows
+    # the optimistic frac (dense 1-letter words) doesn't replay every
+    # step forever.
     state = {"cap": rung0_cap(chunk_bytes, u_cap), "mwl": max_word_len,
-             "grouper": groupers[0], "frac": 4}
+             "frac": 4}
     sharding = NamedSharding(mesh, PartitionSpec(AXIS, None))
     # The engine's stats dict IS a registry scope (dsi_tpu/obs): the
     # same keys as ever, readable by any consumer as the one documented
@@ -778,7 +758,6 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                                  steps=int(eff["steps"]))
                 state.update({"cap": int(eff["cap"]),
                               "mwl": int(eff["mwl"]),
-                              "grouper": eff["grouper"],
                               "frac": int(eff["frac"])})
                 acc.restore({k[4:]: v for k, v in arrays.items()
                              if k.startswith("acc_")})
@@ -828,8 +807,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                                  n_reduce=n_reduce,
                                  max_word_len=state["mwl"],
                                  u_cap=state["cap"], mesh=mesh,
-                                 t_cap_frac=state["frac"],
-                                 grouper=state["grouper"])
+                                 t_cap_frac=state["frac"])
                     _aot_pack_fn(pack_args, mp=rows)
             stats["resume_gap_s"] = round(time.perf_counter() - t_res, 4)
             stats["resume_cursor"] = start_offset
@@ -890,7 +868,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
             meta = {"cursor": ck_cursor["offset"],
                     "steps": ck_cursor["steps"],
                     "cap": state["cap"], "mwl": state["mwl"],
-                    "grouper": state["grouper"], "frac": state["frac"]}
+                    "frac": state["frac"]}
             kind = "full"
             parts = None
             with _span("ckpt_capture", lane="ckpt", stats=stats,
@@ -933,9 +911,9 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
     # (≤ depth) + one being filled + one being finished.
     pool = BufferPool((n_dev, chunk_bytes), retain=2 * depth + 3)
 
-    def step_call(chunks_dev, mwl, cap, frac, g):
+    def step_call(chunks_dev, mwl, cap, frac):
         kw = dict(n_dev=n_dev, n_reduce=n_reduce, max_word_len=mwl,
-                  u_cap=cap, mesh=mesh, t_cap_frac=frac, grouper=g)
+                  u_cap=cap, mesh=mesh, t_cap_frac=frac)
         with _quiet_unusable_donation():  # first call per rung compiles
             if aot:
                 return _aot_step_fn(chunks_dev, donate=donate_steps,
@@ -995,17 +973,14 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
             state["mwl"] = mwl    # (sticky for later optimistic dispatches)
             if on_attempt is not None:
                 on_attempt(mwl, cap)
-            for g in groupers:
-                for frac in (4, 2):
-                    chunks = jax.device_put(chunks_np, sharding)
-                    keys, lens, cnts, parts, scal = step_call(
-                        chunks, mwl, cap, frac, g)
-                    scal_np = np.asarray(scal)
-                    if not scal_np[:, 4].any():
-                        break
+            for frac in (4, 2):
+                chunks = jax.device_put(chunks_np, sharding)
+                keys, lens, cnts, parts, scal = step_call(
+                    chunks, mwl, cap, frac)
+                scal_np = np.asarray(scal)
                 if not scal_np[:, 4].any():
                     break
-            state["grouper"], state["frac"] = g, frac  # cleared rung sticks
+            state["frac"] = frac  # cleared rung sticks
 
             def payload():
                 if device_payload:
@@ -1074,7 +1049,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         with _span("enqueue", lane="dispatch", stats=stats,
                    step=stats["steps"], program="mapreduce_step"):
             keys, lens, cnts, parts, scal = step_call(
-                chunks, mwl, cap, state["frac"], state["grouper"])
+                chunks, mwl, cap, state["frac"])
             if aot or device_accumulate:
                 # Only scal + the packed tensor stay referenced: the four
                 # result tables free as soon as the pack consumes them, so

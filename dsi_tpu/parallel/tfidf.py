@@ -69,8 +69,6 @@ from dsi_tpu.utils.jaxcompat import (enable_x64, x64_scoped,
 
 from dsi_tpu.ops.wordcount import (
     _PAD_KEY64,
-    grouper_ladder,
-    grouper_suffix,
     pack_key_lanes,
     rung0_cap,
     unpack_key_lanes,
@@ -90,7 +88,7 @@ from dsi_tpu.parallel.shuffle import (
 
 def _tfidf_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
                        n_reduce: int, max_word_len: int, u_cap: int,
-                       t_cap_frac: int, grouper: str = "sort"):
+                       t_cap_frac: int):
     """Per-device wave body: map its document, all_to_all, sort received."""
     k = max_word_len // 4
     chunk = chunk.reshape(-1)
@@ -99,7 +97,7 @@ def _tfidf_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
     packed_u, len_u, cnt_u, part, dest, (
         n_unique, max_len, has_high, token_overflow) = map_prologue(
         chunk, n_dev=n_dev, n_reduce=n_reduce, max_word_len=max_word_len,
-        u_cap=u_cap, t_cap_frac=t_cap_frac, grouper=grouper)
+        u_cap=u_cap, t_cap_frac=t_cap_frac)
 
     # Send rows: word key lanes + [len, tf, doc, part] payload, routed by
     # the shared shuffle primitive (parallel/shuffle.py shuffle_rows).
@@ -141,16 +139,14 @@ def _tfidf_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
 
 def _tfidf_wave_step_impl(chunks: jax.Array, doc_ids: jax.Array, *,
                           n_dev: int, n_reduce: int, max_word_len: int,
-                          u_cap: int, mesh: Mesh, t_cap_frac: int = 4,
-                          grouper: str = "sort"):
+                          u_cap: int, mesh: Mesh, t_cap_frac: int = 4):
     """One SPMD wave: ``chunks`` [n_dev, L] uint8 (one zero-padded document
     per device), ``doc_ids`` [n_dev] int32.  Returns per-device sorted
     (word, len, tf, doc, part) rows [D, D*u_cap, K+4] and [D, 5] scalars
     (n_rows, n_unique, max_len, has_high, token_overflow)."""
     body = functools.partial(_tfidf_device_step, n_dev=n_dev,
                              n_reduce=n_reduce, max_word_len=max_word_len,
-                             u_cap=u_cap, t_cap_frac=t_cap_frac,
-                             grouper=grouper)
+                             u_cap=u_cap, t_cap_frac=t_cap_frac)
     return _shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS)),
@@ -160,7 +156,7 @@ def _tfidf_wave_step_impl(chunks: jax.Array, doc_ids: jax.Array, *,
 tfidf_wave_step = x64_scoped(jax.jit(
     _tfidf_wave_step_impl,
     static_argnames=("n_dev", "n_reduce", "max_word_len", "u_cap",
-                     "t_cap_frac", "mesh", "grouper")))
+                     "t_cap_frac", "mesh")))
 
 #: jax.jit donate_argnums for the pipelined wave program: the chunk
 #: upload is consumed by the kernel (the window re-uploads per attempt),
@@ -170,8 +166,7 @@ _WAVE_DONATE = (0,)
 
 
 def _wave_program(*, n_dev: int, n_reduce: int, max_word_len: int,
-                  u_cap: int, size: int, mesh: Mesh, t_cap_frac: int,
-                  grouper: str = "sort"):
+                  u_cap: int, size: int, mesh: Mesh, t_cap_frac: int):
     """The (name, fn) pair for one compiled wave-step shape — same
     single-definition discipline as ``streaming._step_program``.
     ``size`` enters the name for readability only (the memo key already
@@ -182,12 +177,10 @@ def _wave_program(*, n_dev: int, n_reduce: int, max_word_len: int,
                                      n_reduce=n_reduce,
                                      max_word_len=max_word_len,
                                      u_cap=u_cap, mesh=mesh,
-                                     t_cap_frac=t_cap_frac,
-                                     grouper=grouper)
+                                     t_cap_frac=t_cap_frac)
 
     name = (f"tfidf_wave_d{n_dev}_r{n_reduce}_w{max_word_len}"
             f"_u{u_cap}_s{size}_f{t_cap_frac}")
-    name += grouper_suffix(grouper)
     return name, fn
 
 
@@ -314,7 +307,7 @@ def tfidf_sharded(
     Returns ``{word: (reduce_partition, [(doc_index, tf), ...])}`` — exact,
     or None when any document needs the host path (non-ASCII bytes, words
     longer than 64).  Same exactness discipline as ``wordcount_streaming``:
-    waves dispatch optimistically at a sticky (capacity, grouper, frac)
+    waves dispatch optimistically at a sticky (capacity, frac)
     rung, their scalar checks are deferred until they leave the in-flight
     window (``depth - 1`` waves late), and a failed check replays exactly
     that wave through the ladder at the wider — then sticky — shape.
@@ -432,7 +425,6 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                   "replays": 0, "device_accumulate": device_accumulate,
                   "upload_s": 0.0, "kernel_s": 0.0, "pull_s": 0.0,
                   "merge_s": 0.0, "replay_s": 0.0})
-    groupers = grouper_ladder()
     sh_chunk = NamedSharding(mesh, P(AXIS, None))
     sh_ids = NamedSharding(mesh, P(AXIS))
 
@@ -488,8 +480,7 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
         # Sticky dispatch rung, exactly the streaming engine's: only
         # ever moves toward more headroom, so a corpus that widens once
         # doesn't replay every later wave.
-        state = {"cap": rung0_cap(size_max, u_cap),
-                 "grouper": groupers[0], "frac": 4}
+        state = {"cap": rung0_cap(size_max, u_cap), "frac": 4}
         outcome = {"high": False, "widen": False}
 
         def buffer_rows(r: np.ndarray) -> None:
@@ -565,7 +556,6 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                 start_wave = int(eff["wave"])
                 ck_wave[0] = start_wave
                 state.update({"cap": int(eff["cap"]),
-                              "grouper": eff["grouper"],
                               "frac": int(eff["frac"])})
                 table.restore({k[3:]: v for k, v in resume_arrays.items()
                                if k.startswith("pt_")})
@@ -610,8 +600,7 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
             with _span("ckpt", stats=stats, key="ckpt_s",
                        wave=ck_wave[0]):
                 meta = {"mwl": mwl, "wave": ck_wave[0],
-                        "cap": state["cap"], "grouper": state["grouper"],
-                        "frac": state["frac"]}
+                        "cap": state["cap"], "frac": state["frac"]}
                 kind = "full"
                 parts = None
                 with _span("ckpt_capture", lane="ckpt", stats=stats,
@@ -654,7 +643,7 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                                   dtype=np.int32)
                 yield (size, chunk_np, ids_np)
 
-        def wave_call(chunk_np, ids_np, size, cap, frac, g):
+        def wave_call(chunk_np, ids_np, size, cap, frac):
             """Upload + async wave dispatch at one rung.  Each attempt
             re-uploads: the compiled program donates its chunk."""
             with _span("upload", stats=stats, key="upload_s"):
@@ -662,7 +651,7 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                 ids = jax.device_put(ids_np, sh_ids)
             fn = _wave_fn((chunk, ids), n_dev=n_dev, n_reduce=n_reduce,
                           max_word_len=mwl, u_cap=cap, size=size,
-                          mesh=mesh, t_cap_frac=frac, grouper=g)
+                          mesh=mesh, t_cap_frac=frac)
             from dsi_tpu.device.table import _quiet_unusable_donation
 
             with _quiet_unusable_donation():
@@ -671,7 +660,7 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
         def dispatch(item):
             size, chunk_np, ids_np = item
             rows, scal = wave_call(chunk_np, ids_np, size, state["cap"],
-                                   state["frac"], state["grouper"])
+                                   state["frac"])
             fault_point("post-dispatch")
             return (size, chunk_np, ids_np, rows, scal, state["cap"])
 
@@ -683,13 +672,10 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
             cap = state["cap"]
             with _span("replay", stats=stats, key="replay_s"):
                 while True:
-                    for g in groupers:
-                        for frac in (4, 2):
-                            rows, scal = wave_call(chunk_np, ids_np, size,
-                                                   cap, frac, g)
-                            scal_np = np.asarray(scal)
-                            if not scal_np[:, 4].any():
-                                break
+                    for frac in (4, 2):
+                        rows, scal = wave_call(chunk_np, ids_np, size,
+                                               cap, frac)
+                        scal_np = np.asarray(scal)
                         if not scal_np[:, 4].any():
                             break
                     if bool(scal_np[:, 3].any()):
@@ -702,7 +688,7 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                         cap *= 4  # uniques <= tokens <= size/2: terminates
                         continue
                     break
-            state["cap"], state["grouper"], state["frac"] = cap, g, frac
+            state["cap"], state["frac"] = cap, frac
             return rows, scal, scal_np
 
         def commit(rows, scal, scal_np):

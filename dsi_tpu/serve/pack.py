@@ -27,7 +27,7 @@ so per-tenant output is byte-identical to the tenant running alone —
 the parity bar the daemon's tests and bench row enforce.
 
 Exactness discipline: the shared sticky rung (capacity / word window /
-grouper / token frac) widens for the whole batch exactly as the wave
+token frac) widens for the whole batch exactly as the wave
 walk's ladder does — a replay re-runs the batch, every lane benefits,
 and the cleared rung sticks.  Per-lane failures do NOT abort the batch:
 a lane whose chunk carries non-ASCII bytes (or a >64-byte word) is
@@ -78,7 +78,7 @@ from dsi_tpu.ckpt import (
     skip_stream,
 )
 from dsi_tpu.obs import count as _count, metrics_scope, span as _span
-from dsi_tpu.ops.wordcount import grouper_ladder, rung0_cap
+from dsi_tpu.ops.wordcount import rung0_cap
 from dsi_tpu.parallel.merge import PackedCounts
 from dsi_tpu.parallel.shuffle import write_partitioned_output
 
@@ -293,9 +293,8 @@ class PackedWcScheduler:
         # The wave program's size contract: a power of two, >= 256.
         self.chunk_bytes = 1 << max(8, int(chunk_bytes - 1).bit_length())
         self.n_reduce = int(n_reduce)
-        self.groupers = grouper_ladder()
         self.state = {"cap": rung0_cap(self.chunk_bytes, u_cap),
-                      "mwl": 16, "grouper": self.groupers[0], "frac": 4}
+                      "mwl": 16, "frac": 4}
         self.stats = metrics_scope("serve")
         self.stats.update({"packed_steps": 0, "packed_rows": 0,
                            "replays": 0, "upload_s": 0.0, "kernel_s": 0.0,
@@ -320,12 +319,11 @@ class PackedWcScheduler:
         _wave_fn(examples, n_dev=self.n_dev, n_reduce=self.n_reduce,
                  max_word_len=self.state["mwl"], u_cap=self.state["cap"],
                  size=self.chunk_bytes, mesh=self.mesh,
-                 t_cap_frac=self.state["frac"],
-                 grouper=self.state["grouper"])
+                 t_cap_frac=self.state["frac"])
 
     # ── one packed step ──
 
-    def _wave_call(self, chunk_np, ids_np, mwl, cap, frac, g, batch):
+    def _wave_call(self, chunk_np, ids_np, mwl, cap, frac, batch):
         from dsi_tpu.device.table import _quiet_unusable_donation
         from dsi_tpu.parallel.tfidf import _wave_fn
 
@@ -335,7 +333,7 @@ class PackedWcScheduler:
         fn = _wave_fn((chunk, ids), n_dev=self.n_dev,
                       n_reduce=self.n_reduce, max_word_len=mwl,
                       u_cap=cap, size=self.chunk_bytes, mesh=self.mesh,
-                      t_cap_frac=frac, grouper=g)
+                      t_cap_frac=frac)
         with _quiet_unusable_donation():
             return fn(chunk, ids)
 
@@ -349,15 +347,12 @@ class PackedWcScheduler:
         state = self.state
         cap, mwl = state["cap"], state["mwl"]
         while True:
-            for g in self.groupers:
-                for frac in (4, 2):
-                    with _span("kernel", stats=self.stats,
-                               key="kernel_s", **batch):
-                        rows, scal = self._wave_call(chunk_np, ids_np, mwl,
-                                                     cap, frac, g, batch)
-                        scal_np = np.asarray(scal)
-                    if not scal_np[:, 4].any():
-                        break
+            for frac in (4, 2):
+                with _span("kernel", stats=self.stats,
+                           key="kernel_s", **batch):
+                    rows, scal = self._wave_call(chunk_np, ids_np, mwl,
+                                                 cap, frac, batch)
+                    scal_np = np.asarray(scal)
                 if not scal_np[:, 4].any():
                     break
             dead = [int(d) for d in np.flatnonzero(scal_np[:, 3])
@@ -381,7 +376,7 @@ class PackedWcScheduler:
                 self.stats["replays"] += 1
                 continue
             break
-        state.update(cap=cap, mwl=mwl, grouper=g, frac=frac)
+        state.update(cap=cap, mwl=mwl, frac=frac)
         return rows, scal_np, mwl // 4
 
     def step(self, lanes: List[TenantLane]) -> List[TenantLane]:

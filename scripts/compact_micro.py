@@ -176,7 +176,7 @@ def main(argv):
             n, u_cap = n >> cut, max(u_cap >> cut, 4096)
             fn = jax.jit(tokenize_group_core,
                          static_argnames=("max_word_len", "u_cap",
-                                          "t_cap_frac", "grouper"))
+                                          "t_cap_frac"))
             with enable_x64(True):
                 out, first_s, ms = timed(
                     lambda c: fn(c, max_word_len=16, u_cap=u_cap),

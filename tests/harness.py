@@ -137,3 +137,40 @@ def net_job_split_roles(app: str, files, workdir: str, n_reduce: int,
         coord.close()
         producer.close()
         consumer.close()
+
+
+def word_count_program(program: str, n_dev: int = 1, *, size: int,
+                       u_cap: int, **more):
+    """``(name, fn, args, static)`` of one of the programs the word-count
+    map is in, at 16-byte words and ``t_cap_frac`` 4: ``fn`` over the
+    shape structs ``args`` and the static keywords ``static``, and the
+    name its compiled executable is kept under (``None``: the bare map
+    and ``corpus_kernel`` are named by their callers).  ``program``:
+    ``wc``, ``corpus``, ``stream``, ``tfidf``, ``idx``
+    (``pack_docs=True`` for the packed wave)."""
+    import jax
+    import numpy as np
+
+    from dsi_tpu.ops.corpus_wc import corpus_kernel
+    from dsi_tpu.ops.wordcount import tokenize_group_core
+    from dsi_tpu.parallel.grepstream import _idx_program, pack_docs_cap
+    from dsi_tpu.parallel.shuffle import default_mesh
+    from dsi_tpu.parallel.streaming import _step_program
+    from dsi_tpu.parallel.tfidf import _wave_program
+
+    sds = jax.ShapeDtypeStruct
+    if program in ("wc", "corpus"):
+        core = tokenize_group_core if program == "wc" else corpus_kernel
+        static = dict(max_word_len=16, u_cap=u_cap, t_cap_frac=4, **more)
+        return None, core, (sds((size,), np.uint8),), static
+    shape = dict(n_dev=n_dev, n_reduce=10, max_word_len=16, u_cap=u_cap,
+                 mesh=default_mesh(n_dev), t_cap_frac=4)
+    chunks = sds((n_dev, size), np.uint8)
+    if program == "stream":
+        return (*_step_program(**shape), (chunks,), {})
+    if program == "tfidf":
+        return (*_wave_program(size=size, **shape),
+                (chunks, sds((n_dev,), np.int32)), {})
+    ids = (n_dev, pack_docs_cap(size)) if more.get("pack_docs") else (n_dev,)
+    return (*_idx_program(size=size, **shape, **more),
+            (chunks, sds(ids, np.int32)), {})

@@ -99,57 +99,6 @@ def test_tpu_indexer_matches_host_indexer():
     assert tpu_indexer.Reduce("w", ["b", "a", "b"]) == "2 a,b"
 
 
-# ── hash-grouper warm ladder (*_hg AOT entries) ────────────────────────
-
-
-def test_grouper_parity_hash_vs_sort(monkeypatch):
-    """DSI_WC_GROUPER=hash and =sort must produce identical results —
-    the env selection the warm ladder now supports on every platform
-    changes throughput only, never output."""
-    from dsi_tpu.ops.wordcount import count_words_host_result
-
-    raw = (b"the cat and the hat and The end the cat "
-           b"some more words with Mixed Case tokens 123 split9here ") * 40
-    monkeypatch.setenv("DSI_WC_GROUPER", "sort")
-    want = count_words_host_result(raw)
-    monkeypatch.setenv("DSI_WC_GROUPER", "hash")
-    got = count_words_host_result(raw)
-    assert want is not None and got == want
-
-
-def test_grouper_suffix_convention():
-    from dsi_tpu.ops.wordcount import grouper_suffix, warm_groupers
-
-    assert grouper_suffix("sort") == ""  # historical names stay valid
-    assert grouper_suffix("hash") == "_hg"
-    assert set(warm_groupers()) == {"sort", "hash"}
-
-
-def test_hash_grouper_warm_ladder_compiles_hg_programs(monkeypatch):
-    """The warm ladder must compile BOTH grouper variants (`*_hg`
-    alongside the bare sort names), so an env-pinned hash run after it
-    compiles nothing."""
-    from dsi_tpu.backends import aotcache
-    from dsi_tpu.parallel.shuffle import default_mesh
-    from dsi_tpu.parallel.streaming import (warm_stream_aot,
-                                            wordcount_streaming)
-
-    mesh = default_mesh(1)
-    warm_stream_aot(mesh=mesh, chunk_bytes=1 << 14, caps=(1 << 10,))
-    names = {key[0] for key in aotcache._memo}
-    steps = {n for n in names if n.startswith("stream_step_d1_")
-             and "_u1024_" in n}
-    assert any(n.endswith("_hg") for n in steps), steps
-    assert any(not n.endswith("_hg") for n in steps), steps
-    monkeypatch.setenv("DSI_WC_GROUPER", "hash")
-    before = aotcache.stats["compiles"]
-    text = ("warm ladder hash grouper " * 500).encode()
-    assert wordcount_streaming([text], mesh=mesh, n_reduce=10,
-                               chunk_bytes=1 << 14, u_cap=1 << 10,
-                               aot=True) is not None
-    assert aotcache.stats["compiles"] == before
-
-
 # ── block-level Unicode fallback ──────────────────────────────────────
 
 

@@ -558,6 +558,59 @@ def test_tfidf_crash_resume_parity(monkeypatch, tmp_path):
     assert res == base
 
 
+def _run_tfidf(ckpt=None, resume=False, dacc=False, depth=2, stats=None,
+               async_=None, delta=None):
+    reset_faults()
+    return tfidf_sharded(
+        IDX_DOCS, mesh=_mesh(), n_reduce=10, u_cap=1 << 9, depth=depth,
+        device_accumulate=dacc, sync_every=2, checkpoint_dir=ckpt,
+        checkpoint_every=2, checkpoint_async=async_,
+        checkpoint_delta=delta, resume=resume, wave_stats=stats)
+
+
+_RUNNERS["tfidf"] = _run_tfidf
+
+
+def _stamp_grouper(ck: str) -> int:
+    """Every manifest of ``ck`` as PR 43's engines wrote it on the CPU:
+    the sticky rung's ``"grouper": "hash"`` beside its ``frac``."""
+    import json
+
+    from dsi_tpu.utils.atomicio import (read_bytes_verified,
+                                        write_bytes_durable)
+
+    stamped = 0
+    for name in sorted(os.listdir(ck)):
+        if name.startswith("manifest-") and name.endswith(".json"):
+            path = os.path.join(ck, name)
+            manifest = json.loads(read_bytes_verified(path))
+            assert "frac" in manifest["meta"]
+            assert "grouper" not in manifest["meta"]
+            manifest["meta"]["grouper"] = "hash"
+            write_bytes_durable(
+                path, json.dumps(manifest, sort_keys=True).encode("utf-8"))
+            stamped += 1
+    return stamped
+
+
+@pytest.mark.parametrize("engine", ["wc", "idx", "tfidf"])
+def test_checkpoint_with_a_grouper_key_resumes(monkeypatch, tmp_path,
+                                               engine):
+    """A checkpoint whose meta still names a grouper (one written before
+    the hash grouper went) restores and finishes to the uninterrupted
+    run's result: the key is not read, and no other key moved."""
+    run = _RUNNERS[engine]
+    ck = str(tmp_path / "ck")
+    _fault_env(monkeypatch, "post-ckpt", _FAULT_AT["post-ckpt"])
+    with pytest.raises(FaultInjected):
+        run(ckpt=ck)
+    _clear_fault(monkeypatch)
+    assert _stamp_grouper(ck) >= 1
+    stats = {}
+    assert run(ckpt=ck, resume=True, stats=stats) == _baseline(engine, False)
+    assert stats["resume_cursor" if engine == "wc" else "resume_wave"] > 0
+
+
 def test_resume_skips_confirmed_work(monkeypatch, tmp_path):
     """Resume is a restore + tail replay, not a rerun: the resumed run
     processes strictly fewer steps than the whole stream holds."""

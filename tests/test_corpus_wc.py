@@ -68,17 +68,11 @@ def test_first_occurrence_positions_and_lengths():
     raw = b"zed apple zed banana"
     res = corpus_wordcount([raw], piece_size=PIECE)
     words = res.words()
-    # Row ORDER is grouper-dependent (lexicographic for sort, bucket
-    # order for hash — the output writer sorts host-side); positions and
-    # lengths are exact either way.
-    assert sorted(words) == ["apple", "banana", "zed"]
+    # The rows are in lexicographic word order (the wire contract).
+    assert words == ["apple", "banana", "zed"]
     by_word = dict(zip(words, zip(res.pos.tolist(), res.lens.tolist())))
     assert by_word["apple"] == (4, 5)
     assert by_word["zed"] == (0, 3)
-    # The sort grouper's rows stay lexicographic (the chip path's wire
-    # contract).
-    res_s = corpus_wordcount([raw], piece_size=PIECE, grouper="sort")
-    assert res_s.words() == ["apple", "banana", "zed"]
 
 
 def test_non_ascii_falls_back():

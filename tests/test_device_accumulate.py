@@ -82,7 +82,7 @@ def _run_step(mesh, text: bytes, u_cap: int = 64):
     chunks = jax.device_put(chunks_np, NamedSharding(mesh, P(AXIS, None)))
     keys, lens, cnts, parts, scal = mapreduce_step(
         chunks, n_dev=n_dev, n_reduce=10, max_word_len=16, u_cap=u_cap,
-        mesh=mesh, t_cap_frac=4, grouper="sort")
+        mesh=mesh, t_cap_frac=4)
     packed = _slice_pack(keys, lens, cnts, parts, mp=keys.shape[1])
     return packed, scal, np.asarray(scal)
 

@@ -116,7 +116,7 @@ def test_pack_key_lanes_order_and_roundtrip():
     import numpy as np
 
     from dsi_tpu.ops.wordcount import (_PAD_KEY, pack_key_lanes,
-                                       unpack_key_rows)
+                                       unpack_key_lanes)
     from dsi_tpu.utils.jaxcompat import enable_x64
 
     rng = np.random.default_rng(3)
@@ -136,8 +136,7 @@ def test_pack_key_lanes_order_and_roundtrip():
             packed = pack_key_lanes(cols)
             assert len(packed) == (k + 1) // 2
             # roundtrip
-            rows64 = jnp.stack(packed, axis=1)
-            back = np.asarray(unpack_key_rows(rows64, k))
+            back = np.asarray(jnp.stack(unpack_key_lanes(packed, k), axis=1))
             packed_np = [np.asarray(p) for p in packed]
         assert np.array_equal(back, cols_np.T)
         # order: argsort by packed columns == lexsort by original lanes
@@ -149,7 +148,7 @@ def test_pack_key_lanes_order_and_roundtrip():
         assert set(order_packed[-16:]) == set(pad_rows)
 
 
-# ── hash grouper (round 5): exactness under forced collisions ──────────
+# ── grouping is by the word's bytes: hash collisions stay apart ────────
 
 
 def _fnv1a(w: str) -> int:
@@ -160,52 +159,64 @@ def _fnv1a(w: str) -> int:
 
 
 def _colliding_words(mask: int, count: int = 2):
-    """Distinct lowercase words sharing fnv1a low bits (the hash
-    grouper's level-1 bucket index at small chunk shapes)."""
+    """Distinct lowercase words whose fnv1a hashes share their low bits
+    and, modulo 10, their reduce partition."""
     seen: dict = {}
     import itertools
 
     for tup in itertools.product(string.ascii_lowercase, repeat=3):
         w = "".join(tup)
-        b = _fnv1a(w) & mask
+        h = _fnv1a(w)
+        b = (h & mask, (h & 0x7FFFFFFF) % 10)
         seen.setdefault(b, []).append(w)
         if len(seen[b]) >= count:
             return seen[b][:count]
     raise AssertionError("no collision found")
 
 
-def test_hash_grouper_dirty_bucket_exact(monkeypatch):
-    """Two distinct words sharing a level-1 bucket must be separated by
-    the dirty-repair sort, not merged (exactness does not depend on hash
-    luck)."""
-    monkeypatch.setenv("DSI_WC_GROUPER", "hash")
-    # 4 KB pad -> t_cap = 1025 -> n_buckets = 1 << max(10, 10-1) = 1024.
+def _collision_text(case: str) -> str:
+    if case == "random":
+        rng = random.Random(11)
+        words = ["".join(rng.choices(string.ascii_lowercase,
+                                     k=rng.randint(1, 12)))
+                 for _ in range(400)]
+        return " ".join(rng.choice(words) for _ in range(5000))
     w1, w2 = _colliding_words(1023)
-    text = (f"{w1} {w2} " * 150 + f"{w1} filler words here").ljust(3000)
+    if case == "pair":
+        return (f"{w1} {w2} " * 150 + f"{w1} filler words here").ljust(3000)
+    # 600 tokens of the pair, 8 colliding words among them
+    more = " ".join(_colliding_words(255, 8))
+    return f"{w1} {w2} " * 300 + more
+
+
+@pytest.mark.parametrize("case", ["pair", "many", "random"])
+def test_words_that_collide_in_fnv_stay_distinct_rows(case):
+    """Two words whose FNV-1a hashes share their low ten bits and their
+    reduce partition, 600 tokens of such a pair beside eight words that
+    share eight bits, and random text: the program's rows are the host
+    ``Counter``'s, one row a word, and ``fnv_u`` puts each row in the
+    partition ``mr.worker.ihash`` gives its word."""
+    import numpy as np
+
+    from dsi_tpu.ops.wordcount import (_pad_pow2, count_words_kernel,
+                                       decode_packed)
+
+    text = _collision_text(case)
+    want = oracle_counts(text)
+    (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
+     token_overflow) = count_words_kernel(
+        _pad_pow2(text.encode()), max_word_len=16, u_cap=1024, t_cap_frac=4)
+    assert not bool(has_high) and not bool(token_overflow)
+    nu = int(n_unique)
+    words = decode_packed(np.asarray(packed_u), np.asarray(len_u), nu)
+    assert len(set(words)) == nu == len(want)
+    assert dict(zip(words, np.asarray(cnt_u)[:nu].tolist())) == dict(want)
+    parts = ((np.asarray(fnv_u)[:nu] & 0x7FFFFFFF) % 10).tolist()
+    assert parts == [ihash(w) % 10 for w in words]
+    if case != "random":
+        w1, w2 = _colliding_words(1023)
+        assert parts[words.index(w1)] == parts[words.index(w2)]
     check(text)
-
-
-def test_hash_grouper_dirty_overflow_falls_back(monkeypatch):
-    """More colliding tokens than the dirty buffer holds: group_overflow
-    must route the chunk to the sort grouper and stay exact."""
-    monkeypatch.setenv("DSI_WC_GROUPER", "hash")
-    w1, w2 = _colliding_words(1023)
-    # d_cap = max(256, t_cap//16) = 256 at this shape; 600 dirty tokens
-    # overflow it.
-    text = f"{w1} {w2} " * 300
-    check(text)
-
-
-def test_hash_grouper_matches_sort_on_random_text(monkeypatch):
-    rng = random.Random(11)
-    words = ["".join(rng.choices(string.ascii_lowercase, k=rng.randint(1, 12)))
-             for _ in range(400)]
-    text = " ".join(rng.choice(words) for _ in range(5000))
-    monkeypatch.setenv("DSI_WC_GROUPER", "hash")
-    rh = count_words_host_result(text.encode())
-    monkeypatch.setenv("DSI_WC_GROUPER", "sort")
-    rs = count_words_host_result(text.encode())
-    assert rh == rs and rh is not None
 
 
 # ── compaction: one int32 helper, no scatter ───────────────────────────
@@ -288,17 +299,41 @@ def test_compact_positions_jaxpr_holds_no_scatter():
         lambda x: jnp.nonzero(x, size=300, fill_value=999)[0], mask) != []
 
 
-def test_sort_grouper_jaxpr_holds_no_scatter():
-    """The word-count program with the sort grouper scatters nowhere:
-    ``group_sorted``'s totals are a prefix sum read at the run starts."""
+def test_word_count_program_jaxpr_holds_no_scatter():
+    """The word-count program scatters nowhere: ``group_sorted``'s totals
+    are a prefix sum read at the run starts."""
     import functools
 
     import jax.numpy as jnp
 
     from dsi_tpu.ops.wordcount import tokenize_group_core
 
-    fn = functools.partial(tokenize_group_core, u_cap=64, grouper="sort")
+    fn = functools.partial(tokenize_group_core, u_cap=64)
     assert _scatters(fn, jnp.zeros(256, jnp.uint8)) == []
+
+
+@pytest.mark.parametrize("program, n_dev, more, allowed", [
+    ("corpus", 1, {}, []),
+    # shuffle_rows' send-buffer placement, on one device as on four
+    ("stream", 1, {}, ["scatter"]),
+    ("stream", 4, {}, ["scatter"]),
+    ("tfidf", 1, {}, ["scatter"]),
+    ("idx", 1, {}, ["scatter"]),
+    ("idx", 1, {"pack_docs": True}, ["scatter"]),
+])
+def test_whole_programs_scatter_only_where_named(program, n_dev, more,
+                                                 allowed):
+    """The programs the cells run, whole: the map scatters nowhere and
+    adds nowhere, and the one scatter of a step or a wave is the
+    placement ``test_shuffle_rows_jaxpr_holds_one_placement_scatter``
+    pins."""
+    import functools
+
+    from tests.harness import word_count_program
+
+    _, fn, args, static = word_count_program(program, n_dev, size=256,
+                                             u_cap=64, **more)
+    assert _scatters(functools.partial(fn, **static), *args) == allowed
 
 
 @pytest.mark.parametrize("mesh_fold", [False, True])
@@ -520,11 +555,10 @@ def _boundary_chunk(case: str) -> bytes:
     return body.ljust(_N, b"\0")
 
 
-@pytest.mark.parametrize("grouper", ["sort", "hash"])
 @pytest.mark.parametrize("case", ["t_cap_tokens", "t_cap_plus_one",
                                   "no_tokens", "single_letters",
                                   "ends_in_letter"])
-def test_program_at_its_token_boundaries(case, grouper):
+def test_program_at_its_token_boundaries(case):
     """The program as a whole where its buffers end: exactly ``t_cap``
     tokens, one more (``token_overflow``, and the first ``t_cap`` tokens
     counted), none, single letters only, a letter in the last byte; each
@@ -542,7 +576,7 @@ def test_program_at_its_token_boundaries(case, grouper):
     (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
      token_overflow) = count_words_kernel(
         np.frombuffer(chunk, np.uint8), max_word_len=16, u_cap=128,
-        t_cap_frac=4, grouper=grouper)
+        t_cap_frac=4)
     assert bool(token_overflow) == (len(tokens) > t_cap) \
         == (case == "t_cap_plus_one")
     assert not bool(has_high)

@@ -30,13 +30,13 @@ from dsi_tpu.ckpt import CheckpointMismatch, FaultInjected, reset_faults
 from dsi_tpu.cli import planrun as cli
 from dsi_tpu.ops.wordcount import DOC_SEP, count_words_kernel, decode_packed
 from dsi_tpu.parallel.grepstream import (
-    _idx_program,
     indexer_streaming,
     pack_chunk,
     pack_docs_cap,
     plan_packed_waves,
 )
 from dsi_tpu.parallel.shuffle import default_mesh
+from tests.harness import word_count_program
 
 CHUNK = 4096
 WORD = re.compile(rb"[A-Za-z]+")
@@ -400,8 +400,7 @@ def test_kernel_groups_by_word_and_document():
     docs[6] = b"zz" + docs[6]
     chunk, _ = pack_chunk(docs, [list(range(len(docs)))], 1, 4096, 99)
     out = count_words_kernel(jax.numpy.asarray(chunk[0]), max_word_len=16,
-                             u_cap=1024, t_cap_frac=4, grouper="sort",
-                             doc_sep=DOC_SEP)
+                             u_cap=1024, t_cap_frac=4, doc_sep=DOC_SEP)
     packed_u, len_u, cnt_u, _, n_unique, max_len, high, overflow, doc_u = \
         [np.asarray(x) for x in out]
     n = int(n_unique)
@@ -415,76 +414,77 @@ def test_kernel_groups_by_word_and_document():
             want[key] = want.get(key, 0) + 1
     assert got == want and len(got) == n
     assert not high and not overflow and int(max_len) <= 16
-    with pytest.raises(ValueError):
-        count_words_kernel(jax.numpy.asarray(chunk[0]), max_word_len=16,
-                           u_cap=1024, t_cap_frac=4, grouper="hash",
-                           doc_sep=DOC_SEP)
 
 
 # ── with the flag off, the programs are what they were ─────────────────
 
-#: sha256 of the lowered text at commit 9831bd2 (PR 39), jax 0.9.0, CPU:
-#: ``tokenize_group_core`` at 4,096 B, and the wave program on 1 and 4
-#: devices, each with the sort and the hash grouper.
+#: sha256 of the lowered text, jax 0.9.0, CPU.  ``wc`` and ``idx`` at
+#: commit 9831bd2 (PR 39): ``tokenize_group_core`` at 4,096 B, and the
+#: index wave program on 1 and 4 devices.  The rest at commit 6415a3d
+#: (PR 43), before the hash grouper and its ``grouper`` static went: the
+#: stream step and the TF-IDF wave on 4 devices, ``corpus_kernel``, and
+#: the wave program at the rung the served word-count lane starts from.
 LOWERED_BEFORE = {
-    ("wc", 1, "sort"):
+    ("wc", 1):
         "325c474ee16583bd56a0969455f0b9509f541b4d25de105e5e6ca3b9710d9a54",
-    ("wc", 1, "hash"):
-        "c1b9dff76fa6da276b33c47780b988f290795b5010226be1b2e5cf8c8a35fa67",
-    ("idx", 1, "sort"):
+    ("idx", 1):
         "3c924e48102362fb2ce42a2ecd20aed33051df2691cc2b5bdb5145f78ce56349",
-    ("idx", 1, "hash"):
-        "a31143a3739a1d0b2eb20bf20b30737eb396c2b314750dac9b6cfebe63ad011b",
-    ("idx", 4, "sort"):
+    ("idx", 4):
         "0b92b16936fe7d0d12b4d780b0b07f66c79ba1b46f19f5244e9ce506aa75e28b",
-    ("idx", 4, "hash"):
-        "4025cf33bbbab20d0d69c192dc79a4455baa24c7b820b6129a0b1e48d8e31989",
+    ("stream", 4):
+        "5a02b30d30b57be1d4a6044d5536683c85e47033c0caafe627c1e34298e3b547",
+    ("tfidf", 4):
+        "57940b3d99a974903d57a0d34e44c6947bdd3cc965abeaf781e6b50ba1b0bc05",
+    ("corpus", 1):
+        "e5c8779a13ae2286ef15a908653b94c04f9726398a0f4f3146f794e0565ed249",
+    ("serve", 1):
+        "70dc19f9fd2f93034c1920f7f436c35af9b927013bd61d6183b95a0f472bbc07",
+}
+
+#: The compiled program's name: the key of its persisted executable.
+LOWERED_NAMES = {
+    ("idx", 1): "idx_wave_d1_r10_w16_u256_s4096_f4",
+    ("idx", 4): "idx_wave_d4_r10_w16_u256_s4096_f4",
+    ("stream", 4): "stream_step_d4_r10_w16_u256_f4",
+    ("tfidf", 4): "tfidf_wave_d4_r10_w16_u256_s4096_f4",
+    ("serve", 1): "tfidf_wave_d1_r10_w16_u4096_s4096_f4",
 }
 
 
-def _lowered(program, n_dev, grouper, **more):
-    from dsi_tpu.ops.wordcount import tokenize_group_core
+def _lowered(program, n_dev, **more):
+    """(name, lowered text) of one of the programs the grouper is in."""
+    from dsi_tpu.serve.pack import PackedWcScheduler
     from dsi_tpu.utils.jaxcompat import enable_x64
 
-    sds = jax.ShapeDtypeStruct
-    if program == "wc":
-        static = dict(max_word_len=16, u_cap=256, t_cap_frac=4,
-                      grouper=grouper, **more)
-        with enable_x64(True):
-            return jax.jit(tokenize_group_core,
-                           static_argnames=tuple(static)).lower(
-                sds((4096,), np.uint8), **static).as_text()
-    pack = bool(more.get("pack_docs"))
-    name, fn = _idx_program(n_dev=n_dev, n_reduce=10, max_word_len=16,
-                            u_cap=256, size=4096, mesh=default_mesh(n_dev),
-                            t_cap_frac=4, grouper=grouper, **more)
-    ids = (n_dev, pack_docs_cap(4096)) if pack else (n_dev,)
+    u_cap = 256
+    if program == "serve":  # the wave at the rung the lane starts from
+        lane = PackedWcScheduler(mesh=default_mesh(n_dev), chunk_bytes=4096)
+        assert (lane.n_reduce, lane.state["mwl"], lane.state["frac"],
+                lane.chunk_bytes) == (10, 16, 4, 4096)
+        program, u_cap = "tfidf", lane.state["cap"]
+    name, fn, args, static = word_count_program(program, n_dev, size=4096,
+                                                u_cap=u_cap, **more)
     with enable_x64(True):
-        return name, jax.jit(fn).lower(
-            sds((n_dev, 4096), np.uint8), sds(ids, np.int32)).as_text()
+        return name, jax.jit(fn, static_argnames=tuple(static)).lower(
+            *args, **static).as_text()
 
 
-@pytest.mark.parametrize("program, n_dev, grouper", sorted(LOWERED_BEFORE))
-def test_flag_off_lowers_to_the_text_it_lowered_to_before(program, n_dev,
-                                                          grouper):
+@pytest.mark.parametrize("program, n_dev", sorted(LOWERED_BEFORE))
+def test_flag_off_lowers_to_the_text_it_lowered_to_before(program, n_dev):
     if jax.__version__ != "0.9.0":
         pytest.skip("the digests were taken with jax 0.9.0")
-    text = _lowered(program, n_dev, grouper)
-    if program == "idx":
-        name, text = text
-        assert name == (f"idx_wave_d{n_dev}_r10_w16_u256_s4096_f4"
-                        + ("_hg" if grouper == "hash" else ""))
+    name, text = _lowered(program, n_dev)
+    assert name == LOWERED_NAMES.get((program, n_dev))
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        LOWERED_BEFORE[program, n_dev, grouper]
+        LOWERED_BEFORE[program, n_dev]
 
 
 def test_flag_on_is_another_program_under_the_same_module_name():
-    off_name, off = _lowered("idx", 1, "sort")
-    on_name, on = _lowered("idx", 1, "sort", pack_docs=True)
+    off_name, off = _lowered("idx", 1)
+    on_name, on = _lowered("idx", 1, pack_docs=True)
     assert on_name == off_name + "_pk" and on != off
     assert "idx_wave_step" in on.splitlines()[0]
-    assert _lowered("wc", 1, "sort", doc_sep=DOC_SEP) != \
-        _lowered("wc", 1, "sort")
+    assert _lowered("wc", 1, doc_sep=DOC_SEP) != _lowered("wc", 1)
 
 
 # ── spans and counters ─────────────────────────────────────────────────

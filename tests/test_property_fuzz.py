@@ -246,30 +246,29 @@ def test_native_encoder_blobs_roundtrip_and_partition(tmp_path_factory,
     assert sorted(seen) == sorted(pairs)
 
 
-def test_fuzz_hash_vs_sort_grouper_shapes(monkeypatch):
-    """Dual-grouper equivalence across random shapes, vocabularies, and
-    capacities — the hash grouper's bucket/dirty/overflow machinery must
-    agree with the sort grouper everywhere (round 5)."""
+@pytest.mark.parametrize("trial", range(6))
+def test_fuzz_grouper_shapes_match_counter(trial):
+    """Random shapes, vocabularies and capacities (3 to 3,000 words, 50
+    to 20,000 tokens, a first capacity the vocabulary may overflow)
+    through the kernel and its retry ladder, against the host
+    ``Counter`` and ``ihash``."""
     import random
     import string
 
-    from dsi_tpu.ops.wordcount import count_words_host_result
-
-    rng = random.Random(99)
-    for trial in range(6):
-        n_vocab = rng.choice([3, 40, 500, 3000])
-        words = ["".join(rng.choices(string.ascii_letters,
-                                     k=rng.randint(1, 14)))
-                 for _ in range(n_vocab)]
-        n_tokens = rng.choice([50, 2000, 20000])
-        text = " ".join(rng.choice(words) for _ in range(n_tokens))
-        u_cap = rng.choice([1 << 8, 1 << 12])
-        monkeypatch.setenv("DSI_WC_GROUPER", "hash")
-        rh = count_words_host_result(text.encode(), u_cap=u_cap)
-        monkeypatch.setenv("DSI_WC_GROUPER", "sort")
-        rs = count_words_host_result(text.encode(), u_cap=u_cap)
-        assert rh == rs and rh is not None, (trial, n_vocab, n_tokens,
-                                             u_cap)
+    rng = random.Random(99 + trial)
+    n_vocab = rng.choice([3, 40, 500, 3000])
+    words = ["".join(rng.choices(string.ascii_letters,
+                                 k=rng.randint(1, 14)))
+             for _ in range(n_vocab)]
+    n_tokens = rng.choice([50, 2000, 20000])
+    text = " ".join(rng.choice(words) for _ in range(n_tokens))
+    u_cap = rng.choice([1 << 8, 1 << 12])
+    res = count_words_host_result(text.encode(), u_cap=u_cap)
+    assert res is not None, (n_vocab, n_tokens, u_cap)
+    want = collections.Counter(ASCII_WORDS.findall(text))
+    assert {w: c for w, (c, _) in res.items()} == dict(want)
+    for w, (_, h) in res.items():
+        assert h == ihash(w)
 
 
 # ---- checkpoint snapshot round-trips (dsi_tpu/ckpt + device services) ----

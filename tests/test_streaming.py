@@ -181,7 +181,7 @@ def test_pipeline_sticky_rung_bounds_replays():
     """A stream that token-overflows the optimistic frac on EVERY chunk
     (dense single-letter words: tokens ≈ n/2 > t_cap at frac 4) must
     replay at most the in-flight window, not every step: the cleared
-    (grouper, frac) rung sticks for later dispatches just like a widened
+    (frac) rung sticks for later dispatches just like a widened
     capacity."""
     text = b"a b c d e f g h " * 6000
     want = dict(collections.Counter(WORDS.findall(text.decode())))
