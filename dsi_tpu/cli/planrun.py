@@ -41,6 +41,30 @@ Chains:
               (the walk held by a document not yet read),
               read_ahead_hits / read_docs, read_threads.
 
+  sort      — sample → range sort (OSDI'04 section 5.3, TeraSort's
+              partitioner) over files of whole 100-byte records
+              (gensort's: the key is bytes 0-9, compared as unsigned
+              bytes; the record is opaque otherwise).  Stage sample
+              reads --sort-sample keys (default 100,000; fewer where
+              the input holds fewer) at evenly spaced record offsets,
+              host side, sorts them and takes --nreduce - 1 split
+              points at the equal-count positions.  Stage sort takes
+              every record up in steps of --chunk-bytes (whole
+              records) into a store that stays on the device, counts
+              the records a partition against the split points there,
+              and orders the store by key; ties keep input order (file
+              order, then offset).  The ordered records are pulled and
+              committed as mr-out-0 .. mr-out-<nreduce-1>, each the
+              concatenation of its records in key order and every key
+              of mr-out-<r> less than or equal to every key of
+              mr-out-<r+1>: their concatenation in partition order is
+              the sorted input.  One device (--devices 1, the
+              default for this chain).  A file whose length is not a
+              multiple of 100 is refused before any stage; there is
+              no host path: --staged fails the job (exit 1, nothing
+              committed); --check, --hosts, --checkpoint-dir,
+              --pipeline and --stage-shards are not this chain's.
+
 Elastic execution (ISSUE 16): ``--pipeline`` overlaps a grep→wordcount
 pair (the wordcount consumes relay buffers as they SEAL while the grep
 is still producing; strict/staged stays the bit-parity oracle);
@@ -53,7 +77,7 @@ Usage:
         [--pattern2 PAT] [--pipeline] [--stage-shards K] [--pack-docs]
         [--staged] [--chunk-bytes B] [--devices D] [--pipeline-depth K]
         [--device-accumulate] [--sync-every K] [--mesh-shards N]
-        [--nreduce N] [--u-cap U] [--topk K] [--aot]
+        [--nreduce N] [--u-cap U] [--topk K] [--sort-sample N] [--aot]
         [--checkpoint-dir DIR] [--resume] [--workdir DIR] [--check]
         [--stats] [--stats-json FILE] [--trace-dir DIR] inputfiles...
 """
@@ -86,7 +110,7 @@ def _plan_spec(args) -> dict:
             "mesh_shards": args.mesh_shards, "aot": args.aot,
             "n_reduce": args.nreduce, "u_cap": args.u_cap,
             "topk": args.topk, "devices": args.devices,
-            "pack_docs": args.pack_docs}
+            "pack_docs": args.pack_docs, "sample": args.sort_sample}
 
 
 def _run_hosts(args, spec: dict, plan, mesh):
@@ -239,7 +263,7 @@ def _main(argv, opened: list) -> int:
     p.add_argument("files", nargs="+")
     p.add_argument("--chain",
                    choices=("grep-wc", "grep-grep", "wc-topk",
-                            "indexer"),
+                            "indexer", "sort"),
                    default="grep-wc",
                    help="grep-wc commits the word counts of the matching "
                         "lines as mr-out-<r>; indexer commits the whole "
@@ -247,7 +271,10 @@ def _main(argv, opened: list) -> int:
                         "input file, read ahead of the wave walk by a "
                         "pool of reader threads) and writes "
                         "plan-join.json; grep-grep and wc-topk write "
-                        "plan-grep.json / plan-topk.json")
+                        "plan-grep.json / plan-topk.json; sort commits "
+                        "the input's 100-byte records ordered by their "
+                        "10-byte key as mr-out-<r>, range-partitioned "
+                        "from a sample of the keys")
     p.add_argument("--pattern", default=None,
                    help="literal grep pattern (required for grep-wc "
                         "and grep-grep)")
@@ -283,6 +310,10 @@ def _main(argv, opened: list) -> int:
     p.add_argument("--nreduce", type=_positive_int, default=10)
     p.add_argument("--u-cap", type=_positive_int, default=1 << 12)
     p.add_argument("--topk", type=_positive_int, default=16)
+    p.add_argument("--sort-sample", type=_positive_int, default=100_000,
+                   help="--chain sort: keys the sampling pre-pass reads "
+                        "for the split points (TeraSort's "
+                        "mapreduce.terasort.partitions.sample)")
     p.add_argument("--aot", action="store_true")
     p.add_argument("--checkpoint-dir", default=None,
                    help="stage-manifest commits land here: each "
@@ -320,6 +351,17 @@ def _main(argv, opened: list) -> int:
         p.error("--chain grep-grep requires --pattern2")
     if args.pack_docs and args.chain != "indexer":
         p.error("--pack-docs packs the documents of --chain indexer")
+    if args.chain == "sort":
+        for flag in ("check", "hosts", "checkpoint_dir", "pipeline",
+                     "stage_shards"):
+            if getattr(args, flag):
+                p.error(f"--{flag.replace('_', '-')} is not --chain "
+                        "sort's: the chain has one handoff mode and "
+                        "commits once, after its last stage")
+        if args.devices not in (None, 1):
+            p.error("--chain sort orders one worker's share on one "
+                    "device: --devices 1")
+        args.devices = 1
     if args.pipeline and args.staged:
         p.error("--pipeline is chained-mode only (staged execution "
                 "stays strictly sequential: it is the parity oracle)")
@@ -339,6 +381,16 @@ def _main(argv, opened: list) -> int:
         from dsi_tpu.obs import configure_tracing
 
         configure_tracing(trace_dir=args.trace_dir)
+
+    if args.chain == "sort":
+        # Not a truncated sort: refused before any stage (and before
+        # JAX is imported: a record is ops/sortk.RECORD_BYTES long).
+        for path in args.files:
+            if os.path.getsize(path) % 100:
+                print(f"planrun: {path}: {os.path.getsize(path)} bytes is "
+                      "not a whole number of 100-byte records",
+                      file=sys.stderr)
+                return 1
 
     from dsi_tpu.utils.platformpin import require_device
 
@@ -436,6 +488,19 @@ def _main(argv, opened: list) -> int:
         # host app names them (mrsequential in the files' directory).
         committed = res.index.named(
             [os.path.basename(path) for path in args.files])
+    elif args.chain == "sort":
+        from dsi_tpu.obs import span
+        from dsi_tpu.parallel.sortstream import write_sorted_output
+
+        with span("write", lane="host", stats=pstats,
+                  keys=res.final.records) as sp:
+            paths = write_sorted_output(res.final, args.workdir,
+                                        stats=pstats)
+            sp.set(bytes=sum(os.path.getsize(path) for path in paths))
+        pstats["write_s"] = round(pstats["write_s"], 4)
+        print(f"planrun: {res.final.records} records in key order -> "
+              f"{args.workdir}/mr-out-0..{args.nreduce - 1}",
+              file=sys.stderr)
     if committed is not None:
         from dsi_tpu.obs import span
         from dsi_tpu.parallel.shuffle import write_partitioned_output

@@ -125,6 +125,19 @@ waves: ``docs`` unless a rung restarted the walk) and
 ``docs_per_wave_max`` (the most one wave held: the devices' count
 unless the walk packs).
 
+The sort chain (``parallel/sortstream.py``, engine ``sort``; its two
+stages' scopes under ``stage_stats``): ``sample_s`` and
+``sort_sample_keys`` (the sampling pre-pass and the keys it read),
+``sort_records`` (records of the job), ``sort_resident_bytes`` (the store
+that stays on the device from the first step to the ordering: the
+records and their key lanes), ``sort_partition_rows`` (records a
+partition, a list of ``n_reduce``: the device's count against the
+sampled split points), ``order_s`` (the ``order`` span: the host
+blocked on the device's ordering) and ``sort_order_passes`` (its
+single-key sort passes).  The commit's ``pull_s`` / ``d2h_s`` /
+``pull_bytes`` / ``write_commit_s`` stand at the top of ``planrun``'s
+``pipeline_stats`` beside ``write_s``.
+
 Async/incremental checkpoint keys (``dsi_tpu/ckpt`` writer/delta —
 present when checkpointing is on): ``ckpt_async``/``ckpt_delta`` (the
 mode flags), ``ckpt_deltas`` (incremental saves among ``ckpt_saves``),
@@ -299,6 +312,9 @@ PHASE_KEYS = (
     # the job pays before its first stage, and what the walk then waits
     # for a document its reader threads have not read yet
     "pack_s", "read_s", "read_wait_s",
+    # the sort chain (parallel/sortstream.py): the sampling pre-pass, and
+    # the host blocked on the device's ordering of the resident store
+    "sample_s", "order_s",
 )
 
 #: The direct children of a stream command's root ``job`` span on its
@@ -356,6 +372,12 @@ COUNTER_KEYS = (
     # top of planrun's pipeline_stats): documents asked for, those that
     # were there when first asked for, the pool's threads
     "read_docs", "read_ahead_hits", "read_threads",
+    # the sort chain (parallel/sortstream.py): records of the job, keys
+    # the pre-pass sampled, bytes of the store that stays on the device,
+    # records a partition (a list of n_reduce), single-key sort passes
+    # of the ordering
+    "sort_records", "sort_sample_keys", "sort_resident_bytes",
+    "sort_partition_rows", "sort_order_passes",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

@@ -119,6 +119,10 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # threads have not read yet, before "pack" and outside it
     # (utils/ioread.py ReadAheadDocs)
     "read_wait",
+    # the sort chain (parallel/sortstream.py): the sampling pre-pass over
+    # the record files, and the host blocked on the device's ordering of
+    # the resident store
+    "sample", "order",
 ))
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"

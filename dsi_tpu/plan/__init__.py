@@ -5,8 +5,8 @@ indexing pipeline, OSDI'04 §6.4); this package chains this repo's
 engines so stage N+1's upload IS stage N's device-resident output:
 
 * :mod:`~dsi_tpu.plan.graph`  — the :class:`Plan`/:class:`Stage` DAG
-  model (+ the two canonical chains: grep → wordcount-over-matches and
-  indexer → df-top-k → postings join);
+  model (+ the canonical chains: grep → wordcount-over-matches,
+  indexer → df-top-k → postings join, sample → range sort);
 * :mod:`~dsi_tpu.plan.driver` — :func:`run_plan`, driving each stage as
   a resumable step object with relay handoffs
   (``device/relay.py``), stage-manifest commits through ``ckpt/``, and
@@ -25,6 +25,7 @@ from dsi_tpu.plan.graph import (
     grep_cascade_plan,
     grep_wordcount_plan,
     indexer_join_plan,
+    sort_plan,
     wordcount_topk_plan,
 )
 from dsi_tpu.plan.driver import (
@@ -44,5 +45,6 @@ __all__ = [
     "grep_wordcount_plan",
     "indexer_join_plan",
     "run_plan",
+    "sort_plan",
     "wordcount_topk_plan",
 ]

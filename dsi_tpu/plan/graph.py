@@ -9,7 +9,8 @@ device-resident handoffs (``dsi_tpu/device/relay.py``,
 ``parallel/stepobj.py`` exports) instead of host materializations.  The
 driver (``plan/driver.py``) runs it.
 
-Stage kinds (what the driver knows how to run):
+The eight stage kinds (what the driver knows how to run; ``sample`` and
+``range_sort``, the sort chain's, are the two PR 45 added):
 
 * ``grep``          — streaming literal grep over a byte source,
   emitting the matching lines into the outgoing relay (the
@@ -32,6 +33,18 @@ Stage kinds (what the driver knows how to run):
 * ``top_k``         — k highest-count words of an upstream wordcount's
   result (count desc, word asc) — a host reduction over an
   already-host value, no engine.
+* ``sample``        — TeraSort's sampling pre-pass over files of
+  100-byte records (``parallel/sortstream.sample_splits``, host side):
+  ``sample`` keys at evenly spaced record offsets, sorted, ``n_reduce`` - 1
+  split points at the equal-count positions.  The split points are the
+  stage's result.
+* ``range_sort``    — the sort that consumes an upstream ``sample``'s
+  split points (``parallel/sortstream.range_sort``): every record through
+  a device step into a store that stays on the device, ordered there by
+  key; the stage's result is the ordered store, which ``planrun --chain
+  sort`` pulls and commits as ``mr-out-<r>``, totally ordered.  The split
+  points it ran with enter the stage's identity, and so the plan's
+  signature, as a CRC (``splits``).  OSDI'04 section 5.3's own shape.
 * A ``grep`` stage MAY itself have a grep dep (the grep→grep cascade):
   it consumes the upstream relay's line stream instead of a byte
   source and re-greps it with its own pattern.
@@ -52,11 +65,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 #: The stage kinds plan/driver.py can run.
 STAGE_KINDS = ("grep", "wordcount", "indexer", "df_topk", "postings_join",
-               "top_k")
+               "top_k", "sample", "range_sort")
 
 #: Stage params carrying bulk payloads: identity-hashed, never inlined
 #: into the signature.
-_BULK_PARAMS = ("data", "docs", "paths")
+_BULK_PARAMS = ("data", "docs", "paths", "splits")
 
 
 class PlanError(ValueError):
@@ -94,7 +107,7 @@ class Stage:
                         crc = zlib.crc32(bytes(d), crc)
                         total += len(d)
                     out[k] = {"n": len(v), "bytes": total, "crc32": crc}
-                elif k == "data":
+                elif k in ("data", "splits"):
                     out[k] = {"bytes": len(v),
                               "crc32": zlib.crc32(bytes(v))}
                 else:  # paths: names are identity enough (files change
@@ -164,7 +177,7 @@ class Plan:
         }))
 
 
-# ── the two canonical chains ──────────────────────────────────────────
+# ── the canonical chains ──────────────────────────────────────────
 
 
 def grep_wordcount_plan(pattern: str, *, paths: Optional[Sequence[str]]
@@ -224,4 +237,17 @@ def indexer_join_plan(docs: Sequence[bytes], *, topk: int = 16,
                     **packed))
     t = p.add(Stage("dftopk", "df_topk", deps=[i.name], topk=topk))
     p.add(Stage("join", "postings_join", deps=[i.name, t.name]))
+    return p
+
+
+def sort_plan(paths: Sequence[str], *, sample: int = 100_000,
+              **defaults) -> Plan:
+    """sample → range_sort over files of 100-byte records (OSDI'04
+    section 5.3, TeraSort's partitioner): stage 1 reads ``sample`` keys
+    and gives the plan's ``n_reduce`` - 1 split points, stage 2 orders
+    every record on the device and counts the records a partition
+    against them."""
+    p = Plan("sort", **defaults)
+    s = p.add(Stage("sample", "sample", paths=list(paths), sample=sample))
+    p.add(Stage("sort", "range_sort", deps=[s.name], paths=list(paths)))
     return p

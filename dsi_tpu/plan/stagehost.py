@@ -76,7 +76,8 @@ def build_plan(spec: Dict):
     ``planrun`` (which derives the spec from argv) and every stage host
     (which must see the IDENTICAL plan graph)."""
     from dsi_tpu.plan import (grep_cascade_plan, grep_wordcount_plan,
-                              indexer_join_plan, wordcount_topk_plan)
+                              indexer_join_plan, sort_plan,
+                              wordcount_topk_plan)
 
     defaults = dict(chunk_bytes=spec.get("chunk_bytes", 1 << 20),
                     depth=spec.get("depth"),
@@ -107,6 +108,9 @@ def build_plan(spec: Dict):
 
         return indexer_join_plan(ReadAheadDocs(files), pack_docs=bool(
             spec.get("pack_docs", False)), **defaults)
+    if chain == "sort":
+        return sort_plan(files, sample=spec.get("sample", 100_000),
+                         **defaults)
     raise ValueError(f"unknown chain {chain!r}")
 
 
