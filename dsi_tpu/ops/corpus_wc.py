@@ -41,13 +41,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from dsi_tpu.ops.wordcount import (
-    _PAD_KEY,
-    build_lanes,
-    compact_positions,
     exactness_retry,
     group_sorted,
-    is_ascii_letter,
     lex_sort,
+    token_lanes,
 )
 
 # pos<<7|len packing needs pos < 2**25: cap the padded corpus at 32 MiB per
@@ -105,38 +102,21 @@ def corpus_kernel_packed(*pieces_and_table, max_word_len: int = 16,
 
 
 def _corpus_core(chunk, max_word_len: int, u_cap: int, t_cap_frac: int):
-    import jax
     import jax.numpy as jnp
-    from jax import lax
 
     n = chunk.shape[0]
     if n > 1 << _POS_BITS:
         raise ValueError(f"corpus_kernel caps at {1 << _POS_BITS} bytes")
-    k = max_word_len // 4
     t_cap = n // t_cap_frac + 1
 
-    idx = jnp.arange(n, dtype=jnp.int32)
-    letter = is_ascii_letter(chunk)
-    prev_letter = jnp.concatenate([jnp.zeros((1,), jnp.bool_), letter[:-1]])
-    starts = letter & ~prev_letter
-    n_tokens = jnp.sum(starts, dtype=jnp.int32)
+    # Lanes, lengths and first-byte positions of the tokens, by the
+    # word-count program's own movement (ops/wordcount.token_lanes).
+    packed_cols, lengths, n_tokens, _, start_pos = token_lanes(
+        chunk, max_word_len=max_word_len, t_cap_frac=t_cap_frac,
+        with_pos=True)
     token_overflow = n_tokens > t_cap
-
-    # Token length at every position: distance to next non-letter via one
-    # log-depth reverse cumulative-min (no gathers; ops/wordcount.py idiom).
-    m = jnp.where(letter, n, idx)
-    next_nl = lax.associative_scan(jnp.minimum, m, reverse=True)
-    length_all = (next_nl - idx).astype(jnp.int32)
-
-    lanes = build_lanes(chunk, length_all, max_word_len)
-
-    start_pos = compact_positions(starts, t_cap, n - 1)
-    valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
-    lengths = jnp.where(valid, length_all[start_pos], 0)
+    valid = lengths > 0  # a token holds a letter; a pad row has length 0
     max_len = jnp.max(lengths, initial=0)
-    packed_cols = tuple(
-        jnp.where(valid, lane[start_pos], jnp.uint32(_PAD_KEY))
-        for lane in lanes)
     # Position and length ride grouping as ONE pre-packed payload column
     # (pos << 7 | len — already the wire encoding).
     poslen_tok = jnp.where(

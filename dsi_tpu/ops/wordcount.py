@@ -22,13 +22,13 @@ the TPU execution model rather than translated:
 
 TPU-shaped design decisions (what makes this fast, not just correct):
 
-* **no random byte-gathers**: the packed key lanes are built for every
+* **no gathers from the chunk**: the packed key lanes are built for every
   position at once from shifted copies of the chunk (pure elementwise
-  shifts/ors — HBM-bandwidth bound), instead of gathering ``[tokens, 16]``
-  individual bytes, which XLA lowers to millions of scalar loads on TPU;
-* **token lengths without a gather**: distance-to-next-non-letter for all
-  positions via one reverse ``lax.associative_scan`` (log-depth cumulative
-  min), so a token's length is just ``next_nonletter[i] - i``;
+  shifts/ors), a token's length for every position from one reverse
+  running minimum over the token ends, and the positions that start a
+  token then move to the token buffer in ``log2 n`` steps of shifted
+  selects (:func:`token_lanes`, :func:`_move_left`): no sort and no gather
+  over the chunk's positions;
 * **small sort buffer**: tokens are compacted to ``n // t_cap_frac + 1``
   slots (a token needs ≥ 1 letter + a separator ⇒ ``n//2+1`` is the hard
   bound; real text is ≥ 4 bytes/token, so the default frac=4 buffer is 2×
@@ -89,23 +89,6 @@ def _byte_mask(keep: jax.Array) -> jax.Array:
                   jnp.where(keep == 2, jnp.uint32(0xFFFF0000),
                             jnp.where(keep == 1, jnp.uint32(0xFF000000),
                                       jnp.uint32(0)))))
-
-
-def build_lanes(chunk: jax.Array, length_all: jax.Array, max_word_len: int):
-    """Per-position packed key lanes from shifted chunk copies (no gathers).
-
-    lane_j[i] = big-endian uint32 of bytes chunk[i+4j .. i+4j+3], zero-masked
-    past the token length at i.  Big-endian packing keeps uint32 order ==
-    bytewise order and makes host detokenization one ``.tobytes()``.
-    """
-    c = chunk.astype(jnp.uint32)
-    b32 = ((c << 24) | (_shift_left(c, 1) << 16)
-           | (_shift_left(c, 2) << 8) | _shift_left(c, 3))
-    lanes = []
-    for j in range(max_word_len // 4):
-        keep = jnp.clip(length_all - 4 * j, 0, 4)
-        lanes.append(_shift_left(b32, 4 * j) & _byte_mask(keep))
-    return lanes
 
 
 @jax.named_scope("hash")
@@ -211,6 +194,125 @@ def compact_positions(mask: jax.Array, size: int,
     return jnp.where(key < m, key, jnp.int32(fill_value))
 
 
+def _move_left(d: jax.Array, arrays: list) -> list:
+    """Every slot of ``arrays`` whose ``d`` is positive, moved ``d`` slots
+    to the left; a slot with ``d`` 0 stays, and what a slot that nothing
+    reaches holds afterwards is unspecified.  ``d`` is the distance of an
+    ORDER-PRESERVING compaction: slot ``i`` minus the number of live slots
+    before it, 0 for a slot that is not live.
+
+    ``log2`` steps of one shifted select an array, low bit first: at step
+    ``b`` a slot whose ``d`` has bit ``b`` set moves ``2^b``.  Low bits
+    first routes a compaction without collisions: for live slots i < j,
+    ``j - i > d_j - d_i >= 0``, so after any number of low bits two live
+    slots still differ, and a moving slot lands only on one that has moved
+    away or was never live.  ``d`` rides along whole; a vacated slot takes
+    ``d`` 0.  No sort, no gather, no scatter: a step is an elementwise
+    pass over the arrays, where a gather of the live rows cost 7.5 ns a
+    row and lane on a TPU v5e (``scripts/pack_micro.py``; PERF.md, PR 46)."""
+    for b in range(max(d.shape[0] - 1, 1).bit_length()):
+        s = 1 << b
+        bit = ((d >> b) & 1) != 0
+        came = _shift_left(bit, s)
+        arrays = [jnp.where(came, _shift_left(x, s), x) for x in arrays]
+        d = jnp.where(came, _shift_left(d, s), jnp.where(bit, 0, d))
+    return arrays
+
+
+def token_lanes(chunk: jax.Array, *, max_word_len: int, t_cap_frac: int,
+                doc_sep: Optional[int] = None, with_pos: bool = False):
+    """The tokens of a chunk as rows of the token buffer, in the order of
+    their first bytes: ``(packed_cols, lengths, n_tokens, doc_lane,
+    start_pos)``.
+
+    ``packed_cols``: ``max_word_len / 4`` columns ``uint32[t_cap]``, a
+    token's first bytes big-endian, zero past its length, ``_PAD_KEY`` in
+    the rows past the last token (``t_cap = n // t_cap_frac + 1``; of more
+    tokens than that the first ``t_cap`` are kept and ``n_tokens`` says
+    so); ``lengths``: ``int32[t_cap]``, 0 in those rows; ``doc_lane``
+    (``doc_sep``): the count of separator bytes before the token,
+    ``_PAD_KEY`` in those rows, else ``None``; ``start_pos``
+    (``with_pos``): the position of the token's first byte, ``int32``,
+    unspecified in those rows, else ``None``.
+
+    A start is a letter behind a non-letter, so two adjacent positions
+    hold at most one: the chunk is split once into its even and odd bytes
+    and everything else runs over ``n / 2`` slots, a slot a pair of
+    positions.  What a token hands over (its lanes as shifted copies of
+    the bytes, its length by a reverse running minimum over the token
+    ends, the separators before it by a running sum) is computed at every
+    slot as if a token began there, and :func:`_move_left` then moves the
+    slots that hold a start to the front of the buffer.
+    """
+    n = chunk.shape[0]
+    k = max_word_len // 4
+    t_cap = n // t_cap_frac + 1
+    if n % 2:
+        chunk = jnp.concatenate([chunk, jnp.zeros((1,), chunk.dtype)])
+    m = chunk.shape[0] // 2
+
+    with jax.named_scope("tokenize"):
+        ev = lax.slice(chunk, (0,), (2 * m,), (2,))
+        od = lax.slice(chunk, (1,), (2 * m,), (2,))
+        let_ev, let_od = is_ascii_letter(ev), is_ascii_letter(od)
+        start_ev = let_ev & ~jnp.concatenate(
+            [jnp.zeros((1,), jnp.bool_), let_od[:-1]])
+        start_od = let_od & ~let_ev
+        live = start_ev | start_od
+        n_tokens = jnp.sum(live, dtype=jnp.int32)
+        slot = jnp.arange(m, dtype=jnp.int32)
+        start = 2 * slot + start_od.astype(jnp.int32)
+        # A pair holds at most one token end as well, and none before a
+        # start of its own, so the first end at or behind a start's slot
+        # is its token's.
+        end = jnp.where(let_ev & ~let_od, 2 * slot,
+                        jnp.where(let_od & ~_shift_left(let_ev, 1),
+                                  2 * slot + 1, jnp.int32(2 * m)))
+        length = lax.cummin(end, reverse=True) - start + 1
+
+    with jax.named_scope("pack"):
+        e, o = ev.astype(jnp.uint32), od.astype(jnp.uint32)
+        e1, o1, e2 = _shift_left(e, 1), _shift_left(o, 1), _shift_left(e, 2)
+        # The four bytes at an even position and at the odd one behind it;
+        # lane j of a token lies 4j bytes on, two slots of its own parity.
+        b_ev = (e << 24) | (o << 16) | (e1 << 8) | o1
+        b_od = (o << 24) | (e1 << 16) | (o1 << 8) | e2
+        moved = [jnp.where(start_od, _shift_left(b_od, 2 * j),
+                           _shift_left(b_ev, 2 * j)) for j in range(k)]
+        moved.append(length)
+        if doc_sep is not None:
+            sep_od = (od == jnp.uint8(doc_sep)).astype(jnp.int32)
+            sep_ev = (ev == jnp.uint8(doc_sep)).astype(jnp.int32)
+            # No letter is a separator; the odd byte of an even start's
+            # pair lies behind the start.
+            moved.append(jnp.cumsum(sep_ev + sep_od, dtype=jnp.int32)
+                         - jnp.where(start_od, 0, sep_od))
+        if with_pos:
+            moved.append(start)
+
+    with jax.named_scope("compact"):
+        live32 = live.astype(jnp.int32)
+        before = jnp.cumsum(live32, dtype=jnp.int32) - live32
+        moved = _move_left(jnp.where(live, slot - before, 0), moved)
+        if m < t_cap:  # t_cap_frac 2: one row more than there are pairs
+            moved = [jnp.concatenate([x, jnp.zeros((t_cap - m,), x.dtype)])
+                     for x in moved]
+        moved = [x[:t_cap] for x in moved]
+        valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
+        lengths = jnp.where(valid, moved[k], 0)
+
+    with jax.named_scope("pack"):
+        packed_cols = tuple(
+            jnp.where(valid,
+                      moved[j] & _byte_mask(jnp.clip(lengths - 4 * j, 0, 4)),
+                      jnp.uint32(_PAD_KEY))
+            for j in range(k))
+        doc_lane = None if doc_sep is None else jnp.where(
+            valid, moved[k + 1].astype(jnp.uint32), jnp.uint32(_PAD_KEY))
+    return (packed_cols, lengths, n_tokens, doc_lane,
+            moved[-1] if with_pos else None)
+
+
 def running_sum(x: jax.Array) -> jax.Array:
     """Inclusive prefix sum of a 1-D integer array in its OWN dtype: the
     adds are modular, as a ``segment_sum``'s are, so differences of it
@@ -302,9 +404,8 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
     document): one row for every distinct word of every document, and a
     ninth result, ``doc_u`` [u_cap] int32, the row's document as its
     place in the chunk.  A token's place is the count of separators
-    before its first byte, a prefix sum over the chunk read at
-    ``start_pos``, and it sorts as one more key lane behind the word's.
-    With ``None`` the program is what it was.
+    before its first byte (:func:`token_lanes`), and it sorts as one
+    more key lane behind the word's.
 
     Identical tokens are grouped by an exact lexicographic sort of the
     key lanes (:func:`lex_sort`) and a scan of the run boundaries
@@ -321,46 +422,11 @@ def tokenize_group_core(chunk: jax.Array, *, max_word_len: int = 16,
     k = max_word_len // 4
     t_cap = n // t_cap_frac + 1
 
-    with jax.named_scope("tokenize"):
-        letter = is_ascii_letter(chunk)
-        prev_letter = jnp.concatenate(
-            [jnp.zeros((1,), jnp.bool_), letter[:-1]])
-        starts = letter & ~prev_letter
-        next_letter = jnp.concatenate(
-            [letter[1:], jnp.zeros((1,), jnp.bool_)])
-        ends = letter & ~next_letter
-        n_tokens = jnp.sum(starts, dtype=jnp.int32)
-        token_overflow = n_tokens > t_cap
-
-    # Compact to the token buffer.  Token lengths come from the paired
-    # start/end compactions (runs cannot nest, so the i-th start matches
-    # the i-th end), each one int32 sort over the chunk's positions
-    # (compact_positions).  Key lanes gather straight from the single
-    # packed-bytes array at ``start + 4j`` and are masked AFTER
-    # compaction, so the byte-masking runs over t_cap rows and no masked
-    # full-chunk lane arrays are built.
-    with jax.named_scope("compact"):
-        start_pos = compact_positions(starts, t_cap, n - 1)
-        end_pos = compact_positions(ends, t_cap, n - 1)
-        valid = jnp.arange(t_cap, dtype=jnp.int32) < n_tokens
-        lengths = jnp.where(valid, end_pos - start_pos + 1, 0)
-        max_len = jnp.max(lengths, initial=0)
-        if doc_sep is not None:
-            # No letter is a separator, so the sum at a token's start
-            # counts the separators before it.
-            seps = jnp.cumsum(chunk == jnp.uint8(doc_sep), dtype=jnp.int32)
-            doc_lane = jnp.where(valid, seps[start_pos].astype(jnp.uint32),
-                                 jnp.uint32(_PAD_KEY))
-    with jax.named_scope("pack"):
-        c = chunk.astype(jnp.uint32)
-        b32 = ((c << 24) | (_shift_left(c, 1) << 16)
-               | (_shift_left(c, 2) << 8) | _shift_left(c, 3))
-        packed_cols = tuple(
-            jnp.where(valid,
-                      b32[start_pos + 4 * j]
-                      & _byte_mask(jnp.clip(lengths - 4 * j, 0, 4)),
-                      jnp.uint32(_PAD_KEY))
-            for j in range(k))
+    packed_cols, lengths, n_tokens, doc_lane, _ = token_lanes(
+        chunk, max_word_len=max_word_len, t_cap_frac=t_cap_frac,
+        doc_sep=doc_sep)
+    token_overflow = n_tokens > t_cap
+    max_len = jnp.max(lengths, initial=0)
 
     # Group identical words: lexicographic sort over the key lanes
     # (lex_sort: one single-key pass per lane), then run boundaries.  A

@@ -8,7 +8,9 @@ Every form returns the values of ``jnp.nonzero(mask, size=, fill_value=)``
 and is checked against ``np.flatnonzero`` before it is timed.  One JSON
 line per (form, shape) on stdout and in ``chiprun_out/compact_micro.jsonl``.
 ``--kernel`` also times the whole word-count program at a batch map's and
-a stream step's shape; ``--tiny`` divides every shape by 1,024 (a rehearsal
+a stream step's shape (the forms of the token lengths that PR 35 timed
+here went with PR 46: the program compacts no end positions any more,
+``scripts/pack_micro.py``); ``--tiny`` divides every shape by 1,024 (a rehearsal
 of the script on the CPU, whose times mean nothing).
 """
 import json
@@ -21,7 +23,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from dsi_tpu.ops.wordcount import compact_positions, tokenize_group_core
 from dsi_tpu.utils.jaxcompat import enable_x64
@@ -64,35 +65,6 @@ def segmin32(mask, size, fill_value):
 
 FORMS = {"nonzero64": nonzero64, "sort": compact_positions,
          "scatter32": scatter32, "segmin32": segmin32}
-
-
-def lengths_two_sorts(starts, ends, t_cap):
-    """The ``compact`` scope as adopted: both masks compacted."""
-    n = starts.shape[0]
-    s = compact_positions(starts, t_cap, n - 1)
-    e = compact_positions(ends, t_cap, n - 1)
-    return s, e - s + 1
-
-
-def lengths_cummin(starts, ends, t_cap):
-    """One compaction; a token's end from a reverse cummin of the end
-    positions, gathered at the starts."""
-    n = starts.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    s = compact_positions(starts, t_cap, n - 1)
-    next_end = lax.cummin(jnp.where(ends, pos, jnp.int32(n)), reverse=True)
-    return s, next_end[s] - s + 1
-
-
-def lengths_carried(starts, ends, t_cap):
-    """One two-operand sort that carries the token length beside its
-    start (the length from the same reverse cummin)."""
-    n = starts.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    next_end = lax.cummin(jnp.where(ends, pos, jnp.int32(n)), reverse=True)
-    key = jnp.where(starts, pos, jnp.int32(n))
-    key, length = lax.sort((key, next_end - pos + 1), num_keys=1)
-    return key[:t_cap], length[:t_cap]
 
 
 def timed(fn, args, reps):
@@ -151,27 +123,8 @@ def main(argv):
                  first_call_s=first_s, dtype=str(out.dtype),
                  equal=bool((np.asarray(out) == want).all()))
 
-    n = 1 << (24 - cut)
-    chunk = text(n, rng)
-    letter = ((chunk | 32) >= 97) & ((chunk | 32) <= 122)
-    starts = letter & ~np.concatenate([[False], letter[:-1]])
-    ends = letter & ~np.concatenate([letter[1:], [False]])
-    t_cap = n // 4 + 1
-    ref = None
-    for name, form in (("two_sorts", lengths_two_sorts),
-                       ("cummin_gather", lengths_cummin),
-                       ("carried", lengths_carried)):
-        fn = jax.jit(form, static_argnums=2)
-        (s, ln), first_s, ms = timed(
-            fn, (jnp.asarray(starts), jnp.asarray(ends), t_cap), 3)
-        k = int(starts.sum())
-        got = (np.asarray(s)[:k], np.asarray(ln)[:k])
-        ref = ref or got
-        emit(what="lengths", form=name, m=n, size=t_cap, ms=ms,
-             first_call_s=first_s,
-             equal=bool((got[0] == ref[0]).all() and (got[1] == ref[1]).all()))
-
     if "--kernel" in argv:
+        chunk = text(1 << (24 - cut), rng)
         for n, u_cap in ((1 << 24, 1 << 17), (1 << 20, 1 << 16)):
             n, u_cap = n >> cut, max(u_cap >> cut, 4096)
             fn = jax.jit(tokenize_group_core,

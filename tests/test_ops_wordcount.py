@@ -590,3 +590,172 @@ def test_program_at_its_token_boundaries(case):
     assert hashes == [ihash(w) for w in words]
     if not bool(token_overflow):
         check(chunk.rstrip(b"\0").decode())
+
+
+# ── the lane movement: chunk positions -> token buffer rows (PR 46) ────
+
+
+def _lanes_chunk(case: str) -> bytes:
+    """The five boundary chunks and three more: a word of exactly
+    ``max_word_len`` bytes, longer ones (17 and 40), and a pack of
+    documents with an empty one and a separator as the last byte."""
+    from dsi_tpu.ops.wordcount import DOC_SEP
+
+    if case == "word_of_max_len":
+        return (b"x " + b"q" * 16 + b" ab " + b"Z" * 16).ljust(_N, b"\0")
+    if case == "word_longer":
+        return (b"k" * 17 + b"," + b"m" * 40 + b" end").ljust(_N, b" ")
+    if case == "doc_sep_pack":
+        sep = bytes([DOC_SEP])
+        docs = [b"one two one", b"", b"three", b"four five " * 20]
+        return sep.join(docs)[:_N - 1].ljust(_N - 1, b"x") + sep
+    return _boundary_chunk(case)
+
+
+def _gathers_reference(chunk: bytes, k: int, t_cap: int, doc_sep):
+    """The four-gather form the program held until PR 46, in numpy:
+    ``b32[start + 4j]`` masked by the token's length, PAD rows behind."""
+    import numpy as np
+
+    a = np.frombuffer(chunk, np.uint8)
+    n = len(a)
+    letter = (((a | 32) >= 97) & ((a | 32) <= 122))
+    starts = np.flatnonzero(letter & ~np.concatenate([[False], letter[:-1]]))
+    ends = np.flatnonzero(letter & ~np.concatenate([letter[1:], [False]]))
+    n_tokens = len(starts)
+    starts, ends = starts[:t_cap], ends[:t_cap]
+    nt = len(starts)
+    z = np.concatenate([a, np.zeros(3, np.uint8)]).astype(np.uint32)
+    b32 = (z[:n] << 24) | (z[1:n + 1] << 16) | (z[2:n + 2] << 8) | z[3:n + 3]
+    lengths = np.zeros(t_cap, np.int32)
+    lengths[:nt] = ends - starts + 1
+    cols = []
+    for j in range(k):
+        keep = np.clip(lengths[:nt] - 4 * j, 0, 4)
+        mask = (np.uint64(0xFFFFFFFF) << (8 * (4 - keep)).astype(np.uint64)
+                ).astype(np.uint32)
+        col = np.full(t_cap, 0xFFFFFFFF, np.uint32)
+        col[:nt] = b32[np.minimum(starts + 4 * j, n - 1)] & mask
+        cols.append(col)
+    doc = None
+    if doc_sep is not None:
+        doc = np.full(t_cap, 0xFFFFFFFF, np.uint32)
+        doc[:nt] = np.cumsum(a == doc_sep)[starts]
+    pos = np.full(t_cap, n - 1, np.int32)
+    pos[:nt] = starts
+    return cols, lengths, doc, pos, n_tokens
+
+
+_LANES_CASES = ["t_cap_tokens", "t_cap_plus_one", "no_tokens",
+                "single_letters", "ends_in_letter", "word_of_max_len",
+                "word_longer", "doc_sep_pack"]
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("frac", [4, 2])
+@pytest.mark.parametrize("case", _LANES_CASES)
+def test_token_lanes_equal_the_gathers_they_replace(case, frac, x64):
+    """``token_lanes`` row for row against numpy's rendering of the
+    gathers: the key lanes masked by the length, the lengths, the document
+    lane, PAD rows included; with more tokens than ``t_cap`` the first
+    ``t_cap``."""
+    import jax
+    import numpy as np
+
+    from dsi_tpu.ops.wordcount import DOC_SEP, token_lanes
+    from dsi_tpu.utils.jaxcompat import enable_x64
+
+    chunk = _lanes_chunk(case)
+    assert len(chunk) == _N
+    doc_sep = DOC_SEP if case == "doc_sep_pack" else None
+    k, t_cap = 4, _N // frac + 1
+    cols, lengths, doc, pos, n_tokens = _gathers_reference(
+        chunk, k, t_cap, doc_sep)
+    if case == "t_cap_plus_one":
+        assert n_tokens == _N // 4 + 2  # over the buffer at frac 4 alone
+    with enable_x64(x64):
+        got = jax.jit(token_lanes, static_argnames=(
+            "max_word_len", "t_cap_frac", "doc_sep", "with_pos"))(
+            np.frombuffer(chunk, np.uint8), max_word_len=4 * k,
+            t_cap_frac=frac, doc_sep=doc_sep, with_pos=True)
+    got_cols, got_len, got_n, got_doc, got_pos = got
+    assert int(got_n) == n_tokens
+    assert len(got_cols) == k
+    for j in range(k):
+        assert got_cols[j].dtype == np.uint32
+        np.testing.assert_array_equal(np.asarray(got_cols[j]), cols[j])
+    assert got_len.dtype == np.int32
+    np.testing.assert_array_equal(np.asarray(got_len), lengths)
+    nt = min(n_tokens, t_cap)
+    np.testing.assert_array_equal(np.asarray(got_pos)[:nt], pos[:nt])
+    if doc_sep is None:
+        assert got_doc is None
+    else:
+        assert got_doc.dtype == np.uint32
+        np.testing.assert_array_equal(np.asarray(got_doc), doc)
+
+
+def _chunk_gathers(fn, n, *args):
+    """How many ``gather``s of ``fn``'s jaxpr read an operand as long as
+    the chunk or as its pairs of positions (``n`` or ``n // 2``)."""
+    import jax
+
+    from dsi_tpu.utils.jaxcompat import enable_x64
+
+    def count(jaxpr):
+        hits = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "gather" and any(
+                    d in (n, n // 2) for d in eqn.invars[0].aval.shape):
+                hits += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                hits += count(sub)
+        return hits
+
+    with enable_x64(True):
+        return count(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("program, n_dev, more", [
+    ("wc", 1, {}),
+    ("corpus", 1, {}),
+    ("stream", 1, {}),
+    ("stream", 4, {}),
+    ("idx", 1, {"pack_docs": True}),
+])
+def test_word_count_programs_gather_nowhere_from_the_chunk(program, n_dev,
+                                                           more):
+    """The mechanism of PR 46, pinned where no device trace is at hand:
+    no gather reads an array of the chunk's length (the lanes, the
+    lengths and the document lane move to the token buffer by shifted
+    selects)."""
+    import functools
+
+    from tests.harness import word_count_program
+
+    n = 1024  # n, n // 2, t_cap 257, u_cap 64 and 4 * 64: all distinct
+    _, fn, args, static = word_count_program(program, n_dev, size=n,
+                                             u_cap=64, **more)
+    assert _chunk_gathers(functools.partial(fn, **static), n, *args) == 0
+
+
+@pytest.mark.parametrize("doc_sep, extra", [(None, 0), (0x1E, 1)])
+def test_the_gather_count_sees_the_form_it_replaced(doc_sep, extra):
+    """The control of the test above: the form the program held until
+    PR 46, kept in ``scripts/pack_micro.py`` as the micro-benchmark's
+    reference, holds one chunk-long gather a lane and one more for the
+    documents."""
+    import importlib.util
+    import os
+
+    import jax.numpy as jnp
+
+    spec = importlib.util.spec_from_file_location(
+        "pack_micro", os.path.join(os.path.dirname(__file__), os.pardir,
+                                   "scripts", "pack_micro.py"))
+    micro = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(micro)
+    n = 1024
+    assert _chunk_gathers(
+        lambda c: micro.gathers(c, n // 4 + 1, doc_sep), n,
+        jnp.zeros(n, jnp.uint8)) == micro.K + extra

@@ -418,27 +418,28 @@ def test_kernel_groups_by_word_and_document():
 
 # ── with the flag off, the programs are what they were ─────────────────
 
-#: sha256 of the lowered text, jax 0.9.0, CPU.  ``wc`` and ``idx`` at
-#: commit 9831bd2 (PR 39): ``tokenize_group_core`` at 4,096 B, and the
-#: index wave program on 1 and 4 devices.  The rest at commit 6415a3d
-#: (PR 43), before the hash grouper and its ``grouper`` static went: the
-#: stream step and the TF-IDF wave on 4 devices, ``corpus_kernel``, and
-#: the wave program at the rung the served word-count lane starts from.
+#: sha256 of the lowered text, jax 0.9.0, CPU, taken with PR 46, whose
+#: lane movement (``ops/wordcount.token_lanes``) changed every program the
+#: word-count map is in: ``tokenize_group_core`` at 4,096 B, the index
+#: wave program on 1 and 4 devices, the stream step and the TF-IDF wave
+#: on 4 devices, ``corpus_kernel``, and the wave program at the rung the
+#: served word-count lane starts from.  Before PR 46 the digests dated
+#: from commits 9831bd2 (PR 39) and 6415a3d (PR 43).
 LOWERED_BEFORE = {
     ("wc", 1):
-        "325c474ee16583bd56a0969455f0b9509f541b4d25de105e5e6ca3b9710d9a54",
+        "c2193059527678187a46f8430f8647ec7af73340a3090c3f60c7550b5598aa80",
     ("idx", 1):
-        "3c924e48102362fb2ce42a2ecd20aed33051df2691cc2b5bdb5145f78ce56349",
+        "6d4e960fc7eb7656c4cfadc21d6b0721a7b460e96b030998c551ec9aec929150",
     ("idx", 4):
-        "0b92b16936fe7d0d12b4d780b0b07f66c79ba1b46f19f5244e9ce506aa75e28b",
+        "4f3cbb112ca9a0f0f5d4ebfb9d02a96077335383b87fe300a6235f4d8cf689cc",
     ("stream", 4):
-        "5a02b30d30b57be1d4a6044d5536683c85e47033c0caafe627c1e34298e3b547",
+        "226f75bb62f62323c25bbda0beae3389be1685ddcf85b7bbb20e34b3943606dd",
     ("tfidf", 4):
-        "57940b3d99a974903d57a0d34e44c6947bdd3cc965abeaf781e6b50ba1b0bc05",
+        "e58675b922e7bdf571918b87e9bb95f58dd2a79934aa696dfc366b5fe37534f0",
     ("corpus", 1):
-        "e5c8779a13ae2286ef15a908653b94c04f9726398a0f4f3146f794e0565ed249",
+        "f543ea89ebb40499903cf935a27cb596bf37dd39ba30d286b41358207d614d4f",
     ("serve", 1):
-        "70dc19f9fd2f93034c1920f7f436c35af9b927013bd61d6183b95a0f472bbc07",
+        "57cba2c49ec159fe46ba5f5b9e22c3351b48b236bf5ffee4866363d0f5e57603",
 }
 
 #: The compiled program's name: the key of its persisted executable.
