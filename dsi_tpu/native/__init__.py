@@ -147,6 +147,14 @@ def _load():
             lib.pc_merge2.argtypes = (
                 [ctypes.c_void_p] * 4 + [ctypes.c_long]) * 2 + [
                 ctypes.c_int] + [ctypes.c_void_p] * 4
+            lib.pt_run_cuts.restype = ctypes.c_long
+            lib.pt_run_cuts.argtypes = [ctypes.c_void_p, ctypes.c_long,
+                                        ctypes.c_int, ctypes.c_void_p,
+                                        ctypes.c_long]
+            lib.pt_merge_runs.restype = ctypes.c_long
+            lib.pt_merge_runs.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_long,
+                ctypes.c_int] + [ctypes.c_void_p] * 6
             _lib = lib
         except (OSError, AttributeError) as e:
             # AttributeError: a stale .so predating a symbol and a failed
@@ -433,3 +441,75 @@ def merge_runs2(a, b, out) -> Optional[int]:
     return lib.pc_merge2(*_table_pointers(a, k), len(a[0]),
                          *_table_pointers(b, k), len(b[0]), k,
                          *_table_pointers(out, k))
+
+
+def _posting_rows(rows, kk: int):
+    """``rows`` as ``mergeruns.cpp`` reads posting rows: C-contiguous
+    uint32 ``[n, kk + 4]``; anything else is refused here, not read
+    there."""
+    if rows.ndim != 2 or rows.shape[1] != kk + 4 or rows.dtype != "uint32" \
+            or not rows.flags.c_contiguous:
+        raise ValueError("posting rows have to be C-contiguous uint32 "
+                         f"[n, {kk + 4}], not {rows.dtype}{rows.shape}")
+    return rows
+
+
+def run_cuts(rows, kk: int, cuts):
+    """How often the posting rows ``[n, kk + 4]`` descend in their ``kk``
+    key lanes, a row sorting before the one above it
+    (``mergeruns.cpp``); the first ``len(cuts)`` such rows' indices are
+    written to ``cuts`` (C-contiguous int64).  None -> the caller finds
+    them itself."""
+    lib = _load()
+    if lib is None:
+        return None
+    if cuts.dtype != "int64" or cuts.ndim != 1 \
+            or not cuts.flags.c_contiguous:
+        raise ValueError("the cuts have to be C-contiguous int64, not "
+                         f"{cuts.dtype}{cuts.shape}")
+    return lib.pt_run_cuts(_posting_rows(rows, kk).ctypes.data, len(rows),
+                           kk, cuts.ctypes.data, len(cuts))
+
+
+def merge_posting_runs(bufs, cuts, kk: int, out) -> Optional[int]:
+    """Buffers of posting rows merged into the grouped index's columns
+    (``mergeruns.cpp``).  ``bufs[b]`` is ``[n, kk + 4]`` and holds the
+    runs that ``cuts[b]`` (int64 row indices, increasing, inside the
+    buffer) cuts it into, each run's words never descending; the earlier
+    run leaves first among equal words.  ``out`` is ``(skeys [n, kk]
+    uint32, lens uint32, parts uint32, starts int64, tfs uint32, docs
+    uint32)``, every column C-contiguous with room for all the rows.
+    Returns the words written to the first four, or None -> the caller
+    merges the runs itself."""
+    lib = _load()
+    if lib is None:
+        return None
+    import numpy as np
+
+    where, ends = [], []
+    for rows, c in zip(bufs, cuts):
+        n, step = len(_posting_rows(rows, kk)), 4 * (kk + 4)
+        if len(c) and not (0 < c[0] and c[-1] < n
+                           and (c[1:] > c[:-1]).all()):
+            raise ValueError(f"cuts outside a buffer of {n} rows")
+        edges = np.concatenate(([0], c, [n])).astype(np.uint64)
+        where.append(rows.ctypes.data + edges[:-1] * np.uint64(step))
+        ends.append(np.diff(edges))
+    where = np.concatenate(where) if where else np.zeros(0, np.uint64)
+    lens = np.concatenate(ends).astype(np.int64) if ends \
+        else np.zeros(0, np.int64)
+    n_rows = int(lens.sum())
+    for x, dtype in zip(out, ("uint32", "uint32", "uint32", "int64",
+                              "uint32", "uint32")):
+        if x.dtype != dtype or not x.flags.c_contiguous \
+                or len(x) < n_rows:
+            raise ValueError(
+                f"an index column has to be C-contiguous {dtype} with room "
+                f"for {n_rows} rows, not {x.dtype}{x.shape}")
+    if out[0].ndim != 2 or out[0].shape[1] != kk:
+        raise ValueError(f"key lanes {out[0].shape}, wanted [n, {kk}]")
+    words = lib.pt_merge_runs(where.ctypes.data, lens.ctypes.data,
+                              len(lens), kk, *(x.ctypes.data for x in out))
+    if words < 0:
+        raise MemoryError(f"no room to merge {len(lens)} runs")
+    return words

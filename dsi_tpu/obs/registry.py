@@ -40,8 +40,9 @@ that phase):
   ``merge_s``, ``finalize_s`` or ``sync_s``, whichever called it
 * ``group_s``            — the ``group`` span: the indexer's postings
   table grouped into the index (``merge.PostingsTable.finalize_packed``:
-  one lexsort over the key lanes and the run detection), once a job,
-  when the first stage that needs the whole table asks for it
+  the runs the waves' rows arrive in found and merged, no row through a
+  sort), once a job, when the first stage that needs the whole table
+  asks for it
 * ``pack_s``             — the ``pack`` spans of a packed index walk
   (``planrun --chain indexer --pack-docs``): one wave's chunks joined
   from whole documents and its vector of ordinals built
@@ -119,7 +120,10 @@ wave walk adds ``docs`` (documents handed over), ``waves_by_size``
 ``wave_chunk_bytes`` (the documents' bytes and the padded bytes they
 were uploaded as: their ratio is how full the waves were),
 ``postings_rows`` and ``index_terms`` (the table the index is grouped
-from and the terms it holds), ``pack_docs`` (whether the walk packs
+from and the terms it holds), ``group_runs`` and ``group_rows_sorted``
+(the runs the group merged, and the rows of the buffers that did not
+arrive in runs and were sorted first: 0 where every wave's rows come as
+the device leaves them), ``pack_docs`` (whether the walk packs
 whole documents into its waves), ``wave_docs`` (documents dispatched in
 waves: ``docs`` unless a rung restarted the walk) and
 ``docs_per_wave_max`` (the most one wave held: the devices' count
@@ -304,8 +308,8 @@ PHASE_KEYS = (
     # the host merge and the serial tail, split where the work happens
     "compact_s", "finalize_decode_s", "write_format_s", "write_commit_s",
     # the postings table grouped into the index (``group`` span of
-    # ``merge.PostingsTable.finalize_packed``: the lexsort and the run
-    # detection), in the indexer's scope
+    # ``merge.PostingsTable.finalize_packed``: the runs found and
+    # merged), in the indexer's scope
     "group_s",
     # a packed index walk's packer, a wave at a time on the producer
     # thread, and planrun --chain indexer reading its documents: what
@@ -362,9 +366,10 @@ COUNTER_KEYS = (
     # the indexer's wave walk (parallel/grepstream.py): documents handed
     # over, waves dispatched by padded chunk size, the documents' bytes
     # and the padded bytes they were uploaded as, and the table it ends
-    # with: posting rows grouped, terms of the index
+    # with: posting rows grouped, terms of the index, the runs the group
+    # merged, the rows it had to sort first (their buffer was not in runs)
     "docs", "waves_by_size", "wave_doc_bytes", "wave_chunk_bytes",
-    "postings_rows", "index_terms",
+    "postings_rows", "index_terms", "group_runs", "group_rows_sorted",
     # whether it packs whole documents into its waves, the documents it
     # dispatched in waves, the most one wave held
     "pack_docs", "wave_docs", "docs_per_wave_max",

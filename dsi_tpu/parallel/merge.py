@@ -186,39 +186,47 @@ def _merge2_native(a: _Table, b: _Table) -> Optional[_Table]:
     return None if n is None else tuple(x[:n] for x in out)
 
 
-def _merge_runs_numpy(runs: List[_Table]) -> _Table:
-    """Runs of one lane width as one, in numpy: the rows one behind the
-    other, ordered by ONE stable sort of their first two lanes packed
-    into a ``uint64`` (numpy's stable sort of 64-bit integers is a merge
-    of the runs it finds, so rows that arrive as runs cost a merge and
-    not a sort), then the counts summed over each word's rows.  Words
-    that share their first eight bytes and differ later tie in that
-    column: the groups that hold such words, a few, are put in order by
-    their other lanes."""
-    table = tuple(np.concatenate([r[i] for r in runs]) for i in range(4))
-    keys = table[0]
-    k = keys.shape[1]
-    primary = keys[:, 0].astype(np.uint64)
+def _stable_key_order(table: np.ndarray,
+                      k: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The stable order of a ``[n, >= k]`` uint32 table's rows by their
+    first ``k`` columns (key lanes, lane 0 primary), and the table in
+    that order: ONE stable sort of the first two lanes packed into a
+    ``uint64`` (numpy's stable sort of 64-bit integers is a merge of the
+    runs it finds, so rows that arrive as runs cost a merge and not a
+    sort), then one gather of whole rows.  Words that share their first
+    eight bytes and differ later tie in that column: the groups that
+    hold such words, a few, are put in order by their other lanes, rows
+    of one word staying as they stood."""
+    primary = table[:, 0].astype(np.uint64)
     if k > 1:
         primary <<= np.uint64(32)
-        primary |= keys[:, 1]
+        primary |= table[:, 1]
     order = np.argsort(primary, kind="stable")
-    skeys = keys[order]
+    stable = table[order]
     if k > 2:
         primary = primary[order]
         tie = primary[1:] == primary[:-1]
-        mixed = tie & (skeys[1:, 2:] != skeys[:-1, 2:]).any(axis=1)
+        mixed = tie & (stable[1:, 2:k] != stable[:-1, 2:k]).any(axis=1)
         if mixed.any():
-            group = np.zeros(len(keys), np.int64)
+            group = np.zeros(len(table), np.int64)
             np.cumsum(~tie, out=group[1:])
             holds_words = np.zeros(int(group[-1]) + 1, bool)
             holds_words[group[1:][mixed]] = True
             pos = np.flatnonzero(holds_words[group])
-            rest = skeys[pos, 2:]
+            rest = stable[pos, 2:k]
             inner = np.lexsort(tuple(rest[:, j] for j in
                                      range(k - 3, -1, -1)) + (group[pos],))
             order[pos] = order[pos][inner]
-            skeys[pos] = keys[order[pos]]
+            stable[pos] = table[order[pos]]
+    return order, stable
+
+
+def _merge_runs_numpy(runs: List[_Table]) -> _Table:
+    """Runs of one lane width as one, in numpy: the rows one behind the
+    other, put in order by :func:`_stable_key_order` (a merge of the
+    runs, not a sort), then the counts summed over each word's rows."""
+    table = tuple(np.concatenate([r[i] for r in runs]) for i in range(4))
+    order, skeys = _stable_key_order(table[0], table[0].shape[1])
     return _reduce_ordered(table, order, skeys)
 
 
@@ -508,15 +516,73 @@ class PackedWordCounts(Mapping):
                                  self.cnts[rows], 0x0A).tobytes()
 
 
+#: A buffer of posting rows is merged as the runs it arrives in where
+#: they hold this many rows each on average, and is sorted into one run on
+#: entry to the group where they are shorter.  ``scripts/group_micro.py``
+#: on the chip's host (PERF.md section 6, PR 48): the two cost the same at
+#: about 4 rows a run; at 8 the merge wins by a sixth, and the
+#: tournament's seats stay under half the rows' own bytes.
+_RUN_ROWS_MIN = 8
+
+
+def _run_cuts(rows: np.ndarray, kk: int) -> Optional[np.ndarray]:
+    """The rows of a ``[n, kk + 4]`` buffer at which a new run starts
+    (the row sorts before the one above it in its ``kk`` key lanes; an
+    equal word is no descent), found in one pass over the buffer; None
+    where they are so many that the runs fall short of
+    :data:`_RUN_ROWS_MIN` rows each."""
+    cap = len(rows) // _RUN_ROWS_MIN
+    cuts = np.empty(cap, np.int64)
+    found = _native.run_cuts(rows, kk, cuts)
+    if found is None:
+        prev, nxt = rows[:-1, :kk], rows[1:, :kk]
+        first = (prev != nxt).argmax(axis=1)
+        at = np.arange(len(prev))
+        cuts = np.flatnonzero(nxt[at, first] < prev[at, first]) + 1
+        found = len(cuts)
+    return None if found > cap else cuts[:found]
+
+
+def _merge_posting_runs(bufs: List[np.ndarray], cuts: List[np.ndarray],
+                        kk: int) -> Tuple[np.ndarray, ...]:
+    """Buffers of posting rows, each the runs its ``cuts`` divide it
+    into, as the grouped index's columns ``(skeys, lens, parts, starts,
+    tfs, docs)``: the stable merge of the runs, the earlier run first
+    among equal words.  The tournament of ``native/mergeruns.cpp``,
+    which reads every row once and writes the columns; without the
+    library the rows one behind the other, :func:`_stable_key_order`
+    (a merge of the runs it finds and ONE gather of whole rows), the
+    columns cut from the rows in order."""
+    if _native.available():
+        n = sum(map(len, bufs))
+        out = (np.empty((n, kk), np.uint32), np.empty(n, np.uint32),
+               np.empty(n, np.uint32), np.empty(n, np.int64),
+               np.empty(n, np.uint32), np.empty(n, np.uint32))
+        words = _native.merge_posting_runs(bufs, cuts, kk, out)
+        # the per-word columns had room for a word a row: let that go
+        return tuple(x[:words].copy() for x in out[:4]) + out[4:]
+    rows = np.concatenate(bufs) if len(bufs) > 1 else bufs[0]
+    if len(bufs) + sum(map(len, cuts)) > 1:
+        rows = _stable_key_order(rows, kk)[1]
+    starts = _group_starts(rows[:, :kk])
+    return (np.ascontiguousarray(rows[starts, :kk]), rows[starts, kk],
+            rows[starts, kk + 3], starts,
+            np.ascontiguousarray(rows[:, kk + 1]),
+            np.ascontiguousarray(rows[:, kk + 2]))
+
+
 class PostingsTable:
     """TF-IDF accumulator over packed (word, tf, doc, part) row batches.
 
     Rows are retained raw (uint32, ~16+4K bytes each — several times
-    smaller than the Python tuple lists they replace) and grouped once at
-    ``finalize``: one lexsort over the key lanes, run-boundary detection,
-    one bulk spelling decode, and per-word postings sliced out with
-    C-speed ``tolist``/``zip``.  Output matches the dict-based walk:
-    ``{word: (reduce_partition, [(doc_index, tf), ...])}``.
+    smaller than the Python tuple lists they replace), in the order they
+    were handed over, and grouped once at ``finalize``: a wave's rows
+    leave the device in word order, so the buffers are runs and the
+    index is their stable merge, a word's postings in wave order, with
+    no row through a sort; then one bulk spelling decode, and per-word
+    postings sliced out with C-speed ``tolist``/``zip``.  Output matches
+    the dict-based walk: ``{word: (reduce_partition, [(doc_index, tf),
+    ...])}``.
     """
 
     def __init__(self):
@@ -539,8 +605,9 @@ class PostingsTable:
         """Checkpoint image: every buffered row, concatenated in
         insertion order — order is part of the postings contract
         (per-word doc order is an engine invariant), and the stable
-        finalize lexsort preserves it, so a restored table groups
-        bit-identically."""
+        merge of the runs at finalize preserves it (the image's one
+        buffer holds the same runs, found again from its rows), so a
+        restored table groups bit-identically."""
         if not self._bufs:
             return {}
         rows = (np.concatenate(self._bufs) if len(self._bufs) > 1
@@ -565,38 +632,47 @@ class PostingsTable:
         posting — at GB scale the dict materialization alone was ~2 GB of
         the soak's peak RSS.  Use ``to_dict()``
         (or ``lookup_many`` for a few words) only at scales that afford
-        it.  The lexsort and the run detection are the ``group`` span
+        it.  Finding the runs and merging them is the ``group`` span
         (``group_s`` of ``stats``, the engine's scope), which also takes
-        the table's ``postings_rows`` and ``index_terms``."""
+        the table's ``postings_rows`` and ``index_terms``, the runs that
+        were merged (``group_runs``) and the rows of the buffers that did
+        not arrive in runs and went through a sort first
+        (``group_rows_sorted``: 0 where every wave's rows came as the
+        device leaves them)."""
         n_rows = sum(len(b) for b in self._bufs)
         with _span("group", lane="merge", stats=stats, key="group_s",
                    rows=n_rows) as sp:
-            out = self._group()
-            sp.set(terms=len(out))
+            out, runs, rows_sorted = self._group()
+            sp.set(terms=len(out), runs=runs, rows_sorted=rows_sorted)
         if stats is not None:
             stats["postings_rows"] = n_rows
             stats["index_terms"] = len(out)
+            stats["group_runs"] = runs
+            stats["group_rows_sorted"] = rows_sorted
         return out
 
-    def _group(self) -> "PackedPostings":
+    def _group(self) -> Tuple["PackedPostings", int, int]:
+        """The index, the runs it was merged from, and the rows that were
+        sorted first: a buffer is the runs its rows show, or, where they
+        show too many, one run by a stable sort of the buffer alone."""
         if not self._bufs:
-            return PackedPostings(0)
+            return PackedPostings(0), 0, 0
         kk = self._kk
-        rows = np.concatenate(self._bufs) if len(self._bufs) > 1 \
-            else self._bufs[0]
-        keys = rows[:, :kk]
-        order = _lexsort_rows(keys)
-        skeys = keys[order]
-        starts = _group_starts(skeys)
+        bufs, cuts, rows_sorted = [], [], 0
+        for rows in self._bufs:
+            rows = np.ascontiguousarray(rows)
+            cut = _run_cuts(rows, kk)
+            if cut is None:
+                rows = _stable_key_order(rows, kk)[1]
+                rows_sorted += len(rows)
+                cut = np.zeros(0, np.int64)
+            bufs.append(rows)
+            cuts.append(cut)
         out = PackedPostings(kk)
-        out.skeys = np.ascontiguousarray(skeys[starts])
-        out.starts = starts
-        out.ends = np.append(starts[1:], len(rows))
-        out.lens = rows[order[starts], kk]
-        out.parts = rows[order[starts], kk + 3]
-        out.tfs = np.ascontiguousarray(rows[order, kk + 1])
-        out.docs = np.ascontiguousarray(rows[order, kk + 2])
-        return out
+        (out.skeys, out.lens, out.parts, out.starts, out.tfs,
+         out.docs) = _merge_posting_runs(bufs, cuts, kk)
+        out.ends = np.append(out.starts[1:], len(out.tfs))
+        return out, len(bufs) + sum(map(len, cuts)), rows_sorted
 
 
 class PackedPostings:

@@ -12,7 +12,8 @@ are processed in waves of ``n_dev`` (one document per device per wave):
   ``mr-X-Y`` intermediate files exactly as in ``parallel/shuffle.py``,
 * reduce = per-device sort of received rows by word; the host buffers each
   wave's rows as raw uint32 tables (``parallel/merge.py`` PostingsTable),
-  groups them once at the end with one lexsort + run detection + one bulk
+  groups them once at the end (a stable merge of the runs the waves' rows
+  arrive in, each source device's block in word order) + one bulk
   spelling decode, and computes ``df``/``tf·ln(N/df)`` at output time via
   the SAME ``apps.tfidf.format_value`` the host Reduce uses — so the SPMD
   job's ``mr-out-*`` files are byte-identical to the sequential oracle's.
@@ -110,15 +111,16 @@ def _tfidf_device_step(chunk: jax.Array, doc_id: jax.Array, *, n_dev: int,
 
     # Partition received rows valid-first so the host's occupied-prefix
     # D2H slice works; the host accumulator (parallel/merge.py
-    # PostingsTable) groups with its own lexsort at finalize, so the
-    # former full by-word device sort bought nothing but the pad
-    # partition.  One boolean key with ALL columns packed pairwise into
-    # u64 operands (operand count, not comparator width, dominates
-    # XLA's CPU sort) measured +20% whole-soak throughput at 256 MB
-    # (round 5).  Pad detection on the first PACKED column: a pad row
-    # is all-ones in every lane, i.e. uint64-max after packing (a real
-    # first lane can be 0xFFFFFFFF only for non-ASCII bytes, which
-    # has_high rejects).
+    # PostingsTable) groups at finalize by merging the runs it finds
+    # (each source device's block arrives in word order and this stable
+    # sort keeps it), so the former full by-word device sort bought
+    # nothing but the pad partition.  One boolean key with ALL columns
+    # packed pairwise into u64 operands (operand count, not comparator
+    # width, dominates XLA's CPU sort) measured +20% whole-soak
+    # throughput at 256 MB (round 5).  Pad detection on the first
+    # PACKED column: a pad row is all-ones in every lane, i.e.
+    # uint64-max after packing (a real first lane can be 0xFFFFFFFF
+    # only for non-ASCII bytes, which has_high rejects).
     with enable_x64(True):  # every op touching u64 operands needs it
         keys64 = pack_key_lanes(tuple(recv[:, j] for j in range(k)))
         pay64 = pack_key_lanes(tuple(recv[:, k + j] for j in range(4)))

@@ -617,3 +617,208 @@ def test_native_merge_refuses_a_table_it_cannot_read():
         native.rows_increase(a[0].astype(np.int64))
     assert native.rows_increase(a[0]) is True
     assert native.rows_increase(np.ascontiguousarray(a[0][::-1])) is False
+
+
+# ── the postings table merges the runs its rows arrive in ──
+
+
+_FIELDS = ("skeys", "lens", "parts", "starts", "ends", "tfs", "docs")
+
+
+def _lexsort_group(bufs, kk):
+    """What ``PostingsTable._group`` did until it merged runs, kept as the
+    oracle: every row one behind the other, one stable ``np.lexsort``
+    over the key lanes, the table read through the permutation."""
+    rows = np.concatenate(bufs)
+    keys = rows[:, :kk]
+    order = np.lexsort(tuple(keys[:, j] for j in range(kk - 1, -1, -1)))
+    skeys = keys[order]
+    starts = np.flatnonzero(np.r_[True, (skeys[1:] != skeys[:-1]).any(1)])
+    return {"skeys": np.ascontiguousarray(skeys[starts]), "starts": starts,
+            "ends": np.append(starts[1:], len(rows)),
+            "lens": rows[order[starts], kk],
+            "parts": rows[order[starts], kk + 3],
+            "tfs": np.ascontiguousarray(rows[order, kk + 1]),
+            "docs": np.ascontiguousarray(rows[order, kk + 2])}
+
+
+def _wave(rng, vocab, kk, docs, most):
+    """A wave's posting rows as the device leaves them: a row a distinct
+    word a document, in (word, document) order."""
+    rows = []
+    for doc in docs:
+        for w in rng.sample(vocab, rng.randint(1, min(most, len(vocab)))):
+            rows.append((w, doc))
+    rows.sort()
+    return np.stack([np.concatenate([
+        _pack_word(w, kk),
+        np.array([len(w), rng.randint(1, 99), doc, sum(map(ord, w)) % 10],
+                 np.uint32)]) for w, doc in rows])
+
+
+def _vocab(rng, n, longest, alphabet="abcdefghijklmnop"):
+    return sorted({"".join(rng.choices(alphabet, k=rng.randint(1, longest)))
+                   for _ in range(n)})
+
+
+def _group_case(name):
+    """``(buffers handed to add, kk, runs merged or None, rows sorted)``."""
+    rng = random.Random(name)
+    if name.startswith("runs-"):  # a document a wave: a word once a run
+        n = int(name[5:])
+        vocab = _vocab(rng, 200, 12)
+        return [_wave(rng, vocab, 4, [d], 40) for d in range(n)], 4, n, 0
+    if name == "shared-words-a-page-wave":
+        # ties across runs and within one: a word once a document
+        vocab = _vocab(rng, 30, 6)
+        return [_wave(rng, vocab, 4, range(9 * i, 9 * i + 9), 25)
+                for i in range(12)], 4, 12, 0
+    if name == "a-drain-of-five-waves":  # one buffer, several runs
+        vocab = _vocab(rng, 400, 10)
+        waves = [_wave(rng, vocab, 4, [2 * i, 2 * i + 1], 150)
+                 for i in range(5)]
+        return [np.concatenate(waves), _wave(rng, vocab, 4, [10], 50)], \
+            4, None, 0
+    if name == "shuffled":  # no runs to speak of: sorted on entry
+        vocab = _vocab(rng, 300, 10)
+        rows = _wave(rng, vocab, 4, range(4), 200)
+        rng.shuffle(order := list(range(len(rows))))
+        return [_wave(rng, vocab, 4, [7], 90), rows[order],
+                _wave(rng, vocab, 4, [8], 90)], 4, 3, len(rows)
+    if name.startswith("kk-"):
+        kk = int(name[3:])
+        vocab = _vocab(rng, 150, 4 * kk)
+        return [_wave(rng, vocab, kk, [2 * i, 2 * i + 1], 60)
+                for i in range(9)], kk, 9, 0
+    if name == "shared-first-eight-bytes":
+        stems = ["internat", "abcdefgh", "Abcdefgh"]
+        tails = ["", "a", "b", "ional", "ionally", "ionale", "zzzzzzzz",
+                 "ab", "ba", "ionalism"]
+        vocab = sorted({s + t for s in stems for t in tails}
+                       | {"intern", "abc", "zebra"})
+        return [_wave(rng, vocab, 4, [3 * i, 3 * i + 1, 3 * i + 2], 33)
+                for i in range(20)], 4, 20, 0
+    raise AssertionError(name)
+
+
+_GROUP_CASES = ["runs-1", "runs-2", "runs-7", "runs-300",
+                "shared-words-a-page-wave", "a-drain-of-five-waves",
+                "shuffled", "kk-1", "kk-2", "kk-4", "kk-8",
+                "shared-first-eight-bytes"]
+
+
+def _grouped(bufs, kk):
+    table = PostingsTable()
+    for b in bufs:
+        table.add(b, kk)
+    stats: dict = {}
+    return table, table.finalize_packed(stats), stats
+
+
+def _assert_fields(got, want):
+    for f in _FIELDS:
+        a, b = getattr(got, f), want[f]
+        assert a.dtype == b.dtype and a.shape == b.shape, f
+        assert a.flags.c_contiguous and np.array_equal(a, b), f
+
+
+@pytest.mark.parametrize("name", _GROUP_CASES)
+def test_group_equals_the_stable_lexsort_field_for_field(merge_route, name):
+    bufs, kk, runs, rows_sorted = _group_case(name)
+    _, got, stats = _grouped(bufs, kk)
+    _assert_fields(got, _lexsort_group(bufs, kk))
+    assert stats["postings_rows"] == sum(map(len, bufs))
+    assert stats["index_terms"] == len(got)
+    assert stats["group_rows_sorted"] == rows_sorted
+    if runs is not None:
+        assert stats["group_runs"] == runs
+    else:  # the drain's five waves are found from its rows
+        assert 6 <= stats["group_runs"] <= len(bufs[0]) // 8 + 2
+
+
+@pytest.mark.parametrize("name", _GROUP_CASES)
+def test_group_snapshot_restore_groups_bit_identically(merge_route, name):
+    """The image is one buffer of every row in insertion order: the runs
+    are found again from its rows, and the group is the same."""
+    bufs, kk, _, _ = _group_case(name)
+    table, whole, _ = _grouped(bufs, kk)
+    image = {k: v.copy() for k, v in table.snapshot().items()}
+    assert np.array_equal(image["rows"], np.concatenate(bufs))
+    back = PostingsTable()
+    back.restore(image)
+    stats: dict = {}
+    _assert_fields(back.finalize_packed(stats), {
+        f: getattr(whole, f) for f in _FIELDS})
+    assert 1 <= stats["group_runs"]
+
+
+@pytest.mark.parametrize("name", _GROUP_CASES)
+def test_group_routes_agree_byte_for_byte_and_count_alike(monkeypatch,
+                                                          name):
+    from dsi_tpu import native
+
+    if not native.available():
+        pytest.skip("no native library on this host")
+    bufs, kk, _, _ = _group_case(name)
+    _, with_lib, counts = _grouped(bufs, kk)
+    monkeypatch.setattr(native, "_lib", False)
+    _, without, counts_numpy = _grouped(bufs, kk)
+    for f in _FIELDS:
+        assert getattr(with_lib, f).tobytes() == getattr(without,
+                                                         f).tobytes(), f
+    for key in ("postings_rows", "index_terms", "group_runs",
+                "group_rows_sorted"):
+        assert counts[key] == counts_numpy[key], key
+
+
+def test_group_of_an_empty_table(merge_route):
+    from dsi_tpu.parallel.merge import PackedPostings
+
+    stats: dict = {}
+    got = PostingsTable().finalize_packed(stats)
+    empty = PackedPostings(0)
+    _assert_fields(got, {f: getattr(empty, f) for f in _FIELDS})
+    assert stats["postings_rows"] == stats["index_terms"] == 0
+    assert stats["group_runs"] == stats["group_rows_sorted"] == 0
+    table = PostingsTable()
+    table.add(np.zeros((0, 8), np.uint32), 4)  # an empty wave is no run
+    table.restore({})
+    assert len(table.finalize_packed()) == 0
+
+
+def test_native_group_refuses_rows_it_cannot_read():
+    """``mergeruns.cpp`` reads raw pointers: rows of another dtype, width
+    or stride, cuts outside their buffer and columns without room are
+    refused before the call."""
+    from dsi_tpu import native
+
+    if not native.available():
+        pytest.skip("no native library on this host")
+    bufs, kk, _, _ = _group_case("runs-2")
+    n = sum(map(len, bufs))
+
+    def room(rows):
+        return (np.empty((rows, kk), np.uint32), np.empty(rows, np.uint32),
+                np.empty(rows, np.uint32), np.empty(rows, np.int64),
+                np.empty(rows, np.uint32), np.empty(rows, np.uint32))
+
+    none = [np.zeros(0, np.int64)] * 2
+    assert native.merge_posting_runs(bufs, none, kk, room(n)) > 0
+    with pytest.raises(ValueError, match="room"):
+        native.merge_posting_runs(bufs, none, kk, room(n - 1))
+    with pytest.raises(ValueError, match="cuts"):
+        native.merge_posting_runs(bufs, [np.array([len(bufs[0])]), none[0]],
+                                  kk, room(n))
+    for bad in (bufs[0].astype(np.int64), bufs[0][:, :-1], bufs[0][::2],
+                np.asfortranarray(bufs[0])):
+        with pytest.raises(ValueError, match="posting rows"):
+            native.merge_posting_runs([bad, bufs[1]], none, kk, room(n))
+        with pytest.raises(ValueError, match="posting rows"):
+            native.run_cuts(bad, kk, np.empty(4, np.int64))
+    with pytest.raises(ValueError, match="cuts"):
+        native.run_cuts(bufs[0], kk, np.empty(4, np.int32))
+    cuts = np.empty(4, np.int64)
+    assert native.run_cuts(bufs[0], kk, cuts) == 0
+    assert native.run_cuts(np.ascontiguousarray(bufs[0][::-1]), kk,
+                           cuts) >= len(bufs[0]) // 2
+    assert cuts[0] >= 1

@@ -652,7 +652,8 @@ def test_indexer_chain_records_its_new_spans_and_counters(fresh_tracer,
     ps = ast.literal_eval(m.group(1))
     walk = ps["stages"]["indexer"]
     new_counters = {"docs", "waves_by_size", "wave_doc_bytes",
-                    "wave_chunk_bytes", "postings_rows", "index_terms"}
+                    "wave_chunk_bytes", "postings_rows", "index_terms",
+                    "group_runs", "group_rows_sorted"}
     assert new_counters <= set(walk) and new_counters <= set(COUNTER_KEYS)
     assert {"group_s", "enqueue_s", "dispatch_s", "retire_s"} <= set(walk)
     assert "group_s" in PHASE_KEYS
@@ -681,6 +682,10 @@ def test_indexer_chain_records_its_new_spans_and_counters(fresh_tracer,
     (group,) = [e for e in spans.values() if e["name"] == "group"]
     assert group["rows"] == walk["postings_rows"]
     assert group["terms"] == walk["index_terms"]
+    # a wave's rows leave the device in word order: a run a wave, merged,
+    # and no row through a sort
+    assert group["runs"] == walk["group_runs"] == walk["waves"]
+    assert group["rows_sorted"] == walk["group_rows_sorted"] == 0
     assert walk["group_s"] == pytest.approx(group["dur"], abs=5e-4)
     for name, key in (("enqueue", "enqueue_s"), ("dispatch", "dispatch_s"),
                       ("finish", "retire_s")):
