@@ -65,6 +65,27 @@ Chains:
               committed); --check, --hosts, --checkpoint-dir,
               --pipeline and --stage-shards are not this chain's.
 
+  agg       — one stage: SELECT key, SUM(value) ... GROUP BY key over
+              files of rows f0|f1|... ended by a newline (Pavlo et al.,
+              SIGMOD'09, the Aggregation Task over UserVisits).  The key
+              is field 0, 1-16 bytes of printable ASCII other than '|'; the value
+              is field 3, [0-9]{1,3}(.[0-9]{1,6})?, summed exactly as a
+              count of 10^-6 units; the other fields are not read (a
+              file's last row may lack its newline).  --agg-prefix N
+              groups by the first N bytes of the key
+              (SUBSTR(sourceIP, 1, N)).  The rows are read and grouped
+              on the device, by the word count's engine with this map
+              in the tokenizer's place; commits mr-out-<r>, one line a
+              key, "<key> <integer part>.<six digits>", the key in
+              partition ihash(key) % nreduce, each file in key order.
+              A row with fewer than four fields, a key of 0 or over 16
+              bytes, or a value outside the grammar fails the job: exit
+              1, nothing committed, the first such row's file and line
+              in the message; there is no host path.  --staged,
+              --check, --hosts, --checkpoint-dir, --pipeline,
+              --stage-shards, --device-accumulate, --mesh-shards and
+              --aot are not this chain's.
+
 Elastic execution (ISSUE 16): ``--pipeline`` overlaps a grep→wordcount
 pair (the wordcount consumes relay buffers as they SEAL while the grep
 is still producing; strict/staged stays the bit-parity oracle);
@@ -77,7 +98,8 @@ Usage:
         [--pattern2 PAT] [--pipeline] [--stage-shards K] [--pack-docs]
         [--staged] [--chunk-bytes B] [--devices D] [--pipeline-depth K]
         [--device-accumulate] [--sync-every K] [--mesh-shards N]
-        [--nreduce N] [--u-cap U] [--topk K] [--sort-sample N] [--aot]
+        [--nreduce N] [--u-cap U] [--topk K] [--sort-sample N]
+        [--agg-prefix N] [--aot]
         [--checkpoint-dir DIR] [--resume] [--workdir DIR] [--check]
         [--stats] [--stats-json FILE] [--trace-dir DIR] inputfiles...
 """
@@ -110,7 +132,8 @@ def _plan_spec(args) -> dict:
             "mesh_shards": args.mesh_shards, "aot": args.aot,
             "n_reduce": args.nreduce, "u_cap": args.u_cap,
             "topk": args.topk, "devices": args.devices,
-            "pack_docs": args.pack_docs, "sample": args.sort_sample}
+            "pack_docs": args.pack_docs, "sample": args.sort_sample,
+            "agg_prefix": args.agg_prefix}
 
 
 def _run_hosts(args, spec: dict, plan, mesh):
@@ -263,7 +286,7 @@ def _main(argv, opened: list) -> int:
     p.add_argument("files", nargs="+")
     p.add_argument("--chain",
                    choices=("grep-wc", "grep-grep", "wc-topk",
-                            "indexer", "sort"),
+                            "indexer", "sort", "agg"),
                    default="grep-wc",
                    help="grep-wc commits the word counts of the matching "
                         "lines as mr-out-<r>; indexer commits the whole "
@@ -274,7 +297,9 @@ def _main(argv, opened: list) -> int:
                         "plan-grep.json / plan-topk.json; sort commits "
                         "the input's 100-byte records ordered by their "
                         "10-byte key as mr-out-<r>, range-partitioned "
-                        "from a sample of the keys")
+                        "from a sample of the keys; agg commits field "
+                        "3's decimal sum by field 0 of |-delimited rows "
+                        "as mr-out-<r>")
     p.add_argument("--pattern", default=None,
                    help="literal grep pattern (required for grep-wc "
                         "and grep-grep)")
@@ -314,6 +339,9 @@ def _main(argv, opened: list) -> int:
                    help="--chain sort: keys the sampling pre-pass reads "
                         "for the split points (TeraSort's "
                         "mapreduce.terasort.partitions.sample)")
+    p.add_argument("--agg-prefix", type=int, default=0,
+                   help="--chain agg: group by the first N bytes of the "
+                        "key field (0, the default: the whole field)")
     p.add_argument("--aot", action="store_true")
     p.add_argument("--checkpoint-dir", default=None,
                    help="stage-manifest commits land here: each "
@@ -362,6 +390,18 @@ def _main(argv, opened: list) -> int:
             p.error("--chain sort orders one worker's share on one "
                     "device: --devices 1")
         args.devices = 1
+    if args.agg_prefix and args.chain != "agg":
+        p.error("--agg-prefix cuts the key of --chain agg")
+    if args.chain == "agg":
+        if args.agg_prefix < 0:
+            p.error("--agg-prefix is a number of bytes: 0 or more")
+        for flag in ("staged", "check", "hosts", "checkpoint_dir",
+                     "pipeline", "stage_shards", "device_accumulate",
+                     "mesh_shards", "aot"):
+            if getattr(args, flag):
+                p.error(f"--{flag.replace('_', '-')} is not --chain "
+                        "agg's: one stage, summed through the host "
+                        "merge and committed once")
     if args.pipeline and args.staged:
         p.error("--pipeline is chained-mode only (staged execution "
                 "stays strictly sequential: it is the parity oracle)")
@@ -397,6 +437,7 @@ def _main(argv, opened: list) -> int:
     require_device("planrun")
 
     from dsi_tpu.ckpt import CheckpointMismatch
+    from dsi_tpu.ops.fieldsum import BadRow
     from dsi_tpu.parallel.shuffle import default_mesh
     from dsi_tpu.plan import PlanHostPath, run_plan
     from dsi_tpu.plan.stagehost import build_plan
@@ -442,6 +483,10 @@ def _main(argv, opened: list) -> int:
         # length was taken from: nothing is committed.
         print(f"planrun: {e}", file=sys.stderr)
         return 1
+    except BadRow as e:
+        # --chain agg: a row that cannot be read fails the job.
+        print(f"planrun: {e}", file=sys.stderr)
+        return 1
     except PlanHostPath as e:
         # The chain contract is device-resident intermediates; a
         # host-path input breaks it loudly — run the standalone engines
@@ -483,6 +528,11 @@ def _main(argv, opened: list) -> int:
         print(f"planrun: grep lines={g.lines} matched={g.matched} "
               f"occurrences={g.occurrences}", file=sys.stderr)
         committed = res.final
+    elif args.chain == "agg":
+        committed = res.final
+        print(f"planrun: {stats['stage_stats']['agg']['agg_rows']} rows in "
+              f"{len(committed)} groups -> {args.workdir}/mr-out-0.."
+              f"{args.nreduce - 1}", file=sys.stderr)
     elif args.chain == "indexer":
         # The table the join stage grouped, its documents named as the
         # host app names them (mrsequential in the files' directory).

@@ -142,6 +142,14 @@ single-key sort passes).  The commit's ``pull_s`` / ``d2h_s`` /
 ``pull_bytes`` / ``write_commit_s`` stand at the top of ``planrun``'s
 ``pipeline_stats`` beside ``write_s``.
 
+The aggregation chain (``planrun --chain agg``: the stream engine with
+``ops/fieldsum.FieldSum`` as its map; the scope under ``stage_stats`` is
+the word count's own, ``steps``, ``pull_s``, ``merge_s``, ... and all):
+``agg_rows`` (rows the confirmed steps read: the job's newlines),
+``agg_groups`` (keys of the merged table: the committed lines) and
+``agg_value_lanes`` (``uint32`` lanes a step's sum rides through the
+shuffle, the pull and the merge: 2).
+
 Async/incremental checkpoint keys (``dsi_tpu/ckpt`` writer/delta —
 present when checkpointing is on): ``ckpt_async``/``ckpt_delta`` (the
 mode flags), ``ckpt_deltas`` (incremental saves among ``ckpt_saves``),
@@ -383,6 +391,9 @@ COUNTER_KEYS = (
     # of the ordering
     "sort_records", "sort_sample_keys", "sort_resident_bytes",
     "sort_partition_rows", "sort_order_passes",
+    # the aggregation chain (the stream engine with a map,
+    # ops/fieldsum.py): rows read, keys of the merged table, lanes a sum
+    "agg_rows", "agg_groups", "agg_value_lanes",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

@@ -338,6 +338,20 @@ def running_sum(x: jax.Array) -> jax.Array:
         x.dtype)
 
 
+def running_sum_pair(lo: jax.Array, hi: Optional[jax.Array]) -> tuple:
+    """:func:`running_sum`'s 64-bit form over numbers that come as their
+    two ``uint32`` halves (``hi`` None: all zero) and leave as them: the
+    same three 32-bit scans, with no 64-bit operation at all.  (Kept
+    beside it and not under it: the programs that sum ``uint64`` counts
+    lower to the text they had.)"""
+    lo = jnp.cumsum(lo, dtype=jnp.uint32)
+    if hi is not None:
+        hi = jnp.cumsum(hi, dtype=jnp.uint32)
+    fell = lo < jnp.concatenate([jnp.zeros((1,), jnp.uint32), lo[:-1]])
+    carries = jnp.cumsum(fell, dtype=jnp.uint32)
+    return lo, carries if hi is None else hi + carries
+
+
 @jax.named_scope("group")
 def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     """Group adjacent equal rows of lexicographically sorted key columns.
@@ -354,7 +368,10 @@ def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     ``skeys_cols``: k sorted unsigned key columns (uint32 lanes or uint64
     packed lane pairs), PAD rows last — a pad row is all-ones in every
     lane, i.e. the dtype's max in every column; ``counts``: per-row
-    counts to sum within each group.
+    counts to sum within each group, or a pair ``(low, high)`` of
+    ``uint32`` halves (``high`` None: all zero) of values whose totals
+    pass 32 bits: the totals are then ``uint32[out_cap, 2]``, (low, high),
+    summed by :func:`running_sum_pair`.
 
     Returns (keys2d [t,k], totals [out_cap], upos [out_cap] int32, ovalid
     [out_cap], n_unique) — callers gather their payloads at ``upos`` and
@@ -379,6 +396,18 @@ def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     live = jnp.arange(out_cap + 1, dtype=jnp.int32) < n_unique
     ovalid = live[:out_cap]
     bounds = jnp.where(live, starts, jnp.int32(t))
+    if isinstance(counts, tuple):
+        lo, hi = (None if c is None else jnp.where(valid, c, jnp.uint32(0))
+                  for c in counts)
+        at = jnp.maximum(bounds - 1, 0)
+        lo, hi = (jnp.where(bounds > 0, c[at], jnp.uint32(0))
+                  for c in running_sum_pair(lo, hi))
+        borrow = (lo[1:] < lo[:-1]).astype(jnp.uint32)
+        totals = jnp.where(
+            ovalid[:, None],
+            jnp.stack([lo[1:] - lo[:-1], hi[1:] - hi[:-1] - borrow], axis=1),
+            jnp.uint32(0))
+        return keys, totals, upos, ovalid, n_unique
     with enable_x64(True):  # the counts may be 64-bit
         zero = jnp.zeros((), counts.dtype)
         csum = running_sum(jnp.where(valid, counts, zero))

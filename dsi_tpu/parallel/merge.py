@@ -60,7 +60,7 @@ def _index_dtype(limit: int):
 
 def _word_number_rows(key_bytes: np.ndarray, rows: np.ndarray,
                       lens: np.ndarray, numbers: np.ndarray,
-                      last: int) -> np.ndarray:
+                      last: int, decimals: int = 0) -> np.ndarray:
     """``"word number"`` and the byte ``last`` for each of ``rows``, flat,
     from the arrays: the words' bytes out of ``key_bytes`` ([n, 4K]
     uint8, the big-endian view of the key lanes) cut at the longest
@@ -68,19 +68,28 @@ def _word_number_rows(key_bytes: np.ndarray, rows: np.ndarray,
     (an int64 past 2^53 must print exactly, so no float logarithm);
     ``last``.  One [rows, longest word + 1 + most digits + 1] byte matrix
     and a keep-mask: what a row keeps follows its (length, digits)
-    alone, so one mask a class, gathered a row."""
+    alone, so one mask a class, gathered a row.  With ``decimals`` the
+    numbers count 10^-decimals units and print as ``<integer
+    part>.<decimals digits>``: that many columns and the point's more, at
+    the right, which every row keeps."""
     lens = lens.astype(np.int64)
     w = int(lens.max())
-    digits = np.searchsorted(_POW10, numbers, side="right") + 1
+    tail = decimals + 1 if decimals else 0
+    digits = np.searchsorted(
+        _POW10, numbers // 10 ** decimals if decimals else numbers,
+        side="right") + 1
     d = int(digits.max())
-    width = w + d + 2
+    width = w + d + tail + 2
     mat = np.empty((len(rows), width), np.uint8)
     mat[:, :w] = np.take(key_bytes, rows, axis=0)[:, :w]
     mat[:, w] = 0x20
-    for col in range(w + d, w, -1):  # right-aligned, least first
+    for col in range(w + d + tail, w, -1):  # right-aligned, least first
+        if tail and col == w + d + 1:
+            mat[:, col] = 0x2E
+            continue
         numbers, digit = np.divmod(numbers, 10)
         mat[:, col] = digit + 0x30
-    mat[:, w + d + 1] = last
+    mat[:, width - 1] = last
     col = np.arange(width)
     masks = ((col < np.arange(w + 1)[:, None, None])
              | (col >= w + 1 + d - np.arange(d + 1)[None, :, None])
@@ -323,7 +332,8 @@ class PackedCounts:
     """
 
     def __init__(self, compact_rows: int = 1 << 21,
-                 stats: Optional[dict] = None):
+                 stats: Optional[dict] = None, *, decimals: int = 0):
+        self._decimals = decimals  # of the result's numbers as printed
         self._table: Optional[_Table] = None
         self._window: List[_Table] = []
         self._pending = 0  # rows of the window
@@ -358,11 +368,17 @@ class PackedCounts:
         table: a run each, the step program having sorted and grouped
         them.  One call per stream step — the merge phase the pipelined
         engine (parallel/streaming.py) runs on the host while later
-        steps' kernels are still in flight on device."""
+        steps' kernels are still in flight on device.  A tensor of
+        ``kk+4`` lanes carries sums of two, low then high, where a count
+        has one."""
         for d in range(packed.shape[0]):
             nu = int(n_uniques[d])
             r = packed[d, :nu]
-            self.add(r[:, :kk], r[:, kk], r[:, kk + 1], r[:, kk + 2])
+            cnts = r[:, kk + 1]
+            if packed.shape[2] == kk + 4:
+                cnts = cnts.astype(np.int64) | (
+                    r[:, kk + 2].astype(np.int64) << 32)
+            self.add(r[:, :kk], r[:, kk], cnts, r[:, -1])
 
     def _compact(self) -> None:
         """The window's runs into one run, that run into the table."""
@@ -387,8 +403,10 @@ class PackedCounts:
         is built here."""
         self._compact()
         if self._table is None:
-            return PackedWordCounts(stats=self.stats)
-        return PackedWordCounts(*self._table, stats=self.stats)
+            return PackedWordCounts(stats=self.stats,
+                                    decimals=self._decimals)
+        return PackedWordCounts(*self._table, stats=self.stats,
+                                decimals=self._decimals)
 
     # ── checkpoint image (dsi_tpu/ckpt) ──
 
@@ -426,8 +444,10 @@ class PackedWordCounts(Mapping):
 
     ``skeys`` ([n, K] uint32, rows strictly increasing: big-endian
     zero-padded lanes, so lane order is byte order is ``str`` order for
-    the ASCII letters a word holds), ``lens`` (bytes a word), ``cnts``
-    (int64) and ``parts`` are per word.  ``len()`` and
+    the ASCII a key holds: a word's letters, an aggregation key's
+    printable bytes), ``lens`` (bytes a word), ``cnts`` (int64) and
+    ``parts`` are per word.  ``decimals`` makes a count a sum of
+    10^-decimals units, printed ``<integer part>.<decimals digits>``.  ``len()`` and
     :meth:`render_partition` (what ``shuffle.write_partitioned_output``
     commits) read the arrays alone.  The first keyed access, iteration
     or comparison decodes every spelling once (``decode_packed``) into
@@ -441,7 +461,8 @@ class PackedWordCounts(Mapping):
                  lens: Optional[np.ndarray] = None,
                  cnts: Optional[np.ndarray] = None,
                  parts: Optional[np.ndarray] = None,
-                 stats: Optional[dict] = None):
+                 stats: Optional[dict] = None, decimals: int = 0):
+        self.decimals = decimals
         if skeys is None:
             skeys = np.zeros((0, 1), np.uint32)
             lens = parts = np.zeros(0, np.int32)
@@ -513,7 +534,8 @@ class PackedWordCounts(Mapping):
             self._bytes = np.ascontiguousarray(
                 self.skeys.astype(">u4")).view(np.uint8)
         return _word_number_rows(self._bytes, rows, self.lens[rows],
-                                 self.cnts[rows], 0x0A).tobytes()
+                                 self.cnts[rows], 0x0A,
+                                 self.decimals).tobytes()
 
 
 #: A buffer of posting rows is merged as the runs it arrives in where

@@ -101,7 +101,7 @@ def shuffle_rows(rows: jax.Array, dest: jax.Array, *, n_dev: int,
 @jax.named_scope("map")
 def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
                  max_word_len: int, u_cap: int, t_cap_frac: int,
-                 doc_sep: Optional[int] = None):
+                 doc_sep: Optional[int] = None, map=None):
     """Shared per-device map phase: tokenize + combine + partition.
 
     The one place the reference-parity partition rule lives on device:
@@ -116,35 +116,57 @@ def map_prologue(chunk: jax.Array, *, n_dev: int, n_reduce: int,
     (``doc_sep``: ``tokenize_group_core``) a row is a (word, document)
     pair and a seventh result, ``doc_u``, is its document's place in the
     chunk.
+
+    ``map`` (``ops/fieldsum.FieldSum``) puts another map in the
+    tokenizer's place: rows of delimited fields, a row's key field the
+    word and its value what is summed.  ``cnt_u`` is then
+    ``uint32[u_cap, 2]``, a sum's low and high halves, ``has_high`` says a
+    row could not be read, ``token_overflow`` that the chunk holds more
+    rows than the buffer, and two more results follow: the chunk's rows
+    and the first unreadable one's place.
     """
+    core = map.group_core if map is not None else functools.partial(
+        tokenize_group_core, doc_sep=doc_sep)
     (packed_u, len_u, cnt_u, fnv_u, n_unique, max_len, has_high,
-     token_overflow, *docs) = tokenize_group_core(
-        chunk, max_word_len=max_word_len, u_cap=u_cap, t_cap_frac=t_cap_frac,
-        doc_sep=doc_sep)
+     token_overflow, *more) = core(
+        chunk, max_word_len=max_word_len, u_cap=u_cap, t_cap_frac=t_cap_frac)
     uvalid = jnp.arange(u_cap, dtype=jnp.int32) < n_unique
     part = (fnv_u & jnp.uint32(0x7FFFFFFF)) % jnp.uint32(n_reduce)
     dest = jnp.where(uvalid, (part % n_dev).astype(jnp.int32), n_dev)
     return (packed_u, len_u, cnt_u, part, dest,
-            (n_unique, max_len, has_high, token_overflow), *docs)
+            (n_unique, max_len, has_high, token_overflow), *more)
+
+
+def _sort_wide(recv: jax.Array, k: int) -> tuple:
+    """The reduce's sort over rows whose sums are two lanes: the sorted
+    key columns, the lengths, the sums as a (low, high) pair, the
+    partitions."""
+    *scols, mlen, lo, hi, mpart = lex_sort(
+        tuple(recv[:, j] for j in range(k)),
+        tuple(recv[:, j] for j in range(k, k + 4)))
+    return (*scols, mlen, (lo, hi), mpart)
 
 
 def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,
-                 max_word_len: int, u_cap: int, t_cap_frac: int):
-    """Per-device body (runs under shard_map): map, all_to_all, reduce."""
+                 max_word_len: int, u_cap: int, t_cap_frac: int, map=None):
+    """Per-device body (runs under shard_map): map, all_to_all, reduce.
+    A value that is not a count (``map``: :func:`map_prologue`) rides the
+    shuffle as two lanes where a count is one, and is summed as them."""
     k = max_word_len // 4
     chunk = chunk.reshape(-1)  # [1, L] block -> [L]
 
     # ── map: tokenize + local combine (one record per unique word) ──
     packed_u, len_u, cnt_u, part, dest, (
-        n_unique, max_len, has_high, token_overflow) = map_prologue(
+        n_unique, max_len, has_high, token_overflow), *extra = map_prologue(
         chunk, n_dev=n_dev, n_reduce=n_reduce, max_word_len=max_word_len,
-        u_cap=u_cap, t_cap_frac=t_cap_frac)
+        u_cap=u_cap, t_cap_frac=t_cap_frac, map=map)
 
     # ── shuffle: the mr-X-Y files become one ICI collective ──
     with jax.named_scope("shuffle"):
         rows = jnp.concatenate(
             [packed_u, len_u[:, None].astype(jnp.uint32),
-             cnt_u[:, None].astype(jnp.uint32), part[:, None]], axis=1)
+             cnt_u[:, None].astype(jnp.uint32) if map is None else cnt_u,
+             part[:, None]], axis=1)
     recv = shuffle_rows(rows, dest, n_dev=n_dev, u_cap=u_cap, k=k)
 
     # ── reduce: sort received records by word, sum counts per run
@@ -154,9 +176,11 @@ def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,
     with jax.named_scope("reduce"):
         *scols, mlen, mcnt, mpart = lex_sort(
             tuple(recv[:, j] for j in range(k)),
-            (recv[:, k], recv[:, k + 1], recv[:, k + 2]))
+            (recv[:, k], recv[:, k + 1], recv[:, k + 2])) if map is None \
+            else _sort_wide(recv, k)
         mkeys, tot, upos, ovalid, m_unique = group_sorted(
-            tuple(scols), mcnt.astype(jnp.int32), out_cap)
+            tuple(scols), mcnt.astype(jnp.int32) if map is None else mcnt,
+            out_cap)
         with jax.named_scope("group"):
             mlen = mlen.astype(jnp.int32)
             out_keys = jnp.where(ovalid[:, None], mkeys[upos],
@@ -166,30 +190,31 @@ def _device_step(chunk: jax.Array, *, n_dev: int, n_reduce: int,
 
     scalars = jnp.stack([m_unique, n_unique, max_len,
                          has_high.astype(jnp.int32),
-                         token_overflow.astype(jnp.int32)])
+                         token_overflow.astype(jnp.int32), *extra])
     return (out_keys[None], out_len[None], tot[None], out_part[None],
             scalars[None])
 
 
 def _mapreduce_step_impl(chunks: jax.Array, *, n_dev: int, n_reduce: int,
                          max_word_len: int, u_cap: int, mesh: Mesh,
-                         t_cap_frac: int = 4):
+                         t_cap_frac: int = 4, map=None):
     """The full SPMD job step body — jitted twice below (with and without
     input-buffer donation) so the streaming engine's per-step uploads can
     be consumed by the kernel while ``wordcount_sharded`` keeps reusing
     one uploaded corpus across its retry attempts."""
     body = functools.partial(_device_step, n_dev=n_dev, n_reduce=n_reduce,
                              max_word_len=max_word_len, u_cap=u_cap,
-                             t_cap_frac=t_cap_frac)
+                             t_cap_frac=t_cap_frac, map=map)
+    totals = P(AXIS, None) if map is None else P(AXIS, None, None)
     return _shard_map(
         body, mesh=mesh,
         in_specs=P(AXIS, None),
-        out_specs=(P(AXIS, None, None), P(AXIS, None), P(AXIS, None),
+        out_specs=(P(AXIS, None, None), P(AXIS, None), totals,
                    P(AXIS, None), P(AXIS, None)))(chunks)
 
 
 _STEP_STATICS = ("n_dev", "n_reduce", "max_word_len", "u_cap", "t_cap_frac",
-                 "mesh")
+                 "mesh", "map")
 
 #: The full SPMD job step, jitted over the mesh.
 #:
@@ -197,7 +222,9 @@ _STEP_STATICS = ("n_dev", "n_reduce", "max_word_len", "u_cap", "t_cap_frac",
 #: Returns per-device arrays stacked on axis 0: packed word keys
 #: [D, D*u_cap, K], byte lengths, summed counts, reduce-partition ids, and a
 #: [D, 5] scalar block (m_unique, n_unique, max_len, has_high,
-#: token_overflow).
+#: token_overflow).  With ``map`` (``ops/fieldsum.FieldSum``) the sums are
+#: [D, D*u_cap, 2] uint32 (low, high) and the block is [D, 7]: the chunk's
+#: rows and the first unreadable row's place behind the five.
 mapreduce_step = x64_scoped(
     jax.jit(_mapreduce_step_impl, static_argnames=_STEP_STATICS))
 
@@ -228,12 +255,15 @@ def _slice_pack(keys, lens, cnts, parts, *, mp: int):
     the chip).
     ``mp`` is the pow2-rounded occupied prefix, so the bytes pulled track
     vocabulary, not capacity.  Lens/counts/partitions are uint32
-    reinterpretations — all are small non-negative ints."""
+    reinterpretations — all are small non-negative ints.  Sums that come
+    as two lanes ([D, rows, 2]: a step with a ``map``) go down as two,
+    [D, mp, K+4]."""
     with jax.named_scope("pack"):
         return jnp.concatenate(
             [keys[:, :mp],
              lens[:, :mp, None].astype(jnp.uint32),
-             cnts[:, :mp, None].astype(jnp.uint32),
+             cnts[:, :mp, None].astype(jnp.uint32) if cnts.ndim == 2
+             else cnts[:, :mp],
              parts[:, :mp, None].astype(jnp.uint32)], axis=2)
 
 

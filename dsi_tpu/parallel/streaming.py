@@ -262,6 +262,41 @@ def stream_files(paths: Sequence[str],
                 yield b
 
 
+def stream_rows(paths: Sequence[str],
+                block_bytes: int = 4 << 20) -> Iterator[bytes]:
+    """Files of newline-terminated rows as a block stream: nothing between
+    the files but the newline a file's last row lacks, so that a row of
+    the stream is a row of a file and the rows' count is the newlines'."""
+    for p in paths:
+        last = b"\n"
+        with open(p, "rb") as f:
+            while True:
+                b = f.read(block_bytes)
+                if not b:
+                    break
+                last = b[-1:]
+                yield b
+        if last != b"\n":
+            yield b"\n"
+
+
+def _row_batches(blocks: Iterable[bytes], n_dev: int, chunk_bytes: int,
+                 pool: Optional[BufferPool] = None,
+                 offsets: Optional[list] = None) -> Iterator[np.ndarray]:
+    """:func:`batch_stream`'s batches for a stream of rows: cut behind a
+    newline (``parallel/grepstream.batch_lines``' cut), so no row
+    straddles a chunk.  A row that no chunk can hold fails the job."""
+    from dsi_tpu.ops.fieldsum import BadRow
+    from dsi_tpu.parallel.grepstream import _LineTooLong, batch_lines
+
+    try:
+        for batch, _lens, _lines in batch_lines(blocks, n_dev, chunk_bytes,
+                                                pool=pool, offsets=offsets):
+            yield batch
+    except _LineTooLong:
+        raise BadRow(f"a row is longer than a chunk's {chunk_bytes} bytes")
+
+
 def _step_program(*, n_dev: int, n_reduce: int, max_word_len: int,
                   u_cap: int, mesh: Mesh, t_cap_frac: int):
     """The (name, fn) pair for one compiled ``mapreduce_step`` shape —
@@ -464,7 +499,19 @@ class WordcountStep(EngineStep):
     this mode so a late-detected overflow can replay from the same
     resident buffer; ``checkpoint_dir`` is refused (a byte cursor has
     no meaning over foreign batches — chains commit at stage
-    boundaries instead)."""
+    boundaries instead).
+
+    ``map`` (``ops/fieldsum.FieldSum``) makes the engine an aggregation:
+    ``blocks`` are newline-terminated rows of delimited fields
+    (:func:`stream_rows`), batches are cut behind a newline, a step groups
+    the rows' key field and sums their value field (two ``uint32`` lanes
+    a sum through the step, the pull and the merge), and the result's
+    numbers print as decimals.  A row that cannot be read raises
+    ``fieldsum.BadRow`` (the job fails; there is no host path).  The
+    host-merge accumulator only: ``aot``, ``device_accumulate``,
+    ``mesh_shards``, ``checkpoint_dir``, ``wire_upload`` and
+    ``device_batches`` are refused with it.  ``pipeline_stats`` gains
+    ``agg_rows``, ``agg_groups`` and ``agg_value_lanes``."""
 
     def __init__(self, blocks: Iterable[bytes], mesh: Mesh | None = None,
                  n_reduce: int = 10, chunk_bytes: int = 1 << 20,
@@ -482,14 +529,15 @@ class WordcountStep(EngineStep):
                  resume: bool = False,
                  wire_upload: Optional[bool] = None,
                  device_batches=None,
-                 input_range: Optional[Tuple[int, int]] = None):
+                 input_range: Optional[Tuple[int, int]] = None,
+                 map=None):
         super().__init__()
         _wordcount_setup(self, blocks, mesh, n_reduce, chunk_bytes,
                          max_word_len, u_cap, aot, on_attempt, depth,
                          pipeline_stats, device_accumulate, sync_every,
                          mesh_shards, checkpoint_dir, checkpoint_every,
                          checkpoint_async, checkpoint_delta, resume,
-                         wire_upload, device_batches, input_range)
+                         wire_upload, device_batches, input_range, map)
 
 
 def wordcount_streaming(
@@ -642,10 +690,20 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                      mesh_shards, checkpoint_dir, checkpoint_every,
                      checkpoint_async, checkpoint_delta, resume,
                      wire_upload=None, device_batches=None,
-                     input_range=None):
+                     input_range=None, map=None):
     """The engine body behind :class:`WordcountStep`: full setup
     (``resume=True`` chain restore included) ending with the pipeline
     armed and the lifecycle hooks attached to ``step``."""
+    if map is not None:
+        refused = [name for name, on in (
+            ("aot", aot), ("device_accumulate", device_accumulate),
+            ("mesh_shards", mesh_shards_default(mesh_shards)),
+            ("checkpoint_dir", checkpoint_dir),
+            ("wire_upload", wirecodec.wire_upload_default(wire_upload)),
+            ("device_batches", device_batches is not None)) if on]
+        if refused:
+            raise ValueError(f"a map ({map}) runs on the host-merge path "
+                             f"alone: not with {', '.join(refused)}")
     if device_batches is not None and checkpoint_dir:
         raise ValueError("device_batches and checkpoint_dir are "
                          "exclusive: chained stages commit at stage "
@@ -660,8 +718,9 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
     # last cleared rung so a stream that consistently token-overflows
     # the optimistic frac (dense 1-letter words) doesn't replay every
     # step forever.
+    fracs = (4, 2) if map is None else map.fracs
     state = {"cap": rung0_cap(chunk_bytes, u_cap), "mwl": max_word_len,
-             "frac": 4}
+             "frac": fracs[0]}
     sharding = NamedSharding(mesh, PartitionSpec(AXIS, None))
     # The engine's stats dict IS a registry scope (dsi_tpu/obs): the
     # same keys as ever, readable by any consumer as the one documented
@@ -676,7 +735,11 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                   "d2h_s": 0.0, "merge_s": 0.0, "replay_s": 0.0,
                   "finalize_s": 0.0})
     # The accumulator's compactions and counts land in the same scope.
-    acc = PackedCounts(stats=stats)
+    acc = PackedCounts(stats=stats,
+                       decimals=0 if map is None else map.decimals)
+    if map is not None:
+        stats.update({"agg_rows": 0, "agg_groups": 0,
+                      "agg_value_lanes": map.value_lanes})
     # Compressed chunk uploads (ops/wirecodec.py): encode host-side,
     # ship the packed tensor, decode on device as a map prologue.  Off
     # by default = bit-identical raw uploads; on, a batch the codec
@@ -914,6 +977,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
     def step_call(chunks_dev, mwl, cap, frac):
         kw = dict(n_dev=n_dev, n_reduce=n_reduce, max_word_len=mwl,
                   u_cap=cap, mesh=mesh, t_cap_frac=frac)
+        if map is not None:
+            kw["map"] = map
         with _quiet_unusable_donation():  # first call per rung compiles
             if aot:
                 return _aot_step_fn(chunks_dev, donate=donate_steps,
@@ -973,7 +1038,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
             state["mwl"] = mwl    # (sticky for later optimistic dispatches)
             if on_attempt is not None:
                 on_attempt(mwl, cap)
-            for frac in (4, 2):
+            for frac in fracs:
                 chunks = jax.device_put(chunks_np, sharding)
                 keys, lens, cnts, parts, scal = step_call(
                     chunks, mwl, cap, frac)
@@ -981,6 +1046,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                 if not scal_np[:, 4].any():
                     break
             state["frac"] = frac  # cleared rung sticks
+            if map is not None and scal_np[:, 3].any():
+                raise map.bad_row(scal_np, stats["agg_rows"])
 
             def payload():
                 if device_payload:
@@ -1050,14 +1117,18 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                    step=stats["steps"], program="mapreduce_step"):
             keys, lens, cnts, parts, scal = step_call(
                 chunks, mwl, cap, state["frac"])
-            if aot or device_accumulate:
+            if aot or device_accumulate or map is not None:
                 # Only scal + the packed tensor stay referenced: the four
                 # result tables free as soon as the pack consumes them, so
                 # an in-flight step holds one packed copy, not five
                 # tables.  Device accumulation packs eagerly even under
                 # jit — the fold consumes the packed layout, and its
                 # full-capacity shape is deterministic (no flags needed at
-                # dispatch time).
+                # dispatch time).  So does a step with a map: its table is
+                # a row a key of the chunk, half a MiB at the rung an
+                # aggregation settles on, and a pack that waits for the
+                # flags waits behind the next step's kernel (2.4 s of a
+                # 4.7 s job: PERF.md, PR 49).
                 mp = keys.shape[1]
                 packed_dev = (
                     _aot_pack(keys, lens, cnts, parts, mp=mp) if aot
@@ -1085,6 +1156,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
             scal_np = np.asarray(scal)  # blocks until the kernel lands
         if scal_np[:, 3].any():      # non-ASCII: the whole stream is host's
             pool.give(buf)
+            if map is not None:      # a row that cannot be read: no one's
+                raise map.bad_row(scal_np, stats["agg_rows"])
             raise _NeedsHostPath
         exact = (not scal_np[:, 4].any()
                  and int(scal_np[:, 1].max()) <= cap
@@ -1145,6 +1218,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         # This step is now CONFIRMED: its output is merged/folded and
         # nothing after it is.  The fault point sits BEFORE the cursor
         # advances — the classic torn-update instant.
+        if map is not None:  # a replay reads the same rows: counted once
+            stats["agg_rows"] += int(scal_np[:, 5].sum())
         fault_point("mid-fold")
         if ck_store is not None:
             ck_cursor["offset"] = rec_offset
@@ -1169,8 +1244,9 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         pipe.begin(lambda: iter(device_batches))
     else:
         feed = skip_stream(blocks, start_offset) if start_offset else blocks
-        pipe.begin(lambda: batch_stream(feed, n_dev, chunk_bytes,
-                                        pool=pool, offsets=offsets))
+        batches = batch_stream if map is None else _row_batches
+        pipe.begin(lambda: batches(feed, n_dev, chunk_bytes,
+                                   pool=pool, offsets=offsets))
     step._host_excs = (_TokenTooLong, _NeedsHostPath)
     step._save = save_ckpt if ck_store is not None else None
     step._writer = ck_writer
@@ -1195,6 +1271,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         with _span("finalize", lane="host", stats=stats) as sp:
             step.result = acc.finalize()
             sp.set(keys=len(step.result))
+        if map is not None:
+            stats["agg_groups"] = len(step.result)
 
     released = []
 

@@ -6,7 +6,8 @@ engines so stage N+1's upload IS stage N's device-resident output:
 
 * :mod:`~dsi_tpu.plan.graph`  — the :class:`Plan`/:class:`Stage` DAG
   model (+ the canonical chains: grep → wordcount-over-matches,
-  indexer → df-top-k → postings join, sample → range sort);
+  indexer → df-top-k → postings join, sample → range sort, and the
+  one-stage aggregation);
 * :mod:`~dsi_tpu.plan.driver` — :func:`run_plan`, driving each stage as
   a resumable step object with relay handoffs
   (``device/relay.py``), stage-manifest commits through ``ckpt/``, and
@@ -22,6 +23,7 @@ from dsi_tpu.plan.graph import (
     Plan,
     PlanError,
     Stage,
+    agg_plan,
     grep_cascade_plan,
     grep_wordcount_plan,
     indexer_join_plan,
@@ -41,6 +43,7 @@ __all__ = [
     "PlanHostPath",
     "PlanResult",
     "Stage",
+    "agg_plan",
     "grep_cascade_plan",
     "grep_wordcount_plan",
     "indexer_join_plan",

@@ -746,6 +746,34 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
         _note_stage(sc, sp, stage, [books])
         return StageOut(result=ordered)
 
+    if stage.kind == "aggregate":
+        from dsi_tpu.ops.fieldsum import BadRow, FieldSum
+        from dsi_tpu.parallel.streaming import WordcountStep, stream_rows
+
+        if staged:
+            # One stage hands nothing over, so there is nothing to stage;
+            # and no host fallback commits an aggregation.
+            raise PlanHostPath(f"stage {stage.name!r}: the aggregation "
+                               "needs the host path (a staged run "
+                               "materializes on the host)")
+        paths = list(plan.param(stage, "paths"))
+        books = _Books(stream_rows(paths))
+        try:
+            step = WordcountStep(
+                books, mesh=mesh, pipeline_stats=books.stats,
+                n_reduce=int(plan.param(stage, "n_reduce", 10)),
+                u_cap=int(plan.param(stage, "u_cap", 1 << 12)),
+                map=FieldSum(prefix=int(plan.param(stage, "prefix", 0))),
+                **kw)
+            res = _drive(step, i)
+        except BadRow as e:
+            raise e.at(paths) from None
+        _note_stage(sc, sp, stage, [books])
+        if res is None:
+            raise PlanHostPath(f"stage {stage.name!r}: the aggregation "
+                               "needs the host path")
+        return StageOut(result=res)
+
     raise PlanError(f"unrunnable stage kind {stage.kind!r}")
 
 

@@ -9,8 +9,9 @@ device-resident handoffs (``dsi_tpu/device/relay.py``,
 ``parallel/stepobj.py`` exports) instead of host materializations.  The
 driver (``plan/driver.py``) runs it.
 
-The eight stage kinds (what the driver knows how to run; ``sample`` and
-``range_sort``, the sort chain's, are the two PR 45 added):
+The nine stage kinds (what the driver knows how to run; ``sample`` and
+``range_sort``, the sort chain's, are the two PR 45 added, ``aggregate``
+the one PR 49 did):
 
 * ``grep``          — streaming literal grep over a byte source,
   emitting the matching lines into the outgoing relay (the
@@ -45,6 +46,14 @@ The eight stage kinds (what the driver knows how to run; ``sample`` and
   sort`` pulls and commits as ``mr-out-<r>``, totally ordered.  The split
   points it ran with enter the stage's identity, and so the plan's
   signature, as a CRC (``splits``).  OSDI'04 section 5.3's own shape.
+* ``aggregate``     — ``SELECT key, SUM(value) ... GROUP BY key`` over
+  files of newline-terminated, ``|``-delimited rows: the ``wordcount``
+  stage's engine (``WordcountStep``) with ``ops/fieldsum.FieldSum`` as
+  its map, so a row's key field is the word and its decimal value field
+  what is summed, 64 bits wide.  A source stage; its result is the merged
+  table, which ``planrun --chain agg`` commits as ``mr-out-<r>``
+  (``<key> <sum with six decimals>``).  ``prefix`` groups by the key's
+  first bytes.
 * A ``grep`` stage MAY itself have a grep dep (the grep→grep cascade):
   it consumes the upstream relay's line stream instead of a byte
   source and re-greps it with its own pattern.
@@ -65,7 +74,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 #: The stage kinds plan/driver.py can run.
 STAGE_KINDS = ("grep", "wordcount", "indexer", "df_topk", "postings_join",
-               "top_k", "sample", "range_sort")
+               "top_k", "sample", "range_sort", "aggregate")
 
 #: Stage params carrying bulk payloads: identity-hashed, never inlined
 #: into the signature.
@@ -250,4 +259,14 @@ def sort_plan(paths: Sequence[str], *, sample: int = 100_000,
     p = Plan("sort", **defaults)
     s = p.add(Stage("sample", "sample", paths=list(paths), sample=sample))
     p.add(Stage("sort", "range_sort", deps=[s.name], paths=list(paths)))
+    return p
+
+
+def agg_plan(paths: Sequence[str], *, prefix: int = 0, **defaults) -> Plan:
+    """One ``aggregate`` stage over files of ``|``-delimited rows: group
+    by field 0 (its first ``prefix`` bytes where that is not 0), sum
+    field 3, a decimal (Pavlo et al., SIGMOD'09, the Aggregation Task's
+    two queries over ``UserVisits``)."""
+    p = Plan("agg", **defaults)
+    p.add(Stage("agg", "aggregate", paths=list(paths), prefix=int(prefix)))
     return p

@@ -75,9 +75,9 @@ def build_plan(spec: Dict):
     """Rebuild the canonical plan a spec describes — shared by
     ``planrun`` (which derives the spec from argv) and every stage host
     (which must see the IDENTICAL plan graph)."""
-    from dsi_tpu.plan import (grep_cascade_plan, grep_wordcount_plan,
-                              indexer_join_plan, sort_plan,
-                              wordcount_topk_plan)
+    from dsi_tpu.plan import (agg_plan, grep_cascade_plan,
+                              grep_wordcount_plan, indexer_join_plan,
+                              sort_plan, wordcount_topk_plan)
 
     defaults = dict(chunk_bytes=spec.get("chunk_bytes", 1 << 20),
                     depth=spec.get("depth"),
@@ -111,6 +111,8 @@ def build_plan(spec: Dict):
     if chain == "sort":
         return sort_plan(files, sample=spec.get("sample", 100_000),
                          **defaults)
+    if chain == "agg":
+        return agg_plan(files, prefix=spec.get("agg_prefix", 0), **defaults)
     raise ValueError(f"unknown chain {chain!r}")
 
 
