@@ -17,14 +17,16 @@ that phase):
 * ``materialize_wait_s`` — consumer starvation on the producer queue
 * ``upload_s``           — H2D puts of step inputs
 * ``kernel_s``           — seconds blocked on a step's deferred
-  scalar/flag check.  NOT device time: the flags of a step land long
-  before the step's result tables are asked for, and most of the
-  device-compute wall the window failed to hide shows up in
-  ``device_wait_s`` instead
+  scalar/flag check.  NOT device time: it is what the window did not
+  hide of the step program, as ``device_wait_s`` is of whatever the
+  pulled tensor still waits for (``device_wait_share`` adds the two)
 * ``pull_s``             — result pulls, host-blocked; the sum of
   ``device_wait_s`` (blocked in ``jax.block_until_ready`` until the
-  device has produced what is pulled: device time, not transfer) and
-  ``d2h_s`` (the device-to-host copy itself)
+  device has produced what is pulled: device time, not transfer; a
+  word-count step's table is packed when the step is dispatched, so
+  this is what was left of the step's own device time, not a place in
+  the queue behind the next step's kernel) and ``d2h_s`` (the
+  device-to-host copy itself)
 * ``merge_s``            — host-side accumulation of pulled results
 * ``finalize_s``         — the final merge of the accumulator into the
   result: the last compaction; the merged table is the result
@@ -102,7 +104,11 @@ that phase):
 Counters / gauges: ``steps`` (or ``waves``), ``depth``, ``replays``,
 ``results_ready`` (steps whose host reads the device had already
 produced when ``finish`` first asked: the hit count of a copy started
-at dispatch), ``step_pulls``, ``sync_pulls``, ``widens``, ``folds``,
+at dispatch), ``step_pulls`` and its two kinds in the word-count stream
+engine, ``pulls_early`` (served by the tensor packed when the step was
+dispatched) and ``pulls_late`` (served by a pack enqueued at retirement:
+a step whose table outgrew the predicted prefix, or a replay's payload;
+``pulls_early + pulls_late == step_pulls``), ``sync_pulls``, ``widens``, ``folds``,
 ``fold_overflows``, ``appends``, ``append_overflows``,
 ``postings_widens``, ``topk_snapshots``, ``hist_folds``, ``hist_pulls``,
 ``table_cap``, ``sync_every``, ``max_inflight``,
@@ -358,6 +364,7 @@ def job_children_s(stats: dict) -> float:
 COUNTER_KEYS = (
     # pipeline / engine counters
     "steps", "waves", "depth", "replays", "results_ready", "step_pulls",
+    "pulls_early", "pulls_late",
     "sync_pulls", "widens", "folds", "fold_overflows", "appends",
     "append_overflows",
     "postings_widens", "topk_snapshots", "hist_folds", "hist_pulls",

@@ -26,11 +26,13 @@ Three scale levers this module owns:
   every later step, so a low-vocabulary stream never pays for a
   worst-case kernel (the sort inside the step is O(cap log cap)) and a
   high-vocabulary stream widens exactly once,
-* **prefix-sliced D2H** — only the occupied prefix of the result tables
-  (max per-device merged uniques, rounded up to a power of two so the
-  slice programs stay bounded) crosses the wire; the pull cost tracks
-  vocabulary, not capacity (per-pull cost not measured on the chip
-  beyond the smoke's pull_s total),
+* **prefix-sliced D2H** — only a prefix of the result tables (a power
+  of two, so the slice programs stay bounded) crosses the wire; the
+  pull cost tracks vocabulary, not capacity.  The prefix is sticky and
+  predicted, like the capacity: it starts at the start rung's capacity,
+  a step whose max per-device merged uniques outgrow it raises it to
+  their pow2, and the step's pack is enqueued WITH the step, at that
+  prefix, before the step's own count is known,
 * **vectorized merge** — no per-word Python in the steady state.
 
 And the lever that makes the stream a *pipeline* rather than a lockstep
@@ -54,9 +56,11 @@ buffers (each step's upload is DONATED to its kernel —
 ``shuffle.mapreduce_step_donate`` — so a window never doubles chunk
 residency) plus ``depth`` per-step result sets awaiting their deferred
 pull — one packed ``[n_dev, n_dev*u_cap, K+3]`` tensor per in-flight
-step under ``aot`` (the four result tables free as soon as the eager
-pack consumes them), the four equivalent-size tables per step on the
-jit path — plus one kernel's working buffers.  All of it is
+step under ``aot``, device accumulation and a ``map`` (the four result
+tables free as soon as the pack at dispatch consumes them); on the
+host-merge path the packed prefix AND the four tables, which a step
+that outgrew the prefix is packed from at retirement — plus one
+kernel's working buffers.  All of it is
 capacity-bounded (scales with ``depth x n_dev^2 x u_cap``, never with
 corpus bytes); size ``depth``/``u_cap`` together when HBM is tight.
 The host holds a small rotating pool of batch buffers (O(depth)), the
@@ -583,7 +587,8 @@ def wordcount_streaming(
     thread starvation, ``upload_s``, ``kernel_s`` time blocked on step
     flags (not device time), ``pull_s`` and its two parts
     ``device_wait_s`` (blocked until the device has produced the step's
-    packed result) and ``d2h_s`` (the copy), ``merge_s``, ``replay_s``,
+    packed result, which stands right behind the step on its queue) and
+    ``d2h_s`` (the copy), ``merge_s``, ``replay_s``,
     ``finalize_s`` the final merge into the result) plus ``depth``,
     ``steps``, ``replays``, ``max_inflight_chunks`` (peak device chunk
     buffers — bounded by ``depth``) and ``batch_allocs`` (host batch
@@ -596,8 +601,12 @@ def wordcount_streaming(
     ``aot=True`` compiles both step and pack programs explicitly
     (``backends/aotcache.py``) and pulls FULL-capacity packed tables (one
     deterministic shape per rung, so ``warm_stream_aot`` can pre-compile
-    everything) instead of data-dependent pow2 prefixes — fewer programs
-    for more pulled bytes (which side wins on the chip is not measured).
+    everything) instead of pow2 prefixes — fewer programs for more
+    pulled bytes.  Every path packs a step's table when the step is
+    dispatched; the host-merge path packs the sticky prefix
+    (``pipeline_stats`` ``pulls_early``: pulls that tensor served;
+    ``pulls_late``: a step that outgrew it, or a replay's payload,
+    packed at retirement; their sum is ``step_pulls``).
 
     ``device_accumulate=True`` folds each confirmed step's reduce output
     into a persistent on-device merge table (``device/table.py``) instead
@@ -721,6 +730,13 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
     fracs = (4, 2) if map is None else map.fracs
     state = {"cap": rung0_cap(chunk_bytes, u_cap), "mwl": max_word_len,
              "frac": fracs[0]}
+    # Sticky pull prefix: the rows of a step's table the host-merge path
+    # packs when the step is DISPATCHED, before anyone knows the step's
+    # own occupied count.  Predicted like the rung: it starts at the
+    # start rung's capacity and every retired step raises it to its own
+    # ``occupied_prefix``; it never falls within a job, so the pack
+    # shapes a corpus reaches are the ones its first job compiled.
+    state["mp"] = state["cap"]
     sharding = NamedSharding(mesh, PartitionSpec(AXIS, None))
     # The engine's stats dict IS a registry scope (dsi_tpu/obs): the
     # same keys as ever, readable by any consumer as the one documented
@@ -728,7 +744,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
     stats = metrics_scope("stream")
     stats.update({"depth": depth, "steps": 0, "replays": 0,
                   "max_inflight_chunks": 0, "donate_chunks": True,
-                  "step_pulls": 0, "device_accumulate": device_accumulate,
+                  "step_pulls": 0, "pulls_early": 0, "pulls_late": 0,
+                  "device_accumulate": device_accumulate,
                   "device_rows": [0] * n_dev,
                   "batch_s": 0.0, "batch_wait_s": 0.0, "upload_s": 0.0,
                   "kernel_s": 0.0, "pull_s": 0.0, "device_wait_s": 0.0,
@@ -821,7 +838,8 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                                  steps=int(eff["steps"]))
                 state.update({"cap": int(eff["cap"]),
                               "mwl": int(eff["mwl"]),
-                              "frac": int(eff["frac"])})
+                              "frac": int(eff["frac"]),
+                              "mp": int(eff["cap"])})
                 acc.restore({k[4:]: v for k, v in arrays.items()
                              if k.startswith("acc_")})
                 if device_accumulate and meta.get("table_cap"):
@@ -1006,19 +1024,24 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
 
     def pull_packed(keys, lens, cnts, parts, scal_np, in_pull=False):
         """One packed host tensor per step (the single-pull D2H shape,
-        shuffle._slice_pack) + per-device occupied counts + key width.
-        Under aot the prefix is the full capacity instead of the
-        data-dependent pow2 prefix — deterministic shapes beat pull
-        volume there (see the aot note in the docstring)."""
+        shuffle._slice_pack) + per-device occupied counts + key width,
+        from a pack enqueued NOW: the late pull, a replay's payload or a
+        step whose table outgrew the prefix packed at its dispatch.  The
+        prefix is the step's own pow2 occupied prefix, which the sticky
+        one rises to; under aot it is the full capacity —
+        deterministic shapes beat pull volume there (see the aot note
+        in the docstring)."""
         m = int(scal_np[:, 0].max())
         if m == 0:
             return None, None, 0
         kk = keys.shape[2]
+        stats["pulls_late"] += 1
         if aot:
             packed = to_host(lambda: _aot_pack(
                 keys, lens, cnts, parts, mp=keys.shape[1]), in_pull)
         else:
             mp = occupied_prefix(m, keys.shape[1])
+            state["mp"] = max(state["mp"], mp)
             packed = to_host(lambda: _slice_pack(
                 keys, lens, cnts, parts, mp=mp), in_pull)
         return packed, scal_np[:, 0], kk
@@ -1065,12 +1088,14 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
 
     def dispatch(buf: np.ndarray):
         """Optimistically launch one step at the sticky rung — upload +
-        async kernel dispatch, no synchronization.  Under aot the pack
-        program is dispatched HERE too (its full-capacity shape is
-        deterministic, no flags needed): on an in-order device stream a
-        pack dispatched at finish time would queue behind the NEXT step's
-        kernel, serializing exactly what the window exists to overlap —
-        and misattributing that kernel's wall to pull_s."""
+        async kernel dispatch, no synchronization.  The step's pack
+        program is dispatched HERE too, directly behind its step: on an
+        in-order device stream a pack dispatched at finish time would
+        queue behind the NEXT step's kernel, serializing exactly what
+        the window exists to overlap — and misattributing that kernel's
+        wall to pull_s.  Its shape needs no flags: the full capacity
+        under aot, device accumulation and a map, the sticky prefix
+        (``state["mp"]``) on the host-merge path."""
         mwl, cap = state["mwl"], state["cap"]
         if on_attempt is not None:
             on_attempt(mwl, cap)
@@ -1117,26 +1142,24 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                    step=stats["steps"], program="mapreduce_step"):
             keys, lens, cnts, parts, scal = step_call(
                 chunks, mwl, cap, state["frac"])
+            rows = keys.shape[1]
             if aot or device_accumulate or map is not None:
                 # Only scal + the packed tensor stay referenced: the four
                 # result tables free as soon as the pack consumes them, so
                 # an in-flight step holds one packed copy, not five
-                # tables.  Device accumulation packs eagerly even under
-                # jit — the fold consumes the packed layout, and its
-                # full-capacity shape is deterministic (no flags needed at
-                # dispatch time).  So does a step with a map: its table is
-                # a row a key of the chunk, half a MiB at the rung an
-                # aggregation settles on, and a pack that waits for the
-                # flags waits behind the next step's kernel (2.4 s of a
-                # 4.7 s job: PERF.md, PR 49).
-                mp = keys.shape[1]
-                packed_dev = (
-                    _aot_pack(keys, lens, cnts, parts, mp=mp) if aot
-                    else _slice_pack(keys, lens, cnts, parts, mp=mp))
-                handles = (scal, packed_dev, keys.shape[2], None)
+                # tables.  The fold consumes the packed layout at its
+                # full-capacity shape; a step with a map settles on a
+                # table of half a MiB (PERF.md, PR 49).
+                mp, tables = rows, None
             else:
-                handles = (scal, None, keys.shape[2],
-                           (keys, lens, cnts, parts))
+                # Host merge: the predicted prefix.  The tables stay with
+                # the record until retirement, for the step whose own
+                # count turns out to lie past it (a late pack, once).
+                mp, tables = min(state["mp"], rows), (keys, lens, cnts,
+                                                      parts)
+            packed_dev = (_aot_pack(keys, lens, cnts, parts, mp=mp) if aot
+                          else _slice_pack(keys, lens, cnts, parts, mp=mp))
+            handles = (scal, packed_dev, keys.shape[2], tables)
         stats["steps"] += 1
         rec_offset = 0
         if offsets is not None:
@@ -1172,17 +1195,25 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                 # its step cleared.
                 fold_confirmed(packed_dev, scal, scal_np)
             else:
-                with _span("pull", stats=stats, key="pull_s"):
-                    if int(scal_np[:, 0].max()) == 0:
+                with _span("pull", stats=stats, key="pull_s") as sp:
+                    m = int(scal_np[:, 0].max())
+                    early = m <= packed_dev.shape[1]
+                    if m == 0:
                         packed, nus = None, None
-                    elif packed_dev is not None:  # aot: pack already ran
+                    elif early:
+                        # The tensor packed at dispatch holds the step's
+                        # rows (those past nus[d] are padding, cut by
+                        # the merge): the device made it right behind
+                        # the step, so the wait is the step's own.
                         packed = to_host(lambda: packed_dev, True)
                         nus = scal_np[:, 0]
-                    else:
+                        stats["pulls_early"] += 1
+                    else:  # outgrew the predicted prefix: pack it now
                         packed, nus, kk = pull_packed(*tables, scal_np,
                                                       in_pull=True)
                     if packed is not None:
                         stats["step_pulls"] += 1
+                        sp.set(early=early)
                 with _span("merge", stats=stats, key="merge_s"):
                     if packed is not None:
                         acc.add_packed_step(packed, nus, kk)

@@ -356,3 +356,131 @@ def test_stream_result_is_a_mapping_equal_to_the_oracles_dict(how):
     assert res == want and want == res
     assert res.stats["finalize_decoded_keys"] == len(want)  # once, kept
     assert res.stats["finalize_decode_s"] > 0
+
+
+# ── the table packed when the step is dispatched ───────────────────────
+
+
+def _word(i: int) -> str:
+    return "".join(chr(97 + (i // 26 ** j) % 26) for j in range(5))
+
+
+def _segments(rng, n, size, vocab_of):
+    """``n`` pieces of about ``size`` bytes, piece k drawn from the
+    word ordinals ``vocab_of(k)``."""
+    out = []
+    for k in range(n):
+        ids = np.asarray(vocab_of(k))
+        picks = rng.integers(0, len(ids), size // 6)
+        out.append(" ".join(_word(int(ids[j])) for j in picks) + "\n")
+    return "".join(out)
+
+
+def _wcstream_stats(tmp_path, text, *flags):
+    """One ``wcstream`` job over ``text`` in this process: its
+    ``pipeline_stats`` and whether it committed the oracle's bytes."""
+    import ast
+    import contextlib
+    import io
+
+    from dsi_tpu.cli import wcstream
+    from tests.harness import merged_output, oracle_output
+
+    src = tmp_path / "in.txt"
+    src.write_text(text, encoding="ascii")
+    n = len(list(tmp_path.iterdir()))
+    wd = tmp_path / f"out-{n}"
+    err = io.StringIO()
+    with contextlib.redirect_stderr(err):
+        rc = wcstream.main(["--nreduce", "10", "--chunk-bytes", "4096",
+                            "--u-cap", "64", "--stats", "--workdir",
+                            str(wd), *flags, str(src)])
+    assert rc == 0, err.getvalue()
+    m = re.search(r"^wcstream: pipeline_stats=(\{.*\})$", err.getvalue(),
+                  re.M)
+    same = merged_output(str(wd)) == oracle_output("wc", [str(src)],
+                                                   str(tmp_path))
+    return ast.literal_eval(m.group(1)), same
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("vocabulary", ["grows", "fixed"])
+def test_step_tables_are_packed_at_dispatch(tmp_path, devices, vocabulary):
+    """The host-merge pull takes the tensor packed when its step was
+    dispatched, at the sticky prefix.  A fixed vocabulary never outgrows
+    the start rung's prefix; one that grows overflows the rung (a replay,
+    whose payload is packed late), then outgrows the prefix the replay
+    left without overflowing the wider rung: a miss, packed late once,
+    which raises the prefix for the steps behind it."""
+    rng = np.random.default_rng(50)
+    step = devices * 4096
+    if vocabulary == "fixed":
+        text = _segments(rng, 8, step, lambda k: range(40))
+    else:
+        text = (_segments(rng, 4, step, lambda k: range(20))
+                # ~100 words a chunk: over the rung of 64, under 128
+                + _segments(rng, 4, step, lambda k: range(100))
+                # 75 words of its own every 2 KiB: 150-225 a chunk, under
+                # the rung of 256 and over the prefix the replay left
+                + _segments(rng, 6 * devices * 2, 2048,
+                            lambda k: range(1000 + 75 * k, 1075 + 75 * k)))
+    ps, same = _wcstream_stats(tmp_path, text, "--devices", str(devices))
+    assert same  # the sequential reference's bytes
+    assert ps["pulls_early"] + ps["pulls_late"] == ps["step_pulls"]
+    assert ps["step_pulls"] == ps["steps"] >= 8
+    if vocabulary == "fixed":
+        assert ps["replays"] == 0 and ps["pulls_late"] == 0
+    else:
+        assert ps["replays"] >= 1
+        # a replay pulls late once; the rest are misses of the prefix
+        assert ps["pulls_late"] >= ps["replays"] + 1
+        assert ps["pulls_early"] >= ps["pulls_late"]
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_overflowing_steps_early_tensor_is_never_merged(depth):
+    """Every step overflows the start rung until it widens: each such
+    step's tensor, packed at dispatch from an inexact table, is dropped
+    and the replay's merged in its place: the counts are the
+    reference's, neither doubled nor short."""
+    rng = np.random.default_rng(51)
+    text = _segments(rng, 10, 8192, lambda k: range(300)).encode()
+    want = dict(collections.Counter(WORDS.findall(text.decode())))
+    st: dict = {}
+    res = wordcount_streaming([text], mesh=default_mesh(2), n_reduce=10,
+                              chunk_bytes=1 << 12, u_cap=16, depth=depth,
+                              pipeline_stats=st)
+    assert {w: c for w, (c, _) in res.items()} == want
+    assert 1 <= st["replays"] <= depth
+    assert st["pulls_late"] >= st["replays"]
+    assert st["pulls_early"] + st["pulls_late"] == st["step_pulls"] \
+        == st["steps"]
+
+
+@pytest.mark.parametrize("delta", [False, True], ids=["full", "delta"])
+def test_killed_checkpointed_run_resumes_with_the_early_pack(
+        tmp_path, monkeypatch, delta):
+    """A run killed mid-stream, between a step's merge and its cursor,
+    resumes to the uninterrupted run's bytes; the resumed steps are
+    served by the tensors packed at their dispatch (a delta log trims
+    their padding)."""
+    from dsi_tpu.ckpt import FaultInjected, reset_faults
+
+    rng = np.random.default_rng(52)
+    text = _segments(rng, 12, 8192, lambda k: range(50 + 10 * k))
+    flags = ["--devices", "2", "--checkpoint-dir", str(tmp_path / "ck"),
+             "--checkpoint-every", "2"] + (["--ckpt-delta"] if delta else [])
+    reset_faults()
+    monkeypatch.setenv("DSI_FAULT_MODE", "raise")
+    monkeypatch.setenv("DSI_FAULT_POINT", "mid-fold")
+    monkeypatch.setenv("DSI_FAULT_STEP", "7")
+    with pytest.raises(FaultInjected):
+        _wcstream_stats(tmp_path, text, *flags)
+    for k in ("DSI_FAULT_MODE", "DSI_FAULT_POINT", "DSI_FAULT_STEP"):
+        monkeypatch.delenv(k)
+    reset_faults()
+    ps, same = _wcstream_stats(tmp_path, text, *flags, "--resume")
+    assert same
+    assert ps["resume_cursor"] > 0  # restored, not replayed from byte 0
+    assert ps["pulls_early"] >= 1
+    assert ps["pulls_early"] + ps["pulls_late"] == ps["step_pulls"]

@@ -361,6 +361,41 @@ def test_wcstream_stats_split_the_pull_and_time_the_tail(tmp_path):
         "mr-out-0", "mr-out-1", "mr-out-2"]
 
 
+@pytest.mark.parametrize("flags, replays", [
+    ((), False), (("--aot",), False), (("--u-cap", "64"), True)],
+    ids=["host-merge", "aot", "small-rung"])
+def test_pull_spans_say_which_pack_served_them(fresh_tracer, tmp_path,
+                                               flags, replays):
+    """A step's ``pull`` span says whether the tensor packed at the
+    step's dispatch served it (``early``); the two counters are the
+    spans' count, plus the replays' payloads, which are pulled inside
+    their ``replay`` span; the pull's two parts still lie inside it."""
+    from dsi_tpu.obs.registry import COUNTER_KEYS
+
+    rc, ps, _ = _stream_main("wcstream", tmp_path, "--trace-dir",
+                             str(tmp_path / "trace"), *flags)
+    assert rc == 0
+    _, events = _jsonl(str(tmp_path / "trace" / "trace.jsonl"))
+    pulls = [e for e in events if e["ph"] == "X" and e["name"] == "pull"]
+    early = [e for e in pulls if e["early"]]
+    assert len(early) == ps["pulls_early"] >= 1
+    assert len(pulls) - len(early) == ps["pulls_late"] - ps["replays"]
+    assert ps["pulls_early"] + ps["pulls_late"] == ps["step_pulls"]
+    assert (ps["replays"] >= 1) == replays
+    if not replays:  # the start rung's prefix held every step's table
+        assert ps["pulls_late"] == 0
+    assert "pulls_early" in COUNTER_KEYS and "pulls_late" in COUNTER_KEYS
+    assert ps["device_wait_s"] + ps["d2h_s"] <= ps["pull_s"] + 2e-4
+    # the parts are the spans inside the pulls, whichever pack they read
+    spans = {e["id"]: e for e in events if e["ph"] == "X"}
+    for name, key in (("wait", "device_wait_s"), ("d2h", "d2h_s")):
+        inside = [e for e in spans.values() if e["name"] == name
+                  and spans[e["parent"]]["name"] == "pull"]
+        assert len(inside) == len(pulls)
+        assert ps[key] == pytest.approx(sum(e["dur"] for e in inside),
+                                        abs=5e-4), name
+
+
 # ── tracing off ────────────────────────────────────────────────────────
 
 
