@@ -13,7 +13,11 @@ goes through JAX's persistent compilation cache, placed by
 ``utils/compilecache.py``, like every other program.  ``stats`` counts
 the explicit compiles of this process (a persistent-cache hit still
 counts as one: it is a program this process had to obtain) and the wall
-seconds they took, and each is logged on stderr by program name.
+seconds of their two halves, each a span with the program's name as
+its ``program`` field: ``lowered_s`` (the ``lower`` span: tracing the function and
+lowering it to StableHLO, which no cache saves) and ``compiled_s`` (the
+``compile`` span: the backend's compile, or the persistent cache's
+load); each program is logged on stderr by name.
 
 Program-name families: ``wc_kernel*`` and ``corpus_wc*`` single-chunk
 programs, ``stream_step_*``/``stream_pack_*`` streaming programs,
@@ -27,14 +31,13 @@ import contextlib
 import os
 import sys
 import threading
-import time
 from typing import Any, Callable, Dict, Tuple
 
 _memo: Dict[tuple, Callable] = {}
 _memo_lock = threading.Lock()
 
 # Process-wide counters the bench and the warm-ladder tests read.
-stats = {"compiled_s": 0.0, "compiles": 0}
+stats = {"lowered_s": 0.0, "compiled_s": 0.0, "compiles": 0}
 
 
 def _key(name: str, example_args: Tuple[Any, ...],
@@ -89,22 +92,26 @@ def cached_compile(name: str, fn: Callable, example_args: Tuple[Any, ...],
     if hit is not None:
         return hit
 
+    from dsi_tpu.obs import span
     from dsi_tpu.utils.jaxcompat import enable_x64
 
     jitted = jax.jit(fn, static_argnames=tuple(static),
                      donate_argnums=donate_argnums)
-    t0 = time.perf_counter()
     x64_scope = enable_x64(True) if x64 else contextlib.nullcontext()
     # The first device is the default while lowering: the single-chunk
     # kernels are one-device programs by design, also in a multi-device
     # process; programs that carry their own mesh (the shard_map steps)
     # are unaffected.
     with jax.default_device(jax.devices()[0]), x64_scope:
-        compiled = jitted.lower(*example_args, **static).compile()
-    dt = time.perf_counter() - t0
-    stats["compiled_s"] += dt
+        with span("lower", lane="host", stats=stats, key="lowered_s",
+                  program=name) as low:
+            lowered = jitted.lower(*example_args, **static)
+        with span("compile", lane="host", stats=stats, key="compiled_s",
+                  program=name) as comp:
+            compiled = lowered.compile()
     stats["compiles"] += 1
-    _log(f"{name}: compiled in {dt:.1f}s")
+    _log(f"{name}: compiled in {low.elapsed_s + comp.elapsed_s:.1f}s "
+         f"(lower {low.elapsed_s:.2f}s, compile {comp.elapsed_s:.2f}s)")
     with _memo_lock:
         _memo[key] = compiled
     return compiled

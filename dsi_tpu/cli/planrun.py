@@ -269,6 +269,189 @@ def _run_hosts(args, spec: dict, plan, mesh):
     return res, dict(sc)
 
 
+def _job(args, pstats: dict, opened: list):
+    """The job between its parsed arguments and its ``--stats`` line:
+    ``(exit code, what --stats-json and --check read after it)``.
+    ``pstats`` fills as ``--stats`` prints it."""
+    from dsi_tpu.obs import span
+
+    with span("start", lane="host", stats=pstats):
+        if args.chain == "sort":
+            # Not a truncated sort: refused before any stage (and before
+            # JAX is imported: a record is ops/sortk.RECORD_BYTES long).
+            for path in args.files:
+                if os.path.getsize(path) % 100:
+                    print(f"planrun: {path}: {os.path.getsize(path)} bytes "
+                          "is not a whole number of 100-byte records",
+                          file=sys.stderr)
+                    return 1, None
+
+        from dsi_tpu.utils.platformpin import require_device
+
+        require_device("planrun")
+
+        from dsi_tpu.ckpt import CheckpointMismatch
+        from dsi_tpu.ops.fieldsum import BadRow
+        from dsi_tpu.parallel.shuffle import default_mesh
+        from dsi_tpu.plan import PlanHostPath, run_plan
+        from dsi_tpu.plan.stagehost import build_plan
+
+        mesh = default_mesh(args.devices)
+        spec = _plan_spec(args)
+
+        def build():
+            plan = build_plan(spec)
+            if args.chain == "indexer":
+                opened.append(plan.param(plan["indexer"], "docs"))
+            return plan
+
+        read_ahead = args.chain == "indexer" and not args.hosts
+        plan = None if read_ahead else build()
+
+    stats: dict = {}
+    try:
+        if read_ahead:
+            # What the chain's job pays for its documents before its
+            # first stage: their lengths.  Their bytes are read ahead of
+            # the walk (``read_wait_s`` is what the walk waits); the
+            # other chains' stages read theirs.
+            with span("read", lane="host", stats=pstats, key="read_s",
+                      files=len(args.files)):
+                plan = build()
+        if args.hosts:
+            res, stats = _run_hosts(args, spec, plan, mesh)
+        else:
+            res = run_plan(plan, mesh=mesh, staged=args.staged,
+                           checkpoint_dir=args.checkpoint_dir,
+                           resume=args.resume, pipelined=args.pipeline,
+                           stage_shards=args.stage_shards, stats=stats)
+    except CheckpointMismatch as e:
+        print(f"planrun: {e}", file=sys.stderr)
+        return 1, None
+    except OSError as e:
+        if args.chain != "indexer" or args.hosts:
+            raise
+        # A document that cannot be read, or is not the bytes its
+        # length was taken from: nothing is committed.
+        print(f"planrun: {e}", file=sys.stderr)
+        return 1, None
+    except BadRow as e:
+        # --chain agg: a row that cannot be read fails the job.
+        print(f"planrun: {e}", file=sys.stderr)
+        return 1, None
+    except PlanHostPath as e:
+        # The chain contract is device-resident intermediates; a
+        # host-path input breaks it loudly — run the standalone engines
+        # (wcstream/grepstream) for such inputs.
+        print(f"planrun: {e}", file=sys.stderr)
+        return 1, None
+    except RuntimeError as e:
+        # --hosts orchestration failures (stage host died, deadline,
+        # share-nothing audit) — loud, nonzero, no partial artifacts.
+        if not args.hosts:
+            raise
+        print(f"planrun: {e}", file=sys.stderr)
+        return 1, None
+
+    committed = None  # what goes out as mr-out-<r>: a merged table
+    with span("report", lane="host", stats=pstats):
+        if args.resume:
+            print(f"planrun: resumed past "
+                  f"{stats.get('plan_resumed_stages', 0)} committed "
+                  f"stage(s)", file=sys.stderr)
+        for name, wall in stats.get("plan_stage_walls", {}).items():
+            print(f"planrun: stage {name}: {wall}s", file=sys.stderr)
+        print(f"planrun: handoff={stats.get('plan_handoff')} "
+              f"intermediate_bytes={stats.get('plan_intermediate_bytes')} "
+              f"commit_bytes={stats.get('plan_commit_bytes')}",
+              file=sys.stderr)
+
+        os.makedirs(args.workdir, exist_ok=True)
+        # What --stats prints as pipeline_stats: the engines' scopes per
+        # stage, the plan scope, and the commit below.
+        pstats.update(stages=stats.get("stage_stats", {}),
+                      plan={k: v for k, v in stats.items()
+                            if k != "stage_stats"})
+        if "read_s" in pstats:
+            ahead = opened[0].stats
+            pstats["read_s"] = round(pstats["read_s"], 4)
+            pstats.update(ahead,
+                          read_wait_s=round(ahead["read_wait_s"], 4))
+        if args.chain == "grep-wc":
+            g = res.results["grep"]
+            print(f"planrun: grep lines={g.lines} matched={g.matched} "
+                  f"occurrences={g.occurrences}", file=sys.stderr)
+            committed = res.final
+        elif args.chain == "agg":
+            committed = res.final
+            print(f"planrun: {stats['stage_stats']['agg']['agg_rows']} rows "
+                  f"in {len(committed)} groups -> {args.workdir}/mr-out-0.."
+                  f"{args.nreduce - 1}", file=sys.stderr)
+        elif args.chain == "indexer":
+            # The table the join stage grouped, its documents named as
+            # the host app names them (mrsequential in the files'
+            # directory).
+            committed = res.index.named(
+                [os.path.basename(path) for path in args.files])
+    if args.chain == "sort":
+        from dsi_tpu.parallel.sortstream import write_sorted_output
+
+        with span("write", lane="host", stats=pstats,
+                  keys=res.final.records) as sp:
+            paths = write_sorted_output(res.final, args.workdir,
+                                        stats=pstats)
+            sp.set(bytes=sum(os.path.getsize(path) for path in paths))
+        pstats["write_s"] = round(pstats["write_s"], 4)
+    if committed is not None:
+        from dsi_tpu.parallel.shuffle import write_partitioned_output
+
+        with span("write", lane="host", stats=pstats,
+                  keys=len(committed)) as sp:
+            paths = write_partitioned_output(committed, args.nreduce,
+                                             args.workdir, stats=pstats)
+            sp.set(bytes=sum(os.path.getsize(path) for path in paths))
+        for key in ("write_s", "write_format_s", "write_commit_s"):
+            pstats[key] = round(pstats[key], 4)
+    with span("report", lane="host", stats=pstats):
+        if args.chain == "sort":
+            print(f"planrun: {res.final.records} records in key order -> "
+                  f"{args.workdir}/mr-out-0..{args.nreduce - 1}",
+                  file=sys.stderr)
+        elif args.chain == "grep-grep":
+            stages = {name: {"lines": r.lines, "matched": r.matched,
+                             "occurrences": r.occurrences}
+                      for name, r in res.results.items()}
+            path = os.path.join(args.workdir, "plan-grep.json")
+            # dsicheck: allow[raw-write] report artifact, not durable state
+            with open(path, "w", encoding="utf-8") as f:
+                json.dump(stages, f, sort_keys=True, indent=1)
+            g2 = res.final
+            print(f"planrun: cascade matched={g2.matched} "
+                  f"occurrences={g2.occurrences} -> {path}",
+                  file=sys.stderr)
+        elif args.chain == "wc-topk":
+            path = os.path.join(args.workdir, "plan-topk.json")
+            # dsicheck: allow[raw-write] report artifact, not durable state
+            with open(path, "w", encoding="utf-8") as f:
+                json.dump({"topk": [[int(c), w] for c, w in res.final]},
+                          f, sort_keys=True, indent=1)
+            print(f"planrun: top-{len(res.final)} words -> {path}",
+                  file=sys.stderr)
+        elif args.chain == "indexer":
+            out = {w: {"df": df, "part": part, "docs": list(docs)}
+                   for w, (df, part, docs) in res.final.items()}
+            path = os.path.join(args.workdir, "plan-join.json")
+            # dsicheck: allow[raw-write] report artifact, not durable state
+            with open(path, "w", encoding="utf-8") as f:
+                json.dump({"topk": [[c, w] for c, w in
+                                    res.results.get("dftopk", ())],
+                           "join": out}, f, sort_keys=True, indent=1)
+            print(f"planrun: index of {len(committed)} terms -> "
+                  f"{args.workdir}/mr-out-*; join of {len(out)} terms -> "
+                  f"{path}", file=sys.stderr)
+    return 0, (stats, res, mesh, build)
+
+
 def main(argv=None) -> int:
     # The indexer chain's documents are read by a pool of threads
     # (``ioread.ReadAheadDocs``); this process runs job after job, and
@@ -422,178 +605,20 @@ def _main(argv, opened: list) -> int:
 
         configure_tracing(trace_dir=args.trace_dir)
 
-    if args.chain == "sort":
-        # Not a truncated sort: refused before any stage (and before
-        # JAX is imported: a record is ops/sortk.RECORD_BYTES long).
-        for path in args.files:
-            if os.path.getsize(path) % 100:
-                print(f"planrun: {path}: {os.path.getsize(path)} bytes is "
-                      "not a whole number of 100-byte records",
-                      file=sys.stderr)
-                return 1
+    from dsi_tpu.obs import span
+    from dsi_tpu.obs.registry import plan_job_children_s
 
-    from dsi_tpu.utils.platformpin import require_device
-
-    require_device("planrun")
-
-    from dsi_tpu.ckpt import CheckpointMismatch
-    from dsi_tpu.ops.fieldsum import BadRow
-    from dsi_tpu.parallel.shuffle import default_mesh
-    from dsi_tpu.plan import PlanHostPath, run_plan
-    from dsi_tpu.plan.stagehost import build_plan
-
-    mesh = default_mesh(args.devices)
-    spec = _plan_spec(args)
-
-    def build():
-        plan = build_plan(spec)
-        if args.chain == "indexer":
-            opened.append(plan.param(plan["indexer"], "docs"))
-        return plan
-
-    stats: dict = {}
-    read_stats: dict = {}
-    try:
-        if args.hosts:
-            res, stats = _run_hosts(args, spec, build(), mesh)
-        else:
-            if args.chain == "indexer":
-                # What the chain's job pays for its documents before
-                # its first stage: their lengths.  Their bytes are read
-                # ahead of the walk (``read_wait_s`` is what the walk
-                # waits); the other chains' stages read theirs.
-                from dsi_tpu.obs import span
-
-                with span("read", lane="host", stats=read_stats,
-                          key="read_s", files=len(args.files)):
-                    plan = build()
-            else:
-                plan = build()
-            res = run_plan(plan, mesh=mesh, staged=args.staged,
-                           checkpoint_dir=args.checkpoint_dir,
-                           resume=args.resume, pipelined=args.pipeline,
-                           stage_shards=args.stage_shards, stats=stats)
-    except CheckpointMismatch as e:
-        print(f"planrun: {e}", file=sys.stderr)
-        return 1
-    except OSError as e:
-        if args.chain != "indexer" or args.hosts:
-            raise
-        # A document that cannot be read, or is not the bytes its
-        # length was taken from: nothing is committed.
-        print(f"planrun: {e}", file=sys.stderr)
-        return 1
-    except BadRow as e:
-        # --chain agg: a row that cannot be read fails the job.
-        print(f"planrun: {e}", file=sys.stderr)
-        return 1
-    except PlanHostPath as e:
-        # The chain contract is device-resident intermediates; a
-        # host-path input breaks it loudly — run the standalone engines
-        # (wcstream/grepstream) for such inputs.
-        print(f"planrun: {e}", file=sys.stderr)
-        return 1
-    except RuntimeError as e:
-        # --hosts orchestration failures (stage host died, deadline,
-        # share-nothing audit) — loud, nonzero, no partial artifacts.
-        if not args.hosts:
-            raise
-        print(f"planrun: {e}", file=sys.stderr)
-        return 1
-
-    if args.resume:
-        print(f"planrun: resumed past "
-              f"{stats.get('plan_resumed_stages', 0)} committed "
-              f"stage(s)", file=sys.stderr)
-    for name, wall in stats.get("plan_stage_walls", {}).items():
-        print(f"planrun: stage {name}: {wall}s", file=sys.stderr)
-    print(f"planrun: handoff={stats.get('plan_handoff')} "
-          f"intermediate_bytes={stats.get('plan_intermediate_bytes')} "
-          f"commit_bytes={stats.get('plan_commit_bytes')}",
-          file=sys.stderr)
-
-    os.makedirs(args.workdir, exist_ok=True)
-    # What --stats prints as pipeline_stats: the engines' scopes per
-    # stage, the plan scope, and the commit below.
-    pstats = {"stages": stats.get("stage_stats", {}),
-              "plan": {k: v for k, v in stats.items()
-                       if k != "stage_stats"}}
-    if "read_s" in read_stats:
-        ahead = opened[0].stats
-        pstats["read_s"] = round(read_stats["read_s"], 4)
-        pstats.update(ahead, read_wait_s=round(ahead["read_wait_s"], 4))
-    committed = None  # what goes out as mr-out-<r>: a merged table
-    if args.chain == "grep-wc":
-        g = res.results["grep"]
-        print(f"planrun: grep lines={g.lines} matched={g.matched} "
-              f"occurrences={g.occurrences}", file=sys.stderr)
-        committed = res.final
-    elif args.chain == "agg":
-        committed = res.final
-        print(f"planrun: {stats['stage_stats']['agg']['agg_rows']} rows in "
-              f"{len(committed)} groups -> {args.workdir}/mr-out-0.."
-              f"{args.nreduce - 1}", file=sys.stderr)
-    elif args.chain == "indexer":
-        # The table the join stage grouped, its documents named as the
-        # host app names them (mrsequential in the files' directory).
-        committed = res.index.named(
-            [os.path.basename(path) for path in args.files])
-    elif args.chain == "sort":
-        from dsi_tpu.obs import span
-        from dsi_tpu.parallel.sortstream import write_sorted_output
-
-        with span("write", lane="host", stats=pstats,
-                  keys=res.final.records) as sp:
-            paths = write_sorted_output(res.final, args.workdir,
-                                        stats=pstats)
-            sp.set(bytes=sum(os.path.getsize(path) for path in paths))
-        pstats["write_s"] = round(pstats["write_s"], 4)
-        print(f"planrun: {res.final.records} records in key order -> "
-              f"{args.workdir}/mr-out-0..{args.nreduce - 1}",
-              file=sys.stderr)
-    if committed is not None:
-        from dsi_tpu.obs import span
-        from dsi_tpu.parallel.shuffle import write_partitioned_output
-
-        with span("write", lane="host", stats=pstats,
-                  keys=len(committed)) as sp:
-            paths = write_partitioned_output(committed, args.nreduce,
-                                             args.workdir, stats=pstats)
-            sp.set(bytes=sum(os.path.getsize(path) for path in paths))
-        for key in ("write_s", "write_format_s", "write_commit_s"):
-            pstats[key] = round(pstats[key], 4)
-    if args.chain == "grep-grep":
-        stages = {name: {"lines": r.lines, "matched": r.matched,
-                         "occurrences": r.occurrences}
-                  for name, r in res.results.items()}
-        path = os.path.join(args.workdir, "plan-grep.json")
-        # dsicheck: allow[raw-write] report artifact, not durable state
-        with open(path, "w", encoding="utf-8") as f:
-            json.dump(stages, f, sort_keys=True, indent=1)
-        g2 = res.final
-        print(f"planrun: cascade matched={g2.matched} "
-              f"occurrences={g2.occurrences} -> {path}", file=sys.stderr)
-    elif args.chain == "wc-topk":
-        path = os.path.join(args.workdir, "plan-topk.json")
-        # dsicheck: allow[raw-write] report artifact, not durable state
-        with open(path, "w", encoding="utf-8") as f:
-            json.dump({"topk": [[int(c), w] for c, w in res.final]},
-                      f, sort_keys=True, indent=1)
-        print(f"planrun: top-{len(res.final)} words -> {path}",
-              file=sys.stderr)
-    elif args.chain == "indexer":
-        out = {w: {"df": df, "part": part, "docs": list(docs)}
-               for w, (df, part, docs) in res.final.items()}
-        path = os.path.join(args.workdir, "plan-join.json")
-        # dsicheck: allow[raw-write] report artifact, not durable state
-        with open(path, "w", encoding="utf-8") as f:
-            json.dump({"topk": [[c, w] for c, w in
-                                res.results.get("dftopk", ())],
-                       "join": out}, f, sort_keys=True, indent=1)
-        print(f"planrun: index of {len(committed)} terms -> "
-              f"{args.workdir}/mr-out-*; join of {len(out)} terms -> "
-              f"{path}", file=sys.stderr)
-
+    # The root of the main thread's account, as in wcstream: its direct
+    # children (the registry's PLAN_JOB_CHILDREN) cover it.
+    pstats: dict = {}
+    with span("job", lane="host", stats=pstats):
+        rc, ran = _job(args, pstats, opened)
+    if rc:
+        return rc
+    stats, res, mesh, build = ran
+    for key in ("job_s", "start_s", "report_s"):
+        pstats[key] = round(pstats[key], 4)
+    pstats["job_children_s"] = round(plan_job_children_s(pstats), 4)
     # After the commit, so that the line holds the job's tail too
     # (write_s) and the trace its last span.
     if args.stats:
@@ -614,6 +639,8 @@ def _main(argv, opened: list) -> int:
         # topk sample, so parity only holds shard-geometry-to-like.
         # Against --hosts the twin is the in-process chained run — the
         # net-served relays must reproduce it bit-identically.
+        from dsi_tpu.plan import run_plan
+
         twin_staged = False if args.hosts else not args.staged
         twin = run_plan(build(), mesh=mesh, staged=twin_staged,
                         stage_shards=args.stage_shards)

@@ -46,7 +46,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dsi_tpu.obs import span as _span
+from dsi_tpu.obs import enqueued as _enqueued, span as _span
 from dsi_tpu.ops.meshroute import compact_received, exchange_rows, route_dest
 from dsi_tpu.ops.wordcount import _PAD_KEY
 from dsi_tpu.parallel.shuffle import AXIS, occupied_prefix
@@ -239,6 +239,7 @@ class DevicePostings:
             self._buf, self._n, self._dirty, flags = _append_step(
                 self._buf, self._n, self._dirty, rows_dev, scal_dev,
                 mesh=self.mesh)
+        _enqueued(flags)
         return flags
 
     def append(self, rows_dev, scal_dev, nvalid=None) -> None:

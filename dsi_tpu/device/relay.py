@@ -49,7 +49,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dsi_tpu.obs import count as _count, span as _span
+from dsi_tpu.obs import count as _count, enqueued as _enqueued, \
+    span as _span
 from dsi_tpu.parallel.shuffle import AXIS
 from dsi_tpu.utils.jaxcompat import shard_map
 
@@ -184,6 +185,7 @@ class DeviceRelay:
             off = jax.device_put(self._lens.astype(np.int32), self._sh1)
             fn = _pack_fn(self.aot, mesh=self.mesh, cap=self.cap)
             self._acc = fn(self._acc, off, comp_dev)
+            _enqueued(self._acc)
             self._lens += kept
         self._maybe_spill()
         return sealed

@@ -96,7 +96,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dsi_tpu.obs import span as _span, trace_event as _trace_event
+from dsi_tpu.obs import enqueued as _enqueued, span as _span, \
+    trace_event as _trace_event
 from dsi_tpu.ops.meshroute import exchange_rows, route_dest
 from dsi_tpu.ops.wordcount import _PAD_KEY, group_sorted, lex_sort
 from dsi_tpu.parallel.shuffle import AXIS, occupied_prefix
@@ -806,6 +807,7 @@ class DeviceTable:
             with _quiet_unusable_donation():
                 *state, flags = fn(*self._state, packed_dev, scal_dev)
         self._state = tuple(state)
+        _enqueued(flags)
         return flags
 
     def _note_flags(self, flags_np: np.ndarray) -> None:
@@ -817,9 +819,17 @@ class DeviceTable:
                 self.stats["shard_imbalance"] = round(
                     float(occ.max()) * self.mesh_shards / tot, 3)
 
+    def _landed(self, *arrs) -> list:
+        """``arrs`` on the host: blocked until the device has made them
+        (a fold's flags, the packed table), the part of ``fold_s``,
+        ``sync_s`` and ``widen_s`` that is the device's."""
+        with _span("wait", lane="sync", stats=self.stats,
+                   key="sync_wait_s"):
+            return [np.asarray(a) for a in arrs]
+
     def _confirm_oldest(self) -> None:
         flags, packed_dev, scal_dev = self._pending.popleft()
-        flags_np = np.asarray(flags)  # blocks until this fold lands
+        (flags_np,) = self._landed(flags)  # until this fold lands
         self._note_flags(flags_np)
         if flags_np[:, 0].any():
             self.stats["fold_overflows"] += 1
@@ -832,7 +842,7 @@ class DeviceTable:
         orphans = []
         while self._pending:
             flags, packed_dev, scal_dev = self._pending.popleft()
-            flags_np = np.asarray(flags)
+            (flags_np,) = self._landed(flags)
             self._note_flags(flags_np)
             if flags_np[:, 0].any():
                 self.stats["fold_overflows"] += 1
@@ -921,6 +931,7 @@ class DeviceTable:
             keep_dev = self._put_apply(np.asarray(keep, np.int32))
             with _quiet_unusable_donation():
                 self._state = tuple(fn(*self._state, keep_dev))
+            _enqueued(self._state[-1])
             self.cap = new_cap
             self._nrows[drain] = 0
         self.stats["widens"] += 1
@@ -967,6 +978,7 @@ class DeviceTable:
             tkeys, tlens, tcnts, tparts, _ = self._state
             packed_dev, cnts_dev = self._pack_fn(mp)(tkeys, tlens, tparts,
                                                      tcnts)
+            _enqueued(cnts_dev)
             _copy_to_host_async(packed_dev)
             _copy_to_host_async(cnts_dev)
         else:
@@ -1025,6 +1037,7 @@ class DeviceTable:
             mp = occupied_prefix(int(nus.max()),
                                  int(packed_dev.shape[1]))
             sliced = _rows_prefix(packed_dev, mp=mp)
+            _enqueued(sliced)
             _copy_to_host_async(sliced)
             entries.append((sliced, nus))
         self._delta_log.clear()
@@ -1075,8 +1088,7 @@ class DeviceTable:
         tkeys, tlens, tcnts, tparts, _ = self._state
         packed_dev, cnts_dev = self._pack_fn(mp)(tkeys, tlens, tparts, tcnts)
         if only is None:
-            packed = np.asarray(packed_dev)
-            cnts = np.asarray(cnts_dev)
+            packed, cnts = self._landed(packed_dev, cnts_dev)
             self.stats["pull_bytes"] += packed.nbytes + cnts.nbytes
             for d in range(self.n_dev):
                 n = int(self._nrows[d])
@@ -1132,6 +1144,7 @@ class DeviceTable:
                 self.stats["sync_pulls"] += 1
                 with _quiet_unusable_donation():
                     self._state = tuple(self._clear_fn()(*self._state))
+                _enqueued(self._state[-1])
                 self._nrows[:] = 0
         return pulled
 

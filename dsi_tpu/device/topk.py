@@ -64,7 +64,7 @@ from dsi_tpu.device.table import (
     _step_structs,
     _table_structs,
 )
-from dsi_tpu.obs import span as _span
+from dsi_tpu.obs import enqueued as _enqueued, span as _span
 from dsi_tpu.parallel.shuffle import AXIS
 from dsi_tpu.utils.jaxcompat import enable_x64, x64_scoped
 
@@ -361,6 +361,7 @@ class DeviceHistogram:
                    key="hist_s"):
             with _quiet_unusable_donation():
                 self._state = self._fold_fn()(self._state, step_dev)
+            _enqueued(self._state)
             self.stats["hist_folds"] += 1
 
     def _premerge_fn(self):

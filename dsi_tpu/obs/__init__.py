@@ -56,6 +56,7 @@ from dsi_tpu.obs.trace import (
     Tracer,
     configure,
     count,
+    enqueued,
     event,
     flush,
     get_tracer,
@@ -100,6 +101,7 @@ __all__ = [
     "configure",
     "configure_tracing",
     "count",
+    "enqueued",
     "event",
     "flush",
     "flush_tracing",

@@ -77,7 +77,40 @@ that phase):
   the main thread are :data:`JOB_CHILDREN`, and ``job_children_s`` is
   the sum of their keys (``job_s`` less it is the root's self time)
 * ``start_s``            — the ``start`` span: the device gate, the
-  mesh, the engine's construction up to the pipeline armed
+  mesh, the engine's construction up to the pipeline armed (in
+  ``planrun``: the plan's construction too, where no ``read`` span
+  holds it)
+* ``report_s``           — ``planrun``'s ``report`` spans: the stage and
+  handoff lines, ``named()``, ``plan-join.json`` / ``plan-grep.json`` /
+  ``plan-topk.json``.  ``planrun`` prints ``job_s``, ``start_s``,
+  ``report_s`` and ``job_children_s`` at the top of its
+  ``pipeline_stats``; its root's children are
+  :data:`PLAN_JOB_CHILDREN`
+* ``starved_s``          — the starvation account of a job's main thread
+  (``obs/trace.py``, "The starvation account"): seconds of ``job_s``
+  in pieces through which the chip had nothing queued, or ran out, or
+  started with nothing (an upper bound of the chip's idle time as the
+  host sees it); ``starved_dry_s`` is the part with nothing queued at
+  both ends and nothing enqueued between (the lower bound), and
+  ``starved_unseen_s`` the seconds in pieces at an end of which
+  something was in flight and the account's budget allowed no look
+  (neither fed nor starved: where it is a large part of ``job_s`` the
+  account did not run, and ``results_ready`` over ``steps`` is the
+  reading).
+  ``starved_by`` maps a span name to the starved seconds charged to it
+  as the innermost open span (``job`` for the root's own time), and
+  ``starved_groups`` sums them over :data:`STARVED_GROUPS` (``input``,
+  ``dispatch``, ``merge``, ``tail``: the four sum to ``starved_s``).  A
+  span of :data:`DEVICE_BLOCKED` is never starved.  At the top of the
+  ``pipeline_stats`` of ``wcstream``, ``grepstream`` and ``planrun``
+* ``sync_wait_s``        — inside ``fold_s``, ``sync_s`` and ``widen_s``
+  of a device table (``device/table.py``): the ``wait`` spans in which
+  the host is blocked on a fold's flags or on the packed table's copy
+* ``lowered_s`` / ``compiled_s`` — ``backends/aotcache.cached_compile``'s
+  two halves: the ``lower`` span (tracing and lowering to StableHLO) and
+  the ``compile`` span (the backend compile, or the persistent cache's
+  load), summed over the programs this process compiled explicitly
+  (``aotcache.stats``, which is the process's and no engine's scope)
 * ``dispatch_s`` / ``retire_s`` — the pipeline core's ``dispatch`` and
   ``finish`` spans (``finish_s`` is the daemon's job-finish key), one
   each a step; ``upload_s`` and ``enqueue_s`` are inside the first,
@@ -102,9 +135,12 @@ that phase):
   (the NEXT save or the end-of-stream drain found a commit in flight)
 
 Counters / gauges: ``steps`` (or ``waves``), ``depth``, ``replays``,
-``results_ready`` (steps whose host reads the device had already
-produced when ``finish`` first asked: the hit count of a copy started
-at dispatch), ``step_pulls`` and its two kinds in the word-count stream
+``results_ready`` (steps whose programs the device had already run
+when ``finish`` came to retire them: the pipeline core asks the array
+the step's dispatch told the tracer, ``obs.enqueued``, without
+blocking, for every engine on ``StepPipeline`` but the sort's ingest
+loop, whose step is shorter than a hundred looks:
+``count_ready=False``), ``step_pulls`` and its two kinds in the word-count stream
 engine, ``pulls_early`` (served by the tensor packed when the step was
 dispatched) and ``pulls_late`` (served by a pack enqueued at retirement:
 a step whose table outgrew the predicted prefix, or a replay's payload;
@@ -333,6 +369,15 @@ PHASE_KEYS = (
     # the sort chain (parallel/sortstream.py): the sampling pre-pass, and
     # the host blocked on the device's ordering of the resident store
     "sample_s", "order_s",
+    # planrun's root: what it says to stderr and writes beside mr-out-*
+    "report_s",
+    # the starvation account of a job's main thread (obs/trace.py)
+    "starved_s", "starved_dry_s", "starved_unseen_s", "starved_by",
+    "starved_groups",
+    # a device table's folds and syncs, blocked on the device
+    "sync_wait_s",
+    # backends/aotcache.cached_compile's two halves
+    "lowered_s", "compiled_s",
 )
 
 #: The direct children of a stream command's root ``job`` span on its
@@ -356,6 +401,58 @@ def job_children_s(stats: dict) -> float:
         # main thread and are children of ``job`` as well.
         keys.append("batch_s")
     return sum(stats.get(key, 0.0) for key in keys)
+
+#: The direct children of ``planrun``'s root ``job`` span, as
+#: :data:`JOB_CHILDREN` are a stream command's: ``read`` only in the
+#: indexer chain, ``plan`` one a stage (its key in the plan scope),
+#: ``stage_commit`` only under ``--checkpoint-dir`` (the plan scope's too).
+PLAN_JOB_CHILDREN = (
+    ("start", "start_s"), ("read", "read_s"), ("plan", "plan_s"),
+    ("write", "write_s"), ("report", "report_s"),
+    ("stage_commit", "stage_commit_s"),
+)
+
+
+def plan_job_children_s(pstats: dict) -> float:
+    """Seconds in the direct children of a ``planrun`` job's root span:
+    ``pstats`` is its ``pipeline_stats``, whose ``plan`` group holds the
+    stages' and the stage commits' seconds."""
+    plan = pstats.get("plan", {})
+    return sum(pstats.get(key, plan.get(key, 0.0))
+               for _, key in PLAN_JOB_CHILDREN)
+
+
+#: The spans in which the host is blocked on the device, as ``(name,
+#: lane)``: the starvation account (``obs/trace.py``) charges their time
+#: to ``fed`` without asking.  ``wait`` in the ``materialize`` lane, the
+#: step loop's wait for its producer, is not one of them.
+DEVICE_BLOCKED = (
+    ("kernel", "kernel"), ("wait", "pull"), ("d2h", "pull"),
+    ("order", "kernel"), ("wait", "sync"),
+)
+
+#: ``starved_groups``: every span name in one of four groups, by what the
+#: main thread was doing while the chip had nothing queued.  ``input``:
+#: before a step can be cut (the start, the reads, the step loop's wait
+#: for its producer, a stage's construction: ``plan``'s own time);
+#: ``dispatch``: a step on its way to the device; ``merge``: a step's
+#: retirement and the host's part of the device services; ``tail``:
+#: after the last step, and the root's own time.  A name that is not
+#: listed counts under ``tail``.
+STARVED_GROUPS = (
+    ("input", ("start", "read", "read_wait", "sample", "wait",
+               "materialize", "pack", "plan", "probe", "backend_init",
+               "launch", "lower", "compile")),
+    ("dispatch", ("dispatch", "upload", "enqueue", "relay_append",
+                  "relay_spill")),
+    ("merge", ("finish", "pull", "merge", "compact", "replay", "fold",
+               "sync", "widen", "group", "ckpt", "ckpt_capture",
+               "ckpt_commit", "ckpt_save", "ckpt_restore", "shuffle",
+               "append", "hist_fold", "hist_pull", "kernel", "d2h",
+               "order")),
+    ("tail", ("drain", "finalize", "decode", "write", "format", "commit",
+              "report", "stage_commit", "stage_overlap", "resplit", "job")),
+)
 
 #: The canonical counter/gauge keys (module docstring) — previously
 #: prose; now machine-readable because the ``metric-schema`` dsicheck

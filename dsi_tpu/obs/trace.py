@@ -29,6 +29,9 @@ layer writes into:
   when tracing is enabled, at most once a second under a root span, and
   at flush, and the JSONL head carries ``wall0_ns``: the offset between
   this file's clock and the profiler's is recoverable from either.
+* **the starvation account** — whether the chip had work queued, cut at
+  the span boundaries of a job's main thread (below, "The starvation
+  account"): it runs with tracing off, in the spans that have a sink.
 * **events** — ``tracer.event("requeue", ...)`` instant records (the
   control-plane lane).
 * **counters** — ``tracer.count("steps")`` monotonic counters, emitted
@@ -47,6 +50,58 @@ survives the same crashes the checkpoints do):
   (tid) per pipeline stage (materialize/upload/dispatch/kernel/pull/
   merge/replay/fold/sync/widen/ckpt) plus the control-plane lane; load
   it at https://ui.perfetto.dev or chrome://tracing.
+
+## The starvation account
+
+A job is waiting for its chip, or the chip for the job.  The device's
+profiler tells which, from one traced job and slowed by tracing it; the
+program can tell in every job, because every device program it enqueues
+passes through a handful of sites, the device runs them in order, and
+``is_ready()`` on the newest one's result says without blocking whether
+anything is left.
+
+* **what is in flight** — a site that enqueues a device program whose
+  result the host does not at once block on calls :func:`enqueued` with
+  an array that program produces.  One slot, the newest: its result
+  ready means every earlier program's is (on a mesh, ready on every
+  chip).  ``StepPipeline`` keeps each step's own beside its record and
+  asks it at ``finish`` (:meth:`Tracer.landed`, the ``results_ready``
+  counter).
+* **the cut** — a root ``job`` span opens the account on its thread and
+  closes it.  At the enter and the exit of every span that records on
+  that thread (with tracing off: every span with a ``stats`` sink) the
+  piece since the previous boundary is charged to the innermost span
+  that was open through it, as ``dry`` (nothing was in flight when it
+  began and nothing was enqueued in it), ``fed`` (something was in flight
+  at both ends) or ``ran dry`` (the chip ran out inside it, or began
+  it with nothing).  A span of ``registry.DEVICE_BLOCKED`` is fed by
+  definition and is not asked about.  Other threads' spans are not cut:
+  the account is that of the thread that feeds the chip; their
+  ``enqueued`` calls count, whoever feeds it.
+* **what it costs** — nothing is asked while nothing is in flight, and
+  a result once seen ready is not asked again: a stretch paced by the
+  host costs one ``is_ready()`` a dispatch.  Asking has a budget,
+  ``_LOOK_SHARE`` of the wall: every look is timed, and after one that
+  took ``d`` seconds the next is not before ``d / _LOOK_SHARE`` later
+  (a look costs 0.25 us where the backend has the answer at hand and
+  tens of us where it has to make it: PERF.md §6), whoever asked, the
+  account or ``StepPipeline``.  At a boundary at which something is
+  in flight and no look is due, the chip is taken for busy without
+  being seen; a piece taken for fed with neither end seen is counted
+  in ``starved_unseen_s``: the seconds the account vouches for on
+  trust alone, next to nothing where a look is cheap and most of a
+  step loop where it is dear.
+  What it can miss besides is whatever is shorter than the backend
+  takes to show a result ready (0.6 ms after the enqueue on a TPU v5e:
+  PERF.md §6).  A boundary costs about half a microsecond of Python.
+* **what a job gets** — at the root's exit its sink receives
+  ``starved_s`` (ran dry + dry), ``starved_dry_s``, ``starved_by``
+  (span name → seconds), ``starved_groups`` (``registry.
+  STARVED_GROUPS``) and ``starved_unseen_s``; under the root the pieces
+  sum to ``job_s``, and the chip's idle time as the host can see it
+  lies between ``starved_dry_s`` and ``starved_s`` +
+  ``starved_unseen_s``.  With tracing on, a span record carries
+  ``dry``, its own starved seconds.
 
 The process-global tracer (:func:`get_tracer`) is enabled by
 ``DSI_TRACE_DIR=<dir>`` (buffer + durable flush at exit — how
@@ -68,6 +123,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 import dsi_tpu.obs.hist as _hist
+from dsi_tpu.obs.registry import DEVICE_BLOCKED, STARVED_GROUPS
 
 #: Span names recorded into the live stage histograms when the
 #: telemetry plane is active (obs/hist.py owns the pinned set).
@@ -123,7 +179,21 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # the record files, and the host blocked on the device's ordering of
     # the resident store
     "sample", "order",
+    # planrun's root (cli/planrun.py): what the job says to stderr and
+    # writes beside mr-out-* (the stage lines, named(), plan-*.json)
+    "report",
+    # an explicit compile's two halves (backends/aotcache.py)
+    "lower", "compile",
 ))
+
+#: The share of the wall that asking whether a result is ready may cost
+#: (module docstring, "what it costs"): ISSUE 51's budget is 1 % of the
+#: shortest step for the whole account, and the boundaries take the
+#: other half of it.
+_LOOK_SHARE = 0.005
+_BLOCKED = frozenset(DEVICE_BLOCKED)
+_GROUP_OF = {name: group for group, names in STARVED_GROUPS
+             for name in names}
 
 _BUFFER_ENV = "DSI_TRACE_BUFFER_EVENTS"
 _BUFFER_DEFAULT = 500_000
@@ -148,17 +218,118 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+class _Account:
+    """The starvation account of one job's main thread (module
+    docstring).  Its clock is the spans': a boundary is the ``_t0`` or
+    the end a span has just read."""
+
+    __slots__ = ("tr", "thread", "stack", "top", "t_last", "drained",
+                 "seen", "seq", "by", "dry_s", "unseen_s")
+
+    def __init__(self, tr: "Tracer"):
+        self.tr = tr
+        self.thread = None   # armed by the root's enter
+        self.stack: list = []
+        self.top = None      # the innermost open span's entry
+        self.by: Dict[str, float] = {}
+        self.dry_s = self.unseen_s = 0.0
+
+    def _cut(self, now: float, blocked: bool) -> None:
+        """Charge the piece that ends now to the innermost open span.
+        In a blocked one the host was held by the device: fed, whatever
+        follows, and nothing is asked.  Where something is in flight
+        and no look is due, the chip is taken for busy, unseen."""
+        tr = self.tr
+        seq, arr = tr._noted
+        seen = True
+        if seq <= tr._ready_seq:
+            drained = True
+        elif blocked:
+            drained = False
+        elif now >= tr._look_at:
+            drained = tr._look(seq, arr)
+        else:
+            drained = seen = False
+        if not blocked:
+            if drained or self.drained:
+                piece = now - self.t_last
+                self.top[1] += piece
+                if drained and self.drained and seq == self.seq:
+                    self.dry_s += piece
+            elif not (seen or self.seen):
+                self.unseen_s += now - self.t_last
+        self.drained, self.seen = drained, seen
+        self.t_last, self.seq = now, seq
+
+    def enter(self, name: str, lane: str, now: float) -> Optional[list]:
+        tr = self.tr
+        if self.thread is None:  # the root: nothing before it to charge
+            self.thread = threading.get_ident()
+            self.t_last, self.seq = now, tr._noted[0]
+            self.drained = self.seen = self.seq <= tr._ready_seq
+            tr._acct = self
+        elif tr._acct is not self:
+            return None  # a span that outlived its job's root
+        else:
+            self._cut(now, False)
+        self.top = entry = [name, 0.0, (name, lane) in _BLOCKED]
+        self.stack.append(entry)
+        return entry
+
+    def exit(self, entry: Optional[list], now: float,
+             stats: Optional[dict]) -> float:
+        """Close ``entry``; returns its own starved seconds.  The root's
+        exit closes the account and hands ``stats`` the job's."""
+        if self.tr._acct is not self:
+            return 0.0  # as in ``enter``
+        self._cut(now, entry[2])
+        stack = self.stack
+        stack.pop()
+        own = entry[1]
+        if own:
+            self.by[entry[0]] = self.by.get(entry[0], 0.0) + own
+        if stack:
+            self.top = stack[-1]
+        else:
+            self._close(stats)
+        return own
+
+    def _close(self, stats: Optional[dict]) -> None:
+        """The root has closed: hand ``stats`` the job's account."""
+        tr = self.tr
+        tr._acct = None
+        # the job's last array: let go of it, and let the next job begin
+        # with nothing in flight that it could ask about
+        seq = tr._noted[0]
+        tr._noted = (seq, None)
+        tr._ready_seq = seq
+        if stats is None:
+            return
+        groups = dict.fromkeys((g for g, _ in STARVED_GROUPS), 0.0)
+        for name, secs in self.by.items():
+            groups[_GROUP_OF.get(name, "tail")] += secs
+        groups = {g: round(v, 4) for g, v in groups.items()}
+        stats["starved_groups"] = groups
+        stats["starved_s"] = round(sum(groups.values()), 4)
+        stats["starved_dry_s"] = round(self.dry_s, 4)
+        stats["starved_unseen_s"] = round(self.unseen_s, 4)
+        stats["starved_by"] = {n: round(v, 4) for n, v in
+                               sorted(self.by.items()) if v >= 5e-5}
+
+
 class _Span:
     """One live span.  ``tr`` is None when only the stats sink is wanted
-    (tracing disabled but the engine still needs its phase seconds)."""
+    (tracing disabled but the engine still needs its phase seconds);
+    ``acct`` is the starvation account it is a boundary of, if any."""
 
     __slots__ = ("_tr", "name", "lane", "_stats", "_key", "_fields",
                  "_t0", "_depth", "elapsed_s", "id", "_up", "_prev",
-                 "_task", "_ann")
+                 "_task", "_ann", "_acct", "_entry")
 
     def __init__(self, tr: Optional["Tracer"], name: str, lane: str,
                  stats: Optional[dict], key: Optional[str],
-                 fields: Optional[dict], parent=None):
+                 fields: Optional[dict], parent=None,
+                 acct: Optional[_Account] = None):
         self._tr = tr
         self.name = name
         self.lane = lane
@@ -167,6 +338,7 @@ class _Span:
         self._fields = fields
         self.elapsed_s = 0.0
         self.id = None
+        self._acct = acct
         # An explicit parent counts only if it is itself being recorded.
         self._up = parent if getattr(parent, "id", None) is not None \
             else None
@@ -204,13 +376,20 @@ class _Span:
                 self._ann = ann("dsi:" + self.name)
                 self._ann.__enter__()
         self._t0 = time.perf_counter()
+        if self._acct is not None:
+            self._entry = self._acct.enter(self.name, self.lane, self._t0)
         return self
 
     def __exit__(self, *exc) -> bool:
-        dur = time.perf_counter() - self._t0
+        end = time.perf_counter()
+        dur = end - self._t0
         self.elapsed_s = dur
         if self._stats is not None:
             self._stats[self._key] = self._stats.get(self._key, 0.0) + dur
+        if self._acct is not None:
+            dry = self._acct.exit(self._entry, end, self._stats)
+            if dry and self._tr is not None:
+                self.set(dry=round(dry, 6))
         # Stage histogram recording at span close (the tentpole of the
         # live telemetry plane): one module-attribute load when the
         # plane is off, one dict lookup + O(1) bucket bump when on.
@@ -251,6 +430,15 @@ class Tracer:
         #: when the last ``dsi.clock`` mark went out (perf_counter).
         self._ann_cls = None
         self._clock_at = float("-inf")
+        #: The starvation account (module docstring): the newest program
+        #: enqueued, as (ordinal, an array it produces); the ordinal up
+        #: to which results were seen ready; when the next look is due;
+        #: the open account, if a job's root span is.
+        self._seqs = itertools.count(1)
+        self._noted: Tuple = (0, None)
+        self._ready_seq = 0
+        self._look_at = 0.0
+        self._acct: Optional[_Account] = None
         # Construction never DEactivates the histogram plane (another
         # tracer may be feeding it); only an explicit ``enabled=False``
         # assignment does — see the property setter.
@@ -348,14 +536,67 @@ class Tracer:
         without-tracing mode)."""
         if not self.enabled:
             if stats is not None:
-                return _Span(None, name, "", stats,
-                             key or (name + "_s"), None)
+                return _Span(None, name, lane or name, stats,
+                             key or (name + "_s"), None, None,
+                             self._account(name))
             if _hist._active is not None and name in _HOT_STAGES:
                 return _Span(None, name, "", None, None, None)
             return _NOOP_SPAN
         return _Span(self, name, lane or name, stats,
                      (key or (name + "_s")) if stats is not None else None,
-                     fields or None, parent)
+                     fields or None, parent, self._account(name))
+
+    # ── the starvation account (module docstring) ──
+
+    def _account(self, name: str) -> Optional[_Account]:
+        """The account a span opened now, on this thread, is a boundary
+        of: the open one on its own thread; a new one for a root
+        ``job`` (armed when the span is entered); else none."""
+        acct = self._acct
+        if acct is None:
+            return _Account(self) if name == "job" else None
+        return acct if acct.thread == threading.get_ident() else None
+
+    def enqueued(self, arr) -> None:
+        """A device program was enqueued whose result the host does not
+        at once block on; ``arr`` is an array it produces (any one: they
+        are ready together), the smallest to hand."""
+        self._noted = (next(self._seqs), arr)
+
+    @property
+    def enqueued_n(self) -> int:
+        """Ordinal of the newest program :meth:`enqueued` was told."""
+        return self._noted[0]
+
+    def newest(self, since: int) -> Optional[Tuple]:
+        """``(ordinal, array)`` of the newest program enqueued, for
+        :meth:`landed`; None if none was since ordinal ``since``."""
+        seq, arr = self._noted
+        return (seq, arr) if seq > since and arr is not None else None
+
+    def _look(self, seq: int, arr) -> bool:
+        """Ask ``arr``, the result of program ``seq``, whether it is
+        ready, and charge the asking to the budget (``_LOOK_SHARE``).
+        An array that was deleted (donated to a later program) cannot
+        say: the newest one told answers for it, or nobody."""
+        t0 = time.perf_counter()
+        try:
+            ready = bool(arr.is_ready())
+        except RuntimeError:  # "Array has been deleted"
+            newest, newest_arr = self._noted
+            ready = bool(newest > seq and newest_arr is not None
+                         and self._look(newest, newest_arr))
+            seq = newest
+        t1 = time.perf_counter()
+        self._look_at = t1 + (t1 - t0) / _LOOK_SHARE
+        if ready and seq > self._ready_seq:
+            self._ready_seq = seq
+        return ready
+
+    def landed(self, seq: int, arr) -> bool:
+        """Has the device run the program told as ``(seq, arr)``?  Never
+        blocks; a result known ready is not asked again."""
+        return seq <= self._ready_seq or self._look(seq, arr)
 
     def event(self, name: str, /, *, lane: str = "control",
               **fields) -> None:
@@ -601,6 +842,10 @@ def event(name: str, /, **kw) -> None:
 
 def count(name: str, /, n: float = 1, **kw) -> None:
     get_tracer().count(name, n, **kw)
+
+
+def enqueued(arr) -> None:
+    get_tracer().enqueued(arr)
 
 
 def flush() -> Optional[Tuple[str, str]]:

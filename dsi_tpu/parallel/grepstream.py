@@ -98,7 +98,7 @@ from dsi_tpu.device.policy import SyncPolicy, mesh_shards_default
 from dsi_tpu.device.table import (DeviceTable, _copy_to_host_async, _pow2,
                                   _quiet_unusable_donation)
 from dsi_tpu.device.topk import DeviceHistogram, DeviceTopK, KeyCounts
-from dsi_tpu.obs import metrics_scope, span as _span
+from dsi_tpu.obs import enqueued as _enqueued, metrics_scope, span as _span
 from dsi_tpu.ops.grepk import is_literal_pattern
 from dsi_tpu.ops.wordcount import (
     DOC_SEP,
@@ -924,6 +924,7 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             if not emit:
                 outs += (None, None)  # (hist, cand, scal, comp, kept)
             hist_d, cand_d, scal, _, kept_d = outs
+            _enqueued(scal)
             # The results start for the host now, behind the step on the
             # device's queue, not when ``finish_one`` asks for them one
             # pump later: it then reads finished copies instead of paying
@@ -953,11 +954,6 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
     def finish_one(record) -> None:
         buf, row_lines, hist_d, cand_d, scal, comp_d, kept_d, \
             rec_offset, rec_lines = record
-        # Asked before the first read and without blocking: had the
-        # device produced everything this step's host reads convert?
-        if all(arr.is_ready()
-               for arr in host_reads(hist_d, cand_d, scal, kept_d)):
-            stats["results_ready"] += 1
         with _span("kernel", stats=stats, key="kernel_s"):
             scal_np = np.asarray(scal)  # blocks until the kernel lands
         if not np.array_equal(scal_np[:, 1].astype(np.int64), row_lines):
@@ -1080,7 +1076,8 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
             for k in ("batch_s", "batch_wait_s", "upload_s", "kernel_s",
                       "pull_s", "device_wait_s", "d2h_s", "merge_s",
                       "replay_s", "finalize_s", "fold_s", "sync_s",
-                      "widen_s", "hist_s", "ckpt_s", "ckpt_capture_s",
+                      "sync_wait_s", "widen_s", "hist_s", "ckpt_s",
+                      "ckpt_capture_s",
                       "ckpt_commit_s", "ckpt_barrier_s",
                       "ckpt_compress_s", "dispatch_s", "retire_s",
                       "enqueue_s", "drain_s"):
@@ -1753,7 +1750,9 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                              mesh=mesh, t_cap_frac=frac,
                              pack_docs=pack_docs)
                 with _quiet_unusable_donation():
-                    return fn(chunk, ids)
+                    outs = fn(chunk, ids)
+                _enqueued(outs[2])  # (rows, df, scal)
+                return outs
 
         def dispatch(item):
             size, chunk_np, ids_np, doc_bytes, n_held = item
@@ -1833,7 +1832,9 @@ def _indexer_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
                 return
             with _span("pull", stats=st, key="pull_s"):
                 mp = occupied_prefix(m, rows.shape[1])
-                rows_np = np.asarray(rows[:, :mp])
+                # the slice's program and its copy, blocked on at once
+                with _span("d2h", lane="pull", stats=st, key="d2h_s"):
+                    rows_np = np.asarray(rows[:, :mp])
                 st["step_pulls"] += 1
             with _span("merge", stats=st, key="merge_s"):
                 for d in range(n_dev):

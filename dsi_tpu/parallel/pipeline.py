@@ -41,6 +41,7 @@ from typing import Callable, Iterator, Optional, Sequence
 
 import numpy as np
 
+from dsi_tpu.obs import get_tracer as _get_tracer
 from dsi_tpu.obs import hist as _hist
 from dsi_tpu.obs import span as _span
 
@@ -311,7 +312,15 @@ class StepPipeline:
     depth 1), ``wait_key`` (consumer starvation on the queue),
     ``inflight_key`` (peak window occupancy, bounded by ``depth``),
     ``dispatch_s`` and ``retire_s`` (the ``dispatch`` and ``finish``
-    spans below: with the wait, the whole of the thread that pumps).
+    spans below: with the wait, the whole of the thread that pumps) and
+    ``results_ready``: the steps whose programs the device had run
+    before the host came to retire them.  A ``dispatch`` tells the
+    tracer what it enqueued (``obs.enqueued``); the newest array it told
+    stays beside the record, and ``finish`` asks it, without blocking,
+    before the consumer's own reads.  One look a step, whatever it
+    costs (tens of microseconds on a TPU: PERF.md §6): an engine whose
+    step is shorter than a hundred looks passes ``count_ready=False``
+    and prints no ``results_ready``.
 
     Tracing (``dsi_tpu/obs``) is instrumented HERE once for all four
     engines: every produced item, dispatch, and finish is a span —
@@ -331,8 +340,9 @@ class StepPipeline:
                  wait_key: str = "batch_wait_s",
                  inflight_key: str = "max_inflight_chunks",
                  thread_name: str = "dsi-pipeline-producer",
-                 engine: str = ""):
+                 engine: str = "", count_ready: bool = True):
         self.depth = max(1, int(depth))
+        self._count_ready = count_ready
         self._dispatch = dispatch
         self._finish = finish
         self._stats = stats
@@ -344,6 +354,8 @@ class StepPipeline:
         stats.setdefault(produce_key, 0.0)
         stats.setdefault(wait_key, 0.0)
         stats.setdefault(inflight_key, 0)
+        if count_ready:
+            stats.setdefault("results_ready", 0)
         # Live telemetry state (obs/live.py statusz + the stall
         # watchdog): (ordinal, dispatch-perf_counter) per in-flight
         # record, plus monotonic dispatched/finished counters.  Plain
@@ -472,6 +484,8 @@ class StepPipeline:
         """Arm the pipeline over ``make_items()``'s items.  Must be
         balanced by :meth:`end` (any number of ``pump``/``drain`` calls
         in between)."""
+        #: each in-flight record beside what its dispatch enqueued
+        #: last, as ``Tracer.newest`` gives it, or None
         self._pending: collections.deque = collections.deque()
         self._inflight.clear()
         self._last_retire_t = time.perf_counter()
@@ -500,7 +514,10 @@ class StepPipeline:
         step, _ts = self._inflight[0]
         with _span("finish", lane="dispatch", stats=self._stats,
                    key="retire_s", step=step, engine=self._engine) as sp:
-            self._finish(self._pending.popleft())
+            rec, told = self._pending.popleft()
+            if told is not None and _get_tracer().landed(*told):
+                self._stats["results_ready"] += 1
+            self._finish(rec)
         self._inflight.popleft()
         self.finished += 1
         self._last_retire_t = time.perf_counter()
@@ -516,6 +533,8 @@ class StepPipeline:
             item = next(self._feed_iter)
         except StopIteration:
             return False
+        tracer = _get_tracer()
+        before = tracer.enqueued_n
         with _span("dispatch", stats=self._stats, key="dispatch_s",
                    step=self._idx, engine=self._engine):
             rec = self._dispatch(item)
@@ -523,7 +542,8 @@ class StepPipeline:
         self.dispatched = self._idx
         if rec is None:
             return True
-        self._pending.append(rec)
+        self._pending.append(
+            (rec, tracer.newest(before) if self._count_ready else None))
         self._inflight.append((self._idx - 1, time.perf_counter()))
         if len(self._pending) > self._stats[self._inflight_key]:
             self._stats[self._inflight_key] = len(self._pending)

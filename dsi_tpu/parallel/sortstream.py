@@ -54,7 +54,7 @@ from jax.sharding import Mesh
 
 from dsi_tpu.device.table import (_copy_to_host_async,
                                   _quiet_unusable_donation)
-from dsi_tpu.obs import metrics_scope, span as _span
+from dsi_tpu.obs import enqueued as _enqueued, metrics_scope, span as _span
 from dsi_tpu.ops.sortk import (KEY_BYTES, ORDER_PASSES, PAST_END,
                                PULL_LANES, RECORD_BYTES, RECORD_WORDS,
                                chunk_words, ingest_fn, pull_block_fn,
@@ -215,6 +215,7 @@ def range_sort(paths: Sequence[str], splits: np.ndarray, *, mesh: Mesh,
                 with _quiet_unusable_donation():
                     resident[0], resident[1], hist = step_fn(
                         resident[0], resident[1], chunk, splits_dev)
+                _enqueued(hist)
                 _copy_to_host_async(hist)
             sc["steps"] += 1
             return buf, n_valid, hist
@@ -234,11 +235,16 @@ def range_sort(paths: Sequence[str], splits: np.ndarray, *, mesh: Mesh,
                             stats=sc, produce_key="batch_s",
                             wait_key="batch_wait_s",
                             inflight_key="max_inflight_chunks",
-                            thread_name="dsi-sort-reader", engine="sort")
+                            thread_name="dsi-sort-reader", engine="sort",
+                            # a 0.9 ms step behind a 28.6 us program: a
+                            # look a step costs more than the program
+                            # (PERF.md §6, PR 51)
+                            count_ready=False)
         pipe.run(lambda: record_chunks(paths, chunk_records, pool))
         with _span("order", lane="kernel", stats=sc, key="order_s",
                    rows=capacity, passes=ORDER_PASSES):
             ordered = sort_order(*resident)
+            _enqueued(ordered)
             del resident[:]
             ordered.block_until_ready()
         sc["sort_order_passes"] = ORDER_PASSES
@@ -269,6 +275,7 @@ def _blocks(store: OrderedStore, stats: Optional[dict]
     def fly(i: int) -> None:
         if i < len(starts):
             block = cut(store.ordered, np.int32(starts[i]))
+            _enqueued(block)
             _copy_to_host_async(block)
             flying.append(block)
 

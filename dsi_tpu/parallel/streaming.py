@@ -120,7 +120,7 @@ from dsi_tpu.ckpt import (
 )
 from dsi_tpu.device.policy import SyncPolicy, mesh_shards_default
 from dsi_tpu.device.table import DeviceTable, _quiet_unusable_donation
-from dsi_tpu.obs import metrics_scope, span as _span
+from dsi_tpu.obs import enqueued as _enqueued, metrics_scope, span as _span
 from dsi_tpu.ops.wordcount import exactness_retry, rung0_cap
 from dsi_tpu.ops import wirecodec
 from dsi_tpu.parallel.merge import PackedCounts
@@ -1159,6 +1159,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                                                       parts)
             packed_dev = (_aot_pack(keys, lens, cnts, parts, mp=mp) if aot
                           else _slice_pack(keys, lens, cnts, parts, mp=mp))
+            _enqueued(packed_dev)  # the step's program, and its pack's
             handles = (scal, packed_dev, keys.shape[2], tables)
         stats["steps"] += 1
         rec_offset = 0
@@ -1319,7 +1320,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
             for k in ("batch_s", "batch_wait_s", "upload_s", "kernel_s",
                       "pull_s", "device_wait_s", "d2h_s", "merge_s",
                       "replay_s", "finalize_s", "fold_s", "sync_s",
-                      "widen_s", "ckpt_s", "ckpt_capture_s",
+                      "sync_wait_s", "widen_s", "ckpt_s", "ckpt_capture_s",
                       "ckpt_commit_s", "ckpt_barrier_s", "decode_s",
                       "ckpt_compress_s", "dispatch_s", "retire_s",
                       "enqueue_s", "drain_s", "compact_s",

@@ -64,7 +64,7 @@ from dsi_tpu.ckpt import (
     drain_posting_steps,
     fault_point,
 )
-from dsi_tpu.obs import metrics_scope, span as _span
+from dsi_tpu.obs import enqueued as _enqueued, metrics_scope, span as _span
 from dsi_tpu.utils.jaxcompat import (enable_x64, x64_scoped,
                                      shard_map as _shard_map)
 
@@ -657,7 +657,9 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
             from dsi_tpu.device.table import _quiet_unusable_donation
 
             with _quiet_unusable_donation():
-                return fn(chunk, ids)
+                outs = fn(chunk, ids)
+            _enqueued(outs[1])  # (rows, scal)
+            return outs
 
         def dispatch(item):
             size, chunk_np, ids_np = item
@@ -716,7 +718,9 @@ def _tfidf_setup(step, docs, mesh, n_reduce, max_word_len, u_cap,
             # D2H bill tracks this wave's postings, not capacity.
             with _span("pull", stats=stats, key="pull_s"):
                 mp = occupied_prefix(m, rows.shape[1])
-                rows_np = np.asarray(rows[:, :mp])
+                # the slice's program and its copy, blocked on at once
+                with _span("d2h", lane="pull", stats=stats, key="d2h_s"):
+                    rows_np = np.asarray(rows[:, :mp])
                 stats["step_pulls"] += 1
             with _span("merge", stats=stats, key="merge_s"):
                 for d in range(n_dev):

@@ -132,8 +132,10 @@ def _bar(frac: float, width: int = 28) -> str:
 
 def flame(events, out) -> None:
     """Per span name: total, self time (the total less the direct
-    children's, so a span with nothing inside it reads its whole total)
-    and, indented under it, what its direct children sum to."""
+    children's, so a span with nothing inside it reads its whole total),
+    ``dry_s`` (of that self time, what the starvation account of the
+    job's main thread charged it: the chip had nothing queued) and,
+    indented under it, what its direct children sum to."""
     spans = [e for e in events if e.get("ph") == "X"]
     by_id = {(e.get("_file"), e["id"]): e for e in spans
              if e.get("id") is not None}
@@ -141,10 +143,11 @@ def flame(events, out) -> None:
     for e in spans:
         key = (e.get("lane", "?"), e["name"])
         dur = e.get("dur", 0.0)
-        r = rows.setdefault(key, [0.0, 0, 0.0])
+        r = rows.setdefault(key, [0.0, 0, 0.0, 0.0])
         r[0] += dur
         r[1] += 1
         r[2] = max(r[2], dur)
+        r[3] += e.get("dry", 0.0)
         up = by_id.get((e.get("_file"), e.get("parent")))
         if up is not None:
             k = kids.setdefault((up.get("lane", "?"), up["name"]),
@@ -160,18 +163,18 @@ def flame(events, out) -> None:
         return f"{lane}/{name}" if lane != name else name
 
     top = max(r[0] for r in rows.values()) or 1.0
-    print(f"  {'lane/span':<24} {'total_s':>9} {'self_s':>9} {'count':>7} "
-          f"{'mean_ms':>9} {'max_ms':>9}", file=out)
-    for key, (tot, cnt, mx) in sorted(rows.items(),
-                                      key=lambda kv: -kv[1][0]):
+    print(f"  {'lane/span':<24} {'total_s':>9} {'self_s':>9} {'dry_s':>9} "
+          f"{'count':>7} {'mean_ms':>9} {'max_ms':>9}", file=out)
+    for key, (tot, cnt, mx, dry) in sorted(rows.items(),
+                                           key=lambda kv: -kv[1][0]):
         inside = kids.get(key, {})
         self_s = tot - sum(k[0] for k in inside.values())
-        print(f"  {label(key):<24} {tot:>9.3f} {self_s:>9.3f} {cnt:>7} "
-              f"{1e3 * tot / cnt:>9.2f} {1e3 * mx:>9.2f}  "
+        print(f"  {label(key):<24} {tot:>9.3f} {self_s:>9.3f} {dry:>9.3f} "
+              f"{cnt:>7} {1e3 * tot / cnt:>9.2f} {1e3 * mx:>9.2f}  "
               f"{_bar(tot / top)}", file=out)
         for kkey, (ktot, kcnt) in sorted(inside.items(),
                                          key=lambda kv: -kv[1][0]):
-            print(f"    > {label(kkey):<20} {ktot:>9.3f} {'':>9} "
+            print(f"    > {label(kkey):<20} {ktot:>9.3f} {'':>19} "
                   f"{kcnt:>7}", file=out)
 
 

@@ -732,3 +732,535 @@ def test_indexer_chain_records_its_new_spans_and_counters(fresh_tracer,
     # the wave program has its own name in a device trace
     enq = [e for e in spans.values() if e["name"] == "enqueue"]
     assert {e["program"] for e in enq} == {"idx_wave_step"}
+
+
+# ── the starvation account ─────────────────────────────────────────────
+
+
+class _Clock:
+    """``time`` for ``obs/trace.py``: the test moves it."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+    def time_ns(self):
+        return int(self.now * 1e9)
+
+
+class _Result:
+    """A stub of a device array: the test sets its readiness, counts who
+    asks and says what an answer costs on its clock."""
+
+    def __init__(self, ready=False, clock=None, cost_s=0.0):
+        self.ready = ready
+        self.asked = 0
+        self.clock, self.cost_s = clock, cost_s
+
+    def is_ready(self):
+        self.asked += 1
+        if self.clock is not None:
+            self.clock.now += self.cost_s
+        return self.ready
+
+
+@pytest.fixture
+def account(monkeypatch):
+    """A tracer with tracing off on a clock the test moves:
+    ``(tracer, clock)``."""
+    clock = _Clock()
+    monkeypatch.setattr(obs_trace, "time", clock)
+    monkeypatch.setattr(obs_hist, "_active", None)
+    tracer = Tracer(enabled=False)
+    monkeypatch.setattr(obs_trace, "_global", tracer)
+    return tracer, clock
+
+
+def _walk_one_job(tracer, clock, stats):
+    """One step of a job whose every piece has a length of its own."""
+    res = _Result()
+    with tracer.span("job", stats=stats):
+        clock.now += 1.0                      # job: nothing in flight
+        with tracer.span("start", stats=stats):
+            clock.now += 2.0
+        with tracer.span("dispatch", stats=stats):
+            clock.now += 0.5                  # before the program's call
+            with tracer.span("enqueue", lane="dispatch", stats=stats):
+                clock.now += 0.25
+                tracer.enqueued(res)          # began with nothing: ran dry
+                clock.now += 0.25
+            clock.now += 0.1                  # in flight at both ends: fed
+        with tracer.span("finish", stats=stats, key="retire_s"):
+            clock.now += 1.0                  # fed
+            with tracer.span("kernel", stats=stats):
+                clock.now += 3.0              # held by the device
+                res.ready = True
+            clock.now += 0.2                  # ran out somewhere in here
+            with tracer.span("merge", stats=stats):
+                clock.now += 0.7              # nothing in flight
+        clock.now += 0.3
+    return res
+
+
+def test_account_pieces_partition_the_root(account):
+    from dsi_tpu.obs.registry import STARVED_GROUPS
+
+    tracer, clock = account
+    stats = {}
+    _walk_one_job(tracer, clock, stats)
+    assert stats["job_s"] == pytest.approx(9.3)
+    # each piece on the innermost span that was open through it
+    assert stats["starved_by"] == pytest.approx(
+        {"job": 1.3, "start": 2.0, "dispatch": 0.5, "enqueue": 0.5,
+         "finish": 0.2, "merge": 0.7})
+    assert stats["starved_s"] == pytest.approx(5.2)
+    # dry: nothing queued at its start and nothing enqueued in it
+    assert stats["starved_dry_s"] == pytest.approx(1.3 + 2.0 + 0.5 + 0.7)
+    assert stats["starved_groups"] == pytest.approx(
+        {"input": 2.0, "dispatch": 1.0, "merge": 0.9, "tail": 1.3})
+    assert [g for g, _ in STARVED_GROUPS] == list(stats["starved_groups"])
+    assert sum(stats["starved_groups"].values()) == pytest.approx(
+        stats["starved_s"])
+    # fed is the rest: the pieces sum to the root
+    fed = 0.1 + 1.0 + 3.0
+    assert stats["starved_s"] + fed == pytest.approx(stats["job_s"])
+    # the account is closed, and lets go of the job's array
+    assert tracer._acct is None and tracer.newest(0) is None
+
+
+def test_every_span_name_is_in_one_starved_group():
+    from dsi_tpu.obs.registry import DEVICE_BLOCKED, STARVED_GROUPS
+
+    listed = [name for _, names in STARVED_GROUPS for name in names]
+    assert len(listed) == len(set(listed))
+    assert set(listed) <= obs_trace.SPAN_NAMES
+    # the names of a stream or plan job's main thread are all listed
+    for name in ("start", "read", "read_wait", "sample", "wait", "plan",
+                 "dispatch", "upload", "enqueue", "relay_append", "finish",
+                 "pull", "merge", "compact", "replay", "fold", "sync",
+                 "widen", "group", "ckpt", "drain", "finalize", "decode",
+                 "write", "format", "commit", "report", "job"):
+        assert name in listed, name
+    assert {name for name, _ in DEVICE_BLOCKED} <= obs_trace.SPAN_NAMES
+    assert {lane for _, lane in DEVICE_BLOCKED} <= set(obs_trace.LANES)
+
+
+def test_a_device_blocked_span_is_never_starved(account):
+    from dsi_tpu.obs.registry import DEVICE_BLOCKED
+
+    tracer, clock = account
+    stats = {}
+    with tracer.span("job", stats=stats):
+        for name, lane in DEVICE_BLOCKED:
+            # nothing in flight, and yet: held by the device by definition
+            with tracer.span(name, lane=lane, stats=stats):
+                clock.now += 1.0
+        # the step loop's wait for its producer is not the pull's wait
+        with tracer.span("wait", lane="materialize", stats=stats,
+                         key="batch_wait_s"):
+            clock.now += 0.5
+    assert stats["starved_by"] == pytest.approx({"wait": 0.5})
+    assert stats["starved_s"] == pytest.approx(0.5)
+    assert stats["job_s"] == pytest.approx(len(DEVICE_BLOCKED) + 0.5)
+
+
+def _step(tracer, clock, stats, res, older, device_s):
+    """One turn of a depth-2 step loop: ``res``'s dispatch, then the
+    retirement of ``older`` (``(ordinal, result)`` as the pipeline core
+    keeps it), whose program the device ends ``device_s`` into the
+    ``kernel`` span.  Returns what the core keeps of ``res``."""
+    before = tracer.enqueued_n
+    with tracer.span("dispatch", stats=stats):
+        with tracer.span("upload", stats=stats):
+            clock.now += 300e-6
+        with tracer.span("enqueue", lane="dispatch", stats=stats):
+            clock.now += 100e-6
+            tracer.enqueued(res)
+            clock.now += 100e-6
+    told = tracer.newest(before)
+    if older is not None:
+        with tracer.span("finish", stats=stats, key="retire_s"):
+            tracer.landed(*older)
+            with tracer.span("kernel", stats=stats):
+                clock.now += device_s
+                older[1].ready = True
+            with tracer.span("pull", stats=stats):
+                with tracer.span("wait", lane="pull", stats=stats,
+                                 key="device_wait_s"):
+                    clock.now += 10e-6
+                with tracer.span("d2h", lane="pull", stats=stats):
+                    clock.now += 200e-6
+            with tracer.span("merge", stats=stats):
+                clock.now += 1e-3
+    with tracer.span("wait", lane="materialize", stats=stats,
+                     key="batch_wait_s"):
+        clock.now += 20e-6
+    return told
+
+
+@pytest.mark.parametrize("cost_s", [0.25e-6, 40e-6], ids=["cheap", "dear"])
+def test_account_spends_its_share_of_a_device_paced_job_on_looks(account,
+                                                                 cost_s):
+    """Whatever an answer costs, asking takes ``_LOOK_SHARE`` of the wall
+    at most (and one look more: the first is free)."""
+    tracer, clock = account
+    stats = {}
+    results = [_Result(clock=clock, cost_s=cost_s) for _ in range(40)]
+    with tracer.span("job", stats=stats):
+        older = None
+        for res in results:
+            # the device is a step behind: 8 ms of each retirement
+            older = _step(tracer, clock, stats, res, older, 8e-3)
+    looks = sum(r.asked for r in results)
+    # the pipeline's own look a step is asked whatever it costs
+    own = len(results) - 1
+    assert (looks - own - 1) * cost_s <= \
+        obs_trace._LOOK_SHARE * stats["job_s"]
+    # the chip had work all along, bar the first step's way to it; what
+    # it took for fed without a look at either end it counts as unseen
+    first = 300e-6 + 100e-6
+    assert stats["starved_s"] == pytest.approx(first, abs=2e-4)
+    if cost_s < 1e-6:
+        assert stats["starved_unseen_s"] <= 0.005 * stats["job_s"]
+    else:   # the host's 1.7 ms of a step that the device does not hold
+        assert 0.05 * stats["job_s"] <= stats["starved_unseen_s"] \
+            <= 0.2 * stats["job_s"]
+    assert stats["starved_s"] + stats["starved_unseen_s"] <= stats["job_s"]
+
+
+def test_account_asks_a_host_paced_dispatch_once(account):
+    tracer, clock = account
+    stats = {}
+    results = [_Result(ready=True) for _ in range(40)]
+    with tracer.span("job", stats=stats):
+        older = None
+        for res in results:
+            # the program is done before its ``enqueue`` span is
+            older = _step(tracer, clock, stats, res, older, 0.0)
+    # seen ready at the first boundary behind it; not asked again, by
+    # the account or by the pipeline's ``landed``
+    assert [r.asked for r in results] == [1] * len(results)
+    # every piece but the blocked ones began or ended with nothing queued
+    blocked = 39 * (0.0 + 10e-6 + 200e-6)
+    assert stats["starved_s"] == pytest.approx(stats["job_s"] - blocked,
+                                               abs=2e-4)
+    assert stats["starved_groups"]["dispatch"] == pytest.approx(
+        40 * 500e-6, abs=2e-4)
+    assert stats["starved_dry_s"] <= stats["starved_s"]
+
+
+class _TimedResult(_Result):
+    """Ready once the test's clock has reached ``at``."""
+
+    def __init__(self, clock):
+        super().__init__()
+        self.clock, self.at = clock, None
+
+    def is_ready(self):
+        self.asked += 1
+        return self.clock.now >= self.at
+
+
+@pytest.mark.parametrize("device_s, idle, bounds", [
+    # the chip runs out between two dispatches, step after step
+    (1.1e-3, 0.50, (0.35, 0.90)),
+    # it runs out for a moment a step, inside the next dispatch
+    (2.0e-3, 0.10, (0.0, 0.70)),
+    # it never does: the steps queue up behind each other
+    (3.0e-3, 0.0, (0.0, 0.02))], ids=["half-idle", "nearly-fed", "fed"])
+def test_account_finds_the_chip_idle_between_dispatches(account, device_s,
+                                                        idle, bounds):
+    """A step's program of ``device_s`` behind a host that takes 2.2 ms a
+    step: the chip's true idle share lies between the account's two
+    bounds."""
+    tracer, clock = account
+    stats = {}
+    results = [_TimedResult(clock) for _ in range(120)]
+    busy_until = busy = 0.0
+    with tracer.span("job", stats=stats):
+        older = None
+        for res in results:
+            before = tracer.enqueued_n
+            with tracer.span("dispatch", stats=stats):
+                with tracer.span("upload", stats=stats):
+                    clock.now += 680e-6
+                with tracer.span("enqueue", lane="dispatch", stats=stats):
+                    clock.now += 80e-6   # the call reaches the device
+                    res.at = max(clock.now, busy_until) + device_s
+                    busy_until, busy = res.at, busy + device_s
+                    clock.now += 720e-6  # and returns
+                    tracer.enqueued(res)
+            told = tracer.newest(before)
+            if older is not None:
+                with tracer.span("finish", stats=stats, key="retire_s"):
+                    tracer.landed(*older)
+                    with tracer.span("kernel", stats=stats):
+                        clock.now = max(clock.now, older[1].at) + 5e-6
+                    with tracer.span("merge", stats=stats):
+                        for _ in range(14):   # the host's part, in pieces
+                            with tracer.span("compact", stats=stats):
+                                clock.now += 50e-6
+            older = told
+        clock.now = max(clock.now, busy_until)
+    job = stats["job_s"]
+    assert (job - busy) / job == pytest.approx(idle, abs=0.06)
+    low, high = bounds
+    assert low <= stats["starved_dry_s"] / job <= (job - busy) / job + 0.01
+    assert (job - busy) / job - 0.01 <= stats["starved_s"] / job <= high
+    # looks that cost nothing are all due: nothing is left unseen
+    assert stats["starved_unseen_s"] == 0.0
+
+
+def test_another_threads_spans_do_not_cut(account):
+    tracer, clock = account
+    stats, other = {}, {}
+    res = _Result()
+
+    def reader():
+        # a producer's spans, and a program it enqueues: not boundaries
+        with tracer.span("materialize", stats=other, key="batch_s"):
+            tracer.enqueued(res)
+
+    with tracer.span("job", stats=stats):
+        clock.now += 1.0
+        with tracer.span("merge", stats=stats):
+            clock.now += 1.0
+            t = threading.Thread(target=reader)
+            t.start()
+            t.join()
+            clock.now += 1.0    # the other thread fed the chip in here
+        clock.now += 1.0        # in flight at both ends
+    assert "batch_s" in other and "starved_s" not in other
+    assert stats["starved_by"] == pytest.approx({"job": 1.0, "merge": 2.0})
+    assert stats["starved_dry_s"] == pytest.approx(1.0)
+    # a span with a sink on a thread without an account costs no ask
+    assert "materialize" not in stats["starved_by"]
+
+
+def test_a_deleted_array_lets_the_newest_answer(account):
+    """An array donated to a later program cannot say whether the chip
+    has run the one that made it: the newest one told answers for it,
+    and where there is none the chip counts as busy."""
+    tracer, clock = account
+
+    class Donated:
+        def is_ready(self):
+            raise RuntimeError("Array has been deleted.")
+
+    stats = {}
+    with tracer.span("job", stats=stats):
+        tracer.enqueued(Donated())
+        told = tracer.newest(0)
+        clock.now += 1.0
+        with tracer.span("merge", stats=stats):
+            clock.now += 1.0
+        assert not tracer.landed(*told)
+        newer = _Result()
+        tracer.enqueued(newer)
+        assert not tracer.landed(*told)
+        newer.ready = True
+        assert tracer.landed(*told) and newer.asked == 2
+    # began with nothing; after that nobody could say
+    assert stats["starved_by"] == pytest.approx({"job": 1.0})
+
+
+def test_what_is_taken_for_fed_unseen_is_counted(account):
+    """At a boundary at which something is in flight and no look is due
+    the chip is taken for busy; a piece taken for fed with neither end
+    seen is counted in ``starved_unseen_s``."""
+    tracer, clock = account
+    stats = {}
+    res = _Result(clock=clock, cost_s=50e-6)   # the next look: 10 ms on
+    with tracer.span("job", stats=stats):
+        with tracer.span("dispatch", stats=stats):
+            tracer.enqueued(res)
+            clock.now += 1e-3             # began with nothing: starved
+        # asked at ``dispatch``'s exit: busy; the look took 50 us
+        with tracer.span("merge", stats=stats):
+            clock.now += 2e-3             # neither end seen
+        with tracer.span("compact", stats=stats):
+            clock.now += 3e-3             # neither end seen
+        with tracer.span("merge", stats=stats):
+            clock.now += 4e-3             # neither end seen
+        with tracer.span("compact", stats=stats):
+            clock.now += 20e-3            # asked at its end: busy, fed
+        with tracer.span("merge", stats=stats):
+            clock.now += 30e-3
+            res.ready = True
+        clock.now += 1.0                  # asked: ran out in here
+    assert res.asked == 3
+    assert stats["starved_unseen_s"] == pytest.approx(9e-3, abs=2e-4)
+    assert stats["starved_by"] == pytest.approx(
+        {"dispatch": 1e-3, "merge": 30e-3, "job": 1.0}, abs=2e-4)
+
+
+def test_traced_span_records_carry_their_own_dry_seconds(monkeypatch,
+                                                         tmp_path):
+    clock = _Clock()
+    monkeypatch.setattr(obs_trace, "time", clock)
+    tracer = Tracer(enabled=True, trace_dir=str(tmp_path / "trace"))
+    try:
+        stats = {}
+        _walk_one_job(tracer, clock, stats)
+        spans = {e["name"]: e for e in _flushed(tracer) if e["ph"] == "X"}
+    finally:
+        tracer.enabled = False
+    dry = {name: e["dry"] for name, e in spans.items() if "dry" in e}
+    assert dry == pytest.approx(stats["starved_by"])
+    assert "dry" not in spans["kernel"]
+    # a sink-less span records, and is cut, only while tracing is on
+    assert stats["starved_s"] == pytest.approx(5.2)
+
+
+def _plan_inputs(tmp_path, chain):
+    if chain == "sort":
+        import numpy as np
+
+        path = tmp_path / "records.bin"
+        path.write_bytes(np.random.default_rng(3).integers(
+            0, 256, size=100 * 700, dtype=np.uint8).tobytes())
+        return [str(path)]
+    if chain == "agg":
+        path = tmp_path / "rows.txt"
+        path.write_text("".join(
+            f"10.{i % 7}.{i % 13}.{i % 5}|u{i}|d|{i % 9}.{i % 100:02d}|a\n"
+            for i in range(3000)))
+        return [str(path)]
+    return ensure_corpus(str(tmp_path / "inputs"), n_files=2,
+                         file_size=60_000)
+
+
+def _planrun_main(tmp_path, chain, *flags):
+    pytest.importorskip("jax")
+    from dsi_tpu.cli import planrun
+
+    argv = ["--chain", chain, "--devices", "1", "--nreduce", "3",
+            "--chunk-bytes", "16384", "--stats", "--workdir",
+            str(tmp_path / "wd"), *flags]
+    if chain == "grep-wc":
+        argv += ["--pattern", "the"]
+    err = io.StringIO()
+    with contextlib.redirect_stderr(err), \
+            contextlib.redirect_stdout(io.StringIO()):
+        rc = planrun.main(argv + _plan_inputs(tmp_path, chain))
+    assert rc == 0, err.getvalue()[-2000:]
+    m = re.search(r"^planrun: pipeline_stats=(\{.*\})$", err.getvalue(),
+                  re.M)
+    return ast.literal_eval(m.group(1))
+
+
+def _holds_the_account(ps):
+    from dsi_tpu.obs.registry import PHASE_KEYS, STARVED_GROUPS
+
+    assert list(ps["starved_groups"]) == [g for g, _ in STARVED_GROUPS]
+    assert sum(ps["starved_groups"].values()) == pytest.approx(
+        ps["starved_s"], abs=1e-6)
+    assert sum(ps["starved_by"].values()) == pytest.approx(
+        ps["starved_s"], abs=5e-4 * len(ps["starved_by"]))
+    assert 0 <= ps["starved_dry_s"] <= ps["starved_s"] <= ps["job_s"]
+    assert 0 <= ps["starved_unseen_s"] <= ps["job_s"] - ps["starved_s"] \
+        + 1e-3
+    assert set(ps["starved_by"]) <= obs_trace.SPAN_NAMES
+    # held by the device: never starved
+    assert "kernel" not in ps["starved_by"]
+    assert "d2h" not in ps["starved_by"]
+    for key in ("starved_s", "starved_dry_s", "starved_unseen_s",
+                "starved_by", "starved_groups"):
+        assert key in PHASE_KEYS, key
+
+
+@pytest.mark.parametrize("command, flags", [
+    ("wcstream", ()), ("wcstream", ("--device-accumulate",)),
+    ("grepstream", ())], ids=["wcstream", "wcstream-accumulate",
+                              "grepstream"])
+def test_stream_stats_say_where_the_chip_was_starved(tracing_off, tmp_path,
+                                                     command, flags):
+    rc, ps, _ = _stream_main(command, tmp_path, *flags)
+    assert rc == 0
+    _holds_the_account(ps)
+    # the start ends before the first program is enqueued: all of it dry
+    assert ps["starved_by"]["start"] == pytest.approx(ps["start_s"],
+                                                      abs=2e-4)
+    assert ps["starved_dry_s"] >= ps["starved_by"]["start"] - 2e-4
+    # every engine on the pipeline core counts its steps found done
+    assert 0 <= ps["results_ready"] <= ps["steps"]
+    if flags:
+        assert 0 < ps["sync_wait_s"] <= ps["fold_s"] + ps["sync_s"] + 1e-3
+
+
+@pytest.mark.parametrize("chain", ["grep-wc", "indexer", "sort", "agg"])
+def test_planrun_stats_say_where_the_chip_was_starved(tracing_off,
+                                                      tmp_path, chain):
+    from dsi_tpu.obs.registry import PHASE_KEYS, plan_job_children_s
+
+    ps = _planrun_main(tmp_path, chain)
+    _holds_the_account(ps)
+    for key in ("job_s", "start_s", "report_s", "job_children_s",
+                "write_s"):
+        assert ps[key] > 0, key
+        assert key in PHASE_KEYS, key
+    assert ("read_s" in ps) == (chain == "indexer")
+    assert ps["job_children_s"] == pytest.approx(plan_job_children_s(ps),
+                                                 abs=1e-4)
+    assert 0.95 * ps["job_s"] <= ps["job_children_s"] <= ps["job_s"] + 1e-3
+    assert {"start", "plan"} <= set(ps["starved_by"])
+    # per stage as before, and the account nowhere but at the top
+    for stage in ps["stages"].values():
+        assert "starved_s" not in stage
+
+
+@pytest.mark.parametrize("chain", ["grep-wc", "indexer", "sort"])
+def test_planrun_job_children_are_the_registrys_tuple(fresh_tracer,
+                                                      tmp_path, chain):
+    from dsi_tpu.obs.registry import PLAN_JOB_CHILDREN
+
+    ps = _planrun_main(tmp_path, chain, "--trace-dir",
+                       str(tmp_path / "trace"))
+    _, events = _jsonl(str(tmp_path / "trace" / "trace.jsonl"))
+    spans = {e["id"]: e for e in events if e["ph"] == "X"}
+    (job,) = [e for e in spans.values() if e["name"] == "job"]
+    assert job["parent"] is None and job["depth"] == 0
+    kids = _children(events, job)
+    key_of = dict(PLAN_JOB_CHILDREN)
+    want = {"start", "plan", "write", "report"}
+    if chain == "indexer":
+        want.add("read")
+    assert {e["name"] for e in kids} == want <= set(key_of)
+    stages = {"grep-wc": 2, "indexer": 3, "sort": 2}[chain]
+    assert len([e for e in kids if e["name"] == "plan"]) == stages
+    for name in want:
+        total = sum(e["dur"] for e in kids if e["name"] == name)
+        got = ps["plan"]["plan_s"] if name == "plan" else ps[key_of[name]]
+        assert got == pytest.approx(total, abs=5e-4), name
+    assert ps["job_s"] == pytest.approx(job["dur"], abs=1e-4)
+    assert ps["job_s"] - ps["job_children_s"] == pytest.approx(
+        job["dur"] - sum(e["dur"] for e in kids), abs=2e-3)
+    # what the records say was starved is what the line says
+    dry = {}
+    for e in spans.values():
+        if "dry" in e:
+            dry[e["name"]] = dry.get(e["name"], 0.0) + e["dry"]
+    for name in set(dry) | set(ps["starved_by"]):
+        assert dry.get(name, 0.0) == pytest.approx(
+            ps["starved_by"].get(name, 0.0), abs=2e-3), name
+    assert sum(dry.values()) <= job["dur"] + 1e-3
+
+
+def test_cached_compile_times_its_two_halves(global_tracer):
+    jax = pytest.importorskip("jax")
+    from dsi_tpu.backends import aotcache
+
+    before = dict(aotcache.stats)
+    fn = aotcache.cached_compile(
+        "test_two_halves", lambda x: x * 2 + 1,
+        (jax.ShapeDtypeStruct((8,), "int32"),))
+    assert fn(jax.numpy.arange(8, dtype="int32"))[3] == 7
+    assert aotcache.stats["compiles"] == before["compiles"] + 1
+    assert aotcache.stats["lowered_s"] > before["lowered_s"]
+    assert aotcache.stats["compiled_s"] > before["compiled_s"]
+    spans = [e for e in _flushed(global_tracer) if e["ph"] == "X"]
+    assert [(e["name"], e["program"]) for e in spans] == [
+        ("lower", "test_two_halves"), ("compile", "test_two_halves")]
+    assert {"lower", "compile"} <= obs_trace.SPAN_NAMES
