@@ -38,8 +38,16 @@ that phase):
   the spellings
 * ``compact_s``          — the ``compact`` spans of the host accumulator
   (``parallel/merge.py``): the window's runs merged into one and that
-  one into the merged table, which is never sorted again; inside
-  ``merge_s``, ``finalize_s`` or ``sync_s``, whichever called it
+  one into the merged table, which is never sorted again.  A window
+  that filled is compacted on a merger thread, beside the steps that
+  follow, and its span is in no phase of the main thread; the last,
+  partial window on the caller's, inside ``finalize_s``, ``sync_s`` or
+  ``ckpt_s``, whichever asked for the table
+* ``compact_caller_s``   — what of ``compact_s`` the accumulator's
+  caller was held for: the ``compact`` spans on its own thread and its
+  ``merge_wait`` spans (a compaction still in flight when the next
+  window was full, or when the table was asked for); 1 less its share
+  of ``compact_s`` is the share of the compactions the steps hid
 * ``group_s``            — the ``group`` span: the indexer's postings
   table grouped into the index (``merge.PostingsTable.finalize_packed``:
   the runs the waves' rows arrive in found and merged, no row through a
@@ -155,7 +163,8 @@ every batch is a device's step table), ``merge_rows_sorted`` (rows
 handed to an ordering routine: an unsorted batch's on entry, a
 window's at its compaction, the merged table's never) and
 ``merge_compacts`` (all five repeat exactly for one input, with the
-native library or without), ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
+native library or without; ``merge_compacts_async`` of them were handed
+to a merger thread: the windows that filled), ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
 ``resume_cursor``/``resume_wave``, ``device_accumulate``.  The indexer's
 wave walk adds ``docs`` (documents handed over), ``waves_by_size``
 (padded chunk bytes → waves dispatched), ``wave_doc_bytes`` and
@@ -357,6 +366,9 @@ PHASE_KEYS = (
     "enqueue_s",
     # the host merge and the serial tail, split where the work happens
     "compact_s", "finalize_decode_s", "write_format_s", "write_commit_s",
+    # what of compact_s the accumulator's caller was held for: the
+    # compactions on its own thread and its waits for the merger's
+    "compact_caller_s",
     # the postings table grouped into the index (``group`` span of
     # ``merge.PostingsTable.finalize_packed``: the runs found and
     # merged), in the indexer's scope
@@ -445,8 +457,8 @@ STARVED_GROUPS = (
                "launch", "lower", "compile")),
     ("dispatch", ("dispatch", "upload", "enqueue", "relay_append",
                   "relay_spill")),
-    ("merge", ("finish", "pull", "merge", "compact", "replay", "fold",
-               "sync", "widen", "group", "ckpt", "ckpt_capture",
+    ("merge", ("finish", "pull", "merge", "compact", "merge_wait", "replay",
+               "fold", "sync", "widen", "group", "ckpt", "ckpt_capture",
                "ckpt_commit", "ckpt_save", "ckpt_restore", "shuffle",
                "append", "hist_fold", "hist_pull", "kernel", "d2h",
                "order")),
@@ -470,7 +482,7 @@ COUNTER_KEYS = (
     "device_rows",
     # the host accumulator (parallel/merge.py PackedCounts)
     "merge_rows_in", "merge_rows_sorted", "merge_compacts",
-    "merge_runs_in", "merge_runs_unsorted",
+    "merge_runs_in", "merge_runs_unsorted", "merge_compacts_async",
     # its result and the partition writer: spellings turned into ``str``
     # (0 in a wcstream job), rows rendered from the merged table's
     # arrays, rows formatted from a dict (the host fallback's)

@@ -165,6 +165,9 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # compaction of the host accumulator (parallel/merge.py); inside
     # "write", a partition's CPU work and its durable commit
     "job", "start", "enqueue", "compact", "format", "commit",
+    # the accumulator's caller held by the compaction of a full window
+    # that is still in flight on its merger thread (parallel/merge.py)
+    "merge_wait",
     # the indexer's postings table grouped into the index, once a job
     # (parallel/merge.py PostingsTable.finalize_packed)
     "group",

@@ -15,7 +15,8 @@ This module replaces the per-row loops with numpy table algebra:
   the word-count accumulator merges runs and sorts nothing twice: the
   batches since the last compaction (the window) are merged into one run
   and that run into the merged table, which stands apart from the window
-  and never goes through a sort again.  Two pointers in
+  and never goes through a sort again (a window that fills is merged on
+  a thread of its own, the next one filling beside it).  Two pointers in
   ``native/mergeruns.cpp``; without the library one stable sort of the
   window on a packed ``uint64`` key (a merge of the runs it finds), ties
   repaired, ``np.add.reduceat``, and ``np.searchsorted`` placement into the
@@ -39,6 +40,7 @@ the host side can keep up with the device side at GB scale.
 
 from __future__ import annotations
 
+import threading
 from collections.abc import Mapping
 from typing import Dict, List, Optional, Tuple
 
@@ -298,6 +300,42 @@ def _merge_into(table: _Table, run: _Table) -> _Table:
     return tuple(out)
 
 
+def _compact_window(table: Optional[_Table], window: List[_Table],
+                    fields: dict, stats: dict) -> Tuple[_Table, float]:
+    """One compaction, on whichever thread: the window's runs into one
+    run, that run into the merged table; the table and the seconds it
+    took.  Its ``compact`` span is all it writes to ``stats``
+    (``compact_s``)."""
+    with _span("compact", lane="merge", stats=stats,
+               table_rows=0 if table is None else len(table[0]),
+               **fields) as sp:
+        run = _merge_runs(window)
+        table = run if table is None else _merge_into(table, run)
+        sp.set(rows_out=len(table[0]))
+    return table, sp.elapsed_s
+
+
+class _Compaction(threading.Thread):
+    """One full window's compaction on a thread of its own, which ends
+    with it: then ``table`` is the merged table, or ``error`` what the
+    merge raised, for :meth:`PackedCounts._join` to raise in the
+    caller."""
+
+    def __init__(self, table: Optional[_Table], window: List[_Table],
+                 fields: dict, stats: dict):
+        super().__init__(name="dsi-merge-compact", daemon=True)
+        self.table, self.error = None, None
+        self._work = (table, window, fields, stats)
+
+    def run(self) -> None:
+        try:
+            self.table = _compact_window(*self._work)[0]
+        except BaseException as e:  # whatever it is: ``_join`` raises it
+            self.error = e
+        finally:
+            self._work = None
+
+
 class PackedCounts:
     """Word-count accumulator over packed-key row batches.
 
@@ -308,14 +346,28 @@ class PackedCounts:
     strictly increase: a device's step table is one as it arrives; any
     other batch is sorted and reduced on entry, alone).  When the
     window's rows reach ``compact_rows`` a compaction merges the
-    window's runs into one and that one into the table, so host memory
-    is O(vocabulary + window), never O(corpus), and the number of
-    compactions follows the rows handed over, not the table's size.
+    window's runs into one and that one into the table, and the number
+    of compactions follows the rows handed over, not the table's size.
     Nothing is sorted that arrived sorted, and the table is never sorted
     again.  ``finalize`` returns the merged table as a
     :class:`PackedWordCounts`, which equals the ``{word: (count,
     reduce_partition)}`` dict the dict-based merge produced and builds
     it only when a caller needs Python objects.
+
+    A window that fills is compacted on a thread of its own
+    (:class:`_Compaction`: it lives for that one compaction, and
+    ``native/mergeruns.cpp`` holds no interpreter lock), while the
+    caller goes on with an empty window.  One compaction is in flight
+    at most: a second full window, ``finalize``, ``snapshot`` and
+    ``restore`` first wait for it, in a ``merge_wait`` span, and an
+    exception it raised is raised there or in the next ``add``, in the
+    caller (``close`` waits for it and drops it).  So host memory is
+    O(vocabulary + two windows), never O(corpus) (a window of 2^21 rows
+    at 4 key lanes is 67 MB).  What is left when the caller asks for
+    the table, the last, partial window, is compacted on the caller's
+    thread; an accumulator whose window never fills starts no thread.
+    The result is the same bit for bit, whichever thread merged: the
+    same merges in the same order over exact integer sums.
 
     ``stats`` (an engine's scope, else a dict of the accumulator's own)
     takes what the merge costs.  Five counters that repeat exactly for
@@ -324,11 +376,16 @@ class PackedCounts:
     ``add``), ``merge_runs_unsorted`` (those that had to be sorted on
     entry), ``merge_rows_sorted`` (rows handed to an ordering routine:
     the rows of each unsorted batch, and a window's rows at its
-    compaction; the merged table's rows never) and ``merge_compacts``.
-    And the seconds of the ``compact`` and ``decode`` spans
-    (``compact_s``; ``finalize_decode_s``, 0.0 until the result is
-    decoded) and ``finalize_decoded_keys`` (spellings turned into
-    ``str``).
+    compaction; the merged table's rows never) and ``merge_compacts``;
+    ``merge_compacts_async`` of them were handed to a thread.  And the
+    seconds of the ``compact`` spans, on whichever thread
+    (``compact_s``), of those on the caller's own thread and its
+    ``merge_wait`` spans (``compact_caller_s``: the part of
+    ``compact_s`` the caller was held for) and of the ``decode`` span
+    (``finalize_decode_s``, 0.0 until the result is decoded), and
+    ``finalize_decoded_keys`` (spellings turned into ``str``).  The
+    caller writes every key but ``compact_s``, which is the thread's
+    while a compaction is in flight.
     """
 
     def __init__(self, compact_rows: int = 1 << 21,
@@ -338,16 +395,20 @@ class PackedCounts:
         self._window: List[_Table] = []
         self._pending = 0  # rows of the window
         self._unsorted = 0  # batches of the window sorted on entry
+        self._inflight: Optional[_Compaction] = None  # it has the table
         self._compact_rows = max(1, compact_rows)
         self.stats = {} if stats is None else stats
         for key in ("merge_rows_in", "merge_rows_sorted", "merge_compacts",
-                    "merge_runs_in", "merge_runs_unsorted"):
+                    "merge_runs_in", "merge_runs_unsorted",
+                    "merge_compacts_async"):
             self.stats.setdefault(key, 0)
+        self.stats.setdefault("compact_caller_s", 0.0)
 
     def add(self, keys: np.ndarray, lens: np.ndarray, cnts: np.ndarray,
             parts: np.ndarray) -> None:
         if len(keys) == 0:
             return
+        self._join()  # what a compaction in flight raised, raised here
         self.stats["merge_rows_in"] += len(keys)
         self.stats["merge_runs_in"] += 1
         run, arrived_sorted = _own_run(keys, lens, cnts, parts)
@@ -358,7 +419,11 @@ class PackedCounts:
         self._window.append(run)
         self._pending += len(run[0])
         if self._pending >= self._compact_rows:
-            self._compact()
+            self._join(wait=True)
+            job = _Compaction(self._table, *self._take_window(), self.stats)
+            job.start()
+            self._inflight, self._table = job, None
+            self.stats["merge_compacts_async"] += 1
 
     def add_packed_step(self, packed: np.ndarray, n_uniques,
                         kk: int) -> None:
@@ -380,22 +445,53 @@ class PackedCounts:
                     r[:, kk + 2].astype(np.int64) << 32)
             self.add(r[:, :kk], r[:, kk], cnts, r[:, -1])
 
-    def _compact(self) -> None:
-        """The window's runs into one run, that run into the table."""
-        if not self._window:
+    def _take_window(self) -> Tuple[List[_Table], dict]:
+        """The window, to whoever compacts it, with its ``compact``
+        span's fields; the next one is empty.  The counters of its
+        compaction follow the rows alone and are counted here."""
+        window = self._window
+        fields = {"rows_in": self._pending, "runs_in": len(window),
+                  "runs_unsorted": self._unsorted}
+        self.stats["merge_rows_sorted"] += self._pending
+        self.stats["merge_compacts"] += 1
+        self._window, self._pending, self._unsorted = [], 0, 0
+        return window, fields
+
+    def _join(self, wait: bool = False) -> None:
+        """The compaction in flight, if it is over or ``wait``: its
+        table taken, what it raised raised in the caller."""
+        job = self._inflight
+        if job is None:
             return
-        with _span("compact", lane="merge", stats=self.stats,
-                   rows_in=self._pending, runs_in=len(self._window),
-                   runs_unsorted=self._unsorted,
-                   table_rows=0 if self._table is None
-                   else len(self._table[0])) as sp:
-            run = _merge_runs(self._window)
-            self._table = run if self._table is None \
-                else _merge_into(self._table, run)
-            self.stats["merge_rows_sorted"] += self._pending
-            self.stats["merge_compacts"] += 1
-            self._window, self._pending, self._unsorted = [], 0, 0
-            sp.set(rows_out=len(self._table[0]))
+        if job.is_alive():
+            if not wait:
+                return
+            with _span("merge_wait", lane="merge", stats=self.stats,
+                       key="compact_caller_s"):
+                job.join()
+        self._inflight = None
+        if job.error is not None:
+            raise job.error
+        self._table = job.table
+
+    def _compact(self) -> None:
+        """The table, whole: the compaction in flight waited for, then
+        the window's runs into one run and that run into the table, on
+        the caller's thread."""
+        self._join(wait=True)
+        if self._window:
+            self._table, took = _compact_window(
+                self._table, *self._take_window(), self.stats)
+            self.stats["compact_caller_s"] += took
+
+    def close(self) -> None:
+        """For a caller that gives the accumulator up without its result
+        (a job that failed, or fell back to the host path): the
+        compaction in flight is waited for and dropped, so that no thread
+        is left behind."""
+        job, self._inflight = self._inflight, None
+        if job is not None:
+            job.join()
 
     def finalize(self) -> "PackedWordCounts":
         """The merged table as the job's result, after the last
@@ -428,6 +524,7 @@ class PackedCounts:
         (word, count) contributions were buffered, so a restored
         accumulator finalizes bit-identically to the uninterrupted
         one."""
+        self._join(wait=True)
         self._table, self._window = None, []
         self._pending = self._unsorted = 0
         if not arrays or "keys" not in arrays or len(arrays["keys"]) == 0:

@@ -1312,6 +1312,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
         if released:  # idempotent: close() after a suspend/fail re-runs it
             return
         released.append(True)
+        acc.close()  # a job that failed or fell back leaves no merger
         if ck_writer is not None:
             ck_writer.shutdown()
         fold_source_stats(stats, blocks)
@@ -1324,7 +1325,7 @@ def _wordcount_setup(step, blocks, mesh, n_reduce, chunk_bytes,
                       "ckpt_commit_s", "ckpt_barrier_s", "decode_s",
                       "ckpt_compress_s", "dispatch_s", "retire_s",
                       "enqueue_s", "drain_s", "compact_s",
-                      "finalize_decode_s"):
+                      "compact_caller_s", "finalize_decode_s"):
                 if k in stats:
                     stats[k] = round(stats[k], 4)
             pipeline_stats.update(stats)

@@ -584,6 +584,223 @@ def test_an_image_of_the_parents_layout_restores(merge_route):
                                      _parent_table(before + more))))
 
 
+# ── a full window is compacted on a merger thread ──
+
+
+class _InlineCounts(PackedCounts):
+    """The accumulator as it was until a full window went to a merger
+    thread, kept as the plain reference: every compaction on the
+    caller's thread, inside the ``add`` that filled the window."""
+
+    def add(self, keys, lens, cnts, parts):
+        compact_rows, self._compact_rows = self._compact_rows, 1 << 62
+        try:
+            super().add(keys, lens, cnts, parts)
+        finally:
+            self._compact_rows = compact_rows
+        if self._pending >= compact_rows:
+            self._compact()
+
+
+def _compact_rows_for(batches, handovers, snapshot_at=None):
+    """The largest ``compact_rows`` at which ``batches`` fill the window
+    ``handovers`` times (a snapshot before batch ``snapshot_at`` empties
+    it)."""
+    def fills(compact_rows):
+        n = pending = 0
+        for i, b in enumerate(batches):
+            if i == snapshot_at:
+                pending = 0
+            pending += len(b[0])
+            if pending >= compact_rows:
+                n, pending = n + 1, 0
+        return n
+
+    total = sum(len(b[0]) for b in batches)
+    return next(c for c in range(total, 0, -1) if fills(c) == handovers)
+
+
+#: A wait for the merger ends when its thread has ended, a little after
+#: the ``compact`` span it waited for: what ``compact_caller_s`` may read
+#: over ``compact_s`` on a busy host.
+_JOIN_SLACK_S = 0.05
+
+
+def _mergers():
+    import threading
+
+    return [t for t in threading.enumerate()
+            if t.name == "dsi-merge-compact"]
+
+
+def _tables_equal(a, b):
+    return all(x.dtype == y.dtype and x.tobytes() == y.tobytes()
+               for x, y in zip((a.skeys, a.lens, a.cnts, a.parts),
+                               (b.skeys, b.lens, b.cnts, b.parts)))
+
+
+@pytest.mark.parametrize("handovers", [1, 2, 5])
+def test_a_handed_over_window_merges_as_the_inline_compaction(
+        merge_route, handovers):
+    """Result, counters and a mid-stream checkpoint image are those of
+    the accumulator that compacts inline, whichever thread merged."""
+    batches = _sorted_batches(52, 60, vocab=900) \
+        + _merge_case("unsorted-and-duplicates")[0][:3]
+    cut = 2 * len(batches) // 3
+    compact_rows = _compact_rows_for(batches, handovers, cut)
+    stats, want_stats = {}, {}
+    acc = PackedCounts(compact_rows=compact_rows, stats=stats)
+    want = _InlineCounts(compact_rows=compact_rows, stats=want_stats)
+    for b in batches[:cut]:
+        acc.add(*b)
+        want.add(*b)
+    image, want_image = acc.snapshot(), want.snapshot()
+    assert _images_equal(image, want_image)
+    assert acc._window == [] and acc._inflight is None
+    back = PackedCounts(compact_rows=compact_rows)
+    back.restore({k: v.copy() for k, v in image.items()})
+    for b in batches[cut:]:
+        for a in (acc, want, back):
+            a.add(*b)
+    table = want.finalize()
+    assert _tables_equal(acc.finalize(), table)
+    assert _tables_equal(back.finalize(), table)
+    assert {k: stats[k] for k in _COUNTERS} \
+        == {k: want_stats[k] for k in _COUNTERS}
+    assert stats["merge_compacts"] > stats["merge_compacts_async"] \
+        == handovers
+    assert want_stats["merge_compacts_async"] == 0
+    # the inline one was held for every second of its compactions
+    assert want_stats["compact_caller_s"] == pytest.approx(
+        want_stats["compact_s"])
+    assert 0.0 < stats["compact_caller_s"] \
+        <= stats["compact_s"] + _JOIN_SLACK_S
+    assert not _mergers()
+
+
+def test_a_second_full_window_waits_for_the_first(monkeypatch):
+    """One compaction in flight at most: the ``add`` that fills the next
+    window is held, in ``compact_caller_s``, until the first is merged."""
+    import threading
+
+    from dsi_tpu.parallel import merge
+
+    gate, merging, most = threading.Event(), [], [0]
+    merge_runs = merge._merge_runs
+
+    def held(runs):
+        if threading.current_thread().name == "dsi-merge-compact":
+            merging.append(1)
+            most[0] = max(most[0], len(merging))
+            assert gate.wait(30)
+            try:
+                return merge_runs(runs)
+            finally:
+                merging.pop()
+        return merge_runs(runs)
+
+    monkeypatch.setattr(merge, "_merge_runs", held)
+    batches = _sorted_batches(53, 12, most=40)
+    stats: dict = {}
+    acc = PackedCounts(compact_rows=_compact_rows_for(batches, 2),
+                       stats=stats)
+    for b in batches:
+        acc.add(*b)
+        if stats["merge_compacts_async"] == 1:
+            break
+    assert stats["merge_runs_in"] < len(batches) and len(_mergers()) == 1
+    assert stats["compact_caller_s"] == 0.0
+    timer = threading.Timer(0.3, gate.set)
+    timer.start()
+    for b in batches[stats["merge_runs_in"]:]:
+        acc.add(*b)  # one of them fills the window, and waits
+    timer.join(30)
+    assert stats["merge_compacts_async"] == 2 and most[0] == 1
+    assert stats["compact_caller_s"] >= 0.2
+    table = acc.finalize()
+    assert stats["compact_caller_s"] <= stats["compact_s"] + _JOIN_SLACK_S
+    assert _tables_equal(table, _run_case(batches, 1 << 21)[1])
+    assert not _mergers()
+
+
+@pytest.mark.parametrize("call", ["add", "finalize", "snapshot", "restore"])
+def test_what_the_merger_raises_is_raised_in_the_caller(monkeypatch, call):
+    import threading
+
+    from dsi_tpu.parallel import merge
+
+    merge_runs = merge._merge_runs
+
+    def broken(runs):
+        if threading.current_thread().name == "dsi-merge-compact":
+            raise MemoryError("no room for the window")
+        return merge_runs(runs)
+
+    monkeypatch.setattr(merge, "_merge_runs", broken)
+    batches = _sorted_batches(54, 8)
+    acc = PackedCounts(compact_rows=_compact_rows_for(batches[:6], 1))
+    for b in batches[:6]:
+        acc.add(*b)
+    assert acc.stats["merge_compacts_async"] == 1
+    acc._inflight.join(30)  # over: the next call of any kind finds it
+    with pytest.raises(MemoryError, match="no room for the window"):
+        {"add": lambda: acc.add(*batches[6]), "finalize": acc.finalize,
+         "snapshot": acc.snapshot, "restore": lambda: acc.restore({})}[call]()
+    assert not _mergers()
+
+
+def test_no_thread_where_the_window_never_fills_or_is_given_up(monkeypatch):
+    from dsi_tpu.parallel import merge
+
+    batches = _sorted_batches(55, 25)
+    started = []
+    start = merge._Compaction.start
+    monkeypatch.setattr(merge._Compaction, "start",
+                        lambda self: (started.append(self), start(self)))
+    acc, table, stats = _run_case(batches, 1 << 21)
+    assert acc.snapshot() and not started
+    assert stats["merge_compacts_async"] == 0 \
+        and stats["merge_compacts"] == 1
+    # given up with a compaction in flight: ``close`` leaves none behind
+    acc = PackedCounts(compact_rows=_compact_rows_for(batches, 1))
+    for b in batches:
+        acc.add(*b)
+    assert len(started) == 1
+    acc.close()
+    assert not started[0].is_alive() and not _mergers()
+    acc.close()  # and again, with nothing in flight
+
+
+def test_counters_hold_while_merger_and_caller_switch_at_every_turn(
+        merge_route):
+    """The two threads share ``stats``: with the interpreter switching
+    threads as often as it can, dozens of hand-overs, unsorted batches
+    among them, lose no update of any counter."""
+    import sys
+
+    batches = _merge_case("unsorted-and-duplicates")[0] \
+        + _sorted_batches(56, 150, vocab=900)
+    rng = random.Random(56)
+    rng.shuffle(batches)
+    want_stats: dict = {}
+    want = _InlineCounts(compact_rows=48, stats=want_stats)
+    for b in batches:
+        want.add(*b)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        acc, table, stats = _run_case(batches, 48)
+    finally:
+        sys.setswitchinterval(interval)
+    assert _tables_equal(table, want.finalize())
+    assert {k: stats[k] for k in _COUNTERS} \
+        == {k: want_stats[k] for k in _COUNTERS}
+    assert stats["merge_compacts_async"] == stats["merge_compacts"] - 1 > 30
+    assert 0.0 < stats["compact_caller_s"] \
+        <= stats["compact_s"] + _JOIN_SLACK_S
+    assert not _mergers()
+
+
 def test_native_merge_refuses_a_table_it_cannot_read():
     """``mergeruns.cpp`` reads raw pointers: a column of another dtype,
     stride or length, lanes of another width, and an output without room

@@ -437,6 +437,33 @@ def test_step_tables_are_packed_at_dispatch(tmp_path, devices, vocabulary):
         assert ps["pulls_early"] >= ps["pulls_late"]
 
 
+def test_full_windows_are_compacted_beside_the_steps(tmp_path, monkeypatch):
+    """A host-merge job whose accumulator's window fills (the class's
+    default lowered: the engine has no argument for it) hands the full
+    windows to the merger thread, commits the oracle's bytes, and was
+    held for no more of its compactions than they took (a wait ends
+    with the merger's thread, a little after its ``compact`` span)."""
+    import threading
+
+    from dsi_tpu.parallel.merge import PackedCounts
+
+    monkeypatch.setattr(PackedCounts.__init__, "__defaults__", (256, None))
+    rng = np.random.default_rng(52)
+    text = _segments(rng, 24, 4096,
+                     lambda k: range(40 * k, 40 * k + 60))
+    ps, same = _wcstream_stats(tmp_path, text, "--devices", "1")
+    assert same  # the sequential reference's bytes
+    assert ps["merge_rows_in"] >= 4 * 256
+    # every window that filled, and none but the last, partial one
+    assert ps["merge_compacts"] - 1 <= ps["merge_compacts_async"] \
+        <= ps["merge_compacts"]
+    assert ps["merge_compacts_async"] >= ps["merge_rows_in"] // 512 >= 2
+    assert ps["merge_rows_sorted"] == ps["merge_rows_in"]
+    assert 0.0 < ps["compact_caller_s"] <= ps["compact_s"] + 0.05
+    assert not [t for t in threading.enumerate()
+                if t.name == "dsi-merge-compact"]
+
+
 @pytest.mark.parametrize("depth", [2, 3])
 def test_overflowing_steps_early_tensor_is_never_merged(depth):
     """Every step overflows the start rung until it widens: each such

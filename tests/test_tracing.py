@@ -492,11 +492,15 @@ def test_job_children_are_the_registrys_tuple(fresh_tracer, tmp_path,
         assert parents[name] == {"finish"}, name
     assert parents.get("replay", {"finish"}) == {"finish"}
     if command == "wcstream":
-        # a compaction belongs to whichever span handed the rows over (a
-        # replayed step merges inside its ``replay``), or to the last one
+        # a window that filled is compacted on the merger's thread, a
+        # root there; the last, partial one under ``finalize``; and a
+        # wait for the merger belongs to whichever span handed the rows
+        # over (a replayed step merges inside its ``replay``) or asked
+        # for the table
         where = {"sync"} if flags else {"merge", "replay"}
-        assert parents["compact"] & where
-        assert parents["compact"] <= where | {"finalize"}
+        assert ps["merge_compacts_async"] >= 1
+        assert None in parents["compact"] <= {None, "finalize"}
+        assert parents.get("merge_wait", set()) <= where | {"finalize"}
         # the writer reads the merged table's arrays: nothing is decoded
         assert "decode" not in parents
         assert parents["format"] == parents["commit"] == {"write"}
@@ -839,12 +843,31 @@ def test_every_span_name_is_in_one_starved_group():
     # the names of a stream or plan job's main thread are all listed
     for name in ("start", "read", "read_wait", "sample", "wait", "plan",
                  "dispatch", "upload", "enqueue", "relay_append", "finish",
-                 "pull", "merge", "compact", "replay", "fold", "sync",
+                 "pull", "merge", "compact", "merge_wait", "replay", "fold",
+                 "sync",
                  "widen", "group", "ckpt", "drain", "finalize", "decode",
                  "write", "format", "commit", "report", "job"):
         assert name in listed, name
     assert {name for name, _ in DEVICE_BLOCKED} <= obs_trace.SPAN_NAMES
     assert {lane for _, lane in DEVICE_BLOCKED} <= set(obs_trace.LANES)
+
+
+def test_the_mergers_wait_and_keys_are_registered():
+    """``merge_wait``, the accumulator's caller held by a compaction in
+    flight, is a span of the ``merge`` lane and group; the two keys the
+    accumulator adds to its scope are the schema's."""
+    from dsi_tpu.obs.registry import (COUNTER_KEYS, PHASE_KEYS,
+                                      STARVED_GROUPS)
+    from dsi_tpu.parallel.merge import PackedCounts
+
+    assert "merge_wait" in obs_trace.SPAN_NAMES
+    assert "merge_wait" in dict(STARVED_GROUPS)["merge"]
+    assert obs_trace._GROUP_OF["merge_wait"] == "merge"
+    assert "compact_caller_s" in PHASE_KEYS
+    assert "merge_compacts_async" in COUNTER_KEYS
+    stats = PackedCounts().stats
+    assert stats["merge_compacts_async"] == 0
+    assert stats["compact_caller_s"] == 0.0
 
 
 def test_a_device_blocked_span_is_never_starved(account):
