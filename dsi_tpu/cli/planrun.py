@@ -58,12 +58,22 @@ Chains:
               concatenation of its records in key order and every key
               of mr-out-<r> less than or equal to every key of
               mr-out-<r+1>: their concatenation in partition order is
-              the sorted input.  One device (--devices 1, the
-              default for this chain).  A file whose length is not a
-              multiple of 100 is refused before any stage; there is
-              no host path: --staged fails the job (exit 1, nothing
-              committed); --check, --hosts, --checkpoint-dir,
-              --pipeline and --stage-shards are not this chain's.
+              the sorted input.  --devices N (default 1) sorts across
+              N devices: the same sample gives N - 1 device split
+              points beside the partitions', every device owns one key
+              range, a step is --chunk-bytes a device, and every
+              record goes to the device that owns its key through the
+              mesh's all_to_all, is ordered there and pulled from
+              there, device 0's records first; the committed bytes are
+              --devices 1's.  A device's store holds its share of the
+              records by the sample and a sixteenth more: a key range
+              the sample undercounts by more than that fails the job
+              (exit 1, nothing committed, the device named).  A file
+              whose length is not a multiple of 100 is refused before
+              any stage; there is no host path: --staged fails the job
+              (exit 1, nothing committed); --check, --hosts,
+              --checkpoint-dir, --pipeline and --stage-shards are not
+              this chain's.
 
   agg       — one stage: SELECT key, SUM(value) ... GROUP BY key over
               files of rows f0|f1|... ended by a newline (Pavlo et al.,
@@ -293,6 +303,7 @@ def _job(args, pstats: dict, opened: list):
         from dsi_tpu.ckpt import CheckpointMismatch
         from dsi_tpu.ops.fieldsum import BadRow
         from dsi_tpu.parallel.shuffle import default_mesh
+        from dsi_tpu.parallel.sortstream import StoreOverfull
         from dsi_tpu.plan import PlanHostPath, run_plan
         from dsi_tpu.plan.stagehost import build_plan
 
@@ -335,8 +346,9 @@ def _job(args, pstats: dict, opened: list):
         # length was taken from: nothing is committed.
         print(f"planrun: {e}", file=sys.stderr)
         return 1, None
-    except BadRow as e:
-        # --chain agg: a row that cannot be read fails the job.
+    except (BadRow, StoreOverfull) as e:
+        # --chain agg: a row that cannot be read fails the job; --chain
+        # sort: so does a device whose key range outgrew its store.
         print(f"planrun: {e}", file=sys.stderr)
         return 1, None
     except PlanHostPath as e:
@@ -569,10 +581,10 @@ def _main(argv, opened: list) -> int:
                 p.error(f"--{flag.replace('_', '-')} is not --chain "
                         "sort's: the chain has one handoff mode and "
                         "commits once, after its last stage")
-        if args.devices not in (None, 1):
-            p.error("--chain sort orders one worker's share on one "
-                    "device: --devices 1")
-        args.devices = 1
+        if args.devices is not None and args.devices < 1:
+            p.error("--chain sort orders its records on --devices 1 or "
+                    "more")
+        args.devices = args.devices or 1
     if args.agg_prefix and args.chain != "agg":
         p.error("--agg-prefix cuts the key of --chain agg")
     if args.chain == "agg":

@@ -191,7 +191,16 @@ sampled split points), ``order_s`` (the ``order`` span: the host
 blocked on the device's ordering) and ``sort_order_passes`` (its
 single-key sort passes).  The commit's ``pull_s`` / ``d2h_s`` /
 ``pull_bytes`` / ``write_commit_s`` stand at the top of ``planrun``'s
-``pipeline_stats`` beside ``write_s``.
+``pipeline_stats`` beside ``write_s``.  On a mesh (``--devices`` 2 or
+more) the scope also holds ``sort_devices``, ``sort_device_capacity``
+(rows of a device's store: its share by the sample, a sixteenth more,
+one step's landing block), ``sort_exchange_rows`` and
+``sort_exchange_bytes`` (the records, and their 100-byte bytes, whose
+owner is not the device that read them: counted on the device, a step
+at a time, beside the partitions' counts); ``device_rows`` is then the
+records a device holds when the ordering starts and
+``sort_resident_bytes`` the stores' and lanes' bytes summed over the
+devices.
 
 The aggregation chain (``planrun --chain agg``: the stream engine with
 ``ops/fieldsum.FieldSum`` as its map; the scope under ``stage_stats`` is
@@ -507,6 +516,10 @@ COUNTER_KEYS = (
     # of the ordering
     "sort_records", "sort_sample_keys", "sort_resident_bytes",
     "sort_partition_rows", "sort_order_passes",
+    # across a mesh: its devices, rows of a device's store, records
+    # (and their bytes) that left the device that read them
+    "sort_devices", "sort_device_capacity", "sort_exchange_rows",
+    "sort_exchange_bytes",
     # the aggregation chain (the stream engine with a map,
     # ops/fieldsum.py): rows read, keys of the merged table, lanes a sum
     "agg_rows", "agg_groups", "agg_value_lanes",

@@ -716,30 +716,31 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
 
         fault_point(f"plan-stage{i}-advance")
         books = _Books()
-        splits = sample_splits(
+        points = sample_splits(
             list(plan.param(stage, "paths")),
             int(plan.param(stage, "n_reduce", 10)),
-            int(plan.param(stage, "sample", 100_000)), stats=books.stats)
+            int(plan.param(stage, "sample", 100_000)), stats=books.stats,
+            n_dev=int(mesh.devices.size))
         _note_stage(sc, sp, stage, [books])
-        return StageOut(result=splits)
+        return StageOut(result=points)
 
     if stage.kind == "range_sort":
         from dsi_tpu.parallel.sortstream import range_sort
 
         fault_point(f"plan-stage{i}-advance")
-        if staged or mesh.devices.size != 1:
+        if staged:
             # No host fallback commits a sort: the records' way through
-            # the device IS the chain.
+            # the device, or across the mesh's, IS the chain.
             raise PlanHostPath(
-                f"stage {stage.name!r}: the sort needs the host path ("
-                + ("a staged run materializes on the host" if staged else
-                   f"{mesh.devices.size} devices, it runs on one") + ")")
-        splits = ctx[stage.deps[0]].result
+                f"stage {stage.name!r}: the sort needs the host path (a "
+                "staged run materializes on the host)")
+        points = ctx[stage.deps[0]].result
         # What the stage ran with is part of what it is: the plan's
-        # signature carries the split points as a CRC from here on.
-        stage.params["splits"] = splits.tobytes()
+        # signature carries the partitions' split points as a CRC from
+        # here on (the devices' are the layout's, not the answer's).
+        stage.params["splits"] = points.partitions.tobytes()
         books = _Books()
-        ordered = range_sort(list(plan.param(stage, "paths")), splits,
+        ordered = range_sort(list(plan.param(stage, "paths")), points,
                              mesh=mesh, chunk_bytes=kw["chunk_bytes"],
                              depth=kw["depth"], stats=books.stats)
         books.bytes_in = ordered.records * 100

@@ -37,13 +37,16 @@ the one PR 49 did):
 * ``sample``        — TeraSort's sampling pre-pass over files of
   100-byte records (``parallel/sortstream.sample_splits``, host side):
   ``sample`` keys at evenly spaced record offsets, sorted, ``n_reduce`` - 1
-  split points at the equal-count positions.  The split points are the
+  split points at the equal-count positions and, for a mesh, one fewer
+  device split points than it has devices.  The split points are the
   stage's result.
 * ``range_sort``    — the sort that consumes an upstream ``sample``'s
   split points (``parallel/sortstream.range_sort``): every record through
-  a device step into a store that stays on the device, ordered there by
-  key; the stage's result is the ordered store, which ``planrun --chain
-  sort`` pulls and commits as ``mr-out-<r>``, totally ordered.  The split
+  a device step into a store that stays on the device (on a mesh: through
+  the ``all_to_all`` into the store of the device that owns its key
+  range), ordered there by key; the stage's result is the ordered
+  store, which ``planrun --chain sort`` pulls and commits as
+  ``mr-out-<r>``, totally ordered.  The split
   points it ran with enter the stage's identity, and so the plan's
   signature, as a CRC (``splits``).  OSDI'04 section 5.3's own shape.
 * ``aggregate``     — ``SELECT key, SUM(value) ... GROUP BY key`` over

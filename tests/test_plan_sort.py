@@ -63,12 +63,12 @@ def _generated(seed, counts):
     return blobs
 
 
-def _run(paths, workdir, *flags, n_reduce=10, chunk=CHUNK):
+def _run(paths, workdir, *flags, n_reduce=10, chunk=CHUNK, devices=1):
     err = io.StringIO()
     with contextlib.redirect_stderr(err), \
             contextlib.redirect_stdout(io.StringIO()):
         try:
-            rc = cli.main(["--chain", "sort", "--devices", "1",
+            rc = cli.main(["--chain", "sort", "--devices", str(devices),
                            "--nreduce", str(n_reduce), "--chunk-bytes",
                            str(chunk), "--stats", "--workdir", workdir,
                            *flags, *paths])
@@ -225,8 +225,8 @@ def test_no_record_at_all_commits_empty_partitions(tmp_path):
 
 def test_split_points_are_a_function_of_the_input_alone(three_files,
                                                         tmp_path):
-    first = sortstream.sample_splits(three_files, 10)
-    again = sortstream.sample_splits(three_files, 10)
+    first = sortstream.sample_splits(three_files, 10).partitions
+    again = sortstream.sample_splits(three_files, 10).partitions
     assert first.dtype == np.uint32 and first.shape == (9, 3)
     assert first.tobytes() == again.tobytes()
     # as the reference's sampler has them
@@ -298,20 +298,23 @@ def test_partition_kernel_against_bisect_on_the_boundary_keys():
 
 def test_there_is_no_host_path(three_files, tmp_path):
     """``--staged`` would materialize on the host: the job fails and
-    commits nothing; so does a mesh of more than one device."""
+    commits nothing.  A mesh of more than one device is no host path: it
+    sorts (``tests/test_plan_sort_mesh.py``)."""
     rc, text, ps = _run(three_files, str(tmp_path / "wd"), "--staged")
     assert rc == 1 and ps is None
     assert "needs the host path" in text
     assert not os.path.exists(str(tmp_path / "wd"))
     with pytest.raises(PlanHostPath):
         run_plan(sort_plan(three_files, chunk_bytes=CHUNK),
-                 mesh=default_mesh(2))
+                 mesh=default_mesh(2), staged=True)
+    res = run_plan(sort_plan(three_files, chunk_bytes=CHUNK),
+                   mesh=default_mesh(2))
+    assert res.final.records == 6270 and len(res.final.stores) == 2
 
 
 @pytest.mark.parametrize("flag", [("--check",), ("--hosts",),
                                   ("--checkpoint-dir", "ck"),
-                                  ("--pipeline",), ("--stage-shards", "2"),
-                                  ("--devices", "2")])
+                                  ("--pipeline",), ("--stage-shards", "2")])
 def test_flags_that_are_not_this_chains_are_refused(three_files, tmp_path,
                                                     flag):
     rc, text, ps = _run(three_files, str(tmp_path / "wd"), *flag)
