@@ -48,7 +48,7 @@ def main(argv=None) -> int:
                    help="jobs held in memory at once; the rest park as "
                         "checkpoint chains until scheduled")
     p.add_argument("--quota-steps", type=int, default=64,
-                   help="confirmed steps a resident job may take while "
+                   help="packed steps a resident job may ride while "
                         "others queue before it is evicted to its "
                         "chain")
     p.add_argument("--checkpoint-every", type=int, default=8,

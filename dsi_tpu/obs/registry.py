@@ -257,7 +257,20 @@ count) and ``serve_grep`` carry ``packed_steps`` / ``packed_rows`` /
 ``max_tenants_per_step`` / ``host_fallbacks`` and the phases of one
 packed step, ``take_s`` (the ``take_row`` spans: a lane's next row cut
 from its input on the scheduler thread), ``upload_s``, ``kernel_s``,
-``pull_s``, ``merge_s``.  The daemon's own scope ``serve_daemon``
+``pull_s``, ``merge_s``.  The grep packer keeps one step in flight
+(``serve/pack.py``): ``upload_s`` is one ``device_put`` of a step's
+operands, ``kernel_s`` the program's call and the starts of its
+results' copies to the host (it returns before the device has run
+anything), and ``pull_s`` the three reads, taken one call of ``step``
+later, so it holds what of the device's time and of the copies the
+host's work in between did not cover.  Its ``packed_steps`` and
+``packed_rows`` count confirmed steps; ``results_ready`` counts those
+whose program the device had finished when the host came to read them
+(asked as the pipeline core asks it, ``Tracer.landed``), and
+``settles`` those confirmed ahead of their turn, because a lane with a
+row in them was snapshotted for an eviction or finalized.  The word-
+count packer reads each step where it dispatched it.  The daemon's own
+scope ``serve_daemon``
 carries ``submits`` and ``submit_s`` (the ``Submit`` handler:
 validation, the durable journal write, the reply), ``admit_s`` (runner
 construction and chain load), ``evict_s`` and ``evictions`` (a park: the
@@ -539,6 +552,10 @@ COUNTER_KEYS = (
     # serving daemon (the "serve"/"serve_grep" scopes, serve/pack.py)
     "packed_steps", "packed_rows", "max_tenants_per_step",
     "host_fallbacks",
+    # the grep packer's one step in flight: steps confirmed ahead of
+    # their turn for a lane's eviction or finalize (``results_ready``,
+    # above, counts those whose results the device had finished)
+    "settles",
     # the daemon's own counts (the "serve_daemon" scope) and, per job,
     # the seconds from submission to its first row and from there to
     # its committed output (a job record's ``stats``)

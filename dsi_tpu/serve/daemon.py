@@ -890,7 +890,10 @@ class ServeDaemon:
                     self._fail_lanes(wc_lanes, e, "packed step")
                     worked = True
             # One packed grep step over ONE pattern-length group —
-            # groups rotate across scheduler iterations.
+            # groups rotate across scheduler iterations.  The packer
+            # keeps one step in flight: a call confirms the rows the
+            # call before dispatched, and the wall a tenant's histogram
+            # is told is that of the turn its row was confirmed in.
             grep_lanes = [(jid, rec["lane"])
                           for jid, rec in resident.items()
                           if rec["kind"] == "grep"
@@ -904,10 +907,17 @@ class ServeDaemon:
                     self._note_first_rows(grep_lanes)
                     for ln in confirmed:
                         self._hist.record(ln.tenant, wall)
-                    worked = worked or bool(confirmed) or any(
-                        not ln.runnable for _, ln in grep_lanes)
+                    # A call that dispatched and confirmed nothing (the
+                    # first of a burst) has left work for the next one.
+                    worked = worked or bool(confirmed) \
+                        or self.grep_packer.in_flight or any(
+                            not ln.runnable for _, ln in grep_lanes)
                 except Exception as e:  # noqa: BLE001
-                    self._fail_lanes(grep_lanes, e, "packed grep step")
+                    # A step that failed says whose rows it lost: their
+                    # jobs fail, and no others.
+                    lost = [p for p in grep_lanes if p[1].lost is not None]
+                    self._fail_lanes(lost or grep_lanes, e,
+                                     "packed grep step")
                     worked = True
             # A bounded slice of every step-object job — the same
             # ``advance_slice`` primitive the shard workers drive their

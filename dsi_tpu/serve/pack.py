@@ -53,10 +53,17 @@ lane.  :class:`PackedGrepScheduler` therefore groups runnable
 program of that shape — and fills one ``[n_dev, chunk_bytes]`` dispatch
 round-robin across the group's tenants.  The step program keeps no
 per-line buffer, so a tenant's line lengths change nothing: every row of
-a dispatch is confirmed in the step that carried it, in take order
+a dispatch is confirmed with the step that carried it, in take order
 (which is byte-range order).  Per-lane line-number bases are assigned
 host-side at row-take time, so per-tenant output stays byte-identical
 to the tenant running alone — the same parity bar the wc lanes carry.
+One packed step is in flight: a call of :meth:`PackedGrepScheduler.step`
+dispatches the next step, whose results start for the host behind the
+program, and then confirms the step the call before dispatched, whose
+copies have landed under the host's work in between (the stream
+engine's ``step_call`` rule).  A lane that is suspended or finalized
+with a row in flight has the scheduler confirm that step first, so a
+snapshot's cursor and a result always stand at a confirmed row.
 """
 
 from __future__ import annotations
@@ -77,7 +84,9 @@ from dsi_tpu.ckpt import (
     fault_point,
     skip_stream,
 )
-from dsi_tpu.obs import count as _count, metrics_scope, span as _span
+from dsi_tpu.obs import (count as _count, enqueued as _enqueued,
+                         get_tracer as _get_tracer, metrics_scope,
+                         span as _span)
 from dsi_tpu.ops.wordcount import rung0_cap
 from dsi_tpu.parallel.merge import PackedCounts
 from dsi_tpu.parallel.shuffle import write_partitioned_output
@@ -471,6 +480,18 @@ class _GrepRow(NamedTuple):
     base: int
 
 
+class _Flight(NamedTuple):
+    """One dispatched packed step that is not confirmed yet: its rows in
+    take order, the three device arrays whose copies to the host are
+    under way, what its spans say of it, and the program as the tracer
+    was told it (:meth:`~dsi_tpu.obs.trace.Tracer.newest`)."""
+
+    picks: List[Tuple["GrepLane", _GrepRow]]
+    outs: tuple
+    batch: Dict
+    told: Optional[Tuple]
+
+
 class GrepLane:
     """One tenant grep job's lane in :class:`PackedGrepScheduler`: a
     newline-aligned row stream cut from its input files, host-side
@@ -512,6 +533,9 @@ class GrepLane:
         self.rows_taken = 0           # index into self.offsets
         self.confirmed_rows = 0
         self.steps = 0
+        # The eviction-quota clock: packed steps ridden, the one in
+        # flight too, so a residency holds as many rows as it did when
+        # a step was confirmed in the call that dispatched it.
         self.steps_since_resume = 0
         self.hostpath = not (self.m and is_literal_pattern(self.pattern)
                              and self.m <= self.chunk_bytes)
@@ -520,6 +544,12 @@ class GrepLane:
         self.stats: Dict = {} if stats is None else stats  # as TenantLane
         self.first_take_ts: Optional[float] = None
         self._next_base = 0
+        #: the scheduler that took this lane's rows, once it has
+        self._sched: Optional["PackedGrepScheduler"] = None
+        #: what a step that carried a row of this lane failed with: the
+        #: row's results are gone, so the job fails and nothing more of
+        #: the lane is folded or saved
+        self.lost: Optional[Exception] = None
         ident = {"tenant": self.tenant, "pattern": self.pattern,
                  "files": [[os.path.basename(f), os.path.getsize(f)]
                            for f in job["files"]],
@@ -560,7 +590,8 @@ class GrepLane:
 
     @property
     def runnable(self) -> bool:
-        return not (self.hostpath or self.input_done)
+        return not (self.hostpath or self.input_done
+                    or self.lost is not None)
 
     def take_row(self) -> Optional[_GrepRow]:
         """The next row, pulled (and base-numbered) from the stream.
@@ -610,7 +641,6 @@ class GrepLane:
         """One packed step confirmed rows for this lane: count it and
         maybe checkpoint (the wc lanes' cadence discipline)."""
         self.steps += 1
-        self.steps_since_resume += 1
         self.policy.note_step()
         if self.policy.due():
             self.save_ckpt()
@@ -630,8 +660,19 @@ class GrepLane:
             sp.set(bytes=self.store.last_payload_bytes)
         _count("ckpt_saves")
 
+    def _settle(self) -> None:
+        """Stand at a confirmed row: the scheduler confirms the step in
+        flight now if it carries a row of this lane.  A lane that lost a
+        row raises what the step failed with."""
+        if self._sched is not None:
+            self._sched.drain(self)
+        if self.lost is not None and not self.hostpath:
+            raise self.lost
+
     def suspend(self) -> None:
-        """Evict: one forced durable snapshot; dead after."""
+        """Evict: one forced durable snapshot, at the end of the last
+        row taken; dead after."""
+        self._settle()
         if not self.hostpath:
             self.save_ckpt()
         self.writer.drain()
@@ -646,6 +687,7 @@ class GrepLane:
                                                  merge_topk)
         from dsi_tpu.parallel.streaming import stream_files
 
+        self._settle()
         if self.hostpath:
             res = grep_host_oracle(stream_files(self.job["files"]),
                                    self.pattern, bins=self.bins,
@@ -657,6 +699,13 @@ class GrepLane:
         self.writer.drain()
         self.writer.shutdown()
         return res
+
+
+def _lose(picks: List[Tuple[GrepLane, _GrepRow]], e: Exception) -> None:
+    """The step that carried ``picks`` failed with ``e``: their results
+    are gone, so their lanes' jobs fail, and no others."""
+    for lane, _info in picks:
+        lane.lost = e
 
 
 class PackedGrepScheduler:
@@ -683,12 +732,14 @@ class PackedGrepScheduler:
         self.topk = int(topk if topk is not None else DEFAULT_TOPK)
         self.stats = metrics_scope("serve_grep")
         self.stats.update({"packed_steps": 0, "packed_rows": 0,
+                           "results_ready": 0, "settles": 0,
                            "host_fallbacks": 0, "upload_s": 0.0,
                            "kernel_s": 0.0, "pull_s": 0.0,
                            "merge_s": 0.0, "take_s": 0.0,
                            "max_tenants_per_step": 0})
         self._sh_chunk = NamedSharding(mesh, P(AXIS, None))
         self._rr = 0
+        self._flight: Optional[_Flight] = None
         self._jax = jax
 
     def warm(self, m: int) -> None:
@@ -717,39 +768,132 @@ class PackedGrepScheduler:
         return groups[key]
 
     def _dispatch(self, chunk_np, pats_np, lens_np, bases_np, m, batch):
-        """Put, run, read: one packed step.  ``batch`` is what its spans
-        say of it (``rows``, ``tenants``)."""
-        from dsi_tpu.device.table import _quiet_unusable_donation
+        """Put and enqueue: one ``device_put`` of the step's operands,
+        the program's call, and the starts of its results' copies to the
+        host, right behind it on the device's queue.  Returns the three
+        device arrays and reads nothing: :meth:`_confirm` does, one step
+        later, when they are finished copies.  ``batch`` is what the
+        step's spans say of it (``rows``, ``tenants``)."""
+        from dsi_tpu.device.table import (_copy_to_host_async,
+                                          _quiet_unusable_donation)
         from dsi_tpu.parallel.grepstream import grep_pack_fn, step_meta
         from dsi_tpu.utils.jaxcompat import enable_x64
 
         with _span("upload", stats=self.stats, key="upload_s", **batch):
-            chunk = self._jax.device_put(chunk_np, self._sh_chunk)
-            pats = self._jax.device_put(pats_np, self._sh_chunk)
             with enable_x64(True):   # keep the u64 bases u64 through it
-                meta = self._jax.device_put(step_meta(lens_np, bases_np),
-                                            self._sh_chunk)
+                chunk, pats, meta = self._jax.device_put(
+                    (chunk_np, pats_np, step_meta(lens_np, bases_np)),
+                    (self._sh_chunk,) * 3)
         fn = grep_pack_fn(self.n_dev, self.chunk_bytes, m,
                           bins=self.bins, k=self.topk, mesh=self.mesh)
         with _span("kernel", stats=self.stats, key="kernel_s", **batch):
             with _quiet_unusable_donation():
-                hist_ext, cand, scal = fn(chunk, pats, meta)
-        with _span("pull", stats=self.stats, key="pull_s", **batch):
-            return (np.asarray(hist_ext), np.asarray(cand),
-                    np.asarray(scal))
+                outs = fn(chunk, pats, meta)   # (hist_ext, cand, scal)
+            _enqueued(outs[2])
+            for arr in outs:
+                _copy_to_host_async(arr)
+        return outs
+
+    def _launch(self, picks: List[Tuple[GrepLane, _GrepRow]],
+                m: int) -> _Flight:
+        """Fill one batch with ``picks`` and dispatch it; a dispatch
+        that fails loses their rows (:func:`_lose`)."""
+        chunk_np = np.zeros((self.n_dev, self.chunk_bytes), np.uint8)
+        pats_np = np.zeros((self.n_dev, m), np.uint8)
+        lens_np = np.zeros(self.n_dev, dtype=np.int32)
+        bases_np = np.zeros(self.n_dev, dtype=np.int64)
+        # Idle rows carry slot-0's pattern over an all-zero chunk: a
+        # printable-ASCII pattern cannot match zero padding, so they
+        # contribute nothing (the kernel's padding argument).
+        pats_np[:] = np.frombuffer(picks[0][0].pat, dtype=np.uint8)
+        for slot, (lane, info) in enumerate(picks):
+            chunk_np[slot, :len(info.row)] = info.row
+            pats_np[slot] = np.frombuffer(lane.pat, dtype=np.uint8)
+            lens_np[slot] = info.dlen
+            bases_np[slot] = info.base
+            lane._sched = self
+        n_tenants = len({ln.tenant for ln, _i in picks})
+        batch = {"rows": len(picks), "tenants": n_tenants}
+        tracer = _get_tracer()
+        before = tracer.enqueued_n
+        try:
+            outs = self._dispatch(chunk_np, pats_np, lens_np, bases_np, m,
+                                  batch)
+        except Exception as e:
+            _lose(picks, e)
+            raise
+        for lane in dict.fromkeys(ln for ln, _info in picks):
+            lane.steps_since_resume += 1
+        if n_tenants > self.stats["max_tenants_per_step"]:
+            self.stats["max_tenants_per_step"] = n_tenants
+        return _Flight(picks, outs, batch, tracer.newest(before))
+
+    def _confirm(self, rec: _Flight) -> List[GrepLane]:
+        """Read one dispatched step's results and fold them: per-row
+        demux in take order, which IS each lane's byte-range order, so
+        every lane's cursor advances monotonically.  Returns the lanes
+        that confirmed rows.  A read that fails loses the step's rows
+        (:func:`_lose`)."""
+        if rec.told is not None and _get_tracer().landed(*rec.told):
+            self.stats["results_ready"] += 1
+        try:
+            with _span("pull", stats=self.stats, key="pull_s",
+                       **rec.batch):
+                hist_np, cand_np, scal_np = (np.asarray(a)
+                                             for a in rec.outs)
+        except Exception as e:
+            _lose(rec.picks, e)
+            raise
+        with _span("merge", stats=self.stats, key="merge_s", **rec.batch):
+            for slot, (lane, info) in enumerate(rec.picks):
+                if lane.lost is not None:
+                    continue  # it lost an earlier row: nothing more folds
+                n_cand = int(scal_np[slot, 0])
+                pairs = [((int(cand_np[slot, i, 0]) << 32)
+                          | int(cand_np[slot, i, 1]),
+                          int(cand_np[slot, i, 3]))
+                         for i in range(n_cand)]
+                lane.confirm_row(info, hist_np[slot], pairs,
+                                 int(scal_np[slot, 3]),
+                                 int(scal_np[slot, 4]))
+        confirmed = list(dict.fromkeys(
+            lane for lane, _info in rec.picks if lane.lost is None))
+        fault_point("mid-fold")
+        for lane in confirmed:
+            lane.note_step()
+        self.stats["packed_steps"] += 1
+        self.stats["packed_rows"] += len(rec.picks)
+        _count("packed_steps")
+        _count("packed_rows", len(rec.picks))
+        return confirmed
+
+    def drain(self, lane: GrepLane) -> None:
+        """Confirm the step in flight now, ahead of its turn, if it
+        carries a row of ``lane``: what a lane asks before it is
+        snapshotted for an eviction or finalized."""
+        rec = self._flight
+        if rec is not None and any(ln is lane for ln, _info in rec.picks):
+            self._flight = None
+            self.stats["settles"] += 1
+            self._confirm(rec)
+
+    @property
+    def in_flight(self) -> bool:
+        """Whether a dispatched step waits for the next call of
+        :meth:`step` to confirm it."""
+        return self._flight is not None
 
     def step(self, lanes: List[GrepLane]) -> List[GrepLane]:
         """Pack up to ``n_dev`` pending rows from ONE shape group
         (round-robin across its tenants; a lone tenant may fill every
-        row) into one dispatch; demux per row and confirm every row, in
-        take order (a lane's byte-range order).  Returns the lanes that
-        confirmed rows."""
+        row) into one dispatch, then confirm the step the call before
+        dispatched, whose results have reached the host meanwhile.
+        Returns the lanes that confirmed rows: none for the first call of
+        a burst, and the last step's for a call that found no row to
+        take."""
         group = self._pick_group(lanes)
-        if not group:
-            return []
-        m = group[0].m
         picks: List[Tuple[GrepLane, _GrepRow]] = []
-        while len(picks) < self.n_dev:
+        while group and len(picks) < self.n_dev:
             progressed = False
             for lane in group:
                 if len(picks) >= self.n_dev:
@@ -768,47 +912,9 @@ class PackedGrepScheduler:
                 progressed = True
             if not progressed:
                 break
-        if not picks:
-            return []
-        chunk_np = np.zeros((self.n_dev, self.chunk_bytes), np.uint8)
-        pats_np = np.zeros((self.n_dev, m), np.uint8)
-        lens_np = np.zeros(self.n_dev, dtype=np.int32)
-        bases_np = np.zeros(self.n_dev, dtype=np.int64)
-        # Idle rows carry slot-0's pattern over an all-zero chunk: a
-        # printable-ASCII pattern cannot match zero padding, so they
-        # contribute nothing (the kernel's padding argument).
-        pats_np[:] = np.frombuffer(picks[0][0].pat, dtype=np.uint8)
-        for slot, (lane, info) in enumerate(picks):
-            chunk_np[slot, :len(info.row)] = info.row
-            pats_np[slot] = np.frombuffer(lane.pat, dtype=np.uint8)
-            lens_np[slot] = info.dlen
-            bases_np[slot] = info.base
-        n_tenants = len({ln.tenant for ln, _i in picks})
-        batch = {"rows": len(picks), "tenants": n_tenants}
-        hist_np, cand_np, scal_np = self._dispatch(
-            chunk_np, pats_np, lens_np, bases_np, m, batch)
-        fault_point("post-dispatch")
-        # Per-row demux: slots in take order ARE each lane's byte-range
-        # order, so confirming them in slot order advances every lane's
-        # cursor monotonically.
-        with _span("merge", stats=self.stats, key="merge_s", **batch):
-            for slot, (lane, info) in enumerate(picks):
-                n_cand = int(scal_np[slot, 0])
-                pairs = [((int(cand_np[slot, i, 0]) << 32)
-                          | int(cand_np[slot, i, 1]),
-                          int(cand_np[slot, i, 3]))
-                         for i in range(n_cand)]
-                lane.confirm_row(info, hist_np[slot], pairs,
-                                 int(scal_np[slot, 3]),
-                                 int(scal_np[slot, 4]))
-        confirmed = list(dict.fromkeys(lane for lane, _info in picks))
-        fault_point("mid-fold")
-        for lane in confirmed:
-            lane.note_step()
-        self.stats["packed_steps"] += 1
-        self.stats["packed_rows"] += len(picks)
-        _count("packed_steps")
-        _count("packed_rows", len(picks))
-        if n_tenants > self.stats["max_tenants_per_step"]:
-            self.stats["max_tenants_per_step"] = n_tenants
-        return confirmed
+        new = None
+        if picks:
+            new = self._launch(picks, group[0].m)
+            fault_point("post-dispatch")
+        prev, self._flight = self._flight, new
+        return self._confirm(prev) if prev is not None else []

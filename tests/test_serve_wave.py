@@ -314,3 +314,263 @@ def test_mrserve_and_mrsubmit_clis_serve_a_job_and_print_the_stats(
             "kernel", "pull", "merge"} <= names
     assert head["counters"]["packed_steps"] == \
         stats["serve_grep"]["packed_steps"]
+
+
+# ── one packed step in flight (PR 54) ──────────────────────────────────
+# The scheduler alone, one chip, two lanes that alternate: a call of
+# ``step`` dispatches a row and confirms the one the call before
+# dispatched.  Nobody who holds a lane learns of the lag.
+
+
+def _lane(tmp_path, files, name, tag="", every=2):
+    """Tenant ``a``'s or ``b``'s lane (one pattern length, so one group)
+    over two of the cell's files, 16 KiB in rows of 1 KiB; it resumes the
+    chain its checkpoint directory holds, if any."""
+    from dsi_tpu.serve.pack import GrepLane
+
+    fs, pattern = {"a": (files[0:2], "the"), "b": (files[2:4], "and")}[name]
+    job = {"tenant": name, "pattern": pattern, "files": list(fs)}
+    return GrepLane(job, CHUNK, str(tmp_path / (name + tag)),
+                    checkpoint_every=every)
+
+
+def _pair(tmp_path, files, tag="", every=2):
+    return (_lane(tmp_path, files, "a", tag, every),
+            _lane(tmp_path, files, "b", tag, every))
+
+
+def _sched():
+    from dsi_tpu.parallel.shuffle import default_mesh
+    from dsi_tpu.serve.pack import PackedGrepScheduler
+
+    return PackedGrepScheduler(mesh=default_mesh(1), chunk_bytes=CHUNK)
+
+
+def _oracle(lane):
+    from dsi_tpu.parallel.grepstream import grep_host_oracle
+    from dsi_tpu.parallel.streaming import stream_files
+
+    return grep_host_oracle(stream_files(lane.job["files"]), lane.pattern)
+
+
+def _alternate(sched, a, b, calls=None):
+    """``step`` with the two lanes' order swapped call by call, so that
+    with one row a step they take turns; to the end of both inputs, or
+    for ``calls`` calls."""
+    n = 0
+    while (a.runnable or b.runnable) and (calls is None or n < calls):
+        sched.step([a, b] if n % 2 == 0 else [b, a])
+        n += 1
+    return n
+
+
+def _taken_end(lane):
+    """The stream offset just past the last row the lane has taken."""
+    return lane.start_offset + lane.offsets[lane.rows_taken - 1]
+
+
+def test_a_lane_suspended_with_a_row_in_flight_stands_at_that_row(
+        tmp_path, files):
+    sched = _sched()
+    a, b = _pair(tmp_path, files, "-through")
+    _alternate(sched, a, b)
+    through = a.finalize(), b.finalize()
+    assert through == (_oracle(a), _oracle(b))
+
+    a, b = _pair(tmp_path, files)
+    _alternate(sched, a, b, calls=7)     # a: rows 0 2 4 6, b: 1 3 5
+    assert sched.in_flight and a.rows_taken == 4
+    assert a.confirmed_rows == 3 and a.cursor < _taken_end(a)
+    settles = sched.stats["settles"]
+    a.suspend()                          # the row in flight is a's
+    assert not sched.in_flight and sched.stats["settles"] == settles + 1
+    assert a.confirmed_rows == 4 and a.cursor == _taken_end(a)
+    b.suspend()                          # nothing of b's is in flight
+    assert sched.stats["settles"] == settles + 1
+    assert b.confirmed_rows == b.rows_taken == 3
+    a2, b2 = _pair(tmp_path, files)      # the same chains, resumed
+    assert (a2.start_offset, b2.start_offset) == (a.cursor, b.cursor)
+    assert a2.lines == a.lines and a2.confirmed_rows == 4
+    _alternate(sched, a2, b2)
+    assert (a2.finalize(), b2.finalize()) == through
+
+
+def test_finalize_with_a_row_in_flight_returns_the_whole_result(
+        tmp_path, files):
+    sched = _sched()
+    a, b = _pair(tmp_path, files)
+    while a.runnable:                    # a alone, to the end of its input
+        sched.step([a])
+    # the call that found the input at its end had no row to dispatch and
+    # confirmed the last one: nothing is left in flight
+    rows = a.rows_taken
+    assert not sched.in_flight and a.confirmed_rows == rows >= 16
+    sched.step([b])
+    assert sched.in_flight and b.confirmed_rows == 0
+    assert a.finalize() == _oracle(a)    # not a's row: nothing settles
+    assert sched.in_flight and sched.stats["settles"] == 0
+    b.suspend()
+    assert not sched.in_flight and sched.stats["settles"] == 1
+
+    c = _lane(tmp_path, files, "a", "-c")   # a's input, call by call
+    for _ in range(rows):
+        sched.step([c])
+    # every row taken, the last one in flight, the end not yet looked for
+    assert sched.in_flight and c.runnable
+    assert (c.rows_taken, c.confirmed_rows) == (rows, rows - 1)
+    assert c.finalize() == _oracle(c)
+    assert c.confirmed_rows == rows and not sched.in_flight
+    assert sched.stats["settles"] == 2
+    assert sched.stats["packed_rows"] == 2 * rows + 1
+
+
+def test_every_dispatched_step_is_confirmed_once_and_the_counters_say_so(
+        tmp_path, files):
+    sched = _sched()
+    a, b = _pair(tmp_path, files)
+    early = 0
+    n = 0
+    while a.runnable or b.runnable:
+        sched.step([a, b] if n % 2 == 0 else [b, a])
+        n += 1
+        if n in (3, 8) and sched.in_flight:
+            # evict and resume whichever lane has the row in flight
+            victim = a if a.confirmed_rows < a.rows_taken else b
+            assert (a.confirmed_rows < a.rows_taken) != \
+                (b.confirmed_rows < b.rows_taken)
+            victim.suspend()
+            early += 1
+            fresh = _lane(tmp_path, files, victim.tenant)
+            if victim is a:
+                a = fresh
+            else:
+                b = fresh
+    results = a.finalize(), b.finalize()
+    assert early == 2
+    assert results == (_oracle(a), _oracle(b))
+    st = sched.stats
+    rows = a.confirmed_rows + b.confirmed_rows
+    assert rows >= 32                    # 2 x 16 KiB in rows of 1 KiB
+    assert st["packed_rows"] == st["packed_steps"] == rows
+    assert 0 <= st["results_ready"] <= st["packed_steps"]
+    assert early <= st["settles"] <= early + 1   # a last row, finalized
+    assert not sched.in_flight
+    assert st["max_tenants_per_step"] == 1 and st["host_fallbacks"] == 0
+
+
+def test_a_crash_after_a_dispatch_replays_the_rows_that_were_not_confirmed(
+        tmp_path, files, monkeypatch):
+    from dsi_tpu.ckpt import FaultInjected, reset_faults
+
+    sched = _sched()
+    a, b = _pair(tmp_path, files, "-through", every=1)
+    _alternate(sched, a, b)
+    through = a.finalize(), b.finalize()
+
+    a, b = _pair(tmp_path, files, every=1)
+    reset_faults()
+    monkeypatch.setenv("DSI_FAULT_MODE", "raise")
+    monkeypatch.setenv("DSI_FAULT_POINT", "post-dispatch")
+    monkeypatch.setenv("DSI_FAULT_STEP", "4")
+    with pytest.raises(FaultInjected):
+        _alternate(sched, a, b)
+    for k in ("DSI_FAULT_MODE", "DSI_FAULT_POINT", "DSI_FAULT_STEP"):
+        monkeypatch.delenv(k)
+    # four rows dispatched (a b a b), two confirmed and snapshotted, the
+    # third in flight, the fourth's record lost with the "process"
+    assert (a.rows_taken, a.confirmed_rows) == (2, 1)
+    assert (b.rows_taken, b.confirmed_rows) == (2, 1)
+    assert sched.in_flight
+    a2, b2 = _pair(tmp_path, files, every=1)   # the chains, as on disk
+    assert a2.confirmed_rows == 1 and a2.start_offset == a.cursor > 0
+    assert b2.confirmed_rows == 1 and b2.start_offset == b.cursor > 0
+    fresh = _sched()                     # a new process has a new packer
+    _alternate(fresh, a2, b2)
+    assert (a2.finalize(), b2.finalize()) == through
+
+
+class _Unreadable:
+    """A device array whose copy to the host fails."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("device lost (injected)")
+
+
+def test_an_error_at_the_lagged_read_fails_the_jobs_of_that_step_alone(
+        tmp_path, files, monkeypatch):
+    from dsi_tpu.serve.pack import PackedGrepScheduler
+
+    real = PackedGrepScheduler._dispatch
+    dispatched = []
+
+    def dispatch(self, *args):
+        outs = real(self, *args)
+        dispatched.append(1)
+        if len(dispatched) == 3:          # the first job's third row
+            return (_Unreadable(),) + tuple(outs[1:])
+        return outs
+
+    monkeypatch.setattr(PackedGrepScheduler, "_dispatch", dispatch)
+    d = ServeDaemon(str(tmp_path / "spool"), socket_path=short_sock(),
+                    devices=1, chunk_bytes=CHUNK, max_resident=2,
+                    warm=False)
+    reps = [d._rpc_submit({"tenant": t, "app": "grep", "files": fs,
+                           "pattern": p})
+            for t, fs, p in (("t0", files[0:2], "the"),
+                             ("t1", files[2:4], "and"))]
+    d.start()
+    try:
+        client.wait_ready(d.socket_path, timeout=120)
+        final = client.wait(d.socket_path, [r["job_id"] for r in reps],
+                            timeout=240)
+        stats = client.status(d.socket_path)["stats"]
+    finally:
+        d.close()
+    first, second = (final[r["job_id"]] for r in reps)
+    assert first["state"] == "failed"
+    assert "packed grep step" in first["error"]
+    assert "device lost (injected)" in first["error"]
+    assert second["state"] == "done" and second["error"] is None
+    with open(os.path.join(reps[1]["out_dir"], "grep.json")) as f:
+        got = sorted(_render(json.load(f)))
+    assert got == reference_grepstats.lines(
+        files[2:4], {"pattern": "and", "bins": 8, "topk": 16})
+    # the lost step is counted nowhere.  The second job's rows are, the
+    # first job's two confirmed ones, and its fourth: dispatched by the
+    # call that lost the third, read in the next one, folded nowhere
+    assert stats["serve_grep"]["packed_rows"] == \
+        second["stats"]["rows"] + 2 + 1
+    assert stats["daemon"]["jobs_done"] == 1
+
+
+def test_the_scheduler_never_sleeps_on_a_step_in_flight(tmp_path, files):
+    """A call that dispatched and confirmed nothing, the first of a
+    burst, is work: the daemon's idle wait (0.2 s) may begin only with
+    nothing in flight."""
+    d = ServeDaemon(str(tmp_path / "spool"), socket_path=short_sock(),
+                    devices=1, chunk_bytes=CHUNK, max_resident=2,
+                    warm=False)
+    slept_on = []
+    idle_wait = d._wake.wait
+
+    def wait(timeout=None):
+        slept_on.append(d.grep_packer is not None
+                        and d.grep_packer.in_flight)
+        return idle_wait(timeout)
+
+    d._wake.wait = wait
+    d.start()
+    try:
+        client.wait_ready(d.socket_path, timeout=120)
+        time.sleep(0.3)                  # idle: it sleeps, nothing queued
+        for k in range(3):               # three bursts of one small job
+            rep = client.submit(d.socket_path, "t0", files[k:k + 1],
+                                app="grep", pattern="the")
+            final = client.wait(d.socket_path, [rep["job_id"]], timeout=120)
+            assert final[rep["job_id"]]["state"] == "done"
+            time.sleep(0.25)
+        steps = client.status(d.socket_path)["stats"]["serve_grep"]
+    finally:
+        d.close()
+    assert steps["packed_steps"] >= 3 * 8 and steps["settles"] == 0
+    assert len(slept_on) >= 3 and not any(slept_on)
