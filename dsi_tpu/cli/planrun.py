@@ -96,6 +96,40 @@ Chains:
               --stage-shards, --device-accumulate, --mesh-shards and
               --aot are not this chain's.
 
+  join      — one stage: SELECT sourceIP, SUM(adRevenue), AVG(pageRank)
+              FROM Rankings, UserVisits WHERE pageURL = destURL AND
+              visitDate BETWEEN FROM AND TO GROUP BY sourceIP (Pavlo et
+              al., SIGMOD'09, the Join Task), and the row of the largest
+              sum.  --join-build FILE (repeatable) are the build side:
+              rows pageURL|pageRank|..., the key 1-100 bytes of printable
+              ASCII other than '|' and a primary key, the rank
+              [0-9]{1,9}.  The input files are the probe side: rows
+              sourceIP|destURL|visitDate|adRevenue|..., sourceIP 1-16
+              bytes, destURL 1-100, the date YYYY-MM-DD, the value as
+              --chain agg's.  --join-dates FROM:TO is the window, both
+              ends inclusive, dates compared as their ten bytes.  The
+              build side becomes a table that stays on the device,
+              ordered by a hash of its keys; the probe side streams past
+              it: the date compared, the rows inside the window looked
+              up by their whole key (every byte decides: two keys of one
+              hash re-order the table under another salt, and never
+              match), and the matched rows' revenue, rank and count
+              summed by sourceIP on the device, exactly, 64 bits wide.
+              Commits mr-out-<r>, one line a sourceIP with a joined row,
+              "<sourceIP> <sum>.<six digits> <mean>.<six digits>" (the
+              mean is rank sum * 10^6 // rows: truncated, no float), the
+              key in partition ihash(sourceIP) % nreduce, each file in
+              key order, and plan-top.json: {"top": {"sourceIP",
+              "totalRevenue", "avgPageRank"}}, the line of the largest
+              sum (ties: the least sourceIP), or {"top": null} where no
+              row joined.  A row of either table that cannot be read,
+              and a second row with one pageURL, fail the job: exit 1,
+              nothing committed, the rows' files and lines in the
+              message; there is no host path.  --devices other than 1,
+              --staged, --check, --hosts, --checkpoint-dir, --pipeline,
+              --stage-shards, --device-accumulate, --mesh-shards and
+              --aot are not this chain's.
+
 Elastic execution (ISSUE 16): ``--pipeline`` overlaps a grep→wordcount
 pair (the wordcount consumes relay buffers as they SEAL while the grep
 is still producing; strict/staged stays the bit-parity oracle);
@@ -109,7 +143,8 @@ Usage:
         [--staged] [--chunk-bytes B] [--devices D] [--pipeline-depth K]
         [--device-accumulate] [--sync-every K] [--mesh-shards N]
         [--nreduce N] [--u-cap U] [--topk K] [--sort-sample N]
-        [--agg-prefix N] [--aot]
+        [--agg-prefix N] [--join-build FILE]... [--join-dates FROM:TO]
+        [--aot]
         [--checkpoint-dir DIR] [--resume] [--workdir DIR] [--check]
         [--stats] [--stats-json FILE] [--trace-dir DIR] inputfiles...
 """
@@ -143,7 +178,9 @@ def _plan_spec(args) -> dict:
             "n_reduce": args.nreduce, "u_cap": args.u_cap,
             "topk": args.topk, "devices": args.devices,
             "pack_docs": args.pack_docs, "sample": args.sort_sample,
-            "agg_prefix": args.agg_prefix}
+            "agg_prefix": args.agg_prefix,
+            "join_build": list(args.join_build),
+            "join_dates": list(args.join_dates or ())}
 
 
 def _run_hosts(args, spec: dict, plan, mesh):
@@ -279,6 +316,24 @@ def _run_hosts(args, spec: dict, plan, mesh):
     return res, dict(sc)
 
 
+def _top_row(table) -> "dict | None":
+    """The join's second statement over its committed table: the row of
+    the largest sum, the least key among equals (the table is in key
+    order, and ``argmax`` takes the first); None of an empty table."""
+    import numpy as np
+
+    from dsi_tpu.ops.wordcount import decode_packed
+
+    if not len(table):
+        return None
+    at = np.array([np.argmax(table.cnts[:, 0])])
+    unit = 10 ** table.decimals
+    return {"sourceIP": decode_packed(table.skeys[at], table.lens[at], 1)[0],
+            **{name: f"{int(v) // unit}.{int(v) % unit:0{table.decimals}d}"
+               for name, v in (("totalRevenue", table.cnts[at[0], 0]),
+                               ("avgPageRank", table.means(at)[0]))}}
+
+
 def _job(args, pstats: dict, opened: list):
     """The job between its parsed arguments and its ``--stats`` line:
     ``(exit code, what --stats-json and --check read after it)``.
@@ -302,6 +357,7 @@ def _job(args, pstats: dict, opened: list):
 
         from dsi_tpu.ckpt import CheckpointMismatch
         from dsi_tpu.ops.fieldsum import BadRow
+        from dsi_tpu.parallel.joinstream import DuplicateKey, HashCollision
         from dsi_tpu.parallel.shuffle import default_mesh
         from dsi_tpu.parallel.sortstream import StoreOverfull
         from dsi_tpu.plan import PlanHostPath, run_plan
@@ -346,9 +402,11 @@ def _job(args, pstats: dict, opened: list):
         # length was taken from: nothing is committed.
         print(f"planrun: {e}", file=sys.stderr)
         return 1, None
-    except (BadRow, StoreOverfull) as e:
-        # --chain agg: a row that cannot be read fails the job; --chain
-        # sort: so does a device whose key range outgrew its store.
+    except (BadRow, StoreOverfull, DuplicateKey, HashCollision) as e:
+        # --chain agg and join: a row that cannot be read fails the job;
+        # --chain sort: so does a device whose key range outgrew its
+        # store; --chain join: and a build key held twice, or two of one
+        # hash under every salt.
         print(f"planrun: {e}", file=sys.stderr)
         return 1, None
     except PlanHostPath as e:
@@ -399,6 +457,14 @@ def _job(args, pstats: dict, opened: list):
             print(f"planrun: {stats['stage_stats']['agg']['agg_rows']} rows "
                   f"in {len(committed)} groups -> {args.workdir}/mr-out-0.."
                   f"{args.nreduce - 1}", file=sys.stderr)
+        elif args.chain == "join":
+            committed = res.final
+            join = stats["stage_stats"]["join"]
+            print(f"planrun: {join['join_matched_rows']} of "
+                  f"{join['join_probe_rows']} rows joined with "
+                  f"{join['join_build_rows']} in {len(committed)} groups -> "
+                  f"{args.workdir}/mr-out-0..{args.nreduce - 1}",
+                  file=sys.stderr)
         elif args.chain == "indexer":
             # The table the join stage grouped, its documents named as
             # the host app names them (mrsequential in the files'
@@ -449,6 +515,13 @@ def _job(args, pstats: dict, opened: list):
                           f, sort_keys=True, indent=1)
             print(f"planrun: top-{len(res.final)} words -> {path}",
                   file=sys.stderr)
+        elif args.chain == "join":
+            path = os.path.join(args.workdir, "plan-top.json")
+            top = _top_row(committed)
+            # dsicheck: allow[raw-write] report artifact, not durable state
+            with open(path, "w", encoding="utf-8") as f:
+                json.dump({"top": top}, f, sort_keys=True, indent=1)
+            print(f"planrun: top row {top} -> {path}", file=sys.stderr)
         elif args.chain == "indexer":
             out = {w: {"df": df, "part": part, "docs": list(docs)}
                    for w, (df, part, docs) in res.final.items()}
@@ -481,7 +554,7 @@ def _main(argv, opened: list) -> int:
     p.add_argument("files", nargs="+")
     p.add_argument("--chain",
                    choices=("grep-wc", "grep-grep", "wc-topk",
-                            "indexer", "sort", "agg"),
+                            "indexer", "sort", "agg", "join"),
                    default="grep-wc",
                    help="grep-wc commits the word counts of the matching "
                         "lines as mr-out-<r>; indexer commits the whole "
@@ -494,7 +567,11 @@ def _main(argv, opened: list) -> int:
                         "10-byte key as mr-out-<r>, range-partitioned "
                         "from a sample of the keys; agg commits field "
                         "3's decimal sum by field 0 of |-delimited rows "
-                        "as mr-out-<r>")
+                        "as mr-out-<r>; join commits, by field 0 of the "
+                        "input's rows inside --join-dates whose field 1 "
+                        "is a key of --join-build's rows, field 3's sum "
+                        "and the mean of those rows' ranks as mr-out-<r>, "
+                        "and the row of the largest sum as plan-top.json")
     p.add_argument("--pattern", default=None,
                    help="literal grep pattern (required for grep-wc "
                         "and grep-grep)")
@@ -537,6 +614,14 @@ def _main(argv, opened: list) -> int:
     p.add_argument("--agg-prefix", type=int, default=0,
                    help="--chain agg: group by the first N bytes of the "
                         "key field (0, the default: the whole field)")
+    p.add_argument("--join-build", action="append", default=[],
+                   metavar="FILE",
+                   help="--chain join: a file of the build side's rows "
+                        "key|rank|... (repeatable, in input order); the "
+                        "input files are the probe side")
+    p.add_argument("--join-dates", default=None, metavar="FROM:TO",
+                   help="--chain join: the window of the probe rows' "
+                        "dates, YYYY-MM-DD each, both inclusive")
     p.add_argument("--aot", action="store_true")
     p.add_argument("--checkpoint-dir", default=None,
                    help="stage-manifest commits land here: each "
@@ -597,6 +682,28 @@ def _main(argv, opened: list) -> int:
                 p.error(f"--{flag.replace('_', '-')} is not --chain "
                         "agg's: one stage, summed through the host "
                         "merge and committed once")
+    if (args.join_build or args.join_dates) and args.chain != "join":
+        p.error("--join-build and --join-dates are --chain join's")
+    if args.chain == "join":
+        if not args.join_build or not args.join_dates:
+            p.error("--chain join requires --join-build and --join-dates")
+        from dsi_tpu.plan.graph import parse_dates
+
+        try:
+            args.join_dates = list(parse_dates(args.join_dates))
+        except ValueError as e:
+            p.error(f"--join-dates: {e}")
+        if args.devices not in (None, 1):
+            p.error("--chain join holds its table on --devices 1: across "
+                    "a mesh both sides first need the exchange by key")
+        args.devices = 1
+        for flag in ("staged", "check", "hosts", "checkpoint_dir",
+                     "pipeline", "stage_shards", "device_accumulate",
+                     "mesh_shards", "aot"):
+            if getattr(args, flag):
+                p.error(f"--{flag.replace('_', '-')} is not --chain "
+                        "join's: one stage, its table on the device, "
+                        "summed through the host merge and committed once")
     if args.pipeline and args.staged:
         p.error("--pipeline is chained-mode only (staged execution "
                 "stays strictly sequential: it is the parity oracle)")

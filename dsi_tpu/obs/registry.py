@@ -210,6 +210,27 @@ the word count's own, ``steps``, ``pull_s``, ``merge_s``, ... and all):
 ``agg_value_lanes`` (``uint32`` lanes a step's sum rides through the
 shuffle, the pull and the merge: 2).
 
+The join chain (``planrun --chain join``: ``parallel/joinstream.py``,
+engine ``join``; the scope under ``stage_stats`` holds the stream
+engines' ``steps`` (the probe's), ``upload_s``, ``kernel_s``, ``pull_s``,
+``merge_s``, ``step_pulls``, ``pulls_early`` / ``pulls_late`` and the
+accumulator's counters as a word count's): ``join_build_s`` and
+``join_probe_s`` (the ``join_build`` span: the build side's rows counted,
+every chunk through ``join_build_step``, the table ordered and its
+neighbours checked; the ``join_probe`` span: every probe chunk through
+``join_probe_step`` to the last table merged), ``read_s`` (the count of
+the build side's rows, which sizes the table), ``order_s`` (the host
+blocked on ``join_build_order``), ``join_build_rows`` and
+``join_build_bytes`` (rows and bytes of the build side the retired steps
+read: the files' newlines and bytes), ``join_build_steps``,
+``join_table_bytes`` (what stays on the device for the probe: the
+ordered rows and their hashes), ``join_probe_rows`` (every probe row
+read), ``join_window_rows`` (those inside the date window),
+``join_matched_rows`` (those whose key is the table's), ``join_groups``
+(keys of the merged table: the committed lines) and ``join_value_lanes``
+(``uint32`` lanes of a step's three sums: 6).  A phase that starts again
+at its next rung counts from zero.
+
 Async/incremental checkpoint keys (``dsi_tpu/ckpt`` writer/delta —
 present when checkpointing is on): ``ckpt_async``/``ckpt_delta`` (the
 mode flags), ``ckpt_deltas`` (incremental saves among ``ckpt_saves``),
@@ -403,6 +424,8 @@ PHASE_KEYS = (
     # the sort chain (parallel/sortstream.py): the sampling pre-pass, and
     # the host blocked on the device's ordering of the resident store
     "sample_s", "order_s",
+    # the join chain's two phases (parallel/joinstream.py)
+    "join_build_s", "join_probe_s",
     # planrun's root: what it says to stderr and writes beside mr-out-*
     "report_s",
     # the starvation account of a job's main thread (obs/trace.py)
@@ -476,7 +499,7 @@ DEVICE_BLOCKED = (
 STARVED_GROUPS = (
     ("input", ("start", "read", "read_wait", "sample", "wait",
                "materialize", "pack", "plan", "probe", "backend_init",
-               "launch", "lower", "compile")),
+               "launch", "lower", "compile", "join_build", "join_probe")),
     ("dispatch", ("dispatch", "upload", "enqueue", "relay_append",
                   "relay_spill")),
     ("merge", ("finish", "pull", "merge", "compact", "merge_wait", "replay",
@@ -536,6 +559,13 @@ COUNTER_KEYS = (
     # the aggregation chain (the stream engine with a map,
     # ops/fieldsum.py): rows read, keys of the merged table, lanes a sum
     "agg_rows", "agg_groups", "agg_value_lanes",
+    # the join chain (parallel/joinstream.py): rows, bytes and steps of
+    # the build side, bytes of the table that stays on the device, probe
+    # rows read, inside the window and matched, keys of the merged table,
+    # lanes of a step's sums
+    "join_build_rows", "join_build_bytes", "join_build_steps",
+    "join_table_bytes", "join_probe_rows", "join_window_rows",
+    "join_matched_rows", "join_groups", "join_value_lanes",
     # checkpoint/restore
     "ckpt_saves", "ckpt_every", "ckpt_async", "ckpt_delta",
     "ckpt_deltas", "ckpt_full_bytes", "ckpt_delta_bytes",

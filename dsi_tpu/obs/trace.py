@@ -182,6 +182,10 @@ SPAN_NAMES = frozenset(LANES) | frozenset((
     # the record files, and the host blocked on the device's ordering of
     # the resident store
     "sample", "order",
+    # the join chain (parallel/joinstream.py): its two phases, each around
+    # its own steps: the build side into the ordered table on the device,
+    # the probe side past it into the merged table of groups
+    "join_build", "join_probe",
     # planrun's root (cli/planrun.py): what the job says to stderr and
     # writes beside mr-out-* (the stage lines, named(), plan-*.json)
     "report",

@@ -12,7 +12,12 @@ tokens are (``lex_sort`` + ``group_sorted``), the values where the ones
 were, and a key's total leaves the step 64 bits wide as two ``uint32``
 lanes: 8,100 rows of 10^9 units pass 2^32.
 
-How a row is read, in three named scopes a device trace tells apart:
+How a row is read, in three named scopes a device trace tells apart,
+each a function of its own that the join's two maps (``ops/joink.py``)
+call as well (:func:`find_fields`, :func:`key_lanes`,
+:func:`decimal_units`; a key of up to 16 bytes is :class:`FieldSum`'s
+limit, not the finder's: ``key_lanes`` packs as many lanes as it is asked
+for):
 
 * ``fields``: a row starts behind a newline (or at byte 0) and ends at
   the next one, or where the chunk's content ends (a last row without a
@@ -41,7 +46,7 @@ parsed on the host.
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -64,10 +69,30 @@ DECIMALS = 6
 _VALUE_BYTES = 3 + 1 + DECIMALS
 
 
+def file_rows(path: str) -> int:
+    """Rows of a file of newline-terminated rows: its newlines, and a last
+    row without one."""
+    data = np.fromfile(path, np.uint8)
+    return int(np.count_nonzero(data == 10)) + int(
+        len(data) > 0 and data[-1] != 10)
+
+
+def row_place(paths: Sequence[str], row: int) -> Optional[str]:
+    """``<file>:<line>`` (lines from 1) of row ``row`` of the stream the
+    files make, one behind the other (:func:`file_rows` a file); None for
+    an ordinal the files do not hold."""
+    for path in paths:
+        rows = file_rows(path)
+        if 0 <= row < rows:
+            return f"{path}:{row + 1}"
+        row -= rows
+    return None
+
+
 class BadRow(ValueError):
-    """A row the aggregation cannot read: the job fails and commits
-    nothing.  ``row`` is the row's ordinal in the job's input (from 0),
-    until :meth:`at` names its file and line."""
+    """A row a map over delimited rows cannot read: the job fails and
+    commits nothing.  ``row`` is the row's ordinal in the job's input
+    (from 0), until :meth:`at` names its file and line."""
 
     def __init__(self, message: str, row: int = -1):
         super().__init__(message)
@@ -75,17 +100,10 @@ class BadRow(ValueError):
 
     def at(self, paths: Sequence[str]) -> "BadRow":
         """The same failure with the row's file and line (from 1) in the
-        message: newlines counted file by file up to the row's ordinal,
-        a last row without a newline counted as a row."""
-        left = self.row
-        for path in paths:
-            data = np.fromfile(path, np.uint8)
-            rows = int(np.count_nonzero(data == 10)) + int(
-                len(data) > 0 and data[-1] != 10)
-            if 0 <= left < rows:
-                return BadRow(f"{path}:{left + 1}: {self}", self.row)
-            left -= rows
-        return self
+        message (:func:`row_place`)."""
+        place = row_place(paths, self.row)
+        return self if place is None else BadRow(f"{place}: {self}",
+                                                 self.row)
 
 
 class FieldSum(NamedTuple):
@@ -159,24 +177,20 @@ def _row_starts(is_start: jax.Array, size: int) -> jax.Array:
                      < jnp.sum(live, dtype=jnp.int32), moved[:size], 0)
 
 
-def field_rows(chunk: jax.Array, *, spec: FieldSum, max_word_len: int,
-               t_cap_frac: int):
-    """The rows of a chunk, in input order: ``(key_cols, key_lens, values,
-    n_rows, first_bad)``.
-
-    ``key_cols``: ``max_word_len / 4`` columns ``uint32[t_cap]``, the key's
-    (prefix's) bytes big-endian, zero past its length, ``_PAD_KEY`` in the
-    rows past the last (``t_cap = n // t_cap_frac + 1``; of more rows than
-    that the first ``t_cap`` are kept and ``n_rows`` says so);
-    ``key_lens``: ``int32[t_cap]``, 0 in those rows; ``values``:
-    ``uint32[t_cap]``, 10^-6 units, 0 in those rows; ``first_bad``: the
-    place of the first row that cannot be read (``t_cap`` without one)."""
+def find_fields(chunk: jax.Array, *, delim: int, last_field: int,
+                t_cap: int):
+    """The rows of a chunk and their fields 0 to ``last_field``, in input
+    order (scope ``fields``): ``(starts, ends, valid, fields_ok,
+    n_rows)``.  ``starts[f]`` and ``ends[f]``: ``int32[t_cap]``, field
+    ``f``'s first byte and its terminator's place; ``valid``: the rows
+    that are rows (the first ``n_rows``; of more rows than ``t_cap`` the
+    first ``t_cap`` are kept and ``n_rows`` says so); ``fields_ok``: those
+    of them with a delimiter, not the row's end, behind every field before
+    the last.  What every map over delimited rows shares: the
+    aggregation's (:func:`field_rows`) and the join's two
+    (``ops/joink.py``)."""
     n = chunk.shape[0]
-    k = max_word_len // 4
-    t_cap = n // t_cap_frac + 1
-    last_field = max(spec.key_field, spec.value_field)
     pos = jnp.arange(n, dtype=jnp.int32)
-
     with jax.named_scope("fields"):
         content = jnp.max(jnp.where(chunk != 0, pos, -1)) + 1
         is_end = (chunk == 10) | (pos >= content)
@@ -187,7 +201,7 @@ def field_rows(chunk: jax.Array, *, spec: FieldSum, max_word_len: int,
         # ends the row (1) or only a field (0: a delimiter), so that one
         # gather tells both; position n ends whatever is open there (a
         # full chunk's last row).
-        is_delim = chunk == jnp.uint8(spec.delim)
+        is_delim = chunk == jnp.uint8(delim)
         nxt = jnp.concatenate([
             lax.cummin(jnp.where(
                 is_end | is_delim, 2 * pos + (~is_delim).astype(jnp.int32),
@@ -204,29 +218,46 @@ def field_rows(chunk: jax.Array, *, spec: FieldSum, max_word_len: int,
             if f < last_field:  # a delimiter, not the row's end, behind it
                 fields_ok &= (code & 1) == 0
                 begin = jnp.minimum((code >> 1) + 1, n)
+    return starts, ends, valid, fields_ok, n_rows
 
-    words = _words(chunk)
 
+def key_lanes(words: jax.Array, at: jax.Array, field_len: jax.Array,
+              valid: jax.Array, *, k: int, prefix: int = 0):
+    """A key field packed into ``k`` lanes (scope ``key_lanes``):
+    ``(key_cols, key_lens, key_ok)``.  ``words`` are :func:`_words` of
+    the chunk, ``at`` the field's first byte and ``field_len`` its bytes,
+    a row each.  ``key_cols``: ``k`` columns ``uint32``, the key's (its
+    first ``prefix`` bytes' where that is not 0) bytes big-endian, zero
+    past its length, ``_PAD_KEY`` where ``valid`` is not set;
+    ``key_lens``: ``int32``, 0 in those rows; ``key_ok``: a valid row
+    whose field is 1 to ``4 k`` bytes of printable ASCII."""
+    n = words.shape[0] - 1
     with jax.named_scope("key_lanes"):
-        at = starts[spec.key_field]
-        field_len = ends[spec.key_field] - at
         lanes = [words[jnp.minimum(at + 4 * j, n)] for j in range(k)]
         printable = valid
         for p, b in enumerate(_bytes_of(lanes, 4 * k)):
             printable &= (p >= field_len) | ((b >= 0x20) & (b <= 0x7E))
         key_ok = (field_len >= 1) & (field_len <= 4 * k) & printable
         key_lens = jnp.where(
-            valid, jnp.minimum(field_len, spec.prefix) if spec.prefix
+            valid, jnp.minimum(field_len, prefix) if prefix
             else field_len, 0)
         key_cols = tuple(
             jnp.where(valid,
                       lanes[j] & _byte_mask(jnp.clip(key_lens - 4 * j, 0, 4)),
                       jnp.uint32(_PAD_KEY))
             for j in range(k))
+    return key_cols, key_lens, key_ok
 
+
+def decimal_units(words: jax.Array, at: jax.Array, length: jax.Array,
+                  valid: jax.Array):
+    """A decimal field ``[0-9]{1,3}(\\.[0-9]{1,6})?`` as 10^-6 units (scope
+    ``decimal``): ``(values, value_ok)``, ``uint32`` (0 where ``valid``
+    is not set) and whether the field is in the grammar.  ``at`` is the
+    field's first byte, ``length`` its bytes, a row each."""
+    n = words.shape[0] - 1
+    t_cap = at.shape[0]
     with jax.named_scope("decimal"):
-        at = starts[spec.value_field]
-        length = ends[spec.value_field] - at
         window = _bytes_of(
             [words[jnp.minimum(at + 4 * j, n)] for j in range(3)],
             _VALUE_BYTES)
@@ -254,11 +285,44 @@ def field_rows(chunk: jax.Array, *, spec: FieldSum, max_word_len: int,
         value_ok = (all_digits & (dot >= 1) & (dot <= 3)
                     & (fraction != 0) & (fraction <= DECIMALS))
         values = jnp.where(valid, value, jnp.uint32(0))
+    return values, value_ok
 
+
+def first_bad_row(valid: jax.Array, ok: jax.Array) -> jax.Array:
+    """The place of the first valid row that is not ``ok`` (scope
+    ``fields``); the rows' capacity without one."""
+    t_cap = valid.shape[0]
     with jax.named_scope("fields"):
-        bad = valid & ~(fields_ok & key_ok & value_ok)
-        first_bad = jnp.min(jnp.where(
+        bad = valid & ~ok
+        return jnp.min(jnp.where(
             bad, jnp.arange(t_cap, dtype=jnp.int32), jnp.int32(t_cap)))
+
+
+def field_rows(chunk: jax.Array, *, spec: FieldSum, max_word_len: int,
+               t_cap_frac: int):
+    """The rows of a chunk, in input order: ``(key_cols, key_lens, values,
+    n_rows, first_bad)``.
+
+    ``key_cols``: ``max_word_len / 4`` columns ``uint32[t_cap]``, the key's
+    (prefix's) bytes big-endian, zero past its length, ``_PAD_KEY`` in the
+    rows past the last (``t_cap = n // t_cap_frac + 1``; of more rows than
+    that the first ``t_cap`` are kept and ``n_rows`` says so);
+    ``key_lens``: ``int32[t_cap]``, 0 in those rows; ``values``:
+    ``uint32[t_cap]``, 10^-6 units, 0 in those rows; ``first_bad``: the
+    place of the first row that cannot be read (``t_cap`` without one)."""
+    t_cap = chunk.shape[0] // t_cap_frac + 1
+    starts, ends, valid, fields_ok, n_rows = find_fields(
+        chunk, delim=spec.delim,
+        last_field=max(spec.key_field, spec.value_field), t_cap=t_cap)
+    words = _words(chunk)
+    at = starts[spec.key_field]
+    key_cols, key_lens, key_ok = key_lanes(
+        words, at, ends[spec.key_field] - at, valid, k=max_word_len // 4,
+        prefix=spec.prefix)
+    at = starts[spec.value_field]
+    values, value_ok = decimal_units(words, at, ends[spec.value_field] - at,
+                                     valid)
+    first_bad = first_bad_row(valid, fields_ok & key_ok & value_ok)
     return key_cols, key_lens, values, n_rows, first_bad
 
 

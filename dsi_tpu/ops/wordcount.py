@@ -371,7 +371,10 @@ def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     counts to sum within each group, or a pair ``(low, high)`` of
     ``uint32`` halves (``high`` None: all zero) of values whose totals
     pass 32 bits: the totals are then ``uint32[out_cap, 2]``, (low, high),
-    summed by :func:`running_sum_pair`.
+    summed by :func:`running_sum_pair`; or a list of such pairs, several
+    sums a key side by side (the join's revenue, rank and row count): the
+    totals are then ``uint32[out_cap, 2 * len(counts)]``, a pair's two
+    lanes behind the pair's before it.
 
     Returns (keys2d [t,k], totals [out_cap], upos [out_cap] int32, ovalid
     [out_cap], n_unique) — callers gather their payloads at ``upos`` and
@@ -396,18 +399,25 @@ def group_sorted(skeys_cols: tuple, counts: jax.Array, out_cap: int):
     live = jnp.arange(out_cap + 1, dtype=jnp.int32) < n_unique
     ovalid = live[:out_cap]
     bounds = jnp.where(live, starts, jnp.int32(t))
-    if isinstance(counts, tuple):
+
+    def pair_totals(pair):
         lo, hi = (None if c is None else jnp.where(valid, c, jnp.uint32(0))
-                  for c in counts)
+                  for c in pair)
         at = jnp.maximum(bounds - 1, 0)
         lo, hi = (jnp.where(bounds > 0, c[at], jnp.uint32(0))
                   for c in running_sum_pair(lo, hi))
         borrow = (lo[1:] < lo[:-1]).astype(jnp.uint32)
-        totals = jnp.where(
+        return jnp.where(
             ovalid[:, None],
             jnp.stack([lo[1:] - lo[:-1], hi[1:] - hi[:-1] - borrow], axis=1),
             jnp.uint32(0))
+
+    if isinstance(counts, list):
+        totals = jnp.concatenate([pair_totals(pair) for pair in counts],
+                                 axis=1)
         return keys, totals, upos, ovalid, n_unique
+    if isinstance(counts, tuple):
+        return keys, pair_totals(counts), upos, ovalid, n_unique
     with enable_x64(True):  # the counts may be 64-bit
         zero = jnp.zeros((), counts.dtype)
         csum = running_sum(jnp.where(valid, counts, zero))

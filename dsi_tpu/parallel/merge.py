@@ -73,30 +73,40 @@ def _word_number_rows(key_bytes: np.ndarray, rows: np.ndarray,
     alone, so one mask a class, gathered a row.  With ``decimals`` the
     numbers count 10^-decimals units and print as ``<integer
     part>.<decimals digits>``: that many columns and the point's more, at
-    the right, which every row keeps."""
+    the right, which every row keeps.  ``numbers`` of two dimensions
+    ([rows, m]) print as m numbers a row, a space before each: a field of
+    its own width a column, and a class is (length, digits, digits...)."""
     lens = lens.astype(np.int64)
     w = int(lens.max())
     tail = decimals + 1 if decimals else 0
-    digits = np.searchsorted(
-        _POW10, numbers // 10 ** decimals if decimals else numbers,
-        side="right") + 1
-    d = int(digits.max())
-    width = w + d + tail + 2
+    columns = list(numbers.T) if numbers.ndim == 2 else [numbers]
+    digits = [np.searchsorted(
+        _POW10, c // 10 ** decimals if decimals else c, side="right") + 1
+        for c in columns]
+    most = [int(d.max()) for d in digits]
+    width = w + sum(d + tail + 1 for d in most) + 1
     mat = np.empty((len(rows), width), np.uint8)
     mat[:, :w] = np.take(key_bytes, rows, axis=0)[:, :w]
-    mat[:, w] = 0x20
-    for col in range(w + d + tail, w, -1):  # right-aligned, least first
-        if tail and col == w + d + 1:
-            mat[:, col] = 0x2E
-            continue
-        numbers, digit = np.divmod(numbers, 10)
-        mat[:, col] = digit + 0x30
-    mat[:, width - 1] = last
     col = np.arange(width)
-    masks = ((col < np.arange(w + 1)[:, None, None])
-             | (col >= w + 1 + d - np.arange(d + 1)[None, :, None])
-             | (col == w)).reshape(-1, width)
-    return mat[np.take(masks, lens * (d + 1) + digits, axis=0)]
+    masks = col < np.arange(w + 1)[:, None]
+    kind, at = lens, w  # a row's class; the space before the next number
+    for numbers, digit_count, d in zip(columns, digits, most):
+        mat[:, at] = 0x20
+        end = at + d + tail
+        for c in range(end, at, -1):  # right-aligned, least first
+            if tail and c == end - decimals:
+                mat[:, c] = 0x2E
+                continue
+            numbers, digit = np.divmod(numbers, 10)
+            mat[:, c] = digit + 0x30
+        field = (col == at) | ((col <= end) & (
+            col >= at + 1 + d - np.arange(d + 1)[:, None]))
+        masks = (masks[:, None] | field).reshape(-1, width)
+        kind = kind * (d + 1) + digit_count
+        at = end + 1
+    mat[:, at] = last
+    masks[:, at] = True
+    return mat[np.take(masks, kind, axis=0)]
 
 
 def _pad_width(keys: np.ndarray, k: int) -> np.ndarray:
@@ -144,7 +154,8 @@ def _rows_increase(keys: np.ndarray) -> bool:
 
 
 #: One table of the accumulator: key lanes [n, K] uint32, byte lengths
-#: int32, counts int64, reduce partitions int32, all C-contiguous.
+#: int32, counts int64 ([n], or [n, m]: m sums a key side by side, merged
+#: in numpy), reduce partitions int32, all C-contiguous.
 _Table = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
 
 
@@ -189,6 +200,8 @@ def _own_run(keys, lens, cnts, parts) -> Tuple[_Table, bool]:
 def _merge2_native(a: _Table, b: _Table) -> Optional[_Table]:
     """Two runs of one lane width as one, by ``mergeruns.cpp``'s two
     pointers; None without the library."""
+    if a[2].ndim > 1:  # several sums a key: the library merges one
+        return None
     cap = len(a[0]) + len(b[0])
     out = (np.empty((cap, a[0].shape[1]), np.uint32),
            np.empty(cap, np.int32), np.empty(cap, np.int64),
@@ -247,7 +260,7 @@ def _merge_runs(runs: List[_Table]) -> _Table:
     several runs hold collapses on the way), else in numpy."""
     k = max(r[0].shape[1] for r in runs)
     runs = [_pad_table(r, k) for r in runs]
-    if len(runs) > 1 and not _native.available():
+    if len(runs) > 1 and (not _native.available() or runs[0][2].ndim > 1):
         return _merge_runs_numpy(runs)
     while len(runs) > 1:
         merged = [_merge2_native(runs[i], runs[i + 1])
@@ -435,14 +448,19 @@ class PackedCounts:
         engine (parallel/streaming.py) runs on the host while later
         steps' kernels are still in flight on device.  A tensor of
         ``kk+4`` lanes carries sums of two, low then high, where a count
-        has one."""
+        has one; one of ``kk+2+2m`` lanes m such sums a key, side by side
+        (the join's revenue, rank and rows), and the table's counts are
+        then ``[n, m]``."""
+        sums = (packed.shape[2] - kk - 2) // 2  # 0: a count of one lane
         for d in range(packed.shape[0]):
             nu = int(n_uniques[d])
             r = packed[d, :nu]
             cnts = r[:, kk + 1]
-            if packed.shape[2] == kk + 4:
-                cnts = cnts.astype(np.int64) | (
-                    r[:, kk + 2].astype(np.int64) << 32)
+            if sums:
+                wide = r[:, kk + 1:kk + 1 + 2 * sums].astype(np.int64)
+                cnts = wide[:, 0::2] | (wide[:, 1::2] << 32)
+                if sums == 1:
+                    cnts = cnts[:, 0]
             self.add(r[:, :kk], r[:, kk], cnts, r[:, -1])
 
     def _take_window(self) -> Tuple[List[_Table], dict]:
@@ -544,7 +562,12 @@ class PackedWordCounts(Mapping):
     the ASCII a key holds: a word's letters, an aggregation key's
     printable bytes), ``lens`` (bytes a word), ``cnts`` (int64) and
     ``parts`` are per word.  ``decimals`` makes a count a sum of
-    10^-decimals units, printed ``<integer part>.<decimals digits>``.  ``len()`` and
+    10^-decimals units, printed ``<integer part>.<decimals digits>``.
+    Counts of two dimensions, ``[n, 3]``, are a sum, a second sum and the
+    rows a key (the join's): a row prints the sum and the second sum's
+    mean over the rows, both as decimals, the mean by integer division
+    (truncated, exact) and a mapping's value is ``([sum, sum, rows],
+    partition)``.  ``len()`` and
     :meth:`render_partition` (what ``shuffle.write_partitioned_output``
     commits) read the arrays alone.  The first keyed access, iteration
     or comparison decodes every spelling once (``decode_packed``) into
@@ -630,9 +653,20 @@ class PackedWordCounts(Mapping):
             # [n, 4K] uint8: big-endian lanes are the spelling's bytes
             self._bytes = np.ascontiguousarray(
                 self.skeys.astype(">u4")).view(np.uint8)
+        numbers = self.cnts[rows]
+        if numbers.ndim == 2:
+            numbers = np.stack([numbers[:, 0], self.means(rows)], axis=1)
         return _word_number_rows(self._bytes, rows, self.lens[rows],
-                                 self.cnts[rows], 0x0A,
-                                 self.decimals).tobytes()
+                                 numbers, 0x0A, self.decimals).tobytes()
+
+    def means(self, rows: np.ndarray) -> np.ndarray:
+        """The second sum's mean over the rows' count, for ``rows`` of a
+        table of three counts a key, in 10^-decimals units: ``sum *
+        10^decimals // count`` without the product, which 64 bits may
+        not hold."""
+        _, total, n = self.cnts[rows].T
+        unit = 10 ** self.decimals
+        return total // n * unit + total % n * unit // n
 
 
 #: A buffer of posting rows is merged as the runs it arrives in where

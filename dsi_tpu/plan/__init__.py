@@ -27,6 +27,7 @@ from dsi_tpu.plan.graph import (
     grep_cascade_plan,
     grep_wordcount_plan,
     indexer_join_plan,
+    join_plan,
     sort_plan,
     wordcount_topk_plan,
 )
@@ -47,6 +48,7 @@ __all__ = [
     "grep_cascade_plan",
     "grep_wordcount_plan",
     "indexer_join_plan",
+    "join_plan",
     "run_plan",
     "sort_plan",
     "wordcount_topk_plan",

@@ -775,6 +775,35 @@ def _run_stage(plan: Plan, i: int, stage: Stage, ctx: Dict, mesh,
                                "needs the host path")
         return StageOut(result=res)
 
+    if stage.kind == "join":
+        from dsi_tpu.parallel.joinstream import table_join
+
+        fault_point(f"plan-stage{i}-advance")
+        if staged or int(mesh.devices.size) != 1:
+            # No host fallback commits a join, and one device holds the
+            # table: across a mesh the rows of both sides first have to
+            # reach the device that owns their key.
+            why = ("a staged run materializes on the host" if staged else
+                   "its table is one device's: a mesh needs the exchange "
+                   "by key")
+            raise PlanHostPath(f"stage {stage.name!r}: the join needs the "
+                               f"host path ({why})")
+        books = _Books()
+        try:
+            res = table_join(
+                list(plan.param(stage, "build_paths")),
+                list(plan.param(stage, "paths")),
+                tuple(d.encode("ascii") for d in plan.param(stage, "dates")),
+                mesh=mesh, n_reduce=int(plan.param(stage, "n_reduce", 10)),
+                chunk_bytes=kw["chunk_bytes"], depth=kw["depth"],
+                stats=books.stats)
+        finally:
+            books.bytes_in = sum(
+                os.path.getsize(path) for key in ("build_paths", "paths")
+                for path in plan.param(stage, key))
+            _note_stage(sc, sp, stage, [books])
+        return StageOut(result=res)
+
     raise PlanError(f"unrunnable stage kind {stage.kind!r}")
 
 

@@ -9,9 +9,9 @@ device-resident handoffs (``dsi_tpu/device/relay.py``,
 ``parallel/stepobj.py`` exports) instead of host materializations.  The
 driver (``plan/driver.py``) runs it.
 
-The nine stage kinds (what the driver knows how to run; ``sample`` and
+The ten stage kinds (what the driver knows how to run; ``sample`` and
 ``range_sort``, the sort chain's, are the two PR 45 added, ``aggregate``
-the one PR 49 did):
+the one PR 49 did, ``join`` the one PR 55 did):
 
 * ``grep``          — streaming literal grep over a byte source,
   emitting the matching lines into the outgoing relay (the
@@ -57,6 +57,17 @@ the one PR 49 did):
   table, which ``planrun --chain agg`` commits as ``mr-out-<r>``
   (``<key> <sum with six decimals>``).  ``prefix`` groups by the key's
   first bytes.
+* ``join``          — an inner equi-join of two tables of
+  newline-terminated, ``|``-delimited rows, filtered by a date window and
+  grouped (``parallel/joinstream.table_join``; Pavlo et al., SIGMOD'09,
+  the Join Task): a source stage with TWO path inputs, ``build_paths``
+  (rows ``key|rank|...``, the key 1-100 bytes and a primary key: the
+  table that is built and stays on the device) and ``paths`` (rows
+  ``group|key|date|value|...``: streamed past it), both in the stage's
+  identity and so in the plan's signature, and ``dates``, the window's
+  two ends.  Its result is the merged table of groups with three sums a
+  key, which ``planrun --chain join`` commits as ``mr-out-<r>``
+  (``<group> <sum of values> <mean of ranks>``) beside ``plan-top.json``.
 * A ``grep`` stage MAY itself have a grep dep (the grep→grep cascade):
   it consumes the upstream relay's line stream instead of a byte
   source and re-greps it with its own pattern.
@@ -72,16 +83,17 @@ so the driver asks for a signature only where a stage store reads it.
 from __future__ import annotations
 
 import json
+import re
 import zlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 #: The stage kinds plan/driver.py can run.
 STAGE_KINDS = ("grep", "wordcount", "indexer", "df_topk", "postings_join",
-               "top_k", "sample", "range_sort", "aggregate")
+               "top_k", "sample", "range_sort", "aggregate", "join")
 
 #: Stage params carrying bulk payloads: identity-hashed, never inlined
 #: into the signature.
-_BULK_PARAMS = ("data", "docs", "paths", "splits")
+_BULK_PARAMS = ("data", "docs", "paths", "build_paths", "splits")
 
 
 class PlanError(ValueError):
@@ -122,8 +134,8 @@ class Stage:
                 elif k in ("data", "splits"):
                     out[k] = {"bytes": len(v),
                               "crc32": zlib.crc32(bytes(v))}
-                else:  # paths: names are identity enough (files change
-                    out[k] = list(v)  # under any cursor scheme anyway)
+                else:  # paths of either input: names are identity enough
+                    out[k] = list(v)  # (files change under any cursor)
             else:
                 out[k] = v
         return out
@@ -272,4 +284,29 @@ def agg_plan(paths: Sequence[str], *, prefix: int = 0, **defaults) -> Plan:
     two queries over ``UserVisits``)."""
     p = Plan("agg", **defaults)
     p.add(Stage("agg", "aggregate", paths=list(paths), prefix=int(prefix)))
+    return p
+
+
+def parse_dates(text: str) -> Tuple[str, str]:
+    """``FROM:TO`` as the window's two dates (``YYYY-MM-DD`` each, both
+    inclusive: a date is compared as its ten bytes); ``ValueError`` for
+    anything else."""
+    first, _, last = text.partition(":")
+    if not all(re.fullmatch("[0-9]{4}-[0-9]{2}-[0-9]{2}", d, re.ASCII)
+               for d in (first, last)):
+        raise ValueError(f"{text!r} is not FROM:TO with both dates "
+                         "YYYY-MM-DD")
+    return first, last
+
+
+def join_plan(build_paths: Sequence[str], paths: Sequence[str], *,
+              dates: Tuple[str, str], **defaults) -> Plan:
+    """One ``join`` stage: ``build_paths``' rows ``key|rank|...`` joined
+    with ``paths``' rows ``group|key|date|value|...`` on the key, inside
+    the window ``dates`` (both ends inclusive), the values' sum and the
+    ranks' mean by group (Pavlo et al., SIGMOD'09, the Join Task:
+    ``Rankings`` and ``UserVisits``)."""
+    p = Plan("join", **defaults)
+    p.add(Stage("join", "join", build_paths=list(build_paths),
+                paths=list(paths), dates=[str(d) for d in dates]))
     return p

@@ -77,7 +77,7 @@ def build_plan(spec: Dict):
     (which must see the IDENTICAL plan graph)."""
     from dsi_tpu.plan import (agg_plan, grep_cascade_plan,
                               grep_wordcount_plan, indexer_join_plan,
-                              sort_plan, wordcount_topk_plan)
+                              join_plan, sort_plan, wordcount_topk_plan)
 
     defaults = dict(chunk_bytes=spec.get("chunk_bytes", 1 << 20),
                     depth=spec.get("depth"),
@@ -113,6 +113,9 @@ def build_plan(spec: Dict):
                          **defaults)
     if chain == "agg":
         return agg_plan(files, prefix=spec.get("agg_prefix", 0), **defaults)
+    if chain == "join":
+        return join_plan(spec["join_build"], files,
+                         dates=spec["join_dates"], **defaults)
     raise ValueError(f"unknown chain {chain!r}")
 
 

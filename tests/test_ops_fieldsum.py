@@ -370,3 +370,166 @@ def test_running_sum_pair_against_uint64():
     assert (np.asarray(only_lo[0]).astype(np.uint64) | (np.asarray(
         only_lo[1]).astype(np.uint64) << np.uint64(32))
         == np.cumsum(lo)).all()
+
+
+# ── the aggregation's map, as it was before the join's maps shared it ──
+
+
+def _field_rows_before(chunk, *, spec, max_word_len, t_cap_frac):
+    """``ops/fieldsum.field_rows`` of the commit before PR 55: one
+    function, before its three scopes became functions of their own."""
+    n = chunk.shape[0]
+    k = max_word_len // 4
+    t_cap = n // t_cap_frac + 1
+    last_field = max(spec.key_field, spec.value_field)
+    pos = jnp.arange(n, dtype=jnp.int32)
+
+    with jax.named_scope("fields"):
+        content = jnp.max(jnp.where(chunk != 0, pos, -1)) + 1
+        is_end = (chunk == 10) | (pos >= content)
+        is_start = (pos < content) & jnp.concatenate(
+            [jnp.ones((1,), jnp.bool_), is_end[:-1]])
+        n_rows = jnp.sum(is_start, dtype=jnp.int32)
+        # Every position's next terminator, and in the low bit whether it
+        # ends the row (1) or only a field (0: a delimiter), so that one
+        # gather tells both; position n ends whatever is open there (a
+        # full chunk's last row).
+        is_delim = chunk == jnp.uint8(spec.delim)
+        nxt = jnp.concatenate([
+            jax.lax.cummin(jnp.where(
+                is_end | is_delim, 2 * pos + (~is_delim).astype(jnp.int32),
+                jnp.int32(2 * n + 1)), reverse=True),
+            jnp.full((1,), 2 * n + 1, jnp.int32)])
+        valid = jnp.arange(t_cap, dtype=jnp.int32) < n_rows
+        begin = fieldsum._row_starts(is_start, t_cap)
+        fields_ok = valid
+        starts, ends = [], []
+        for f in range(last_field + 1):
+            code = nxt[begin]
+            starts.append(begin)
+            ends.append(code >> 1)
+            if f < last_field:  # a delimiter, not the row's end, behind it
+                fields_ok &= (code & 1) == 0
+                begin = jnp.minimum((code >> 1) + 1, n)
+
+    words = fieldsum._words(chunk)
+
+    with jax.named_scope("key_lanes"):
+        at = starts[spec.key_field]
+        field_len = ends[spec.key_field] - at
+        lanes = [words[jnp.minimum(at + 4 * j, n)] for j in range(k)]
+        printable = valid
+        for p, b in enumerate(fieldsum._bytes_of(lanes, 4 * k)):
+            printable &= (p >= field_len) | ((b >= 0x20) & (b <= 0x7E))
+        key_ok = (field_len >= 1) & (field_len <= 4 * k) & printable
+        key_lens = jnp.where(
+            valid, jnp.minimum(field_len, spec.prefix) if spec.prefix
+            else field_len, 0)
+        key_cols = tuple(
+            jnp.where(valid,
+                      lanes[j] & fieldsum._byte_mask(jnp.clip(key_lens - 4 * j, 0, 4)),
+                      jnp.uint32(fieldsum._PAD_KEY))
+            for j in range(k))
+
+    with jax.named_scope("decimal"):
+        at = starts[spec.value_field]
+        length = ends[spec.value_field] - at
+        window = fieldsum._bytes_of(
+            [words[jnp.minimum(at + 4 * j, n)] for j in range(3)],
+            fieldsum._VALUE_BYTES)
+        digits = [b - jnp.uint32(0x30) for b in window]
+        # the dot, if any, stands at byte 1, 2 or 3; without one the
+        # value is its integer digits, and the dot's place is its length
+        dot = length
+        for p in (3, 2, 1):
+            dot = jnp.where((window[p] == 0x2E) & (p < length), p, dot)
+        value = jnp.zeros((t_cap,), jnp.uint32)
+        all_digits = valid
+        for d in (1, 2, 3):
+            total = jnp.zeros((t_cap,), jnp.uint32)
+            for p in range(min(d + 1 + fieldsum.DECIMALS, fieldsum._VALUE_BYTES)):
+                if p == d:
+                    continue
+                weight = 10 ** (fieldsum.DECIMALS + d - 1 - p if p < d
+                                else fieldsum.DECIMALS - (p - d))
+                total += jnp.where(p < length, digits[p] * jnp.uint32(weight),
+                                   jnp.uint32(0))
+            value = jnp.where(dot == d, total, value)
+        for p in range(fieldsum._VALUE_BYTES):
+            all_digits &= (p >= length) | (p == dot) | (digits[p] <= 9)
+        fraction = length - dot - 1  # -1 without a dot
+        value_ok = (all_digits & (dot >= 1) & (dot <= 3)
+                    & (fraction != 0) & (fraction <= fieldsum.DECIMALS))
+        values = jnp.where(valid, value, jnp.uint32(0))
+
+    with jax.named_scope("fields"):
+        bad = valid & ~(fields_ok & key_ok & value_ok)
+        first_bad = jnp.min(jnp.where(
+            bad, jnp.arange(t_cap, dtype=jnp.int32), jnp.int32(t_cap)))
+    return key_cols, key_lens, values, n_rows, first_bad
+
+
+@pytest.mark.parametrize("n,frac,prefix", [(1 << 14, 64, 0), (1 << 14, 4, 7),
+                                           (1 << 12, 64, 0)])
+def test_the_factored_field_rows_lowers_to_the_text_it_had(n, frac, prefix):
+    chunk = jax.ShapeDtypeStruct((n,), jnp.uint8)
+    kw = dict(spec=FieldSum(prefix=prefix), max_word_len=16, t_cap_frac=frac)
+
+    def field_rows(c):  # named as the program's, whichever it traces
+        return fn(c, **kw)
+
+    texts = []
+    for fn in (_field_rows_before, fieldsum.field_rows):
+        texts.append(jax.jit(field_rows).lower(chunk).as_text())
+    assert texts[0] == texts[1]
+
+
+def test_group_sorted_takes_several_sums_a_key():
+    rng = np.random.default_rng(4)
+    keys = np.sort(rng.integers(0, 40, 3000).astype(np.uint32))
+    a, b = (rng.integers(0, 1 << 32, 3000, dtype=np.uint64).astype(np.uint32)
+            for _ in range(2))
+    ones = np.ones(3000, np.uint32)
+    _, totals, _, ovalid, n_unique = jax.jit(
+        lambda k, x, y, z: wordcount.group_sorted(
+            (k,), [(x, None), (y, None), (z, None)], 64))(keys, a, b, ones)
+    totals = np.asarray(totals).astype(np.uint64)
+    assert totals.shape == (64, 6) and int(n_unique) == len(set(keys))
+    got = totals[:, 0::2] | (totals[:, 1::2] << np.uint64(32))
+    for i, key in enumerate(sorted(set(keys.tolist()))):
+        rows = keys == key
+        assert got[i].tolist() == [int(a[rows].astype(np.uint64).sum()),
+                                   int(b[rows].astype(np.uint64).sum()),
+                                   int(rows.sum())]
+    assert not got[int(n_unique):].any() and ovalid.sum() == n_unique
+    # one pair in a tuple is what it was
+    _, one, *_ = jax.jit(lambda k, x: wordcount.group_sorted(
+        (k,), (x, None), 64))(keys, a)
+    assert (np.asarray(one) == np.asarray(totals[:, :2])).all()
+
+
+def test_a_packed_step_of_three_sums_and_the_rendered_mean():
+    groups = {b"10.0.0.1": ((1 << 40) + 5, (1 << 33) + 1, 3),
+              b"9.9.9.9": (7, 2, 3), b"a": (12_500_000, 4, 3)}
+    keys = sorted(groups)
+    packed = np.zeros((1, 4, 12), np.uint32)
+    for i, key in enumerate(keys):
+        packed[0, i, :4] = _lanes(key)
+        packed[0, i, 4] = len(key)
+        for j, value in enumerate(groups[key]):
+            packed[0, i, 5 + 2 * j] = value & 0xFFFFFFFF
+            packed[0, i, 6 + 2 * j] = value >> 32
+        packed[0, i, 11] = i % 2
+    acc = PackedCounts(decimals=6, compact_rows=4)
+    for _ in range(3):  # through a window's merge and into the table
+        acc.add_packed_step(packed, [3], 4)
+    result = acc.finalize()
+    assert result.cnts.tolist() == [[3 * v for v in groups[k]] for k in keys]
+    assert result.parts.tolist() == [0, 1, 0]
+    want = {k: f"{k.decode()} {3 * r // 10 ** 6}.{3 * r % 10 ** 6:06d} "
+               f"{s * 10 ** 6 // n // 10 ** 6}.{s * 10 ** 6 // n % 10 ** 6:06d}\n"
+            for k, (r, s, n) in groups.items()}
+    assert result.render_partition(0).decode() == want[keys[0]] + want[keys[2]]
+    assert result.render_partition(1).decode() == want[keys[1]]
+    assert want[b"9.9.9.9"] == "9.9.9.9 0.000021 0.666666\n"
+    assert result[ "a"] == ([37_500_000, 12, 9], 0)

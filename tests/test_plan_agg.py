@@ -258,7 +258,7 @@ def test_the_engine_refuses_a_map_beside_what_it_cannot_carry(option):
 
 
 def test_the_stage_the_plan_and_the_counters_are_registered():
-    assert len(STAGE_KINDS) == 9 and STAGE_KINDS[-1] == "aggregate"
+    assert STAGE_KINDS[8] == "aggregate"
     plan = agg_plan(["a", "b"], prefix=7, chunk_bytes=CHUNK)
     (stage,) = plan.ordered()
     assert (stage.name, stage.kind, stage.deps) == ("agg", "aggregate", ())
