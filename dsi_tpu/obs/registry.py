@@ -165,7 +165,11 @@ window's at its compaction, the merged table's never) and
 ``merge_compacts`` (all five repeat exactly for one input, with the
 native library or without; ``merge_compacts_async`` of them were handed
 to a merger thread: the windows that filled), ``buffer_allocs``, ``ckpt_saves``, ``ckpt_every``, ``resume_gap_s``,
-``resume_cursor``/``resume_wave``, ``device_accumulate``.  The indexer's
+``resume_cursor``/``resume_wave``, ``device_accumulate``.  The grep
+engine's row cut (``grepstream.batch_lines``) adds ``recopied_bytes``:
+the bytes that waited behind a block's last cut as the head of the next
+row and so were copied twice (under a row's worth a block; near the
+input's bytes would say the rows are not cut in place).  The indexer's
 wave walk adds ``docs`` (documents handed over), ``waves_by_size``
 (padded chunk bytes → waves dispatched), ``wave_doc_bytes`` and
 ``wave_chunk_bytes`` (the documents' bytes and the padded bytes they
@@ -525,6 +529,9 @@ COUNTER_KEYS = (
     "table_cap", "sync_every", "max_inflight",
     "buffer_allocs", "device_accumulate", "donate_chunks", "stalls",
     "device_rows",
+    # the grep engine's row cut (grepstream.batch_lines): the bytes that
+    # waited behind a block's last cut and so were copied twice
+    "recopied_bytes",
     # the host accumulator (parallel/merge.py PackedCounts)
     "merge_rows_in", "merge_rows_sorted", "merge_compacts",
     "merge_runs_in", "merge_runs_unsorted", "merge_compacts_async",

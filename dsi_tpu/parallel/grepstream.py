@@ -9,8 +9,9 @@ TF-IDF already got — engines that consume the shared dispatch/finish
 pipeline core (``parallel/pipeline.py``) with the same contract those
 engines honor bit-identically:
 
-* a background producer feeds a bounded queue (``batch_lines`` /
-  ``_wave_chunk`` materialization off the critical path),
+* a background producer feeds a bounded queue (``batch_lines``, which
+  cuts each row out of the incoming block in place, and ``_wave_chunk``
+  materialize off the critical path),
 * a ``depth``-deep in-flight window of donated per-step uploads through
   ``aotcache.cached_compile(donate_argnums)``,
 * per-step scalar checks DEFERRED until a step leaves the window.  The
@@ -167,12 +168,24 @@ def _default_topk_cap(n_dev: int, k: int) -> int:
 
 def batch_lines(blocks: Iterable[bytes], n_dev: int, chunk_bytes: int,
                 pool: Optional[BufferPool] = None,
-                offsets: Optional[list] = None):
+                offsets: Optional[list] = None,
+                stats: Optional[dict] = None,
+                count_lines: bool = True):
     """Slice a byte-block stream into zero-padded ``[n_dev, chunk_bytes]``
     batches, cutting rows only at newline boundaries so no line straddles
     a row.  Yields ``(batch, lens, row_lines)`` — per-row valid byte
     counts and per-row line counts (the host side of the device's line
     accounting: newlines plus an unterminated tail line).
+
+    A row is cut only while more than ``chunk_bytes`` are pending, or at
+    the end of input, and then behind the last newline its first
+    ``chunk_bytes`` hold.  The rows are cut where the block lies: the
+    newline is found by a backward search (``rfind`` returns within a
+    line) and the row's bytes go from the block into the batch, their
+    only copy.  What a block leaves behind its last cut (under a row)
+    waits in ``rem`` as the head of the next row: those bytes alone are
+    copied twice, and ``stats["recopied_bytes"]`` counts them where the
+    engine hands its ``stats``.
 
     With ``pool`` batches come from the engine's rotating buffer set;
     the consumer hands each batch back via ``pool.give`` once its step
@@ -182,8 +195,12 @@ def batch_lines(blocks: Iterable[bytes], n_dev: int, chunk_bytes: int,
     With ``offsets`` (the checkpoint cursor hook, the ``batch_stream``
     contract) the stream offset just past each yielded batch's content
     is appended, before the yield.
+
+    ``count_lines=False`` is for a caller that throws ``row_lines`` away
+    (``streaming._row_batches``): the counting pass is skipped and the
+    counts stay zero.
     """
-    carry = bytearray()
+    rem = bytearray()  # never past chunk_bytes: linear for 1-byte blocks
     consumed = 0
 
     def new_batch() -> np.ndarray:
@@ -196,42 +213,59 @@ def batch_lines(blocks: Iterable[bytes], n_dev: int, chunk_bytes: int,
     row_lines = np.zeros(n_dev, dtype=np.int64)
     row = 0
 
-    def fill_rows(final: bool):
+    def take_rem(n: int) -> None:
+        # the first n waiting bytes open the row: their second copy
+        batch[row, :n] = np.frombuffer(rem, np.uint8, n)
+        del rem[:n]
+        if stats is not None:
+            stats["recopied_bytes"] += n
+
+    def end_row(n: int):
         nonlocal batch, lens, row_lines, row, consumed
-        while carry and (len(carry) > chunk_bytes or final):
-            if len(carry) <= chunk_bytes:
-                cut = len(carry)  # final tail: whole remainder fits
-            else:
-                win = np.frombuffer(memoryview(carry)[:chunk_bytes],
-                                    dtype=np.uint8)
-                hits = np.flatnonzero(win == 10)
-                del win  # release the export before the carry resize
-                if hits.size == 0:
-                    raise _LineTooLong
-                cut = int(hits[-1]) + 1  # cut AFTER the last newline
-            view = np.frombuffer(carry, dtype=np.uint8, count=cut)
-            batch[row, :cut] = view
-            n_nl = int(np.count_nonzero(view == 10))
-            del view
-            del carry[:cut]
-            consumed += cut
-            batch[row, cut:] = 0
-            lens[row] = cut
-            row_lines[row] = n_nl + (1 if batch[row, cut - 1] != 10 else 0)
-            row += 1
-            if row == n_dev:
-                if offsets is not None:
-                    offsets.append(consumed)
-                yield batch, lens, row_lines
-                batch = new_batch()
-                lens = np.zeros(n_dev, dtype=np.int32)
-                row_lines = np.zeros(n_dev, dtype=np.int64)
-                row = 0
+        filled = batch[row, :n]
+        batch[row, n:] = 0
+        lens[row] = n
+        if count_lines:
+            row_lines[row] = (np.count_nonzero(filled == 10)
+                              + (1 if filled[-1] != 10 else 0))
+        consumed += n
+        row += 1
+        if row == n_dev:
+            if offsets is not None:
+                offsets.append(consumed)
+            yield batch, lens, row_lines
+            batch = new_batch()
+            lens = np.zeros(n_dev, dtype=np.int32)
+            row_lines = np.zeros(n_dev, dtype=np.int64)
+            row = 0
 
     for block in blocks:
-        carry.extend(block)
-        yield from fill_rows(final=False)
-    yield from fill_rows(final=True)
+        if not hasattr(block, "rfind"):
+            block = bytes(block)  # a memoryview: search what can
+        pos, size = 0, len(block)
+        while len(rem) + size - pos > chunk_bytes:
+            head = len(rem)
+            end = block.rfind(b"\n", pos, pos + chunk_bytes - head) + 1
+            if end:  # cut AFTER the last newline that fits
+                if head:
+                    take_rem(head)
+                n = head + end - pos
+                batch[row, head:n] = np.frombuffer(block, np.uint8,
+                                                   end - pos, pos)
+                pos = end
+            else:
+                # The block's part of the room holds no newline: the
+                # remainder (whole lines a block left) is searched on.
+                n = rem.rfind(b"\n") + 1
+                if not n:
+                    raise _LineTooLong
+                take_rem(n)
+            yield from end_row(n)
+        rem += memoryview(block)[pos:]
+    if rem:  # the final tail: at most a row, it fits whole
+        n = len(rem)
+        take_rem(n)
+        yield from end_row(n)
     if row:
         batch[row:] = 0  # recycled buffer: stale tail rows must not count
         if offsets is not None:
@@ -679,7 +713,7 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
     stats.update({"depth": depth, "steps": 0, "replays": 0,
                   "results_ready": 0, "step_pulls": 0, "sync_pulls": 0,
                   "device_accumulate": device_accumulate,
-                  "batch_s": 0.0, "batch_wait_s": 0.0,
+                  "batch_s": 0.0, "batch_wait_s": 0.0, "recopied_bytes": 0,
                   "upload_s": 0.0, "kernel_s": 0.0, "pull_s": 0.0,
                   "device_wait_s": 0.0, "d2h_s": 0.0, "pull_bytes": 0,
                   "merge_s": 0.0, "replay_s": 0.0})
@@ -1028,8 +1062,8 @@ def _grep_setup(step, blocks, pattern, mesh, chunk_bytes, depth, aot,
     feed = skip_stream(blocks, start_offset) if start_offset else blocks
     step._pipe = pipe
     step._cursor_ref = ck_cursor
-    pipe.begin(lambda: batch_lines(feed, n_dev, chunk_bytes,
-                                   pool=pool, offsets=offsets))
+    pipe.begin(lambda: batch_lines(feed, n_dev, chunk_bytes, pool=pool,
+                                   offsets=offsets, stats=stats))
     step._host_excs = (_LineTooLong,)
     step._save = save_ckpt if ck_store is not None else None
     step._writer = ck_writer

@@ -288,14 +288,17 @@ def _row_batches(blocks: Iterable[bytes], n_dev: int, chunk_bytes: int,
                  pool: Optional[BufferPool] = None,
                  offsets: Optional[list] = None) -> Iterator[np.ndarray]:
     """:func:`batch_stream`'s batches for a stream of rows: cut behind a
-    newline (``parallel/grepstream.batch_lines``' cut), so no row
-    straddles a chunk.  A row that no chunk can hold fails the job."""
+    newline (``parallel/grepstream.batch_lines``' cut, in place in the
+    incoming block, without its line counts, which nothing here reads),
+    so no row straddles a chunk.  A row that no chunk can hold fails the
+    job."""
     from dsi_tpu.ops.fieldsum import BadRow
     from dsi_tpu.parallel.grepstream import _LineTooLong, batch_lines
 
     try:
         for batch, _lens, _lines in batch_lines(blocks, n_dev, chunk_bytes,
-                                                pool=pool, offsets=offsets):
+                                                pool=pool, offsets=offsets,
+                                                count_lines=False):
             yield batch
     except _LineTooLong:
         raise BadRow(f"a row is longer than a chunk's {chunk_bytes} bytes")

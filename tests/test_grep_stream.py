@@ -79,6 +79,175 @@ def test_batch_lines_exact_chunk_final_line_fits():
     assert int(lens[0]) == 16 and int(row_lines[0]) == 1
 
 
+# The cut against a plain reference written here: join the blocks, cut
+# greedily behind the last newline that fits, a row while more than a
+# chunk is left and the rest as the last row.
+
+_CUT_CHUNK = 32
+
+_CUT_BLOCK_SIZES = (1, 7, _CUT_CHUNK - 1, _CUT_CHUNK, _CUT_CHUNK + 1,
+                    4 * _CUT_CHUNK, "random")
+
+
+def _cut_text(kind: str) -> bytes:
+    rng = np.random.default_rng(sum(kind.encode()))
+    lines = [b"w" * int(n) for n in rng.integers(0, _CUT_CHUNK - 1, 40)]
+    if kind == "empty_stream":
+        return b""
+    if kind == "no_final_newline":
+        return b"\n".join(lines) + b"\ntail without its newline"
+    if kind == "empty_line_runs":
+        for at in (3, 11, 12, 30):
+            lines[at:at] = [b""] * (2 * _CUT_CHUNK + at)
+    if kind == "exact_chunk_line":
+        # a line that fills a row to its last byte, three times
+        for at in (0, 9, 17):
+            lines[at:at] = [b"x" * (_CUT_CHUNK - 1)]
+        lines.append(b"x" * (_CUT_CHUNK - 1))
+    return b"\n".join(lines) + b"\n"
+
+
+def _cut_blocks(data: bytes, size, gaps: bool = False):
+    rng = np.random.default_rng(len(data))
+    blocks, pos = [], 0
+    while pos < len(data):
+        n = int(rng.integers(1, 5 * _CUT_CHUNK)) if size == "random" else size
+        blocks.append(data[pos:pos + n])
+        pos += n
+    if gaps:  # empty blocks between the full ones, and at both ends
+        blocks = [b for full in blocks for b in (b"", full, b"")]
+        # ... in the other forms a block may take
+        blocks = [bytearray(b) if i % 3 == 1 else
+                  memoryview(b) if i % 3 == 2 else b
+                  for i, b in enumerate(blocks)]
+    return blocks
+
+
+def _reference_cut(data: bytes, n_dev: int, chunk: int):
+    """``(rows, offsets)``: the rows, and the stream offset behind every
+    ``n_dev`` of them and behind the last."""
+    rows, pos = [], 0
+    while len(data) - pos > chunk:
+        cut = data.rfind(b"\n", pos, pos + chunk) + 1
+        if not cut:
+            raise _LineTooLong
+        rows.append(data[pos:cut])
+        pos = cut
+    if pos < len(data):
+        rows.append(data[pos:])
+    ends = np.cumsum([len(r) for r in rows]).tolist()
+    return rows, ends[n_dev - 1::n_dev] + (ends[-1:] if len(rows) % n_dev
+                                           else [])
+
+
+def _cut_rows(blocks, n_dev: int, chunk: int, pool=None):
+    """``batch_lines``' rows, lengths and line counts, flat, the unused
+    rows of the last batch dropped once they are seen to be zero, and its
+    offsets."""
+    offsets, rows, lens, lines = [], [], [], []
+    for batch, blens, row_lines in batch_lines(iter(blocks), n_dev, chunk,
+                                               pool=pool, offsets=offsets):
+        assert batch.shape == (n_dev, chunk)
+        for d in range(n_dev):
+            assert not batch[d, blens[d]:].any()  # zero tail, stale or not
+            if blens[d] == 0:
+                assert row_lines[d] == 0 and not blens[d:].any()
+                continue
+            rows.append(bytes(batch[d, :blens[d]]))
+            lens.append(int(blens[d]))
+            lines.append(int(row_lines[d]))
+        if pool is not None:
+            batch[:] = 0xFF  # what a confirmed step leaves is stale
+            pool.give(batch)
+    return rows, lens, lines, offsets
+
+
+@pytest.mark.parametrize("n_dev", [1, 2, 4])
+@pytest.mark.parametrize("size", _CUT_BLOCK_SIZES)
+@pytest.mark.parametrize("kind", ["no_final_newline", "empty_line_runs",
+                                  "exact_chunk_line", "empty_blocks_between",
+                                  "empty_stream"])
+def test_batch_lines_matches_the_plain_cut(kind, size, n_dev):
+    data = _cut_text(kind)
+    blocks = _cut_blocks(data, size, gaps=kind == "empty_blocks_between")
+    want_rows, want_offsets = _reference_cut(data, n_dev, _CUT_CHUNK)
+    rows, lens, lines, offsets = _cut_rows(blocks, n_dev, _CUT_CHUNK)
+    assert rows == want_rows
+    assert lens == [len(r) for r in want_rows]
+    assert lines == [r.count(b"\n") + (not r.endswith(b"\n"))
+                     for r in want_rows]
+    assert offsets == want_offsets
+    if kind == "exact_chunk_line":
+        assert lens.count(_CUT_CHUNK) >= 4
+
+
+@pytest.mark.parametrize("size", _CUT_BLOCK_SIZES)
+def test_batch_lines_line_of_a_chunk_and_a_byte_raises(size):
+    data = (_cut_text("exact_chunk_line") + b"y" * _CUT_CHUNK + b"\n"
+            + b"after\n")
+    before, _ = _reference_cut(data[:data.index(b"y")], 1, _CUT_CHUNK)
+    got = []
+    with pytest.raises(_LineTooLong):
+        for batch, lens, _lines in batch_lines(
+                iter(_cut_blocks(data, size)), 1, _CUT_CHUNK):
+            got.append(bytes(batch[0, :lens[0]]))
+    assert got == before  # raised only once every row before it was cut
+    with pytest.raises(_LineTooLong):
+        _reference_cut(data, 1, _CUT_CHUNK)
+
+
+@pytest.mark.parametrize("n_dev", [1, 2, 4])
+@pytest.mark.parametrize("size", [7, _CUT_CHUNK + 1, 4 * _CUT_CHUNK])
+def test_batch_lines_zeroes_what_a_recycled_buffer_held(size, n_dev):
+    from dsi_tpu.parallel.pipeline import BufferPool
+
+    pool = BufferPool((n_dev, _CUT_CHUNK), retain=4)
+    stale = [pool.take() for _ in range(3)]
+    for buf in stale:
+        buf[:] = 0xFF
+        pool.give(buf)
+    data = _cut_text("no_final_newline")
+    want_rows, want_offsets = _reference_cut(data, n_dev, _CUT_CHUNK)
+    # _cut_rows asserts every row's tail and the last batch's unused rows
+    rows, _lens, _lines, offsets = _cut_rows(_cut_blocks(data, size), n_dev,
+                                             _CUT_CHUNK, pool=pool)
+    assert rows == want_rows and offsets == want_offsets
+    assert len(want_rows) % 4 and pool.allocs == 3  # a partial last batch
+
+
+@pytest.mark.parametrize("n_dev", [1, 2, 4])
+@pytest.mark.parametrize("size", [1, _CUT_CHUNK, "random"])
+def test_batch_lines_resumes_behind_any_offset(size, n_dev):
+    """The checkpoint cursor's contract: the stream behind ``offsets[i]``
+    gives the batches from ``i + 1`` on."""
+    from dsi_tpu.ckpt import skip_stream
+
+    data = _cut_text("empty_line_runs")
+    blocks = _cut_blocks(data, size)
+    rows, lens, lines, offsets = _cut_rows(blocks, n_dev, _CUT_CHUNK)
+    assert len(offsets) > 4
+    for i in range(len(offsets) - 1):
+        again = _cut_rows(skip_stream(iter(blocks), offsets[i]), n_dev,
+                          _CUT_CHUNK)
+        at = (i + 1) * n_dev
+        assert again == (rows[at:], lens[at:], lines[at:],
+                         [o - offsets[i] for o in offsets[i + 1:]])
+
+
+def test_row_batches_are_batch_lines_rows_without_the_counts():
+    from dsi_tpu.parallel.streaming import _row_batches
+
+    data = _cut_text("empty_line_runs")
+    blocks = _cut_blocks(data, 4 * _CUT_CHUNK)
+    offsets, want_offsets = [], []
+    got = [b.copy() for b in _row_batches(iter(blocks), 2, _CUT_CHUNK,
+                                          offsets=offsets)]
+    want = [b.copy() for b, _l, _n in batch_lines(
+        iter(blocks), 2, _CUT_CHUNK, offsets=want_offsets)]
+    assert offsets == want_offsets and len(got) == len(want)
+    assert all(np.array_equal(g, w) for g, w in zip(got, want))
+
+
 # ── grep: oracle + host path ───────────────────────────────────────────
 
 
